@@ -373,7 +373,7 @@ def _build_fused_step(node_specs, head_specs, grad_slots, hg_present,
 # instead of executing it, and Trainer.step() executes the plan with
 # the multi-tensor optimizer appended — fwd+bwd+update as ONE XLA
 # program, no separate optimizer dispatch re-reading w/g/m from HBM
-# (PERF_r05 §2: that program measures 0.49 ms on ResNet-50).
+# (that program: 0.49 ms on ResNet-50, round-5 builder figure).
 #
 # Safety contract: anything that needs gradients before step() flushes
 # the pending plan first (Parameter.grad()/list_grad() call

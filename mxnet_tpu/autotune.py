@@ -76,6 +76,7 @@ _VMEM_BUDGET = 10 * 1024 * 1024
 _HBM_BW_BY_KIND = (("v5e", 819e9), ("v5p", 2765e9), ("v4", 1228e9),
                    ("v3", 900e9), ("v6", 1600e9))
 _HBM_BW_FALLBACK = 819e9
+_PEAK_FLOPS_FALLBACK = 197e12
 
 
 class Candidate:
@@ -91,13 +92,22 @@ class Candidate:
                   is the candidate program. Used for the probe compile
                   (cost mode) and the paired measurement (measure
                   mode); example_args must be concrete arrays.
+    opaque      : the program is a Pallas kernel. XLA's cost analysis
+                  cannot see into it — an opaque custom call on the
+                  chip, the interpreter's grid loop (body counted
+                  once, so small blocks look cheap) on the CPU — so
+                  the candidate scores on its analytic features and
+                  the probe compile is a feasibility check only.
     """
 
-    __slots__ = ("params", "flops", "hbm_bytes", "vmem_bytes", "build")
+    __slots__ = ("params", "flops", "hbm_bytes", "vmem_bytes", "build",
+                 "opaque")
 
     def __init__(self, params: dict, flops: float = 0.0,
                  hbm_bytes: float = 0.0, vmem_bytes: float = 0.0,
-                 build: Optional[Callable] = None):
+                 build: Optional[Callable] = None,
+                 opaque: bool = False):
+        self.opaque = bool(opaque)
         self.params = dict(params)
         self.flops = float(flops)
         self.hbm_bytes = float(hbm_bytes)
@@ -191,18 +201,15 @@ def clear():
 # scoring
 # ---------------------------------------------------------------------------
 def _peaks():
+    """(FLOP/s, bytes/s) the roofline RANKS candidates with — never
+    reported. An unknown device kind (the CPU mesh) ranks with the v5e
+    pair: only the ratio of the two matters."""
+    import jax
     from . import telemetry
-    pf = telemetry.peak_flops()
-    bw = _HBM_BW_FALLBACK
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-        for marker, v in _HBM_BW_BY_KIND:
-            if marker in kind:
-                bw = v
-                break
-    except Exception:
-        pass
+    kind = jax.devices()[0].device_kind.lower()
+    pf = telemetry.known_peak_flops() or _PEAK_FLOPS_FALLBACK
+    bw = next((v for marker, v in _HBM_BW_BY_KIND if marker in kind),
+              _HBM_BW_FALLBACK)
     return pf, bw
 
 
@@ -244,6 +251,8 @@ def _score_cost(cands: Sequence[Candidate]):
             try:
                 fn, args = c.build()
                 compiled, flops, hbm = _aot_probe(fn, args)
+                if c.opaque:
+                    flops = hbm = None
             except Exception as e:
                 _LOG.debug("autotune: probe compile failed for %r "
                            "(%s: %s) — candidate disqualified",
@@ -436,7 +445,8 @@ def tuned_rows(kernel: str, M: int, C: int, esize: int, default,
                           hbm_bytes=hbm_bytes,
                           vmem_bytes=bm * per_row_bytes * 2
                           + extra_bytes,
-                          build=None if probe is None else probe(bm))
+                          build=None if probe is None else probe(bm),
+                          opaque=True)
                 for bm in _ROW_GRID
                 if bm >= floor and M % bm == 0]
 

@@ -133,7 +133,7 @@ define("MXNET_FUSED_BACKWARD", bool, True,
        "fwd+bwd XLA program at backward() (autograd.py).")
 define("MXNET_SHARDED_AUTO_LAYOUT", bool, True,
        "Let XLA pick parameter layouts for ShardedTrainStep on TPU "
-       "(AUTO layouts; PERF_r03/r05).")
+       "(AUTO layouts).")
 define("MXNET_PALLAS_INTERPRET", bool, False,
        "Run Pallas kernels in interpreter mode (CPU testing).")
 define("MXNET_PALLAS_LAYERNORM", bool, True,
@@ -196,8 +196,8 @@ define("MXNET_TRAINER_FUSED_UPDATE", bool, True,
        "Gluon hybridize+Trainer loops execute the multi-tensor "
        "optimizer INSIDE the compiled fwd+bwd program (one XLA "
        "program per step, no separate optimizer dispatch re-reading "
-       "w/g/m from HBM — PERF_r05 §2 measured that program at 0.49 "
-       "ms on ResNet-50). Engages only when the kvstore resolves to "
+       "w/g/m from HBM — that program: 0.49 ms on ResNet-50, round-5 "
+       "builder figure). Engages only when the kvstore resolves to "
        "the local single-device path with update_on_kvstore=False, "
        "the optimizer has a fused in-graph form (SGD), every trained "
        "parameter has grad_req='write' and no GradGuard is active; "
@@ -462,9 +462,9 @@ define("MXNET_PEAK_FLOPS", float, 0.0,
        "Per-chip peak FLOP/s used by the mx_mfu gauge "
        "(model-flops-utilization = measured executed FLOPs per second "
        "/ peak). 0 = auto-detect from the device kind (TPU v3/v4/v5e/"
-       "v6e bf16 peaks); unknown devices (e.g. the CPU dryrun mesh) "
-       "fall back to the v5e flagship 197e12 so the gauge stays "
-       "populated and cross-round comparable.")
+       "v6e bf16 peaks); on an unknown device kind (e.g. the CPU "
+       "mesh) the gauge is not populated and telemetry.peak_flops() "
+       "raises — state a peak here to get one.")
 define("MXNET_MODELWATCH", bool, False,
        "Training-dynamics observability (mxnet_tpu/modelwatch.py; "
        "needs MXNET_TELEMETRY=1): per-layer gradient/param/update-"

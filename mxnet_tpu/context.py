@@ -107,7 +107,10 @@ def _resolve(devtype: str, devid: int) -> jax.Device:
     accs = _accelerators()
     if not accs:
         # CPU fallback keeps the tpu-context test-suite runnable on the
-        # 8-virtual-device CPU mesh (SURVEY.md §4 pattern 4).
+        # 8-virtual-device CPU mesh (SURVEY.md §4 pattern 4). It also
+        # means tpu(i) proves nothing about the platform: a program
+        # that reports device numbers calls
+        # runtime.require_accelerator() first (chip_smoke.py, bench.py).
         accs = jax.local_devices()
     if devid >= len(accs):
         raise MXNetError(

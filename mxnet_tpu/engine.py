@@ -97,8 +97,6 @@ class NativeDependencyEngine:
         import ctypes
         from . import native as native_mod
         lib = native_mod.load_engine_lib()
-        if lib is None:
-            raise MXNetError("native engine library unavailable")
         self._lib = lib
         self._ct = ctypes
         self._h = lib.MXEngineCreate(num_workers, int(naive))

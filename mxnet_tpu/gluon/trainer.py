@@ -204,8 +204,8 @@ class Trainer:
         the Trainer arms autograd so the NEXT backward() defers, and
         this step executes fwd+bwd+optimizer as ONE compiled program —
         removing the separate optimizer dispatch that re-reads w/g/m
-        from HBM (PERF_r05 §2: 0.49 ms on ResNet-50). Any mismatch
-        falls back to the reference-idiomatic separate program.
+        from HBM (0.49 ms on ResNet-50, round-5 builder figure). Any
+        mismatch falls back to the reference-idiomatic separate program.
 
         ZeRO mode (MXNET_ZERO, multi-replica loops; gluon/zero.py,
         docs/ZERO.md): gradients are reduce-scattered instead of

@@ -657,12 +657,8 @@ class ZeroEngine:
             raise ValueError(variant)
 
         from ..parallel.collectives import shard_map
-        try:
-            mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-        except TypeError:     # newer jax renamed/dropped check_rep
-            mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+        mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return compilewatch.watched_jit(
             mapped, "zero.%s" % variant, site="zero",
             arg_names=arg_names, instance="zero.%s" % variant,

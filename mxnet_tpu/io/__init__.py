@@ -390,9 +390,6 @@ class ImageRecordIter(DataIter):
         if len(data_shape) != 3 or data_shape[0] != 3:
             raise ValueError("data_shape must be (3, H, W)")
         self._lib = native_mod.load_io_lib()
-        if self._lib is None:
-            raise MXNetError("native io library unavailable: %s"
-                             % native_mod.last_error())
         self._c, self._h, self._w = (int(data_shape[0]), int(data_shape[1]),
                                      int(data_shape[2]))
         self._label_width = int(label_width)

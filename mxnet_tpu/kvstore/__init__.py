@@ -173,12 +173,8 @@ class _CollectiveReducer:
         extra = 1 if cfg.stochastic and cfg.mode == "int8" else 0
         in_specs = (P(axis),) * (1 + nkeys) + (P(),) * extra
         out_specs = (P(axis),) + (P(),) * nkeys
-        try:
-            mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-        except TypeError:      # newer jax renamed/dropped check_rep
-            mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+        mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         arg_names = ["residual"] + ["grad%d" % i for i in range(nkeys)] \
             + (["qseed"] if extra else [])
         fn = compilewatch.watched_jit(

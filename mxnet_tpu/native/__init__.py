@@ -1,9 +1,11 @@
 """Loader for the native runtime components (C++ .so via ctypes).
 
 The reference's hot paths are C++ (src/io, src/engine); here the native
-layer is built from mxnet_tpu/native/*.cc. The library is compiled on
-first use if the checkout doesn't ship a binary (g++ is part of the
-supported toolchain); pure-Python fallbacks exist for every consumer.
+layer is built from mxnet_tpu/native/*.cc. The binaries are not checked
+in (.gitignore): the library is compiled on first use (g++ and make are
+part of the supported toolchain). A build or load that fails raises
+MXNetError carrying the compiler's output — there is no Python stand-in
+to fall back to, and a silent ``None`` hid the reason.
 """
 from __future__ import annotations
 
@@ -11,29 +13,36 @@ import ctypes
 import os
 import subprocess
 
+from ..base import MXNetError
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB = None
-_TRIED = False
+
+
+def _load(target):
+    """ctypes handle of ``target``, built with make if absent."""
+    path = os.path.join(_DIR, target)
+    if not os.path.exists(path):
+        # one target at a time: the engine must not become unavailable
+        # because the io lib's -ljpeg link failed
+        proc = subprocess.run(["make", "-C", _DIR, target],
+                              capture_output=True, text=True, timeout=120)
+        if proc.returncode:
+            raise MXNetError("native build of %s failed:\n%s%s"
+                             % (target, proc.stdout, proc.stderr))
+    try:
+        return ctypes.CDLL(path)
+    except OSError as e:
+        raise MXNetError("native library %s does not load: %s"
+                         % (target, e)) from e
 
 
 def load_io_lib():
-    """Return the libmxtpu_io ctypes handle, building it if needed;
-    None if unavailable (callers fall back to Python)."""
-    global _LIB, _TRIED
-    if _LIB is not None or _TRIED:
+    """Return the libmxtpu_io ctypes handle, building it if needed."""
+    global _LIB
+    if _LIB is not None:
         return _LIB
-    _TRIED = True
-    path = os.path.join(_DIR, "libmxtpu_io.so")
-    if not os.path.exists(path):
-        try:
-            subprocess.run(["make", "-C", _DIR], capture_output=True,
-                           timeout=120, check=True)
-        except Exception:
-            return None
-    try:
-        lib = ctypes.CDLL(path)
-    except OSError:
-        return None
+    lib = _load("libmxtpu_io.so")
     lib.MXIOGetLastError.restype = ctypes.c_char_p
     lib.MXIOCreateImageRecordIter.restype = ctypes.c_void_p
     lib.MXIOCreateImageRecordIter.argtypes = [
@@ -52,36 +61,19 @@ def load_io_lib():
 
 
 def last_error() -> str:
-    lib = load_io_lib()
-    if lib is None:
-        return "native io library unavailable"
-    return (lib.MXIOGetLastError() or b"").decode()
+    return (load_io_lib().MXIOGetLastError() or b"").decode()
 
 
 _ENGINE_LIB = None
-_ENGINE_TRIED = False
 
 
 def load_engine_lib():
     """Return the libmxtpu_engine ctypes handle (MXEngine*/MXGetVersion
-    C ABI), building on demand; None if unavailable."""
-    global _ENGINE_LIB, _ENGINE_TRIED
-    if _ENGINE_LIB is not None or _ENGINE_TRIED:
+    C ABI), building on demand."""
+    global _ENGINE_LIB
+    if _ENGINE_LIB is not None:
         return _ENGINE_LIB
-    _ENGINE_TRIED = True
-    path = os.path.join(_DIR, "libmxtpu_engine.so")
-    if not os.path.exists(path):
-        try:
-            # build only the engine target: it must not become
-            # unavailable because the io lib's -ljpeg link failed
-            subprocess.run(["make", "-C", _DIR, "libmxtpu_engine.so"],
-                           capture_output=True, timeout=120, check=True)
-        except Exception:
-            return None
-    try:
-        lib = ctypes.CDLL(path)
-    except OSError:
-        return None
+    lib = _load("libmxtpu_engine.so")
     lib.MXGetLastError.restype = ctypes.c_char_p
     lib.MXGetVersion.argtypes = [ctypes.POINTER(ctypes.c_int)]
     lib.MXEngineCreate.restype = ctypes.c_void_p

@@ -111,12 +111,13 @@ def sdp_selfatt(rng, queries_keys_values, *, heads, dropout=0.0,
     interleaved_matmul composition is the fallback. The [L,L]
     probabilities and dropout masks never hit HBM; the backward
     recomputes them flash-style from per-block hardware-PRNG seeds."""
-    L, N, _ = queries_keys_values.shape
+    L, N, thd = queries_keys_values.shape
     p = float(dropout) if _train else 0.0
     from .pallas_attention import flash_selfatt, selfatt_plan
     heads_i = int(heads)
     plan = selfatt_plan(L, heads_i, N, p,
-                        dtype=queries_keys_values.dtype)
+                        dtype=queries_keys_values.dtype,
+                        head_dim=thd // (3 * heads_i))
     if plan is not None:
         n_blk = plan["n_blocks"]
         if p > 0.0:
@@ -242,7 +243,7 @@ def fused_lm_head_ce(hidden, weight, bias, labels):
 # ---------------------------------------------------------------------------
 # streaming chunked LM-head cross entropy (round-6 kernel work)
 #
-# The r5 `--fusedce` experiment (PERF_r05.md §1 negative results) showed
+# The r5 `--fusedce` experiment (round-5 builder figures) showed
 # that recomputing the FULL-vocab logits in the backward costs more MXU
 # time (~2.9 ms) than the saved logits traffic at seq 128. This op keeps
 # the fused op's memory win without that loss: an online softmax over

@@ -4,7 +4,8 @@ interleaved_matmul_selfatt_qk/valatt — the reference's hand-written
 attention kernels exist for exactly this reason: stock composition
 leaves perf on the table).
 
-Round-7 rework (ISSUE 14, PERF_r06 residual "transpose_jvp 1.76 ms"):
+Round-7 rework (ISSUE 14; the round-6 builder's forecast residual
+"transpose_jvp 1.76 ms"):
 the kernel now consumes the reference-packed ``(L, N, heads*3*hd)``
 QKV layout DIRECTLY. The r6 version reshaped to ``(N*heads, L, 3*hd)``
 with an XLA transpose outside the kernel — cheap per call, but its jvp
@@ -42,6 +43,7 @@ default as the incumbent.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +54,7 @@ __all__ = ["flash_selfatt", "flash_selfatt_available", "selfatt_plan"]
 _MAX_L = 1024   # scores for one head block must fit VMEM comfortably
 _BB = 16        # max heads per grid step (the r6 batch-head block size)
 _SUBLANE = 16   # seq padding unit (bf16 sublane tile)
+_LANE = 128     # minor-axis tile
 
 # VMEM working-set budget shared with autotune's feasibility gate
 _VMEM_BUDGET = 10 * 1024 * 1024
@@ -73,20 +76,33 @@ def _block_bytes(bbh, L_pad, hd, esize, n_score_temps):
                   + n_score_temps * L_pad * L_pad * 4)
 
 
+def _lane_unit(hd):
+    """Smallest head count whose ``hd`` lanes fill whole 128-lane
+    tiles: a block's minor dimension (``bbh*hd`` out, ``bbh*3*hd`` in)
+    must be a multiple of 128 for the Mosaic lowering."""
+    return _LANE // math.gcd(_LANE, hd)
+
+
+def _fits(bbh, L_pad, hd, esize):
+    return _block_bytes(bbh, L_pad, hd, esize, 5) * 2 <= _VMEM_BUDGET
+
+
 def _default_block_heads(heads, L_pad, hd, esize):
-    """Largest divisor of ``heads`` ≤ _BB whose working set fits the
-    VMEM budget (backward temp count = 5, the worse case); None when
-    even one head per step cannot fit."""
-    for bbh in range(min(heads, _BB), 0, -1):
-        if heads % bbh:
-            continue
-        if _block_bytes(bbh, L_pad, hd, esize, 5) * 2 <= _VMEM_BUDGET:
-            return bbh
-    return None
+    """Heads per grid step: a multiple of the lane unit whose working
+    set fits the VMEM budget (backward temp count = 5, the worse
+    case), least head padding first, then fewest grid steps; None when
+    no such block exists."""
+    unit = _lane_unit(hd)
+    fits = [b for b in range(unit, _ceil_to(min(heads, _BB), unit) + 1,
+                             unit)
+            if _fits(b, L_pad, hd, esize)]
+    if not fits:
+        return None
+    return min(fits, key=lambda b: (_ceil_to(heads, b), -b))
 
 
 def selfatt_plan(L, heads, batch, dropout=0.0, dtype=None,
-                 block_heads=None):
+                 block_heads=None, head_dim=64):
     """Kernel launch geometry for one packed self-attention call — or
     None when the Pallas path cannot serve it (the caller then uses the
     unfused interleaved-matmul composition).
@@ -95,12 +111,14 @@ def selfatt_plan(L, heads, batch, dropout=0.0, dtype=None,
     ``bbh`` heads per grid step (autotuned unless ``block_heads``
     overrides), ``heads_pad = n_hblk * bbh`` (zero-padded final block
     when bbh does not divide heads), ``n_blocks = batch * n_hblk`` the
-    per-block dropout-seed count.
+    per-block dropout-seed count. ``head_dim`` sizes the VMEM check and
+    the lane alignment of ``bbh`` (BERT-family 64 when not given).
     """
     from ..config import get as _cfg
-    if not _cfg("MXNET_FLASH_ATTENTION"):
+    from .pallas_common import kernels_allowed
+    if not _cfg("MXNET_FLASH_ATTENTION") or not kernels_allowed():
         return None
-    if L < 1 or L > _MAX_L or heads < 1 or batch < 1:
+    if L < 1 or L > _MAX_L or heads < 1 or batch < 1 or head_dim < 1:
         return None
     if dtype is not None and jnp.dtype(dtype) not in (
             jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16)):
@@ -111,67 +129,64 @@ def selfatt_plan(L, heads, batch, dropout=0.0, dtype=None,
     esize = 2 if dtype is None else jnp.dtype(dtype).itemsize
     L_pad = _ceil_to(L, _SUBLANE)
     return _resolve_plan(int(L), int(L_pad), int(heads), int(batch),
-                         esize, block_heads)
+                         esize, block_heads, int(head_dim))
 
 
-def _resolve_plan(L, L_pad, heads, batch, esize, block_heads):
-    # hd is not known here (the plan is layout-only); size the VMEM
-    # check with the BERT-family head dim 64 — the score temps dominate
-    # the budget for every realistic hd anyway
-    hd_est = 64
-    default = _default_block_heads(heads, L_pad, hd_est, esize)
+def _resolve_plan(L, L_pad, heads, batch, esize, block_heads, hd):
+    default = _default_block_heads(heads, L_pad, hd, esize)
     if default is None:
         return None
     if block_heads is not None:
+        # explicit override (tests): taken as given — an unaligned one
+        # is the interpreter's business and a compile error on the chip
         bbh = int(block_heads)
         if bbh < 1:
             return None
     else:
         bbh = _tuned_block_heads(L, L_pad, heads, batch, esize,
-                                 default, hd_est)
-    if _block_bytes(bbh, L_pad, hd_est, esize, 5) * 2 > _VMEM_BUDGET:
+                                 default, hd)
+    if not _fits(bbh, L_pad, hd, esize):
         bbh = default
     n_hblk = -(-heads // bbh)
     return {"bbh": bbh, "L_pad": L_pad, "heads_pad": n_hblk * bbh,
             "n_hblk": n_hblk, "n_blocks": batch * n_hblk}
 
 
-def _tuned_block_heads(L, L_pad, heads, batch, esize, default, hd_est):
+def _tuned_block_heads(L, L_pad, heads, batch, esize, default, hd):
     """Consult the autotune table for the head-block size (off mode —
     the default — returns ``default`` untouched)."""
     from .. import autotune
 
+    unit = _lane_unit(hd)
+
     def _candidates():
         cands = []
-        # descending: every divisor candidate has identical analytic
+        # descending: every unpadded candidate has identical analytic
         # roofline features (heads_pad == heads), and _score_cost
         # breaks ties on candidate ORDER — larger head blocks mean
         # fewer grid steps, so they must be the preferred tie-winners
-        for bbh in sorted({b for b in (1, 2, 4, 8, _BB, heads)
-                           if 1 <= b <= max(heads, _BB)}
-                          | {b for b in range(1, min(heads, _BB) + 1)
-                             if heads % b == 0}, reverse=True):
+        for bbh in range(_ceil_to(min(heads, _BB), unit), 0, -unit):
             n_hblk = -(-heads // bbh)
             # analytic roofline features: 4 batched matmuls of
             # (L, hd) x (hd, L) per (batch, head) pair fwd+bwd
-            flops = 4.0 * batch * n_hblk * bbh * L_pad * L_pad * hd_est
-            hbm = batch * heads * L * 4 * hd_est * esize
+            flops = 4.0 * batch * n_hblk * bbh * L_pad * L_pad * hd
+            hbm = batch * n_hblk * bbh * L * 4 * hd * esize
             cands.append(autotune.Candidate(
                 {"block_heads": bbh}, flops=flops, hbm_bytes=hbm,
-                vmem_bytes=_block_bytes(bbh, L_pad, hd_est, esize, 5)
-                * 2,
-                build=_probe_builder(L, heads, batch, hd_est, bbh)))
+                vmem_bytes=_block_bytes(bbh, L_pad, hd, esize, 5) * 2,
+                build=_probe_builder(L, heads, batch, hd, bbh),
+                opaque=True))
         return cands
 
     def _valid(params):
         bbh = params.get("block_heads")
-        return (isinstance(bbh, int) and 1 <= bbh
-                and _block_bytes(bbh, L_pad, hd_est, esize, 5) * 2
-                <= _VMEM_BUDGET)
+        return (isinstance(bbh, int) and bbh >= 1 and bbh % unit == 0
+                and _fits(bbh, L_pad, hd, esize))
 
     out = autotune.lookup(
         "pallas_selfatt_packed",
-        {"L": L, "heads": heads, "batch": batch, "esize": esize},
+        {"L": L, "heads": heads, "batch": batch, "esize": esize,
+         "hd": hd},
         {"block_heads": default}, candidates=_candidates,
         validate=_valid)
     return int(out.get("block_heads", default))
@@ -221,13 +236,22 @@ def _keep_mask(pltpu, seed, shape, thresh, interpret):
     return bits >= jnp.uint32(thresh)
 
 
+def _bdot(a, b, contract):
+    """Head-batched in-kernel matmul, f32 accumulation. bf16 operands
+    pin DEFAULT precision: an ambient jax_default_matmul_precision of
+    "highest" would ask Mosaic for an fp32 contraction of bf16 vectors,
+    which it refuses ("Bad lhs type")."""
+    prec = lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
+    return lax.dot_general(a, b, (contract, ((0,), (0,))), precision=prec,
+                           preferred_element_type=jnp.float32)
+
+
 def _attn_fwd_math(pltpu, q, k, seed, L, L_pad, p_drop, keep, thresh,
                    interpret):
     """Shared fwd math on (BBH, L_pad, d) operands: returns (p_raw,
     p_dropped, keep_mask). Padded key columns (>= L) are masked to −∞
     before the softmax so real positions see the unpadded problem."""
-    s = lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32)
+    s = _bdot(q, k, ((2,), (2,)))
     if L_pad != L:
         col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(col < L, s, -1e30)
@@ -240,16 +264,26 @@ def _attn_fwd_math(pltpu, q, k, seed, L, L_pad, p_drop, keep, thresh,
     return p, p, None
 
 
-def _split_qkv_block(blk, bbh, d):
-    """(L_pad, 1, bbh*3*d) packed block -> bf16 (bbh, L_pad, d) q/k/v.
-    Minor-axis slicing + an in-VMEM relayout — the (de)interleave that
-    used to be an HLO transpose outside the kernel."""
-    L_pad = blk.shape[0]
-    x = blk.reshape(L_pad, bbh, 3 * d)
-    q = x[:, :, :d].transpose(1, 0, 2)
-    k = x[:, :, d:2 * d].transpose(1, 0, 2)
-    v = x[:, :, 2 * d:].transpose(1, 0, 2)
-    return q, k, v
+def _split_heads(ref, bbh, d, parts=1, part=0):
+    """(L_pad, bbh*parts*d) packed block ref -> (bbh, L_pad, d): every
+    head's ``part``-th d-wide field as a static lane slice, stacked on
+    a new major axis — the (de)interleave that used to be an HLO
+    transpose outside the kernel. (A minor-axis reshape to
+    (L_pad, bbh, parts*d) is refused by Mosaic: "unsupported shape
+    cast".)"""
+    return jnp.stack([ref[:, (parts * h + part) * d:
+                          (parts * h + part + 1) * d]
+                      for h in range(bbh)], axis=0)
+
+
+def _split_qkv_block(qkv_ref, bbh, d):
+    """bf16 (bbh, L_pad, d) q, k, v of an interleaved [q|k|v] block."""
+    return tuple(_split_heads(qkv_ref, bbh, d, 3, i) for i in range(3))
+
+
+def _merge_heads(x):
+    """(bbh, L_pad, w) -> (L_pad, bbh*w): heads back onto the lanes."""
+    return jnp.concatenate([x[h] for h in range(x.shape[0])], axis=1)
 
 
 @functools.lru_cache(maxsize=None)
@@ -265,19 +299,16 @@ def _fwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
     def pallas_selfatt_packed_fwd(seed_ref, qkv_ref, o_ref):
         n = pl.program_id(0)
         j = pl.program_id(1)
-        q, k, v = _split_qkv_block(qkv_ref[:], bbh, d)
+        q, k, v = _split_qkv_block(qkv_ref, bbh, d)
         q = q.astype(jnp.float32) * scale
         k = k.astype(jnp.float32)
         _, pd, _ = _attn_fwd_math(pltpu, q, k,
                                   seed_ref[n * n_hblk + j],
                                   L, L_pad, p_drop, keep, thresh,
                                   interpret)
-        o = lax.dot_general(pd.astype(jnp.bfloat16), v,
-                            (((2,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-        # back to the packed (L_pad, 1, bbh*d) output layout
-        o_ref[:] = o.transpose(1, 0, 2).reshape(L_pad, 1, bbh * d) \
-            .astype(o_ref.dtype)
+        o = _bdot(pd.astype(jnp.bfloat16), v, ((2,), (1,)))
+        # back to the packed (L_pad, bbh*d) output layout
+        o_ref[:] = _merge_heads(o).astype(o_ref.dtype)
 
     return pl.pallas_call(
         pallas_selfatt_packed_fwd,
@@ -285,13 +316,13 @@ def _fwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
             num_scalar_prefetch=1,
             grid=(N, n_hblk),
             in_specs=[
-                pl.BlockSpec((L_pad, 1, bbh * 3 * d),
-                             lambda n, j, seeds: (0, n, j)),
+                pl.BlockSpec((L_pad, bbh * 3 * d),
+                             lambda n, j, seeds: (0, n * n_hblk + j)),
             ],
-            out_specs=pl.BlockSpec((L_pad, 1, bbh * d),
-                                   lambda n, j, seeds: (0, n, j)),
+            out_specs=pl.BlockSpec((L_pad, bbh * d),
+                                   lambda n, j, seeds: (0, n * n_hblk + j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((L_pad, N, heads_pad * d),
+        out_shape=jax.ShapeDtypeStruct((L_pad, N * heads_pad * d),
                                        jnp.bfloat16),
         interpret=interpret,
         name="pallas_selfatt_packed_fwd",
@@ -311,21 +342,17 @@ def _bwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
     def pallas_selfatt_packed_bwd(seed_ref, qkv_ref, do_ref, dqkv_ref):
         n = pl.program_id(0)
         j = pl.program_id(1)
-        q, k, v = _split_qkv_block(qkv_ref[:], bbh, d)
+        q, k, v = _split_qkv_block(qkv_ref, bbh, d)
         q = q.astype(jnp.float32) * scale
         k = k.astype(jnp.float32)
-        do = do_ref[:].reshape(L_pad, bbh, d).transpose(1, 0, 2) \
-            .astype(jnp.float32)
+        do = _split_heads(do_ref, bbh, d).astype(jnp.float32)
         p, pd, keep_mask = _attn_fwd_math(
             pltpu, q, k, seed_ref[n * n_hblk + j], L, L_pad, p_drop,
             keep, thresh, interpret)
         # dV (bbh,L,d) = Pdᵀ·dO : contract over query positions
-        dv = lax.dot_general(pd, do, (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
+        dv = _bdot(pd, do, ((1,), (1,)))
         # dPd (bbh,L,L) = dO·Vᵀ
-        dpd = lax.dot_general(do, v.astype(jnp.float32),
-                              (((2,), (2,)), ((0,), (0,))),
-                              preferred_element_type=jnp.float32)
+        dpd = _bdot(do, v.astype(jnp.float32), ((2,), (2,)))
         if p_drop > 0.0:
             dp = jnp.where(keep_mask, dpd / keep, 0.0)
         else:
@@ -333,16 +360,11 @@ def _bwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
         ds = p * (dp - jnp.sum(dp * p, axis=2, keepdims=True))
         dsb = ds.astype(jnp.bfloat16)
         # dq (bbh,L,d) = dS·K ; dk (bbh,L,d) = dSᵀ·(Q·scale)
-        dq = lax.dot_general(dsb, k.astype(jnp.bfloat16),
-                             (((2,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32) * scale
-        dk = lax.dot_general(dsb, q.astype(jnp.bfloat16),
-                             (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
+        dq = _bdot(dsb, k.astype(jnp.bfloat16), ((2,), (1,))) * scale
+        dk = _bdot(dsb, q.astype(jnp.bfloat16), ((1,), (1,)))
         # re-pack [dq|dk|dv] into the interleaved minor axis
         out = jnp.concatenate([dq, dk, dv], axis=2)   # (bbh, L, 3d)
-        dqkv_ref[:] = out.transpose(1, 0, 2) \
-            .reshape(L_pad, 1, bbh * 3 * d).astype(dqkv_ref.dtype)
+        dqkv_ref[:] = _merge_heads(out).astype(dqkv_ref.dtype)
 
     return pl.pallas_call(
         pallas_selfatt_packed_bwd,
@@ -350,15 +372,15 @@ def _bwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
             num_scalar_prefetch=1,
             grid=(N, n_hblk),
             in_specs=[
-                pl.BlockSpec((L_pad, 1, bbh * 3 * d),
-                             lambda n, j, seeds: (0, n, j)),
-                pl.BlockSpec((L_pad, 1, bbh * d),
-                             lambda n, j, seeds: (0, n, j)),
+                pl.BlockSpec((L_pad, bbh * 3 * d),
+                             lambda n, j, seeds: (0, n * n_hblk + j)),
+                pl.BlockSpec((L_pad, bbh * d),
+                             lambda n, j, seeds: (0, n * n_hblk + j)),
             ],
-            out_specs=pl.BlockSpec((L_pad, 1, bbh * 3 * d),
-                                   lambda n, j, seeds: (0, n, j)),
+            out_specs=pl.BlockSpec((L_pad, bbh * 3 * d),
+                                   lambda n, j, seeds: (0, n * n_hblk + j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((L_pad, N, heads_pad * 3 * d),
+        out_shape=jax.ShapeDtypeStruct((L_pad, N * heads_pad * 3 * d),
                                        jnp.bfloat16),
         interpret=interpret,
         name="pallas_selfatt_packed_bwd",
@@ -389,7 +411,8 @@ def _make_op(heads, p_drop, bbh):
                         heads_pad, d)
         call = _fwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop,
                          _interpret())
-        o = call(seeds, x)                    # (L_pad, N, heads_pad*d)
+        o = call(seeds, x.reshape(L_pad, N * heads_pad * 3 * d))
+        o = o.reshape(L_pad, N, heads_pad * d)
         return o[:L, :, :heads * d].astype(qkv.dtype)
 
     def fwd(qkv, seeds):
@@ -412,7 +435,9 @@ def _make_op(heads, p_drop, bbh):
             do = jnp.pad(do, ((0, L_pad - L), (0, 0), (0, 0)))
         call = _bwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop,
                          _interpret())
-        dqkv = call(seeds, x, do)     # (L_pad, N, heads_pad*3*d)
+        dqkv = call(seeds, x.reshape(L_pad, N * heads_pad * 3 * d),
+                    do.reshape(L_pad, N * heads_pad * d))
+        dqkv = dqkv.reshape(L_pad, N, heads_pad * 3 * d)
         return (dqkv[:L, :, :heads * 3 * d].astype(qkv.dtype),
                 jnp.zeros(seeds.shape, jax.dtypes.float0))
 
@@ -435,8 +460,7 @@ def flash_selfatt(qkv, seeds, *, heads, dropout=0.0, block_heads=None):
     L, N, thd = qkv.shape
     if block_heads is None:
         d = thd // (3 * heads)
-        plan = selfatt_plan(L, heads, N, float(dropout),
-                            dtype=None)
+        plan = selfatt_plan(L, heads, N, float(dropout), head_dim=d)
         if plan is None:
             raise ValueError(
                 "flash_selfatt: shape (L=%d, heads=%d, batch=%d) is "

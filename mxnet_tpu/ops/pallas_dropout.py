@@ -2,9 +2,9 @@
 src/operator/nn/dropout.cc, whose CUDA path likewise fuses curand mask
 generation into the scale kernel).
 
-Why this exists (round-6 perf work, PERF_r05.md §1): the BERT-base step
-spends 0.36 ms in standalone `rng-bit-generator` programs producing
-dropout masks, plus the HBM round-trip of the masks themselves. Here
+Why this exists (round-6 perf work; round-5 builder figures): the
+BERT-base step spends 0.36 ms in standalone `rng-bit-generator` programs
+producing dropout masks, plus the HBM round-trip of the masks themselves. Here
 the TPU hardware PRNG (pltpu.prng_seed / prng_random_bits — the same
 mechanism ops/pallas_attention.py uses for in-kernel attention dropout)
 generates the keep-mask INSIDE the multiply kernel: forward reads x and
@@ -48,7 +48,8 @@ def _pick_rows(M, C, esize):
 def pallas_dropout_available(shape, dtype, p):
     """True when the in-kernel-PRNG dropout can serve this call."""
     from ..config import get as _cfg
-    if not _cfg("MXNET_PALLAS_DROPOUT"):
+    from .pallas_common import kernels_allowed
+    if not _cfg("MXNET_PALLAS_DROPOUT") or not kernels_allowed():
         return False
     if _interpret():
         return False          # pltpu PRNG has no interpreter impl

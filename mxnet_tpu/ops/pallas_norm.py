@@ -2,9 +2,9 @@
 src/operator/nn/layer_norm.cc :: LayerNormCompute / LayerNormGradCompute,
 whose hand-written CUDA kernels exist for exactly this reason).
 
-Why this exists (round-6 perf work, PERF_r05.md §1): the BERT-base step
-spends 5.27 ms/step in `convert_reduce_fusion` — dominated by XLA's
-LayerNorm backward, which splits into a reduction island (dgamma/dbeta +
+Why this exists (round-6 perf work; round-5 builder figures): the
+BERT-base step spends 5.27 ms/step in `convert_reduce_fusion` —
+dominated by XLA's LayerNorm backward, which splits into a reduction island (dgamma/dbeta +
 row moments) and an elementwise island, re-reading the activations and
 the upstream gradient from HBM for each. LN is pure VPU/bandwidth work,
 so the only fix is fewer HBM sweeps:
@@ -64,7 +64,8 @@ def pallas_ln_available(shape, dtype, axis):
     """True when the Pallas LN kernels can serve this call (the caller
     falls back to the XLA _ln_fused path otherwise)."""
     from ..config import get as _cfg
-    if not _cfg("MXNET_PALLAS_LAYERNORM"):
+    from .pallas_common import kernels_allowed
+    if not _cfg("MXNET_PALLAS_LAYERNORM") or not kernels_allowed():
         return False
     if len(shape) < 2 or axis != len(shape) - 1:
         return False

@@ -13,12 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-try:                                      # jax >= 0.6 (top-level export)
-    from jax import shard_map
-except ImportError:                       # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 
 _LOSSY_SYNC_WARNED = False   # once-per-process EF-less quantize warning
 

@@ -254,12 +254,8 @@ def _flat_transition(n: int, shard_len: int, dtype, devices):
         total = lax.psum(jnp.asarray(1, jnp.int32), "dp")
         return x, total
 
-    try:
-        mapped = shard_map(body, mesh=mesh, in_specs=P("dp"),
-                           out_specs=(P("dp"), P()), check_rep=False)
-    except TypeError:                       # newer jax: no check_rep
-        mapped = shard_map(body, mesh=mesh, in_specs=P("dp"),
-                           out_specs=(P("dp"), P()))
+    mapped = shard_map(body, mesh=mesh, in_specs=P("dp"),
+                       out_specs=(P("dp"), P()), check_vma=False)
     prog = compilewatch.watched_jit(
         mapped, "reshard.transition", site="reshard",
         arg_names=("stack",), instance="n=%d len=%d" % (n, shard_len),
@@ -502,12 +498,8 @@ def _general_transition(dst_sharding, shape, dtype):
         return x, total
 
     spec = dst_sharding.spec
-    try:
-        mapped = shard_map(body, mesh=mesh, in_specs=spec,
-                           out_specs=(spec, P()), check_rep=False)
-    except TypeError:
-        mapped = shard_map(body, mesh=mesh, in_specs=spec,
-                           out_specs=(spec, P()))
+    mapped = shard_map(body, mesh=mesh, in_specs=spec,
+                       out_specs=(spec, P()), check_vma=False)
     prog = compilewatch.watched_jit(
         mapped, "reshard.transition_nd", site="reshard",
         arg_names=("array",),

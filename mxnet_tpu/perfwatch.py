@@ -22,8 +22,7 @@ content fingerprint: re-ingesting the same file is a no-op.
 **Detection.** Noise-aware three-way verdicts per series
 (:func:`judge_series`): the baseline is the rolling median of the
 preceding window and the deviation score is MAD-scaled (median
-absolute deviation x 1.4826 — robust to the wall-clock spikes
-PERF_r05 §2 documents), with a relative-tolerance floor so a flat
+absolute deviation x 1.4826 — robust to wall-clock spikes), with a relative-tolerance floor so a flat
 trajectory with near-zero MAD does not alarm on noise. A regression
 must clear BOTH the MAD score (``MXNET_PERFWATCH_MAD_K``) and the
 relative tolerance (``MXNET_PERFWATCH_TOL``, per-metric overrides in

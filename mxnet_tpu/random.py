@@ -23,8 +23,8 @@ __all__ = ["seed", "take_key", "uniform", "normal", "randint", "randn",
 
 _lock = threading.Lock()
 _seed = 0
-# Default key impl: 'rbg' maps to the TPU hardware PRNG (fast path; see
-# PERF_r03.md). Scoped to keys THIS library creates — the process-global
+# Default key impl: 'rbg' maps to the TPU hardware PRNG (fast path).
+# Scoped to keys THIS library creates — the process-global
 # jax_default_prng_impl is deliberately left untouched so importing
 # mxnet_tpu does not change unrelated JAX code's random streams.
 from .config import get as _cfg

@@ -9,7 +9,7 @@ round-trip — the last structural overhead past the fused step (ROADMAP
 item 5, arxiv 1810.09868's full-program argument) — collapses to one
 dispatch per chunk, and XLA sees a K-step window it can software-
 pipeline (prefetching the next step's weights into VMEM while the
-current one computes — the copy-done residual PERF_r06 measures).
+current one computes — the copy-done residual of the BERT step).
 
 Correctness contract (the reason this layer can exist at all): while a
 chunk is buffering, no parameter changes — every buffered plan captured

@@ -637,12 +637,10 @@ def replica_main(spec: dict):
     fleet, factory ("module:callable"), env overrides, and whatever
     the factory consumes (ckpt_prefix, tenants, sizes...)."""
     config.apply_overrides(spec.get("env"))
-    try:
-        import jax
-        jax.config.update("jax_platforms",
-                          spec.get("platform") or "cpu")
-    except Exception:
-        pass
+    # the platform comes from the spec, explicitly: a replica that
+    # defaulted to the CPU would serve from it without saying so
+    import jax
+    jax.config.update("jax_platforms", spec["platform"])
     telemetry.refresh()
     sched = _resolve_factory(spec.get("factory"))(spec)
     kv = dist.fleet_kv(spec.get("kv_addr") or None)
@@ -677,6 +675,11 @@ class ReplicaManager:
         base = dict(spec or {})
         base.setdefault("factory",
                         factory or "mxnet_tpu.serve.fleet:demo_factory")
+        if not base.get("platform"):
+            raise MXNetError(
+                "ReplicaManager: spec['platform'] must name the JAX "
+                "platform the replicas run on (e.g. 'cpu'); one chip "
+                "serves one process, so 'tpu' fits one replica per chip")
         base["fleet"] = fleet
         base["kv_addr"] = kv_addr
         if heartbeat_s is not None:

@@ -89,8 +89,8 @@ _CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
 _COLLECTIVE_PRIMS = {"psum", "pmax", "pmin", "ppermute", "pbroadcast",
                      "all_gather", "all_to_all", "reduce_scatter",
                      "psum_scatter", "allreduce",
-                     # the shard_map-era *2 spellings (jax >= 0.4.3x)
-                     "psum2", "pmax2", "pmin2", "pbroadcast2"}
+                     # what a check_vma shard_map binds them as
+                     "psum_invariant", "all_gather_invariant"}
 # labels of programs that perform the weight update (donation check)
 _UPDATE_LABELS = ("autograd.fused_step", "zero.step", "zero.reduce")
 # labels of serving forward programs (ISSUE 12): their request inputs
@@ -158,7 +158,7 @@ def _sub_jaxprs(params: Dict[str, Any]):
 
 
 def _as_jaxprs(v):
-    import jax.core as jcore
+    import jax.extend.core as jcore
     if isinstance(v, jcore.ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, jcore.Jaxpr):
@@ -197,11 +197,8 @@ _SUPP_CACHE_CAP = 256
 
 
 def _eqn_frame(eqn):
-    try:
-        from jax._src import source_info_util as siu
-        return siu.user_frame(eqn.source_info)
-    except Exception:
-        return None
+    from jax._src import source_info_util as siu
+    return siu.user_frame(eqn.source_info.traceback)
 
 
 def suppressed_at_eqn(rule_id: str, eqn) -> bool:

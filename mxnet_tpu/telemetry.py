@@ -59,7 +59,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "span", "phase", "counter",
            "fault_event", "checkpoint_event", "reset",
            "memory_snapshot", "memory_diff", "ndarray_live",
            "parse_metric_key",
-           "debit_stall", "peak_flops", "local_fleet_stats",
+           "debit_stall", "peak_flops", "known_peak_flops",
+           "local_fleet_stats",
            "fleet_snapshot", "FLEET_FIELDS", "crash_bundle",
            "install_crash_bundler"]
 
@@ -386,39 +387,43 @@ _STEP_LOCK = threading.Lock()
 _STEP = {"count": 0, "last": None, "t0": None, "useful_s": 0.0,
          "stall_s": 0.0, "flops0": 0.0, "compile_at_last": 0.0}
 
-# per-chip bf16 peak FLOP/s by device kind (MXNET_PEAK_FLOPS overrides;
-# unknown kinds — e.g. the CPU dryrun mesh — fall back to the v5e
-# flagship so mx_mfu stays populated and cross-round comparable)
+# per-chip bf16 peak FLOP/s by device kind (MXNET_PEAK_FLOPS overrides).
+# A kind that is not in the table — the CPU mesh — has no peak: the
+# mx_mfu gauge stays unpopulated and peak_flops() raises, so a CPU run
+# never reports a v5e utilization.
 _PEAK_BY_KIND = (("v6", 918e12), ("trillium", 918e12), ("v5p", 459e12),
                  ("v5", 197e12), ("v4", 275e12), ("v3", 123e12),
                  ("v2", 45e12))
-_PEAK_FALLBACK = 197e12
-_PEAK = [None]          # cached (refresh() drops it)
+_PEAK = [None]          # cached (refresh() drops it); 0.0 = unknown
+
+
+def known_peak_flops() -> Optional[float]:
+    """MXNET_PEAK_FLOPS when set, else the table's entry for the
+    attached device kind, else None."""
+    v = _PEAK[0]
+    if v is None:
+        from .config import get as _cfg
+        v = float(_cfg("MXNET_PEAK_FLOPS"))
+        if v <= 0:
+            import jax
+            kind = jax.devices()[0].device_kind.lower()
+            v = next((flops for marker, flops in _PEAK_BY_KIND
+                      if marker in kind), 0.0)
+        _PEAK[0] = v
+    return v or None
 
 
 def peak_flops() -> float:
-    """Per-chip peak FLOP/s the MFU gauge divides by: MXNET_PEAK_FLOPS
-    when set, else auto-detected from the device kind."""
-    v = _PEAK[0]
-    if v is not None:
-        return v
-    try:
-        from .config import get as _cfg
-        v = float(_cfg("MXNET_PEAK_FLOPS"))
-    except Exception:
-        v = 0.0
-    if v <= 0:
-        v = _PEAK_FALLBACK
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind.lower()
-            for marker, flops in _PEAK_BY_KIND:
-                if marker in kind:
-                    v = flops
-                    break
-        except Exception:
-            pass
-    _PEAK[0] = v
+    """Per-chip peak FLOP/s an MFU divides by. Raises for a device
+    kind with no known peak: set MXNET_PEAK_FLOPS to state one."""
+    v = known_peak_flops()
+    if v is None:
+        import jax
+        from .base import MXNetError
+        raise MXNetError(
+            "no peak FLOP/s known for device kind %r: MFU is undefined "
+            "here; set MXNET_PEAK_FLOPS to state a peak"
+            % jax.devices()[0].device_kind)
     return v
 
 
@@ -514,8 +519,9 @@ def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
             h.observe((now - last) / n)
         if wall > 0:
             gauge("mx_goodput").set(min(1.0, useful_s / wall))
-            mfu = (flops_now - flops0) / wall / peak_flops()
-            gauge("mx_mfu").set(mfu)
+            peak = known_peak_flops()
+            if peak is not None:
+                gauge("mx_mfu").set((flops_now - flops0) / wall / peak)
     _maybe_fleet_tick(count, prev_count)
 
 

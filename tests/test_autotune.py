@@ -293,5 +293,5 @@ def test_attention_plan_consult_stable(monkeypatch):
     assert p1 == p2 and p1 is not None
     key = autotune.entry_key(
         "pallas_selfatt_packed",
-        {"L": 16, "heads": 4, "batch": 4, "esize": 2})
+        {"L": 16, "heads": 4, "batch": 4, "esize": 2, "hd": 64})
     assert key in autotune.table()

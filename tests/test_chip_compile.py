@@ -1,0 +1,158 @@
+"""Chipless compiles: every Pallas kernel of the BERT path, at BERT-base
+widths, through the TPU compiler for a DESCRIBED v5e chip (no chip is
+attached here; nothing runs). Interpret mode — what every other kernel
+test uses — cannot see what Mosaic refuses: block shapes off the (8, 128)
+tiling, unsupported shape casts, primitives with no TPU lowering.
+
+This is the one file that loads the TPU library: the topology is
+described inside a module-scoped fixture (never at import), and every
+compile happens in the test's own process. A compile that passes is not
+a chip run; ``chip_smoke.py`` is.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# BERT-base: seq 128, batch 32, 12 heads x 64, hidden 768, FFN 3072
+L, N, H, D, C = 128, 32, 12, 64, 768
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    """Kernels built for the chip, not the interpreter — steered from
+    the test: the code under test asks ``interpret_mode()`` and, with
+    only CPU devices attached, would answer True."""
+    from mxnet_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+
+
+def _custom_calls(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text() \
+        .count("tpu_custom_call")
+
+
+def _sum32(x):
+    return jnp.sum(x.astype(jnp.float32))
+
+
+BF = jnp.bfloat16
+
+
+def test_layer_norm(one_chip, compiled_mode):
+    from mxnet_tpu.ops.pallas_norm import (pallas_layer_norm,
+                                           pallas_ln_available)
+    assert pallas_ln_available((L, N, C), BF, 2)
+    shapes = [((L, N, C), BF), ((C,), BF), ((C,), BF)]
+    assert _custom_calls(one_chip, pallas_layer_norm, *shapes) == 1
+    grad = jax.grad(lambda x, g, b: _sum32(pallas_layer_norm(x, g, b)),
+                    argnums=(0, 1, 2))
+    assert _custom_calls(one_chip, grad, *shapes) >= 1
+
+
+@pytest.mark.parametrize("p", [0.0, 0.1])
+def test_flash_attention(one_chip, compiled_mode, p):
+    from mxnet_tpu.ops.pallas_attention import flash_selfatt, selfatt_plan
+    plan = selfatt_plan(L, H, N, p, dtype=BF, head_dim=D)
+    assert plan is not None
+    shapes = [((L, N, 3 * H * D), BF), ((plan["n_blocks"],), jnp.int32)]
+
+    def fwd(qkv, seeds):
+        return flash_selfatt(qkv, seeds, heads=H, dropout=p,
+                             block_heads=plan["bbh"])
+
+    assert _custom_calls(one_chip, fwd, *shapes) == 1
+    grad = jax.grad(lambda qkv, seeds: _sum32(fwd(qkv, seeds)))
+    assert _custom_calls(one_chip, grad, *shapes) >= 1
+
+
+def test_bias_gelu(one_chip, compiled_mode):
+    from mxnet_tpu.ops.pallas_epilogue import (bias_gelu_available,
+                                               pallas_bias_gelu)
+    assert bias_gelu_available((L, N, 4 * C), BF, BF)
+    shapes = [((L, N, 4 * C), BF), ((4 * C,), BF)]
+    assert _custom_calls(one_chip, pallas_bias_gelu, *shapes) == 1
+    grad = jax.grad(lambda x, b: _sum32(pallas_bias_gelu(x, b)),
+                    argnums=(0, 1))
+    assert _custom_calls(one_chip, grad, *shapes) >= 1
+
+
+def test_bias_residual(one_chip, compiled_mode):
+    from mxnet_tpu.ops.pallas_epilogue import (bias_residual_available,
+                                               pallas_bias_residual)
+    assert bias_residual_available((L, N, C), BF, BF, BF)
+    shapes = [((L, N, C), BF), ((C,), BF), ((L, N, C), BF)]
+    assert _custom_calls(one_chip, pallas_bias_residual, *shapes) == 1
+
+
+def test_dropout(one_chip, compiled_mode):
+    from mxnet_tpu.ops.pallas_dropout import (pallas_dropout,
+                                              pallas_dropout_available)
+    assert pallas_dropout_available((L, N, C), BF, 0.1)
+
+    def fwd(x):
+        return pallas_dropout(jax.random.key(0), x, 0.1)
+
+    shapes = [((L, N, C), BF)]
+    assert _custom_calls(one_chip, fwd, *shapes) == 1
+    # cotangent made to depend on x: the backward reads only the seeds,
+    # and a program with no used chip-resident input lowers for the CPU
+    assert _custom_calls(one_chip, jax.grad(lambda x: _sum32(fwd(x) * x)),
+                         *shapes) >= 1
+
+
+def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
+        one_chip, compiled_mode):
+    """Why ShardedTrainStep traces inside auto_partitioned(mesh): a
+    kernel in a program GSPMD partitions over four chips is refused;
+    inside the scope the ops answer "not available" instead."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.pallas_attention import selfatt_plan
+    from mxnet_tpu.ops.pallas_common import auto_partitioned, kernels_allowed
+    from mxnet_tpu.ops.pallas_norm import (pallas_layer_norm,
+                                           pallas_ln_available)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    rows = NamedSharding(mesh, P(None, "dp"))
+    rep = NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct((L, N, C), BF, sharding=rows),
+            jax.ShapeDtypeStruct((C,), BF, sharding=rep),
+            jax.ShapeDtypeStruct((C,), BF, sharding=rep)]
+    with pytest.raises(NotImplementedError, match="automatically part"):
+        jax.jit(pallas_layer_norm).lower(*args).compile()
+    with auto_partitioned(mesh):
+        assert not kernels_allowed()
+        assert not pallas_ln_available((L, N, C), BF, 2)
+        assert selfatt_plan(L, H, N, 0.0, dtype=BF, head_dim=D) is None
+    assert kernels_allowed()
+    one = Mesh(np.array(topo.devices[:1]), ("dp",))
+    with auto_partitioned(one):
+        assert pallas_ln_available((L, N, C), BF, 2)

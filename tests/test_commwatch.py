@@ -288,7 +288,7 @@ def test_kvstore_grouped_reduce_records_comm():
         'mx_comm_exposed_seconds_total{axis="kv",op="allreduce"}'] > 0
 
 
-def test_sharded_step_comm_bandwidth_on_dryrun_mesh():
+def test_sharded_step_comm_bandwidth_on_dryrun_mesh(monkeypatch):
     """Single-process bandwidth accounting on the 8-device mesh: the
     GSPMD collectives of a dp x tp sharded step show nonzero bytes AND
     bandwidth, labeled with their mesh axes (ISSUE 6 acceptance)."""
@@ -297,6 +297,9 @@ def test_sharded_step_comm_bandwidth_on_dryrun_mesh():
     from mxnet_tpu.gluon import nn
     from mxnet_tpu.parallel import (MeshConfig, P, ShardedTrainStep,
                                     make_mesh)
+    # the CPU mesh has no peak of its own: state one, or no mx_mfu
+    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e12")
+    telemetry.refresh()
     net = nn.HybridSequential()
     # explicit prefix: the tp param_rule must match regardless of how
     # many Dense blocks earlier tests burned off the global name counter

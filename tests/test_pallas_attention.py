@@ -4,8 +4,6 @@ tools/bert_bench.py)."""
 import numpy as np
 import pytest
 
-from conftest import relay_mosaic_guard
-
 import jax
 import jax.numpy as jnp
 
@@ -24,24 +22,23 @@ def _ref(qkv, heads):
 
 @pytest.mark.parametrize("L,N,H,d", [(16, 4, 4, 8), (32, 2, 8, 16)])
 def test_flash_selfatt_matches_unfused(L, N, H, d):
-    with relay_mosaic_guard():
-        rng = np.random.RandomState(0)
-        qkv = jnp.asarray(rng.randn(L, N, H * 3 * d).astype(np.float32))
-        assert flash_selfatt_available(L, H, N)
-        plan = selfatt_plan(L, H, N, 0.0)
-        seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
-        o1 = flash_selfatt(qkv, seeds, heads=H,
-                           block_heads=plan["bbh"])
-        o2 = _ref(qkv, H)
-        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
-                                   rtol=2e-2, atol=2e-2)
-        r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-        g1 = jax.grad(lambda q: jnp.sum(
-            flash_selfatt(q, seeds, heads=H,
-                          block_heads=plan["bbh"]) * r))(qkv)
-        g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
-        denom = float(jnp.max(jnp.abs(g2))) + 1e-9
-        assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
+    rng = np.random.RandomState(0)
+    qkv = jnp.asarray(rng.randn(L, N, H * 3 * d).astype(np.float32))
+    assert flash_selfatt_available(L, H, N)
+    plan = selfatt_plan(L, H, N, 0.0)
+    seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
+    o1 = flash_selfatt(qkv, seeds, heads=H,
+                       block_heads=plan["bbh"])
+    o2 = _ref(qkv, H)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
+                               rtol=2e-2, atol=2e-2)
+    r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
+    g1 = jax.grad(lambda q: jnp.sum(
+        flash_selfatt(q, seeds, heads=H,
+                      block_heads=plan["bbh"]) * r))(qkv)
+    g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
+    denom = float(jnp.max(jnp.abs(g2))) + 1e-9
+    assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
 
 
 def test_sdp_selfatt_op_fallback_and_eval_mode():

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from mxnet_tpu.ops.pallas_attention import (_keep_mask, flash_selfatt,
@@ -233,7 +234,7 @@ def _walk_transposes(jaxpr, out):
             sub = getattr(v, "jaxpr", None)
             if sub is not None:
                 _walk_transposes(sub, out)
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jax.extend.core.Jaxpr):
                 _walk_transposes(v, out)
     return out
 

@@ -215,3 +215,19 @@ def test_bert_ffn_and_cell_parity(monkeypatch):
     off = cell(x).asnumpy()
     monkeypatch.delenv("MXNET_PALLAS_EPILOGUE")
     np.testing.assert_allclose(on, off, rtol=1e-4, atol=1e-4)
+
+
+def test_erf_matches_xla():
+    """The kernels evaluate erf from mul/add/div (Mosaic has no erf
+    lowering): within 1e-6 absolute of lax.erf over the f32 range,
+    saturation and the dense centre included."""
+    from jax import lax
+    from mxnet_tpu.ops.pallas_epilogue import _erf
+    x = jnp.asarray(np.concatenate([
+        np.linspace(-8.0, 8.0, 400001),
+        np.random.RandomState(0).randn(100000) * 3.0,
+        [0.0, -0.0, 1e-20, 4.0, -4.0, 1e30, -1e30]]).astype(np.float32))
+    got = np.asarray(jax.jit(_erf)(x))
+    np.testing.assert_allclose(got, np.asarray(lax.erf(x)), rtol=0,
+                               atol=1e-6)
+    assert np.all(np.abs(got) <= 1.0 + 1e-6)

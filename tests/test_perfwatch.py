@@ -14,9 +14,47 @@ pytestmark = pytest.mark.obs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the checked-in BENCH history series (r01..r05 headline values) —
-# the real trajectory every statistics test below is calibrated on
-BENCH_FILES = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
+# the driver's five round-1..5 headline rows (ResNet-50 img/s/chip on a
+# v5e; the BENCH_r0N.json record files themselves were deleted in PR 21
+# — round-5 figures, not re-measured). The statistics tests below are
+# calibrated on this trajectory; the wrappers are rebuilt per session.
+_HISTORY = [
+    {"value": 2337.52, "vs_baseline": 0.7792},
+    {"value": 2752.49, "vs_baseline": 0.9175},
+    {"value": 2846.83, "vs_baseline": 0.9489},
+    {"value": 2780.09, "vs_baseline": 0.9267,
+     "path": "gluon_hybridize_trainer",
+     "sharded_train_step_img_s": 2819.84},
+    {"value": 2789.14, "vs_baseline": 0.9297,
+     "path": "gluon_hybridize_trainer", "method": "xplane_device_time",
+     "sharded_train_step_img_s": 2819.96},
+]
+_WARN_TAIL = ("/root/repo/bench.py:159: DeprecationWarning: Conversion of "
+              "an array with ndim > 0 to a scalar is deprecated\n"
+              "  float(jax.device_get(loss.sum()._jax()))\n")
+
+
+@pytest.fixture(scope="module")
+def bench_files(tmp_path_factory):
+    """BENCH_r01..r05.json driver wrappers ({"n","cmd","rc","tail",
+    "parsed"}) written into a temp dir; returns their sorted paths."""
+    d = tmp_path_factory.mktemp("bench_history")
+    paths = []
+    for n, extra in enumerate(_HISTORY, 1):
+        parsed = {"metric": "resnet50_v1_train_throughput",
+                  "unit": "images/sec/chip"}
+        parsed.update(extra)
+        path = str(d / ("BENCH_r%02d.json" % n))
+        with open(path, "w") as f:
+            json.dump({"n": n, "cmd": "python bench.py", "rc": 0,
+                       "tail": _WARN_TAIL + json.dumps(parsed) + "\n",
+                       "parsed": parsed}, f)
+        paths.append(path)
+    return paths
+
+
+def _bench_glob(bench_files):
+    return os.path.join(os.path.dirname(bench_files[0]), "BENCH_r*.json")
 
 
 @pytest.fixture(autouse=True)
@@ -94,19 +132,19 @@ def test_fingerprint_partitioning_two_device_kinds(tmp_path):
     assert by_kind["tpu_v4"]["verdict"] == "flat"
 
 
-def test_ingest_file_wrapper_and_glob_idempotent(tmp_path):
+def test_ingest_file_wrapper_and_glob_idempotent(tmp_path, bench_files):
     """BENCH_r*.json driver wrappers ingest via their parsed record,
     stamped with the round from the wrapper's n."""
     db = perfwatch.PerfDB(str(tmp_path / "db"))
-    out = db.ingest_glob(os.path.join(REPO, "BENCH_r*.json"))
-    assert len(out) == len(BENCH_FILES) >= 5
+    out = db.ingest_glob(_bench_glob(bench_files))
+    assert len(out) == len(bench_files) >= 5
     assert all(len(fps) == 1 for fps in out.values())
-    again = db.ingest_glob(os.path.join(REPO, "BENCH_r*.json"))
+    again = db.ingest_glob(_bench_glob(bench_files))
     assert all(fps == [] for fps in again.values())    # idempotent
     kind = db.device_kinds()[0]
     rows = db.records(kind, "resnet50_v1_train_throughput")
     assert [r["round"] for r in rows] == list(
-        range(1, len(BENCH_FILES) + 1))
+        range(1, len(bench_files) + 1))
 
 
 # ---------------------------------------------------------------------------
@@ -193,11 +231,10 @@ def test_metric_direction_rules():
 # CLI: report renders the checked-in history, --gate flips on a
 # synthetic 10% regression naming the metric
 # ---------------------------------------------------------------------------
-def test_perfwatch_gate_green_on_checked_in_history(capsys):
-    """Tier-1 smoke: the checked-in BENCH_r01..r05 history must gate
-    green (this is the PERF_r06 on-chip gate-list entry)."""
+def test_perfwatch_gate_green_on_checked_in_history(capsys, bench_files):
+    """Tier-1 smoke: the round-1..5 history must gate green."""
     import tools.perfwatch as pw
-    assert pw.main(["report", "--gate"]) == 0
+    assert pw.main(["report", "--gate", _bench_glob(bench_files)]) == 0
     out = capsys.readouterr().out
     assert "resnet50_v1_train_throughput" in out
     assert "PERFWATCH_GATE_OK" in out
@@ -205,20 +242,21 @@ def test_perfwatch_gate_green_on_checked_in_history(capsys):
     assert "improvement@r02" in out
 
 
-def test_perfwatch_gate_trips_on_injected_regression(tmp_path, capsys):
+def test_perfwatch_gate_trips_on_injected_regression(tmp_path, capsys,
+                                                     bench_files):
     import tools.perfwatch as pw
-    for p in BENCH_FILES:
+    for p in bench_files:
         with open(p) as f:
             w = json.load(f)
         with open(tmp_path / os.path.basename(p), "w") as f:
             json.dump(w, f)
-    with open(BENCH_FILES[-1]) as f:
+    with open(bench_files[-1]) as f:
         w = json.load(f)
     parsed = dict(w["parsed"])
     parsed["value"] = round(parsed["value"] * 0.9, 2)     # -10%
     parsed.pop("sharded_train_step_img_s", None)
     with open(tmp_path / "BENCH_r99.json", "w") as f:
-        json.dump({"n": len(BENCH_FILES) + 1, "cmd": w["cmd"],
+        json.dump({"n": len(bench_files) + 1, "cmd": w["cmd"],
                    "rc": 0, "tail": "", "parsed": parsed}, f)
     rc = pw.main(["report", "--gate",
                   str(tmp_path / "BENCH_r*.json")])
@@ -233,11 +271,11 @@ def test_perfwatch_gate_trips_on_injected_regression(tmp_path, capsys):
     assert "perf=" in telemetry.heartbeat_line()
 
 
-def test_perfwatch_ingest_and_report_persistent_store(tmp_path,
-                                                      capsys):
+def test_perfwatch_ingest_and_report_persistent_store(tmp_path, capsys,
+                                                      bench_files):
     import tools.perfwatch as pw
     db_dir = str(tmp_path / "db")
-    rc = pw.main(["ingest", os.path.join(REPO, "BENCH_r*.json"),
+    rc = pw.main(["ingest", _bench_glob(bench_files),
                   "--db", db_dir])
     assert rc == 0
     assert pw.main(["report", "--gate", "--db", db_dir]) == 0
@@ -309,15 +347,14 @@ def test_bench_json_schema_accepts_and_rejects():
         bench_json.emit({"metric": "x"})
 
 
-def test_checked_in_history_validates_and_parses_clean():
-    """Every checked-in BENCH record is schema-valid, and the
-    driver's last-JSON-line rule recovers exactly the parsed record
-    from the raw stdout tail — DeprecationWarning lines in the r04/
-    r05 tails (the pre-fix float()-on-ndarray noise) never confuse
-    the parse (bench.py now extracts via .item())."""
+def test_checked_in_history_validates_and_parses_clean(bench_files):
+    """Every history record is schema-valid, and the driver's
+    last-JSON-line rule recovers exactly the parsed record from the
+    raw stdout tail — DeprecationWarning lines in the tail never
+    confuse the parse."""
     import tools.bench_json as bench_json
-    assert len(BENCH_FILES) >= 5
-    for p in BENCH_FILES:
+    assert len(bench_files) >= 5
+    for p in bench_files:
         with open(p) as f:
             w = json.load(f)
         assert bench_json.validate(w["parsed"]) == [], p

@@ -203,7 +203,7 @@ def _flat_ar(cfg, ndev=8):
 
     return jax.jit(shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
                              out_specs=(P("dp"), P("dp")),
-                             check_rep=False))
+                             check_vma=False))
 
 
 def test_ef_accumulation_vs_numpy_reference():
@@ -296,7 +296,7 @@ def test_hierarchical_tiers():
             return out[None], nr[None]
 
         ar = jax.jit(shard_map(f, mesh=mesh, in_specs=(spec, spec),
-                               out_specs=(spec, spec), check_rep=False))
+                               out_specs=(spec, spec), check_vma=False))
         res = jnp.zeros((8, S), jnp.float32)
         tot_out = np.zeros(S, np.float64)
         tot_true = np.zeros(S, np.float64)
@@ -336,7 +336,7 @@ def test_hierarchical_grad_sync_quant_residual():
 
     sync = jax.jit(coll.shard_map(f, mesh=mesh, in_specs=(spec, spec),
                                   out_specs=(spec, spec),
-                                  check_rep=False))
+                                  check_vma=False))
     rng = np.random.RandomState(9)
     tree = {"w": rng.randn(8, 10, 7).astype(np.float32),
             "b": rng.randn(8, 5).astype(np.float32)}
@@ -880,7 +880,7 @@ def test_grad_sync_env_does_not_auto_quantize(monkeypatch):
         return jax.tree_util.tree_map(lambda x: x[None], s)
 
     sync = jax.jit(coll.shard_map(f, mesh=mesh, in_specs=(spec,),
-                                  out_specs=spec, check_rep=False))
+                                  out_specs=spec, check_vma=False))
     rng = np.random.RandomState(96)
     g = rng.randn(8, 40).astype(np.float32)
     out = np.asarray(sync({"w": jnp.asarray(g)})["w"])[0]
@@ -913,7 +913,7 @@ def test_grad_sync_flushes_residual_when_quant_resolves_off(monkeypatch):
 
     sync = jax.jit(coll.shard_map(f, mesh=mesh, in_specs=(spec, spec),
                                   out_specs=(spec, spec),
-                                  check_rep=False))
+                                  check_vma=False))
     rng = np.random.RandomState(97)
     g = rng.randn(8, 24).astype(np.float32)
     res = rng.randn(8, 24).astype(np.float32)   # a carried correction

@@ -519,7 +519,7 @@ def test_fleet_multiprocess_sigkill_zero_drop(tmp_path):
     ref = net(nd.array(x)).asnumpy()
 
     mgr = fleet.ReplicaManager(
-        n=2, spec={"ckpt_prefix": prefix, "seed": 99,
+        n=2, spec={"ckpt_prefix": prefix, "seed": 99, "platform": "cpu",
                    "heartbeat_s": 0.25, "miss_k": 3})
     router = None
     try:

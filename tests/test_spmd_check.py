@@ -80,12 +80,8 @@ def _sharded(shape, mesh, spec, dtype=jnp.float32):
 
 def _shard_map(body, mesh, in_specs, out_specs):
     from mxnet_tpu.parallel import shard_map
-    try:
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:          # newer jax renamed/dropped check_rep
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def _first_weight_spec(net, spec):

@@ -90,22 +90,14 @@ def emit(record: Dict[str, Any], *, source: str = "",
 
     The record is printed on its own stdout line (the driver/parse
     contract) AFTER being stamped with the perfwatch environment
-    fingerprint and offered to the trajectory store — both
-    best-effort: the bench must still report even when the
-    observability layer is unavailable. Returns the (enriched)
-    record."""
+    fingerprint and offered to the trajectory store. A failure in
+    either raises: a record without its fingerprint is not one to
+    compare. Returns the (enriched) record."""
+    from mxnet_tpu import perfwatch
     check(record)
     if "env" not in record:
-        try:
-            from mxnet_tpu import perfwatch
-            record["env"] = perfwatch.environment_fingerprint()
-        except Exception:
-            pass
-    try:
-        from mxnet_tpu import perfwatch
-        perfwatch.maybe_record(record, source=source)
-    except Exception:
-        pass
+        record["env"] = perfwatch.environment_fingerprint()
+    perfwatch.maybe_record(record, source=source)
     print(json.dumps(record), file=stream or sys.stdout)
     return record
 

@@ -20,8 +20,8 @@ commwatch, run a wall-clocked step loop, and gate on the live mx_mfu
 gauge (executed FLOPs from the compiled program's cost_analysis /
 wall / peak — metered, not the analytic attribution the legacy line
 prints). Exits nonzero when MFU% < P OR when the meter failed to
-populate (so `--mfu-gate 0` on the CPU dryrun still asserts the
-metering pipeline works; the 55 bar is an on-chip gate:
+populate (the CPU mesh has no peak FLOP/s: there the gauge populates
+only with MXNET_PEAK_FLOPS stated; the 55 bar is an on-chip gate:
 `python tools/bert_bench.py --mfu-gate 55`).
 
 --json: emit one machine-comparable JSON line (the BENCH_*.json
@@ -76,10 +76,16 @@ def _make_head_loss(vocab, units, mode):
     return Wrapper(blk)
 
 
-def build_step(batch, seq, split_update=False, head_mode="auto"):
+def build_step(batch, seq, split_update=False, head_mode="auto",
+               net=None, mesh=None):
     """head_mode: 'dense' = in-model decoder + composed CE (the r2
     reference path); 'fused'/'chunked'/'auto' = parametric head loss
-    (BERTMLMLoss; 'auto' follows MXNET_CHUNKED_CE)."""
+    (BERTMLMLoss; 'auto' follows MXNET_CHUNKED_CE).
+
+    ``net``: an uninitialized BERTModel to train in place of BERT-base
+    (chip_smoke.py: dropout 0 for the cross-device comparison, a tiny
+    one for the CPU rehearsal). ``mesh``: defaults to the first device;
+    a mesh with a 'dp' axis shards the batch over it."""
     import jax
     import mxnet_tpu as mx
     from mxnet_tpu import nd
@@ -87,29 +93,33 @@ def build_step(batch, seq, split_update=False, head_mode="auto"):
     from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
 
     in_model_decoder = head_mode == "dense"
-    net = bert_12_768_12(use_pooler=False, use_classifier=False,
-                         use_decoder=in_model_decoder)
+    if net is None:
+        net = bert_12_768_12(use_pooler=False, use_classifier=False,
+                             use_decoder=in_model_decoder)
     net.initialize()
+    vocab = net.word_embed.weight.shape[0]
     rng = np.random.RandomState(0)
-    ids = rng.randint(0, 30522, (2, seq)).astype(np.float32)
+    ids = rng.randint(0, vocab, (2, seq)).astype(np.float32)
     tt = np.zeros((2, seq), np.float32)
     net(nd.array(ids), nd.array(tt))  # resolve deferred shapes
 
     loss = _MLMLoss() if in_model_decoder else \
-        _make_head_loss(30522, 768, head_mode)
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+        _make_head_loss(vocab, net._units, head_mode)
+    if mesh is None:
+        mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    spec = P("dp") if mesh.shape.get("dp", 1) > 1 else P()
     step = ShardedTrainStep(net, loss, mesh, optimizer="lamb",
                             lr=1e-3, wd=0.01, dtype="bfloat16",
                             n_data_inputs=3,
-                            data_specs=[P(), P(), P()],
+                            data_specs=[spec, spec, spec],
                             split_update=split_update)
-    x = nd.array(rng.randint(0, 30522, (batch, seq)).astype(np.float32))
+    x = nd.array(rng.randint(0, vocab, (batch, seq)).astype(np.float32))
     t = nd.array(np.zeros((batch, seq), np.float32))
     # label layout follows the head it feeds: the decoder path scores
     # (seq, batch, vocab) logits; the parametric heads consume
     # outputs[0], which the model returns batch-major (bert.py)
     lab_shape = (seq, batch) if in_model_decoder else (batch, seq)
-    y = nd.array(rng.randint(0, 30522, lab_shape).astype(np.float32))
+    y = nd.array(rng.randint(0, vocab, lab_shape).astype(np.float32))
     return step, (x, t, y)
 
 
@@ -139,7 +149,6 @@ def _pop_float_flag(argv, name):
 
 def main():
     import json
-    import time
     import jax
 
     argv = sys.argv[1:]
@@ -159,6 +168,11 @@ def main():
         head_mode = "dense"
     else:
         head_mode = "auto"
+    # device numbers only: no chip, no run (tests drive build_step
+    # directly; the CPU never goes through main)
+    from mxnet_tpu import runtime
+    device = runtime.require_accelerator()
+    runtime.enable_compile_cache()
     step, data = build_step(batch, seq, split_update="--split" in argv,
                             head_mode=head_mode)
     for _ in range(3):
@@ -166,19 +180,8 @@ def main():
     float(jax.device_get(loss))
 
     from devtime import device_ms_per_step
-    try:
-        ms = device_ms_per_step(lambda: step.step(*data), 8,
-                                lambda o: float(jax.device_get(o)))
-    except Exception:
-        ms = 0.0
-    if ms <= 0:
-        # no xplane device time off-chip (the CPU dryrun): wall-clock
-        # the synced loop instead
-        t0 = time.perf_counter()
-        for _ in range(8):
-            loss = step.step(*data)
-        float(jax.device_get(loss))
-        ms = (time.perf_counter() - t0) / 8 * 1e3
+    ms = device_ms_per_step(lambda: step.step(*data), 8,
+                            lambda o: float(jax.device_get(o)))
     # FLOP model (fwd+bwd+update ~ 3x fwd): encoder 12 layers x
     # (qkv 3*768^2 + proj 768^2 + ffn 2*768*3072) * 2 MAC + attention
     # 2*2*L*768 per token + decoder head 768*30522 (+768^2 transform)
@@ -273,6 +276,7 @@ def main():
             "metric": "bert_base_mlm_train_step",
             "value": round(samples_s, 2),
             "unit": "samples/sec/chip",
+            "device": device,
             "batch": batch, "seq": seq, "head": head_mode,
             "device_ms_per_step": round(ms, 3),
             "analytic_tflops": round(tflops, 2),

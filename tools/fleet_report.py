@@ -2,6 +2,11 @@
 """Fleet observability report — per-rank step/comm/skew table + the
 all-axes collective profile (ISSUE 6 acceptance tool).
 
+A CPU tool: every mode pins JAX to the CPU platform (virtual devices).
+Several modes import JAX in this process and then start replica or
+worker processes — correct on the CPU only; on a chip the parent would
+hold the device its children need. Nothing here reports a device number.
+
 Single-process mode (default; runs under the 8-virtual-device CPU
 dryrun in tier-1): drives a workload over EVERY mesh axis the stack
 trains with — a dcn x dp x tp ShardedTrainStep (GSPMD-inserted
@@ -1084,7 +1089,7 @@ def run_serve_fleet(args) -> int:
                {"name": "paid", "weight": 4, "deadline_ms": 30000},
                {"name": "batch", "weight": 0.5}]
     mgr = fleet.ReplicaManager(
-        n=3, spec={"ckpt_prefix": prefix, "seed": 99,
+        n=3, spec={"ckpt_prefix": prefix, "seed": 99, "platform": "cpu",
                    "heartbeat_s": 0.25, "miss_k": 3,
                    "tenants": tenants})
     router = None
@@ -1270,6 +1275,10 @@ def run_serve_fleet(args) -> int:
 
 def run_single(args) -> int:
     os.environ["MXNET_TELEMETRY"] = "1"
+    # the CPU mesh has no peak FLOP/s of its own: state a nominal one so
+    # the gate below can check that the meter populates. The mfu this
+    # prints is a pipeline check, not a utilization of any device.
+    os.environ.setdefault("MXNET_PEAK_FLOPS", "1e12")
     if "--xla_force_host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +

@@ -23,6 +23,11 @@ Launchers:
 
 `-s/--num-servers` is accepted for command-line parity and must be 0:
 parameter servers do not exist in the SPMD design.
+
+One process per chip: this launcher must never import JAX (or
+mxnet_tpu, which does). A parent that has touched JAX holds the chip,
+and the worker that needs it then fails or hangs. It imports only the
+standard library — keep it so.
 """
 from __future__ import annotations
 

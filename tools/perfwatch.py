@@ -24,7 +24,7 @@ Commands (cmd defaults to ``report``):
                     trials, median of per-round paired ratios).
 
 Flags: ``--gate`` exits nonzero on any confirmed regression, naming
-the metric (the CI/on-chip-session hook — PERF_r06 gate list);
+the metric (the CI/on-chip-session hook — on-chip gate);
 ``--export-autotune-corpus [DIR]`` joins stored kernel_micro records
 into the per-device_kind (features, measured-time) corpus files the
 ROADMAP-4 cost model trains on (autotune-cache shaped, loadable via

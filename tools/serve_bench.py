@@ -97,8 +97,9 @@ def main(argv=None):
 
     snap = telemetry.snapshot()
     flops1 = snap["counters"].get("mx_executed_flops_total", 0.0)
-    mfu = (flops1 - flops0) / wall / telemetry.peak_flops() \
-        if wall > 0 else 0.0
+    # no peak known for this device kind (the CPU default): no mfu
+    peak = telemetry.known_peak_flops()
+    mfu = (flops1 - flops0) / wall / peak if wall > 0 and peak else None
     steady = len([p for p in compilewatch.programs()
                   if p["fn"] == "serve.forward"]) - compiled_after_warmup
 
@@ -129,7 +130,7 @@ def main(argv=None):
         "steady_recompiles": steady,
         "warmup_programs": n_buckets,
         "requests_ok": ok, "requests_failed": err,
-        "mfu": round(mfu, 6),
+        "mfu": None if mfu is None else round(mfu, 6),
         "tokens_per_s": round(tokens_per_s, 1),
         "tenants": {r["tenant"]: {"requests": r["requests"],
                                   "p50_ms": round(r["p50_ms"], 3),
