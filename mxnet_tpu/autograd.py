@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .base import MXNetError
+from . import telemetry
 
 __all__ = ["record", "pause", "train_mode", "predict_mode", "is_recording",
            "is_training", "set_recording", "set_training", "backward", "grad",
@@ -458,6 +459,7 @@ class _PendingStep:
             runner = _build_fused(self.node_specs, self.head_specs,
                                   self.grad_slots, self.hg_present)
             _FUSED_CACHE[self.skey] = runner
+        telemetry.count_launch("gluon")
         flat, grads = runner(self.leaf_vals, self.rng_vals, self.hg_vals)
         self._finish(flat, grads)
 
@@ -473,9 +475,11 @@ class _PendingStep:
                                        self.grad_slots, self.hg_present,
                                        upd_math)
             _FUSED_STEP_CACHE[key] = runner
-        flat, grads, new_ws, new_states = runner(
-            self.leaf_vals, self.rng_vals, self.hg_vals, state_vals,
-            hp_vals)
+        telemetry.count_launch("gluon")
+        # under the Trainer's step::update.launch: its .lookup / .call
+        flat, grads, new_ws, new_states = runner.call_phased(
+            "update.launch", self.leaf_vals, self.rng_vals, self.hg_vals,
+            state_vals, hp_vals)
         self._finish(flat, grads)
         return new_ws, new_states
 
@@ -665,6 +669,14 @@ def _try_fused_backward(heads, head_grads, order):
 
 def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     """Run reverse-mode from ``heads`` to every reachable variable's .grad."""
+    # the step's backward on the host: the tape walk and either the
+    # fused plan's launch, its stash for an armed Trainer, or the
+    # classic per-node walk
+    with telemetry.phase("backward"):
+        _backward(heads, head_grads, retain_graph, train_mode)
+
+
+def _backward(heads, head_grads, retain_graph, train_mode):
     from .ndarray.ndarray import NDArray
 
     # a plan stashed by a previous armed backward that was never

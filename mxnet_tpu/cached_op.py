@@ -21,6 +21,7 @@ import jax
 
 from .base import MXNetError
 from . import autograd
+from . import telemetry
 from .ndarray import NDArray
 from .ndarray.ndarray import _place
 from . import random as rand_mod
@@ -184,12 +185,14 @@ class CachedOp:
     def _run_vjp(self, args):
         """One forward-with-residuals execution + its backward closure
         (shared by the eager recording path and deferred forcing)."""
+        telemetry.count_launch("gluon")
         try:
             all_raw, vjp_partial = self._vjp_fwd(*args)
             bwd = self._bwd
 
             def vjp_fn(cots):
                 cots = cots if isinstance(cots, tuple) else (cots,)
+                telemetry.count_launch("gluon")
                 return bwd(vjp_partial, tuple(cots))
         except Exception:
             # fallback: eager vjp (still correct, not one fused program)
@@ -284,6 +287,7 @@ class CachedOp:
             return out_arrays if len(out_arrays) > 1 else out_arrays[0]
 
         fn = self._fns[train]
+        telemetry.count_launch("gluon")
         all_raw = fn(*rng_args, *raw) if self._needs_rng else fn(*raw)
         outs_raw, aux_vals = all_raw[:n_vis], all_raw[n_vis:]
         if train:

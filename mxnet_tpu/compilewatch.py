@@ -330,6 +330,25 @@ class WatchedJit:
             on = telemetry._resolve()
         if not on:
             return self._jit(*args)
+        sig, entry = self._lookup(args)
+        return self._dispatch(sig, entry, args)
+
+    def call_phased(self, phase: str, *args):
+        """``self(*args)`` under two step-phase spans, for a caller that
+        times the host's share of one launch: ``step::<phase>.lookup``
+        (the arguments' signature and the program cache) and
+        ``step::<phase>.call`` (the executable's own call: the runtime
+        takes the arguments, allocates the outputs and enqueues the
+        program; a first call compiles here)."""
+        with telemetry.phase(phase + ".lookup"):
+            sig, entry = self._lookup(args) if telemetry.enabled() \
+                else (None, None)
+        with telemetry.phase(phase + ".call"):
+            return self._dispatch(sig, entry, args)
+
+    def _lookup(self, args):
+        """(signature, cached entry or None); signature None where the
+        call goes to the plain jit."""
         for a in args:
             if isinstance(a, jax.core.Tracer):
                 # called under an outer jax trace (e.g. autograd
@@ -337,12 +356,16 @@ class WatchedJit:
                 # through the plain jit — a trace is not a compile,
                 # and AOT-compiling tracer args would record phantom
                 # programs (or raise under MXNET_COMPILE_STRICT)
-                return self._jit(*args)
+                return None, None
         try:
             sig = tuple(_arg_sig(a) for a in args)
         except Exception:
+            return None, None
+        return sig, self._cache.get(sig)
+
+    def _dispatch(self, sig, entry, args):
+        if sig is None:
             return self._jit(*args)
-        entry = self._cache.get(sig)
         if entry is not None:
             telemetry.count_event("mx_compile_cache_hits_total",
                                   fn=self.fn_label)

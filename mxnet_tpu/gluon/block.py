@@ -22,6 +22,7 @@ from ..ndarray import NDArray
 from .. import symbol as sym_mod
 from ..symbol import Symbol
 from .. import autograd
+from .. import telemetry
 from ..cached_op import CachedOp
 from .parameter import (Constant, DeferredInitializationError, Parameter,
                         ParameterDict)
@@ -404,6 +405,15 @@ class HybridBlock(Block):
     def _call_cached_op(self, *args):
         if self._cached_op is None:
             self._build_cache(*args)
+        if autograd.is_recording():
+            # the step's forward on the host: gathering the parameters,
+            # the CachedOp's signature and program lookup, and its
+            # launch or (fused backward on) deferral
+            with telemetry.phase("forward"):
+                return self._run_cached_op(args)
+        return self._run_cached_op(args)
+
+    def _run_cached_op(self, args):
         ctx = args[0].ctx
         arrays = []
         data_map = {"data%d" % i: a for i, a in enumerate(args)}

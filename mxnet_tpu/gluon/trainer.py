@@ -214,7 +214,25 @@ class Trainer:
         the updated parameters are all-gathered back — one watched SPMD
         program per step (two with a GradGuard: the finiteness check
         runs on the scattered shards, still one extra sync). Same
-        wire traffic as allreduce, ~N x less optimizer-state HBM."""
+        wire traffic as allreduce, ~N x less optimizer-state HBM.
+
+        The whole of it is one span, ``step::update``
+        (docs/OBSERVABILITY.md "Step spans"): parent of
+        ``step::update.prep`` / ``.launch`` / ``.writeback`` on the
+        fused path, where the phase histogram files it under
+        ``fused_step``, and of ``step::allreduce`` / ``step::guard`` /
+        ``step::optimizer`` on the classic one. The step is marked
+        after the span has closed, so the span lands in its own step's
+        record of ``telemetry.step_log``."""
+        with telemetry.phase("update") as span:
+            useful = self._step(batch_size, ignore_stale_grad, span)
+        if useful is not None:
+            telemetry.mark_step(useful=useful)
+
+    def _step(self, batch_size, ignore_stale_grad, span):
+        """``step`` inside its span. Returns whether the step was
+        useful (False: a guard dropped the update), or None where the
+        step is marked elsewhere (a K-step chunk marks itself)."""
         if not self._kv_initialized:
             self._contexts = self._check_contexts()
             self._init_kvstore()
@@ -262,7 +280,7 @@ class Trainer:
                         done = runner.push(plan, prep)
                         if done:
                             self._rearm_fused_update()
-                            return      # mark_step rides the chunk
+                            return None     # mark_step rides the chunk
                         # runner refused (sig change, force bail,
                         # grad_req='add'): run THIS step now. Older
                         # buffered steps already drained inside push —
@@ -270,9 +288,8 @@ class Trainer:
                         from .. import scan as scan_mod
                         scan_mod._refresh_grad_leaves(plan)
                         if not guard_on:
-                            with telemetry.phase("fused_step"):
-                                done = self._consume_fused_plan(
-                                    plan, prepared=prep)
+                            done = self._consume_fused_plan(
+                                plan, prepared=prep)
                         else:
                             # guarded step can't bypass the guard on
                             # the per-step consume — rewind the prep's
@@ -285,12 +302,7 @@ class Trainer:
                             plan.execute()
                 if runner is None and not done:
                     if eligible and not guard_on:
-                        # own phase label: this program contains
-                        # fwd+bwd+update, so charging it to 'optimizer'
-                        # would gut the per-step phase breakdown
-                        # (docs/OBSERVABILITY.md)
-                        with telemetry.phase("fused_step"):
-                            done = self._consume_fused_plan(plan)
+                        done = self._consume_fused_plan(plan)
                         if not done:
                             # a consume-level bail is STRUCTURAL (param
                             # missing from the tape, mp tuple state): it
@@ -323,8 +335,12 @@ class Trainer:
                                 rescale=self._optimizer.rescale_grad,
                                 update_now=unorm)
                     self._rearm_fused_update()   # stay armed
-                    telemetry.mark_step()
-                    return
+                    # own phase label: this program contains
+                    # fwd+bwd+update, so charging it to 'optimizer'
+                    # would gut the per-step phase breakdown
+                    # (docs/OBSERVABILITY.md); the span keeps its name
+                    span.labels["phase"] = "fused_step"
+                    return True
                 # plan executed plainly (grads written) — fall through
                 # to the classic guard/update path
                 self._fused_armed = False
@@ -338,14 +354,12 @@ class Trainer:
             from . import zero as zero_mod
             status = engine.run_step(ignore_stale_grad)
             if status == zero_mod.DONE:
-                telemetry.mark_step()
-                return
+                return True
             if status == zero_mod.SKIPPED:
-                # useful=False: a guard-skipped step's interval is
+                # not useful: a guard-skipped step's interval is
                 # debited from the mx_goodput meter (same contract as
                 # the replicated guard path below)
-                telemetry.mark_step(useful=False)
-                return
+                return False
             # BAIL is structural (sparse grads, parameter set changed):
             # it would recur every step — dissolve the accumulated
             # state shards into the per-context updaters and fall back
@@ -395,10 +409,9 @@ class Trainer:
                         named, action,
                         rescale=self._optimizer.rescale_grad)
             if not proceed:
-                # useful=False: a guard-skipped step's interval is
+                # not useful: a guard-skipped step's interval is
                 # debited from the mx_goodput meter
-                telemetry.mark_step(useful=False)
-                return          # skipped step (counted by the guard)
+                return False    # skipped step (counted by the guard)
         with telemetry.phase("optimizer"):
             caps = mw.note_pre_update(self._trainable_named()) \
                 if mw_on else None
@@ -406,7 +419,7 @@ class Trainer:
             if caps:
                 mw.note_post_update(caps)
         self._rearm_fused_update()
-        telemetry.mark_step()
+        return True
 
     # ------------------------------------------------------------------
     # ZeRO weight-update sharding (MXNET_ZERO; gluon/zero.py,
@@ -707,21 +720,32 @@ class Trainer:
         `prepared` (a scan.FusedPrep) skips the prologue: the scan
         buffer already ran it at push time, counters included."""
         import jax.numpy as jnp
-        prep = prepared if prepared is not None \
-            else self._prep_fused_plan(plan)
+        with telemetry.phase("update.prep"):
+            prep = prepared if prepared is not None \
+                else self._prep_fused_plan(plan)
+            if prep is not None:
+                items = prep.items
+                mom_rows, plain_rows = prep.mom_rows, prep.plain_rows
+                upd_math = self._make_upd_math(prep)
+                state_vals = [items[k][3]._jax() for k in mom_rows]
+                hp_vals = (jnp.asarray(prep.lrs[list(mom_rows)]),
+                           jnp.asarray(prep.wds[list(mom_rows)]),
+                           jnp.asarray(prep.lrs[list(plain_rows)]),
+                           jnp.asarray(prep.wds[list(plain_rows)]))
         if prep is None:
             plan.execute()
             return False
-        items = prep.items
-        mom_rows, plain_rows = prep.mom_rows, prep.plain_rows
-        upd_math = self._make_upd_math(prep)
-        state_vals = [items[k][3]._jax() for k in mom_rows]
-        hp_vals = (jnp.asarray(prep.lrs[list(mom_rows)]),
-                   jnp.asarray(prep.wds[list(mom_rows)]),
-                   jnp.asarray(prep.lrs[list(plain_rows)]),
-                   jnp.asarray(prep.wds[list(plain_rows)]))
-        new_ws, new_moms = plan.execute_with_update(
-            prep.upd_key, upd_math, state_vals, hp_vals)
+        with telemetry.phase("update.launch"):
+            new_ws, new_moms = plan.execute_with_update(
+                prep.upd_key, upd_math, state_vals, hp_vals)
+        with telemetry.phase("update.writeback"):
+            self._write_back_fused(prep, new_ws, new_moms)
+        return True
+
+    def _write_back_fused(self, prep, new_ws, new_moms):
+        """Rebind the parameters and momenta to the fused step's
+        outputs (``step::update.writeback``)."""
+        items, mom_rows = prep.items, prep.mom_rows
         mw = self._modelwatch
         caps = None
         if mw is not None and mw.sampling:
@@ -740,7 +764,6 @@ class Trainer:
             # instead of the classic one-step-stale stash
             unorm = mw.note_post_update(caps, defer=False)
             self._mw_fused_caps = (caps, unorm)
-        return True
 
     def allreduce_grads(self):
         if not self._kv_initialized:

@@ -566,7 +566,26 @@ class ShardedTrainStep:
         Multi-process meshes: each process passes its LOCAL slice of
         the batch (the per-worker view, matching split_and_load
         semantics); the global array is assembled from process-local
-        data without gathering."""
+        data without gathering.
+
+        One span, ``step::sharded``, with two children
+        (docs/OBSERVABILITY.md "Step spans"): ``step::sharded.place``
+        (the batch onto the mesh) and ``step::sharded.launch`` (the
+        compiled step's call; each program handed to the runtime counts
+        in ``mx_program_launches_total{path="sharded"}``)."""
+        from .. import telemetry
+        with telemetry.phase("sharded"):
+            with telemetry.phase("sharded.place"):
+                arrays = self._place(data, rng)
+            with telemetry.phase("sharded.launch"):
+                loss, stepped = self._launch(arrays)
+        if stepped:
+            telemetry.mark_step()
+        return loss
+
+    def _place(self, data, rng):
+        """The step's batch as global arrays on the mesh (and ``rng``,
+        when given, as the step's replicated key)."""
         if not hasattr(self, "_multiproc"):
             me = jax.process_index()
             self._multiproc = any(d.process_index != me
@@ -591,17 +610,24 @@ class ShardedTrainStep:
                 pass
             rep = NamedSharding(self.mesh, P())
             self._rng_dev = jax.device_put(rng, rep)
+        return arrays
+
+    def _launch(self, arrays):
+        """Call the step's compiled program(s) on placed ``arrays``.
+        Returns (loss, whether an optimizer step was taken: a
+        gradient-accumulation micro-step takes none)."""
+        from .. import telemetry
         if self._split_update:
+            telemetry.count_launch("sharded")
             grads, self.aux, self._rng_dev, loss = self._grad_fn(
                 self.params, self.aux, self._rng_dev, *arrays)
+            telemetry.count_launch("sharded")
             self.params, self.states, self._t_dev = self._update_fn(
                 self.params, self.states, grads, self._t_dev)
             self._t += 1
-            from .. import telemetry
-            telemetry.mark_step()
-            return loss
+            return loss, True
         if self.grad_accum == 1:
-            from .. import commwatch, telemetry
+            from .. import commwatch
             import contextlib
             watch = contextlib.nullcontext()
             if self._use_auto_layout:
@@ -625,6 +651,7 @@ class ShardedTrainStep:
                 watch = commwatch.program_watch(prog_key, "sharded_step")
             else:
                 fn = self._fused
+            telemetry.count_launch("sharded")
             with watch:
                 (self.params, self.aux, self.states, self._t_dev,
                  self._rng_dev, loss) = fn(
@@ -637,17 +664,17 @@ class ShardedTrainStep:
                     # kvstore comm_span)
                     jax.device_get(loss)
             self._t += 1
-            telemetry.mark_step()
-            return loss
+            return loss, True
         if self._grads is None:
             self._grads = {k: jax.device_put(jnp.zeros_like(v),
                                              self.param_shardings[k])
                            for k, v in self.params.items()}
+        telemetry.count_launch("sharded")
         if self._micro_count < self.grad_accum - 1:
             self._grads, self.aux, self._rng_dev, loss = self._micro(
                 self.params, self.aux, self._grads, self._rng_dev, *arrays)
             self._micro_count += 1
-            return loss
+            return loss, False
         (self.params, self.aux, self.states, self._t_dev, self._rng_dev,
          loss) = self._apply(self.params, self.aux, self.states,
                              self._grads, self._t_dev, self._rng_dev,
@@ -655,9 +682,7 @@ class ShardedTrainStep:
         self._t += 1
         self._micro_count = 0
         self._grads = None
-        from .. import telemetry
-        telemetry.mark_step()
-        return loss
+        return loss, True
 
     # ------------------------------------------------------------------
     # checkpoint / resume (SURVEY §5.4 superset: the reference is

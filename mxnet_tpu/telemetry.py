@@ -15,10 +15,14 @@ Three instrument kinds, one flat process-wide registry:
   1e3s — sized for durations in seconds), tracking count/sum/min/max
   and estimating percentiles from the bucket counts.
 
-Plus a :class:`span` context manager that times a region into BOTH the
-chrome-trace profiler (``profiler.record_event``, visible whenever the
-profiler is in the ``run`` state) and a latency histogram (when
-telemetry is enabled).
+Plus a :class:`span` context manager, the ONE span primitive of the
+training path. A live span (telemetry on, or the profiler in ``run``)
+goes to four places: a ``jax.profiler.TraceAnnotation`` of its name (so
+it sits in a ``jax.profiler`` trace beside the device's ops, on the
+profiler's clock), the bounded per-step log (:func:`step_log`: name,
+start, end, parent span, step), the chrome-trace profiler
+(``profiler.record_event``) and a latency histogram (when telemetry is
+enabled).
 
 Cost model: everything is gated on ``MXNET_TELEMETRY`` (cached bool —
 call :func:`refresh` after mutating the environment). The disabled
@@ -37,24 +41,37 @@ Exposure, three ways (docs/OBSERVABILITY.md):
 
 Wired call sites: engine.push_async (queued→running→done spans +
 per-label latency), kvstore/dist (bytes, call latency, retry/deadline
-counters), Trainer.step / Module.update / DataLoader (per-step phase
-breakdown: data/forward/backward/allreduce/optimizer/guard),
-guardrails.emit, faultinject fires, model checkpoint writes, and
-Monitor stats.
+counters), guardrails.emit, faultinject fires, model checkpoint writes,
+Monitor stats, and the per-step phases (``step::<phase>`` spans,
+docs/OBSERVABILITY.md "Step spans"), each where its work happens:
+``forward`` in HybridBlock's call into its CachedOp under
+``autograd.record()`` (the Estimator's own pair around the whole pass
+folds the block's into it; a net that is not hybridized has a forward
+span only under the Estimator), ``backward`` in ``autograd.backward``,
+``update`` around ``Trainer.step`` with ``update.prep`` /
+``update.launch`` / ``update.writeback`` on the fused path and
+``allreduce`` / ``guard`` / ``optimizer`` on the classic one,
+``sharded`` with ``sharded.place`` / ``sharded.launch`` in
+``ShardedTrainStep.step``, ``data`` in the Estimator / DataLoader,
+and Module.update's allreduce / guard / optimizer.
 """
 from __future__ import annotations
 
 import bisect
+import collections
 import logging
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import profiler
 
 __all__ = ["Counter", "Gauge", "Histogram", "span", "phase", "counter",
            "gauge", "histogram", "enabled", "enable", "refresh",
-           "snapshot", "render_prometheus", "mark_step",
+           "snapshot", "render_prometheus", "mark_step", "step_log",
+           "count_launch",
            "heartbeat_line", "count_event", "guard_event",
            "fault_event", "checkpoint_event", "reset",
            "memory_snapshot", "memory_diff", "ndarray_live",
@@ -300,6 +317,7 @@ def reset():
         _STEP["stall_s"] = 0.0
         _STEP["flops0"] = 0.0
         _STEP["compile_at_last"] = 0.0
+        _STEPLOG.clear()
     with _FLEET_LOCK:
         _FLEET["last"] = None
     with _BUNDLE_LOCK:
@@ -324,15 +342,60 @@ def reset():
 # ---------------------------------------------------------------------------
 # spans — chrome trace + latency histogram in one context manager
 # ---------------------------------------------------------------------------
-class span:
-    """Time a region into the chrome-trace profiler (category `cat`)
-    and, when telemetry is on, into histogram `hist` (with `labels`).
-    Near-zero cost when both the profiler and telemetry are off.
-    Instrumentation failures are swallowed — a span must never poison
-    the region it observes. ``cancel()`` inside the block drops the
-    record (e.g. a probe that turned out not to be real work)."""
+class _StepLog:
+    """The spans of the step that is open and of the last
+    ``STEP_LOG_STEPS`` closed ones. A live span appends one tuple
+    ``(name, start, end, parent, step)`` (``time.perf_counter``
+    seconds; ``parent`` the enclosing open span's name on the same
+    thread, or None; ``step`` the number of steps :func:`mark_step`
+    had counted) on exit; :func:`mark_step` closes the open step with
+    the launches counted since the last close. Both ends are bounded:
+    the ring drops the oldest step, and a step that never closes (a
+    process that serves, an evaluation loop) stops taking spans at
+    ``OPEN_SPAN_CAP`` and counts what it dropped."""
+    STEP_LOG_STEPS = 512
+    OPEN_SPAN_CAP = 4096
 
-    __slots__ = ("name", "cat", "hist", "labels", "args", "_t0", "_live")
+    __slots__ = ("open", "dropped", "closed", "launches")
+
+    def __init__(self):
+        self.clear()
+
+    def clear(self):
+        self.open: List[tuple] = []
+        self.dropped = 0
+        self.closed = collections.deque(maxlen=self.STEP_LOG_STEPS)
+        self.launches: Dict[str, float] = {}
+
+
+class _OpenSpans(threading.local):
+    def __init__(self):
+        self.names: List[str] = []     # innermost last
+
+
+_STEPLOG = _StepLog()
+_OPEN_SPANS = _OpenSpans()
+LAUNCH_COUNTER = "mx_program_launches_total"
+LAUNCH_PATHS = ("gluon", "sharded")
+
+
+class span:
+    """Time a region. When live (telemetry on, or the chrome-trace
+    profiler in ``run``) it enters a ``jax.profiler.TraceAnnotation``
+    of its name, and on exit appends to the step log
+    (:func:`step_log`), writes the chrome-trace event (category `cat`)
+    and, when telemetry is on, observes histogram `hist` (with
+    `labels`). With both off a span is one gate read: no clock, no
+    annotation, no record. A span opened inside an open span of the
+    same name on the same thread is folded into it (the Estimator's
+    ``step::forward`` around a hybridized block's own): one sample,
+    not two. Instrumentation failures are swallowed — a span must
+    never poison the region it observes. ``cancel()`` inside the block
+    drops the record (e.g. a probe that turned out not to be real
+    work)."""
+
+    __slots__ = ("name", "cat", "hist", "labels", "args", "_t0", "_live",
+                 "_ann", "_parent")
 
     def __init__(self, name: str, cat: str = "telemetry",
                  hist: Optional[str] = None, args: Optional[dict] = None,
@@ -347,19 +410,40 @@ class span:
         self._live = False
 
     def __enter__(self):
+        self._ann = None
         try:
             self._live = enabled() or profiler.state() == "run"
             if self._live:
+                names = _OPEN_SPANS.names
+                if self.name in names:
+                    self._live = False      # folded into the open one
+                    return self
+                self._parent = names[-1] if names else None
+                ann = TraceAnnotation(self.name)
+                ann.__enter__()
+                names.append(self.name)
+                self._ann = ann
                 self._t0 = time.perf_counter()
         except Exception:
             self._live = False
         return self
 
     def __exit__(self, *exc):
-        if not self._live:
+        ann = self._ann
+        if ann is None:
             return False
         try:
             t1 = time.perf_counter()
+            ann.__exit__(None, None, None)
+            _OPEN_SPANS.names.pop()
+            if not self._live:              # cancelled inside the block
+                return False
+            log = _STEPLOG
+            if len(log.open) < log.OPEN_SPAN_CAP:
+                log.open.append((self.name, self._t0, t1, self._parent,
+                                 _STEP["count"]))
+            else:
+                log.dropped += 1
             dt = t1 - self._t0
             profiler.record_event(self.name, self.cat, self._t0 * 1e6,
                                   dt * 1e6, self.args)
@@ -373,9 +457,13 @@ class span:
 def phase(name: str) -> span:
     """A step-phase span: chrome-trace event ``step::<name>`` (category
     ``step``) + the ``mx_step_phase_seconds{phase=<name>}`` histogram.
-    Phases: data / forward / backward / allreduce / optimizer / guard /
-    fused_step / zero_step / modelwatch (the training-dynamics read on
-    steps where no guard shares it — docs/OBSERVABILITY.md)."""
+    Phases: data / forward / backward / update (with update.prep /
+    update.launch / update.writeback on the fused path, where the
+    histogram files the whole of it under ``fused_step``; allreduce /
+    guard / optimizer on the classic one) / sharded (sharded.place /
+    sharded.launch) / zero_step / modelwatch (the training-dynamics
+    read on steps where no guard shares it). The names are stable:
+    docs/OBSERVABILITY.md "Step spans"."""
     return span("step::%s" % name, "step", hist="mx_step_phase_seconds",
                 phase=name)
 
@@ -522,7 +610,70 @@ def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
             peak = known_peak_flops()
             if peak is not None:
                 gauge("mx_mfu").set((flops_now - flops0) / wall / peak)
+    _close_step(prev_count)
     _maybe_fleet_tick(count, prev_count)
+
+
+def count_launch(path: str):
+    """One compiled program handed to the runtime on the training path
+    -> ``mx_program_launches_total{path=gluon|sharded}``: the whole-graph
+    programs (CachedOp's, the fused backward and fused step, the sharded
+    step), not the eager per-op ones. :func:`mark_step` closes the
+    count into the step log. No-op when telemetry is off."""
+    count_event(LAUNCH_COUNTER, path=path)
+
+
+def _close_step(step: int):
+    """Move the open step's spans into the ring, with the launches
+    counted since the last close. Runs in :func:`mark_step`, so a span
+    still open then (``step::update`` around a K-step chunk's retire)
+    lands in the next step's record."""
+    log = _STEPLOG
+    spans, log.open = log.open, []
+    dropped, log.dropped = log.dropped, 0
+    launches = {}
+    for path in LAUNCH_PATHS:
+        m = _METRICS.get((LAUNCH_COUNTER, (("path", path),)))
+        if m is not None:
+            total = m.get()
+            delta = total - log.launches.get(path, 0.0)
+            log.launches[path] = total
+            if delta:
+                launches[path] = delta
+    log.closed.append((step, spans, launches, dropped))
+
+
+def step_log(n: Optional[int] = None) -> List[dict]:
+    """The last ``n`` closed steps (all the ring holds when None),
+    oldest first. Per step: ``step`` (its index: the steps counted
+    before it), ``spans`` {name: {``count``, ``seconds`` (summed
+    durations), ``self_seconds`` (``seconds`` minus what the spans
+    opened directly inside it cover)}}, ``launches`` {path: programs
+    handed to the runtime during the step}, ``events`` (the raw
+    ``(name, start, end, parent, step)`` tuples, in order of exit;
+    ``time.perf_counter`` seconds) and ``dropped`` (spans the open
+    step refused at its cap). The ring holds
+    ``_StepLog.STEP_LOG_STEPS`` steps; nothing on the hot path writes
+    it out."""
+    closed = list(_STEPLOG.closed)
+    if n is not None:
+        closed = closed[-n:] if n > 0 else []
+    out = []
+    for step, events, launches, dropped in closed:
+        spans: Dict[str, dict] = {}
+        for name, start, end, _parent, _step in events:
+            row = spans.setdefault(
+                name, {"count": 0, "seconds": 0.0, "self_seconds": 0.0})
+            row["count"] += 1
+            row["seconds"] += end - start
+            row["self_seconds"] += end - start
+        for _name, start, end, parent, _step in events:
+            if parent in spans:
+                spans[parent]["self_seconds"] -= end - start
+        out.append({"step": step, "spans": spans,
+                    "launches": dict(launches), "events": list(events),
+                    "dropped": dropped})
+    return out
 
 
 def _maybe_fleet_tick(step_count: int, prev_count: int = None):
