@@ -6,6 +6,7 @@ JPEG decode + crop/mirror augment + double buffering).
 """
 from __future__ import annotations
 
+import functools
 import threading
 from collections import namedtuple
 from typing import List, Optional
@@ -360,6 +361,91 @@ class PrefetchingIter(DataIter):
         return self.current_batch.pad
 
 
+class _Produced:
+    """What one production of `ImageRecordIter` leaves behind: written
+    by the producing op, read once the op's engine var has been waited
+    for (`var` is None when there is nothing to wait for)."""
+
+    __slots__ = ("var", "done", "at", "data", "label", "pad")
+
+    def __init__(self):
+        self.var = None
+        self.done = False
+        # "mid": a batch inside an epoch. "turn": the op met the epoch
+        # marker, so StopIteration is owed before this batch, which is
+        # the first of the next epoch. "first": that batch once
+        # StopIteration has been raised, or waived by reset().
+        self.at = "mid"
+        self.data = self.label = None
+        self.pad = 0
+
+
+def _record_producer(lib, handle, batch_size, hwc, label_width, round_batch,
+                     dev, post):
+    """Everything one batch of `ImageRecordIter` takes, as a function of
+    its `_Produced` slot. It refers to the native handle and not to the
+    iterator: an op in flight must not keep the iterator alive, whose
+    `__del__` waits for the op before it frees the handle."""
+    import ctypes as ct
+    import jax
+
+    def produce(out):
+        try:
+            data_p = ct.POINTER(ct.c_uint8)()
+            label_p = ct.POINTER(ct.c_float)()
+            n = ct.c_int(0)
+            refs = (ct.byref(data_p), ct.byref(label_p), ct.byref(n))
+            rc = lib.MXIONext(handle, *refs)
+            if rc == 1:
+                # the epoch marker. The look-ahead carries across the
+                # turn: reset here, once, and go on to the next epoch's
+                # first batch, which the user's reset() adopts
+                out.at = "turn"
+                lib.MXIOReset(handle)
+                rc = lib.MXIONext(handle, *refs)
+                if rc == 1:
+                    return          # an epoch with no batch in it
+            if rc != 0:
+                from .. import native as native_mod
+                raise MXNetError("ImageRecordIter: %s"
+                                 % native_mod.last_error())
+            count = n.value
+            buf = np.ctypeslib.as_array(data_p, shape=(count,) + hwc)
+            lab = np.ctypeslib.as_array(label_p, shape=(count, label_width))
+            if count < batch_size and round_batch:
+                # pad the tail batch by repeating (reference round_batch)
+                reps = -(-batch_size // count)
+                buf = np.tile(buf, (reps, 1, 1, 1))[:batch_size]
+                lab = np.tile(lab, (reps, 1))[:batch_size]
+                out.pad = batch_size - count
+            elif count < batch_size:
+                # round_batch=False short tail: still pad to the
+                # advertised provide_data shape (consumers bind to the
+                # full batch_size) and signal the padding via
+                # DataBatch.pad, like the reference's
+                # last-batch-handling contract
+                full = np.zeros((batch_size,) + buf.shape[1:], buf.dtype)
+                full[:count] = buf
+                fl = np.zeros((batch_size,) + lab.shape[1:], lab.dtype)
+                fl[:count] = lab
+                buf, lab = full, fl
+                out.pad = batch_size - count
+            else:
+                # the views alias the native double buffer, which the
+                # producer recycles at the NEXT MXIONext call (the next
+                # production's): copy out before the upload, which may
+                # read the host buffer after device_put returns
+                buf = buf.copy()
+                lab = lab.copy()
+            out.data = post(jax.device_put(buf, dev))
+            out.label = jax.device_put(np.ascontiguousarray(
+                lab[:, 0] if label_width == 1 else lab), dev)
+        finally:
+            out.done = True
+
+    return produce
+
+
 class ImageRecordIter(DataIter):
     """Image RecordIO iterator on the native C++ pipeline.
 
@@ -374,6 +460,28 @@ class ImageRecordIter(DataIter):
     parity) transposes + casts + normalizes ON DEVICE where XLA fuses it
     into the consumer. mean/std normalization happens on device for the
     same reason.
+
+    One batch ahead, on the device (ref: src/io/iter_prefetcher.h, the
+    PrefetcherIter upstream's ImageRecordIter is built on): `next()`
+    returns the batch whose production the previous call started, and
+    starts the next one before it returns. A production is one op on
+    the native engine (label `io_batch_upload`): fetch from the native
+    double buffer, copy out, pad the tail, upload, normalise. So the
+    upload of batch k+1 is enqueued on the device ahead of the step
+    that consumes batch k. The batch is handed over: its op has
+    completed, `data[0]` / `label[0]` carry no engine gate and go
+    straight into a recorded, hybridized step, and an error of the op
+    raises at the `next()` that would have returned its batch. The
+    look-ahead carries across the end of an epoch: the op that meets
+    the marker resets the native pipeline and produces the next epoch's
+    first batch, `next()` raises StopIteration as before, and `reset()`
+    adopts that batch instead of resetting again. A `reset()` inside an
+    epoch waits for the op in flight, discards its batch (an error of
+    that op raises at the `reset()`) and resets; the next batch is then
+    produced inside `next()`, as the first one is. Without the native
+    engine every batch is.
+    `mx_io_batches_total{handoff=ready|waited|cold}` counts how each
+    batch came (docs/OBSERVABILITY.md).
     """
 
     def __init__(self, path_imgrec, data_shape, batch_size,
@@ -395,14 +503,11 @@ class ImageRecordIter(DataIter):
         self._label_width = int(label_width)
         self._layout = data_layout
         self._dtype = np.dtype(dtype)
-        self._mean = np.array([mean_r, mean_g, mean_b], np.float32)
-        self._std = np.array([std_r, std_g, std_b], np.float32)
         self._ctx = ctx or current_context()
-        self._round_batch = bool(round_batch)
         idx = path_imgidx.encode() if (path_imgidx and shuffle) else None
         if shuffle and not path_imgidx:
             raise MXNetError("shuffle=True needs path_imgidx")
-        import ctypes as ct
+        self._ahead = None      # the _Produced of the batch after this one
         self._handle = self._lib.MXIOCreateImageRecordIter(
             path_imgrec.encode(), idx, int(batch_size), self._h, self._w,
             self._label_width, int(bool(shuffle)), int(bool(rand_crop)),
@@ -411,8 +516,12 @@ class ImageRecordIter(DataIter):
         if not self._handle:
             raise MXNetError("ImageRecordIter init failed: %s"
                              % native_mod.last_error())
-        self._ct = ct
-        self._jit_post = None
+        self._produce = _record_producer(
+            self._lib, self._handle, int(batch_size),
+            (self._h, self._w, self._c), self._label_width,
+            bool(round_batch), self._ctx.jax_device,
+            self._postprocess(np.array([mean_r, mean_g, mean_b], np.float32),
+                              np.array([std_r, std_g, std_b], np.float32)))
 
     @property
     def provide_data(self):
@@ -427,112 +536,108 @@ class ImageRecordIter(DataIter):
             else (self.batch_size, self._label_width)
         return [DataDesc("softmax_label", shape, np.float32, "N")]
 
-    def reset(self):
-        self._lib.MXIOReset(self._handle)
-
-    def _postprocess(self, raw_u8):
+    def _postprocess(self, mean, std):
         """Device-side cast/normalize/transpose — one tiny jitted
         program whose output XLA lays out for the consumer."""
-        if self._jit_post is None:
-            import jax
-            import jax.numpy as jnp
-            mean, std = self._mean, self._std
-            layout, dt = self._layout, self._dtype
+        import jax
+        import jax.numpy as jnp
+        layout, dt = self._layout, self._dtype
 
-            @jax.jit
-            def post(x):  # x: N,H,W,C u8
-                y = x.astype(jnp.float32)
-                if (mean != 0).any():
-                    y = y - mean.reshape(1, 1, 1, 3)
-                if (std != 1).any():
-                    y = y / std.reshape(1, 1, 1, 3)
-                if layout == "NCHW":
-                    y = y.transpose(0, 3, 1, 2)
-                return y.astype(dt)
+        @jax.jit
+        def post(x):  # x: N,H,W,C u8
+            y = x.astype(jnp.float32)
+            if (mean != 0).any():
+                y = y - mean.reshape(1, 1, 1, 3)
+            if (std != 1).any():
+                y = y / std.reshape(1, 1, 1, 3)
+            if layout == "NCHW":
+                y = y.transpose(0, 3, 1, 2)
+            return y.astype(dt)
 
-            self._jit_post = post
-        return self._jit_post(raw_u8)
+        return post
+
+    def _start(self, eng):
+        """Start one production: on a worker of the native engine, or
+        here when there is none."""
+        out = _Produced()
+        if eng is None:
+            self._produce(out)
+        else:
+            out.var = eng.new_var()
+            eng.push_async(functools.partial(self._produce, out),
+                           write_vars=(out.var,), label="io_batch_upload")
+        return out
+
+    @staticmethod
+    def _collect(out):
+        """Wait for a production's op; its error raises here, once."""
+        if out.var is None:
+            return
+        from ..engine import native_engine
+        var, out.var = out.var, None
+        eng = native_engine()
+        try:
+            eng.wait_for_var(var)
+        finally:
+            eng.delete_var(var)
+
+    def reset(self):
+        out, self._ahead = self._ahead, None
+        if out is not None:
+            # a discarded batch's error raises here: no next() is left
+            # to meet it
+            self._collect(out)
+            if out.at != "mid" and out.data is not None:
+                # the look-ahead already turned the epoch: one native
+                # reset an epoch, and its first batch is ready
+                out.at = "first"
+                self._ahead = out
+                return
+        self._lib.MXIOReset(self._handle)
 
     def next(self):
-        import jax
-        ct = self._ct
-        data_p = ct.POINTER(ct.c_uint8)()
-        label_p = ct.POINTER(ct.c_float)()
-        n = ct.c_int(0)
-        rc = self._lib.MXIONext(self._handle, ct.byref(data_p),
-                                ct.byref(label_p), ct.byref(n))
-        if rc == 1:
-            raise StopIteration
-        if rc != 0:
-            from .. import native as native_mod
-            raise MXNetError("ImageRecordIter: %s" % native_mod.last_error())
-        count = n.value
-        pad = 0
-        buf = np.ctypeslib.as_array(data_p,
-                                    shape=(count, self._h, self._w, self._c))
-        lab = np.ctypeslib.as_array(label_p,
-                                    shape=(count, self._label_width))
-        if count < self.batch_size and self._round_batch:
-            # pad the tail batch by repeating (reference round_batch)
-            reps = -(-self.batch_size // count)
-            buf = np.tile(buf, (reps, 1, 1, 1))[:self.batch_size]
-            lab = np.tile(lab, (reps, 1))[:self.batch_size]
-            pad = self.batch_size - count
-        elif count < self.batch_size:
-            # round_batch=False short tail: still pad to the advertised
-            # provide_data shape (consumers bind to the full batch_size)
-            # and signal the padding via DataBatch.pad, like the
-            # reference's last-batch-handling contract
-            full = np.zeros((self.batch_size,) + buf.shape[1:], buf.dtype)
-            full[:count] = buf
-            fl = np.zeros((self.batch_size,) + lab.shape[1:], lab.dtype)
-            fl[:count] = lab
-            buf, lab = full, fl
-            pad = self.batch_size - count
-        else:
-            # the views alias the native double buffer, which the
-            # producer recycles after our NEXT MXIONext call — copy out
-            # (on THIS thread, before the next MXIONext) so the async
-            # upload can't read overwritten pixels
-            buf = buf.copy()
-            lab = lab.copy()
-        # native-IO -> device hand-off as a native-engine op (ref:
-        # SURVEY §1 L2 "every mutation flows through the engine"): the
-        # host->HBM upload + normalize run on an engine worker with the
-        # batch arrays gated on the op's write var, so next() returns
-        # immediately and the upload overlaps the consumer's compute;
-        # an upload error re-raises at wait_to_read.
-        dev = self._ctx.jax_device
-        label_arr = np.ascontiguousarray(
-            lab[:, 0] if self._label_width == 1 else lab)
-
-        def make(data, label, buf=buf, label_arr=label_arr):
-            def upload():
-                raw = jax.device_put(buf, dev)
-                data._set_jax(self._postprocess(raw))
-                label._set_jax(jax.device_put(label_arr, dev))
-            return upload
-
-        from ..engine import gate_arrays, native_or_none, push_gated
+        from .. import telemetry
+        from ..engine import native_or_none
         eng = native_or_none()
-        if eng is None:
-            data = NDArray(None, self._ctx)
-            label = NDArray(None, self._ctx)
-            make(data, label)()
+        out, self._ahead = self._ahead, None
+        if out is None or eng is None:
+            # no look-ahead: produced inside a next(), this one or, with
+            # no engine, the one that met the epoch marker
+            handoff = "cold"
+            out = out or self._start(eng)
         else:
-            data = NDArray(None, self._ctx)
-            label = NDArray(None, self._ctx)
-            avals = [jax.ShapeDtypeStruct(tuple(self.provide_data[0][1]),
-                                          np.dtype(self._dtype)),
-                     jax.ShapeDtypeStruct(label_arr.shape, label_arr.dtype)]
-            var, _gate = gate_arrays([data, label], avals)
-            push_gated(make(data, label), var, label="io_batch_upload")
-        return DataBatch([data], [label], pad=pad,
+            handoff = "ready" if out.done else "waited"
+        self._collect(out)
+        if out.at == "turn":
+            out.at = "first"
+            if out.data is not None:
+                self._ahead = out
+            raise StopIteration
+        # Hand-off (ref: SURVEY §1 L2 "every mutation flows through the
+        # engine"): this batch's op has completed on the engine — in
+        # steady state it was pushed a whole step ago, so its upload and
+        # normalise sit on the device ahead of the step that was
+        # launched since — and the arrays carry no gate: they can enter
+        # a recorded CachedOp with no wait_to_read(). The op's error
+        # raised above, at this next(). The next batch's production
+        # starts now, before the consumer launches its step.
+        telemetry.count_event("mx_io_batches_total", handoff=handoff)
+        if eng is not None:
+            self._ahead = self._start(eng)
+        return DataBatch([NDArray(out.data, self._ctx)],
+                         [NDArray(out.label, self._ctx)], pad=out.pad,
                          provide_data=self.provide_data,
                          provide_label=self.provide_label)
 
     def __del__(self):
         try:
+            out, self._ahead = getattr(self, "_ahead", None), None
+            if out is not None:
+                try:
+                    # the op in flight uses the handle
+                    self._collect(out)
+                except Exception:
+                    pass
             if getattr(self, "_handle", None):
                 self._lib.MXIOFree(self._handle)
                 self._handle = None

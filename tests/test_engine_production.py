@@ -136,10 +136,11 @@ def test_async_checkpoint_error_at_wait(tmp_path):
     assert "w" in a2
 
 
-def test_native_io_handoff_gated(tmp_path):
-    """ImageRecordIter batches are engine-gated: next() hands back
-    arrays whose upload runs on an engine worker; values are correct at
-    wait (production API: the BASELINE ResNet input pipeline)."""
+def test_native_io_handoff_ungated(tmp_path):
+    """ImageRecordIter batches are produced by an engine op
+    (`io_batch_upload`) and handed over once it has completed; values
+    are correct (production API: the BASELINE ResNet input
+    pipeline)."""
     from mxnet_tpu import recordio
     from mxnet_tpu.io import ImageRecordIter
     rec = str(tmp_path / "d.rec")
@@ -158,7 +159,8 @@ def test_native_io_handoff_gated(tmp_path):
                          shuffle=False)
     batch = it.next()
     d = batch.data[0]
-    # gated: pending until read; shape known without forcing
+    # handed over: no gate to force
+    assert d._pending is None
     assert d.shape == (4, 3, 8, 8)
     vals = d.asnumpy()
     labels = batch.label[0].asnumpy()
@@ -248,3 +250,69 @@ model.save_checkpoint("%s/nonexistent-dir/ck", 0, None,
         r.returncode != 0, \
         "checkpoint write failure vanished at exit: rc=%d out=%r" % (
             r.returncode, blob[-500:])
+
+
+def test_native_io_batch_feeds_a_recorded_step_unwaited(tmp_path):
+    """A batch from ImageRecordIter.next() is handed over, not gated:
+    with no wait_to_read() it goes through autograd.record() -> a
+    hybridized block -> backward() -> Trainer.step, and the step matches
+    the same step on nd.array of the same pixels (on the parent the
+    fused backward met `'EngineGate' object has no attribute
+    'out_values'`)."""
+    from mxnet_tpu import autograd, gluon, recordio
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.io import ImageRecordIter
+    rec = str(tmp_path / "s.rec")
+    idx = str(tmp_path / "s.idx")
+    w = recordio.MXIndexedRecordIO(idx, rec, "w")
+    rng = np.random.RandomState(3)
+    imgs = rng.randint(0, 255, (8, 8, 8, 3)).astype(np.uint8)
+    for i in range(8):
+        w.write_idx(i, recordio.pack(
+            recordio.IRHeader(0, float(i % 3), i, 0), imgs[i].tobytes()))
+    w.close()
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 8), batch_size=4,
+                         std_r=255.0, std_g=255.0, std_b=255.0)
+
+    def build():
+        net = nn.HybridSequential()
+        net.add(nn.Conv2D(4, 3, padding=1, in_channels=3),
+                nn.Activation("relu"), nn.Flatten(), nn.Dense(3))
+        net.initialize(mx.initializer.Xavier(rnd_type="gaussian"))
+        net.hybridize(static_alloc=True, static_shape=True)
+        return net, gluon.Trainer(net.collect_params(), "sgd",
+                                  {"learning_rate": 0.1, "momentum": 0.9},
+                                  kvstore="device")
+
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    fed, fed_tr = build()
+    ref, ref_tr = build()
+    fed(nd.zeros((4, 3, 8, 8)))             # shapes known: copy the weights
+    ref(nd.zeros((4, 3, 8, 8)))
+    for a, b in zip(fed.collect_params().values(),
+                    ref.collect_params().values()):
+        b.set_data(a.data())
+    losses = []
+    for k in range(2):                      # the cold batch, then a look-ahead
+        batch = it.next()
+        x, y = batch.data[0], batch.label[0]
+        assert x._pending is None and y._pending is None
+        rx = nd.array(imgs[4 * k:4 * k + 4].astype(np.float32)
+                      .transpose(0, 3, 1, 2) / 255.0)
+        ry = nd.array(np.arange(4 * k, 4 * k + 4) % 3, dtype="float32")
+        pair = []
+        for net, tr, data, label in ((fed, fed_tr, x, y),
+                                     (ref, ref_tr, rx, ry)):
+            with autograd.record():
+                loss = loss_fn(net(data), label)
+            loss.backward()
+            tr.step(4)
+            pair.append(loss.asnumpy())
+        np.testing.assert_allclose(pair[0], pair[1], rtol=1e-5, atol=1e-6)
+        losses.append(pair[0])
+    assert np.isfinite(losses).all()
+    for a, b in zip(fed.collect_params().values(),
+                    ref.collect_params().values()):
+        np.testing.assert_allclose(a.data().asnumpy(), b.data().asnumpy(),
+                                   rtol=1e-5, atol=1e-6)

@@ -303,3 +303,273 @@ def test_image_record_iter_no_round_batch_tail_pad(tmp_path):
         take = 5 - (b.pad or 0)
         labels.extend(b.label[0].asnumpy().astype(int)[:take].tolist())
     assert sorted(labels) == list(range(13))
+
+
+# ---------------------------------------------------------------------------
+# ImageRecordIter keeps one batch ahead (ISSUE 26): what a user can see is
+# what the native handle, driven directly, gives.
+# ---------------------------------------------------------------------------
+def _native_epochs(rec, idx, batch, h, w, shuffle, mirror, seed, epochs):
+    """[[(uint8 NHWC, labels)] per batch] per epoch from the native
+    handle itself: MXIONext until the marker, then MXIOReset."""
+    import ctypes as ct
+    from mxnet_tpu import native as nat
+    lib = nat.load_io_lib()
+    hd = lib.MXIOCreateImageRecordIter(
+        rec.encode(), idx.encode() if shuffle else None, batch, h, w, 1,
+        int(shuffle), 0, int(mirror), 0, 1, seed)
+    assert hd
+    data_p = ct.POINTER(ct.c_uint8)()
+    label_p = ct.POINTER(ct.c_float)()
+    n = ct.c_int(0)
+    out = []
+    try:
+        for _ in range(epochs):
+            out.append([])
+            while True:
+                rc = lib.MXIONext(hd, ct.byref(data_p), ct.byref(label_p),
+                                  ct.byref(n))
+                if rc == 1:
+                    break
+                assert rc == 0
+                out[-1].append((
+                    np.ctypeslib.as_array(
+                        data_p, shape=(n.value, h, w, 3)).copy(),
+                    np.ctypeslib.as_array(
+                        label_p, shape=(n.value,)).copy()))
+            lib.MXIOReset(hd)
+    finally:
+        lib.MXIOFree(hd)
+    return out
+
+
+def _as_handed_over(raw, lab, batch):
+    """A native batch as next() hands it over: round_batch tail padding,
+    NCHW float32, and pad."""
+    count = len(raw)
+    if count < batch:
+        reps = -(-batch // count)
+        raw = np.tile(raw, (reps, 1, 1, 1))[:batch]
+        lab = np.tile(lab, reps)[:batch]
+    return (raw.astype(np.float32).transpose(0, 3, 1, 2), lab,
+            batch - count)
+
+
+def _assert_epoch(got, want, batch):
+    """An epoch from _epoch_of is, bit for bit, the native handle's."""
+    assert len(got) == len(want)        # StopIteration at the same call
+    for (data, label, pad), (raw, lab) in zip(got, want):
+        wd, wl, wp = _as_handed_over(raw, lab, batch)
+        np.testing.assert_array_equal(data, wd)
+        np.testing.assert_array_equal(label, wl)
+        assert pad == wp
+
+
+def _epoch_of(it):
+    """One epoch of next() up to StopIteration: [(data, label, pad)]."""
+    got = []
+    while True:
+        try:
+            b = it.next()
+        except StopIteration:
+            return got
+        assert b.data[0].shape == tuple(it.provide_data[0][1])
+        assert b.label[0].shape == tuple(it.provide_label[0][1])
+        got.append((b.data[0].asnumpy(), b.label[0].asnumpy(), b.pad or 0))
+
+
+@pytest.mark.parametrize("engine", ["native", "none"])
+def test_image_record_iter_lookahead_matches_native_epochs(
+        tmp_path, monkeypatch, engine):
+    """Three epochs of next() / StopIteration / reset() over a record
+    count that is no multiple of the batch: data, labels and pad are
+    bit for bit what the native handle gives, with the look-ahead and
+    its carry across reset(), and with no engine at all."""
+    if engine == "none":
+        import mxnet_tpu.engine as eng_mod
+        monkeypatch.setattr(eng_mod, "native_or_none", lambda: None)
+    rec, idx, _ = _write_raw_pack(tmp_path, n=23, name="la")
+    want = _native_epochs(rec, idx, 5, 8, 12, False, False, 0, 3)
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=5)
+    for epoch in want:
+        assert len(epoch) == 5
+        got = _epoch_of(it)
+        _assert_epoch(got, epoch, 5)
+        assert got[-1][2] == 2
+        it.reset()
+
+
+def test_image_record_iter_lookahead_shuffled_epochs(tmp_path):
+    """Shuffle and rand_mirror on, a fixed seed: the first epoch is bit
+    for bit the native handle's; every later epoch holds each record
+    once, mirrored or not, under its own label, and ends at the same
+    call."""
+    rec, idx, imgs = _write_raw_pack(tmp_path, n=23, name="ls")
+    (first,) = _native_epochs(rec, idx, 5, 8, 12, True, True, 11, 1)
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=5, shuffle=True,
+                         rand_mirror=True, seed=11)
+    got = _epoch_of(it)
+    assert len(first) == 5
+    _assert_epoch(got, first, 5)
+    orders = [[int(x) for d, l, p in got for x in l[:5 - p]]]
+    for _ in range(3):
+        it.reset()
+        got = _epoch_of(it)
+        assert len(got) == 5 and [p for _, _, p in got] == [0, 0, 0, 0, 2]
+        seen = []
+        for data, label, pad in got:
+            for img, lab in zip(data[:5 - pad], label[:5 - pad]):
+                want = imgs[int(lab)].astype(np.float32)
+                img = img.transpose(1, 2, 0)
+                assert np.array_equal(img, want) \
+                    or np.array_equal(img, want[:, ::-1])
+                seen.append(int(lab))
+            # the padding repeats the tail's own records
+            np.testing.assert_array_equal(
+                data[5 - pad:], np.tile(data[:5 - pad], (5, 1, 1, 1))[:pad])
+        assert sorted(seen) == list(range(23))
+        orders.append(seen)
+    assert any(o != orders[0] for o in orders[1:])      # reshuffled
+
+
+def test_image_record_iter_reset_in_mid_epoch(tmp_path):
+    """reset() inside an epoch discards the batch in flight and starts
+    over from the epoch's first batch; so does a reset() after the last
+    batch, before StopIteration was seen, and the epoch after it is
+    whole."""
+    rec, idx, imgs = _write_raw_pack(tmp_path, n=12, name="lm")
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=4)
+
+    def labels(b):
+        return b.label[0].asnumpy().astype(int).tolist()
+
+    assert labels(it.next()) == [0, 1, 2, 3]
+    assert labels(it.next()) == [4, 5, 6, 7]
+    it.reset()
+    b = it.next()
+    assert labels(b) == [0, 1, 2, 3]
+    np.testing.assert_array_equal(
+        b.data[0].asnumpy()[2], imgs[2].astype(np.float32).transpose(2, 0, 1))
+    it.reset()
+    it.reset()                      # twice in a row, and before any next()
+    assert [labels(b) for b in it] == [[0, 1, 2, 3], [4, 5, 6, 7],
+                                       [8, 9, 10, 11]]
+    it.reset()
+    for _ in range(3):
+        last = it.next()
+    assert labels(last) == [8, 9, 10, 11]
+    it.reset()                      # the look-ahead has turned the epoch
+    assert [labels(b) for b in it] == [[0, 1, 2, 3], [4, 5, 6, 7],
+                                       [8, 9, 10, 11]]
+    # without a reset() the iteration goes on into the next epoch
+    assert labels(it.next()) == [0, 1, 2, 3]
+
+
+@pytest.fixture
+def _io_counters(monkeypatch):
+    from mxnet_tpu import telemetry
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    monkeypatch.delenv("MXNET_TELEMETRY_HEARTBEAT", raising=False)
+    telemetry.refresh()
+    telemetry.reset()
+
+    def read():
+        snap = telemetry.snapshot()["counters"]
+        return {k: int(snap.get('mx_io_batches_total{handoff="%s"}' % k, 0))
+                for k in ("ready", "waited", "cold")}
+    yield read
+    telemetry.refresh()
+    telemetry.reset()
+
+
+def test_image_record_iter_handoff_counter(tmp_path, _io_counters):
+    """mx_io_batches_total: one cold batch, then ready / waited only,
+    an epoch turn included; a reset() in mid-epoch costs one more cold
+    batch."""
+    rec, idx, _ = _write_raw_pack(tmp_path, n=12, name="lc")
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=4)
+    assert _io_counters() == {"ready": 0, "waited": 0, "cold": 0}
+    it.next()
+    assert _io_counters() == {"ready": 0, "waited": 0, "cold": 1}
+    for rest in (2, 3):             # the epoch's rest, then a whole one
+        assert len(list(it)) == rest
+        it.reset()
+    got = _io_counters()
+    assert got["cold"] == 1 and got["ready"] + got["waited"] == 5
+    from mxnet_tpu.engine import native_wait_all
+    it.next()
+    native_wait_all()               # the look-ahead's op is over
+    got = _io_counters()
+    it.next()
+    assert _io_counters() == dict(got, ready=got["ready"] + 1)
+    it.reset()                      # in mid-epoch
+    it.next()
+    now = _io_counters()
+    assert now["cold"] == 2 and sum(now.values()) == 9
+
+
+def test_image_record_iter_handoff_counter_without_engine(
+        tmp_path, monkeypatch, _io_counters):
+    """With no native engine every batch is produced inside a next():
+    all of them count cold, the one adopted at an epoch turn too."""
+    import mxnet_tpu.engine as eng_mod
+    monkeypatch.setattr(eng_mod, "native_or_none", lambda: None)
+    rec, idx, _ = _write_raw_pack(tmp_path, n=12, name="le")
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=4)
+    for _ in range(2):
+        assert len(list(it)) == 3
+        it.reset()
+    assert _io_counters() == {"ready": 0, "waited": 0, "cold": 6}
+
+
+def test_image_record_iter_discarded_batch_error_raises_at_reset(tmp_path):
+    """An upload that fails for a batch reset() discards is not dropped:
+    it raises at that reset(), and the iterator goes on after another."""
+    rec, idx, _ = _write_raw_pack(tmp_path, n=12, name="lx")
+    it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                         data_shape=(3, 8, 12), batch_size=4)
+    produce, calls = it._produce, []
+
+    def second_fails(out):
+        calls.append(out)
+        if len(calls) != 2:         # the look-ahead the first next() starts
+            return produce(out)
+        out.done = True
+        raise RuntimeError("no room on the device")
+
+    it._produce = second_fails
+    assert it.next().label[0].asnumpy().tolist() == [0, 1, 2, 3]
+    with pytest.raises(RuntimeError, match="no room on the device"):
+        it.reset()
+    it.reset()
+    assert [b.label[0].asnumpy().astype(int).tolist() for b in it] == [
+        [0, 1, 2, 3], [4, 5, 6, 7], [8, 9, 10, 11]]
+
+
+def test_image_record_iter_deleted_with_an_op_in_flight(tmp_path):
+    """An iterator dropped while its look-ahead is in flight waits for
+    the op before it frees the native handle: no hang, no warning, no
+    error left on the engine."""
+    import gc
+    import warnings
+    from mxnet_tpu.engine import native_engine
+    rec, idx, _ = _write_raw_pack(tmp_path, n=64, h=64, w=64, name="ld")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        for _ in range(4):
+            it = ImageRecordIter(path_imgrec=rec, path_imgidx=idx,
+                                 data_shape=(3, 64, 64), batch_size=16,
+                                 shuffle=True, rand_mirror=True)
+            b = it.next()
+            assert it._ahead is not None
+            del it
+            gc.collect()
+            assert b.data[0].shape == (16, 3, 64, 64)
+    assert not [m for m in native_engine().pending_ops()
+                if m[0] == "io_batch_upload"]
+    nd.waitall()
