@@ -187,6 +187,7 @@ from . import random_ops  # noqa: E402,F401
 from . import optimizer_ops  # noqa: E402,F401
 from . import rnn_ops   # noqa: E402,F401
 from . import contrib_ops  # noqa: E402,F401
+from . import decoder_ops  # noqa: E402,F401
 from . import quantized_ops  # noqa: E402,F401
 from . import tensor_tail  # noqa: E402,F401
 from . import vision_ops  # noqa: E402,F401

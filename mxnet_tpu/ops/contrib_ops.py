@@ -447,3 +447,12 @@ def chunked_lm_head_ce(hidden, weight, bias, labels, *, chunk_size=0):
     with jax.named_scope("chunked_lm_head_ce"):
         loss = _make_chunked_ce(chunk)(h2, weight, bias, lab)
     return loss.reshape(lead)
+
+
+@register("_contrib_chunked_lm_head_ce_nobias")
+def chunked_lm_head_ce_nobias(hidden, weight, labels, *, chunk_size=0):
+    """:func:`chunked_lm_head_ce` for a head with no bias (an untied
+    decoder head): the same streaming op with a zero bias."""
+    return chunked_lm_head_ce(
+        hidden, weight, jnp.zeros((weight.shape[0],), jnp.float32), labels,
+        chunk_size=chunk_size)

@@ -568,6 +568,11 @@ class ShardedTrainStep:
         semantics); the global array is assembled from process-local
         data without gathering.
 
+        Floating data inputs are cast to the compute dtype inside the
+        step; integer inputs (token ids, labels) reach the graph as
+        they are. Pass ids as int32: a float32 id is rounded to bf16
+        before the embedding lookup (odd ids over 256 become even).
+
         One span, ``step::sharded``, with two children
         (docs/OBSERVABILITY.md "Step spans"): ``step::sharded.place``
         (the batch onto the mesh) and ``step::sharded.launch`` (the

@@ -156,3 +156,53 @@ def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
     one = Mesh(np.array(topo.devices[:1]), ("dp",))
     with auto_partitioned(one):
         assert pallas_ln_available((L, N, C), BF, 2)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid decoder's ops at the Nemotron-H widths (hidden 2688; 64
+# Mamba heads x 64, 8 groups x 128; 32/2 attention heads x 128; experts
+# 2688 x 1856, 8 of 128 held, top 6), forward and backward: XLA
+# compositions, no kernel of this repo's or of the compiler's own
+# ---------------------------------------------------------------------------
+def test_expert_product_follows_the_buffer_not_the_experts(one_chip):
+    from mxnet_tpu.ops import decoder_ops as D
+    t, hidden, width, held, routed = 8192, 2688, 1856, 8, 128
+
+    def loss(x, r, b, up, down):
+        y, rows = D._moe_experts(x, r, b, up, down, top_k=6, offset=0,
+                                 scale=2.5, norm_topk=True)
+        return _sum32(y)
+
+    shapes = [((t, hidden), BF), ((routed, hidden), BF),
+              ((routed,), jnp.float32), ((held, width, hidden), BF),
+              ((held, hidden, width), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))) \
+        .lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the sorted path's FLOPs follow the buffer (20 blocks of 512 rows),
+    # not buffer x experts: 5 products of 10240 x 2688 x 1856 (forward
+    # and backward, the last forward product dead under a sum), beside
+    # the dense path's loop under the conditional, whose body (one
+    # expert over the 8192 rows: forward, recomputation, backward) is
+    # counted once
+    one = 2 * 10240 * hidden * width
+    flops = compiled.cost_analysis()["flops"]
+    assert 5 * one < flops < (5 + 7 * 8192 / 10240) * one * 1.2
+
+
+@pytest.mark.parametrize("length", [1024])
+def test_scan_and_attention_compile_at_published_widths(one_chip, length):
+    from mxnet_tpu.ops import decoder_ops as D
+    f32 = jnp.float32
+    scan = jax.grad(lambda *a: _sum32(D._ssd(*a, 128)), argnums=(0, 1, 3, 4))
+    shapes = [((1, length, 64, 64), BF), ((1, length, 64), f32), ((64,), f32),
+              ((1, length, 8, 128), BF), ((1, length, 8, 128), BF),
+              ((64,), f32)]
+    assert _custom_calls(one_chip, scan, *shapes) == 0
+    attn = jax.grad(lambda *a: _sum32(D._causal_gqa(*a, 512)),
+                    argnums=(0, 1, 2))
+    shapes = [((1, length, 32, 128), BF), ((1, length, 2, 128), BF),
+              ((1, length, 2, 128), BF)]
+    assert _custom_calls(one_chip, attn, *shapes) == 0

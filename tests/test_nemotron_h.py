@@ -1,0 +1,231 @@
+"""The zoo's Nemotron-H decoder (gluon/model_zoo/nemotron_h.py) at toy
+widths on the CPU: the blocks against the benchmark's plain float32
+reference, the auxiliary states through ``ShardedTrainStep``, the
+expert rows' gauges, and integer inputs through the sharded step."""
+import jax
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxbench import manifest
+from mxnet_tpu import autograd, gluon, nd, telemetry
+from mxnet_tpu.gluon.model_zoo import nemotron_h as zoo
+from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
+
+REF = manifest.load_module("reference", "nemotron_twotower_30b_a3b.py")
+CFGMOD = manifest.load_module("configs", "nemotron_twotower_30b_a3b.py")
+
+CFG = dict(
+    hidden_size=48, hybrid_override_pattern="MEMEM*EMEMEM", num_hidden_layers=9,
+    layer_norm_epsilon=1e-5, mamba_num_heads=4, mamba_head_dim=8, n_groups=2,
+    ssm_state_size=16, conv_kernel=4, chunk_size=8, time_step_min=0.001,
+    time_step_max=0.1, time_step_floor=1e-4, n_routed_experts=16,
+    experts_held=4, expert_offset=4, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=40, n_shared_experts=1,
+    num_experts_per_tok=3, routed_scaling_factor=2.5, norm_topk_prob=True,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8, vocab_size=64)
+
+
+def _build(cfg=CFG, seed=3):
+    mx.random.seed(seed)
+    net = zoo.NemotronHModel(cfg, prefix="")
+    head = zoo.NemotronHLMLoss(cfg, prefix="")
+    net.initialize()
+    head.initialize()
+    return net, head
+
+
+def _weights(net, head):
+    return CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
+
+
+def _batch(seed=0, shape=(2, 21)):
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32),
+            rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32))
+
+
+def test_blocks_and_loss_match_the_reference():
+    net, head = _build()
+    ids, labels = _batch()
+    with autograd.pause():
+        hidden = net(nd.array(ids, dtype="int32"))
+        loss = head(hidden, nd.array(labels, dtype="int32")).mean() \
+            .asnumpy().item()
+    w = _weights(net, head)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(REF.forward(w, ids, CFG))
+        want_loss = float(REF.lm_loss(w, ids, labels, CFG))
+    np.testing.assert_allclose(hidden.asnumpy(), want, rtol=1e-4, atol=1e-4)
+    assert loss == pytest.approx(want_loss, rel=1e-5)
+
+
+def test_layers_follow_the_pattern_and_the_share():
+    net, _ = _build()
+    kinds = [type(layer).__name__ for layer in net.layers]
+    assert kinds == ["Mamba2Layer", "ExpertLayer", "Mamba2Layer",
+                     "ExpertLayer", "Mamba2Layer", "AttentionLayer",
+                     "ExpertLayer", "Mamba2Layer", "ExpertLayer"]
+    params = net.collect_params()
+    assert params["layers1_router_weight"].shape == (16, 48)    # all routed
+    assert params["layers1_experts_up_weight"].shape == (4, 24, 48)  # held
+    assert params["layers0_in_proj_weight"].shape == (32 + 96 + 4, 48)
+    assert params["layers0_conv_weight"].shape == (96, 4)
+    assert params["layers5_k_weight"].shape == (16, 48)
+    assert not [n for n in params if n.endswith("bias")
+                and "conv" not in n and "dt_" not in n
+                and "e_score_correction" not in n]
+
+
+@pytest.mark.parametrize("change", [
+    dict(hybrid_override_pattern="MEMX", num_hidden_layers=4),
+    dict(hybrid_override_pattern="ME", num_hidden_layers=4),
+    dict(experts_held=8, expert_offset=12)])
+def test_a_configuration_that_cannot_be_built_is_refused(change):
+    with pytest.raises(ValueError):
+        zoo.NemotronHModel(dict(CFG, **change), prefix="")
+
+
+def test_seeded_initial_values():
+    net, _ = _build()
+    p = {k: v.data().asnumpy() for k, v in net.collect_params().items()}
+    a = np.exp(p["layers0_a_log"])
+    assert (a >= 1).all() and (a <= 16).all()
+    dt = np.log1p(np.exp(p["layers0_dt_bias"]))
+    assert (dt >= 1e-4).all() and (dt <= 0.1001).all()
+    np.testing.assert_array_equal(p["layers0_d"], 1.0)
+    np.testing.assert_array_equal(p["layers0_norm_weight"], 1.0)
+    assert np.abs(p["layers0_conv_bias"]).max() > 0
+    assert 0 < np.abs(p["layers1_e_score_correction_bias"]).max() <= 0.01
+    np.testing.assert_array_equal(p["layers1_expert_rows"], 0.0)
+    again, _ = _build()
+    np.testing.assert_array_equal(
+        p["layers2_in_proj_weight"],
+        again.collect_params()["layers2_in_proj_weight"].data().asnumpy())
+
+
+def _step(net, head, dtype=None):
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    return ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
+                            optimizer="adamw", lr=3e-4, wd=3e-5, beta2=0.95,
+                            dtype=dtype, n_data_inputs=2,
+                            data_specs=[P(), P()])
+
+
+def test_bias_and_row_counts_ride_as_auxiliary_states():
+    """Not trainable: no gradient, no optimizer state; the bias keeps
+    its seeded value, the counts are rewritten by the step."""
+    net, head = _build()
+    step = _step(net, head)
+    aux = sorted(step.aux)
+    assert aux == sorted("layers%d_%s" % (i, n) for i in (1, 3, 6, 8)
+                         for n in ("e_score_correction_bias", "expert_rows"))
+    assert not set(aux) & set(step.params) and not set(aux) & set(step.states)
+    bias = np.asarray(step.aux["layers1_e_score_correction_bias"])
+    ids, labels = _batch()
+    first = float(step.step(nd.array(ids, dtype="int32"),
+                            nd.array(labels, dtype="int32")))
+    second = float(step.step(nd.array(ids, dtype="int32"),
+                             nd.array(labels, dtype="int32")))
+    assert second < first
+    np.testing.assert_array_equal(
+        np.asarray(step.aux["layers1_e_score_correction_bias"]), bias)
+    rows = np.asarray(step.aux["layers1_expert_rows"])
+    assert rows.shape == (2, 4) and rows[0].sum() > 0
+    np.testing.assert_array_equal(rows[0], rows[1])
+
+
+def test_expert_rows_are_published_and_nothing_is_dropped():
+    telemetry.reset()
+    net, head = _build()
+    step = _step(net, head)
+    ids, labels = _batch(1)
+    step.step(nd.array(ids, dtype="int32"), nd.array(labels, dtype="int32"))
+    rows = zoo.publish_expert_rows(step.aux)
+    assert sorted(rows) == ["layers1", "layers3", "layers6", "layers8"]
+    want = np.asarray(step.aux["layers3_expert_rows"])[0]
+    np.testing.assert_array_equal(rows["layers3"], want)
+    for e in range(4):
+        assert telemetry.gauge("mx_moe_expert_rows", block="layers3",
+                               expert=str(e)).value == want[e]
+    assert telemetry.counter("mx_moe_dropped_rows_total").value == 0
+    # from the Gluon parameters too (an eager forward writes them)
+    with autograd.pause():
+        net(nd.array(ids, dtype="int32"))
+    eager = zoo.publish_expert_rows(
+        {k: v.data() for k, v in net.collect_params().items()})
+    assert eager["layers1"].sum() > 0
+    assert telemetry.counter("mx_moe_dropped_rows_total").value == 0
+
+
+def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():
+    net, head = _build()
+    w = _weights(net, head)
+    step = _step(net, head, dtype="bfloat16")
+    ids, labels = _batch(2)
+    got = float(step.step(nd.array(ids, dtype="int32"),
+                          nd.array(labels, dtype="int32")))
+    with jax.default_matmul_precision("highest"):
+        want = float(REF.lm_loss(w, ids, labels, CFG))
+    assert got == pytest.approx(want, rel=5e-3)
+
+
+class _OddRows(gluon.HybridBlock):
+    """Embedding whose row i holds i % 2: a bf16-rounded odd id (every
+    odd id over 256 rounds to an even one) reads 0 where it holds 1."""
+
+    def __init__(self, vocab, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.embed = gluon.nn.Embedding(vocab, 8, prefix="embed_")
+
+    def hybrid_forward(self, F, ids):
+        return self.embed(ids)
+
+
+class _MeanLoss:
+    def __call__(self, out, labels):
+        return [(out.mean(axis=-1) - labels).mean()]
+
+
+def test_integer_inputs_reach_the_embedding_unrounded():
+    """``ShardedTrainStep`` casts floating data inputs to the compute
+    dtype; integer ids must pass as they are, at a vocabulary (16,384)
+    whose odd ids bf16 cannot hold."""
+    vocab = 16384
+    net = _OddRows(vocab, prefix="")
+    net.initialize()
+    net.embed.weight.set_data(nd.array(
+        np.repeat((np.arange(vocab) % 2)[:, None], 8, 1).astype(np.float32)))
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    step = ShardedTrainStep(net, _MeanLoss(), mesh, optimizer="sgd", lr=0.0,
+                            momentum=0.0, dtype="bfloat16", n_data_inputs=2,
+                            data_specs=[P(), P()])
+    ids = np.array([[16383, 16381, 257, 8191, 1, 12345]], np.int32)
+    assert (ids % 2 == 1).all()
+    loss = float(step.step(nd.array(ids, dtype="int32"),
+                           nd.array(np.zeros(ids.shape, np.float32))))
+    assert loss == 1.0
+
+
+def test_three_adamw_steps_at_a_constant_rate_match_the_reference():
+    """The shared ``_apply_update`` rule under the decoder: bias
+    corrections folded into the rate, a decay the rate does not scale,
+    the router's bias never updated."""
+    net, head = _build()
+    w = _weights(net, head)
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    opt = dict(name="adamw", lr=3e-3, wd=3e-5, beta1=0.9, beta2=0.95,
+               epsilon=1e-8)
+    step = ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
+                            n_data_inputs=2, data_specs=[P(), P()],
+                            optimizer="adamw",
+                            **{k: v for k, v in opt.items() if k != "name"})
+    ids, labels = _batch(4)
+    got = [float(step.step(nd.array(ids, dtype="int32"),
+                           nd.array(labels, dtype="int32")))
+           for _ in range(3)]
+    sizes = dict(CFG, deployment={"expert_offset": CFG["expert_offset"]})
+    want = REF.train_losses(w, (ids, labels), sizes, opt, 3)
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    assert got[2] < got[1] < got[0]
