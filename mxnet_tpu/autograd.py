@@ -21,6 +21,8 @@ The fused fast path (whole-graph jax.grad) lives in CachedOp instead.
 """
 from __future__ import annotations
 
+import collections
+import sys
 import threading
 
 from typing import Dict, List, Optional, Sequence
@@ -117,7 +119,7 @@ class _Node:
     __slots__ = ("inputs", "vjp_fn", "out_avals", "n_rng", "n_extra",
                  "op_name", "fwd_fn", "rng_key", "input_ssa", "raw_inputs",
                  "fused_key", "fused_ok", "executed", "force_cb", "out_refs",
-                 "out_values")
+                 "out_values", "aux_in")
 
     def __init__(self, op_name, inputs, vjp_fn, out_avals, n_rng, n_extra,
                  fwd_fn=None, rng_key=None, raw_inputs=None, fused_key=None,
@@ -143,6 +145,7 @@ class _Node:
         self.force_cb = force_cb        # fills outputs + vjp_fn when forced
         self.out_refs = None            # weakrefs to out arrays (deferred only)
         self.out_values = None          # raw outputs after force (replay feed)
+        self.aux_in = ()                # input positions rewritten by extra outputs
         # SSA producers captured AT RECORD TIME: a later recorded
         # mutation rebinds inp._ag_node, so replay must not chase the
         # live pointer (it would feed post-mutation values to
@@ -175,7 +178,7 @@ def _record_node(op, inputs, out_arrays, vjp_fn, out_avals, n_rng=0,
 
 def _record_deferred_node(op_name, inputs, out_arrays, out_avals, n_rng,
                           n_extra, fwd_fn, rng_key, raw_inputs, fused_key,
-                          force_cb, aux_arrays=()):
+                          force_cb, aux_arrays=(), aux_in=()):
     """Record a node whose execution is DEFERRED: outputs are pending
     NDArrays filled either by node.force() (classic path / value read)
     or by the fused backward program (autograd.backward bulking —
@@ -186,6 +189,10 @@ def _record_deferred_node(op_name, inputs, out_arrays, out_avals, n_rng,
     node = _Node(op_name, inputs, None, out_avals, n_rng, n_extra,
                  fwd_fn=fwd_fn, rng_key=rng_key, raw_inputs=raw_inputs,
                  fused_key=fused_key, executed=False, force_cb=force_cb)
+    # where the mutated inputs sit among `inputs`: the fused step hands
+    # their old buffers to the program as the ones its extra outputs
+    # may take
+    node.aux_in = aux_in
     refs = []
     for i, arr in enumerate(out_arrays):
         arr._ag_node = node
@@ -345,12 +352,25 @@ def _build_fused(node_specs, head_specs, grad_slots, hg_present):
 
 
 def _build_fused_step(node_specs, head_specs, grad_slots, hg_present,
-                      upd_math):
+                      upd_math, owned_slots=None):
     """fwd+bwd+optimizer in ONE program (MXNET_TRAINER_FUSED_UPDATE):
     upd_math is the Trainer-supplied pure update — it receives
     (leaf_vals, grads, state_vals, hp_vals) and returns (new_ws,
     new_states) for its parameter rows. Gradients are still produced as
-    program outputs so Parameter.grad() keeps its post-step contents."""
+    program outputs so Parameter.grad() keeps its post-step contents.
+
+    ``owned_slots`` = (weight leaf slots, running-statistic leaf slots,
+    the other leaves' slots) builds the DONATING variant: the buffers the step
+    overwrites arrive apart from the leaves it only reads, as one
+    donated argument (last step's gradients, weights, optimizer states,
+    running statistics), so every output that replaces one of them
+    takes its buffer and the runtime allocates nothing for it. jax
+    pairs a donated input with the first unclaimed output of its shape
+    and dtype, so both sides keep one order — gradients, weights,
+    states, then the tape's own outputs — and the large buffers pair
+    with their own successors whatever the tape returns. The old
+    gradients are inputs nothing reads (``keep_unused``): they are
+    there to be overwritten."""
     compute = _fused_compute(node_specs, head_specs, grad_slots, hg_present)
 
     def runner(leaf_vals, rng_vals, hg_vals, state_vals, hp_vals):
@@ -359,11 +379,61 @@ def _build_fused_step(node_specs, head_specs, grad_slots, hg_present,
         return flat, grads, new_ws, new_states
 
     from .compilewatch import watched_jit
-    return watched_jit(runner, fn_label="autograd.fused_step",
+    instance = "tape[%d nodes]+update" % len(node_specs)
+    if owned_slots is None:
+        return watched_jit(runner, fn_label="autograd.fused_step",
+                           site="trainer.step",
+                           arg_names=["leaves", "rng", "head_grads",
+                                      "opt_states", "opt_hyper"],
+                           instance=instance)
+    w_slots, aux_slots, rest_slots = owned_slots
+    n_leaves = len(w_slots) + len(aux_slots) + len(rest_slots)
+
+    def donating(owned, rest_vals, rng_vals, hg_vals, hp_vals):
+        _old_grads, w_vals, state_vals, aux_vals = owned
+        leaf_vals = [None] * n_leaves
+        for slots, vals in ((w_slots, w_vals), (aux_slots, aux_vals),
+                            (rest_slots, rest_vals)):
+            for s, v in zip(slots, vals):
+                leaf_vals[s] = v
+        flat, grads, new_ws, new_states = runner(
+            leaf_vals, rng_vals, hg_vals, state_vals, hp_vals)
+        return grads, new_ws, new_states, flat
+
+    return watched_jit(donating, fn_label="autograd.fused_step",
                        site="trainer.step",
-                       arg_names=["leaves", "rng", "head_grads",
-                                  "opt_states", "opt_hyper"],
-                       instance="tape[%d nodes]+update" % len(node_specs))
+                       arg_names=["owned", "leaves", "rng", "head_grads",
+                                  "opt_hyper"],
+                       instance=instance + "/donating",
+                       donate_argnums=(0,), keep_unused=True)
+
+
+def _rest_slots(w_slots, aux_slots, n_leaves):
+    """The leaf slots a donating step only reads: batch, label,
+    whatever else the tape captured."""
+    taken = set(w_slots)
+    taken.update(aux_slots)
+    return [s for s in range(n_leaves) if s not in taken]
+
+
+def _publish_aliasing(runner, n_outputs):
+    """``mx_fused_step_outputs{kind=aliased|all}``: how many outputs of
+    the donating program just built take an input's buffer, from the
+    compiled program's own input-output alias table. Read once per
+    program, never per step."""
+    if not telemetry.enabled():
+        return
+    try:
+        aliased = 0
+        for compiled in runner.executables():
+            # the module's header line: input_output_alias={ {0}:
+            # (3, {}, may-alias), ... }
+            head = compiled.as_text().split("\n", 1)[0]
+            aliased = head.count("-alias)")
+        telemetry.gauge("mx_fused_step_outputs", kind="aliased").set(aliased)
+        telemetry.gauge("mx_fused_step_outputs", kind="all").set(n_outputs)
+    except Exception:
+        pass
 
 
 # ---------------------------------------------------------------------------
@@ -430,6 +500,32 @@ def flush_all_pending():
     flush_pending_step()
 
 
+class _StepGate:
+    """``NDArray._pending``-compatible gate (cf. ``engine.EngineGate``)
+    over what a fused step is rewriting, from its launch to its
+    write-back. A donated buffer is deleted at the launch and its
+    handle is rebound only when the program's outputs are back; a
+    reader on another thread in between (a serving scheduler on the
+    live parameters, a logging or checkpoint thread holding a handle)
+    must not meet the deleted array, so the step stands in as the
+    pending producer of every handle it donates and of every deferred
+    node it executes: ``_jax()`` on one waits here and then reads the
+    new value. The stepping thread itself passes."""
+
+    __slots__ = ("_done", "_owner")
+
+    def __init__(self):
+        self._done = threading.Event()
+        self._owner = threading.get_ident()
+
+    def force(self, _node=None):
+        if threading.get_ident() != self._owner:
+            self._done.wait()
+
+    def open(self):
+        self._done.set()
+
+
 class _PendingStep:
     """A built-but-unexecuted fused backward (all specs + captured
     values). execute() runs the plain fused-backward program;
@@ -437,10 +533,12 @@ class _PendingStep:
 
     __slots__ = ("skey", "node_specs", "head_specs", "grad_slots",
                  "hg_present", "leaf_arrays", "leaf_vals", "rng_vals",
-                 "hg_vals", "order", "token")
+                 "hg_vals", "order", "token", "aux_slots", "_gate",
+                 "_gated", "_todo")
 
     def __init__(self, skey, node_specs, head_specs, grad_slots, hg_present,
-                 leaf_arrays, leaf_vals, rng_vals, hg_vals, order):
+                 leaf_arrays, leaf_vals, rng_vals, hg_vals, order,
+                 aux_slots=()):
         self.skey = skey
         self.node_specs = node_specs
         self.head_specs = head_specs
@@ -452,6 +550,12 @@ class _PendingStep:
         self.hg_vals = hg_vals
         self.order = order
         self.token = None
+        # leaf slots of the mutated inputs (BatchNorm's running
+        # statistics) whose new values the tape returns, in the order
+        # it returns them — fixed by the tape's structure
+        self.aux_slots = aux_slots
+        self._gate = None       # execute_with_update .. release
+        self._gated = self._todo = ()
 
     def execute(self):
         runner = _FUSED_CACHE.get(self.skey)
@@ -463,34 +567,171 @@ class _PendingStep:
         flat, grads = runner(self.leaf_vals, self.rng_vals, self.hg_vals)
         self._finish(flat, grads)
 
-    def execute_with_update(self, upd_key, upd_math, state_vals, hp_vals):
+    def execute_with_update(self, upd_key, upd_math, state_vals, hp_vals,
+                            owners=None):
         """Run fwd+bwd+update as one program. upd_key must uniquely name
         upd_math's math (cache key alongside the tape structure);
         returns (new_ws, new_states) in upd_math's row order for the
-        caller to write back."""
-        key = (self.skey, upd_key)
-        runner = _FUSED_STEP_CACHE.get(key)
-        if runner is None:
-            runner = _build_fused_step(self.node_specs, self.head_specs,
-                                       self.grad_slots, self.hg_present,
-                                       upd_math)
-            _FUSED_STEP_CACHE[key] = runner
+        caller to write back.
+
+        ``owners`` = (leaf slot of each row's weight, the NDArrays
+        behind ``state_vals``, each row's gradient NDArray) names the
+        handles the caller rebinds to this step's outputs. Where each
+        of their buffers, and each running statistic's, is held by its
+        handle and this plan alone, the donating program runs and the
+        outputs take those buffers; where any has another holder (a
+        ``detach()``, a same-device copy, a serving session's capture)
+        this step runs the program that donates nothing, whole — and
+        leaves every handle on a buffer of its own making, so the next
+        step donates again. Counted per step in
+        ``mx_fused_step_total{donated=1|0}``."""
+        gate = self._gate = _StepGate()
+        # the nodes this launch executes: a reader that forces one on
+        # another thread meanwhile waits for the fill below instead of
+        # replaying the forward from inputs the launch may have taken
+        todo = {n: n.force_cb for n in self.order if not n.executed}
+        self._todo = todo
+        for n in todo:
+            n.force_cb = gate.force
+        owned = None if owners is None \
+            else self._owned(owners, state_vals, gate)
+        donated = owned is not None
+        key = (self.skey, upd_key, donated)
+        entry = _FUSED_STEP_CACHE.get(key)
+        built = entry is None
+        if built:
+            # with the program, the slots of the leaves it only reads
+            rest_slots = _rest_slots(owners[0], self.aux_slots,
+                                     len(self.leaf_vals)) if donated else None
+            entry = _FUSED_STEP_CACHE[key] = (_build_fused_step(
+                self.node_specs, self.head_specs, self.grad_slots,
+                self.hg_present, upd_math,
+                (owners[0], self.aux_slots, rest_slots)
+                if donated else None), rest_slots)
+        runner, rest_slots = entry
         telemetry.count_launch("gluon")
+        telemetry.count_event(telemetry.FUSED_STEP_COUNTER,
+                              donated="1" if donated else "0")
         # under the Trainer's step::update.launch: its .lookup / .call
-        flat, grads, new_ws, new_states = runner.call_phased(
-            "update.launch", self.leaf_vals, self.rng_vals, self.hg_vals,
-            state_vals, hp_vals)
-        self._finish(flat, grads)
+        if donated:
+            leaf_vals = self.leaf_vals
+            rest = [leaf_vals[s] for s in rest_slots]
+            grads, new_ws, new_states, flat = runner.call_phased(
+                "update.launch", owned, rest, self.rng_vals, self.hg_vals,
+                hp_vals)
+            if built:
+                _publish_aliasing(runner, len(grads) + len(new_ws)
+                                  + len(new_states) + len(flat))
+        else:
+            flat, grads, new_ws, new_states = runner.call_phased(
+                "update.launch", self.leaf_vals, self.rng_vals,
+                self.hg_vals, state_vals, hp_vals)
+        self._finish(flat, grads, todo=todo)
         return new_ws, new_states
 
-    def _finish(self, flat, grads, write_grads=True):
+    def release(self):
+        """The caller has rebound its handles to the step's outputs
+        (or the step failed): let waiting readers through. A handle
+        still gated here was never rebound; it keeps what it has."""
+        gate, self._gate = self._gate, None
+        if gate is None:
+            return
+        for h in self._gated:
+            p = h._pending
+            if p is not None and p[0] is gate:
+                h._pending = None
+        for n, cb in self._todo.items():
+            if not n.executed:          # the launch never got to it
+                n.force_cb = cb
+        self._gated = self._todo = ()
+        gate.open()
+
+    def _owned(self, owners, state_vals, gate):
+        """The buffers this step overwrites, grouped as the donating
+        program takes them — (last step's gradients, weights, optimizer
+        states, running statistics) — or None where any of them could
+        still be read after the step: donation deletes the array under
+        every holder, so each must be the current value of the handle
+        that is about to be rebound (no view, no engine reader pinned
+        to it), and its reference count must be what that handle, this
+        plan's own containers and the lists built here account for.
+        Every Python-level alias — another NDArray over the same
+        array, a raw value someone kept — shows up as one reference
+        more. (``jax.device_put`` onto the array's own device makes an
+        alias this cannot see: the repo's own such sites keep the
+        source array beside the copy.)"""
+        w_slots, state_arrs, grad_arrs = owners
+        leaf_arrays, leaf_vals = self.leaf_arrays, self.leaf_vals
+        aux_slots = self.aux_slots
+        handles = list(grad_arrs)
+        handles += [leaf_arrays[s] for s in w_slots]
+        handles += state_arrs
+        handles += [leaf_arrays[s] for s in aux_slots]
+        owned = ([g._buf for g in grad_arrs],
+                 [leaf_vals[s] for s in w_slots],
+                 state_vals,
+                 [leaf_vals[s] for s in aux_slots])
+        # a gradient takes the old gradient's buffer only if it has its
+        # shape and dtype; one that has not would be donated for nothing
+        # (in a generator's own scope: a loop variable left bound here
+        # would be a reference the count below does not expect)
+        if any(g.shape != w.shape or g.dtype != w.dtype
+               for g, w in zip(owned[0], owned[1])):
+            return None
+        vals = [v for group in owned for v in group]
+        held = collections.Counter(map(id, vals))
+        if len(held) != len(vals):
+            return None             # one array under two of the handles
+        for group in owned:
+            held.update(map(id, group))
+        held.update(map(id, leaf_vals))
+        for n in self.order:
+            if n.raw_inputs is not None:
+                held.update(map(id, n.raw_inputs))
+        first_aux = len(vals) - len(aux_slots)
+        refs = sys.getrefcount
+        gated = self._gated = []
+        for i, h in enumerate(handles):
+            v = vals[i]
+            if h._buf is not v or h._base is not None or h._read_pins \
+                    or h.stype != "default":
+                break
+            p = h._pending
+            if i >= first_aux:
+                # a statistic: pending on a node this launch fills
+                # (and gates); one it will not refill stays as it is
+                if p is None or \
+                        getattr(p[0], "force_cb", None) != gate.force:
+                    break
+            elif p is not None:
+                break
+            else:
+                # gate first, count after: whoever read the buffer
+                # before the gate holds a reference the count sees,
+                # whoever reads after it waits for the write-back
+                h._pending = (gate, i, v.aval)      # no reference to v
+                gated.append(h)
+            # beside what `held` counted: the handle, the local `v`
+            # and the call's own argument
+            if refs(v) != held[id(v)] + 3:
+                break
+        else:
+            return owned
+        for h in gated:
+            h._pending = None
+        self._gated = ()
+        return None
+
+    def _finish(self, flat, grads, write_grads=True, todo=()):
         # fill pending outputs of still-deferred nodes + stash replay
         # values (a node forced in the deferral window just skips its
-        # fill — the replayed values are identical by construction)
+        # fill — the replayed values are identical by construction).
+        # `todo`: nodes a gated launch took on; a reader that forced
+        # one meanwhile marked it executed and waits for this fill
         off = 0
         for n, sp in zip(self.order, self.node_specs):
             n_out = sp[3]
-            if not n.executed:
+            if not n.executed or n in todo:
                 n.executed = True
                 n.force_cb = None
                 _fill_pending(n, flat[off:off + n_out])
@@ -595,6 +836,7 @@ def _try_fused_backward(heads, head_grads, order):
     leaf_vals = []
     node_specs = []
     rng_vals = []
+    aux_slots = []
     for n in order:
         ins = []
         for inp, ssa, rawv in zip(n.inputs, n.input_ssa, n.raw_inputs):
@@ -636,6 +878,7 @@ def _try_fused_backward(heads, head_grads, order):
                            len(n.out_avals)))
         if n.n_rng:
             rng_vals.append(n.rng_key)
+        aux_slots += [ins[j][1] for j in n.aux_in if ins[j][0] == "l"]
 
     head_specs = []
     for h in heads:
@@ -654,7 +897,7 @@ def _try_fused_backward(heads, head_grads, order):
             len(leaf_arrays), hg_present)
     plan = _PendingStep(skey, tuple(node_specs), tuple(head_specs),
                         grad_slots, hg_present, leaf_arrays, leaf_vals,
-                        rng_vals, hg_vals, list(order))
+                        rng_vals, hg_vals, list(order), tuple(aux_slots))
     if _ARM_TOKEN[0] is not None and _ARM_LEAF_IDS[0] and \
             _ARM_LEAF_IDS[0] <= {id(leaf_arrays[s]) for s in grad_slots}:
         # this tape IS the armed Trainer's loop (its parameters are the

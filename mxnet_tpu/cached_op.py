@@ -264,7 +264,8 @@ class CachedOp:
                 fwd_fn=self._train_flat,
                 rng_key=rng_args[0] if rng_args else None,
                 raw_inputs=raws, fused_key=("cop", self._uid),
-                force_cb=self._force_node, aux_arrays=aux_arrays)
+                force_cb=self._force_node, aux_arrays=aux_arrays,
+                aux_in=tuple(self._aux_idx))
             return out_arrays if len(out_arrays) > 1 else out_arrays[0]
 
         raw = [a._jax() for a in inputs]

@@ -268,7 +268,8 @@ class WatchedJit:
                  static_repr: Optional[str] = None,
                  exec_via_jit: bool = False,
                  donate_argnums: Sequence[int] = (),
-                 flops_factor: float = 1.0):
+                 flops_factor: float = 1.0,
+                 keep_unused: bool = False):
         # donated arg slots flow into jax.jit (XLA may alias those
         # input buffers into outputs — the serving path's in/out
         # staging reuse, ISSUE 12) and into the Level-2 graph hook,
@@ -291,7 +292,11 @@ class WatchedJit:
         # collective-interleave check consumes (staticcheck/race.py) —
         # sticky across signatures, never cleared
         self.issues_collectives = False
-        self._jit = jax.jit(fn, donate_argnums=self.donate_argnums)
+        # keep_unused: a donated input the program never reads (a buffer
+        # handed over only to be overwritten) stays an input, so an
+        # output can take it
+        self._jit = jax.jit(fn, donate_argnums=self.donate_argnums,
+                            keep_unused=keep_unused)
         self.fn_label = fn_label
         self.site = site
         self.instance = instance or fn_label
@@ -322,6 +327,11 @@ class WatchedJit:
     @property
     def recompiles(self) -> int:
         return self._recompiles
+
+    def executables(self) -> List[Any]:
+        """The AOT executables this wrapper serves calls from (none for
+        a signature that runs through the plain jit)."""
+        return [e for e in self._cache.values() if hasattr(e, "as_text")]
 
     # -- dispatch -------------------------------------------------------
     def __call__(self, *args):
@@ -568,13 +578,14 @@ def watched_jit(fn: Callable, fn_label: str, site: str,
                 static_repr: Optional[str] = None,
                 exec_via_jit: bool = False,
                 donate_argnums: Sequence[int] = (),
-                flops_factor: float = 1.0) -> WatchedJit:
+                flops_factor: float = 1.0,
+                keep_unused: bool = False) -> WatchedJit:
     """Wrap ``fn`` for watched jit execution (see module docstring)."""
     return WatchedJit(fn, fn_label, site, arg_names=arg_names,
                       instance=instance, static_repr=static_repr,
                       exec_via_jit=exec_via_jit,
                       donate_argnums=donate_argnums,
-                      flops_factor=flops_factor)
+                      flops_factor=flops_factor, keep_unused=keep_unused)
 
 
 # ---------------------------------------------------------------------------

@@ -205,7 +205,15 @@ define("MXNET_TRAINER_FUSED_UPDATE", bool, True,
        "optimizer program. Between backward() and step() gradients "
        "are deferred; reading them through Parameter.grad()/"
        "list_grad() flushes the pending program first "
-       "(docs/KERNELS.md).")
+       "(docs/KERNELS.md). The program donates the buffers the step "
+       "overwrites (weights, momenta, the last step's gradients, "
+       "BatchNorm's running statistics) whenever their handles are "
+       "their only holders, so its outputs take them and nothing is "
+       "allocated; a step that finds another holder (a detach(), a "
+       "same-device copy, a serving session's capture) runs the "
+       "variant that donates nothing, once, and every handle taken "
+       "before a step stays readable after it (docs/TRAINING.md "
+       "'What the fused step donates'; mx_fused_step_total).")
 define("MXNET_SCAN_STEPS", int, 1,
        "Whole-loop compilation (mxnet_tpu/scan.py, docs/TRAINING.md): "
        "fuse K consecutive training steps into ONE compiled program "

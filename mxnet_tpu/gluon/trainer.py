@@ -571,9 +571,11 @@ class Trainer:
             r.flush()
 
     def _scan_note_pre_update(self, prep):
-        """Pre-update weight aliases for a chunk about to write back —
-        the boundary analogue of the per-step fused capture (sampling
-        moves to the chunk boundary: one capture per K steps)."""
+        """Pre-update weight aliases, for modelwatch's update norms, on
+        a sampled step: taken by the per-step fused consume BEFORE its
+        launch (an alias is a second holder, so that step donates
+        nothing and the aliases stay readable) and by a K-step chunk
+        about to write back (one capture per K steps)."""
         mw = self._modelwatch
         if mw is None or not mw.sampling:
             return None
@@ -727,33 +729,40 @@ class Trainer:
                 items = prep.items
                 mom_rows, plain_rows = prep.mom_rows, prep.plain_rows
                 upd_math = self._make_upd_math(prep)
-                state_vals = [items[k][3]._jax() for k in mom_rows]
+                state_arrs = [items[k][3] for k in mom_rows]
+                state_vals = [a._jax() for a in state_arrs]
                 hp_vals = (jnp.asarray(prep.lrs[list(mom_rows)]),
                            jnp.asarray(prep.wds[list(mom_rows)]),
                            jnp.asarray(prep.lrs[list(plain_rows)]),
                            jnp.asarray(prep.wds[list(plain_rows)]))
+                # what this step rebinds: each row's weight (by leaf
+                # slot), momentum and gradient. The program takes their
+                # buffers for its outputs when nothing else holds them
+                owners = ([it[5] for it in items], state_arrs,
+                          [it[2]._grad for it in items])
+                caps = self._scan_note_pre_update(prep)
         if prep is None:
             plan.execute()
             return False
-        with telemetry.phase("update.launch"):
-            new_ws, new_moms = plan.execute_with_update(
-                prep.upd_key, upd_math, state_vals, hp_vals)
-        with telemetry.phase("update.writeback"):
-            self._write_back_fused(prep, new_ws, new_moms)
+        try:
+            with telemetry.phase("update.launch"):
+                new_ws, new_moms = plan.execute_with_update(
+                    prep.upd_key, upd_math, state_vals, hp_vals, owners)
+            with telemetry.phase("update.writeback"):
+                self._write_back_fused(prep, new_ws, new_moms, caps)
+        finally:
+            # readers on other threads waited at the step's gate from
+            # the launch on: every handle reads its new value now
+            plan.release()
         return True
 
-    def _write_back_fused(self, prep, new_ws, new_moms):
+    def _write_back_fused(self, prep, new_ws, new_moms, caps=None):
         """Rebind the parameters and momenta to the fused step's
-        outputs (``step::update.writeback``)."""
+        outputs (``step::update.writeback``). ``caps``: modelwatch's
+        pre-update weight aliases of a sampled step — they feed both
+        the update-norm reduction and the param-norm side of the
+        fused-path stats."""
         items, mom_rows = prep.items, prep.mom_rows
-        mw = self._modelwatch
-        caps = None
-        if mw is not None and mw.sampling:
-            # pre-update weight aliases, captured before the write-back
-            # rebinds the buffers — feeds both the update-norm
-            # reduction and the param-norm side of the fused-path stats
-            caps = mw.note_pre_update(
-                [(it[1].name, it[2]) for it in items])
         for k, (i, param, data_arr, state, _gp, _ws) in enumerate(items):
             data_arr._set_jax(new_ws[k])
         for mi, k in enumerate(mom_rows):
@@ -762,7 +771,7 @@ class Trainer:
             # defer=False: the fused path's read happens AFTER this
             # update, so the vector rides the same step's report
             # instead of the classic one-step-stale stash
-            unorm = mw.note_post_update(caps, defer=False)
+            unorm = self._modelwatch.note_post_update(caps, defer=False)
             self._mw_fused_caps = (caps, unorm)
 
     def allreduce_grads(self):

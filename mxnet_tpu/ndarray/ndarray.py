@@ -102,7 +102,17 @@ class NDArray:
                 self._cache = base._jax()[self._index]
                 self._cache_ver = base._version
             return self._cache
-        return self._buf
+        buf = self._buf
+        p = self._pending
+        if p is not None:
+            # gated between the two reads: a fused step on another
+            # thread is about to give this buffer to its program
+            # (autograd._StepGate gates before it counts references,
+            # so a value read before the gate is one it sees held).
+            # Wait for its write-back and read again.
+            p[0].force()
+            buf = self._buf
+        return buf
 
     def _set_jax(self, buf):
         """Rebind to a new buffer (the mutation primitive). The pending
@@ -718,6 +728,22 @@ def _place(buf, ctx: Context):
     if hasattr(buf, "devices") and buf.devices() == {dev}:
         return buf
     return jax.device_put(buf, dev)
+
+
+def _shares_buffer(placed, buf) -> bool:
+    """Whether ``placed`` (a ``jax.device_put`` of ``buf``) reads
+    ``buf``'s own device memory: a put onto the array's device, or
+    replicated over a mesh that holds it, makes a second array over
+    the same buffer. A holder of such a copy keeps ``buf`` beside it:
+    the Trainer's fused step donates only buffers whose reference
+    count shows no second holder (``autograd._PendingStep._owned``),
+    and donation would delete the copy along with the source."""
+    try:
+        mine = buf.unsafe_buffer_pointer()
+        return any(s.data.unsafe_buffer_pointer() == mine
+                   for s in placed.addressable_shards)
+    except Exception:
+        return True
 
 
 # ---------------------------------------------------------------------------

@@ -51,7 +51,7 @@ from ..base import MXNetError
 from .. import telemetry
 from .. import tracing
 from ..context import current_context
-from ..ndarray.ndarray import _place
+from ..ndarray.ndarray import _place, _shares_buffer
 from .. import random as rand_mod
 from .bucketing import BucketLadder
 
@@ -253,13 +253,21 @@ class InferenceSession:
         if self._mesh is None:
             return
         from jax.sharding import NamedSharding
-        out = []
+        out, shared = [], []
         for _i, name in self._param_pos:
             p = self._all_params[name]
             buf = p.data(p.list_ctx()[0])._jax()
-            out.append(jax.device_put(
-                buf, NamedSharding(self._mesh, self._spec_for(name))))
+            placed = jax.device_put(
+                buf, NamedSharding(self._mesh, self._spec_for(name)))
+            out.append(placed)
+            if _shares_buffer(placed, buf):
+                shared.append(buf)
         self._sharded_params = out
+        # the parameters' own arrays wherever a capture reads their
+        # memory (a replicated spec on a mesh that holds the
+        # parameter's device): a training step in this process then
+        # sees a second holder and leaves these buffers alone
+        self._shared_sources = shared
 
     def _weight_args(self) -> List:
         if self._mesh is not None:

@@ -430,6 +430,19 @@ def _check_donation(jaxpr, donated, mk) -> List[Finding]:
 # ---------------------------------------------------------------------------
 # the compilewatch hook (one gate read on the compile MISS path only)
 # ---------------------------------------------------------------------------
+def _donated_invars(wrapper, traced) -> Sequence[int]:
+    """The jaxpr inputs a watched program donates. ``donate_argnums``
+    counts arguments and the jaxpr's inputs are their leaves: an
+    argument that is a list of buffers (the fused step's owned group)
+    donates every one of them."""
+    try:
+        import jax
+        return [i for i, info in enumerate(
+            jax.tree_util.tree_leaves(traced.args_info)) if info.donated]
+    except Exception:
+        return getattr(wrapper, "donate_argnums", ()) or ()
+
+
 def _hook(wrapper, traced, signature, compiled=None) -> None:
     """Called by WatchedJit._compile_and_call once per new signature.
     Any failure in here must never poison the compile (the caller
@@ -444,7 +457,7 @@ def _hook(wrapper, traced, signature, compiled=None) -> None:
         found = check_closed_jaxpr(
             cj, wrapper.fn_label, instance=wrapper.instance,
             arg_names=wrapper._arg_names,
-            donated=getattr(wrapper, "donate_argnums", ()) or ())
+            donated=_donated_invars(wrapper, traced))
         with _LOCK:
             _CHECKED[0] += 1
             for f in found:

@@ -356,7 +356,7 @@ class _StepLog:
     STEP_LOG_STEPS = 512
     OPEN_SPAN_CAP = 4096
 
-    __slots__ = ("open", "dropped", "closed", "launches")
+    __slots__ = ("open", "dropped", "closed", "counted")
 
     def __init__(self):
         self.clear()
@@ -365,7 +365,7 @@ class _StepLog:
         self.open: List[tuple] = []
         self.dropped = 0
         self.closed = collections.deque(maxlen=self.STEP_LOG_STEPS)
-        self.launches: Dict[str, float] = {}
+        self.counted: Dict[tuple, float] = {}    # totals at the last close
 
 
 class _OpenSpans(threading.local):
@@ -377,6 +377,13 @@ _STEPLOG = _StepLog()
 _OPEN_SPANS = _OpenSpans()
 LAUNCH_COUNTER = "mx_program_launches_total"
 LAUNCH_PATHS = ("gluon", "sharded")
+FUSED_STEP_COUNTER = "mx_fused_step_total"
+# the counters mark_step closes into the step log: a step's record key
+# -> (counter, its label, the label's values)
+_STEP_COUNTERS = {
+    "launches": (LAUNCH_COUNTER, "path", LAUNCH_PATHS),
+    "fused_steps": (FUSED_STEP_COUNTER, "donated", ("1", "0")),
+}
 
 
 class span:
@@ -631,16 +638,18 @@ def _close_step(step: int):
     log = _STEPLOG
     spans, log.open = log.open, []
     dropped, log.dropped = log.dropped, 0
-    launches = {}
-    for path in LAUNCH_PATHS:
-        m = _METRICS.get((LAUNCH_COUNTER, (("path", path),)))
-        if m is not None:
-            total = m.get()
-            delta = total - log.launches.get(path, 0.0)
-            log.launches[path] = total
-            if delta:
-                launches[path] = delta
-    log.closed.append((step, spans, launches, dropped))
+    counts = {}
+    for key, (name, label, values) in _STEP_COUNTERS.items():
+        counts[key] = got = {}
+        for value in values:
+            m = _METRICS.get((name, ((label, value),)))
+            if m is not None:
+                total = m.get()
+                delta = total - log.counted.get((name, value), 0.0)
+                log.counted[(name, value)] = total
+                if delta:
+                    got[value] = delta
+    log.closed.append((step, spans, counts, dropped))
 
 
 def step_log(n: Optional[int] = None) -> List[dict]:
@@ -649,7 +658,9 @@ def step_log(n: Optional[int] = None) -> List[dict]:
     before it), ``spans`` {name: {``count``, ``seconds`` (summed
     durations), ``self_seconds`` (``seconds`` minus what the spans
     opened directly inside it cover)}}, ``launches`` {path: programs
-    handed to the runtime during the step}, ``events`` (the raw
+    handed to the runtime during the step}, ``fused_steps`` {"1" | "0":
+    fused Gluon steps taken with / without donated buffers,
+    ``mx_fused_step_total``}, ``events`` (the raw
     ``(name, start, end, parent, step)`` tuples, in order of exit;
     ``time.perf_counter`` seconds) and ``dropped`` (spans the open
     step refused at its cap). The ring holds
@@ -659,7 +670,7 @@ def step_log(n: Optional[int] = None) -> List[dict]:
     if n is not None:
         closed = closed[-n:] if n > 0 else []
     out = []
-    for step, events, launches, dropped in closed:
+    for step, events, counts, dropped in closed:
         spans: Dict[str, dict] = {}
         for name, start, end, _parent, _step in events:
             row = spans.setdefault(
@@ -671,8 +682,8 @@ def step_log(n: Optional[int] = None) -> List[dict]:
             if parent in spans:
                 spans[parent]["self_seconds"] -= end - start
         out.append({"step": step, "spans": spans,
-                    "launches": dict(launches), "events": list(events),
-                    "dropped": dropped})
+                    "events": list(events), "dropped": dropped,
+                    **{key: dict(got) for key, got in counts.items()}})
     return out
 
 

@@ -651,6 +651,51 @@ class TestGraphHook:
         assert telemetry.counter("mx_staticcheck_findings_total",
                                  rule="graph-f32-promotion").get() > 0
 
+    def test_real_fused_step_program_donates_what_it_overwrites(self):
+        """The Gluon loop's own ``autograd.fused_step`` program, as the
+        hook receives it: the variant that donates its weights, momenta
+        and gradients is clean; the one a held alias falls back to is
+        what the rule is written for."""
+        from mxnet_tpu import gluon
+        net = nn.HybridSequential()
+        net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
+        net.initialize()
+        net.hybridize()
+        lf = gluon.loss.SoftmaxCrossEntropyLoss()
+        lf.hybridize()
+        tr = gluon.Trainer(net.collect_params(), "sgd",
+                           {"learning_rate": 0.1, "momentum": 0.9})
+        x = nd.ones((8, 12))
+        y = nd.array(np.arange(8) % 4, dtype="int32")
+
+        def step():
+            with autograd.record():
+                loss = lf(net(x), y)
+            loss.backward()
+            tr.step(8)
+
+        def fused_step_findings():
+            return [f for f in staticcheck.graph_findings()
+                    if f.rule == "graph-nondonated-update-param"
+                    and "autograd.fused_step" in f.path]
+
+        try:
+            for _ in range(3):
+                step()
+            ran = [p for p in compilewatch.programs()
+                   if p["fn"] == "autograd.fused_step"]
+            assert [p["instance"] for p in ran] == \
+                ["tape[2 nodes]+update/donating"]
+            assert fused_step_findings() == []
+            held = next(iter(net.collect_params().values())).data().detach()
+            step()
+            assert held.asnumpy().shape == held.shape
+            assert [f.path for f in fused_step_findings()] == \
+                ["autograd.fused_step (tape[2 nodes]+update)"]
+        finally:
+            autograd.disarm_fused_update()
+            autograd.flush_pending_step()
+
 
 # ===========================================================================
 # Level 3 — engine race detector
