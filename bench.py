@@ -34,25 +34,9 @@ def main():
     device = runtime.require_accelerator()
     runtime.enable_compile_cache()
 
-    # --scan-steps K: run the headline Gluon loop with MXNET_SCAN_STEPS=K
-    # (whole-loop compilation, mxnet_tpu/scan.py). ResNet-50's BatchNorm
-    # keeps cross-step aux state, so the chunk runner force-bails to the
-    # per-step path with one warning — the flag then measures "no
-    # regression from the scan plumbing" rather than the fused-chunk win
-    # (tools/loop_micro.py measures that on a BN-free model).
-    argv = list(sys.argv[1:])
-    scan_steps = 1
-    if "--scan-steps" in argv:
-        i = argv.index("--scan-steps")
-        scan_steps = int(argv[i + 1])
-        del argv[i:i + 2]
-    os.environ["MXNET_SCAN_STEPS"] = str(scan_steps)
+    argv = sys.argv[1:]
     batch = int(argv[0]) if len(argv) > 0 else 128
     steps = int(argv[1]) if len(argv) > 1 else 16
-    if scan_steps > 1 and steps % scan_steps:
-        # whole chunks only: a partial tail would flush sequentially and
-        # skew the paired K-vs-1 comparison
-        steps = (steps // scan_steps + 1) * scan_steps
 
     net = resnet50_v1()
     net.initialize(init=mx.initializer.MSRAPrelu())
@@ -260,7 +244,6 @@ def main():
         "comm_bandwidth": comm,
         "grad_noise_scale": noise_scale,
         "modelwatch_anomalies": mw_anomalies,
-        "scan_steps": scan_steps,
         "optimizer_state_bytes": trainer.optimizer_state_bytes(),
         "zero": isinstance(trainer._zero, _zero_mod.ZeroEngine),
         "quantize": _qcfg.mode if _qcfg is not None else "off",

@@ -257,11 +257,6 @@ def _release_cop(uid):
                  if any(sp[0] == ("cop", uid) for sp in k[0][0])]
     for k in dead_step:
         del _FUSED_STEP_CACHE[k]
-    for cb in _COP_EVICT_HOOKS:
-        try:
-            cb(uid)
-        except Exception:
-            pass
 
 
 def _fused_enabled():
@@ -457,47 +452,6 @@ _FUSED_STEP_CACHE: Dict = {}
 _ARM_TOKEN = [None]
 _ARM_LEAF_IDS = [frozenset()]
 _PENDING = [None]
-# K-step scan layer (mxnet_tpu/scan.py) integration points: a drain
-# callback for gradient readers (Parameter.grad must see the buffered
-# chunk's updates+grads before reporting), a CachedOp-eviction hook so
-# the scan program cache releases tapes with the other caches, and a
-# counter of cross-tape forces (a tape whose inputs keep referencing a
-# PREVIOUS tape's deferred outputs — BatchNorm running stats — replays
-# that forward eagerly every step; the scan runner reads this to bail)
-_SCAN_FLUSHERS: list = []
-_COP_EVICT_HOOKS: list = []
-_XTAPE_FORCES = [0]
-
-
-def register_scan_flusher(cb):
-    _SCAN_FLUSHERS.append(cb)
-
-
-def register_cop_evict_hook(cb):
-    _COP_EVICT_HOOKS.append(cb)
-
-
-def cross_tape_forces() -> int:
-    return _XTAPE_FORCES[0]
-
-
-def flush_scan_chunks():
-    """Drain every buffered K-step scan chunk (each buffered plan runs
-    its fused fwd+bwd+update sequentially — bit-parity with the
-    per-step path by construction). Cheap no-op when nothing is
-    buffered."""
-    for cb in _SCAN_FLUSHERS:
-        cb()
-
-
-def flush_all_pending():
-    """Everything a gradient reader needs executed before the read:
-    buffered scan chunks first (they are OLDER steps, and their
-    updates were already requested by Trainer.step), then any plan
-    still stashed between backward() and step() (plain backward — its
-    step was never taken)."""
-    flush_scan_chunks()
-    flush_pending_step()
 
 
 class _StepGate:
@@ -722,7 +676,7 @@ class _PendingStep:
         self._gated = ()
         return None
 
-    def _finish(self, flat, grads, write_grads=True, todo=()):
+    def _finish(self, flat, grads, todo=()):
         # fill pending outputs of still-deferred nodes + stash replay
         # values (a node forced in the deferral window just skips its
         # fill — the replayed values are identical by construction).
@@ -736,16 +690,6 @@ class _PendingStep:
                 n.force_cb = None
                 _fill_pending(n, flat[off:off + n_out])
             off += n_out
-
-        if not write_grads:
-            # scanned-chunk interior step (mxnet_tpu/scan.py): every
-            # buffered plan's grad_req is 'write', so only the LAST
-            # step's gradients survive — the chunk retirement writes
-            # those once and skips the K-1 dead intermediate writes
-            for n in self.order:
-                n.raw_inputs = None
-                n.vjp_fn = None
-            return
 
         # leaf gradient write-back (same req semantics as the classic
         # walk); a var captured under two different values occupies two
@@ -847,10 +791,7 @@ def _try_fused_backward(heads, head_grads, order):
                 pi = node_index.get(id(prod))
                 if pi is None:
                     # producer outside this tape slice — force it and
-                    # feed the concrete value as a leaf (counted: the
-                    # scan runner reads this to detect cross-step aux
-                    # state like BatchNorm running stats and bail)
-                    _XTAPE_FORCES[0] += 1
+                    # feed the concrete value as a leaf
                     prod.force()
                     rawv = prod.out_values[slot]
                     pend = False

@@ -260,7 +260,7 @@ class WatchedJit:
                  "_flops_by_sig", "_last_sig", "_recompiles",
                  "_diff_history", "_warned", "donate_argnums",
                  "expected_signatures", "issues_collectives",
-                 "flops_factor", "__weakref__")
+                 "__weakref__")
 
     def __init__(self, fn: Callable, fn_label: str, site: str,
                  arg_names: Optional[Sequence[str]] = None,
@@ -268,7 +268,6 @@ class WatchedJit:
                  static_repr: Optional[str] = None,
                  exec_via_jit: bool = False,
                  donate_argnums: Sequence[int] = (),
-                 flops_factor: float = 1.0,
                  keep_unused: bool = False):
         # donated arg slots flow into jax.jit (XLA may alias those
         # input buffers into outputs — the serving path's in/out
@@ -280,13 +279,6 @@ class WatchedJit:
         # warn_n recompiles BEYOND the planned set — a bucket miss past
         # the ladder still storms, a deliberate warmup never does
         self.expected_signatures = 0
-        # MFU-credit multiplier for multi-step programs: XLA's cost
-        # analysis counts a lax.scan body ONCE regardless of trip
-        # count (measured: a K=8 scan reports ~1.09x the single-step
-        # FLOPs), so a program that retires K optimizer steps per
-        # execution sets flops_factor=K to keep mx_executed_flops_total
-        # (the mx_mfu numerator) honest
-        self.flops_factor = float(flops_factor)
         # set True by the Level-4 SPMD hook when a compiled signature's
         # HLO contains cross-device collectives: the mark the engine's
         # collective-interleave check consumes (staticcheck/race.py) —
@@ -472,7 +464,7 @@ class WatchedJit:
                 self._cache[sig] = _DEGRADED
             self._last_sig = sig
             if flops:
-                self._flops_by_sig[sig] = flops * self.flops_factor
+                self._flops_by_sig[sig] = flops
                 self._count_exec(sig)     # the miss call executed too
 
             record = {
@@ -578,14 +570,13 @@ def watched_jit(fn: Callable, fn_label: str, site: str,
                 static_repr: Optional[str] = None,
                 exec_via_jit: bool = False,
                 donate_argnums: Sequence[int] = (),
-                flops_factor: float = 1.0,
                 keep_unused: bool = False) -> WatchedJit:
     """Wrap ``fn`` for watched jit execution (see module docstring)."""
     return WatchedJit(fn, fn_label, site, arg_names=arg_names,
                       instance=instance, static_repr=static_repr,
                       exec_via_jit=exec_via_jit,
                       donate_argnums=donate_argnums,
-                      flops_factor=flops_factor, keep_unused=keep_unused)
+                      keep_unused=keep_unused)
 
 
 # ---------------------------------------------------------------------------

@@ -214,30 +214,12 @@ define("MXNET_TRAINER_FUSED_UPDATE", bool, True,
        "variant that donates nothing, once, and every handle taken "
        "before a step stays readable after it (docs/TRAINING.md "
        "'What the fused step donates'; mx_fused_step_total).")
-define("MXNET_SCAN_STEPS", int, 1,
-       "Whole-loop compilation (mxnet_tpu/scan.py, docs/TRAINING.md): "
-       "fuse K consecutive training steps into ONE compiled program "
-       "via lax.scan over the fused fwd+bwd+update step "
-       "(MXNET_TRAINER_FUSED_UPDATE), with params, grads and "
-       "optimizer state carried on device across iterations (donated "
-       "in-place — the whole chunk runs at zero host traffic) and "
-       "guard/modelwatch/telemetry sampling moved to the chunk "
-       "boundary (one host sync per K steps; a skip_step GradGuard "
-       "verdict is computed in-program as a where-select skip and "
-       "surfaced as a K-vector output). 1 (default) keeps the "
-       "per-step path; ineligible configs (non-SGD, clip/zero/raise "
-       "guard policies, kvstore, multi-device, cross-step aux state "
-       "like BatchNorm running stats) fall back to per-step with one "
-       "warning. Checkpoints still land between scanned chunks "
-       "(states_blob/save flush the partial chunk) with bit-parity "
-       "on resume.")
 define("MXNET_PREFETCH_DEPTH", int, 2,
        "DataLoader device double-buffer: stage up to this many "
        "upcoming batches into device memory ahead of the consumer "
-       "(gluon/data/dataloader.py), so a scanned K-step chunk "
-       "(MXNET_SCAN_STEPS) finds its batches already resident in HBM "
-       "and the host upload overlaps the previous chunk's compute. 0 "
-       "disables read-ahead (batches are uploaded on demand).")
+       "(gluon/data/dataloader.py), so the host upload overlaps the "
+       "previous steps' compute. 0 disables read-ahead (batches are "
+       "uploaded on demand).")
 define("MXNET_ZERO", bool, False,
        "ZeRO-style weight-update sharding for the data-parallel Gluon "
        "Trainer (gluon/zero.py; arxiv 2004.13336): gradients are "

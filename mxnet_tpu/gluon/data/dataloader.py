@@ -459,10 +459,7 @@ class DataLoader:
         buffer handle here starts the DMA without blocking, so by the
         time the consumer reaches a read-ahead batch its arrays are
         already resident in device memory and the upload overlapped
-        the previous steps' compute. This is the device double-buffer
-        feeding the K-step scanned chunk (MXNET_SCAN_STEPS): the chunk
-        launches with all K batches on device, zero host traffic
-        mid-program."""
+        the previous steps' compute."""
         if isinstance(batch, NDArray):
             batch._jax()
         elif isinstance(batch, (list, tuple)):

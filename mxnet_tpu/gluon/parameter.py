@@ -177,11 +177,10 @@ class Parameter:
         if self._grad is None:
             raise RuntimeError("Parameter %s grad_req='null'" % self.name)
         # fused-update deferral (MXNET_TRAINER_FUSED_UPDATE): a stashed
-        # backward not yet consumed by Trainer.step() — and any buffered
-        # K-step scan chunk (MXNET_SCAN_STEPS) — must run before
+        # backward not yet consumed by Trainer.step() must run before
         # gradients are observed; cheap no-op otherwise
         from .. import autograd as _ag
-        _ag.flush_all_pending()
+        _ag.flush_pending_step()
         if ctx is None:
             return next(iter(self._grad.values()))
         return self._grad[ctx]
@@ -190,7 +189,7 @@ class Parameter:
         if self._grad is None:
             raise RuntimeError("Parameter %s grad_req='null'" % self.name)
         from .. import autograd as _ag
-        _ag.flush_all_pending()
+        _ag.flush_pending_step()
         return list(self._grad.values())
 
     def list_ctx(self) -> List[Context]:

@@ -8,6 +8,7 @@ kernel per device replica.
 """
 from __future__ import annotations
 
+from collections import namedtuple
 from typing import Dict, List, Optional
 
 from ..base import MXNetError
@@ -17,6 +18,18 @@ from .. import telemetry
 from .parameter import Parameter, ParameterDict
 
 __all__ = ["Trainer"]
+
+# What the fused consume derives from the optimizer before its launch
+# (Trainer._prep_fused_plan).
+FusedPrep = namedtuple("FusedPrep", [
+    "items",        # [(i, param, data_arr, state, grad_pos, ws_slot)]
+    "rows",         # ((grad_pos, ws_slot, has_mom), ...)
+    "gdt",          # grad dtypes per row
+    "mom_rows", "plain_rows",
+    "upd_key",      # ("sgd", momentum, clip, rescale, rows, gdt)
+    "lrs", "wds",   # np.float32 per row
+    "momentum", "clip", "rescale",
+])
 
 
 class Trainer:
@@ -50,8 +63,6 @@ class Trainer:
         self._mw_fused_caps = None     # fused-path pre-update captures
         self._fused_armed = False      # MXNET_TRAINER_FUSED_UPDATE state
         self._fused_structural_bail = False
-        self._scan = None              # MXNET_SCAN_STEPS chunk runner
-        self._scan_warned = False      # eligibility notice, once
         self._zero = None              # MXNET_ZERO engine: None=unresolved,
         self._zero_bailed = False      # False=disabled, else zero.ZeroEngine
 
@@ -226,30 +237,15 @@ class Trainer:
         record of ``telemetry.step_log``."""
         with telemetry.phase("update") as span:
             useful = self._step(batch_size, ignore_stale_grad, span)
-        if useful is not None:
-            telemetry.mark_step(useful=useful)
+        telemetry.mark_step(useful=useful)
 
     def _step(self, batch_size, ignore_stale_grad, span):
         """``step`` inside its span. Returns whether the step was
-        useful (False: a guard dropped the update), or None where the
-        step is marked elsewhere (a K-step chunk marks itself)."""
+        useful (False: a guard dropped the update)."""
         if not self._kv_initialized:
             self._contexts = self._check_contexts()
             self._init_kvstore()
         self._optimizer.rescale_grad = self._scale / batch_size
-        if self._scan is None and not self._scan_warned:
-            from .. import scan as scan_mod
-            if scan_mod.steps() > 1 and not self._fused_update_eligible():
-                # eligibility-ladder notice, once per Trainer: K-step
-                # scanning was requested but this loop can't take it
-                # (non-SGD optimizer, kvstore/multi-device, guard
-                # policy beyond skip_step, ...) — per-step it is
-                self._scan_warned = True
-                import logging
-                logging.getLogger("mxnet_tpu.scan").warning(
-                    "MXNET_SCAN_STEPS=%d requested but this Trainer is "
-                    "not scan-eligible (see docs/TRAINING.md eligibility "
-                    "ladder) — running per-step", scan_mod.steps())
         mw = self.modelwatch
         if mw is not None:
             mw.begin_step(batch_size, len(self._contexts))
@@ -260,60 +256,20 @@ class Trainer:
                 # re-validate NOW, not just at arm time: a GradGuard (or
                 # flag/optimizer change) installed between steps must
                 # not be bypassed for the already-stashed update
-                done = False
-                eligible = self._fused_update_eligible()
-                guard = self.grad_guard
-                guard_on = guard is not None and \
-                    getattr(guard, "enabled", False)
-                runner = self._scan_runner() if eligible else None
-                if runner is not None:
-                    # K-step whole-loop mode (MXNET_SCAN_STEPS;
-                    # mxnet_tpu/scan.py): prep advances the optimizer
-                    # counters NOW (per-step hyperparams), the plan
-                    # buffers, and the K-th push retires the chunk as
-                    # one lax.scan program
-                    prep = self._prep_fused_plan(plan)
-                    if prep is None:
+                if self._fused_update_eligible():
+                    done = self._consume_fused_plan(plan)
+                    if not done:
+                        # a consume-level bail is STRUCTURAL (param
+                        # missing from the tape, mp tuple state): it
+                        # would recur every step, deferring each
+                        # backward for nothing — stop re-arming.
                         self._fused_structural_bail = True
-                        runner = None
-                    else:
-                        done = runner.push(plan, prep)
-                        if done:
-                            self._rearm_fused_update()
-                            return None     # mark_step rides the chunk
-                        # runner refused (sig change, force bail,
-                        # grad_req='add'): run THIS step now. Older
-                        # buffered steps already drained inside push —
-                        # replay against their updates.
-                        from .. import scan as scan_mod
-                        scan_mod._refresh_grad_leaves(plan)
-                        if not guard_on:
-                            done = self._consume_fused_plan(
-                                plan, prepared=prep)
-                        else:
-                            # guarded step can't bypass the guard on
-                            # the per-step consume — rewind the prep's
-                            # counter advance (the classic _update
-                            # below re-advances) and go classic
-                            opt = self._optimizer
-                            opt._index_update_count = \
-                                dict(prep.base_counts)
-                            opt.num_update = prep.base_num
-                            plan.execute()
-                if runner is None and not done:
-                    if eligible and not guard_on:
-                        done = self._consume_fused_plan(plan)
-                        if not done:
-                            # a consume-level bail is STRUCTURAL (param
-                            # missing from the tape, mp tuple state): it
-                            # would recur every step, deferring each
-                            # backward for nothing — stop re-arming.
-                            self._fused_structural_bail = True
-                    else:
-                        # eligibility change (guard installed, flag
-                        # flipped) — not structural; re-arming may
-                        # succeed later
-                        plan.execute()     # plain fused backward
+                else:
+                    # eligibility change (guard installed, flag
+                    # flipped) — not structural; re-arming may
+                    # succeed later
+                    done = False
+                    plan.execute()     # plain fused backward
                 if done:
                     fused_mw = self._mw_fused_caps
                     self._mw_fused_caps = None
@@ -505,13 +461,7 @@ class Trainer:
             return False
         guard = self.grad_guard
         if guard is not None and getattr(guard, "enabled", False):
-            # one exception: under MXNET_SCAN_STEPS>1 a skip_step-only
-            # guard rides the scan boundary (in-program where-select
-            # skip, verdicts replayed at retirement) — any other guard
-            # feature needs the classic per-step pass
-            from .. import scan as scan_mod
-            if not scan_mod.guard_compatible(self, guard):
-                return False
+            return False               # the guard's pass is per step
         opt = self._optimizer
         # exact-class check: a subclass may override the update math the
         # in-graph form replicates
@@ -539,79 +489,25 @@ class Trainer:
             _ag.disarm_fused_update(self)
         self._fused_armed = False
 
-    # ------------------------------------------------------------------
-    # K-step whole-loop mode (MXNET_SCAN_STEPS; mxnet_tpu/scan.py,
-    # docs/TRAINING.md)
-    # ------------------------------------------------------------------
-    def _scan_runner(self):
-        """This Trainer's chunk buffer, built lazily; None when
-        MXNET_SCAN_STEPS<=1 or the runner bailed (eligibility ladder).
-        A K change mid-run drains the old buffer and starts a new
-        runner at the new length."""
-        from .. import scan as scan_mod
-        k = scan_mod.steps()
-        if k <= 1:
-            self._scan_flush()
-            return None
-        r = self._scan
-        if r is None:
-            r = scan_mod.ChunkRunner(self, k)
-            self._scan = r
-        elif r.k != k and not r.bailed:
-            r.flush()
-            r = scan_mod.ChunkRunner(self, k)
-            self._scan = r
-        return None if r.bailed else r
-
-    def _scan_flush(self):
-        """Drain any buffered scan chunk (checkpoint/reshard/state
-        access boundaries). Cheap no-op when nothing is buffered."""
-        r = self._scan
-        if r is not None:
-            r.flush()
-
-    def _scan_note_pre_update(self, prep):
+    def _note_pre_update(self, prep):
         """Pre-update weight aliases, for modelwatch's update norms, on
-        a sampled step: taken by the per-step fused consume BEFORE its
-        launch (an alias is a second holder, so that step donates
-        nothing and the aliases stay readable) and by a K-step chunk
-        about to write back (one capture per K steps)."""
+        a sampled step: taken by the fused consume BEFORE its launch
+        (an alias is a second holder, so that step donates nothing and
+        the aliases stay readable)."""
         mw = self._modelwatch
         if mw is None or not mw.sampling:
             return None
         return mw.note_pre_update(
             [(it[1].name, it[2]) for it in prep.items])
 
-    def _scan_boundary_report(self, prep, caps):
-        """modelwatch at the scan boundary: per-layer stats over the
-        chunk's FINAL gradients and post-chunk weights, update norms
-        measured across the whole chunk (K steps of movement — the
-        documented sampling-at-boundary semantics)."""
-        mw = self._modelwatch
-        if mw is None or not mw.sampling or caps is None:
-            return
-        with telemetry.phase("modelwatch"):
-            unorm = mw.note_post_update(caps, defer=False)
-            named = [(it[1].name,
-                      next(iter(it[1]._grad.values())))
-                     for it in prep.items]
-            mw.step_report(
-                named,
-                [(n, alias) for n, alias, _arr in caps],
-                rescale=prep.rescale,
-                update_now=unorm)
-
     def _prep_fused_plan(self, plan):
-        """The optimizer-side prologue of the fused consume, split out
-        so the K-step scan buffer (mxnet_tpu/scan.py) can run it at
-        BUFFER time: validate the tape<->parameter mapping and advance
-        the update counters exactly when the per-step path would, so
-        schedule-dependent hyperparams (lr keyed on num_update) carry
-        their correct per-step values into a chunk retired later.
-        Returns a scan.FusedPrep, or None on structural mismatch
+        """The optimizer-side prologue of the fused consume: validate
+        the tape<->parameter mapping and advance the update counters
+        exactly as the classic update would, so schedule-dependent
+        hyperparams (lr keyed on num_update) carry their per-step
+        values. Returns a FusedPrep, or None on structural mismatch
         (counters untouched — the caller falls back)."""
         import numpy as np
-        from .. import scan as scan_mod
         opt = self._optimizer
         upd = self._updaters[0]
         pos_by_id = {}
@@ -641,11 +537,7 @@ class Trainer:
             return None
 
         # hyperparams exactly as SGD.update_multi's hyper(): counters
-        # advance, then per-tensor lrs/wds ride as device tensors.
-        # base_* lets the scan path rewind the advance when a refused
-        # push degrades to the classic update (which re-advances).
-        base_counts = dict(opt._index_update_count)
-        base_num = opt.num_update
+        # advance, then per-tensor lrs/wds ride as device tensors
         for i, *_ in items:
             opt._update_count(i)
         lrs = np.array([opt._get_lr(it[0]) for it in items], np.float32)
@@ -654,23 +546,18 @@ class Trainer:
         clip = -1.0 if opt.clip_gradient is None else float(opt.clip_gradient)
         rescale = float(opt.rescale_grad)
         rows = tuple((it[4], it[5], it[3] is not None) for it in items)
-        # grad dtype straight off the storage dict: Parameter.list_grad
-        # would drain the very scan buffer a prep may be feeding
         gdt = tuple(str(next(iter(it[1]._grad.values())).dtype)
                     for it in items)
         mom_rows = tuple(k for k, r in enumerate(rows) if r[2])
         plain_rows = tuple(k for k, r in enumerate(rows) if not r[2])
         upd_key = ("sgd", momentum, clip, rescale, rows, gdt)
-        names = tuple(it[1].name for it in items)
-        return scan_mod.FusedPrep(
+        return FusedPrep(
             items, rows, gdt, mom_rows, plain_rows, upd_key, lrs, wds,
-            momentum, clip, rescale, names, base_counts, base_num)
+            momentum, clip, rescale)
 
     def _make_upd_math(self, prep):
-        """The pure multi-tensor SGD update over a prep's rows —
-        traced into the fused step program AND the K-step scan body
-        (identical math is what makes chunked and per-step
-        trajectories bitwise equal)."""
+        """The pure multi-tensor SGD update over a prep's rows, traced
+        into the fused step program."""
         import jax.numpy as jnp
         from ..ops import get_op
         mom_impl = get_op("preloaded_multi_sgd_mom_update").impl
@@ -714,17 +601,14 @@ class Trainer:
 
         return upd_math
 
-    def _consume_fused_plan(self, plan, prepared=None):
+    def _consume_fused_plan(self, plan):
         """Execute a deferred backward plan with the SGD multi-tensor
         update appended — one XLA program. Returns True on success;
         on any structural mismatch the plan is executed plainly (grads
-        written) and False is returned so the classic path proceeds.
-        `prepared` (a scan.FusedPrep) skips the prologue: the scan
-        buffer already ran it at push time, counters included."""
+        written) and False is returned so the classic path proceeds."""
         import jax.numpy as jnp
         with telemetry.phase("update.prep"):
-            prep = prepared if prepared is not None \
-                else self._prep_fused_plan(plan)
+            prep = self._prep_fused_plan(plan)
             if prep is not None:
                 items = prep.items
                 mom_rows, plain_rows = prep.mom_rows, prep.plain_rows
@@ -740,7 +624,7 @@ class Trainer:
                 # buffers for its outputs when nothing else holds them
                 owners = ([it[5] for it in items], state_arrs,
                           [it[2]._grad for it in items])
-                caps = self._scan_note_pre_update(prep)
+                caps = self._note_pre_update(prep)
         if prep is None:
             plan.execute()
             return False
@@ -876,10 +760,6 @@ class Trainer:
         if not self._kv_initialized:
             self._contexts = self._check_contexts()
             self._init_kvstore()
-        # a buffered K-step scan chunk holds updates not yet applied:
-        # drain it so the checkpoint lands BETWEEN scanned chunks
-        # (docs/TRAINING.md checkpoint granularity)
-        self._scan_flush()
         from . import zero as zero_mod
         if isinstance(self._zero, zero_mod.ZeroEngine):
             blob = self._zero.serialized_states()
@@ -916,7 +796,6 @@ class Trainer:
         if not self._kv_initialized:
             self._contexts = self._check_contexts()
             self._init_kvstore()
-        self._scan_flush()   # stale buffered steps must not replay
         engine = self._zero_engine()
         if engine is not None:
             engine.load_serialized_states(states)
@@ -986,7 +865,6 @@ class Trainer:
         if eng is not None:
             eng.wait_for_all()
         model_mod.wait_checkpoints()
-        self._scan_flush()   # chunked updates apply before rebinding
         old_zero = self._zero \
             if isinstance(self._zero, zero_mod.ZeroEngine) else None
         for param in self._params:

@@ -221,11 +221,10 @@ class NDArray:
     def grad(self) -> Optional["NDArray"]:
         if self._grad is not None:
             # fused-update deferral (MXNET_TRAINER_FUSED_UPDATE): a
-            # backward stashed for an armed Trainer — and any buffered
-            # K-step scan chunk — must execute before its gradients are
-            # observed; cheap None check otherwise
+            # backward stashed for an armed Trainer must execute before
+            # its gradients are observed; cheap None check otherwise
             from .. import autograd as _ag
-            _ag.flush_all_pending()
+            _ag.flush_pending_step()
         return self._grad
 
     # ------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Shared helpers for the Pallas kernel modules (pallas_attention,
-pallas_fused, pallas_norm, pallas_dropout, pallas_epilogue) — one
+pallas_norm, pallas_dropout, pallas_epilogue) — one
 platform probe and one partitioning gate, so the decisions can never
 diverge between kernels."""
 from __future__ import annotations

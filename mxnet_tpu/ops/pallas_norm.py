@@ -136,8 +136,7 @@ def _bwd_call(M, C, bm, eps, dtype_name, interpret):
         m2 = jnp.mean(dyg * xhat, axis=1, keepdims=True)
         dx_ref[:] = (inv * (dyg - m1 - xhat * m2)).astype(dx_ref.dtype)
         # dgamma/dbeta partial sums over this row block, accumulated
-        # across sequential grid steps (same revisiting pattern as the
-        # pallas_fused dw accumulator)
+        # across sequential grid steps
         dg = jnp.sum(dyf * xhat, axis=0)
         db = jnp.sum(dyf, axis=0)
         row = jnp.concatenate(

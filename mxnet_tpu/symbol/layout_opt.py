@@ -8,8 +8,7 @@ lever is keeping 2-D conv activations channels-last END TO END so
 XLA's elementwise fusions and conv custom-calls agree on one physical
 layout: profiling a ResNet-50 v1 train step (batch 128, bf16, one v5e
 chip) showed the NCHW-traced graph spends ~2.4 GB/step in pure layout
-conversion copies that this pass eliminates (46.9 -> 44.0 ms/step,
-tools/layout_exp.py).
+conversion copies that this pass eliminates (46.9 -> 44.0 ms/step).
 
 ``convert_layout(sym)`` rebuilds the traced Symbol DAG: 4-D conv/
 pool/BN islands run in NHWC (one transpose where an island starts,

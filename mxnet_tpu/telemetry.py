@@ -550,21 +550,11 @@ def debit_stall(seconds: float, kind: str = "checkpoint"):
         pass
 
 
-def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
+def mark_step(useful: bool = True):
     """Called once per optimizer step (Trainer.step / Module.update /
     ShardedTrainStep.step): counts ``mx_steps_total`` and observes the
     wall time SINCE THE PREVIOUS step into ``mx_step_seconds`` — i.e.
     the full loop including data/forward/backward, not just the update.
-
-    ``n`` > 1 marks a MULTI-STEP program execution (a scanned K-step
-    chunk, MXNET_SCAN_STEPS): the step counter advances by n, the
-    interval is split into n equal per-step observations (heartbeat
-    steps/rate and step-time percentiles keep meaning "per optimizer
-    step", not "per program"), and goodput/MFU credit the whole
-    window. ``skipped`` says how many of the n steps dropped their
-    update in-program (guard where-select skips): that fraction of the
-    interval is debited from goodput, exactly as ``useful=False``
-    debits a whole per-step interval.
 
     ``useful=False`` marks a step whose update was dropped (a guard
     skip): its interval is debited from goodput. Each mark also
@@ -581,8 +571,6 @@ def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
     """
     if not enabled():
         return
-    n = max(1, int(n))
-    skipped = min(n, max(0, int(skipped)))
     now = time.perf_counter()
     flops_now = _executed_flops()
     compile_now = _compile_seconds()
@@ -590,7 +578,7 @@ def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
         last = _STEP["last"]
         _STEP["last"] = now
         prev_count = _STEP["count"]
-        _STEP["count"] = prev_count + n
+        _STEP["count"] = prev_count + 1
         if last is None:
             _STEP["t0"] = now
             _STEP["flops0"] = flops_now
@@ -600,25 +588,21 @@ def mark_step(useful: bool = True, n: int = 1, skipped: int = 0):
             compile_dt = max(0.0, compile_now - _STEP["compile_at_last"])
             _STEP["compile_at_last"] = compile_now
             if useful:
-                _STEP["useful_s"] += max(0.0, dt - compile_dt) \
-                    * (n - skipped) / n
+                _STEP["useful_s"] += max(0.0, dt - compile_dt)
             t0 = _STEP["t0"]
             wall = now - t0 if t0 is not None else 0.0
             useful_s = max(0.0, _STEP["useful_s"] - _STEP["stall_s"])
             flops0 = _STEP["flops0"]
-        count = _STEP["count"]
-    counter("mx_steps_total").inc(n)
+    counter("mx_steps_total").inc()
     if last is not None:
-        h = histogram("mx_step_seconds")
-        for _ in range(n):
-            h.observe((now - last) / n)
+        histogram("mx_step_seconds").observe(now - last)
         if wall > 0:
             gauge("mx_goodput").set(min(1.0, useful_s / wall))
             peak = known_peak_flops()
             if peak is not None:
                 gauge("mx_mfu").set((flops_now - flops0) / wall / peak)
     _close_step(prev_count)
-    _maybe_fleet_tick(count, prev_count)
+    _maybe_fleet_tick(prev_count + 1)
 
 
 def count_launch(path: str):
@@ -633,8 +617,7 @@ def count_launch(path: str):
 def _close_step(step: int):
     """Move the open step's spans into the ring, with the launches
     counted since the last close. Runs in :func:`mark_step`, so a span
-    still open then (``step::update`` around a K-step chunk's retire)
-    lands in the next step's record."""
+    still open then lands in the next step's record."""
     log = _STEPLOG
     spans, log.open = log.open, []
     dropped, log.dropped = log.dropped, 0
@@ -687,21 +670,15 @@ def step_log(n: Optional[int] = None) -> List[dict]:
     return out
 
 
-def _maybe_fleet_tick(step_count: int, prev_count: int = None):
+def _maybe_fleet_tick(step_count: int):
     """MXNET_FLEET_SNAPSHOT_PERIOD: every N steps, publish + merge the
     cross-rank fleet view. Step-count driven (not wall-clock) so every
     rank of a synchronous job reaches the collective on the same step.
-    A multi-step mark (mark_step(n=K)) fires when the count CROSSES a
-    period boundary — the exact multiple may be jumped over. Failures
-    never poison the step."""
+    Failures never poison the step."""
     try:
         from .config import get as _cfg
         period = int(_cfg("MXNET_FLEET_SNAPSHOT_PERIOD"))
-        if period <= 0 or step_count == 0:
-            return
-        if prev_count is None:
-            prev_count = step_count - 1
-        if step_count // period == prev_count // period:
+        if period <= 0 or step_count % period:
             return
         fleet_snapshot()
     except Exception:

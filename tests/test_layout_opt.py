@@ -277,3 +277,44 @@ def test_structured_dropout_axes_remap():
     d2 = [n for n in new2._topo()
           if not n.is_variable and n.op.name == "Dropout"][0]
     assert not d2.attrs.get("axes")
+
+
+def test_resnet_bottleneck_step_has_no_pallas_call(monkeypatch,
+                                                   pallas_interpret):
+    """The ResNet cells read ``pallas_ms.train_img`` 0.0: a bottleneck
+    block's fused Gluon step (NHWC pass on, every kernel switch at its
+    default) traces no ``pallas_call``. The same loop over a LayerNorm
+    head does trace one, so the count below can tell."""
+    from jax.experimental import pallas as pl
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import BottleneckV1
+    traced = []
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **k: (traced.append(k.get("name")), real(*a, **k))[1])
+    monkeypatch.setenv("MXNET_TRAINER_FUSED_UPDATE", "1")
+
+    def steps(net, x):
+        net.initialize()
+        net.hybridize(static_alloc=True, static_shape=True)
+        loss_fn = gluon.loss.L2Loss()
+        loss_fn.hybridize()
+        trainer = gluon.Trainer(net.collect_params(), "sgd",
+                                {"learning_rate": 0.1, "momentum": 0.9})
+        for _ in range(3):      # classic, then the fused step twice
+            with autograd.record():
+                out = net(x)
+                loss = loss_fn(out, nd.zeros(out.shape))
+            loss.backward()
+            trainer.step(x.shape[0])
+        assert trainer._fused_armed
+        autograd.disarm_fused_update()
+        return len(traced)
+
+    head = gluon.nn.HybridSequential()
+    head.add(gluon.nn.Dense(128, flatten=False), gluon.nn.LayerNorm())
+    assert steps(head, nd.ones((4, 8, 128))) > 0
+    del traced[:]
+    block = BottleneckV1(64, 1, downsample=True, in_channels=32)
+    assert steps(block, nd.ones((2, 32, 8, 8))) == 0

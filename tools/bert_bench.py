@@ -1,8 +1,8 @@
 """BERT-base MLM-style pretraining step benchmark (the COVERAGE_r02
 flagship config: 12L/768/12H, seq 128, batch 32, bf16 compute + fp32
-masters, LAMB, dropout 0.1) with optional per-op device-time breakdown.
+masters, LAMB, dropout 0.1).
 
-Usage: python tools/bert_bench.py [batch] [seq] [--breakdown]
+Usage: python tools/bert_bench.py [batch] [seq]
            [--fusedce | --chunkedce | --densece] [--gate N]
            [--mfu-gate P] [--json]
 
@@ -158,7 +158,6 @@ def main():
     args = [a for a in argv if not a.startswith("--")]
     batch = int(args[0]) if args else 32
     seq = int(args[1]) if len(args) > 1 else 128
-    breakdown = "--breakdown" in argv
 
     if "--fusedce" in argv:
         head_mode = "fused"
@@ -192,11 +191,6 @@ def main():
     print(f"device_ms_per_step={ms:.3f} samples/s={samples_s:.1f} "
           f"~TFLOP/s={tflops:.1f} (~{tflops / 197 * 100:.0f}% MFU of "
           f"197 bf16 peak) head={head_mode}")
-
-    if breakdown:
-        from opbreakdown import op_breakdown
-        op_breakdown(lambda: step.step(*data), 8,
-                     lambda o: float(jax.device_get(o)), top=25)
 
     mfu = goodput = None
     noise_scale = None

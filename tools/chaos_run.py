@@ -172,132 +172,6 @@ def run_nan_round(rng, epochs, rnd, workdir=None):
           % (rnd, guard.skipped_steps, guard.steps), flush=True)
 
 
-def run_scan_round(rng, rnd, k=8):
-    """Whole-loop-compilation mode (MXNET_SCAN_STEPS, docs/TRAINING.md):
-    per round, the same seeded training run executes per-step (K=1) and
-    scanned (K=8), both with a skip_step guard and ONE nan_grad
-    injection landing INSIDE a later chunk, and a checkpoint taken
-    mid-chunk. Asserts:
-
-    * the mid-chunk ``states_blob`` is bitwise identical K=1 vs K=8
-      (checkpoints land BETWEEN scanned chunks — the partial chunk is
-      flushed, never serialized half-applied);
-    * final params are bitwise identical (the in-program where-select
-      skip replays the per-step guard exactly — the poisoned step is
-      dropped without touching the other K-1 steps in its chunk);
-    * a fresh process-restart stand-in (new net, params + optimizer
-      blob loaded) finishing the run at K=8 reproduces the reference
-      bitwise (resume bit-parity);
-    * the scanned run paid fewer guard host syncs (one per chunk)."""
-    import mxnet_tpu as mx
-    from mxnet_tpu import autograd, faultinject, gluon, guardrails, nd
-
-    init_seed = rng.randrange(1 << 30)
-    total = 3 * k + 2                            # 3+ chunks, ragged tail
-    ckpt_at = k + 1 + rng.randrange(k - 1)       # strictly mid-chunk
-    inject_at = 2 * k + rng.randrange(k - 1)     # inside a later chunk
-    print("[scan round %d] init_seed=%d k=%d ckpt_at=%d inject_at=%d"
-          % (rnd, init_seed, k, ckpt_at, inject_at), flush=True)
-
-    rsd = np.random.RandomState(12345 + rnd)
-    batches = [(nd.array(rsd.randn(8, 8).astype(np.float32)),
-                nd.array(rsd.randn(8, 1).astype(np.float32)))
-               for _ in range(total)]
-
-    def build():
-        mx.random.seed(init_seed)
-        np.random.seed(init_seed)
-        # shared prefix: the three builds of a round (reference,
-        # scanned, resumed) must agree on param names for the bitwise
-        # comparisons
-        net = gluon.nn.HybridSequential(prefix="scanr%d_" % rnd)
-        with net.name_scope():
-            net.add(gluon.nn.Dense(16, activation="relu", in_units=8))
-            net.add(gluon.nn.Dense(1, in_units=16))
-        net.initialize(mx.initializer.Xavier())
-        net.hybridize(static_alloc=True, static_shape=True)
-        lf = gluon.loss.L2Loss()
-        lf.hybridize(static_alloc=True, static_shape=True)
-        tr = gluon.Trainer(net.collect_params(), "sgd",
-                           {"learning_rate": 0.05, "momentum": 0.9},
-                           kvstore=None)
-        tr.grad_guard = guardrails.GradGuard(nonfinite="skip_step")
-        return net, lf, tr
-
-    def params_of(net):
-        autograd.flush_all_pending()
-        return {kname: p.data().asnumpy()
-                for kname, p in net.collect_params().items()}
-
-    def drive(kk, start, stop, net, lf, tr, take_ckpt=False):
-        os.environ["MXNET_TRAINER_FUSED_UPDATE"] = "1"
-        os.environ["MXNET_SCAN_STEPS"] = str(kk)
-        out = {}
-        for step in range(start, stop):
-            if step == inject_at:
-                faultinject.set_fault("nan_grad", 1.0, max_fires=1)
-            bx, by = batches[step]
-            with autograd.record():
-                l = lf(net(bx), by)
-            l.backward()
-            tr.step(bx.shape[0])
-            if take_ckpt and step == ckpt_at:
-                # flushes the buffered partial chunk first: the blob is
-                # a between-chunks state
-                out["blob"] = tr.states_blob()
-                out["params"] = params_of(net)
-        return out
-
-    try:
-        # reference: per-step run, straight through
-        faultinject.reset()
-        net1, lf1, tr1 = build()
-        c1 = drive(1, 0, total, net1, lf1, tr1, take_ckpt=True)
-        ref = params_of(net1)
-        g1 = tr1.grad_guard
-
-        # scanned run, straight through
-        faultinject.reset()
-        netk, lfk, trk = build()
-        ck = drive(k, 0, total, netk, lfk, trk, take_ckpt=True)
-        got = params_of(netk)
-        gk = trk.grad_guard
-
-        assert c1["blob"] == ck["blob"], \
-            "mid-chunk optimizer blob differs K=1 vs K=%d" % k
-        for name in ref:
-            assert np.array_equal(c1["params"][name], ck["params"][name]), \
-                "mid-chunk checkpoint param %s differs" % name
-            assert np.array_equal(ref[name], got[name]), \
-                "final param %s differs K=1 vs K=%d" % (name, k)
-            assert np.isfinite(got[name]).all(), \
-                "param %s poisoned despite in-program skip" % name
-        assert g1.skipped_steps == 1 and gk.skipped_steps == 1, \
-            (g1.skipped_steps, gk.skipped_steps)
-        assert gk.sync_count < g1.sync_count, \
-            "scan paid %d syncs vs %d per-step" % (gk.sync_count,
-                                                   g1.sync_count)
-
-        # restart stand-in: fresh net, checkpoint loaded, finish at K=k
-        faultinject.reset()
-        netr, lfr, trr = build()
-        for name, p in netr.collect_params().items():
-            p.set_data(nd.array(ck["params"][name]))
-        trr.load_states_blob(ck["blob"])
-        drive(k, ckpt_at + 1, total, netr, lfr, trr)
-        res = params_of(netr)
-        for name in ref:
-            assert np.array_equal(ref[name], res[name]), \
-                "resumed param %s differs from fault-free run" % name
-        print("[scan round %d] chunk parity + mid-chunk ckpt + resume "
-              "bitwise OK; syncs %d (K=%d) vs %d (K=1); 1 in-chunk nan "
-              "skipped" % (rnd, gk.sync_count, k, g1.sync_count),
-              flush=True)
-    finally:
-        faultinject.reset()
-        os.environ["MXNET_SCAN_STEPS"] = "1"
-
-
 def run_postmortem_round(rng, workdir):
     """Crash-bundle acceptance (ISSUE 11): train under modelwatch with
     the raise policy and a one-shot nan_grad injection; the run must
@@ -501,11 +375,6 @@ def main(argv=None):
     ap.add_argument("--nan-inject", action="store_true",
                     help="guardrails mode: NaN-gradient injection under "
                          "the skip_step policy (no checkpoint chaos)")
-    ap.add_argument("--scan", action="store_true",
-                    help="whole-loop-compilation mode: K-step scanned "
-                         "chunks vs per-step bit-parity, mid-chunk "
-                         "checkpoint + resume, in-chunk nan skip "
-                         "(MXNET_SCAN_STEPS; docs/TRAINING.md)")
     ap.add_argument("--preempt", action="store_true",
                     help="elastic-topology mode: slice preemption "
                          "absorbed by a live reshard, zero restarts "
@@ -526,12 +395,6 @@ def main(argv=None):
                 run_preempt_round(rng, args.epochs, workdir, rnd,
                                   zero=bool(rnd % 2))
             print("CHAOS_OK mode=preempt rounds=%d seed=%d"
-                  % (args.rounds, args.seed), flush=True)
-            return 0
-        if args.scan:
-            for rnd in range(args.rounds):
-                run_scan_round(rng, rnd)
-            print("CHAOS_OK mode=scan rounds=%d seed=%d"
                   % (args.rounds, args.seed), flush=True)
             return 0
         if args.nan_inject:
