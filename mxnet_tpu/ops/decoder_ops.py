@@ -5,22 +5,24 @@ dropless expert layer that is told which experts of the router's range
 it holds. (Dao & Gu, arXiv:2405.21060 sec. 6-7 for the scan; the layer
 equations are those of docs/KERNELS.md "Hybrid decoder ops".)
 
-All of them are XLA compositions: there is no Mosaic kernel to stand
-down, so under a GSPMD mesh they are partitioned like any other op.
-Matrix products take their inputs in the dtype they are given (bf16
-inside ``ShardedTrainStep``) and accumulate in float32; decays,
-softmax, norms and the router are computed in float32.
+All but one are XLA compositions, which a GSPMD mesh partitions like
+any other op. Causal attention has two schedules of one algorithm: a
+Pallas flash kernel (``ops/pallas_causal_gqa.py``) where the call is
+one it can serve, the blocked composition here everywhere else
+(:func:`_attend`). Matrix products take their inputs in the dtype they
+are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
+decays, softmax, norms and the router are computed in float32.
 
 Three *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
 ``_contrib_gqa_mixer``) hold a whole pre-norm mixer each,
 ``mixer(RMSNorm(x))``, and are where recomputation lives: the Mamba-2
 and expert mixers are ``jax.checkpoint``-ed whole, so a training step
 keeps their input and recomputes their inside in the backward; the
-attention mixer keeps its q/k/v/context and recomputes each query
-block's scores. The device-side scopes ``mx.mamba2``,
-``mx.mamba2.ssd``, ``mx.moe``, ``mx.moe.experts`` and
-``mx.attn.causal`` name their instructions in the compiled program
-(forward, recomputation and backward alike).
+attention mixer keeps its q/k/v/context (and, on the kernel path, the
+rows' log-sum-exp) and recomputes each query block's scores. The
+device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``,
+``mx.moe.experts`` and ``mx.attn.causal`` name their instructions in
+the compiled program (forward, recomputation and backward alike).
 """
 from __future__ import annotations
 
@@ -30,7 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import register
+from .. import telemetry
+from . import pallas_causal_gqa, register
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -243,6 +246,23 @@ def _causal_gqa(q, k, v, block):
     return jnp.concatenate(out, axis=1).reshape(b, length, heads, d)
 
 
+def _attend(q, k, v):
+    """Causal GQA by whichever schedule the call allows, chosen from
+    what can be observed here and nothing else: the flash kernel for
+    bf16 q / k / v with a head width of whole lane tiles, whole groups
+    of query heads and a length of whole ``QUERY_BLOCK`` tiles, traced
+    for one device (``pallas_causal_gqa.causal_gqa_available``); the
+    blocked composition for everything else. Counted once a traced call
+    in ``mx_attn_causal_path_total{path="pallas"|"xla"}``."""
+    kernel = pallas_causal_gqa.causal_gqa_available(q, k, v, QUERY_BLOCK)
+    telemetry.count_event("mx_attn_causal_path_total",
+                          path="pallas" if kernel else "xla")
+    with jax.named_scope(pallas_causal_gqa.SCOPE):
+        if kernel:
+            return pallas_causal_gqa.flash_causal_gqa(q, k, v, QUERY_BLOCK)
+        return _causal_gqa(q, k, v, QUERY_BLOCK)
+
+
 @register("_contrib_causal_gqa_attention")
 def causal_gqa_attention(query, key, value):
     """Causal ``softmax(Q K^T / sqrt(d)) V`` with grouped keys and
@@ -252,9 +272,9 @@ def causal_gqa_attention(query, key, value):
     ``QUERY_BLOCK`` at a time, each block against the keys up to its own
     end only (the masked upper triangle is not computed beyond the
     diagonal block), so no length x length array exists; each block's
-    scores are recomputed in the backward."""
-    with jax.named_scope("mx.attn.causal"):
-        return _causal_gqa(query, key, value, QUERY_BLOCK)
+    scores are recomputed in the backward (:func:`_attend`: in VMEM by
+    the flash kernel, through HBM by the composition)."""
+    return _attend(query, key, value)
 
 
 @register("_contrib_gqa_mixer")
@@ -270,8 +290,7 @@ def gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight, *,
     q = _dense(x, q_weight).reshape(b, length, h, d)
     k = _dense(x, k_weight).reshape(b, length, kv, d)
     v = _dense(x, v_weight).reshape(b, length, kv, d)
-    with jax.named_scope("mx.attn.causal"):
-        ctx = _causal_gqa(q, k, v, QUERY_BLOCK)
+    ctx = _attend(q, k, v)
     return _dense(ctx.reshape(b, length, h * d), o_weight)
 
 

@@ -1,0 +1,285 @@
+"""Causal grouped-query attention as one flash kernel (forward and
+backward), for the hybrid decoder's attention layer
+(``ops/decoder_ops.py::_causal_gqa`` is the composition it stands in
+for, and stays the path of everything this kernel cannot serve).
+
+Layout. q and the context are viewed as ``(batch, length, heads * d)``
+and k / v as ``(batch, length, kv_heads * d)`` (free row-major
+reshapes); a ``BlockSpec`` picks query head ``h``'s ``d`` lanes and
+key-value head ``h // (heads // kv_heads)``'s, so no transpose exists
+before or after a call. One grid step is one query head and one tile of
+``tile`` queries; the head's whole k and v sit in VMEM (their block
+index changes once a group of heads), and the step loops over the key
+tiles up to its own: tiles wholly above the diagonal are never visited,
+the diagonal tile is masked, so the work is the composition's at
+``QUERY_BLOCK`` granularity.
+
+Both kernels compute every tile transposed, keys x queries
+(``S^T = K_j Q^T``): a query's running max and sum, the saved
+log-sum-exp and ``delta = sum(dO * O)`` are then dense ``(1, tile)``
+rows that broadcast along sublanes, and the reductions over keys run
+over sublanes. (With queries on rows they are ``(tile, 1)`` columns,
+one lane in use of a vreg's 128: the forward took 8.6 ms on a v5e where
+it now takes 6.4; PERF.md section 6, PR 31.)
+
+Forward (``pallas_causal_gqa_fwd``): online softmax; a ``tile x tile``
+score tile lives in VMEM only; saved are the context and the row
+log-sum-exp, ``(batch, heads, 1, length)`` float32.
+
+Backward (``pallas_causal_gqa_bwd``, one kernel, five products a tile):
+each probability tile is rebuilt from q, k and the log-sum-exp
+and consumed on the spot (dv, dp, ds, dk, dq). dq of the step's query
+tile accumulates over the key tiles in a small buffer; dk / dv
+accumulate in float32 over the query tiles and over the heads of a
+group in two ``(length, d)`` VMEM buffers and are written once a group.
+That residency is what bounds the length this kernel takes
+(``_VMEM_BUDGET``).
+
+Precision is the composition's: bf16 operands into the MXU with float32
+accumulation (``Precision.DEFAULT`` pinned: Mosaic refuses a float32
+contraction of bf16), scale, mask, max, exp and sums in float32,
+probabilities and ``dS`` cast to bf16 for their products.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import pallas_common
+
+__all__ = ["SCOPE", "causal_gqa_available", "flash_causal_gqa"]
+
+# the device-side scope of causal attention, kernel or composition
+# (``decoder_ops._attend`` opens it around either; the backward rule
+# here opens it again, being traced after the caller's has closed)
+SCOPE = "mx.attn.causal"
+
+_LANE = 128
+F32 = jnp.float32
+BF16 = jnp.bfloat16
+
+# what one backward step may hold in VMEM (of the v5e's 128 MiB): the
+# group's k, v, dk, dv blocks twice over (the pipeline's two buffers),
+# the two float32 accumulators, and a few score tiles
+_VMEM_BUDGET = 96 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b^T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a^T @ b
+
+
+def _bwd_vmem_bytes(length, d, tile):
+    resident = length * d * (4 * 2 * 2 + 2 * 4)     # k v dk dv x2, 2 acc
+    tiles = 6 * tile * tile * 4 + 8 * tile * d * 4
+    return resident + tiles
+
+
+def causal_gqa_available(q, k, v, tile):
+    """Whether the kernel may serve this call, from what the code can
+    observe: one device in the mesh being traced for, bf16 q / k / v,
+    a head width of whole 128-lane tiles, whole groups of query heads,
+    a length of whole tiles, and a k / v that fits VMEM."""
+    b, length, heads, d = q.shape
+    kv = k.shape[2]
+    return bool(
+        pallas_common.kernels_allowed()
+        and all(t.dtype == BF16 for t in (q, k, v))
+        and d % _LANE == 0 and tile % _LANE == 0
+        and kv > 0 and heads % kv == 0
+        and length > 0 and length % tile == 0
+        and _bwd_vmem_bytes(length, d, tile) <= _VMEM_BUDGET)
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, precision=lax.Precision.DEFAULT,
+                           preferred_element_type=F32)
+
+
+def _seen(tile):
+    """(keys, queries) bool of the diagonal tile: key position <= query
+    position."""
+    return lax.broadcasted_iota(jnp.int32, (tile, tile), 0) \
+        <= lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+
+
+def _compiler_params(pltpu, semantics, length, d, tile):
+    """The backward's working set bounds the forward's too."""
+    nbytes = _bwd_vmem_bytes(length, d, tile) + (16 << 20)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=min(nbytes, 110 << 20))
+
+
+def _block_specs(pl, length, d, tile, rep):
+    """(a head's tile of q / o / do / dq, its group's whole k / v / dk /
+    dv, a head's tile of a row statistic) over the grid (batch, head,
+    query tile)."""
+    return (pl.BlockSpec((None, tile, d), lambda n, h, i: (n, i, h)),
+            pl.BlockSpec((None, length, d), lambda n, h, i: (n, 0, h // rep)),
+            pl.BlockSpec((None, None, 1, tile), lambda n, h, i: (n, h, 0, i)))
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_call(b, length, heads, kv, d, tile, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rep, nq = heads // kv, length // tile
+    scale = 1.0 / math.sqrt(d)
+
+    def pallas_causal_gqa_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                              m_ref, l_ref, acc_ref):
+        i = pl.program_id(2)
+        q = q_ref[...]
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+        def key_tile(j, diagonal):
+            rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+            st = _dot(k_ref[rows, :], q, _NT) * scale       # keys x queries
+            if diagonal:
+                st = jnp.where(_seen(tile), st, -jnp.inf)
+            m_prev = m_ref[...]
+            m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            pt = jnp.exp(st - m_next)
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(pt, axis=0,
+                                                      keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + _dot(
+                v_ref[rows, :], pt.astype(BF16), _TN)       # d x queries
+            m_ref[...] = m_next
+
+        lax.fori_loop(0, i, lambda j, c: key_tile(j, False), None)
+        key_tile(i, True)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l)
+
+    q_spec, kv_spec, row_spec = _block_specs(pl, length, d, tile, rep)
+    return pl.pallas_call(
+        pallas_causal_gqa_fwd,
+        grid=(b, heads, nq),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
+                   jax.ShapeDtypeStruct((b, heads, 1, length), F32)],
+        scratch_shapes=[pltpu.VMEM((1, tile), F32),
+                        pltpu.VMEM((1, tile), F32),
+                        pltpu.VMEM((d, tile), F32)],
+        compiler_params=_compiler_params(
+            pltpu, ("parallel", "parallel", "arbitrary"), length, d, tile),
+        interpret=interpret,
+        name="pallas_causal_gqa_fwd",
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _bwd_call(b, length, heads, kv, d, tile, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rep, nq = heads // kv, length // tile
+    scale = 1.0 / math.sqrt(d)
+
+    def pallas_causal_gqa_bwd(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                              delta_ref, dq_ref, dk_ref, dv_ref,
+                              dq_acc, dk_acc, dv_acc):
+        h, i = pl.program_id(1), pl.program_id(2)
+
+        @pl.when((h % rep == 0) & (i == 0))
+        def _():
+            dk_acc[...] = jnp.zeros(dk_acc.shape, F32)
+            dv_acc[...] = jnp.zeros(dv_acc.shape, F32)
+
+        q, do = q_ref[...], do_ref[...]
+        lse, delta = lse_ref[...], delta_ref[...]           # (1, tile)
+        dq_acc[...] = jnp.zeros(dq_acc.shape, F32)
+
+        def key_tile(j, diagonal):
+            rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+            kj, vj = k_ref[rows, :], v_ref[rows, :]
+            st = _dot(kj, q, _NT) * scale               # keys x queries
+            if diagonal:
+                st = jnp.where(_seen(tile), st, -jnp.inf)
+            pt = jnp.exp(st - lse)
+            dv_acc[rows, :] += _dot(pt.astype(BF16), do, _NN)
+            dpt = _dot(vj, do, _NT)
+            dst = (pt * (dpt - delta) * scale).astype(BF16)
+            dk_acc[rows, :] += _dot(dst, q, _NN)
+            dq_acc[...] += _dot(kj, dst, _TN)               # d x queries
+
+        lax.fori_loop(0, i, lambda j, c: key_tile(j, False), None)
+        key_tile(i, True)
+        dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
+
+        @pl.when((h % rep == rep - 1) & (i == nq - 1))
+        def _():
+            dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    q_spec, kv_spec, row_spec = _block_specs(pl, length, d, tile, rep)
+    kv_shape = jax.ShapeDtypeStruct((b, length, kv * d), BF16)
+    return pl.pallas_call(
+        pallas_causal_gqa_bwd,
+        grid=(b, heads, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
+                   kv_shape, kv_shape],
+        scratch_shapes=[pltpu.VMEM((d, tile), F32),
+                        pltpu.VMEM((length, d), F32),
+                        pltpu.VMEM((length, d), F32)],
+        compiler_params=_compiler_params(
+            pltpu, ("parallel", "arbitrary", "arbitrary"), length, d, tile),
+        interpret=interpret,
+        name="pallas_causal_gqa_bwd",
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def flash_causal_gqa(q, k, v, tile):
+    """Causal ``softmax(Q K^T / sqrt(d)) V``: q (batch, length, heads,
+    d), k / v (batch, length, kv_heads, d), all bf16, query head h
+    reading key-value head ``h // (heads // kv_heads)``; ``tile``
+    queries and keys a tile (check :func:`causal_gqa_available`
+    first)."""
+    return _forward(q, k, v, tile)[0]
+
+
+def _lanes(x):
+    """(batch, length, heads, d) -> (batch, length, heads * d)."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _forward(q, k, v, tile):
+    b, length, heads, d = q.shape
+    call = _fwd_call(b, length, heads, k.shape[2], d, int(tile),
+                     pallas_common.interpret_mode())
+    o, lse = call(_lanes(q), _lanes(k), _lanes(v))
+    return o.reshape(q.shape), lse
+
+
+def _vjp_fwd(q, k, v, tile):
+    o, lse = _forward(q, k, v, tile)
+    return o, (q, k, v, o, lse)
+
+
+def _vjp_bwd(tile, res, do):
+    q, k, v, o, lse = res
+    b, length, heads, d = q.shape
+    call = _bwd_call(b, length, heads, k.shape[2], d, int(tile),
+                     pallas_common.interpret_mode())
+    with jax.named_scope(SCOPE):
+        do = do.astype(BF16)
+        delta = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1) \
+            .transpose(0, 2, 1)[:, :, None, :]
+        dq, dk, dv = call(_lanes(q), _lanes(k), _lanes(v), _lanes(do), lse,
+                          delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+flash_causal_gqa.defvjp(_vjp_fwd, _vjp_bwd)

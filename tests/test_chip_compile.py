@@ -162,7 +162,8 @@ def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
 # the hybrid decoder's ops at the Nemotron-H widths (hidden 2688; 64
 # Mamba heads x 64, 8 groups x 128; 32/2 attention heads x 128; experts
 # 2688 x 1856, 8 of 128 held, top 6), forward and backward: XLA
-# compositions, no kernel of this repo's or of the compiler's own
+# compositions, no kernel of this repo's or of the compiler's own; but
+# attention through its op, which takes the flash kernel (last test)
 # ---------------------------------------------------------------------------
 def test_expert_product_follows_the_buffer_not_the_experts(one_chip):
     from mxnet_tpu.ops import decoder_ops as D
@@ -206,3 +207,31 @@ def test_scan_and_attention_compile_at_published_widths(one_chip, length):
     shapes = [((1, length, 32, 128), BF), ((1, length, 2, 128), BF),
               ((1, length, 2, 128), BF)]
     assert _custom_calls(one_chip, attn, *shapes) == 0
+
+
+@pytest.mark.parametrize("length", [8192, 1024])
+def test_causal_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length):
+    """The op's gradient at the published widths takes the flash kernel:
+    two Mosaic custom calls, named ``pallas_causal_gqa_*`` (what
+    ``pallas_ms`` sums), each placed under ``mx.attn.causal`` by the
+    benchmark's own reader, the one traced in the backward rule too."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_causal_gqa_attention").impl
+    grad = jax.grad(lambda *a: _sum32(op(*a)), argnums=(0, 1, 2))
+    shapes = [((1, length, 32, 128), BF), ((1, length, 2, 128), BF),
+              ((1, length, 2, 128), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, ["mx.attn.causal"])
+    names = sorted(name for name in placed
+                   if name.startswith("pallas_causal_gqa_"))
+    assert len(calls) == len(names) == 2
+    assert names[0].startswith("pallas_causal_gqa_bwd")
+    assert names[1].startswith("pallas_causal_gqa_fwd")
+    for line in calls:
+        assert 'mx.attn.causal' in line.split('op_name="')[1].split('"')[0]

@@ -1,0 +1,170 @@
+"""The causal grouped-query flash kernel (ops/pallas_causal_gqa.py) in
+interpret mode on the CPU: values and gradients against the blocked
+composition it stands in for (``decoder_ops._causal_gqa``) and against
+the float32 reference of tests/test_decoder_ops.py; causality; and the
+ladder by which ``decoder_ops._attend`` picks a schedule, each rung
+counted in ``mx_attn_causal_path_total``. What Mosaic makes of the
+kernels at the published widths is tests/test_chip_compile.py's."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from mxnet_tpu import telemetry
+from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_causal_gqa as P
+from mxnet_tpu.ops.pallas_common import auto_partitioned
+from test_decoder_ops import _attention_ref
+
+F32, BF = jnp.float32, jnp.bfloat16
+COUNTER = "mx_attn_causal_path_total"
+
+
+def _qkv(seed, length, heads, kv, d=128, batch=1, dtype=BF):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    shapes = [(batch, length, heads, d), (batch, length, kv, d),
+              (batch, length, kv, d), (batch, length, heads, d)]
+    return [jax.random.normal(k, s, F32).astype(dtype)
+            for k, s in zip(keys, shapes)]
+
+
+def _value_and_grads(fn, q, k, v, cot):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return [t.astype(F32) for t in (out,) + vjp(cot.astype(out.dtype))]
+
+
+def _close(got, want, rel):
+    """Each array to within ``rel`` of the wanted one's largest entry
+    (bf16 results of sums taken in different orders)."""
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=rel * float(jnp.max(jnp.abs(w))))
+
+
+@pytest.mark.parametrize("heads, kv", [(2, 2), (4, 1), (16, 1)],
+                         ids=["1to1", "4to1", "16to1"])
+@pytest.mark.parametrize("length, tile", [(128, 128), (384, 128), (512, 256)],
+                         ids=["one_tile", "three_tiles", "two_tiles_of_256"])
+def test_kernel_matches_the_composition_and_the_reference(length, tile,
+                                                          heads, kv):
+    q, k, v, cot = _qkv(length + heads, length, heads, kv, batch=2)
+    got = _value_and_grads(lambda *a: P.flash_causal_gqa(*a, tile),
+                           q, k, v, cot)
+    # the composition on the same bf16 inputs: two roundings of one sum
+    _close(got, _value_and_grads(lambda *a: D._causal_gqa(*a, tile),
+                                 q, k, v, cot), 2e-2)
+    # the plain float32 reference on the same values
+    _close(got, _value_and_grads(
+        _attention_ref, *(t.astype(F32) for t in (q, k, v, cot))), 2e-2)
+
+
+@pytest.mark.parametrize("t", [0, 127, 128, 200, 382])
+def test_a_key_after_position_t_never_reaches_output_t(t):
+    q, k, v, _ = _qkv(5, 384, 4, 2)
+    later = (jnp.arange(384) > t)[None, :, None, None]
+    out = P.flash_causal_gqa(q, k, v, 128)
+    moved = P.flash_causal_gqa(q, jnp.where(later, k + 3, k),
+                               jnp.where(later, v - 2, v), 128)
+    np.testing.assert_array_equal(np.asarray(out[:, :t + 1], F32),
+                                  np.asarray(moved[:, :t + 1], F32))
+    assert not np.array_equal(np.asarray(out[:, t + 1:], F32),
+                              np.asarray(moved[:, t + 1:], F32))
+
+
+# ---------------------------------------------------------------------------
+# which schedule a call takes, through the registered ops
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def counted():
+    """{path: count} of the calls counted since the fixture began."""
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    start = {p: telemetry.counter(COUNTER, path=p).get()
+             for p in ("pallas", "xla")}
+    yield lambda: {p: telemetry.counter(COUNTER, path=p).get() - n
+                   for p, n in start.items()}
+    telemetry.enable(was)
+
+
+def _attention(q, k, v):
+    return get_op("_contrib_causal_gqa_attention").impl(q, k, v)
+
+
+def _mixer(q, k, v):
+    """The mixer op on a hidden state whose projections give q, k, v's
+    shapes (weights of ones: only the path is looked at; the state
+    hangs on q, so that q's gradient runs the mixer's backward)."""
+    b, length, heads, d = q.shape
+    kv, hidden = k.shape[2], 16
+    w = lambda rows: jnp.ones((rows, hidden), q.dtype)
+    data = jnp.ones((b, length, hidden), q.dtype) * jnp.mean(q)
+    return get_op("_contrib_gqa_mixer").impl(
+        data, jnp.ones((hidden,), q.dtype),
+        w(heads * d), w(kv * d), w(kv * d),
+        jnp.ones((hidden, heads * d), q.dtype),
+        num_heads=heads, num_kv_heads=kv, head_dim=d)
+
+
+def _pallas_calls(fn, *args):
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)), (0, 1, 2))
+    return sum(name == "pallas_call"
+               for name in walk(jax.make_jaxpr(grad)(*args).jaxpr))
+
+
+def _two_devices():
+    return auto_partitioned(Mesh(np.array(jax.devices()[:2]), ("dp",)))
+
+
+RUNGS = {
+    # name: (length, heads, kv, d, dtype, scope to trace in)
+    "float32_inputs": (D.QUERY_BLOCK, 2, 1, 128, F32, None),
+    "two_device_mesh": (D.QUERY_BLOCK, 2, 1, 128, BF, _two_devices),
+    "ragged_length": (D.QUERY_BLOCK + 8, 2, 1, 128, BF, None),
+    "head_width_off_the_lanes": (D.QUERY_BLOCK, 2, 1, 64, BF, None),
+}
+
+
+@pytest.mark.parametrize("op", [_attention, _mixer], ids=["op", "mixer"])
+@pytest.mark.parametrize("rung", sorted(RUNGS))
+def test_each_rung_takes_the_composition_and_is_counted_xla(rung, op,
+                                                            counted):
+    length, heads, kv, d, dtype, scope = RUNGS[rung]
+    q, k, v, _ = _qkv(1, length, heads, kv, d, dtype=dtype)
+    if scope is None:
+        assert not P.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
+        calls = _pallas_calls(op, q, k, v)
+    else:
+        with scope():
+            assert not P.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
+            calls = _pallas_calls(op, q, k, v)
+    assert calls == 0
+    assert counted() == {"pallas": 0, "xla": 1}
+
+
+@pytest.mark.parametrize("op", [_attention, _mixer], ids=["op", "mixer"])
+def test_an_eligible_call_takes_the_kernel_and_is_counted_pallas(op, counted):
+    q, k, v, _ = _qkv(2, D.QUERY_BLOCK, 2, 1)
+    assert P.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
+    assert _pallas_calls(op, q, k, v) == 2      # forward, backward
+    assert counted() == {"pallas": 1, "xla": 0}
+
+
+def test_the_op_on_the_kernel_path_gives_the_composition_s_values():
+    q, k, v, cot = _qkv(3, 2 * D.QUERY_BLOCK, 2, 1)
+    _close(_value_and_grads(_attention, q, k, v, cot),
+           _value_and_grads(lambda *a: D._causal_gqa(*a, D.QUERY_BLOCK),
+                            q, k, v, cot), 2e-2)
+
+
+def test_a_length_whose_keys_do_not_fit_vmem_takes_the_composition():
+    shape = lambda heads: jax.ShapeDtypeStruct((1, 1 << 16, heads, 128), BF)
+    assert not P.causal_gqa_available(shape(32), shape(2), shape(2),
+                                      D.QUERY_BLOCK)
+    shape = lambda heads: jax.ShapeDtypeStruct((1, 1 << 13, heads, 128), BF)
+    assert P.causal_gqa_available(shape(32), shape(2), shape(2),
+                                  D.QUERY_BLOCK)
