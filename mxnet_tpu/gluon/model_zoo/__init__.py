@@ -4,8 +4,13 @@
 encoder and its MLM head. ``nemotron_h``: the first decoder, a causal
 Nemotron-H stack (Mamba-2, routed experts, grouped-query attention by a
 pattern string) and its LM-head loss; its mixers recompute their inside
-in the backward (docs/TRAINING.md "Decoder layers and recomputation")."""
+in the backward (docs/TRAINING.md "Decoder layers and recomputation").
+``keye_vl``: the second, Keye-VL 2.0's language model (a learned
+top-k key selector in front of every attention layer, M-RoPE, a softmax
+router over SwiGLU experts), which returns a second loss beside its
+hidden states."""
 from . import vision
 from . import bert
 from . import nemotron_h
+from . import keye_vl
 from .vision import get_model

@@ -160,9 +160,9 @@ class ExpertLayer(_Layer):
                        shared_up_weight, shared_down_weight,
                        experts_up_weight, experts_down_weight):
         return x + F._contrib_moe_mixer(
-            x, norm_weight, router_weight, e_score_correction_bias,
-            expert_rows, shared_up_weight, shared_down_weight,
-            experts_up_weight, experts_down_weight, **self._attrs)
+            x, norm_weight, router_weight, expert_rows, experts_up_weight,
+            experts_down_weight, e_score_correction_bias, shared_up_weight,
+            shared_down_weight, **self._attrs)
 
 
 class AttentionLayer(_Layer):

@@ -1,9 +1,13 @@
-"""Ops of a hybrid decoder layer stack: RMSNorm (plain, and gated over
+"""Ops of a decoder layer stack: RMSNorm (plain, and gated over
 groups), the causal depthwise conv and the chunked state-space (SSD)
-scan of a Mamba-2 mixer, blocked causal grouped-query attention, and a
-dropless expert layer that is told which experts of the router's range
-it holds. (Dao & Gu, arXiv:2405.21060 sec. 6-7 for the scan; the layer
-equations are those of docs/KERNELS.md "Hybrid decoder ops".)
+scan of a Mamba-2 mixer, rotary positions (one axis, or sectioned over
+several), blocked causal grouped-query attention, the same attention
+over the keys a learned selector keeps (an index score for every causal
+pair, the exact ``top_k`` largest a query, and a second loss that trains
+the selector), and a dropless expert layer that is told which experts of
+the router's range it holds. (Dao & Gu, arXiv:2405.21060 sec. 6-7 for
+the scan; DeepSeek-V3.2-Exp's sparse attention for the selector; the
+layer equations are those of docs/KERNELS.md "Hybrid decoder ops".)
 
 All but one are XLA compositions, which a GSPMD mesh partitions like
 any other op. Causal attention has two schedules of one algorithm: a
@@ -13,16 +17,20 @@ one it can serve, the blocked composition here everywhere else
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
-Three *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
-``_contrib_gqa_mixer``) hold a whole pre-norm mixer each,
-``mixer(RMSNorm(x))``, and are where recomputation lives: the Mamba-2
-and expert mixers are ``jax.checkpoint``-ed whole, so a training step
-keeps their input and recomputes their inside in the backward; the
-attention mixer keeps its q/k/v/context (and, on the kernel path, the
-rows' log-sum-exp) and recomputes each query block's scores. The
-device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``,
-``mx.moe.experts`` and ``mx.attn.causal`` name their instructions in
-the compiled program (forward, recomputation and backward alike).
+Four *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
+``_contrib_gqa_mixer``, ``_contrib_sparse_gqa_mixer``) hold a whole
+pre-norm mixer each, ``mixer(RMSNorm(x))``, and are where recomputation
+lives: the Mamba-2, expert and sparse-attention mixers are
+``jax.checkpoint``-ed whole, so a training step keeps their input and
+recomputes their inside in the backward (the sparse one also keeps each
+row's selection threshold and its context, so neither the search nor a
+second pass of the attention is repeated); the dense attention mixer
+keeps its q/k/v/context (and, on the kernel path, the rows'
+log-sum-exp) and recomputes each query block's scores. The device-side
+scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``, ``mx.moe.experts``,
+``mx.attn.causal`` and ``mx.attn.dsa`` (inside it ``mx.attn.index``,
+``mx.attn.select``, ``mx.attn.sparse``) name their instructions in the
+compiled program (forward, recomputation and backward alike).
 """
 from __future__ import annotations
 
@@ -31,6 +39,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import pallas_causal_gqa, register
@@ -295,22 +304,356 @@ def gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight, *,
 
 
 # ---------------------------------------------------------------------------
+# rotary positions
+# ---------------------------------------------------------------------------
+def _rotary_angles(positions, pairs, theta, sections=()):
+    """(batch, length, pairs) float32: frequency pair ``i`` turns by
+    ``position * theta^(-i / pairs)``. positions (batch, length), or
+    (axes, batch, length) with ``sections`` (pairs an axis, in order,
+    summing to ``pairs``): pair ``i`` then reads the axis whose run
+    holds it (M-RoPE: time, height, width)."""
+    inv = float(theta) ** (-jnp.arange(pairs, dtype=F32) / pairs)
+    pos = positions.astype(F32)
+    if sections:
+        if sum(sections) != pairs or pos.shape[0] != len(sections):
+            raise ValueError("rotary sections %s over %d position axes do "
+                             "not cover %d frequency pairs"
+                             % (tuple(sections), pos.shape[0], pairs))
+        axis_of_pair = [a for a, n in enumerate(sections) for _ in range(n)]
+        pos = jnp.moveaxis(pos, 0, -1)[..., jnp.array(axis_of_pair)]
+    else:
+        pos = pos[..., None]
+    return pos * inv
+
+
+def _rotate(x, angles):
+    """x (batch, length, heads, d), lane ``i`` paired with ``i + d/2``:
+    each pair turned by its angle, in float32."""
+    half = x.shape[-1] // 2
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    xf = x.astype(F32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1) \
+        .astype(x.dtype)
+
+
+def _text_positions(batch, length, sections=()):
+    """Text's position ids: the token's index, on every axis where
+    ``sections`` asks for several."""
+    shape = (len(sections), batch, length) if sections else (batch, length)
+    return jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32), shape)
+
+
+@register("_contrib_rotary")
+def rotary(data, positions=None, *, theta=10000.0, sections=()):
+    """Rotary position embedding over the last axis of data (batch,
+    length, heads, d): lane ``i`` and lane ``i + d/2`` are a pair,
+    turned by ``position * theta^(-i / (d/2))``. ``positions`` are
+    integers (batch, length); with ``sections`` (frequency pairs an
+    axis, summing to d/2) they are (axes, batch, length) and pair ``i``
+    reads the axis whose section holds it (multi-axis M-RoPE; for text
+    every axis holds the token's index). Without ``positions`` a
+    token's position is its index on every axis."""
+    sections = tuple(int(n) for n in sections)
+    if positions is None:
+        positions = _text_positions(*data.shape[:2], sections)
+    return _rotate(data, _rotary_angles(positions, data.shape[-1] // 2,
+                                        theta, sections))
+
+
+# ---------------------------------------------------------------------------
+# attention over the keys a learned selector keeps
+# ---------------------------------------------------------------------------
+_KEPT = "mx.attn.dsa.kept"      # what the mixer's checkpoint policy saves
+
+
+def _order_keys(x):
+    """float32 -> uint32 whose unsigned order is the floats' (both
+    zeros alike, above every negative; 0 is below every number)."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    top = jnp.uint32(0x80000000)
+    return jnp.where(x == 0, top, jnp.where(u >= top, ~u, u | top))
+
+
+def _kth_largest(keys, k):
+    """The ``k``-th largest of each row of uint32 ``keys`` (rows, n), 0
+    for a row with fewer than ``k`` entries above 0, without sorting:
+    the largest value that at least ``k`` entries reach, found a bit at
+    a time from the top (32 passes of compare-and-count)."""
+    def bit(i, found):
+        trial = found | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+            jnp.uint32)))
+        reach = jnp.sum(keys >= trial[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= k, trial, found)
+
+    return lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:1], jnp.uint32))
+
+
+def _seen_keys(scores, seen):
+    return jnp.where(seen, _order_keys(scores), jnp.uint32(0))
+
+
+def _thresholds(scores, seen, k):
+    """For each query row of index scores (rows, keys) float32 under the
+    causal mask ``seen``: (the order key of its ``k``-th largest seen
+    score, how many keys that tie with it are kept). A row that sees at
+    most ``k`` keys keeps them all (threshold 0)."""
+    keys = _seen_keys(scores, seen)
+    tau = _kth_largest(keys, k)
+    above = jnp.sum(keys > tau[:, None], axis=-1, dtype=jnp.int32)
+    return tau, k - above
+
+
+def _selected(scores, seen, tau, ties):
+    """The selected set as a mask: the seen keys above a row's
+    threshold and, of those equal to it, the first ``ties`` by index
+    (``lax.top_k``'s rule: ties to the lower index)."""
+    keys = _seen_keys(scores, seen)
+    level = seen & (keys == tau[:, None])
+    first = jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= ties[:, None]
+    return (keys > tau[:, None]) | (level & first)
+
+
+def _index_scores(iq, ik, iw):
+    """``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``, float32: iq
+    (b, q, heads, d), ik (b, k, d), iw (b, q, heads) float32."""
+    s = _mm("bqjd,bkd->bjqk", iq, ik)
+    return jnp.sum(jax.nn.relu(s) * jnp.moveaxis(iw, -1, 1)[..., None],
+                   axis=1)
+
+
+def _sparse_gqa(q, k, v, iq, ik, iw, top_k):
+    """(context, index loss, mean keys a query attended). The blocked
+    composition of :func:`_causal_gqa` with a second mask: a block of
+    queries against the keys up to its end computes every pair's index
+    score and attention score and masks those not selected, so its
+    products are dense attention's. Per block, outside what is
+    differentiated: the rows' thresholds (kept for the backward, where
+    the mixer's ``jax.checkpoint`` policy saves them by name);
+    inside a ``jax.checkpoint``: index scores again (they carry the
+    index loss's gradient), the mask from the thresholds, the masked
+    softmax, the context, and the block's share of the index loss."""
+    block = QUERY_BLOCK
+    b, length, heads, d = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, length, kv, heads // kv, d)
+    scale = 1.0 / math.sqrt(d)
+
+    def seen_of(first, shape):
+        qi = first + lax.broadcasted_iota(jnp.int32, shape, 0)
+        return lax.broadcasted_iota(jnp.int32, shape, 1) <= qi
+
+    def select(iqb, ikb, iwb, first):
+        with jax.named_scope("mx.attn.index"):
+            scores = _index_scores(iqb, ikb, iwb)
+        with jax.named_scope("mx.attn.select"):
+            seen = seen_of(first, scores.shape[-2:])
+            return jax.vmap(lambda s: _thresholds(s, seen, top_k))(scores)
+
+    @jax.checkpoint
+    def rows(qb, kb, vb, iqb, ikb, iwb, tau, ties, first):
+        with jax.named_scope("mx.attn.index"):
+            scores = _index_scores(iqb, ikb, iwb)
+        with jax.named_scope("mx.attn.select"):
+            seen = seen_of(first, scores.shape[-2:])
+            keep = jax.vmap(lambda s, t, n: _selected(s, seen, t, n))(
+                lax.stop_gradient(scores), tau, ties)
+        with jax.named_scope("mx.attn.sparse"):
+            s = _mm("bqgrd,bkgd->bgrqk", qb, kb) * scale
+            p = jax.nn.softmax(jnp.where(keep[:, None, None], s, -jnp.inf),
+                               axis=-1)
+            ctx = _mm("bgrqk,bkgd->bqgrd", p.astype(vb.dtype), vb) \
+                .astype(qb.dtype)
+            target = lax.stop_gradient(jnp.mean(p, axis=(1, 2)))
+        with jax.named_scope("mx.attn.index"):
+            # KL(target || softmax over the kept keys of the index scores)
+            logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
+            kl = jnp.sum(jax.scipy.special.xlogy(target, target)
+                         - jnp.where(keep, target * logq, 0.0))
+        return ctx, kl, jnp.sum(keep, dtype=F32)
+
+    out, loss, kept = [], 0.0, 0.0
+    for lo in range(0, length, block):
+        hi = min(lo + block, length)
+        index_in = (iq[:, lo:hi], ik[:, :hi], iw[:, lo:hi])
+        tau, ties = select(*(lax.stop_gradient(a) for a in index_in), lo)
+        tau, ties = (checkpoint_name(a, _KEPT) for a in (tau, ties))
+        ctx, kl, n = rows(qg[:, lo:hi], k[:, :hi], v[:, :hi], *index_in,
+                          tau, ties, lo)
+        out.append(ctx)
+        loss, kept = loss + kl, kept + n
+    ctx = jnp.concatenate(out, axis=1).reshape(b, length, heads, d)
+    return ctx, loss / (b * length), kept / (b * length)
+
+
+def _sparse_attend(q, k, v, iq, ik, iw, top_k):
+    """(context, index loss (1,), the auxiliary state); the caller opens
+    ``mx.attn.dsa``."""
+    telemetry.count_event("mx_attn_sparse_path_total", path="masked")
+    ctx, loss, kept = _sparse_gqa(q, k, v, iq, ik, iw.astype(F32), int(top_k))
+    return ctx, loss.reshape(1), lax.stop_gradient(jnp.stack([kept, loss]))
+
+
+_DSA_DOC = """
+
+    The selector (DeepSeek-V3.2-Exp's lightning indexer): index_query
+    (batch, length, index_heads, index_dim), index_key (batch, length,
+    index_dim), index_weight (batch, length, index_heads) give every
+    causal pair the score ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+    kI[s])`` (float32 accumulation); query ``t`` attends the ``top_k``
+    keys of largest ``I[t, :t+1]``, all of them while ``t < top_k``,
+    ties to the lower index (exactly ``lax.top_k``'s set); the context
+    is ``softmax`` over that set of ``q . k / sqrt(d)`` times v, query
+    head h reading key-value head ``h // (heads // kv_heads)``. The
+    second output is the index loss, shape (1,): the mean over
+    positions of ``KL(p_t || softmax_{s in S_t} I[t, s])`` with ``p_t``
+    the attention probabilities averaged over the heads, gradient
+    stopped. The selection is not differentiated: the index inputs get
+    gradient from the index loss alone and the context gives them none.
+    ``dsa_state`` (2,) float32 is an auxiliary state (written, never
+    differentiated): the mean number of keys a query attended in this
+    call, and the index loss. Queries are taken ``QUERY_BLOCK`` at a
+    time against the keys up to the block's end; a block computes every
+    pair's two scores and masks the pairs not selected (no length x
+    length array; no key is gathered), finds each row's ``top_k``-th
+    largest score without sorting it (a search over the float's bit
+    pattern: 32 passes of compare-and-count) and recomputes its scores
+    in the backward from the kept thresholds."""
+
+
+@register("_contrib_sparse_gqa_attention", num_outputs=2, mutate_aux={2: 6})
+def sparse_gqa_attention(query, key, value, index_query, index_key,
+                         index_weight, dsa_state, *, top_k):
+    """Causal grouped-query attention over the keys a learned selector
+    keeps: (context (batch, length, heads, d), index loss)."""
+    with jax.named_scope("mx.attn.dsa"):
+        return _sparse_attend(query, key, value, index_query, index_key,
+                              index_weight, top_k)
+
+
+sparse_gqa_attention.__doc__ += _DSA_DOC
+
+
+def _layer_norm(x, gamma, beta, eps):
+    xf = x.astype(F32)
+    mu = jnp.mean(xf, -1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
+    return ((xf - mu) * lax.rsqrt(var + eps) * gamma.astype(F32)
+            + beta.astype(F32)).astype(x.dtype)
+
+
+def _sparse_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
+                  q_norm_gamma, k_norm_gamma, index_q_weight, index_k_weight,
+                  index_weight_weight, index_k_norm_gamma, index_k_norm_beta,
+                  positions, *, h, kv, d, ih, idim, top_k, theta, sections,
+                  eps):
+    b, length, _ = data.shape
+    if positions is None:
+        positions = _text_positions(b, length, sections)
+    x = _rms(data, norm_gamma, eps)
+    turn = _rotary_angles(positions, d // 2, theta, sections)
+    q = _rotate(_rms(_dense(x, q_weight).reshape(b, length, h, d),
+                     q_norm_gamma, eps), turn)
+    k = _rotate(_rms(_dense(x, k_weight).reshape(b, length, kv, d),
+                     k_norm_gamma, eps), turn)
+    v = _dense(x, v_weight).reshape(b, length, kv, d)
+    # the selector reads the normed input and hands it no gradient
+    xi = lax.stop_gradient(x)
+    turn = _rotary_angles(positions[0] if sections else positions, idim // 2,
+                          theta)
+    iq = _rotate(_dense(xi, index_q_weight).reshape(b, length, ih, idim),
+                 turn)
+    ik = _rotate(_layer_norm(_dense(xi, index_k_weight), index_k_norm_gamma,
+                             index_k_norm_beta, eps)[:, :, None],
+                 turn)[:, :, 0]
+    iw = _mm("bti,ji->btj", xi, index_weight_weight) / math.sqrt(ih * idim)
+    ctx, loss, state = _sparse_attend(q, k, v, iq, ik, iw, top_k)
+    ctx = checkpoint_name(ctx, _KEPT)
+    return _dense(ctx.reshape(b, length, h * d), o_weight), loss, state
+
+
+@register("_contrib_sparse_gqa_mixer", num_outputs=2, mutate_aux={2: 13})
+def sparse_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
+                     q_norm_gamma, k_norm_gamma, index_q_weight,
+                     index_k_weight, index_weight_weight, index_k_norm_gamma,
+                     index_k_norm_beta, dsa_state, positions=None, *,
+                     num_heads, num_kv_heads, head_dim, index_heads,
+                     index_head_dim, top_k, rope_theta=10000.0,
+                     rope_sections=(), eps=1e-6):
+    """A pre-norm sparse-attention mixer, ``mixer(h)``, ``h =
+    RMSNorm(data)``: bias-free q/k/v projections, RMSNorm over each
+    head of q and k, rotary positions (:func:`rotary`: ``rope_sections``
+    over (axes, batch, length) ``positions``, every axis the token's
+    index where none are given), the selector on ``h`` with its gradient
+    stopped there (index queries ``index_heads`` x ``index_head_dim``,
+    one index key head under a LayerNorm, both turned whole by the
+    first position axis; index weights ``W_w h / sqrt(index_heads x
+    index_head_dim)``), :func:`sparse_gqa_attention`, bias-free output
+    projection: (mixer output (batch, length, hidden), index loss).
+    Recomputed whole in the backward (``jax.checkpoint``), but for each
+    row's selection threshold and the context, which a step keeps
+    beside ``data``."""
+    fn = jax.checkpoint(
+        lambda *arrays: _sparse_mixer(
+            *arrays, h=int(num_heads), kv=int(num_kv_heads), d=int(head_dim),
+            ih=int(index_heads), idim=int(index_head_dim), top_k=int(top_k),
+            theta=float(rope_theta),
+            sections=tuple(int(n) for n in rope_sections), eps=float(eps)),
+        policy=jax.checkpoint_policies.save_only_these_names(_KEPT))
+    with jax.named_scope("mx.attn.dsa"):
+        return fn(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
+                  q_norm_gamma, k_norm_gamma, index_q_weight, index_k_weight,
+                  index_weight_weight, index_k_norm_gamma, index_k_norm_beta,
+                  positions)
+
+
+sparse_gqa_mixer.__doc__ += _DSA_DOC
+
+
+# ---------------------------------------------------------------------------
 # routed experts
 # ---------------------------------------------------------------------------
-def _route(x, router_w, bias, top_k, scale, norm_topk):
-    """(chosen expert ids (T, k), their weights (T, k) float32)."""
+def _route(x, router_w, bias, top_k, scale, norm_topk, score_func="sigmoid"):
+    """(chosen expert ids (T, k), their weights (T, k) float32). Every
+    expert's score is ``score_func`` of its float32 logit: ``sigmoid``
+    (each expert alone) or ``softmax`` (over all the router's experts).
+    The ``top_k`` largest scores are chosen, after ``bias`` is added
+    where one is given (it moves the choice, never the weight); the
+    weights are the chosen scores, normalised to sum 1 where
+    ``norm_topk``, times ``scale``."""
     logits = jnp.einsum("td,ed->te", x.astype(F32), router_w.astype(F32),
                         precision=_HI)
-    s = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(s + bias.astype(F32), top_k)
+    s = _SCORES[score_func](logits)
+    _, idx = lax.top_k(s if bias is None else s + bias.astype(F32), top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
     return idx, w * scale
 
 
-def _relu2_mlp(x, w1, w2):
-    h = jnp.square(jax.nn.relu(_mm("...d,fd->...f", x, w1))).astype(x.dtype)
+_SCORES = {"sigmoid": jax.nn.sigmoid,
+           "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def _swiglu(h):
+    """h holds ``[gate | up]`` along its last axis: ``silu(gate) * up``."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+# what an expert does between its two products, on the first one's
+# float32 output: w1 (width, hidden) for ``relu2``; for ``swiglu`` the
+# gate's and the up projection's rows stacked, (2 x width, hidden)
+_ACTIVATIONS = {"relu2": _relu2, "swiglu": _swiglu}
+
+
+def _mlp(x, w1, w2, act=_relu2):
+    h = act(_mm("...d,fd->...f", x, w1)).astype(x.dtype)
     return _mm("...f,df->...d", h, w2)
 
 
@@ -362,7 +705,7 @@ _sum_slots.defvjp(
     lambda res, g: (_gather_rows(g, *res), None, None))
 
 
-def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block):
+def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act):
     """Rows gathered into one buffer sorted by expert, whole blocks an
     expert; one batched product over the blocks, each against its
     expert's weights (the same work whatever the routing: a block is
@@ -376,8 +719,7 @@ def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block):
     weight_of_row = jnp.zeros((cap + 1,), F32) \
         .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1]
     xr = _gather_rows(x, token_of_row, row).reshape(-1, block, x.shape[1])
-    h = jnp.square(jax.nn.relu(
-        _mm("bmd,bfd->bmf", xr, up[expert_of_block]))).astype(x.dtype)
+    h = act(_mm("bmd,bfd->bmf", xr, up[expert_of_block])).astype(x.dtype)
     yr = _mm("bmf,bdf->bmd", h, down[expert_of_block]).reshape(cap, -1)
     yr = (yr * weight_of_row[:, None]).astype(x.dtype)
     filled = (token_of_row < t).reshape(-1, block)
@@ -387,7 +729,7 @@ def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block):
     return _sum_slots(yr, token_of_row, row), done
 
 
-def _experts_dense(x, held, local, w_slot, counts, up, down):
+def _experts_dense(x, held, local, w_slot, counts, up, down, act):
     """Every held expert that is routed a row, over every token,
     weighted by the slot that chose it (0 where none did): exact
     whatever the routing; an expert that is routed none is skipped. A
@@ -399,7 +741,7 @@ def _experts_dense(x, held, local, w_slot, counts, up, down):
     def term(e, a, b):
         def routed():
             we = jnp.sum(jnp.where(held & (local == e), w_slot, 0.0), axis=1)
-            return _relu2_mlp(x, a, b) * we[:, None]
+            return _mlp(x, a, b, act) * we[:, None]
 
         return lax.cond(counts[e] > 0, routed,
                         lambda: jnp.zeros(x.shape, F32))
@@ -411,11 +753,13 @@ def _experts_dense(x, held, local, w_slot, counts, up, down):
 
 
 def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
-                 norm_topk, capacity_factor=CAPACITY_FACTOR,
-                 block_rows=BLOCK_ROWS):
+                 norm_topk, score_func="sigmoid", activation="relu2",
+                 capacity_factor=CAPACITY_FACTOR, block_rows=BLOCK_ROWS):
     t = x.shape[0]
     n_held, n_routed = w1.shape[0], router_w.shape[0]
-    idx, w_slot = _route(x, router_w, bias, top_k, scale, norm_topk)
+    act = _ACTIVATIONS[activation]
+    idx, w_slot = _route(x, router_w, bias, top_k, scale, norm_topk,
+                         score_func)
     local = idx - offset
     held = (local >= 0) & (local < n_held)
     # one buffer for the held experts' rows together: `capacity_factor`
@@ -432,7 +776,7 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
     with jax.named_scope("mx.moe.experts"):
         def sorted_rows():
             return _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                   block)
+                                   block, act)
 
         if blocks >= most:
             y, done = sorted_rows()
@@ -445,20 +789,26 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
             y, done = lax.cond(
                 fits, sorted_rows,
                 lambda: (_experts_dense(x, held, local, w_slot, counts, w1,
-                                        w2), counts))
+                                        w2, act), counts))
     return y, jnp.stack([counts, done]).astype(F32)
 
 
 _MOE_DOC = """
 
     The router scores all ``n_routed`` experts (router_weight
-    (n_routed, hidden), float32 product): ``s = sigmoid(x W_r^T)``, the
-    ``top_k`` largest of ``s + score_bias`` are chosen, their weights
-    are ``s[chosen]``, normalised to sum 1 (``norm_topk_prob``) and
-    times ``routed_scaling_factor``. This chip holds the
-    ``w1.shape[0]`` experts from ``expert_offset`` on: w1 (held, width,
-    hidden), w2 (held, hidden, width), ``f_e(x) = W2_e relu(W1_e x)^2``;
-    only their terms are computed. Rows are gathered, sorted by expert
+    (n_routed, hidden), float32 product): ``s = score_func(x W_r^T)``,
+    ``sigmoid`` (each expert alone) or ``softmax`` (over the router's
+    experts); the ``top_k`` largest of ``s`` are chosen, of
+    ``s + score_bias`` where that input is given; their weights are
+    ``s[chosen]``, normalised to sum 1 (``norm_topk_prob``) and times
+    ``routed_scaling_factor``. This chip holds the ``w1.shape[0]``
+    experts from ``expert_offset`` on; only their terms are computed.
+    ``activation`` names the expert: ``relu2``, w1 (held, width, hidden),
+    w2 (held, hidden, width), ``f_e(x) = W2_e relu(W1_e x)^2``; or
+    ``swiglu``, w1 (held, 2 x width, hidden) holding the gate's rows and
+    then the up projection's, ``f_e(x) = W2_e (silu(W_gate,e x) *
+    W_up,e x)``. Both attributes describe the model; neither changes how
+    the rows are moved. Rows are gathered, sorted by expert
     and padded to whole blocks of ``BLOCK_ROWS`` an expert, into one
     buffer of ``CAPACITY_FACTOR`` times the held experts' even share
     (plus a block an expert), and multiplied in one batched product
@@ -473,38 +823,51 @@ _MOE_DOC = """
 @register("_contrib_moe_experts", num_outputs=1, mutate_aux={1: 3})
 def moe_experts(data, router_weight, score_bias, expert_rows, w1, w2, *,
                 top_k, expert_offset=0, routed_scaling_factor=1.0,
-                norm_topk_prob=True):
+                norm_topk_prob=True, score_func="sigmoid",
+                activation="relu2"):
     """The routed part of an expert layer over data (..., hidden):
-    ``sum_{k: chosen_k held here} w_k f_{chosen_k}(x)``."""
+    ``sum_{k: chosen_k held here} w_k f_{chosen_k}(x)``. ``score_bias``
+    may be None (no bias on the choice)."""
     with jax.named_scope("mx.moe"):
         y, rows = _moe_experts(
             data.reshape(-1, data.shape[-1]), router_weight, score_bias,
             w1, w2, top_k=int(top_k), offset=int(expert_offset),
             scale=float(routed_scaling_factor),
-            norm_topk=bool(norm_topk_prob))
+            norm_topk=bool(norm_topk_prob), score_func=str(score_func),
+            activation=str(activation))
     return y.reshape(data.shape), lax.stop_gradient(rows)
 
 
 moe_experts.__doc__ += _MOE_DOC
 
 
-@register("_contrib_moe_mixer", num_outputs=1, mutate_aux={1: 4})
-def moe_mixer(data, norm_gamma, router_weight, score_bias, expert_rows,
-              shared_w1, shared_w2, w1, w2, *, top_k, expert_offset=0,
-              routed_scaling_factor=1.0, norm_topk_prob=True, eps=1e-5):
-    """A pre-norm expert mixer, ``mixer(RMSNorm(data))``: the shared
-    expert (the same squared-ReLU MLP, shared_w1 (width_s, hidden),
-    shared_w2 (hidden, width_s)) plus the routed part of
-    :func:`moe_experts`. data (batch, length, hidden). Recomputed whole
-    in the backward (``jax.checkpoint``): a step keeps ``data`` only."""
+@register("_contrib_moe_mixer", num_outputs=1, mutate_aux={1: 3})
+def moe_mixer(data, norm_gamma, router_weight, expert_rows, w1, w2,
+              score_bias=None, shared_w1=None, shared_w2=None, *, top_k,
+              expert_offset=0, routed_scaling_factor=1.0, norm_topk_prob=True,
+              score_func="sigmoid", activation="relu2", eps=1e-5):
+    """A pre-norm expert mixer, ``mixer(RMSNorm(data))``: the routed
+    part of :func:`moe_experts` plus, where ``shared_w1`` / ``shared_w2``
+    are given, a shared expert of the same ``activation`` (shared_w1
+    (width_s, hidden), or (2 x width_s, hidden) for ``swiglu``;
+    shared_w2 (hidden, width_s)). The optional inputs come last: a
+    model without a score bias or a shared expert leaves them out. data
+    (batch, length, hidden). Recomputed whole in the backward
+    (``jax.checkpoint``): a step keeps ``data`` only."""
+    act = _ACTIVATIONS[str(activation)]
+
+    # (the argument order PR 28 gave it: a compiled Nemotron step is
+    # found again in the persistent cache)
     def mixer(data, norm_gamma, router_weight, score_bias, shared_w1,
               shared_w2, w1, w2):
         x = _rms(data, norm_gamma, float(eps)).reshape(-1, data.shape[-1])
         y, rows = _moe_experts(
             x, router_weight, score_bias, w1, w2, top_k=int(top_k),
             offset=int(expert_offset), scale=float(routed_scaling_factor),
-            norm_topk=bool(norm_topk_prob))
-        y = y.astype(F32) + _relu2_mlp(x, shared_w1, shared_w2)
+            norm_topk=bool(norm_topk_prob), score_func=str(score_func),
+            activation=str(activation))
+        if shared_w1 is not None:
+            y = y.astype(F32) + _mlp(x, shared_w1, shared_w2, act)
         return y.astype(data.dtype).reshape(data.shape), \
             lax.stop_gradient(rows)
 

@@ -455,10 +455,10 @@ def _create(opname: str, inputs: List[Symbol], attrs: Dict[str, Any],
 def _static_num_outputs(op: Operator, attrs) -> int:
     if op.name in ("split", "amp_multicast"):
         return int(attrs.get("num_outputs", 1))
-    if isinstance(op.num_outputs, int) and op.num_outputs > 1 \
-            and not op.mutate_aux:
-        # registry-declared multi-output ops (quantize_v2 etc.);
-        # mutate_aux ops expose only their visible output here
+    if isinstance(op.num_outputs, int) and op.num_outputs > 1:
+        # registry-declared multi-output ops (quantize_v2 etc.):
+        # num_outputs counts the visible ones, whatever a mutate_aux
+        # op writes back beside them
         return op.num_outputs
     if op.name == "RNN":
         return 3 if attrs.get("mode", "lstm") == "lstm" else 2

@@ -1,0 +1,411 @@
+"""The keye_vl2_30b_a3b configuration's benchmark files: the cell's own
+check in float32 at toy widths (that it catches a wrong top-k, missing
+q/k norms, a selector left untrained and a wrong optimizer, and that its
+control, the reference with bf16 masters, comes out wrong at the cell's
+own limits), the model's and the scopes' counts beside what each op
+executes when compiled alone, the configuration file against the catalog
+row, the six scopes in a compiled step, and the seven new readers."""
+import copy
+import json
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxbench import manifest, run as mxrun, scopes
+
+CELL = "keye_vl2_30b_a3b_midtrain_s8192"
+CONFIG = "keye_vl2_30b_a3b"
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+SCOPE_READERS = {
+    "index_scores_ms.train": "mx.attn.index",
+    "index_select_ms.train": "mx.attn.select",
+    "sparse_attn_ms.train": "mx.attn.sparse",
+    "index_scores_roofline_pct.train": "mx.attn.index",
+    "index_select_roofline_pct.train": "mx.attn.select",
+    "sparse_attn_roofline_pct.train": "mx.attn.sparse"}
+NEW_READERS = sorted(SCOPE_READERS) + ["sparse_keys_per_query.train"]
+
+
+def _ctx(loss_rtol=1e-5, seed=5):
+    ctx, gen, _ = mxrun.context(CELL, seed=seed, seconds=0.0, trace=False,
+                                rehearse=True)
+    # float32, and smaller than the toy (what a fault needs to show)
+    ctx.sizes = dict(ctx.sizes, compute_dtype="float32", hidden_size=64,
+                     sa_config=dict(ctx.sizes["sa_config"], topk=8))
+    ctx.traffic = dict(ctx.traffic, seq=32, batch_per_chip=2)
+    ctx.sizes["check"] = dict(ctx.sizes["check"], loss_rtol=loss_rtol,
+                              drop_rtol=2e-3)
+    return ctx, gen
+
+
+def _checked(ctx, gen):
+    batch = ctx.traffic["batch_per_chip"] * len(ctx.devices)
+    return gen.checked_loop(ctx, batch, ctx.traffic["seq"])
+
+
+def test_losses_after_one_and_two_updates_match():
+    """The cell's own check in float32 with tight tolerances: both
+    losses before any update and after one AdamW update."""
+    ctx, gen = _ctx()
+    loop, ok = _checked(ctx, gen)
+    assert ok
+    assert int(loop.step_obj._t) == ctx.sizes["check"]["steps"] == 2
+    assert loop.weights is None
+    # three of four queries select at the toy length too
+    assert ctx.traffic["seq"] == 4 * ctx.sizes["sa_config"]["topk"]
+
+
+def _skew(ctx, name, wrong):
+    real = getattr(ctx.refmod, name)
+    setattr(ctx.refmod, name, lambda *a, **k: wrong(real, *a, **k))
+
+
+def test_a_wrong_top_k_fails_the_check():
+    ctx, gen = _ctx()
+    half = copy.deepcopy(ctx.sizes["sa_config"])
+    half["topk"] //= 2
+    _skew(ctx, "attention", lambda real, w, p, x, pos, cfg: real(
+        w, p, x, pos, dict(cfg, sa_config=half)))
+    assert not _checked(ctx, gen)[1]
+
+
+def test_missing_qk_norms_fail_the_check():
+    ctx, gen = _ctx()
+    d = ctx.sizes["head_dim"]
+    _skew(ctx, "_rms", lambda real, x, w, eps: x
+          if getattr(w, "shape", None) == (d,) else real(x, w, eps))
+    assert not _checked(ctx, gen)[1]
+
+
+def test_a_selector_nothing_trains_fails_the_check():
+    """The index loss left out of the reference: the first loss differs
+    by the loss itself."""
+    ctx, gen = _ctx()
+    _skew(ctx, "forward", lambda real, *a, **k: (real(*a, **k)[0], 0.0))
+    assert not _checked(ctx, gen)[1]
+
+
+def test_sigmoid_scores_fail_the_check():
+    ctx, gen = _ctx()
+
+    def route(real, w, p, x, cfg):
+        s = jax.nn.sigmoid(x @ w[p + "router_weight"].T)
+        wk, chosen = jax.lax.top_k(s, cfg["num_experts_per_tok"])
+        return chosen, wk / wk.sum(-1, keepdims=True)
+
+    _skew(ctx, "route", route)
+    assert not _checked(ctx, gen)[1]
+
+
+@pytest.mark.parametrize("key, wrong", [("epsilon", 1e-3), ("wd", 0.01),
+                                        ("lr", 2e-3)])
+def test_a_wrong_optimizer_fails_the_check(key, wrong):
+    ctx, gen = _ctx()
+    _skew(ctx, "train_losses", lambda real, w, b, s, o, n: real(
+        w, b, s, dict(o, **{key: wrong}), n))
+    assert not _checked(ctx, gen)[1]
+
+
+@pytest.mark.parametrize("seed", [5, 3_000_000_019])
+def test_the_check_passes_the_system_and_tells_its_control_apart(seed):
+    """The system at the configuration's precision (bf16 compute on
+    float32 masters) and the control (the reference with bf16 masters)
+    through the same comparison, at the limits the configuration's file
+    gives. The system passes. The control differs by the change over an
+    update and not by the first loss: at toy widths and the toy's rate
+    of 1e-3 by over twice the system's error; at the cell's rate of 1e-5
+    an update is under a bf16 master's resolution and the control loses
+    a third of the change, which is what the file's limit is set
+    between (on the chip 32.8% and 37.5% against the system's 0.38%:
+    PERF.md section 6; here the same rate shows the same loss)."""
+    import re
+    ctx, gen, _ = mxrun.context(CELL, seed=seed, seconds=0.0, trace=False,
+                                rehearse=True)
+    assert ctx.sizes["compute_dtype"] == "bfloat16"
+    chk = manifest.load_json("configs", CONFIG + ".json")["check"]
+    assert ctx.sizes["check"] == chk
+    said = []
+    ctx.say = said.append
+    assert _checked(ctx, gen)[1]
+    (line,) = [m for m in said if "change off by" in m]
+    system = float(re.search(r"change off by ([0-9.e+-]+)", line).group(1))
+    batch = ctx.traffic["batch_per_chip"] * len(ctx.devices)
+    ok, first, drop = gen.control(ctx, batch, ctx.traffic["seq"])
+    assert first <= chk["loss_rtol"]
+    assert drop > 2 * system
+    # the cell's own rate: the control's masters cannot hold the update
+    ctx.traffic = dict(ctx.traffic, optimizer=manifest.traffic(
+        "midtrain_clm_s8192")[0]["optimizer"])
+    assert ctx.traffic["optimizer"]["lr"] == 1e-5
+    ok, first, drop = gen.control(ctx, batch, ctx.traffic["seq"])
+    assert not ok and first <= chk["loss_rtol"] and drop > chk["drop_rtol"]
+
+
+def test_the_traffic_file_is_the_issues():
+    traffic, gen = manifest.traffic("midtrain_clm_s8192")
+    assert traffic["kind"] == "train_lm_stream"
+    assert (traffic["seq"], traffic["batch_per_chip"]) == (8192, 1)
+    opt = traffic["optimizer"]
+    assert (opt["name"], opt["lr"], opt["beta1"], opt["beta2"],
+            opt["epsilon"]) == ("adamw", 1e-5, 0.9, 0.95, 1e-8)
+    assert opt["wd"] == pytest.approx(0.1 * opt["lr"])
+    assert traffic["feed"] == {"type": "token_rows", "pool_sequences": 256}
+    assert (traffic["inflight_steps"], traffic["warmup_steps"],
+            traffic["trace_seconds"], traffic["dropout"]) == (2, 3, 6, 0.0)
+    mem = traffic["memory_analysis_b1"]
+    assert mem["arguments_bytes"] + mem["temporaries_bytes"] < 15e9
+    cell = manifest.workload(CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (CONFIG, "midtrain_clm_s8192", 1)
+    assert len(cell["why"]) <= 200 and len(cell["layer_metrics"]) == 19
+    assert set(NEW_READERS) <= set(cell["layer_metrics"])
+
+
+# ---------------------------------------------------------------------------
+def test_model_flops_and_the_scopes_counts():
+    sizes, cfgmod, _ = manifest.config(CONFIG)
+    # ISSUE 32's arithmetic: 14,681,088 selected pairs a head at 8,192
+    # (1,792.1 a query against 4,096.5 causal)
+    assert cfgmod.selected_pairs(8192, 2048) == 14_681_088
+    assert cfgmod.selected_pairs(8192, 2048) / 8192 == 1792.125
+    assert cfgmod.causal_pairs(8192) / 8192 == 4096.5
+    assert cfgmod.selected_pairs(100, 2048) == cfgmod.causal_pairs(100)
+    # multiply-adds a token: attention 18,874,368 of projections + 2 x
+    # 1792.125 x 4096; selector 2,260,992 of projections + 4096.5 x 1024;
+    # experts: router 262,144 + 8 x 16/128 x 4,718,592; head 38,895,616
+    assert cfgmod._attn_macs(sizes, 8192) == 18_874_368 + 14_681_088
+    assert cfgmod._index_macs(sizes, 8192) == 2_260_992 + 4_194_816
+    assert cfgmod._moe_macs(sizes) == 262_144 + 4_718_592
+    per_tok = 6 * (33_555_456 + 6_455_808 + 4_980_736) + 2048 * 18_992
+    assert cfgmod.train_flops_per_sample(sizes, 8192) == per_tok * 6 * 8192
+    assert cfgmod.expert_capacity(sizes, 8192) == 48 * 512
+    assert cfgmod.expert_even_share(sizes, 8192) == 512
+    costs = cfgmod.scope_costs(sizes, 8192, 1)
+    assert set(costs) == {"mx.attn.index", "mx.attn.select",
+                          "mx.attn.sparse", "mx.moe.experts"}
+    assert costs["mx.attn.sparse"][0] == 6 * 7 * 2 * 14_681_088 * 32 * 128
+    assert costs["mx.attn.index"][0] == 6 * 4 * 2 * 33_558_528 * 16 * 64
+    assert costs["mx.attn.select"] == (0, 6 * 33_558_528 * 4)
+    # the Nemotron file's rule: the buffer whole, 3 + 2 + 6 matrix products
+    assert costs["mx.moe.experts"][0] == 6 * 11 * 2 * 24_576 * 2048 * 768
+    twice = cfgmod.scope_costs(sizes, 8192, 2)
+    assert twice["mx.attn.sparse"][0] == 2 * costs["mx.attn.sparse"][0]
+    assert twice["mx.attn.select"][1] == 2 * costs["mx.attn.select"][1]
+
+
+def _flops_alone(fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().cost_analysis()["flops"]
+
+
+def test_scope_costs_stay_under_what_the_ops_execute_alone():
+    """``scope_costs`` beside ``cost_analysis()`` of each op's gradient
+    compiled alone (here, for the CPU: no chip is needed to count) at
+    the published widths, 2,048 tokens, top-k 512: never above it. The
+    masked form executes the pairs it masks, every causal pair of a
+    query block (2,621,440 a head against 917,760 selected: 2.86x), so
+    the selector's counts, the least the mathematics needs, are a
+    fraction of what runs: executed / least reads 1.82 here (XLA runs 5
+    of the attention's 7 products under this test's plain sum of the
+    context, 127 GFLOP against 69.8)."""
+    from mxnet_tpu.ops import decoder_ops as D, get_op
+    sizes, cfgmod, _ = manifest.config(CONFIG)
+    sizes = dict(sizes, num_hidden_layers=1,
+                 sa_config=dict(sizes["sa_config"], topk=512))
+    seq, bf = 2048, jnp.bfloat16
+    costs = cfgmod.scope_costs(sizes, seq, 1)
+
+    def attend(q, k, v, iq, ik, iw):
+        ctx, loss, _ = D._sparse_attend(q, k, v, iq, ik, iw, 512)
+        return jnp.sum(ctx.astype(jnp.float32)) + loss[0]
+
+    ran = _flops_alone(
+        jax.grad(attend, argnums=tuple(range(6))),
+        ((1, seq, 32, 128), bf), ((1, seq, 4, 128), bf),
+        ((1, seq, 4, 128), bf), ((1, seq, 16, 64), bf), ((1, seq, 64), bf),
+        ((1, seq, 16), jnp.float32))
+    least = costs["mx.attn.sparse"][0] + costs["mx.attn.index"][0]
+    assert cfgmod.selected_pairs(seq, 512) == 917_760
+    assert least < ran
+    assert 1.5 < ran / least < 4.5, ran / least
+
+    op = get_op("_contrib_moe_mixer").impl
+
+    def experts(x, g, r, w1, w2):
+        y, _ = op(x, g, r, jnp.zeros((2, 16), jnp.float32), w1, w2, top_k=8,
+                  score_func="softmax", activation="swiglu", eps=1e-6)
+        return jnp.sum(y.astype(jnp.float32))
+
+    ran = _flops_alone(
+        jax.grad(experts, argnums=(0, 2, 3, 4)), ((1, seq, 2048), bf),
+        ((2048,), bf), ((128, 2048), bf), ((16, 1536, 2048), bf),
+        ((16, 2048, 768), bf))
+    # the expert scope is counted by the Nemotron file's rule (what
+    # runs: the buffer's 32 blocks of 256 rows through 11 matrix
+    # products), not the least: XLA's own count of this compile, the
+    # dense path's branch and the router's product in it its way,
+    # reads 0.84 of it
+    assert cfgmod.expert_capacity(sizes, seq) == 32 * 256
+    assert 0.7 < ran / costs["mx.moe.experts"][0] < 1.5
+
+
+def test_configuration_keeps_every_published_key():
+    sizes = manifest.load_json("configs", CONFIG + ".json")
+    if not os.path.exists(CATALOG):
+        pytest.skip("no catalog here")
+    with open(CATALOG) as f:
+        (row,) = [r for r in map(json.loads, f)
+                  if r["source_url"] == sizes["source"]]
+    assert row["name"] == "Keye-VL-2.0-30B-A3B"
+    for key, value in row["config"].items():
+        if key not in sizes["reduced"]:
+            assert sizes[key] == value, key
+    assert sizes["reduced"] == ["num_hidden_layers", "num_experts",
+                                "vocab_size", "towers"]
+    assert set(sizes["reduced"]) == set(sizes["reduced_why"]) \
+        == set(sizes["published"]) - {"layer_kinds"}
+    for key in ("num_hidden_layers", "num_experts", "vocab_size"):
+        assert sizes["published"][key] == row["config"][key]
+    assert sizes["deployment"]["router_experts"] \
+        == row["config"]["num_experts"] == 128
+    assert sizes["deployment"]["chips_sharing_a_layer"] * sizes[
+        "num_experts"] == 128
+    assert sizes["vocab_size"] * 8 == row["config"]["vocab_size"]
+    # the floors: four layers, eight experts, an eighth of the vocabulary
+    assert sizes["num_hidden_layers"] >= 4 and sizes["num_experts"] >= 8
+    assert {"equations", "assumed", "check", "toy"} <= set(sizes)
+    # the toy is the same shape: every section a whole number of pairs
+    toy = sizes["toy"]
+    assert sum(toy["rope_scaling"]["mrope_section"]) * 2 == toy["head_dim"]
+
+
+def test_parameters_as_the_file_states_them():
+    sizes, cfgmod, _ = manifest.config(CONFIG)
+    u, w = sizes["hidden_size"], sizes["moe_intermediate_size"]
+    h, kv, d = (sizes["num_attention_heads"], sizes["num_key_value_heads"],
+                sizes["head_dim"])
+    sa = sizes["sa_config"]
+    ih, idim = sa["indexer_num_heads"], sa["indexer_head_dim"]
+    attn = 2 * u * h * d + 2 * u * kv * d
+    selector = u * (ih * idim + idim + ih) + 2 * idim
+    layer = attn + 2 * u + 2 * d + selector + 128 * u + 16 * 3 * u * w
+    assert (attn, selector, layer) == (18_874_368, 2_261_120, 96_899_456)
+    total = 6 * layer + 2 * sizes["vocab_size"] * u + u
+    assert total == 659_190_016
+    assert "659.19M" in sizes["deployment"]["parameters_here"]
+
+
+# ---------------------------------------------------------------------------
+def test_a_compiled_step_names_its_scopes():
+    """The toy step compiled here carries all six scopes in its
+    instructions' metadata, the selector's in the backward too."""
+    from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
+    ctx, _ = _ctx()
+    names = ctx.cfgmod.SCOPES
+    net, loss, n_in = ctx.cfgmod.sharded_parts(ctx.sizes, 0.0, 32)
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    step = ShardedTrainStep(net, loss, mesh, optimizer="adamw",
+                            n_data_inputs=n_in, data_specs=[P()] * n_in)
+    ids = np.zeros((2, 32), np.int32)
+    text = step._fused.lower(step.params, step.aux, step.states, step._t_dev,
+                             step._rng_dev, ids, ids).compile().as_text()
+    found = scopes.scope_map(text, names)
+    assert set(found.values()) == set(names)
+    assert [line for line in text.splitlines()
+            if "transpose(jvp(mx.attn.dsa))" in line
+            and "mx.attn.index" in line]
+    assert scopes.scope_of("jit(f)/mx.attn.dsa/checkpoint/mx.attn.select/"
+                           "while/body/closed_call/reduce_sum", names) \
+        == "mx.attn.select"
+    assert scopes.scope_of("jit(f)/transpose(jvp(mx.attn.dsa))/"
+                           "rematted_computation/dot_general", names) \
+        == "mx.attn.dsa"
+    assert scopes.with_parents({"mx.attn.index": 1.0, "mx.attn.select": 0.5,
+                                "mx.attn.sparse": 2.0, "mx.attn.dsa": 0.25},
+                               names) == {"mx.attn.index": 1.0,
+                                          "mx.attn.select": 0.5,
+                                          "mx.attn.sparse": 2.0,
+                                          "mx.attn.dsa": 0.25}
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_new_readers_report_nothing_without_their_source(name):
+    """On a program without the scopes or the gauge (a parent commit),
+    and in an untraced run."""
+    from mxnet_tpu import telemetry
+    telemetry.reset()
+    reader = manifest.layer_metric(name)
+    bare = types.SimpleNamespace(traced_steps=3, device_kind="TPU v5 lite")
+    assert reader.read(bare) is None
+    empty = types.SimpleNamespace(traced_steps=3, device_kind="TPU v5 lite",
+                                  scope_seconds={}, scope_costs={},
+                                  expert_rows={}, expert_even=512.0)
+    assert reader.read(empty) is None
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_READERS))
+def test_scope_readers_read_a_recorded_run(name):
+    """The numbers of this PR's first traced chip run (PERF.md section
+    5) through the readers: milliseconds a step, and shares under 100."""
+    sizes, cfgmod, _ = manifest.config(CONFIG)
+    costs = cfgmod.scope_costs(sizes, 8192, 1)
+    run = types.SimpleNamespace(
+        traced_steps=9, device_kind="TPU v5 lite", scope_costs=costs,
+        scope_seconds={"mx.attn.dsa": 0.5608, "mx.attn.index": 0.6282,
+                       "mx.attn.select": 0.084, "mx.attn.sparse": 4.363,
+                       "mx.moe": 0.1123, "mx.moe.experts": 1.3882})
+    reader = manifest.layer_metric(name)
+    scope = SCOPE_READERS[name]
+    assert reader.SCOPE == scope
+    ms = run.scope_seconds[scope] * 1e3 / 9
+    if name.endswith("_ms.train"):
+        assert reader.UNIT == "ms/step"
+        assert reader.read(run) == pytest.approx(ms)
+    else:
+        flops, nbytes = costs[scope]
+        assert reader.UNIT == "%"
+        assert reader.read(run) == pytest.approx(
+            100 * max(flops / 197e12, nbytes / 819e9) / (ms / 1e3))
+        assert 0 < reader.read(run) < 100
+
+
+def test_keys_per_query_reads_the_programs_gauge():
+    from mxnet_tpu import telemetry
+    telemetry.reset()
+    reader = manifest.layer_metric("sparse_keys_per_query.train")
+    assert reader.UNIT == "count" and reader.read(None) is None
+    for block, keys in (("layers0", 1792.125), ("layers1", 1792.125),
+                        ("layers2", 4096.5)):
+        telemetry.gauge("mx_attn_keys_per_query", block=block).set(keys)
+    telemetry.gauge("mx_attn_index_loss", block="layers0").set(0.09)
+    assert reader.read(None) == pytest.approx((2 * 1792.125 + 4096.5) / 3)
+    telemetry.reset()
+
+
+def test_benchmark_json_lists_the_cell_and_its_metrics():
+    with open(os.path.join(os.path.dirname(manifest.ROOT),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (config,) = [c for c in bench["configs"] if c["name"] == CONFIG]
+    sizes = manifest.load_json("configs", CONFIG + ".json")
+    assert config["source"] == sizes["source"]
+    assert config["reduced"] == sizes["reduced"]
+    assert config["file"] == "mxbench/configs/%s.json" % CONFIG
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert cell == {"name": CELL, "config": CONFIG, "chips": 1,
+                    "traffic": "midtrain_clm_s8192",
+                    "why": manifest.workload(CELL)["why"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_READERS:
+        m = by_name[name]
+        assert m["workloads"] == [CELL] and m["layer"] == "kernels"
+        assert m["moves"] == "train_samples_per_s"
+        assert m["unit"] == manifest.layer_metric(name).UNIT
+    for name in manifest.workload(CELL)["layer_metrics"]:
+        assert CELL in by_name[name].get("workloads", [CELL]), name
+    assert CELL in [m for m in bench["end_to_end"]
+                    if m["name"] == "train_samples_per_s"][0]["workloads"]

@@ -235,3 +235,97 @@ def test_causal_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
     assert names[1].startswith("pallas_causal_gqa_fwd")
     for line in calls:
         assert 'mx.attn.causal' in line.split('op_name="')[1].split('"')[0]
+
+
+# ---------------------------------------------------------------------------
+# the Keye-VL language model's sparse-attention mixer at the published
+# widths (hidden 2048, 32 / 4 heads of 128, selector 16 x 64), and a
+# whole toy training step: all XLA, nothing of Mosaic's, the selection
+# without a sort
+# ---------------------------------------------------------------------------
+def test_sparse_attention_mixer_compiles_at_published_widths(one_chip):
+    """1,024 tokens, top-k 256 so that selection engages in both query
+    blocks: forward + backward for the described chip; the three inner
+    scopes name instructions under ``mx.attn.dsa``; the k-th largest
+    score comes from a loop of counts, no ``sort``."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_sparse_gqa_mixer").impl
+    length, hidden, h, kv, d, ih, idim = 1024, 2048, 32, 4, 128, 16, 64
+    attrs = dict(num_heads=h, num_kv_heads=kv, head_dim=d, index_heads=ih,
+                 index_head_dim=idim, top_k=256, rope_theta=1e7,
+                 rope_sections=(16, 24, 24), eps=1e-6)
+
+    def loss(*a):
+        y, index_loss, _ = op(*a, **attrs)
+        return _sum32(y) + index_loss[0]
+
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((h * d, hidden), BF), ((kv * d, hidden), BF),
+              ((kv * d, hidden), BF), ((hidden, h * d), BF), ((d,), BF),
+              ((d,), BF), ((ih * idim, hidden), BF), ((idim, hidden), BF),
+              ((ih, hidden), BF), ((idim,), BF), ((idim,), BF),
+              ((2,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(13)))) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    names = ("mx.attn.index", "mx.attn.select", "mx.attn.sparse",
+             "mx.attn.dsa")
+    found = scopes.scope_map(text, names)
+    assert set(found.values()) == set(names)
+    select = [line for line in text.splitlines() if "mx.attn.select" in line]
+    assert [line for line in select if " while(" in line]
+    assert not [line for line in select if " sort(" in line]
+    # two blocks of scores, never a length x length one
+    assert "f32[1,4,8,512,1024]" in text
+    assert "f32[1,4,8,1024,1024]" not in text
+
+
+def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
+    """The zoo model through ``trace_block`` as ``ShardedTrainStep``
+    traces it (both losses, bf16 compute, AdamW through the shared
+    ``_apply_update``), at the configuration's toy widths."""
+    from mxbench import manifest
+    from mxnet_tpu.parallel.sharded import _apply_update, trace_block
+    sizes, cfgmod, _ = manifest.config("keye_vl2_30b_a3b")
+    sizes = dict(sizes, **sizes["toy"])
+    net, loss, n_in = cfgmod.sharded_parts(sizes, 0.0, 64)
+    fn, data_names, names, _ = trace_block(net, loss, n_in)
+    shapes = {n: p.shape for block in (net, loss.head)
+              for n, p in block.collect_params().items()}
+    aux_names = [n for n in names if n in fn._aux_names]
+    names = [n for n in names if n not in fn._aux_names]
+    assert len(aux_names) == 2 * sizes["num_hidden_layers"]
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+
+    hp = dict(lr=1e-5, momentum=0.9, wd=1e-6, beta1=0.9, beta2=0.95,
+              epsilon=1e-8, clip_gradient=-1.0, rescale_grad=1.0)
+
+    def loss_of(params, aux, data):
+        feed = {k: v.astype(BF) for k, v in params.items()}
+        feed.update(zip(data_names, data))
+        feed.update(aux)
+        out, new_aux = fn(feed)
+        return _sum32(out[0]), new_aux
+
+    def step(params, aux, states, t, *data):
+        (value, new_aux), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(params, aux, list(data))
+        new = {k: _apply_update("adamw", hp, w, grads[k], states[k], t)
+               for k, w in params.items()}
+        return value, new_aux, new
+
+    params = {n: sds(shapes[n]) for n in names}
+    aux = {n: sds(shapes[n]) for n in aux_names}
+    ids = sds((2, 64), jnp.int32)
+    text = jax.jit(step).lower(
+        params, aux, {n: (params[n], params[n]) for n in names}, sds(()),
+        ids, ids).compile().as_text()
+    assert "tpu_custom_call" not in text
+    for scope in cfgmod.SCOPES:
+        assert scope in text, scope

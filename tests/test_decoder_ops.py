@@ -316,3 +316,323 @@ def test_mixers_take_bfloat16_and_stay_near_float32():
     assert got.dtype == jnp.bfloat16
     err = jnp.linalg.norm(got.astype(F32) - want) / jnp.linalg.norm(want)
     assert float(err) < 2e-2
+
+
+# ---------------------------------------------------------------------------
+# rotary positions, the learned key selector and the attention over the
+# keys it keeps, softmax / SwiGLU experts: against the plain reference
+# of the benchmark's Keye-VL configuration
+# ---------------------------------------------------------------------------
+KREF = manifest.load_module("reference", "keye_vl2_30b_a3b.py")
+KTOY = manifest.load_json("configs", "keye_vl2_30b_a3b.json")["toy"]
+
+
+def test_rotary_with_three_distinct_position_axes():
+    (x,) = _rand(30, (2, 9, 3, 16))
+    pos = jnp.asarray(np.random.default_rng(0).integers(0, 5000, (3, 2, 9)),
+                      jnp.int32)
+    assert not np.array_equal(pos[0], pos[1])
+    op = get_op("_contrib_rotary").impl
+    _same_values_and_grads(
+        lambda x: op(x, pos, theta=1e7, sections=(2, 3, 3)),
+        lambda x: KREF.rope(x, pos, 1e7, [2, 3, 3]), (x,))
+    # one axis, and text: no positions given is every axis the index
+    _close(op(x, pos[1], theta=1e4), KREF.rope(x, pos[1], 1e4))
+    index = jnp.broadcast_to(jnp.arange(9), (3, 2, 9))
+    _close(op(x, theta=1e7, sections=(2, 3, 3)),
+           KREF.rope(x, index, 1e7, [2, 3, 3]))
+    _close(op(x, theta=1e7), KREF.rope(x, index[0], 1e7))
+    # a section that reads another axis changes the result
+    swapped = op(x, pos[jnp.array([0, 2, 1])], theta=1e7, sections=(2, 3, 3))
+    assert float(jnp.max(jnp.abs(
+        swapped - op(x, pos, theta=1e7, sections=(2, 3, 3))))) > 0.1
+    with pytest.raises(ValueError):
+        op(x, pos, theta=1e7, sections=(2, 3, 2))
+
+
+def _program_set(scores, first, k):
+    """The program's selected set for a block of query rows."""
+    n = scores.shape[-1]
+    seen = jnp.arange(n)[None, :] <= (first + jnp.arange(
+        scores.shape[-2]))[:, None]
+    return jax.vmap(lambda s: D._selected(
+        s, seen, *D._thresholds(s, seen, k)))(scores)
+
+
+@pytest.mark.parametrize("first, k", [(0, 8), (24, 8), (24, 40), (5, 1)])
+def test_the_selected_set_is_top_ks_row_for_row(first, k):
+    iq, ik, iw = _rand(31, (2, 16, 4, 8), (2, first + 16, 8), (2, 16, 4))
+    scores = D._index_scores(iq, ik, iw)
+    _close(scores, KREF.index_scores(iq, ik, iw), 1e-5)
+    want = KREF.selected(scores, first, k)
+    got = _program_set(scores, first, k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rows = np.asarray(got).sum(-1)
+    np.testing.assert_array_equal(
+        rows, np.broadcast_to(np.minimum(first + 1 + np.arange(16), k),
+                              rows.shape))
+
+
+def test_ties_go_to_the_lower_index():
+    """Planted ties at a row's threshold (and a row of nothing but
+    ties, zeros of both signs among them): exactly ``lax.top_k``'s set."""
+    rng = np.random.default_rng(3)
+    scores = rng.normal(size=(1, 8, 32)).astype(np.float32)
+    kth = np.sort(scores[0, 2])[::-1][5]
+    scores[0, 2, [1, 9, 30]] = kth              # four keys at the threshold
+    scores[0, 3] = rng.integers(-1, 2, 32)      # few distinct values
+    scores[0, 4] = 0.0
+    scores[0, 4, ::3] = -0.0
+    scores[0, 5] = np.where(rng.random(32) < 0.5, 0.0, -0.0)
+    scores[0, 5, 7] = 1.0
+    scores = jnp.asarray(scores)
+    for first in (24, 0):
+        # zeros of either sign are one value, as the reference's own
+        # index_scores hands them to lax.top_k
+        want = KREF.selected(jnp.where(scores == 0, 0.0, scores), first, 6)
+        got = _program_set(scores, first, 6)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    tied = np.flatnonzero(np.asarray(scores[0, 2]) == kth)
+    kept = np.flatnonzero(np.asarray(_program_set(scores, 24, 6))[0, 2])
+    assert len(tied) == 4 and len(kept) == 6
+    assert set(tied[:1]) <= set(kept) and tied[-1] not in kept
+
+
+def test_kth_largest_without_sorting():
+    keys = jnp.asarray(np.random.default_rng(4).integers(
+        1, 2 ** 32, (5, 50), dtype=np.uint64).astype(np.uint32))
+    for k in (1, 7, 50):
+        want = np.sort(np.asarray(keys), axis=-1)[:, -k]
+        np.testing.assert_array_equal(np.asarray(D._kth_largest(keys, k)),
+                                      want)
+    assert (np.asarray(D._kth_largest(keys, 51)) == 0).all()
+    # the order of the floats is the order of their keys
+    x = jnp.asarray([-np.inf, -3.5, -1e-30, -0.0, 0.0, 1e-30, 2.0, np.inf],
+                    F32)
+    order = np.asarray(D._order_keys(x)).astype(np.int64)
+    assert (np.diff(order) >= 0).all() and order[3] == order[4]
+    assert order.min() > 0
+
+
+def _attn_cfg():
+    return dict(KTOY, rms_norm_eps=1e-6, rope_theta=1e7)
+
+
+def _attn_weights(seed, cfg):
+    u = cfg["hidden_size"]
+    h, kv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                cfg["head_dim"])
+    sa = cfg["sa_config"]
+    ih, idim = sa["indexer_num_heads"], sa["indexer_head_dim"]
+    names = ["q_weight", "k_weight", "v_weight", "o_weight", "q_norm_weight",
+             "k_norm_weight", "index_q_weight", "index_k_weight",
+             "index_w_weight", "index_k_norm_weight", "index_k_norm_bias"]
+    shapes = [(h * d, u), (kv * d, u), (kv * d, u), (u, h * d), (d,), (d,),
+              (ih * idim, u), (idim, u), (ih, u), (idim,), (idim,)]
+    w = dict(zip(names, _rand(seed, *shapes, scale=0.3)))
+    for n in ("q_norm_weight", "k_norm_weight", "index_k_norm_weight"):
+        w[n] = 1.0 + w[n]
+    return names, w
+
+
+def _mixer(x, norm_w, w, names, cfg, positions=None):
+    sa = cfg["sa_config"]
+    return get_op("_contrib_sparse_gqa_mixer").impl(
+        x, norm_w, *[w[n] for n in names], jnp.zeros((2,), F32), positions,
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        index_heads=sa["indexer_num_heads"],
+        index_head_dim=sa["indexer_head_dim"], top_k=sa["topk"],
+        rope_theta=cfg["rope_theta"],
+        rope_sections=tuple(cfg["rope_scaling"]["mrope_section"]),
+        eps=cfg["rms_norm_eps"])
+
+
+@pytest.mark.parametrize("length, block", [(37, 16), (32, 512)])
+def test_sparse_attention_mixer_against_the_reference(monkeypatch, length,
+                                                      block):
+    """Forward, both outputs and the state; ``jax.grad`` of each output
+    to every input; several query blocks and one, a length that is not
+    whole blocks; three distinct position axes."""
+    monkeypatch.setattr(D, "QUERY_BLOCK", block)
+    monkeypatch.setattr(KREF, "QUERY_BLOCK", block)
+    cfg = _attn_cfg()
+    names, w = _attn_weights(32, cfg)
+    x, norm_w = _rand(33, (2, length, cfg["hidden_size"]),
+                      (cfg["hidden_size"],))
+    norm_w = 1.0 + 0.1 * norm_w
+    pos = jnp.asarray(np.random.default_rng(1).integers(
+        0, 900, (3, 2, length)), jnp.int32)
+
+    def fn(x, norm_w, *ws):
+        return _mixer(x, norm_w, dict(zip(names, ws)), names, cfg, pos)
+
+    def ref(x, norm_w, *ws):
+        return KREF.attention(dict(zip(names, ws)), "",
+                              KREF._rms(x, norm_w, cfg["rms_norm_eps"]), pos,
+                              cfg)
+
+    args = (x, norm_w) + tuple(w[n] for n in names)
+    y, loss, state = fn(*args)
+    want_y, want_loss = ref(*args)
+    _close(y, want_y, 1e-4)
+    assert loss.shape == (1,) and float(loss[0]) > 0
+    assert float(loss[0]) == pytest.approx(float(want_loss), rel=1e-4)
+    k = cfg["sa_config"]["topk"]
+    assert float(state[0]) == pytest.approx(
+        sum(min(t + 1, k) for t in range(length)) / length)
+    assert float(state[1]) == pytest.approx(float(loss[0]))
+    # one gradient of both outputs together (a cotangent on the mixer's
+    # output, the index loss weighted 3) to every input
+    argnums = tuple(range(len(args)))
+    (cot,) = _rand(34, y.shape)
+
+    def both(out):
+        return jnp.sum(out[0] * cot) + 3.0 * jnp.sum(out[1])
+
+    _close(jax.grad(lambda *a: both(fn(*a)), argnums)(*args),
+           jax.grad(lambda *a: both(ref(*a)), argnums)(*args), 2e-4)
+
+
+def test_each_loss_trains_its_own_parameters():
+    """The index loss reaches the selector's parameters and nothing
+    else; the language model's side reaches everything but them."""
+    cfg = _attn_cfg()
+    names, w = _attn_weights(35, cfg)
+    x, norm_w = _rand(36, (1, 12, cfg["hidden_size"]), (cfg["hidden_size"],))
+    args = (x, 1.0 + 0.1 * norm_w) + tuple(w[n] for n in names)
+    argnums = tuple(range(len(args)))
+
+    def fn(*a):
+        return _mixer(a[0], a[1], dict(zip(names, a[2:])), names, cfg)
+
+    by_index = jax.grad(lambda *a: fn(*a)[1].sum(), argnums)(*args)
+    by_lm = jax.grad(lambda *a: jnp.sum(jnp.square(fn(*a)[0])),
+                     argnums)(*args)
+    for name, gi, gl in zip(("data", "norm") + tuple(names), by_index, by_lm):
+        moved_i = float(jnp.max(jnp.abs(gi))) > 0
+        moved_l = float(jnp.max(jnp.abs(gl))) > 0
+        assert moved_i == name.startswith("index_"), name
+        assert moved_l == (not name.startswith("index_")), name
+
+
+def test_the_sparse_mixer_keeps_thresholds_and_context_only(capsys):
+    """Beside its arguments the mixer's checkpoint keeps each row's
+    threshold and tie count and the context: no score block, no
+    projection."""
+    cfg = _attn_cfg()
+    names, w = _attn_weights(37, cfg)
+    x, norm_w = _rand(38, (1, 24, cfg["hidden_size"]), (cfg["hidden_size"],))
+    args = (x, norm_w) + tuple(w[n] for n in names)
+
+    def fn(*a):
+        return jnp.sum(_mixer(a[0], a[1], dict(zip(names, a[2:])), names,
+                              cfg)[0])
+
+    assert _remat_count(jax.grad(fn), *args) > 0
+    jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    kept = [line.split(" ")[0] for line in capsys.readouterr().out
+            .splitlines() if "from the argument" not in line
+            and "from a constant" not in line]
+    h, d = cfg["num_attention_heads"], cfg["head_dim"]
+    assert sorted(kept) == sorted(["u32[1,24]", "i32[1,24]",
+                                   "f32[1,24,%d,%d]" % (h, d)])
+
+
+# ---------------------------------------------------------------------------
+KCFG = {"num_experts_per_tok": 3, "norm_topk_prob": True}
+
+
+def _swiglu_weights(seed, hidden=12, routed=16, held=4, width=10, offset=4):
+    r, gate_up, down = _rand(seed, (routed, hidden),
+                             (held, 2 * width, hidden), (held, hidden, width))
+    return {"router_weight": r, "experts_gate_up_weight": gate_up,
+            "experts_down_weight": down}, dict(KCFG, expert_offset=offset)
+
+
+def _swiglu_moe(x, w, cfg, capacity_factor=None):
+    kwargs = {} if capacity_factor is None \
+        else {"capacity_factor": capacity_factor}
+    y, rows = D._moe_experts(
+        x.reshape(-1, x.shape[-1]), w["router_weight"], None,
+        w["experts_gate_up_weight"], w["experts_down_weight"],
+        top_k=cfg["num_experts_per_tok"], offset=cfg["expert_offset"],
+        scale=1.0, norm_topk=cfg["norm_topk_prob"], score_func="softmax",
+        activation="swiglu", **kwargs)
+    return y.reshape(x.shape), rows
+
+
+@pytest.mark.parametrize("capacity_factor", [0.25, None, 100.0])
+def test_softmax_swiglu_experts(capacity_factor):
+    """A softmax router without a bias over gated experts, the dense
+    path, the default and a buffer no routing overfills: the numbers
+    and gradients of the reference's loop over the held experts."""
+    w, cfg = _swiglu_weights(40)
+    (x,) = _rand(41, (2, 20, 12))
+    names = sorted(w)
+
+    def fn(x, *ws):
+        return _swiglu_moe(x, dict(zip(names, ws)), cfg, capacity_factor)[0]
+
+    def ref(x, *ws):
+        return KREF.experts(dict(zip(names, ws)), "", x, cfg)
+
+    args = (x,) + tuple(w[n] for n in names)
+    _close(fn(*args), ref(*args))
+    (cot,) = _rand(42, x.shape)
+    nums = tuple(range(len(args)))
+    _close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
+           jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args), 5e-5)
+    # through the registered op too, the bias left out
+    y, rows = get_op("_contrib_moe_experts").impl(
+        x, w["router_weight"], None, jnp.zeros((2, 4), F32),
+        w["experts_gate_up_weight"], w["experts_down_weight"], top_k=3,
+        expert_offset=4, score_func="softmax", activation="swiglu")
+    _close(y, ref(*args))
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
+
+
+def test_the_moe_mixer_takes_its_optional_inputs_last():
+    """Without a score bias and a shared expert, and with both: the
+    same op, the routed part unchanged."""
+    w, cfg = _swiglu_weights(43)
+    x, norm_w, bias, shared_up, shared_down = _rand(
+        44, (2, 10, 12), (12,), (16,), (2 * 6, 12), (12, 6))
+    op = get_op("_contrib_moe_mixer").impl
+    attrs = dict(top_k=3, expert_offset=4, score_func="softmax",
+                 activation="swiglu", eps=1e-6)
+    rows = jnp.zeros((2, 4), F32)
+    bare, _ = op(x, norm_w, w["router_weight"], rows,
+                 w["experts_gate_up_weight"], w["experts_down_weight"],
+                 **attrs)
+    h = KREF._rms(x, norm_w, 1e-6)
+    _close(bare, KREF.experts(w, "", h, cfg))
+    full, _ = op(x, norm_w, w["router_weight"], rows,
+                 w["experts_gate_up_weight"], w["experts_down_weight"],
+                 0.0 * bias, shared_up, shared_down, **attrs)
+    _close(full, bare + KREF.swiglu(h, shared_up[:6], shared_up[6:],
+                                    shared_down))
+    with pytest.raises(KeyError):
+        op(x, norm_w, w["router_weight"], rows, w["experts_gate_up_weight"],
+           w["experts_down_weight"], **dict(attrs, activation="gelu"))
+
+
+def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
+    """16 experts in 8 shares of 2 (the cell: 128 in 8 shares of 16):
+    the shares' parts, with no shared expert to count once, are the
+    layer with all 16 held."""
+    w, cfg = _swiglu_weights(45, held=16, offset=0)
+    (x,) = _rand(46, (30, 12))
+    want = KREF.experts(w, "", x, cfg)
+    got, counts = 0.0, []
+    for offset in range(0, 16, 2):
+        share = dict(w, experts_gate_up_weight=w["experts_gate_up_weight"]
+                     [offset:offset + 2], experts_down_weight=w[
+                         "experts_down_weight"][offset:offset + 2])
+        part, rows = _swiglu_moe(x, share, dict(cfg, expert_offset=offset))
+        _close(part, KREF.experts(share, "", x,
+                                  dict(cfg, expert_offset=offset)))
+        got = got + part
+        counts.append(np.asarray(rows[0]))
+    _close(got, want)
+    assert int(np.sum(counts)) == 30 * 3        # every choice held once
