@@ -9,11 +9,15 @@ the router's range it holds. (Dao & Gu, arXiv:2405.21060 sec. 6-7 for
 the scan; DeepSeek-V3.2-Exp's sparse attention for the selector; the
 layer equations are those of docs/KERNELS.md "Hybrid decoder ops".)
 
-All but one are XLA compositions, which a GSPMD mesh partitions like
+All but two are XLA compositions, which a GSPMD mesh partitions like
 any other op. Causal attention has two schedules of one algorithm: a
 Pallas flash kernel (``ops/pallas_causal_gqa.py``) where the call is
 one it can serve, the blocked composition here everywhere else
-(:func:`_attend`). Matrix products take their inputs in the dtype they
+(:func:`_attend`). Attention over a selector's keys likewise
+(:func:`_sparse_attend`): the selection is always the XLA code here,
+the attention over the selected set runs in flash kernels that take the
+set as a mask (``ops/pallas_sparse_gqa.py``) or as the masked
+composition. Matrix products take their inputs in the dtype they
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
@@ -23,8 +27,9 @@ pre-norm mixer each, ``mixer(RMSNorm(x))``, and are where recomputation
 lives: the Mamba-2, expert and sparse-attention mixers are
 ``jax.checkpoint``-ed whole, so a training step keeps their input and
 recomputes their inside in the backward (the sparse one also keeps each
-row's selection threshold and its context, so neither the search nor a
-second pass of the attention is repeated); the dense attention mixer
+row's selection threshold, its context and, on the kernel path, its
+log-sum-exp, so neither the search nor a second pass of the attention
+is repeated); the dense attention mixer
 keeps its q/k/v/context (and, on the kernel path, the rows'
 log-sum-exp) and recomputes each query block's scores. The device-side
 scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``, ``mx.moe.experts``,
@@ -34,6 +39,7 @@ compiled program (forward, recomputation and backward alike).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -42,7 +48,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
-from . import pallas_causal_gqa, register
+from . import pallas_causal_gqa, pallas_sparse_gqa, register
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -423,14 +429,44 @@ def _index_scores(iq, ik, iw):
                    axis=1)
 
 
+def _seen_block(first, shape):
+    """(queries, keys) bool of a block whose first query is at position
+    ``first``: key position <= query position."""
+    qi = first + lax.broadcasted_iota(jnp.int32, shape, 0)
+    return lax.broadcasted_iota(jnp.int32, shape, 1) <= qi
+
+
+def _block_thresholds(scores, first, k):
+    """:func:`_thresholds` of each row of a query block's index scores
+    (batch, queries, keys)."""
+    seen = _seen_block(first, scores.shape[-2:])
+    return jax.vmap(lambda s: _thresholds(s, seen, k))(scores)
+
+
+def _block_selected(scores, tau, ties, first):
+    """:func:`_selected` of a query block: bool (batch, queries, keys)."""
+    seen = _seen_block(first, scores.shape[-2:])
+    return jax.vmap(lambda s, t, n: _selected(s, seen, t, n))(
+        lax.stop_gradient(scores), tau, ties)
+
+
+def _index_kl(scores, keep, target):
+    """A block's sum of ``KL(target || softmax over the kept keys of the
+    index scores)``."""
+    logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
+    return jnp.sum(jax.scipy.special.xlogy(target, target)
+                   - jnp.where(keep, target * logq, 0.0))
+
+
 def _sparse_gqa(q, k, v, iq, ik, iw, top_k):
     """(context, index loss, mean keys a query attended). The blocked
     composition of :func:`_causal_gqa` with a second mask: a block of
     queries against the keys up to its end computes every pair's index
     score and attention score and masks those not selected, so its
     products are dense attention's. Per block, outside what is
-    differentiated: the rows' thresholds (kept for the backward, where
-    the mixer's ``jax.checkpoint`` policy saves them by name);
+    differentiated: the rows' thresholds (kept for the backward, like
+    the context: the mixer's ``jax.checkpoint`` policy saves what is
+    named ``_KEPT``);
     inside a ``jax.checkpoint``: index scores again (they carry the
     index loss's gradient), the mask from the thresholds, the masked
     softmax, the context, and the block's share of the index loss."""
@@ -440,26 +476,19 @@ def _sparse_gqa(q, k, v, iq, ik, iw, top_k):
     qg = q.reshape(b, length, kv, heads // kv, d)
     scale = 1.0 / math.sqrt(d)
 
-    def seen_of(first, shape):
-        qi = first + lax.broadcasted_iota(jnp.int32, shape, 0)
-        return lax.broadcasted_iota(jnp.int32, shape, 1) <= qi
-
     def select(iqb, ikb, iwb, first):
         with jax.named_scope("mx.attn.index"):
             scores = _index_scores(iqb, ikb, iwb)
         with jax.named_scope("mx.attn.select"):
-            seen = seen_of(first, scores.shape[-2:])
-            return jax.vmap(lambda s: _thresholds(s, seen, top_k))(scores)
+            return _block_thresholds(scores, first, top_k)
 
     @jax.checkpoint
     def rows(qb, kb, vb, iqb, ikb, iwb, tau, ties, first):
         with jax.named_scope("mx.attn.index"):
             scores = _index_scores(iqb, ikb, iwb)
         with jax.named_scope("mx.attn.select"):
-            seen = seen_of(first, scores.shape[-2:])
-            keep = jax.vmap(lambda s, t, n: _selected(s, seen, t, n))(
-                lax.stop_gradient(scores), tau, ties)
-        with jax.named_scope("mx.attn.sparse"):
+            keep = _block_selected(scores, tau, ties, first)
+        with jax.named_scope(pallas_sparse_gqa.SCOPE):
             s = _mm("bqgrd,bkgd->bgrqk", qb, kb) * scale
             p = jax.nn.softmax(jnp.where(keep[:, None, None], s, -jnp.inf),
                                axis=-1)
@@ -467,10 +496,7 @@ def _sparse_gqa(q, k, v, iq, ik, iw, top_k):
                 .astype(qb.dtype)
             target = lax.stop_gradient(jnp.mean(p, axis=(1, 2)))
         with jax.named_scope("mx.attn.index"):
-            # KL(target || softmax over the kept keys of the index scores)
-            logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
-            kl = jnp.sum(jax.scipy.special.xlogy(target, target)
-                         - jnp.where(keep, target * logq, 0.0))
+            kl = _index_kl(scores, keep, target)
         return ctx, kl, jnp.sum(keep, dtype=F32)
 
     out, loss, kept = [], 0.0, 0.0
@@ -484,14 +510,136 @@ def _sparse_gqa(q, k, v, iq, ik, iw, top_k):
         out.append(ctx)
         loss, kept = loss + kl, kept + n
     ctx = jnp.concatenate(out, axis=1).reshape(b, length, heads, d)
-    return ctx, loss / (b * length), kept / (b * length)
+    return checkpoint_name(ctx, _KEPT), loss / (b * length), \
+        kept / (b * length)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _sparse_gqa_flash(q, k, v, iq, ik, iw, top_k):
+    """:func:`_sparse_gqa`'s three results, the attention over the
+    selected keys in the flash kernels of ``ops/pallas_sparse_gqa.py``:
+    no score block exists outside VMEM. The selection is the
+    composition's own code and so its set, handed to the kernels as a
+    mask; the index loss reads the kernels' head-averaged probabilities.
+    Differentiated by hand (:func:`_flash_bwd`), a block at a time like
+    the composition's recomputed ``rows``: kept from the forward are
+    each row's threshold and tie count, the context and the rows'
+    log-sum-exp (all named for the mixer's ``jax.checkpoint``), so the
+    backward runs the index scores, the mask and the probabilities
+    again, never the forward kernel."""
+    return _flash_fwd(q, k, v, iq, ik, iw, top_k)[0]
+
+
+def _keys_by_queries(keep):
+    """A block's selected set as the kernels read it: int8 (batch,
+    keys, queries)."""
+    return jnp.swapaxes(keep, 1, 2).astype(jnp.int8)
+
+
+def _head_mean_probs(q, k, mask, lse, block):
+    """A query block's attention probabilities averaged over the heads,
+    (batch, queries, keys) float32, from the kernel that rebuilds them
+    (keys x queries: the swap is a change of view, the compiler keeps a
+    block's index scores laid out that way too)."""
+    with jax.named_scope(pallas_sparse_gqa.SCOPE):
+        return jnp.swapaxes(pallas_sparse_gqa.head_mean_probs(
+            q, k, mask, lse, block, QUERY_BLOCK), 1, 2)
+
+
+def _index_blocks(iq, ik, iw):
+    """(block number, its first query, its index inputs) a query block:
+    the block's index queries and weights, the index keys up to its
+    end."""
+    for i, lo in enumerate(range(0, iq.shape[1], QUERY_BLOCK)):
+        hi = lo + QUERY_BLOCK
+        yield i, lo, (iq[:, lo:hi], ik[:, :hi], iw[:, lo:hi])
+
+
+def _flash_fwd(q, k, v, iq, ik, iw, top_k):
+    tile = QUERY_BLOCK
+    b, length = q.shape[:2]
+    scores, taus, ties, keeps, masks = [], [], [], [], []
+    for _, lo, index_in in _index_blocks(iq, ik, iw):
+        with jax.named_scope("mx.attn.index"):
+            scores.append(_index_scores(*index_in))
+        with jax.named_scope("mx.attn.select"):
+            tau, tie = _block_thresholds(scores[-1], lo, top_k)
+            keeps.append(_block_selected(scores[-1], tau, tie, lo))
+            masks.append(_keys_by_queries(keeps[-1]))
+            taus.append(tau)
+            ties.append(tie)
+    with jax.named_scope("mx.attn.select"):
+        mask = pallas_sparse_gqa.mask_blocks(masks, length)
+    with jax.named_scope(pallas_sparse_gqa.SCOPE):
+        ctx, lse = pallas_sparse_gqa.attend(q, k, v, mask, tile)
+    loss = kept = 0.0
+    for i, (block_scores, keep) in enumerate(zip(scores, keeps)):
+        target = _head_mean_probs(q, k, masks[i], lse, i)
+        with jax.named_scope("mx.attn.index"):
+            loss = loss + _index_kl(block_scores, keep, target)
+        kept = kept + jnp.sum(keep, dtype=F32)
+    tau, tie, ctx, lse = (checkpoint_name(a, _KEPT) for a in (
+        jnp.concatenate(taus, axis=1), jnp.concatenate(ties, axis=1), ctx,
+        lse))
+    return (ctx, loss / (b * length), kept / (b * length)), \
+        (q, k, v, iq, ik, iw, tau, tie, ctx, lse)
+
+
+def _flash_bwd(top_k, res, cotangents):
+    """A block at a time: its index scores again (with the pullback to
+    the index inputs), its mask from the kept thresholds, its
+    probabilities from the kept log-sum-exp, the index loss's gradient;
+    then one backward kernel under the blocks' masks together. (The
+    rule is traced after the caller's scopes have closed: it opens them
+    again.)"""
+    q, k, v, iq, ik, iw, tau, ties, ctx, lse = res
+    dctx, dloss, _ = cotangents
+    tile = QUERY_BLOCK
+    b, length = q.shape[:2]
+    share = dloss / (b * length)
+    diq, diw, masks = [], [], []
+    dik = jnp.zeros(ik.shape, F32)
+    with jax.named_scope("mx.attn.dsa"):
+        for i, lo, index_in in _index_blocks(iq, ik, iw):
+            hi = lo + tile
+            with jax.named_scope("mx.attn.index"):
+                scores, pull = jax.vjp(_index_scores, *index_in)
+            with jax.named_scope("mx.attn.select"):
+                keep = _block_selected(scores, tau[:, lo:hi], ties[:, lo:hi],
+                                       lo)
+                masks.append(_keys_by_queries(keep))
+            target = _head_mean_probs(q, k, masks[-1], lse, i)
+            with jax.named_scope("mx.attn.index"):
+                dq_i, dk_i, dw_i = pull(
+                    jax.grad(_index_kl)(scores, keep, target) * share)
+                dik = dik.at[:, :hi].add(dk_i.astype(F32))
+            diq.append(dq_i)
+            diw.append(dw_i)
+        with jax.named_scope("mx.attn.select"):
+            mask = pallas_sparse_gqa.mask_blocks(masks, length)
+        with jax.named_scope(pallas_sparse_gqa.SCOPE):
+            dq, dk, dv = pallas_sparse_gqa.attend_bwd(q, k, v, mask, ctx, lse,
+                                                      dctx, tile)
+    return (dq, dk, dv, jnp.concatenate(diq, axis=1), dik.astype(ik.dtype),
+            jnp.concatenate(diw, axis=1))
+
+
+_sparse_gqa_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _sparse_attend(q, k, v, iq, ik, iw, top_k):
-    """(context, index loss (1,), the auxiliary state); the caller opens
-    ``mx.attn.dsa``."""
-    telemetry.count_event("mx_attn_sparse_path_total", path="masked")
-    ctx, loss, kept = _sparse_gqa(q, k, v, iq, ik, iw.astype(F32), int(top_k))
+    """(context, index loss (1,), the auxiliary state) by whichever form
+    the call allows, chosen from what can be observed here and nothing
+    else (``pallas_sparse_gqa.sparse_gqa_available``: what
+    :func:`_attend` asks, and kernels that will be compiled or whose
+    interpretation was asked for); counted once a traced call in
+    ``mx_attn_sparse_path_total{path="pallas"|"masked"}``. The caller
+    opens ``mx.attn.dsa``."""
+    kernel = pallas_sparse_gqa.sparse_gqa_available(q, k, v, QUERY_BLOCK)
+    telemetry.count_event("mx_attn_sparse_path_total",
+                          path="pallas" if kernel else "masked")
+    form = _sparse_gqa_flash if kernel else _sparse_gqa
+    ctx, loss, kept = form(q, k, v, iq, ik, iw.astype(F32), int(top_k))
     return ctx, loss.reshape(1), lax.stop_gradient(jnp.stack([kept, loss]))
 
 
@@ -519,7 +667,10 @@ _DSA_DOC = """
     length array; no key is gathered), finds each row's ``top_k``-th
     largest score without sorting it (a search over the float's bit
     pattern: 32 passes of compare-and-count) and recomputes its scores
-    in the backward from the kept thresholds."""
+    in the backward from the kept thresholds. Where the call allows it
+    (:func:`_sparse_attend`) the attention scores live in the VMEM of
+    flash kernels that read the selected set as a mask; the set is the
+    same bit for bit."""
 
 
 @register("_contrib_sparse_gqa_attention", num_outputs=2, mutate_aux={2: 6})
@@ -569,7 +720,6 @@ def _sparse_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
                  turn)[:, :, 0]
     iw = _mm("bti,ji->btj", xi, index_weight_weight) / math.sqrt(ih * idim)
     ctx, loss, state = _sparse_attend(q, k, v, iq, ik, iw, top_k)
-    ctx = checkpoint_name(ctx, _KEPT)
     return _dense(ctx.reshape(b, length, h * d), o_weight), loss, state
 
 
@@ -592,8 +742,9 @@ def sparse_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
     index_head_dim)``), :func:`sparse_gqa_attention`, bias-free output
     projection: (mixer output (batch, length, hidden), index loss).
     Recomputed whole in the backward (``jax.checkpoint``), but for each
-    row's selection threshold and the context, which a step keeps
-    beside ``data``."""
+    row's selection threshold and the context (and the rows'
+    log-sum-exp on the kernel path), which a step keeps beside
+    ``data``."""
     fn = jax.checkpoint(
         lambda *arrays: _sparse_mixer(
             *arrays, h=int(num_heads), kv=int(num_kv_heads), d=int(head_dim),

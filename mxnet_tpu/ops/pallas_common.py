@@ -9,18 +9,22 @@ import threading
 
 import jax
 
-__all__ = ["interpret_mode", "auto_partitioned", "kernels_allowed"]
+__all__ = ["interpret_mode", "interpret_asked", "auto_partitioned",
+           "kernels_allowed"]
 
 _TRACING = threading.local()
+
+
+def interpret_asked() -> bool:
+    """True when MXNET_PALLAS_INTERPRET asks for interpreter mode."""
+    from ..config import get as _cfg
+    return bool(_cfg("MXNET_PALLAS_INTERPRET"))
 
 
 def interpret_mode() -> bool:
     """True when Pallas kernels must run in interpreter mode: forced by
     MXNET_PALLAS_INTERPRET, or no TPU backend is attached."""
-    from ..config import get as _cfg
-    if _cfg("MXNET_PALLAS_INTERPRET"):
-        return True
-    return jax.devices()[0].platform != "tpu"
+    return interpret_asked() or jax.devices()[0].platform != "tpu"
 
 
 @contextlib.contextmanager

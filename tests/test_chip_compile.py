@@ -284,6 +284,66 @@ def test_sparse_attention_mixer_compiles_at_published_widths(one_chip):
     assert "f32[1,4,8,1024,1024]" not in text
 
 
+def _sparse_mixer_gradient(one_chip, length, top_k):
+    """The compiled text of the sparse mixer's value and gradient (both
+    outputs, to all 13 parameters) at the published widths."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_sparse_gqa_mixer").impl
+    hidden, h, kv, d, ih, idim = 2048, 32, 4, 128, 16, 64
+    attrs = dict(num_heads=h, num_kv_heads=kv, head_dim=d, index_heads=ih,
+                 index_head_dim=idim, top_k=top_k, rope_theta=1e7,
+                 rope_sections=(16, 24, 24), eps=1e-6)
+
+    def loss(*a):
+        y, index_loss, _ = op(*a, **attrs)
+        return _sum32(y) + index_loss[0]
+
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((h * d, hidden), BF), ((kv * d, hidden), BF),
+              ((kv * d, hidden), BF), ((hidden, h * d), BF), ((d,), BF),
+              ((d,), BF), ((ih * idim, hidden), BF), ((idim, hidden), BF),
+              ((ih, hidden), BF), ((idim,), BF), ((idim,), BF),
+              ((2,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(13)))) \
+        .lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("length, top_k", [(1024, 256), (8192, 2048)])
+def test_sparse_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length, top_k):
+    """Compiled, not interpreted, the mixer takes the flash kernels:
+    Mosaic accepts them within the VMEM limit, every custom call is
+    named ``pallas_sparse_gqa_*`` (what ``pallas_ms`` sums) and placed
+    under ``mx.attn.sparse`` by the benchmark's own reader (the forward
+    kernel once: the recomputation does not run it again; the
+    probabilities a query block in the forward and again in the
+    backward rule; one backward kernel), and no score block is left in
+    the program."""
+    from mxbench import scopes
+    text = _sparse_mixer_gradient(one_chip, length, top_k)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = ("mx.attn.index", "mx.attn.select", "mx.attn.sparse",
+             "mx.attn.dsa")
+    placed = scopes.scope_map(text, names)
+    assert set(placed.values()) == set(names)
+    kernels = {name: scope for name, scope in placed.items()
+               if name.startswith("pallas_sparse_gqa_")}
+    blocks = length // 512
+    assert len(calls) == len(kernels) == 2 + 2 * blocks
+    assert set(kernels.values()) == {"mx.attn.sparse"}
+    kinds = [name.split(".")[0] for name in kernels]
+    assert kinds.count("pallas_sparse_gqa_fwd") == 1
+    assert kinds.count("pallas_sparse_gqa_bwd") == 1
+    assert kinds.count("pallas_sparse_gqa_probs") == 2 * blocks
+    for line in calls:
+        assert "mx.attn.sparse" in line.split('op_name="')[1].split('"')[0]
+    assert "f32[1,4,8,512," not in text
+    assert "f32[1,32,512," not in text
+
+
 def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
     """The zoo model through ``trace_block`` as ``ShardedTrainStep``
     traces it (both losses, bf16 compute, AdamW through the shared

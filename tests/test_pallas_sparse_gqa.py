@@ -1,0 +1,382 @@
+"""The sparse grouped-query flash kernels (ops/pallas_sparse_gqa.py) in
+interpret mode on the CPU: context, head-averaged probabilities, index
+loss, state and every gradient against the composition they stand in for
+(``decoder_ops._sparse_gqa``) and against a plain float32 reference;
+rows whose selected keys miss whole tiles; the causal limit; causality;
+what the mixer keeps; and the ladder by which
+``decoder_ops._sparse_attend`` picks a form, each rung counted in
+``mx_attn_sparse_path_total``. What Mosaic makes of the kernels at the
+published widths is tests/test_chip_compile.py's."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from mxnet_tpu import telemetry
+from mxnet_tpu.ops import (decoder_ops as D, get_op, pallas_causal_gqa as P,
+                           pallas_common, pallas_sparse_gqa as S)
+from mxnet_tpu.ops.pallas_common import auto_partitioned
+from test_decoder_ops import KREF
+
+F32, BF = jnp.float32, jnp.bfloat16
+COUNTER = "mx_attn_sparse_path_total"
+TILE = 128
+
+pytestmark = pytest.mark.usefixtures("pallas_interpret")
+
+
+@pytest.fixture(autouse=True)
+def _tile(monkeypatch):
+    """Query blocks of 128 (the op's 512 in interpret mode is minutes)."""
+    monkeypatch.setattr(D, "QUERY_BLOCK", TILE)
+
+
+def _inputs(seed, length, heads, kv, d=128, batch=1, dtype=BF, ih=2, idim=8):
+    """q, k, v, index queries / keys / weights, a cotangent for the
+    context."""
+    keys = jax.random.split(jax.random.key(seed), 7)
+    shapes = [(batch, length, heads, d), (batch, length, kv, d),
+              (batch, length, kv, d), (batch, length, ih, idim),
+              (batch, length, idim), (batch, length, ih),
+              (batch, length, heads, d)]
+    q, k, v, iq, ik, iw, cot = (jax.random.normal(key, s, F32)
+                                for key, s in zip(keys, shapes))
+    return [t.astype(dtype) for t in (q, k, v, iq, ik)] + [iw] \
+        + [cot.astype(dtype)]
+
+
+def _reference(q, k, v, iq, ik, iw, top_k):
+    """Plain float32: whole score rows, ``lax.top_k``'s set."""
+    b, length, heads, d = q.shape
+    k, v = (jnp.repeat(t, heads // k.shape[2], axis=2) for t in (k, v))
+    scores = KREF.index_scores(iq, ik, iw)
+    keep = KREF.selected(jax.lax.stop_gradient(scores), 0, top_k)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(d))
+    att = jax.nn.softmax(jnp.where(keep[:, None], s, -jnp.inf), -1)
+    target = jax.lax.stop_gradient(att.mean(1))
+    logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
+    kl = jnp.sum(jax.scipy.special.xlogy(target, target)
+                 - jnp.where(keep, target * logq, 0.0))
+    return (jnp.einsum("bhqk,bkhd->bqhd", att, v), kl / (b * length),
+            jnp.sum(keep, dtype=F32) / (b * length))
+
+
+def _value_and_grads(form, args, cot, top_k):
+    """[context, index loss, keys a query, six gradients] of the context
+    under ``cot`` plus three times the index loss, float32."""
+    def both(*a):
+        ctx, loss, kept = form(*a, top_k)
+        return jnp.sum(ctx.astype(F32) * cot.astype(F32)) + 3.0 * loss, \
+            (ctx, loss, kept)
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        both, argnums=tuple(range(6)), has_aux=True))(*args)
+    return [t.astype(F32) for t in out + grads]
+
+
+def _close(got, want, rel):
+    """Each array to within ``rel`` of the wanted one's largest entry
+    (bf16 results of sums taken in different orders)."""
+    for g, w in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(g)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=rel * float(jnp.max(jnp.abs(w))))
+
+
+@pytest.mark.parametrize("heads, kv", [(2, 2), (8, 1), (16, 1)],
+                         ids=["1to1", "8to1", "16to1"])
+@pytest.mark.parametrize("length, top_k", [
+    (TILE, 48), (3 * TILE, 48), (3 * TILE, TILE), (3 * TILE, 200)],
+    ids=["one_tile", "three_tiles_k_below", "k_a_tile", "k_above"])
+def test_kernels_match_the_composition_and_the_reference(length, top_k,
+                                                         heads, kv):
+    *args, cot = _inputs(length + heads + top_k, length, heads, kv)
+    got = _value_and_grads(D._sparse_gqa_flash, args, cot, top_k)
+    # the composition on the same bf16 inputs: the same selected set bit
+    # for bit, two roundings of one sum elsewhere
+    want = _value_and_grads(D._sparse_gqa, args, cot, top_k)
+    assert float(got[2]) == float(want[2]) == pytest.approx(
+        sum(min(t + 1, top_k) for t in range(length)) / length)
+    _close(got, want, 2e-2)
+    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-3)
+    # the plain float32 reference on the same values
+    ref = _value_and_grads(_reference, [t.astype(F32) for t in args], cot,
+                           top_k)
+    _close(got, ref, 2e-2)
+    assert float(got[1]) == pytest.approx(float(ref[1]), rel=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# the kernels under a mask handed to them
+# ---------------------------------------------------------------------------
+def _masked_reference(q, k, v, keep):
+    """(context, head-averaged probabilities) of a softmax over the
+    pairs ``keep`` (batch, queries, keys) names, float32."""
+    heads, d = q.shape[2], q.shape[3]
+    k, v = (jnp.repeat(t, heads // k.shape[2], axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(d))
+    att = jax.nn.softmax(jnp.where(keep[:, None], s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", att, v), att.mean(1)
+
+
+def _kernel_mask(keep):
+    length = keep.shape[1]
+    return [D._keys_by_queries(keep[:, lo:lo + TILE, :lo + TILE])
+            for lo in range(0, length, TILE)]
+
+
+def test_rows_that_select_nothing_in_a_tile_stay_finite_and_right():
+    """Three tiles. Rows 260-299 select keys of the last tile they visit
+    only (none in their first two); rows 300-339 none in their first;
+    rows 340-383 the first tile only (none in their last but
+    themselves); the rest a random half of what they see."""
+    length, heads, kv = 3 * TILE, 4, 2
+    q, k, v, _, _, _, cot = _inputs(11, length, heads, kv, batch=2)
+    rows = jnp.arange(length)[:, None]
+    cols = jnp.arange(length)[None, :]
+    seen = cols <= rows
+    keep = seen & (jax.random.uniform(jax.random.key(12), (length, length))
+                   < 0.5)
+    keep = jnp.where((rows >= 260) & (rows < 300), cols >= 2 * TILE, keep)
+    keep = jnp.where((rows >= 300) & (rows < 340), cols >= TILE, keep)
+    keep = jnp.where((rows >= 340), cols < TILE, keep)
+    keep = jnp.broadcast_to((keep | (rows == cols)) & seen,
+                            (2, length, length))
+    blocks = _kernel_mask(keep)
+    mask = S.mask_blocks(blocks, length)
+
+    ctx, lse = S.attend(q, k, v, mask, TILE)
+    dq, dk, dv = S.attend_bwd(q, k, v, mask, ctx, lse, cot, TILE)
+    probs = jnp.concatenate([
+        jnp.pad(jnp.swapaxes(S.head_mean_probs(q, k, blk, lse, i, TILE), 1, 2),
+                ((0, 0), (0, 0), (0, length - blk.shape[1])))
+        for i, blk in enumerate(blocks)], axis=1)
+
+    f32 = [t.astype(F32) for t in (q, k, v)]
+    (want_ctx, want_probs), vjp = jax.vjp(
+        lambda *a: _masked_reference(*a, keep), *f32)
+    want_grads = vjp((cot.astype(F32), jnp.zeros_like(want_probs)))
+    _close([t.astype(F32) for t in (ctx, probs, dq, dk, dv)],
+           [want_ctx, want_probs, *want_grads], 2e-2)
+    assert bool(jnp.all(jnp.isfinite(lse)))
+    # a pair that is not selected has probability 0, exactly
+    assert float(jnp.max(jnp.where(keep, 0.0, probs))) == 0.0
+
+
+def test_rows_that_see_no_more_than_top_k_give_the_causal_kernel_s_values():
+    """``top_k`` at the length: the selected set is the causal one and
+    the kernels give ``flash_causal_gqa``'s values."""
+    length = 3 * TILE
+    *args, cot = _inputs(13, length, 4, 2)
+    got = _value_and_grads(D._sparse_gqa_flash, args, cot, length)
+    assert float(got[2]) == (length + 1) / 2
+    out, vjp = jax.vjp(lambda *a: P.flash_causal_gqa(*a, TILE), *args[:3])
+    # (one entry in 196,608 a bf16 rounding apart: XLA's CPU fusions of
+    # the two kernels' tile code differ)
+    _close([got[0]] + got[3:6],
+           [t.astype(F32) for t in (out,) + vjp(cot)], 1e-3)
+
+
+@pytest.mark.parametrize("t", [0, 127, 128, 200, 300])
+def test_a_key_after_position_t_never_reaches_output_t(t):
+    q, k, v, iq, ik, iw, _ = _inputs(5, 3 * TILE, 4, 2)
+    later = jnp.arange(3 * TILE) > t
+    run = jax.jit(lambda *a: D._sparse_gqa_flash(*a, 48)[0])
+    out = run(q, k, v, iq, ik, iw)
+    moved = run(q, jnp.where(later[None, :, None, None], k + 3, k),
+                jnp.where(later[None, :, None, None], v - 2, v), iq,
+                jnp.where(later[None, :, None], ik + 1, ik), iw)
+    np.testing.assert_array_equal(np.asarray(out[:, :t + 1], F32),
+                                  np.asarray(moved[:, :t + 1], F32))
+    assert not np.array_equal(np.asarray(out[:, t + 1:], F32),
+                              np.asarray(moved[:, t + 1:], F32))
+
+
+# ---------------------------------------------------------------------------
+# which form a call takes, through the registered ops
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def counted():
+    """{path: count} of the calls counted since the fixture began."""
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    start = {p: telemetry.counter(COUNTER, path=p).get()
+             for p in ("pallas", "masked")}
+    yield lambda: {p: telemetry.counter(COUNTER, path=p).get() - n
+                   for p, n in start.items()}
+    telemetry.enable(was)
+
+
+def _attention(q, k, v, iq, ik, iw):
+    return get_op("_contrib_sparse_gqa_attention").impl(
+        q, k, v, iq, ik, iw, jnp.zeros((2,), F32), top_k=48)
+
+
+HIDDEN = 32
+
+
+def _mixer_form(q, k, iq):
+    """(the mixer op at q, k and the index queries' shapes, a hidden
+    state and weights for it)."""
+    b, length, heads, d = q.shape
+    kv, (ih, idim) = k.shape[2], iq.shape[2:]
+    keys = iter(jax.random.split(jax.random.key(7), 8))
+
+    def w(*shape):
+        return (0.3 * jax.random.normal(next(keys), shape, F32)) \
+            .astype(q.dtype)
+
+    ones = lambda n: jnp.ones((n,), q.dtype)
+    args = (w(b, length, HIDDEN), ones(HIDDEN), w(heads * d, HIDDEN),
+            w(kv * d, HIDDEN), w(kv * d, HIDDEN), w(HIDDEN, heads * d),
+            ones(d), ones(d), w(ih * idim, HIDDEN), w(idim, HIDDEN),
+            w(ih, HIDDEN), ones(idim), jnp.zeros((idim,), q.dtype))
+    op = get_op("_contrib_sparse_gqa_mixer").impl
+    return lambda *a: op(
+        *a, jnp.zeros((2,), F32), num_heads=heads, num_kv_heads=kv,
+        head_dim=d, index_heads=ih, index_head_dim=idim, top_k=48,
+        rope_theta=1e7, rope_sections=(d // 8, 3 * d // 16, 3 * d // 16)), \
+        args
+
+
+def _forms(rung_inputs):
+    """(the op, its arguments) for the attention op and for the mixer."""
+    q, k, v, iq, ik, iw, _ = rung_inputs
+    return {"op": (_attention, (q, k, v, iq, ik, iw)),
+            "mixer": _mixer_form(q, k, iq)}
+
+
+def _kernel_calls(fn, args):
+    """How many of each kernel the gradient of both outputs holds, the
+    recomputation included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    def loss(*a):
+        out = fn(*a)
+        return jnp.sum(out[0].astype(F32)) + out[1][0]
+
+    floats = tuple(i for i, a in enumerate(args)
+                   if jnp.issubdtype(a.dtype, jnp.floating))
+    names = list(walk(jax.make_jaxpr(jax.grad(loss, floats))(*args).jaxpr))
+    return {n: names.count(n) for n in set(names)}
+
+
+def _two_devices():
+    return auto_partitioned(Mesh(np.array(jax.devices()[:2]), ("dp",)))
+
+
+RUNGS = {
+    # name: (length, d, dtype, scope to trace in)
+    "float32_inputs": (TILE, 128, F32, None),
+    "two_device_mesh": (TILE, 128, BF, _two_devices),
+    "ragged_length": (TILE + 8, 128, BF, None),
+    "head_width_off_the_lanes": (TILE, 64, BF, None),
+}
+
+
+@pytest.mark.parametrize("form", ["op", "mixer"])
+@pytest.mark.parametrize("rung", sorted(RUNGS))
+def test_each_rung_takes_the_composition_and_is_counted_masked(rung, form,
+                                                               counted):
+    length, d, dtype, scope = RUNGS[rung]
+    inputs = _inputs(1, length, 2, 1, d, dtype=dtype)
+    fn, args = _forms(inputs)[form]
+    if scope is None:
+        assert not S.sparse_gqa_available(*inputs[:3], TILE)
+        calls = _kernel_calls(fn, args)
+    else:
+        with scope():
+            assert not S.sparse_gqa_available(*inputs[:3], TILE)
+            calls = _kernel_calls(fn, args)
+    assert calls == {}
+    assert counted() == {"pallas": 0, "masked": 1}
+
+
+@pytest.mark.parametrize("form", ["op", "mixer"])
+def test_no_chip_and_no_interpretation_asked_takes_the_composition(
+        form, counted, monkeypatch):
+    """Kernels that would be interpreted only because no chip is
+    attached serve nothing: the call is the composition's, as on any
+    CPU; where they will be compiled they serve."""
+    inputs = _inputs(1, TILE, 2, 1)
+    fn, args = _forms(inputs)[form]
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET")
+    assert pallas_common.interpret_mode()
+    assert not S.sparse_gqa_available(*inputs[:3], TILE)
+    assert _kernel_calls(fn, args) == {}
+    assert counted() == {"pallas": 0, "masked": 1}
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    assert S.sparse_gqa_available(*inputs[:3], TILE)
+
+
+@pytest.mark.parametrize("form", ["op", "mixer"])
+def test_an_eligible_call_takes_the_kernels_and_is_counted_pallas(form,
+                                                                  counted):
+    """Two query blocks: one forward kernel (the mixer's recomputation
+    does not run it again), the probabilities a block in the forward
+    and a block in the backward, one backward kernel."""
+    inputs = _inputs(2, 2 * TILE, 2, 1)
+    fn, args = _forms(inputs)[form]
+    assert S.sparse_gqa_available(*inputs[:3], TILE)
+    assert _kernel_calls(fn, args) == {"pallas_sparse_gqa_fwd": 1,
+                                       "pallas_sparse_gqa_probs": 4,
+                                       "pallas_sparse_gqa_bwd": 1}
+    assert counted() == {"pallas": 1, "masked": 0}
+
+
+def test_the_op_on_the_kernel_path_gives_the_composition_s_values(
+        monkeypatch):
+    inputs = _inputs(3, 2 * TILE, 4, 2)
+    fn, args = _forms(inputs)["mixer"]
+
+    def run():
+        def loss(*a):
+            y, index_loss, state = fn(*a)
+            return jnp.sum(y.astype(F32)) + index_loss[0], (y, index_loss,
+                                                            state)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))), has_aux=True))(*args)
+        return [t.astype(F32) for t in out + grads]
+
+    got = run()
+    monkeypatch.setattr(S, "sparse_gqa_available", lambda *a: False)
+    want = run()
+    np.testing.assert_array_equal(got[2][0], want[2][0])   # keys a query
+    _close(got, want, 3e-2)
+
+
+def test_the_mixer_on_the_kernel_path_keeps_thresholds_context_and_lse(
+        capsys):
+    """Beside its arguments the mixer's checkpoint keeps each row's
+    threshold and tie count, the context and the rows' log-sum-exp: no
+    mask, no probabilities, no projection."""
+    inputs = _inputs(4, 2 * TILE, 2, 1)
+    fn, args = _forms(inputs)["mixer"]
+
+    def loss(*a):
+        y, index_loss, _ = fn(*a)
+        return jnp.sum(y.astype(F32)) + index_loss[0]
+
+    jax.ad_checkpoint.print_saved_residuals(loss, *args)
+    kept = [line.split(" ")[0] for line in capsys.readouterr().out
+            .splitlines() if "from the argument" not in line
+            and "from a constant" not in line]
+    n = 2 * TILE
+    assert sorted(kept) == sorted([
+        "u32[1,%d]" % n, "i32[1,%d]" % n, "bf16[1,%d,2,128]" % n,
+        "f32[1,2,1,%d]" % n])
+
+
+def test_a_length_whose_mask_and_keys_do_not_fit_vmem_takes_the_composition(
+        monkeypatch):
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    shape = lambda n, heads: jax.ShapeDtypeStruct((1, n, heads, 128), BF)
+    for n, fits in ((1 << 13, True), (1 << 14, True), (1 << 15, False)):
+        assert S.sparse_gqa_available(shape(n, 32), shape(n, 4), shape(n, 4),
+                                      512) == fits
