@@ -8,9 +8,12 @@ in the backward (docs/TRAINING.md "Decoder layers and recomputation").
 ``keye_vl``: the second, Keye-VL 2.0's language model (a learned
 top-k key selector in front of every attention layer, M-RoPE, a softmax
 router over SwiGLU experts), which returns a second loss beside its
-hidden states."""
+hidden states. ``mellum``: the third, Mellum 2 (sliding-window and full
+attention layers mixed by a per-layer list, a rotary table per layer
+type with YaRN on the full ones, the same router and experts)."""
 from . import vision
 from . import bert
 from . import nemotron_h
 from . import keye_vl
+from . import mellum
 from .vision import get_model

@@ -14,6 +14,19 @@ tiles up to its own: tiles wholly above the diagonal are never visited,
 the diagonal tile is masked, so the work is the composition's at
 ``QUERY_BLOCK`` granularity.
 
+With a ``window`` (a sliding-window layer: query ``t`` sees the keys
+``t - window < s <= t``) the same two kernels visit only the key tiles
+that hold a key of the step's band: the diagonal tile first (every
+query sees itself there, so the running max is finite before a tile
+that hides all its keys from some query), then the tiles wholly inside
+the band, then the one or two tiles that the band's far edge crosses,
+masked below the edge as the diagonal tile is masked above it. At
+``tile`` 512 and ``window`` 1,024 that is three tiles a query tile
+whatever the length. Without ``window`` (or with one no shorter than
+the length) the traced program is the causal one, unchanged. k and v
+stay whole in VMEM either way: the window saves products, not
+residency, and the length is bounded as before.
+
 Both kernels compute every tile transposed, keys x queries
 (``S^T = K_j Q^T``): a query's running max and sum, the saved
 log-sum-exp and ``delta = sum(dO * O)`` are then dense ``(1, tile)``
@@ -48,15 +61,19 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from . import pallas_common
 
-__all__ = ["SCOPE", "causal_gqa_available", "flash_causal_gqa"]
+__all__ = ["SCOPE", "WINDOW_SCOPE", "causal_gqa_available",
+           "flash_causal_gqa"]
 
-# the device-side scope of causal attention, kernel or composition
-# (``decoder_ops._attend`` opens it around either; the backward rule
-# here opens it again, being traced after the caller's has closed)
+# the device-side scopes of causal attention over every earlier key and
+# over a window of them, kernel or composition (``decoder_ops._attend``
+# opens one around either; the backward rule here opens it again,
+# being traced after the caller's has closed)
 SCOPE = "mx.attn.causal"
+WINDOW_SCOPE = "mx.attn.window"
 
 _LANE = 128
 F32 = jnp.float32
@@ -106,6 +123,36 @@ def _seen(tile):
         <= lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
 
 
+def _band(tile, dist, window):
+    """(keys, queries) bool of the tile ``dist`` tiles before the
+    diagonal one: 0 <= query position - key position < window."""
+    ahead = dist * tile \
+        + lax.broadcasted_iota(jnp.int32, (tile, tile), 1) \
+        - lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    return (ahead >= 0) & (ahead < window)
+
+
+def _key_tiles(pl, i, key_tile, tile, length, window):
+    """Run ``key_tile(j, mask)`` over the key tiles that query tile
+    ``i`` sees; ``mask`` (keys x queries, or None for a tile seen
+    whole) is static."""
+    if window is None or window >= length:
+        lax.fori_loop(0, i, lambda j, c: key_tile(j, None), None)
+        key_tile(i, _seen(tile))
+        return
+    key_tile(i, _seen(tile) if window >= tile else _band(tile, 0, window))
+    # before the diagonal: tile i - dist holds the pairs dist * tile -
+    # (tile - 1) .. dist * tile + (tile - 1) positions apart
+    whole = max(window // tile - 1, 0)
+    lax.fori_loop(jnp.maximum(i - whole, 0), i,
+                  lambda j, c: key_tile(j, None), None)
+    for dist in range(whole + 1, length // tile):
+        if (dist - 1) * tile + 1 >= window:
+            break
+        pl.when(i >= dist)(functools.partial(
+            key_tile, i - dist, _band(tile, dist, window)))
+
+
 def _compiler_params(pltpu, semantics, length, d, tile):
     """The backward's working set bounds the forward's too."""
     nbytes = _bwd_vmem_bytes(length, d, tile) + (16 << 20)
@@ -123,7 +170,7 @@ def _block_specs(pl, length, d, tile, rep):
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(b, length, heads, kv, d, tile, interpret):
+def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -138,11 +185,11 @@ def _fwd_call(b, length, heads, kv, d, tile, interpret):
         l_ref[...] = jnp.zeros(l_ref.shape, F32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
 
-        def key_tile(j, diagonal):
+        def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
             st = _dot(k_ref[rows, :], q, _NT) * scale       # keys x queries
-            if diagonal:
-                st = jnp.where(_seen(tile), st, -jnp.inf)
+            if mask is not None:
+                st = jnp.where(mask, st, -jnp.inf)
             m_prev = m_ref[...]
             m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
@@ -153,8 +200,7 @@ def _fwd_call(b, length, heads, kv, d, tile, interpret):
                 v_ref[rows, :], pt.astype(BF16), _TN)       # d x queries
             m_ref[...] = m_next
 
-        lax.fori_loop(0, i, lambda j, c: key_tile(j, False), None)
-        key_tile(i, True)
+        _key_tiles(pl, i, key_tile, tile, length, window)
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
         lse_ref[...] = m_ref[...] + jnp.log(l)
@@ -178,7 +224,7 @@ def _fwd_call(b, length, heads, kv, d, tile, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_call(b, length, heads, kv, d, tile, interpret):
+def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -199,12 +245,12 @@ def _bwd_call(b, length, heads, kv, d, tile, interpret):
         lse, delta = lse_ref[...], delta_ref[...]           # (1, tile)
         dq_acc[...] = jnp.zeros(dq_acc.shape, F32)
 
-        def key_tile(j, diagonal):
+        def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
             kj, vj = k_ref[rows, :], v_ref[rows, :]
             st = _dot(kj, q, _NT) * scale               # keys x queries
-            if diagonal:
-                st = jnp.where(_seen(tile), st, -jnp.inf)
+            if mask is not None:
+                st = jnp.where(mask, st, -jnp.inf)
             pt = jnp.exp(st - lse)
             dv_acc[rows, :] += _dot(pt.astype(BF16), do, _NN)
             dpt = _dot(vj, do, _NT)
@@ -212,8 +258,7 @@ def _bwd_call(b, length, heads, kv, d, tile, interpret):
             dk_acc[rows, :] += _dot(dst, q, _NN)
             dq_acc[...] += _dot(kj, dst, _TN)               # d x queries
 
-        lax.fori_loop(0, i, lambda j, c: key_tile(j, False), None)
-        key_tile(i, True)
+        _key_tiles(pl, i, key_tile, tile, length, window)
         dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
 
         @pl.when((h % rep == rep - 1) & (i == nq - 1))
@@ -240,14 +285,18 @@ def _bwd_call(b, length, heads, kv, d, tile, interpret):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def flash_causal_gqa(q, k, v, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_causal_gqa(q, k, v, tile, window=None, keep=None):
     """Causal ``softmax(Q K^T / sqrt(d)) V``: q (batch, length, heads,
     d), k / v (batch, length, kv_heads, d), all bf16, query head h
     reading key-value head ``h // (heads // kv_heads)``; ``tile``
     queries and keys a tile (check :func:`causal_gqa_available`
-    first)."""
-    return _forward(q, k, v, tile)[0]
+    first). With ``window`` query ``t`` sees the keys ``t - window < s
+    <= t`` only, and only their tiles are visited. ``keep`` names the
+    context and the rows' log-sum-exp (``checkpoint_name``) for a
+    caller whose ``jax.checkpoint`` policy saves them, so that its
+    backward does not run the forward kernel again."""
+    return _forward(q, k, v, tile, window)[0]
 
 
 def _lanes(x):
@@ -255,25 +304,31 @@ def _lanes(x):
     return x.reshape(x.shape[:2] + (-1,))
 
 
-def _forward(q, k, v, tile):
+def _static(window):
+    return None if window is None else int(window)
+
+
+def _forward(q, k, v, tile, window):
     b, length, heads, d = q.shape
     call = _fwd_call(b, length, heads, k.shape[2], d, int(tile),
-                     pallas_common.interpret_mode())
+                     _static(window), pallas_common.interpret_mode())
     o, lse = call(_lanes(q), _lanes(k), _lanes(v))
     return o.reshape(q.shape), lse
 
 
-def _vjp_fwd(q, k, v, tile):
-    o, lse = _forward(q, k, v, tile)
+def _vjp_fwd(q, k, v, tile, window, keep):
+    o, lse = _forward(q, k, v, tile, window)
+    if keep is not None:
+        o, lse = checkpoint_name(o, keep), checkpoint_name(lse, keep)
     return o, (q, k, v, o, lse)
 
 
-def _vjp_bwd(tile, res, do):
+def _vjp_bwd(tile, window, keep, res, do):
     q, k, v, o, lse = res
     b, length, heads, d = q.shape
     call = _bwd_call(b, length, heads, k.shape[2], d, int(tile),
-                     pallas_common.interpret_mode())
-    with jax.named_scope(SCOPE):
+                     _static(window), pallas_common.interpret_mode())
+    with jax.named_scope(SCOPE if window is None else WINDOW_SCOPE):
         do = do.astype(BF16)
         delta = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1) \
             .transpose(0, 2, 1)[:, :, None, :]

@@ -389,3 +389,90 @@ def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
     assert "tpu_custom_call" not in text
     for scope in cfgmod.SCOPES:
         assert scope in text, scope
+
+
+# ---------------------------------------------------------------------------
+# Mellum 2's two mixers at the published widths (hidden 2304, 32 / 4
+# heads of 128, 16 of 64 experts of width 896) and the cell's 16,384
+# tokens: what a step of the long-context cell is made of
+# ---------------------------------------------------------------------------
+def _rotary_mixer_gradient(one_chip, length, **attrs):
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_rotary_gqa_mixer").impl
+    hidden, h, kv, d = 2304, 32, 4, 128
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((h * d, hidden), BF), ((kv * d, hidden), BF),
+              ((kv * d, hidden), BF), ((hidden, h * d), BF), ((d,), BF),
+              ((d,), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(jax.value_and_grad(
+        lambda *a: _sum32(op(*a, num_heads=h, num_kv_heads=kv, head_dim=d,
+                             rope_theta=5e5, eps=1e-6, **attrs)),
+        argnums=tuple(range(8)))).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kind, attrs, scope, other", [
+    ("sliding", dict(window=1024), "mx.attn.window", "mx.attn.causal"),
+    ("full", dict(rope_yarn=(16, 8192, 32, 1),
+                  attention_factor=1.2772588722239782),
+     "mx.attn.causal", "mx.attn.window")])
+def test_rotary_mixer_at_16384_takes_the_kernel_under_its_kind_s_scope(
+        one_chip, compiled_mode, kind, attrs, scope, other):
+    """Both kinds of Mellum 2's attention layer at the cell's length:
+    Mosaic accepts the windowed kernels (a loop from a traced first
+    tile, a ``cond`` around the band's tile) and the causal ones at
+    twice the Nemotron cell's length; the forward kernel is in the
+    program once (the mixer's recomputation keeps the context and the
+    log-sum-exp), the backward once; both under the scope the benchmark
+    reads for that kind, and the whole mixer's temporaries stay under a
+    gigabyte and a half."""
+    from mxbench import scopes
+    compiled = _rotary_mixer_gradient(one_chip, 16384, **attrs)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, [scope, other, "mx.attn.rotary"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_causal_gqa_")}
+    assert len(calls) == len(kernels) == 2
+    assert set(kernels.values()) == {scope}
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "pallas_causal_gqa_bwd", "pallas_causal_gqa_fwd"]
+    assert other not in placed.values()
+    assert "mx.attn.rotary" in placed.values()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    # no score block: 512 queries against a band, or against every key
+    assert "f32[1,4,8,512," not in text
+
+
+def test_expert_mixer_at_16384_chunks_its_blocks(one_chip):
+    """16,384 tokens over 16 held experts of 64 at top 8 fill a buffer
+    of 144 blocks, three times what one batched product takes
+    (``BLOCKS_AT_ONCE``): the product runs as a loop over chunks of
+    blocks, and the mixer's gradient keeps under 4 GB of temporaries
+    (9.5 GB as one product, which the step cannot give it)."""
+    from mxnet_tpu.ops import decoder_ops as D, get_op
+    op = get_op("_contrib_moe_mixer").impl
+    length, hidden, width, held, routed = 16384, 2304, 896, 16, 64
+
+    def loss(x, g, r, w1, w2):
+        y, _ = op(x, g, r, jnp.zeros((2, held), jnp.float32), w1, w2,
+                  top_k=8, score_func="softmax", activation="swiglu",
+                  eps=1e-6)
+        return _sum32(y)
+
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((routed, hidden), BF), ((held, 2 * width, hidden), BF),
+              ((held, hidden, width), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*args).compile()
+    assert 144 > D.BLOCKS_AT_ONCE and 144 % D.BLOCKS_A_CHUNK == 0
+    text = compiled.as_text()
+    chunk = "%d,%d,512,%d" % (144 // D.BLOCKS_A_CHUNK, D.BLOCKS_A_CHUNK,
+                              2 * width)
+    assert "f32[%s]" % chunk in text        # a chunk's kept hidden layer
+    assert "bf16[144,%d,%d]" % (2 * width, hidden) not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9

@@ -15,6 +15,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_causal_gqa as P
 from mxnet_tpu.ops.pallas_common import auto_partitioned
 from test_decoder_ops import _attention_ref
+from test_rotary_window_ops import _window_ref
 
 F32, BF = jnp.float32, jnp.bfloat16
 COUNTER = "mx_attn_causal_path_total"
@@ -168,3 +169,100 @@ def test_a_length_whose_keys_do_not_fit_vmem_takes_the_composition():
     shape = lambda heads: jax.ShapeDtypeStruct((1, 1 << 13, heads, 128), BF)
     assert P.causal_gqa_available(shape(32), shape(2), shape(2),
                                   D.QUERY_BLOCK)
+
+
+# ---------------------------------------------------------------------------
+# the sliding window: only the band's key tiles are visited
+# ---------------------------------------------------------------------------
+WINDOW_COUNTER = "mx_attn_window_path_total"
+
+
+@pytest.mark.parametrize("length, tile, window", [
+    (512, 128, 200),    # the band's edge inside a tile: two tiles masked
+    (512, 128, 256),    # on a tile boundary: one tile masked, one whole
+    (384, 128, 100),    # narrower than a tile: the diagonal tile banded too
+    (256, 128, 256),    # the length: every key seen, the causal program
+    (256, 128, 1000)],
+    ids=["inside", "boundary", "narrow", "length", "beyond"])
+def test_windowed_kernel_matches_a_whole_mask_and_the_composition(
+        length, tile, window):
+    q, k, v, cot = _qkv(length + window, length, 2, 1)
+    got = _value_and_grads(
+        lambda *a: P.flash_causal_gqa(*a, tile, window), q, k, v, cot)
+    _close(got, _value_and_grads(
+        lambda *a: D._causal_gqa(*a, tile, window), q, k, v, cot), 2e-2)
+    _close(got, _value_and_grads(
+        lambda *a: _window_ref(*a, window),
+        *(t.astype(F32) for t in (q, k, v, cot))), 2e-2)
+
+
+@pytest.mark.parametrize("t", [130, 255, 256, 383])
+def test_a_key_before_the_band_never_reaches_output_t(t):
+    """Keys at or before ``t - window`` do not move row ``t``; the
+    band's first key does."""
+    window = 130
+    q, k, v, _ = _qkv(6, 384, 4, 2)
+    out = P.flash_causal_gqa(q, k, v, 128, window)
+    before = (jnp.arange(384) <= t - window)[None, :, None, None]
+    moved = P.flash_causal_gqa(q, jnp.where(before, k + 3, k),
+                               jnp.where(before, v - 2, v), 128, window)
+    np.testing.assert_array_equal(np.asarray(out[:, t:], F32),
+                                  np.asarray(moved[:, t:], F32))
+    first = (jnp.arange(384) == t - window + 1)[None, :, None, None]
+    moved = P.flash_causal_gqa(q, k, jnp.where(first, v - 2, v), 128, window)
+    assert not np.array_equal(np.asarray(out[:, t], F32),
+                              np.asarray(moved[:, t], F32))
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_without_a_window_the_kernel_s_program_is_the_causal_one():
+    """``window=None``, and a window no shorter than the length, trace
+    the kernels they traced before the argument existed: one loop from
+    tile 0 and the diagonal tile, no ``cond`` for a band's tile."""
+    q, k, v, cot = _qkv(7, 384, 2, 1)
+
+    def grad_text(*window):
+        fn = lambda *a: jnp.sum(P.flash_causal_gqa(*a, 128, *window)
+                                .astype(F32))
+        return str(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(q, k, v))
+
+    plain = grad_text()
+    assert plain == grad_text(None) == grad_text(None, None)
+    assert plain == grad_text(384) == grad_text(5000)
+    banded = grad_text(130)
+    assert banded != plain
+    # beside the two that open and close a group's dk / dv: one for
+    # each tile that the band's far edge crosses, forward and backward
+    assert banded.count("cond[") == plain.count("cond[") + 4
+
+
+def test_a_windowed_call_is_counted_in_a_series_of_its_own(counted):
+    was = {p: telemetry.counter(WINDOW_COUNTER, path=p).get()
+           for p in ("pallas", "xla")}
+    q, k, v, _ = _qkv(8, D.QUERY_BLOCK, 2, 1)
+    assert _pallas_calls(lambda *a: D._attend(*a, window=100), q, k, v) == 2
+    assert _pallas_calls(lambda *a: D._attend(
+        *(t.astype(F32) for t in a), window=100), q, k, v) == 0
+    now = {p: telemetry.counter(WINDOW_COUNTER, path=p).get() - n
+           for p, n in was.items()}
+    assert now == {"pallas": 1, "xla": 1}
+    assert counted() == {"pallas": 0, "xla": 0}     # full-causal calls only
+
+
+def test_the_scope_follows_the_kind_forward_and_backward():
+    q, k, v, _ = _qkv(9, D.QUERY_BLOCK, 2, 1)
+
+    def text(**kw):
+        fn = lambda *a: jnp.sum(D._attend(*a, **kw).astype(F32))
+        return jax.jit(jax.grad(fn, (0, 1, 2))).lower(q, k, v).as_text(
+            debug_info=True)
+
+    windowed, causal = text(window=100), text()
+    assert "mx.attn.window" in windowed and "mx.attn.causal" not in windowed
+    assert "mx.attn.causal" in causal and "mx.attn.window" not in causal
