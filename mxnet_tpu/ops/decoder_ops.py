@@ -11,7 +11,7 @@ arXiv:2405.21060 sec. 6-7 for the scan; DeepSeek-V3.2-Exp's sparse
 attention for the selector; the layer equations are those of
 docs/KERNELS.md "Hybrid decoder ops".)
 
-All but two are XLA compositions, which a GSPMD mesh partitions like
+All but three are XLA compositions, which a GSPMD mesh partitions like
 any other op. Causal attention has two schedules of one algorithm: a
 Pallas flash kernel (``ops/pallas_causal_gqa.py``) where the call is
 one it can serve, the blocked composition here everywhere else
@@ -19,7 +19,11 @@ one it can serve, the blocked composition here everywhere else
 (:func:`_sparse_attend`): the selection is always the XLA code here,
 the attention over the selected set runs in flash kernels that take the
 set as a mask (``ops/pallas_sparse_gqa.py``) or as the masked
-composition. Matrix products take their inputs in the dtype they
+composition. The expert buffer's products likewise
+(:func:`_blocks_product`): grouped kernels that read each block's
+weight tiles from the experts' own arrays
+(``ops/pallas_grouped_mlp.py``), or the batched product over gathered
+copies of them. Matrix products take their inputs in the dtype they
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
@@ -53,7 +57,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
-from . import pallas_causal_gqa, pallas_sparse_gqa, register
+from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_sparse_gqa,
+               register)
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -983,23 +988,32 @@ _sum_slots.defvjp(
 _HIDDEN = "mx.moe.experts.hidden"   # what a chunk of blocks keeps
 
 
-def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act):
+def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act, kernel):
     """Each block of ``xr`` (blocks, rows, hidden) through its expert's
     two products, each row times its slot's weight: (blocks x rows,
-    hidden) in ``xr``'s dtype. Every block reads a copy of its expert's
-    weights, and the backward writes a float32 gradient a block before
-    summing by expert; so up to ``BLOCKS_AT_ONCE`` blocks that is one
-    batched product, and beyond it a loop over chunks of blocks, each
-    chunk keeping its first product's output and gathering its weights
-    again in the backward: the copies that exist at once are a chunk's,
-    not the buffer's, and the float32 rows a chunk's (144 blocks of 8.3
-    M weights at 16,384 tokens over 16 experts of width 896: 9.5 GB of
+    hidden) in ``xr``'s dtype, every block computed, by the schedule
+    :func:`_moe_experts` picked (``kernel``). The grouped
+    kernels take the whole buffer in one call and read each block's
+    weight tiles where the experts' arrays hold them; their backward
+    sums the weight gradients by expert in VMEM. In the composition
+    every block reads a copy of its expert's weights, and the backward
+    writes a float32 gradient a block before summing by expert; so up
+    to ``BLOCKS_AT_ONCE`` blocks that is one batched product, and
+    beyond it a loop over chunks of blocks, each chunk keeping its
+    first product's output and gathering its weights again in the
+    backward: the copies that exist at once are a chunk's, not the
+    buffer's, and the float32 rows a chunk's (144 blocks of 8.3 M
+    weights at 16,384 tokens over 16 experts of width 896: 9.5 GB of
     temporaries as one product)."""
     def product(xb, eb, kept=lambda pre: pre):
         pre = kept(_mm("bmd,bfd->bmf", xb, up[eb]))
         return _mm("bmf,bdf->bmd", act(pre).astype(xb.dtype), down[eb])
 
     n, block = xr.shape[:2]
+    if kernel:
+        return pallas_grouped_mlp.grouped_mlp(
+            xr.reshape(n * block, -1), expert_of_block, weight_of_row, up,
+            down, act)
     if n <= BLOCKS_AT_ONCE:
         yr = product(xr, expert_of_block).reshape(n * block, -1)
         return (yr * weight_of_row[:, None]).astype(xr.dtype)
@@ -1021,7 +1035,8 @@ def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act):
         .reshape(n * block, -1)
 
 
-def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act):
+def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act,
+                    kernel):
     """Rows gathered into one buffer sorted by expert, whole blocks an
     expert; one batched product over the blocks, each against its
     expert's weights (the same work whatever the routing: a block is
@@ -1035,7 +1050,8 @@ def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act):
     weight_of_row = jnp.zeros((cap + 1,), F32) \
         .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1]
     xr = _gather_rows(x, token_of_row, row).reshape(-1, block, x.shape[1])
-    yr = _blocks_product(xr, expert_of_block, weight_of_row, up, down, act)
+    yr = _blocks_product(xr, expert_of_block, weight_of_row, up, down, act,
+                         kernel)
     filled = (token_of_row < t).reshape(-1, block)
     done = jnp.sum(jnp.where(
         expert_of_block[:, None] == jnp.arange(n_held),
@@ -1066,6 +1082,70 @@ def _experts_dense(x, held, local, w_slot, counts, up, down, act):
     return acc.astype(x.dtype)
 
 
+def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel):
+    """(the held experts' terms summed by token, the rows of each that
+    were computed): the sorted buffer where the routing fits it. No row
+    is dropped: routing that overfills the buffer takes the dense
+    product over the held experts instead (whole matrices at the MXU's
+    pace: where routing piles the tokens on a few experts, cheaper than
+    more passes of the gathered product, which PR 28 measured at 5x its
+    cost). ``routing``: :func:`_slots_to_rows`' four results, ``held``
+    and ``local``."""
+    row, counts, expert_of_block, fits, held, local = routing
+    return lax.cond(
+        fits,
+        lambda: _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
+                                block, act, kernel),
+        lambda: (_experts_dense(x, held, local, w_slot, counts, w1, w2, act),
+                 counts))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act):
+    """:func:`_held_terms` on the kernel path, differentiated by hand so
+    that nothing but its inputs crosses from the forward to the
+    backward. Differentiated as it stands, the ``cond`` hands on each
+    branch's residuals and fills the other branch's with zeros; the
+    kernels' residuals include the experts' weights as they lie, so
+    every layer's ``cond`` would return a copy of them and the program
+    carry a zero array of their size (144 MiB in the Keye-VL cell, whose
+    step then no longer fits the chip: PERF.md section 6, PR 35). Here
+    the backward is a ``cond`` of its own over the same ``fits``, each
+    branch the pullback of the forward's branch; the caller's
+    ``jax.checkpoint`` recomputes nothing for it."""
+    return _held_terms(x, w_slot, w1, w2, routing, block, act, True)
+
+
+def _kept_by_inputs_fwd(x, w_slot, w1, w2, routing, block, act):
+    return _held_terms(x, w_slot, w1, w2, routing, block, act, True), \
+        (x, w_slot, w1, w2, routing)
+
+
+def _kept_by_inputs_bwd(block, act, res, cotangents):
+    x, w_slot, w1, w2, routing = res
+    row, counts, expert_of_block, fits, held, local = routing
+
+    def pullback(branch):
+        return lambda: jax.vjp(branch, x, w_slot, w1, w2)[1](cotangents[0])
+
+    # (the rule is traced after the caller's scopes have closed)
+    with jax.named_scope("mx.moe"), jax.named_scope("mx.moe.experts"):
+        grads = lax.cond(
+            fits,
+            pullback(lambda x, w_slot, w1, w2: _experts_sorted(
+                x, row, w_slot, expert_of_block, w1, w2, block, act,
+                True)[0]),
+            pullback(lambda x, w_slot, w1, w2: _experts_dense(
+                x, held, local, w_slot, counts, w1, w2, act)))
+    # the four gradients leave together: without the barrier the TPU
+    # compiler's buffer assignment for the Keye-VL cell's step needs
+    # 31 MB more than the chip has (PERF.md section 6, PR 35)
+    return tuple(lax.optimization_barrier(grads)) + (None,)
+
+
+_held_terms_kept_by_inputs.defvjp(_kept_by_inputs_fwd, _kept_by_inputs_bwd)
+
+
 def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
                  norm_topk, score_func="sigmoid", activation="relu2",
                  capacity_factor=CAPACITY_FACTOR, block_rows=BLOCK_ROWS):
@@ -1087,23 +1167,26 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
                  + n_held)
     row, counts, expert_of_block, fits = _slots_to_rows(
         held, local, n_held, blocks * block, block)
+    # the buffer's products by whichever schedule the call allows,
+    # chosen from what can be observed here and nothing else (bf16 rows
+    # and weights of sizes the kernels' tiles take, traced for one
+    # device, kernels that will be compiled or whose interpretation was
+    # asked for), counted once a traced call
+    kernel = pallas_grouped_mlp.grouped_mlp_available(
+        jax.ShapeDtypeStruct((blocks, block, x.shape[1]), x.dtype), w1, w2)
+    telemetry.count_event("mx_moe_experts_path_total",
+                          path="pallas" if kernel else "xla")
+    routing = (row, counts, expert_of_block, fits, held, local)
     with jax.named_scope("mx.moe.experts"):
-        def sorted_rows():
-            return _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                   block, act)
-
         if blocks >= most:
-            y, done = sorted_rows()
+            y, done = _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
+                                      block, act, kernel)
+        elif kernel:
+            y, done = _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing,
+                                                 block, act)
         else:
-            # no row is dropped: routing that overfills the buffer takes
-            # the dense product over the held experts instead (whole
-            # matrices at the MXU's pace: where routing piles the tokens
-            # on a few experts, cheaper than more passes of the gathered
-            # product, which PR 28 measured at 5x its cost)
-            y, done = lax.cond(
-                fits, sorted_rows,
-                lambda: (_experts_dense(x, held, local, w_slot, counts, w1,
-                                        w2, act), counts))
+            y, done = _held_terms(x, w_slot, w1, w2, routing, block, act,
+                                  False)
     return y, jnp.stack([counts, done]).astype(F32)
 
 
@@ -1125,8 +1208,10 @@ _MOE_DOC = """
     the rows are moved. Rows are gathered, sorted by expert
     and padded to whole blocks of ``BLOCK_ROWS`` an expert, into one
     buffer of ``CAPACITY_FACTOR`` times the held experts' even share
-    (plus a block an expert), and multiplied in one batched product
-    over the blocks: the same work whatever the routing fills it with.
+    (plus a block an expert), and every block multiplied by its
+    expert's weights (grouped Pallas kernels where the call allows
+    them, :func:`_blocks_product`, else one batched product over the
+    blocks): the same work whatever the routing fills it with.
     Routing that overfills the buffer takes a dense product over the
     held experts that are routed a row instead, so no row is ever
     dropped. ``expert_rows`` (2, held) float32 is an auxiliary state

@@ -10,6 +10,7 @@ compile happens in the test's own process. A compile that passes is not
 a chip run; ``chip_smoke.py`` is.
 """
 import os
+import re
 
 import pytest
 
@@ -165,7 +166,17 @@ def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
 # compositions, no kernel of this repo's or of the compiler's own; but
 # attention through its op, which takes the flash kernel (last test)
 # ---------------------------------------------------------------------------
-def test_expert_product_follows_the_buffer_not_the_experts(one_chip):
+@pytest.fixture
+def composed_experts(monkeypatch):
+    """The expert buffer's products as the XLA composition, the grouped
+    kernels stood down (a plain CPU's answer, made explicit)."""
+    from mxnet_tpu.ops import pallas_grouped_mlp
+    monkeypatch.setattr(pallas_grouped_mlp, "grouped_mlp_available",
+                        lambda *a: False)
+
+
+def test_expert_product_follows_the_buffer_not_the_experts(one_chip,
+                                                           composed_experts):
     from mxnet_tpu.ops import decoder_ops as D
     t, hidden, width, held, routed = 8192, 2688, 1856, 8, 128
 
@@ -446,12 +457,13 @@ def test_rotary_mixer_at_16384_takes_the_kernel_under_its_kind_s_scope(
     assert "f32[1,4,8,512," not in text
 
 
-def test_expert_mixer_at_16384_chunks_its_blocks(one_chip):
+def test_expert_mixer_at_16384_chunks_its_blocks(one_chip, composed_experts):
     """16,384 tokens over 16 held experts of 64 at top 8 fill a buffer
     of 144 blocks, three times what one batched product takes
-    (``BLOCKS_AT_ONCE``): the product runs as a loop over chunks of
-    blocks, and the mixer's gradient keeps under 4 GB of temporaries
-    (9.5 GB as one product, which the step cannot give it)."""
+    (``BLOCKS_AT_ONCE``): the composition's product runs as a loop over
+    chunks of blocks, and the mixer's gradient keeps under 4 GB of
+    temporaries (9.5 GB as one product, which the step cannot give
+    it)."""
     from mxnet_tpu.ops import decoder_ops as D, get_op
     op = get_op("_contrib_moe_mixer").impl
     length, hidden, width, held, routed = 16384, 2304, 896, 16, 64
@@ -476,3 +488,118 @@ def test_expert_mixer_at_16384_chunks_its_blocks(one_chip):
     assert "f32[%s]" % chunk in text        # a chunk's kept hidden layer
     assert "bf16[144,%d,%d]" % (2 * width, hidden) not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+# ---------------------------------------------------------------------------
+# the expert mixer of the three decoder cells at their published widths:
+# the buffer's products are the grouped kernels of ops/pallas_grouped_mlp
+# where the widths are whole lane tiles
+# ---------------------------------------------------------------------------
+def _expert_mixer_args(sharding, length, hidden, width, held, routed, mul):
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((routed, hidden), BF), ((held, mul * width, hidden), BF),
+              ((held, hidden, width), BF)]
+    return [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+
+
+def _expert_mixer_gradient(held, **attrs):
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_moe_mixer").impl
+
+    def loss(x, g, r, w1, w2):
+        y, _ = op(x, g, r, jnp.zeros((2, held), jnp.float32), w1, w2,
+                  eps=1e-6, **attrs)
+        return _sum32(y)
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+
+
+EXPERT_CELLS = {
+    # length, hidden, width, held, routed, rows of w1 a width, blocks
+    "mellum2": ((16384, 2304, 896, 16, 64, 2), 144,
+                dict(top_k=8, score_func="softmax", activation="swiglu")),
+    "keye_vl": ((8192, 2048, 768, 16, 128, 2), 48,
+                dict(top_k=8, score_func="softmax", activation="swiglu")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
+        one_chip, compiled_mode, cell):
+    """Mosaic accepts the three kernels at both cells' widths; a step's
+    seven calls (two
+    forward, the first again in the mixer's recomputation, four
+    backward) are placed under the scope the benchmark reads; no
+    gathered copy of a weight and no float32 gradient a block exists;
+    and the Mellum 2 mixer's gradient needs 1.9 GB of temporaries where
+    the chunked composition needs 3.63."""
+    from mxbench import scopes
+    sizes, blocks, attrs = EXPERT_CELLS[cell]
+    length, hidden, width, held, routed, mul = sizes
+    compiled = jax.jit(_expert_mixer_gradient(held, **attrs)).lower(
+        *_expert_mixer_args(one_chip, *sizes)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, ["mx.moe.experts", "mx.moe"])
+    # (a call traced inside the backward's ``jax.vjp`` is named
+    # ``jvp_pallas_grouped_mlp_nt_``)
+    kernels = {name: s for name, s in placed.items()
+               if "pallas_grouped_mlp_" in name}
+    assert len(calls) == len(kernels) == 7
+    assert set(kernels.values()) == {"mx.moe.experts"}
+    assert sorted(re.search("pallas_grouped_mlp_(dw|nn|nt)", n).group(1)
+                  for n in kernels) == ["dw"] * 2 + ["nn"] * 2 + ["nt"] * 3
+    assert "s32[%d]" % blocks in text
+    for out, inner in ((mul * width, hidden), (hidden, width)):
+        assert "bf16[%d,%d,%d]" % (blocks, out, inner) not in text
+        assert "f32[%d,%d,%d]" % (blocks, out, inner) not in text
+    memory = compiled.memory_analysis()
+    # nothing but its inputs crosses the overflow ``cond`` (a copy of
+    # the weights and a zero array of their size did: 168 MB of program
+    # at the Keye-VL widths for 17)
+    assert memory.generated_code_size_in_bytes < 40e6
+    if cell == "mellum2":
+        assert memory.temp_size_in_bytes < 2.5e9
+
+
+def test_expert_mixer_off_the_lane_tiles_keeps_the_composition(one_chip,
+                                                               compiled_mode):
+    """The Nemotron cell's width, 1,856, is 14.5 lane tiles:
+    ``grouped_mlp_available`` says no (Mosaic takes the width as one
+    whole tile, but the step's AUTO parameter layouts then do not
+    survive the persistent compile cache: PERF.md section 6, PR 35),
+    and the mixer's gradient compiles with no Mosaic call."""
+    from mxnet_tpu.ops import pallas_grouped_mlp
+    sizes = (8192, 2688, 1856, 8, 128, 1)
+    assert not pallas_grouped_mlp.grouped_mlp_available(
+        jax.ShapeDtypeStruct((20, 512, 2688), BF),
+        jax.ShapeDtypeStruct((8, 1856, 2688), BF),
+        jax.ShapeDtypeStruct((8, 2688, 1856), BF))
+    text = jax.jit(_expert_mixer_gradient(
+        8, top_k=6, routed_scaling_factor=2.5)).lower(
+            *_expert_mixer_args(one_chip, *sizes)).compile().as_text()
+    assert "tpu_custom_call" not in text
+
+
+def test_expert_mixer_under_a_mesh_keeps_the_composition(one_chip,
+                                                         compiled_mode):
+    """Traced for a program GSPMD partitions over the described 2 x 2
+    chips, the expert buffer's kernels stand down: the mixer compiles
+    there with no Mosaic call."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.pallas_common import auto_partitioned
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    sizes, _, attrs = EXPERT_CELLS["keye_vl"]
+    args = _expert_mixer_args(NamedSharding(mesh, P()), *sizes)
+    args[0] = jax.ShapeDtypeStruct((4,) + args[0].shape[1:], BF,
+                                   sharding=NamedSharding(mesh, P("dp")))
+    with auto_partitioned(mesh):
+        text = jax.jit(_expert_mixer_gradient(sizes[3], **attrs)) \
+            .lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text

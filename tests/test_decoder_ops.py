@@ -44,6 +44,54 @@ def _same_values_and_grads(fn, ref, args, tol=2e-5):
 
 
 # ---------------------------------------------------------------------------
+def _near(got, want, rel):
+    """Every leaf within ``rel`` of the wanted leaf's largest entry (a
+    bf16 path against float32 numbers)."""
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert g.shape == w.shape
+        assert np.abs(g - w).max() <= rel * max(np.abs(w).max(), 1e-6)
+
+
+@pytest.fixture(params=["xla", "pallas"])
+def path(request, monkeypatch):
+    """The expert buffer's two schedules: the composition as every test
+    here runs it (float32, toy widths), and the grouped kernels,
+    interpreted, on the calls they serve (bf16 rows and weights, hidden
+    and width of a lane tile: :func:`_experts_on`)."""
+    if request.param == "pallas":
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    return request.param
+
+
+def _experts_on(path, weights, seed, matrices):
+    """(weights, x, what the op is given, the tolerances of value and
+    gradient) for a path: on ``pallas`` a lane tile wide and the rows
+    and the experts' ``matrices`` rounded to bf16 (the reference reads
+    the rounded values in float32, so both route alike)."""
+    if path == "xla":
+        w, cfg = weights()
+        (x,) = _rand(seed, (2, 20, 12))
+        return w, cfg, x, lambda name, a: a, _close, \
+            lambda got, want: _close(got, want, 5e-5)
+    w, cfg = weights(hidden=128, width=128)
+    (x,) = _rand(seed, (2, 20, 128))
+    x = x.astype(jnp.bfloat16).astype(F32)
+    w = {n: a.astype(jnp.bfloat16).astype(F32) if n in matrices else a
+         for n, a in w.items()}
+
+    def given(name, a):
+        return a.astype(jnp.bfloat16) if name in matrices + ("x",) else a
+
+    return w, cfg, x, given, lambda got, want: _near(got, want, 2e-2), \
+        lambda got, want: _near(got, want, 3e-2)
+
+
+def _takes_the_kernels(fn, *args):
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
 @pytest.mark.parametrize("shape", [(3, 16), (2, 5, 24)])
 def test_rms_norm(shape):
     x, w = _rand(0, shape, shape[-1:])
@@ -197,27 +245,33 @@ def _moe(x, w, cfg, capacity_factor=None):
 
 
 @pytest.mark.parametrize("capacity_factor", [0.25, None, 100.0])
-def test_routed_experts(capacity_factor):
+def test_routed_experts(capacity_factor, path):
     """Buffers too small for the routing (the dense path), the
     default, and buffers no routing can overfill: the same numbers and
-    gradients as the reference's loop over the held experts."""
-    w, cfg = _moe_weights(10)
-    (x,) = _rand(11, (2, 20, 12))
+    gradients as the reference's loop over the held experts, by the
+    composition and by the grouped kernels."""
+    w, cfg, x, given, value_close, grad_close = _experts_on(
+        path, lambda **kw: _moe_weights(10, **kw), 11,
+        ("experts_up_weight", "experts_down_weight"))
     names = sorted(w)
 
     def fn(x, *ws):
-        return _moe(x, dict(zip(names, ws)), cfg, capacity_factor)[0]
+        ws = {n: given(n, a) for n, a in zip(names, ws)}
+        return _moe(given("x", x), ws, cfg, capacity_factor)[0].astype(F32)
 
     def ref(x, *ws):
         return REF.experts(dict(zip(names, ws)), "", x, cfg, shared=False)
 
     args = (x,) + tuple(w[n] for n in names)
-    _close(fn(*args), ref(*args))
+    # (a quarter of the buffer is blocks of 8 rows: not a bf16 tile)
+    assert _takes_the_kernels(fn, *args) == (
+        path == "pallas" and capacity_factor != 0.25)
+    value_close(fn(*args), ref(*args))
     cot = _rand(12, x.shape)[0]
     nums = (0,) + tuple(1 + i for i, n in enumerate(names)
                         if n != "e_score_correction_bias")
-    _close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
-           jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args), 5e-5)
+    grad_close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
+               jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args))
 
 
 @pytest.mark.parametrize("capacity_factor", [0.25, None])
@@ -563,32 +617,39 @@ def _swiglu_moe(x, w, cfg, capacity_factor=None):
 
 
 @pytest.mark.parametrize("capacity_factor", [0.25, None, 100.0])
-def test_softmax_swiglu_experts(capacity_factor):
+def test_softmax_swiglu_experts(capacity_factor, path):
     """A softmax router without a bias over gated experts, the dense
     path, the default and a buffer no routing overfills: the numbers
-    and gradients of the reference's loop over the held experts."""
-    w, cfg = _swiglu_weights(40)
-    (x,) = _rand(41, (2, 20, 12))
+    and gradients of the reference's loop over the held experts, by the
+    composition and by the grouped kernels."""
+    w, cfg, x, given, value_close, grad_close = _experts_on(
+        path, lambda **kw: _swiglu_weights(40, **kw), 41,
+        ("experts_gate_up_weight", "experts_down_weight"))
     names = sorted(w)
 
     def fn(x, *ws):
-        return _swiglu_moe(x, dict(zip(names, ws)), cfg, capacity_factor)[0]
+        ws = {n: given(n, a) for n, a in zip(names, ws)}
+        return _swiglu_moe(given("x", x), ws, cfg,
+                           capacity_factor)[0].astype(F32)
 
     def ref(x, *ws):
         return KREF.experts(dict(zip(names, ws)), "", x, cfg)
 
     args = (x,) + tuple(w[n] for n in names)
-    _close(fn(*args), ref(*args))
+    assert _takes_the_kernels(fn, *args) == (
+        path == "pallas" and capacity_factor != 0.25)
+    value_close(fn(*args), ref(*args))
     (cot,) = _rand(42, x.shape)
     nums = tuple(range(len(args)))
-    _close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
-           jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args), 5e-5)
+    grad_close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
+               jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args))
     # through the registered op too, the bias left out
     y, rows = get_op("_contrib_moe_experts").impl(
-        x, w["router_weight"], None, jnp.zeros((2, 4), F32),
-        w["experts_gate_up_weight"], w["experts_down_weight"], top_k=3,
+        given("x", x), w["router_weight"], None, jnp.zeros((2, 4), F32),
+        given("experts_gate_up_weight", w["experts_gate_up_weight"]),
+        given("experts_down_weight", w["experts_down_weight"]), top_k=3,
         expert_offset=4, score_func="softmax", activation="swiglu")
-    _close(y, ref(*args))
+    value_close(y, ref(*args))
     np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
 
 

@@ -1,0 +1,333 @@
+"""The grouped kernels of the expert buffer (ops/pallas_grouped_mlp.py),
+interpreted on the CPU at a lane tile's width: output and every gradient
+(rows, slot weights, both weights, and the router's through the slot
+weights) against the composition they stand in for and against a plain
+float32 loop over the held experts (the benchmark's own references are
+``tests/test_decoder_ops.py::test_routed_experts`` /
+``test_softmax_swiglu_experts``, which run under both paths); the traps
+of the weight-gradient kernel (an expert with no block, the empty blocks
+past the last run, one expert drawing nearly every token); the overflow
+path; the ladder of what the kernels do not serve; the counter. What
+Mosaic makes of the real widths is ``tests/test_chip_compile.py``'s to
+say."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu import telemetry
+from mxnet_tpu.ops import decoder_ops as D, pallas_common
+from mxnet_tpu.ops import pallas_grouped_mlp as G
+
+F32, BF = jnp.float32, jnp.bfloat16
+COUNTER = "mx_moe_experts_path_total"
+HIDDEN = WIDTH = 128
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+
+
+def _rand(seed, *shapes, scale=1.0):
+    keys = jax.random.split(jax.random.key(seed), len(shapes))
+    return [(scale * jax.random.normal(k, s, F32)).astype(BF)
+            for k, s in zip(keys, shapes)]
+
+
+def _near(got, want, rel=2e-2):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert g.shape == w.shape
+        assert np.all(np.isfinite(g))
+        assert np.abs(g - w).max() <= rel * max(np.abs(w).max(), 1e-6)
+
+
+def _pallas_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+# ---------------------------------------------------------------------------
+# the kernels alone: a buffer laid out by hand
+# ---------------------------------------------------------------------------
+ACTS = {"relu2": (D._relu2, 1), "swiglu": (D._swiglu, 2)}
+# 8 blocks over 4 experts: a long run, an expert with no block, a run of
+# one, and two empty blocks mapped to the last expert
+EXPERT_OF_BLOCK = [0, 0, 0, 0, 1, 3, 3, 3]
+BLOCK = 16
+
+
+def _buffer(seed, act):
+    mul = ACTS[act][1]
+    rows = len(EXPERT_OF_BLOCK) * BLOCK
+    x, up, down = _rand(seed, (rows, HIDDEN), (4, mul * WIDTH, HIDDEN),
+                        (4, HIDDEN, WIDTH), scale=0.3)
+    filled = jnp.arange(rows) < 6 * BLOCK       # the last two blocks: empty
+    x = jnp.where(filled[:, None], x, 0).astype(BF)
+    w = jnp.where(filled, jax.random.uniform(jax.random.key(seed + 1),
+                                             (rows,), F32, 0.1, 1.0), 0.0)
+    return x, jnp.array(EXPERT_OF_BLOCK, jnp.int32), w, up, down
+
+
+def _by_hand(x, eob, w, up, down, act):
+    """Block by block in float32, the casts where the paths make them."""
+    out = []
+    for b, e in enumerate(EXPERT_OF_BLOCK):
+        xb = x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
+        h = ACTS[act][0](xb @ up[e].astype(F32).T).astype(BF).astype(F32)
+        out.append(h @ down[e].astype(F32).T)
+    return jnp.concatenate(out) * w[:, None]
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+def test_kernels_against_the_composition_and_float32(interpreted, act):
+    x, eob, w, up, down = _buffer(1, act)
+    fn = ACTS[act][0]
+    (cot,) = _rand(3, x.shape)
+    cot = cot.astype(F32)
+
+    def kernels(x, w, up, down):
+        return jnp.sum(G.grouped_mlp(x, eob, w, up, down, fn).astype(F32)
+                       * cot)
+
+    def composed(x, w, up, down):
+        xr = x.reshape(len(EXPERT_OF_BLOCK), BLOCK, HIDDEN)
+        y = (D._mm("bmf,bdf->bmd", fn(D._mm("bmd,bfd->bmf", xr, up[eob]))
+                   .astype(BF), down[eob]).reshape(x.shape) * w[:, None])
+        return jnp.sum(y.astype(BF).astype(F32) * cot)
+
+    def plain(x, w, up, down):
+        return jnp.sum(_by_hand(x, eob, w, up, down, act) * cot)
+
+    assert G.grouped_mlp_available(
+        x.reshape(len(EXPERT_OF_BLOCK), BLOCK, HIDDEN), up, down)
+    assert _pallas_calls(jax.grad(kernels, (0, 1, 2, 3)), x, w, up, down) == 6
+    _near(G.grouped_mlp(x, eob, w, up, down, fn),
+          _by_hand(x, eob, w, up, down, act))
+    got = jax.grad(kernels, (0, 1, 2, 3))(x, w, up, down)
+    _near(got, jax.grad(composed, (0, 1, 2, 3))(x, w, up, down))
+    _near(got, jax.grad(plain, (0, 1, 2, 3))(x, w, up, down))
+    # the expert no block is mapped to: exact zeros, not what the
+    # kernel's unvisited tiles held; the empty blocks' rows likewise
+    for dw in got[2:]:
+        assert dw.dtype == BF
+        assert float(jnp.max(jnp.abs(dw[2].astype(F32)))) == 0.0
+        assert float(jnp.max(jnp.abs(dw[3].astype(F32)))) > 0.0
+    assert float(jnp.max(jnp.abs(got[0][6 * BLOCK:].astype(F32)))) == 0.0
+
+
+def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
+    """Whatever an unvisited tile of the output holds (here: what the
+    interpreter left there), ``_dw`` returns zeros for that expert, and
+    the last expert's sum includes the empty blocks' zeros."""
+    g, x = _rand(5, (8 * BLOCK, HIDDEN), (8 * BLOCK, WIDTH))
+    eob = jnp.array(EXPERT_OF_BLOCK, jnp.int32)
+    dw = G._dw(g, x, eob, 4)
+    want = jnp.stack([
+        sum((g[b * BLOCK:(b + 1) * BLOCK].astype(F32).T
+             @ x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
+             for b, e in enumerate(EXPERT_OF_BLOCK) if e == held),
+            jnp.zeros((HIDDEN, WIDTH), F32)) for held in range(4)])
+    _near(dw, want, 1e-2)
+    assert float(jnp.max(jnp.abs(dw[2].astype(F32)))) == 0.0
+    np.testing.assert_array_equal(np.asarray(G.visited(eob, 4)),
+                                  [True, True, False, True])
+
+
+# ---------------------------------------------------------------------------
+# through the op: routing decides the buffer
+# ---------------------------------------------------------------------------
+def _layer(seed, act, tokens=256, routed=16, held=4, offset=4):
+    mul = ACTS[act][1]
+    x, up, down = _rand(seed, (tokens, HIDDEN), (held, mul * WIDTH, HIDDEN),
+                        (held, HIDDEN, WIDTH), scale=0.3)
+    r = 0.3 * jax.random.normal(jax.random.key(seed + 7), (routed, HIDDEN),
+                                F32)
+    return x, r, up, down, offset
+
+
+def _experts(x, r, bias, up, down, act, offset, **kw):
+    score = "softmax" if act == "swiglu" else "sigmoid"
+    y, rows = D._moe_experts(x, r, bias, up, down, top_k=2, offset=offset,
+                             scale=1.5, norm_topk=True, score_func=score,
+                             activation=act, **kw)
+    return y.astype(F32), rows
+
+
+def _reference(x, r, bias, up, down, act, offset):
+    """A float32 loop over the held experts, each over every token,
+    weighted by the slot that chose it, on the values the op was
+    given."""
+    score = "softmax" if act == "swiglu" else "sigmoid"
+    x, up, down = (a.astype(F32) for a in (x, up, down))
+    chosen, wk = D._route(x, r, bias, 2, 1.5, True, score)
+    y = jnp.zeros_like(x)
+    for e in range(up.shape[0]):
+        we = jnp.sum(jnp.where(chosen == offset + e, wk, 0.0), axis=-1)
+        y = y + we[:, None] * jnp.matmul(
+            ACTS[act][0](jnp.matmul(x, up[e].T, precision="highest")),
+            down[e].T, precision="highest")
+    return y
+
+
+def _favouring(expert, strength, routed=16):
+    return jnp.zeros((routed,), F32).at[expert].set(strength)
+
+
+CASES = {
+    # what the router alone decides: every held expert a few rows
+    "even": lambda: jnp.zeros((16,), F32),
+    # one held expert drawing nearly every token (the Mellum 2 cell's
+    # layer 3: 14,016 of 16,384): a long run, short ones, empty blocks
+    "one_draws_most": lambda: _favouring(5, 10.0),
+    # a held expert no token is routed to
+    "one_draws_none": lambda: _favouring(6, -10.0),
+    # no token to any held expert: every block empty, mapped to the last
+    "none_held": lambda: jnp.zeros((16,), F32).at[jnp.array([0, 1])]
+    .set(10.0),
+}
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_expert_layer_by_the_kernels(interpreted, monkeypatch, case, act):
+    x, r, up, down, offset = _layer(11, act)
+    bias = CASES[case]()
+
+    def loss(fn):
+        (cot,) = _rand(13, x.shape)
+
+        def of(x, r, up, down):
+            return jnp.sum(fn(x, r, bias, up, down, act, offset)[0]
+                           * cot.astype(F32))
+        return of
+
+    def op(*a):
+        return _experts(*a)
+
+    def ref(*a):
+        return _reference(*a), None
+
+    args = (x, r, up, down)
+    assert _pallas_calls(jax.grad(loss(op), (0, 1, 2, 3)), *args) > 0
+    y, rows = _experts(x, r, bias, up, down, act, offset)
+    got = jax.grad(loss(op), (0, 1, 2, 3))(*args)
+    _near(y, ref(x, r, bias, up, down, act, offset)[0])
+    _near(got, jax.grad(loss(ref), (0, 1, 2, 3))(*args), 3e-2)
+    # the composition on the same call: the same rows counted, numbers
+    # and gradients within bf16 of each other
+    monkeypatch.setattr(G, "grouped_mlp_available", lambda *a: False)
+    assert _pallas_calls(jax.grad(loss(op), (0, 1, 2, 3)), *args) == 0
+    y_xla, rows_xla = _experts(x, r, bias, up, down, act, offset)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_xla))
+    _near(y, y_xla)
+    _near(got, jax.grad(loss(op), (0, 1, 2, 3))(*args), 3e-2)
+    counts = np.asarray(rows[0])
+    if case == "one_draws_most":
+        assert counts[1] > 0.8 * x.shape[0]
+    if case == "none_held":
+        assert not counts.any() and float(jnp.max(jnp.abs(y))) == 0.0
+    for dw in got[2:]:      # an expert routed no row: exact zeros
+        for e in np.flatnonzero(counts == 0):
+            assert float(jnp.max(jnp.abs(dw[e].astype(F32)))) == 0.0
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+def test_overflowing_routing_still_takes_the_dense_product(interpreted, act):
+    """A buffer a quarter of the default's with blocks of a bf16 tile:
+    the kernels are in the program (the sorted branch), the routing
+    overfills the buffer, the dense branch runs, and no row is
+    dropped."""
+    x, r, up, down, offset = _layer(17, act, tokens=512, held=8, offset=0)
+    bias = _favouring(1, 10.0)
+    kw = dict(capacity_factor=0.5)
+
+    def fn(x):
+        return _experts(x, r, bias, up, down, act, offset, **kw)[0]
+
+    text = str(jax.make_jaxpr(fn)(x))
+    assert "pallas_call" in text and "cond" in text
+    y, rows = _experts(x, r, bias, up, down, act, offset, **kw)
+    _near(y, _reference(x, r, bias, up, down, act, offset))
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
+    assert float(rows[0][1]) > 384      # it did overflow the 384 rows
+
+
+# ---------------------------------------------------------------------------
+# what the kernels do not serve, and the counter
+# ---------------------------------------------------------------------------
+def _shapes(block=16, hidden=128, f1=256, f=128, dtype=BF, wdtype=BF):
+    return (jax.ShapeDtypeStruct((4, block, hidden), dtype),
+            jax.ShapeDtypeStruct((2, f1, hidden), wdtype),
+            jax.ShapeDtypeStruct((2, hidden, f), wdtype))
+
+
+LADDER = {
+    "float32 rows": dict(dtype=F32),
+    "float32 weights": dict(wdtype=F32),
+    "a block off the bf16 tile": dict(block=8),
+    "a toy hidden size": dict(hidden=32),
+    "a toy width": dict(f1=64, f=32),
+    "a width off the lane tiles": dict(f1=1856, f=1856),
+    "tiles beyond the budget": dict(block=4096, hidden=8192),
+}
+
+
+@pytest.mark.parametrize("rung", sorted(LADDER))
+def test_what_the_kernels_leave_to_the_composition(interpreted, rung):
+    assert G.grouped_mlp_available(*_shapes())
+    assert not G.grouped_mlp_available(*_shapes(**LADDER[rung]))
+
+
+def test_the_cells_widths(interpreted):
+    for block, hidden, f1, f in [(512, 2304, 1792, 896),     # Mellum 2
+                                 (512, 2048, 1536, 768)]:    # Keye-VL
+        assert G.grouped_mlp_available(*_shapes(block, hidden, f1, f))
+    assert (G._tile(1792), G._tile(2304), G._tile(2048)) == (896, 768, 1024)
+    # the Nemotron cell's 1,856 is 14.5 lane tiles: the composition
+    # (Mosaic takes it whole, the compile cache's layouts do not)
+    assert not G.grouped_mlp_available(*_shapes(512, 2688, 1856, 1856))
+
+
+def test_a_plain_cpu_keeps_the_composition():
+    assert pallas_common.interpret_mode() \
+        and not pallas_common.interpret_asked()
+    assert not G.grouped_mlp_available(*_shapes())
+
+
+def test_a_mesh_of_several_devices_stands_the_kernels_down(interpreted):
+    from jax.sharding import Mesh
+    devices = np.array(jax.devices()[:2])
+    with pallas_common.auto_partitioned(Mesh(devices, ("dp",))):
+        assert not G.grouped_mlp_available(*_shapes())
+    with pallas_common.auto_partitioned(Mesh(devices[:1], ("dp",))):
+        assert G.grouped_mlp_available(*_shapes())
+
+
+def _count(path):
+    return telemetry.counter(COUNTER, path=path).get()
+
+
+@pytest.fixture
+def counting():
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    yield
+    telemetry.enable(was)
+
+
+@pytest.mark.parametrize("path", ["pallas", "xla"])
+def test_a_traced_call_is_counted_once_under_its_path(monkeypatch, counting,
+                                                      path):
+    if path == "pallas":
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    x, r, up, down, offset = _layer(19, "swiglu")
+    before = {p: _count(p) for p in ("pallas", "xla")}
+    fn = jax.jit(jax.grad(lambda x: jnp.sum(
+        _experts(x, r, None, up, down, "swiglu", offset)[0])))
+    fn(x)
+    fn(x)       # compiled: not traced, not counted again
+    other = "xla" if path == "pallas" else "pallas"
+    assert _count(path) == before[path] + 1
+    assert _count(other) == before[other]
