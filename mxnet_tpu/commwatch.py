@@ -354,7 +354,7 @@ _COLL_RE = re.compile(
     r"(ragged-all-to-all|all-reduce|all-gather|reduce-scatter|"
     r"all-to-all|collective-permute|collective-broadcast)"
     r"(?:-start)?\(")
-_INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_INSTR_NAME_RE = telemetry._HLO_INSTRUCTION     # one reader of HLO lines
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 _IOTA_RE = re.compile(

@@ -259,6 +259,9 @@ def fused_lm_head_ce(hidden, weight, bias, labels):
 # computed once); what disappears is the logits round-trips.
 # ---------------------------------------------------------------------------
 _NEG_BIG = -1.0e30    # pad bias: exp(_NEG_BIG - lse) underflows to 0 in f32
+# device-side scope of the streaming head, forward and backward
+# (docs/OBSERVABILITY.md "Device-side scopes")
+HEAD_SCOPE = "mx.head.ce"
 
 
 def _ce_pad(w, b, chunk):
@@ -319,6 +322,12 @@ def _make_chunked_ce(chunk):
         return loss, (h2, w, b, labels, lse)
 
     def bwd(res, dy):
+        # a backward rule is traced outside the scope its call was
+        # made in: it opens the head's scope again
+        with jax.named_scope(HEAD_SCOPE):
+            return _bwd(res, dy)
+
+    def _bwd(res, dy):
         h2, w, b, labels, lse = res
         w3, b2, n = _ce_pad(w, b, chunk)
         T, U = h2.shape
@@ -444,7 +453,7 @@ def chunked_lm_head_ce(hidden, weight, bias, labels, *, chunk_size=0):
     units = hidden.shape[-1]
     h2 = hidden.reshape(-1, units)
     lab = labels.reshape(-1).astype(jnp.int32)
-    with jax.named_scope("chunked_lm_head_ce"):
+    with jax.named_scope(HEAD_SCOPE):
         loss = _make_chunked_ce(chunk)(h2, weight, bias, lab)
     return loss.reshape(lead)
 

@@ -286,7 +286,10 @@ def embedding(data, weight, *, input_dim, output_dim, dtype="float32", sparse_gr
     """Row gather (ref: indexing_op.cc :: Embedding). XLA lowers to a
     dynamic-gather; on TPU this is HBM-bandwidth bound, so keep indices int32."""
     idx = data.astype(jnp.int32)
-    return jnp.take(weight, idx, axis=0)
+    # the gather and, in the backward, the scatter-add into the table's
+    # gradient (docs/OBSERVABILITY.md "Device-side scopes")
+    with jax.named_scope("mx.embed"):
+        return jnp.take(weight, idx, axis=0)
 
 
 @register("take")

@@ -33,6 +33,16 @@ from ..ops.pallas_common import auto_partitioned
 __all__ = ["shard_params", "ShardedTrainStep", "data_parallel_step",
            "trace_block", "batch_axes"]
 
+# Goes into the step functions' names and through them into the compiled
+# module's name (``jit_fused_step_mx1``). ``jax.named_scope``s are debug
+# info, and JAX's persistent-cache key leaves debug info out: a step
+# that differs from a cached one only by its scopes is served the cached
+# executable, whose text carries the scopes of whoever compiled it. The
+# module's name is hashed. Raise the number when the scopes of the step
+# or of an op it runs change (docs/OBSERVABILITY.md "Device-side
+# scopes"); it costs every cached step one cold compile.
+_SCOPE_SCHEMA = "mx1"
+
 
 def batch_axes(mesh: Mesh):
     """The mesh axes the batch dim is sharded over: ('dcn', 'dp') on a
@@ -326,9 +336,11 @@ class ShardedTrainStep:
             feed = dict(params)
             feed.update(dict(zip(data_names, data)))
             if compute_dtype is not None:
-                feed = {k: (v.astype(compute_dtype)
-                            if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                        for k, v in feed.items()}
+                with jax.named_scope("mx.params.cast"):
+                    feed = {k: (v.astype(compute_dtype)
+                                if jnp.issubdtype(v.dtype, jnp.floating)
+                                else v)
+                            for k, v in feed.items()}
             # aux (BN moving stats) stay fp32: training BN only UPDATES
             # them (FMutateInputs) — casting to the compute dtype would
             # run the EMA carry in bf16 precision for nothing
@@ -345,10 +357,11 @@ class ShardedTrainStep:
 
         def update_of(params, states, grads, t):
             new_params, new_states = {}, {}
-            for k, w in params.items():
-                g = grads[k].astype(jnp.float32)
-                new_params[k], new_states[k] = _apply_update(
-                    optimizer, hp, w, g, states[k], t)
+            with jax.named_scope("mx.optimizer"):
+                for k, w in params.items():
+                    g = grads[k].astype(jnp.float32)
+                    new_params[k], new_states[k] = _apply_update(
+                        optimizer, hp, w, g, states[k], t)
             return new_params, new_states
 
         # t (optimizer step) and the PRNG key live ON DEVICE and are
@@ -395,6 +408,10 @@ class ShardedTrainStep:
             new_params, new_states = update_of(params, states, grads, t)
             return new_params, new_states, t + 1.0
 
+        for step_fn in (fused_step, micro_step, apply_step, grad_step,
+                        update_step):
+            step_fn.__name__ += "_" + _SCOPE_SCHEMA
+
         p_sh = self.param_shardings
         s_sh = self.state_shardings
         rep = NamedSharding(self.mesh, P())
@@ -413,8 +430,11 @@ class ShardedTrainStep:
             and not self._split_update
             and _cfg("MXNET_SHARDED_AUTO_LAYOUT")
             and all(d.platform == "tpu" for d in self.mesh.devices.flat))
-        self._compiled = {}   # data avals -> compiled executable
-        self._watched = {}    # data avals -> AOT executable (commwatch)
+        self._compiled = {}   # data avals -> AUTO-layout executable
+        # (step function, data avals) -> (AOT executable,
+        # telemetry.DeviceProgram): every program this step launches,
+        # what device_scopes() and telemetry.device_scope_tables() read
+        self._programs = {}
         self._fused_fn = fused_step
         a_sh = {k: rep for k in self.aux}
         with self.mesh:
@@ -464,26 +484,39 @@ class ShardedTrainStep:
                     donate_argnums=(0, 1, 2, 4, 5))
 
     # ------------------------------------------------------------------
-    def _layout_compiled(self, arrays):
+    def _layout_compiled(self, arrays, key):
         """AUTO-layout AOT path: the FIRST compile lets the compiler pick
         parameter layouts and re-lays-out params/states once; every
         later data shape compiles with those layouts PINNED, so cached
         executables never disagree about where the params live."""
-        key = tuple((a.shape, str(a.dtype)) for a in arrays)
-        fn = self._compiled.get(key)
-        if fn is not None:
-            return fn
-        if not self._compiled:
-            # lower from abstract avals: concrete arrays carry a
-            # committed layout, which conflicts with AUTO
-            sds = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)
-            lowered = self._fused.lower(
-                jax.tree_util.tree_map(sds, self.params),
-                jax.tree_util.tree_map(sds, self.aux),
-                jax.tree_util.tree_map(sds, self.states),
-                sds(self._t_dev), sds(self._rng_dev),
-                *[sds(a) for a in arrays])
-            fn = lowered.compile()
+        ent = self._programs.get(("fused_step", key))
+        if ent is not None:
+            return ent
+        first = not self._compiled
+        if first:
+            jitted = self._fused
+        else:
+            rep = NamedSharding(self.mesh, P())
+            d_sh = tuple(self.data_shardings)
+            a_sh = {k: rep for k in self.aux}
+            with self.mesh:
+                jitted = jax.jit(
+                    self._fused_fn,
+                    in_shardings=(self._param_formats, a_sh,
+                                  self._state_formats, rep, rep) + d_sh,
+                    out_shardings=(self._param_formats, a_sh,
+                                   self._state_formats, rep, rep, rep),
+                    donate_argnums=(0, 1, 2, 3, 4))
+        # lower from abstract avals: concrete arrays carry a
+        # committed layout, which conflicts with AUTO
+        sds = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)
+        ent = fn, _ = self._executable("fused_step", jitted, key, (
+            jax.tree_util.tree_map(sds, self.params),
+            jax.tree_util.tree_map(sds, self.aux),
+            jax.tree_util.tree_map(sds, self.states),
+            sds(self._t_dev), sds(self._rng_dev),
+            *[sds(a) for a in arrays]))
+        if first:
             from .. import commwatch, compilewatch
             commwatch.register_program(
                 ("sharded_step", id(self), key), "sharded_step",
@@ -496,22 +529,10 @@ class ShardedTrainStep:
                 jax.device_put, self.params, in_fmts[0])
             self.states = jax.tree_util.tree_map(
                 jax.device_put, self.states, in_fmts[2])
-        else:
-            rep = NamedSharding(self.mesh, P())
-            d_sh = tuple(self.data_shardings)
-            a_sh = {k: rep for k in self.aux}
-            with self.mesh:
-                fn = jax.jit(
-                    self._fused_fn,
-                    in_shardings=(self._param_formats, a_sh,
-                                  self._state_formats, rep, rep) + d_sh,
-                    out_shardings=(self._param_formats, a_sh,
-                                   self._state_formats, rep, rep, rep),
-                    donate_argnums=(0, 1, 2, 3, 4))
         self._compiled[key] = fn
-        return fn
+        return ent
 
-    def _watched_executable(self, arrays):
+    def _watched_executable(self, arrays, key):
         """Observability execution path (MXNET_TELEMETRY +
         MXNET_COMMWATCH): compile the fused step ONCE per data shape
         through the AOT stages and execute the AOT executable — same
@@ -520,44 +541,73 @@ class ShardedTrainStep:
         meters feed on: its ``cost_analysis`` FLOPs become the
         measured mx_mfu numerator and its HLO text yields the
         GSPMD-collective inventory (op/axis/bytes) commwatch charges
-        per execution (ISSUE 6). Gate off: the plain jit path runs
-        and none of this exists."""
-        key = tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
-        ent = self._watched.get(key)
-        if ent is not None:
-            prog_key = ("sharded_step", id(self), key)
-            from .. import commwatch, compilewatch
-            if not commwatch.has_program(prog_key):
-                # telemetry.reset() cleared the inventories (the
-                # warmup -> reset -> meter pattern) but the executable
-                # outlived them: re-register from the cache so MFU and
-                # GSPMD comm keep flowing
-                commwatch.register_program(
-                    prog_key, "sharded_step", compiled=ent,
-                    mesh=self.mesh,
-                    flops=compilewatch._extract_cost(ent))
-            return ent, prog_key
+        per execution (ISSUE 6). Gate off: the same executable runs
+        with no meter on it."""
         import time
         from .. import commwatch, compilewatch, telemetry
-        t0 = time.perf_counter()
-        lowered = self._fused.lower(self.params, self.aux, self.states,
-                                    self._t_dev, self._rng_dev, *arrays)
-        compiled = lowered.compile()
-        dt = time.perf_counter() - t0
-        compilewatch.note_external_compile(dt)
-        try:
-            telemetry.counter("mx_compile_total", fn="sharded_step").inc()
-            telemetry.histogram("mx_compile_seconds", fn="sharded_step",
-                                stage="total").observe(dt)
-        except Exception:
-            pass
-        flops = compilewatch._extract_cost(compiled)
+        if ("fused_step", key) not in self._programs:
+            t0 = time.perf_counter()
+            self._executable("fused_step", self._fused, key, (
+                self.params, self.aux, self.states, self._t_dev,
+                self._rng_dev, *arrays))
+            dt = time.perf_counter() - t0
+            compilewatch.note_external_compile(dt)
+            try:
+                telemetry.counter("mx_compile_total",
+                                  fn="sharded_step").inc()
+                telemetry.histogram("mx_compile_seconds", fn="sharded_step",
+                                    stage="total").observe(dt)
+            except Exception:
+                pass
+        compiled, program = self._programs["fused_step", key]
         prog_key = ("sharded_step", id(self), key)
-        commwatch.register_program(prog_key, "sharded_step",
-                                   compiled=compiled, mesh=self.mesh,
-                                   flops=flops)
-        self._watched[key] = compiled
-        return compiled, prog_key
+        if not commwatch.has_program(prog_key):
+            # new, or telemetry.reset() cleared the inventories (the
+            # warmup -> reset -> meter pattern) and the executable
+            # outlived them: MFU and GSPMD comm keep flowing
+            commwatch.register_program(
+                prog_key, "sharded_step", compiled=compiled,
+                mesh=self.mesh, flops=compilewatch._extract_cost(compiled))
+        return compiled, program, prog_key
+
+    # ------------------------------------------------------------------
+    # device-side scopes (docs/OBSERVABILITY.md "Device-side scopes")
+    # ------------------------------------------------------------------
+    def _executable(self, label, jitted, key, args):
+        """(AOT executable, telemetry.DeviceProgram) of one step
+        function (``label``) for one data shape (``key``: the data
+        avals): lowered and compiled once through the AOT stages and
+        kept. Every program of the step launches through here, so the
+        executable whose text the table is read from is the one that
+        ran, and no table costs a second compile."""
+        ent = self._programs.get((label, key))
+        if ent is None:
+            from .. import telemetry
+            lowered = jitted.lower(*args)
+            compiled = lowered.compile()
+            ent = self._programs[(label, key)] = (
+                compiled, telemetry.DeviceProgram(label, lowered, compiled))
+        return ent
+
+    def device_scopes(self) -> List[dict]:
+        """Which instruction of this step's compiled program(s) belongs
+        to which scope of the program: one table for each program that
+        has launched, most recent launch first (one, unless gradients
+        accumulate, the update is split off, or the data's shape
+        changed). A table is ``{"program": "fused_step", "module":
+        "jit_fused_step_mx1", "launched": <wall time>, "scopes": {HLO
+        instruction name: innermost mx.* scope}, "unscoped":
+        {instruction name: the end of its op_name}, "missing": [scope
+        of the lowering that the executable lacks], "stale": bool}``
+        (``telemetry.DeviceProgram.table``). Built from the compiled
+        executable's text on the first call and kept; nothing is parsed
+        on the step path or at construction.
+        ``telemetry.device_scope_tables()`` finds the same tables
+        without a handle on the step."""
+        launched = sorted((p for _, p in self._programs.values()
+                           if p.launched is not None),
+                          key=lambda p: -p.launched)
+        return [p.table() for p in launched]
 
     def step(self, *data, rng=None):
         """Run one (micro-)step. With grad_accum=N, every Nth call also
@@ -622,24 +672,33 @@ class ShardedTrainStep:
         Returns (loss, whether an optimizer step was taken: a
         gradient-accumulation micro-step takes none)."""
         from .. import telemetry
+        # the dtype itself, not its name: str() of one costs 3 us
+        key = tuple((a.shape, a.dtype) for a in arrays)
         if self._split_update:
+            args = (self.params, self.aux, self._rng_dev, *arrays)
+            fn, prog = self._executable("grad_step", self._grad_fn, key,
+                                        args)
             telemetry.count_launch("sharded")
-            grads, self.aux, self._rng_dev, loss = self._grad_fn(
-                self.params, self.aux, self._rng_dev, *arrays)
+            prog.note_launch()
+            grads, self.aux, self._rng_dev, loss = fn(*args)
+            args = (self.params, self.states, grads, self._t_dev)
+            # one update program, whatever the data's shape
+            fn, prog = self._executable("update_step", self._update_fn, (),
+                                        args)
             telemetry.count_launch("sharded")
-            self.params, self.states, self._t_dev = self._update_fn(
-                self.params, self.states, grads, self._t_dev)
+            prog.note_launch()
+            self.params, self.states, self._t_dev = fn(*args)
             self._t += 1
             return loss, True
         if self.grad_accum == 1:
             from .. import commwatch
             import contextlib
             watch = contextlib.nullcontext()
+            step_args = lambda: (self.params, self.aux, self.states,
+                                 self._t_dev, self._rng_dev, *arrays)
             if self._use_auto_layout:
-                fn = self._layout_compiled(arrays)
+                fn, prog = self._layout_compiled(arrays, key)
                 if commwatch.enabled():
-                    key = tuple((tuple(a.shape), str(a.dtype))
-                                for a in arrays)
                     prog_key = ("sharded_step", id(self), key)
                     if not commwatch.has_program(prog_key):
                         # inventory lost to telemetry.reset(), or the
@@ -652,16 +711,16 @@ class ShardedTrainStep:
                     watch = commwatch.program_watch(prog_key,
                                                     "sharded_step")
             elif commwatch.enabled():
-                fn, prog_key = self._watched_executable(arrays)
+                fn, prog, prog_key = self._watched_executable(arrays, key)
                 watch = commwatch.program_watch(prog_key, "sharded_step")
             else:
-                fn = self._fused
+                fn, prog = self._executable("fused_step", self._fused, key,
+                                            step_args())
             telemetry.count_launch("sharded")
+            prog.note_launch()
             with watch:
                 (self.params, self.aux, self.states, self._t_dev,
-                 self._rng_dev, loss) = fn(
-                    self.params, self.aux, self.states, self._t_dev,
-                    self._rng_dev, *arrays)
+                 self._rng_dev, loss) = fn(*step_args())
                 if commwatch.enabled():
                     # dispatch is async: the watch must time program
                     # COMPLETION or the derived per-collective
@@ -676,14 +735,19 @@ class ShardedTrainStep:
                            for k, v in self.params.items()}
         telemetry.count_launch("sharded")
         if self._micro_count < self.grad_accum - 1:
-            self._grads, self.aux, self._rng_dev, loss = self._micro(
-                self.params, self.aux, self._grads, self._rng_dev, *arrays)
+            args = (self.params, self.aux, self._grads, self._rng_dev,
+                    *arrays)
+            fn, prog = self._executable("micro_step", self._micro, key, args)
+            prog.note_launch()
+            self._grads, self.aux, self._rng_dev, loss = fn(*args)
             self._micro_count += 1
             return loss, False
+        args = (self.params, self.aux, self.states, self._grads, self._t_dev,
+                self._rng_dev, *arrays)
+        fn, prog = self._executable("apply_step", self._apply, key, args)
+        prog.note_launch()
         (self.params, self.aux, self.states, self._t_dev, self._rng_dev,
-         loss) = self._apply(self.params, self.aux, self.states,
-                             self._grads, self._t_dev, self._rng_dev,
-                             *arrays)
+         loss) = fn(*args)
         self._t += 1
         self._micro_count = 0
         self._grads = None

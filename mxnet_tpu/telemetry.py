@@ -60,18 +60,25 @@ from __future__ import annotations
 import bisect
 import collections
 import logging
+import re
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
+try:        # whether a trace is recording has no public reader
+    from jax._src import profiler as _jax_profiler
+except ImportError:     # a JAX that moved it: no program is ever pinned
+    _jax_profiler = None
 
 from . import profiler
 
 __all__ = ["Counter", "Gauge", "Histogram", "span", "phase", "counter",
            "gauge", "histogram", "enabled", "enable", "refresh",
            "snapshot", "render_prometheus", "mark_step", "step_log",
-           "count_launch",
+           "count_launch", "innermost_scope", "hlo_scopes",
+           "DeviceProgram", "device_scope_tables",
            "heartbeat_line", "count_event", "guard_event",
            "fault_event", "checkpoint_event", "reset",
            "memory_snapshot", "memory_diff", "ndarray_live",
@@ -306,7 +313,9 @@ def histogram(name: str, /, **labels) -> Histogram:
 def reset():
     """Drop every registered instrument, the step clock and the
     MFU/goodput meter window (test isolation; production code never
-    calls this)."""
+    calls this). Also lets go of the program a trace last recorded
+    (``device_scope_tables``'s one strong reference) and with it of its
+    executable, if no table was built from it."""
     with _REG_LOCK:
         _METRICS.clear()
     with _STEP_LOCK:
@@ -320,6 +329,7 @@ def reset():
         _STEPLOG.clear()
     with _FLEET_LOCK:
         _FLEET["last"] = None
+    _LAST_LAUNCHED[0] = _TRACED[0] = None
     with _BUNDLE_LOCK:
         # crash-bundle budget + recent-event tail are per-"run" state:
         # a test (or a deliberate meter re-arm) starting fresh gets the
@@ -668,6 +678,185 @@ def step_log(n: Optional[int] = None) -> List[dict]:
                     "events": list(events), "dropped": dropped,
                     **{key: dict(got) for key, got in counts.items()}})
     return out
+
+
+# ---------------------------------------------------------------------------
+# device-side scopes — which instruction of a compiled program belongs
+# to which ``jax.named_scope`` of the program. The device trace names an
+# event by its HLO instruction and nothing else; the compiled program's
+# text gives every instruction its ``op_name``, in which a named scope
+# is one path element (inside ``jvp(...)``, ``transpose(...)`` and
+# ``checkpoint/rematted_computation`` too). The rule, the only one in
+# the package: an instruction belongs to the LAST element of its
+# ``op_name`` that starts with ``mx.``. No list of scope names exists
+# here: a scope an op opens tomorrow shows without an edit.
+# (docs/OBSERVABILITY.md "Device-side scopes")
+# ---------------------------------------------------------------------------
+SCOPE_PREFIX = "mx."
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([\w.\-]+)")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_PATH_ELEMENT = re.compile(r"[\w.]+")
+_QUOTED = re.compile(r'"([^"\n]*)"')
+
+
+def innermost_scope(op_name: str) -> Optional[str]:
+    """The last path element of an instruction's ``op_name`` that
+    starts with ``mx.``; None where it names no scope of the program.
+    An instruction XLA merged from several ops carries their paths
+    joined by ``;``: it goes to the scope of the first path that names
+    one, or to a scope nested inside that one (``mx.mamba2.ssd`` inside
+    ``mx.mamba2``) where a later path names it."""
+    best = None
+    for path in op_name.split(";"):
+        found = None
+        for element in _PATH_ELEMENT.findall(path):
+            if element.startswith(SCOPE_PREFIX):
+                found = element
+        if found is not None and (best is None
+                                  or found.startswith(best + ".")):
+            best = found
+    return best
+
+
+def hlo_scopes(compiled_text: str
+               ) -> Tuple[Optional[str], Dict[str, str], Dict[str, str]]:
+    """(the module's name, {instruction name: innermost scope},
+    {instruction name: label} of the instructions under no scope) over
+    every computation of a compiled program's text
+    (``compiled.as_text()``). A label is the last two elements of the
+    ``op_name``: what to print beside ``fusion.1797``. A fusion is one
+    instruction: it goes to the scope its own metadata names (that of
+    its root)."""
+    head = _HLO_MODULE.match(compiled_text)
+    scopes: Dict[str, str] = {}
+    unscoped: Dict[str, str] = {}
+    for line in compiled_text.splitlines():
+        meta = _HLO_OP_NAME.search(line)
+        name = _HLO_INSTRUCTION.match(line) if meta else None
+        if name is None:
+            continue
+        scope = innermost_scope(meta.group(1))
+        if scope is not None:
+            scopes[name.group(1)] = scope
+        else:
+            unscoped[name.group(1)] = "/".join(
+                meta.group(1).split("/")[-2:])
+    return (head.group(1) if head else None), scopes, unscoped
+
+
+def _lowered_scope_names(lowered_text: str) -> set:
+    """The scopes a lowering's text (``as_text(debug_info=True)``)
+    names in its locations: what the program opened when it was
+    traced, whatever executable was then served for it."""
+    return {scope for scope in map(innermost_scope,
+                                   _QUOTED.findall(lowered_text))
+            if scope is not None}
+
+
+class DeviceProgram:
+    """One compiled program of a training step, as the step publishes
+    it: a ``label`` (the step function's name), ``launched`` (the wall
+    time at which it last became the program that launches, None before
+    its first launch) and, on request, its instruction -> scope table.
+
+    It holds the ``(lowered, compiled)`` pair of ``jax.stages`` objects
+    the step ran (the executable is the one that launches, not a second
+    compile of it) until the first :meth:`table`, and only the table
+    after it. Nothing is parsed before that: the text of a large step
+    is tens of MB."""
+
+    __slots__ = ("label", "launched", "_stages", "_table", "_ref",
+                 "__weakref__")
+
+    def __init__(self, label: str, lowered, compiled):
+        self.label = label
+        self.launched: Optional[float] = None
+        self._stages: Optional[tuple] = (lowered, compiled)
+        self._table: Optional[dict] = None
+        self._ref = weakref.ref(self)
+        with _DEVICE_LOCK:
+            _DEVICE_PROGRAMS.add(self)
+
+    def note_launch(self):
+        """On the step path, no lock. The clock is read and the
+        process-wide "launched last" set only when another program
+        launched in between; a launch under a ``jax.profiler`` trace
+        pins the program (see ``_TRACED``), the next one outside a
+        trace lets the pin go."""
+        if _LAST_LAUNCHED[0] is not self._ref:
+            self.launched = time.time()
+            _LAST_LAUNCHED[0] = self._ref
+        if _tracing():
+            _TRACED[0] = self
+        elif _TRACED[0] is not None:
+            _TRACED[0] = None
+
+    def table(self) -> dict:
+        """``{"program", "module", "launched", "scopes", "unscoped",
+        "missing", "stale"}``: ``module`` is the name a trace's "XLA
+        Modules" event starts with, ``scopes`` is {HLO instruction
+        name: innermost ``mx.*`` scope}, ``unscoped`` is {instruction
+        name: the end of its ``op_name``} for the rest. ``missing``:
+        the scopes the lowering names and the executable does not (the
+        compiler may have removed a scope's only instructions; a sum
+        over such a scope is not 0, it is unknown). ``stale``: the
+        executable names *none* of the lowering's scopes. It then came
+        from a compile cache written by a tree with other scopes
+        (JAX's cache key leaves debug info out), and every sum over
+        ``scopes`` would read 0 where work ran."""
+        with _TABLE_LOCK:
+            if self._table is None:
+                (lowered, compiled), self._stages = self._stages, None
+                module, scopes, unscoped = hlo_scopes(compiled.as_text())
+                opened = _lowered_scope_names(
+                    lowered.as_text(debug_info=True))
+                missing = opened - set(scopes.values())
+                self._table = {
+                    "program": self.label, "module": module,
+                    "scopes": scopes, "unscoped": unscoped,
+                    "missing": sorted(missing),
+                    "stale": bool(opened) and missing == opened}
+        return dict(self._table, launched=self.launched)
+
+
+def _tracing() -> bool:
+    """Whether a ``jax.profiler`` trace is being recorded in this
+    process (``start_trace`` / ``trace``, ``profiler.set_state("run")``
+    among them)."""
+    state = getattr(_jax_profiler, "_profile_state", None)
+    return getattr(state, "profile_session", None) is not None
+
+
+_DEVICE_LOCK = threading.Lock()
+_TABLE_LOCK = threading.Lock()
+_DEVICE_PROGRAMS: "weakref.WeakSet[DeviceProgram]" = weakref.WeakSet()
+# a weak reference to the program that launched last: what a launch
+# compares itself with, so that a loop over one program stores nothing
+_LAST_LAUNCHED: List[Optional["weakref.ref[DeviceProgram]"]] = [None]
+# The one strong reference, and only under a trace: the program whose
+# launch a ``jax.profiler`` trace last recorded outlives its step, so
+# that whoever reduces the trace after the training loop has returned
+# (a benchmark's reader, an operator's script at the end of a job)
+# still finds the table of what ran. Its executable rides along until
+# the table is built; the pin goes at the next launch outside a trace
+# and at ``telemetry.reset()``.
+_TRACED: List[Optional[DeviceProgram]] = [None]
+
+
+def device_scope_tables() -> List[DeviceProgram]:
+    """Every live step's launched programs, and the one a trace last
+    recorded even where its step is gone: most recent launch first. The
+    entries are cheap (``label``, ``launched``); ``entry.table()``
+    builds one program's table. A reader with a ``jax.profiler`` trace
+    and no handle on the step takes ``device_scope_tables()[0]``."""
+    with _DEVICE_LOCK:
+        found = set(_DEVICE_PROGRAMS)
+    if _TRACED[0] is not None:
+        found.add(_TRACED[0])
+    return sorted((p for p in found if p.launched is not None),
+                  key=lambda p: -p.launched)
 
 
 def _maybe_fleet_tick(step_count: int):
