@@ -17,7 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import register
+from .. import telemetry
+from . import pallas_attention, register
 
 
 def _split_qkv(qkv, heads):
@@ -110,14 +111,17 @@ def sdp_selfatt(rng, queries_keys_values, *, heads, dropout=0.0,
     residual; the packed tests assert this on the jaxpr). The unfused
     interleaved_matmul composition is the fallback. The [L,L]
     probabilities and dropout masks never hit HBM; the backward
-    recomputes them flash-style from per-block hardware-PRNG seeds."""
+    recomputes them flash-style from per-block hardware-PRNG seeds.
+    Counted once a traced call in
+    ``mx_attn_selfatt_path_total{path="pallas"|"xla"}``."""
     L, N, thd = queries_keys_values.shape
     p = float(dropout) if _train else 0.0
-    from .pallas_attention import flash_selfatt, selfatt_plan
     heads_i = int(heads)
-    plan = selfatt_plan(L, heads_i, N, p,
-                        dtype=queries_keys_values.dtype,
-                        head_dim=thd // (3 * heads_i))
+    plan = pallas_attention.selfatt_plan(
+        L, heads_i, N, p, dtype=queries_keys_values.dtype,
+        head_dim=thd // (3 * heads_i))
+    telemetry.count_event("mx_attn_selfatt_path_total",
+                          path="xla" if plan is None else "pallas")
     if plan is not None:
         n_blk = plan["n_blocks"]
         if p > 0.0:
@@ -125,8 +129,9 @@ def sdp_selfatt(rng, queries_keys_values, *, heads, dropout=0.0,
                                        dtype=jnp.int32)
         else:
             seeds = jnp.zeros((n_blk,), jnp.int32)
-        return flash_selfatt(queries_keys_values, seeds, heads=heads_i,
-                             dropout=p, block_heads=plan["bbh"])
+        return pallas_attention.flash_selfatt(
+            queries_keys_values, seeds, heads=heads_i, dropout=p,
+            block_heads=plan["bbh"])
     scores = interleaved_matmul_selfatt_qk(queries_keys_values,
                                            heads=heads_i)
     att = jax.nn.softmax(scores, axis=-1)

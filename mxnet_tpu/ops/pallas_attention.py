@@ -39,6 +39,15 @@ deterministic integer-hash stream so the seed-recompute contract is
 testable on the CPU mesh). ``block_heads`` is autotuned
 (``MXNET_AUTOTUNE``, mxnet_tpu/autotune.py) with the hand-picked
 default as the incumbent.
+
+A plan for every length up to ``_MAX_L`` (ISSUE 39): a grid step holds
+a head block's whole ``(L_pad, L_pad)`` scores, so its working set
+grows with the square of the length, and the default scoped VMEM limit
+(16 MiB) stopped serving 12 x 64 at 336 positions. A call reckoned
+over that limit's budget now states its own ``vmem_limit_bytes``
+(``_compiler_params``; the v5e has 128 MiB), and a block is planned
+inside ``_VMEM_MAX``: at 12 x 64 six heads a step at 512 positions,
+two at 1,024. The kernels' bodies are the ones they were.
 """
 from __future__ import annotations
 
@@ -51,13 +60,18 @@ from jax import lax
 
 __all__ = ["flash_selfatt", "flash_selfatt_available", "selfatt_plan"]
 
-_MAX_L = 1024   # scores for one head block must fit VMEM comfortably
+_MAX_L = 1024   # longest sequence a plan is made for
 _BB = 16        # max heads per grid step (the r6 batch-head block size)
 _SUBLANE = 16   # seq padding unit (bf16 sublane tile)
 _LANE = 128     # minor-axis tile
 
-# VMEM working-set budget shared with autotune's feasibility gate
+# What the default scoped VMEM limit (16 MiB) leaves a kernel: a call
+# reckoned inside it asks nothing of the compiler, one over it states
+# its own limit (``_compiler_params``). No block is planned over
+# ``_VMEM_MAX`` of the v5e's 128 MiB: two heads x 64 at ``_MAX_L``
+# reckon 82 MiB.
 _VMEM_BUDGET = 10 * 1024 * 1024
+_VMEM_MAX = 96 * 1024 * 1024
 
 
 def _interpret():
@@ -84,14 +98,27 @@ def _lane_unit(hd):
 
 
 def _fits(bbh, L_pad, hd, esize):
-    return _block_bytes(bbh, L_pad, hd, esize, 5) * 2 <= _VMEM_BUDGET
+    return _block_bytes(bbh, L_pad, hd, esize, 5) * 2 <= _VMEM_MAX
+
+
+def _compiler_params(pltpu, nbytes):
+    """``pallas_call`` keywords of a call reckoned at ``nbytes``: none
+    inside the budget of the default scoped limit (the program is the
+    one it was), else the reckoning as ``vmem_limit_bytes`` with 16 MiB
+    for what it does not see."""
+    if nbytes <= _VMEM_BUDGET:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=nbytes + (16 << 20))}
 
 
 def _default_block_heads(heads, L_pad, hd, esize):
     """Heads per grid step: a multiple of the lane unit whose working
-    set fits the VMEM budget (backward temp count = 5, the worse
-    case), least head padding first, then fewest grid steps; None when
-    no such block exists."""
+    set fits ``_VMEM_MAX`` (backward temp count = 5, the worse case),
+    least head padding first, then fewest grid steps (on the chip at
+    12 x 64, 512 positions: six heads a step 4.05 ms a layer forward +
+    backward, two heads 4.13-4.20; PERF.md, PR 39); None when no such
+    block exists."""
     unit = _lane_unit(hd)
     fits = [b for b in range(unit, _ceil_to(min(heads, _BB), unit) + 1,
                              unit)
@@ -166,6 +193,8 @@ def _tuned_block_heads(L, L_pad, heads, batch, esize, default, hd):
         # breaks ties on candidate ORDER — larger head blocks mean
         # fewer grid steps, so they must be the preferred tie-winners
         for bbh in range(_ceil_to(min(heads, _BB), unit), 0, -unit):
+            if not _fits(bbh, L_pad, hd, esize):
+                continue
             n_hblk = -(-heads // bbh)
             # analytic roofline features: 4 batched matmuls of
             # (L, hd) x (hd, L) per (batch, head) pair fwd+bwd
@@ -173,7 +202,9 @@ def _tuned_block_heads(L, L_pad, heads, batch, esize, default, hd):
             hbm = batch * n_hblk * bbh * L * 4 * hd * esize
             cands.append(autotune.Candidate(
                 {"block_heads": bbh}, flops=flops, hbm_bytes=hbm,
-                vmem_bytes=_block_bytes(bbh, L_pad, hd, esize, 5) * 2,
+                # gated by ``_fits`` above, not by the tuner's budget
+                # (the default limit's): the call states its own limit
+                vmem_bytes=0.0,
                 build=_probe_builder(L, heads, batch, hd, bbh),
                 opaque=True))
         return cands
@@ -326,6 +357,7 @@ def _fwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
                                        jnp.bfloat16),
         interpret=interpret,
         name="pallas_selfatt_packed_fwd",
+        **_compiler_params(pltpu, _block_bytes(bbh, L_pad, d, 2, 2) * 2),
     )
 
 
@@ -384,6 +416,7 @@ def _bwd_call(L, L_pad, N, heads_pad, bbh, d, p_drop, interpret):
                                        jnp.bfloat16),
         interpret=interpret,
         name="pallas_selfatt_packed_bwd",
+        **_compiler_params(pltpu, _block_bytes(bbh, L_pad, d, 2, 5) * 2),
     )
 
 

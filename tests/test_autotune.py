@@ -224,6 +224,19 @@ def test_attention_cost_mode_prefers_large_head_blocks(monkeypatch):
     assert plan["bbh"] >= 6                # 12 or a padded 16 — not 1
 
 
+def test_attention_cost_mode_above_the_default_vmem_limit(monkeypatch):
+    """A block that states its own VMEM limit (ISSUE 39) is gated by
+    the kernel's budget, not the tuner's: at 256 positions of 12 x 64
+    the twelve heads a step reckon over the tuner's 10 MB, and cost
+    mode must not settle for the two that reckon under it."""
+    monkeypatch.setenv("MXNET_AUTOTUNE", "cost")
+    from mxnet_tpu.ops import pallas_attention
+    plan = pallas_attention.selfatt_plan(256, 12, 1, 0.0)
+    assert pallas_attention._block_bytes(
+        plan["bbh"], 256, 64, 2, 5) * 2 > autotune._VMEM_BUDGET
+    assert plan["bbh"] == 12
+
+
 def test_bad_mode_string_is_off(monkeypatch):
     monkeypatch.setenv("MXNET_AUTOTUNE", "turbo")
     assert autotune.mode() == "off"

@@ -76,12 +76,19 @@ def test_layer_norm(one_chip, compiled_mode):
     assert _custom_calls(one_chip, grad, *shapes) >= 1
 
 
+# (length, batch) of 32,768 tokens a step: the s128 cell's call, the
+# s512 cell's, and lengths no cell runs, up to the cap (ISSUE 39: a plan
+# past 336 positions, under a VMEM limit the call states itself)
 @pytest.mark.parametrize("p", [0.0, 0.1])
-def test_flash_attention(one_chip, compiled_mode, p):
+@pytest.mark.parametrize("length, batch", [(L, N), (384, 85), (512, 64),
+                                           (768, 42), (1024, 32)],
+                         ids=["L128", "L384", "L512", "L768", "L1024"])
+def test_flash_attention(one_chip, compiled_mode, length, batch, p):
     from mxnet_tpu.ops.pallas_attention import flash_selfatt, selfatt_plan
-    plan = selfatt_plan(L, H, N, p, dtype=BF, head_dim=D)
+    plan = selfatt_plan(length, H, batch, p, dtype=BF, head_dim=D)
     assert plan is not None
-    shapes = [((L, N, 3 * H * D), BF), ((plan["n_blocks"],), jnp.int32)]
+    shapes = [((length, batch, 3 * H * D), BF),
+              ((plan["n_blocks"],), jnp.int32)]
 
     def fwd(qkv, seeds):
         return flash_selfatt(qkv, seeds, heads=H, dropout=p,
@@ -90,6 +97,27 @@ def test_flash_attention(one_chip, compiled_mode, p):
     assert _custom_calls(one_chip, fwd, *shapes) == 1
     grad = jax.grad(lambda qkv, seeds: _sum32(fwd(qkv, seeds)))
     assert _custom_calls(one_chip, grad, *shapes) >= 1
+
+
+def test_the_op_at_512_positions_compiles_to_its_two_kernels(one_chip,
+                                                             compiled_mode):
+    """The registered op's value and gradient at 512 positions: two
+    Mosaic custom calls named ``pallas_selfatt_packed_*`` (what
+    ``pallas_ms`` sums); nothing of the composition's is left: no
+    product outside the kernels, no mask drawn by XLA."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_sdp_selfatt").impl
+    grad = jax.value_and_grad(lambda qkv, key: _sum32(
+        op(key, qkv, heads=H, dropout=0.1, _train=True)))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in [((512, 8, 3 * H * D), BF), ((2,), jnp.uint32)]]
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert sum("pallas_selfatt_packed_fwd" in c for c in calls) == 1
+    assert sum("pallas_selfatt_packed_bwd" in c for c in calls) == 1
+    assert "bernoulli" not in text and "dot(" not in text
 
 
 def test_bias_gelu(one_chip, compiled_mode):

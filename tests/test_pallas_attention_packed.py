@@ -15,6 +15,7 @@ import jax
 import jax.extend.core
 import jax.numpy as jnp
 
+from mxnet_tpu.ops import pallas_attention
 from mxnet_tpu.ops.pallas_attention import (_keep_mask, flash_selfatt,
                                             flash_selfatt_available,
                                             selfatt_plan)
@@ -181,6 +182,84 @@ def test_dropout_seed_recompute_parity():
     assert not bool(jnp.all(o1 == o3))
 
 
+def _grad_gap(f, g, qkv, r):
+    g1 = jax.grad(lambda q: jnp.sum(f(q) * r))(qkv)
+    g2 = jax.grad(lambda q: jnp.sum(g(q) * r))(qkv)
+    return float(jnp.max(jnp.abs(g1 - g2))) \
+        / (float(jnp.max(jnp.abs(g2))) + 1e-9)
+
+
+# (L, heads) at BERT's head width 64, past the 336 positions where the
+# default scoped VMEM limit stopped serving: whole sublane tiles, a
+# ragged length, two and four heads, a padded head block
+@pytest.mark.parametrize("L,H", [(512, 2), (512, 4), (400, 2), (400, 4),
+                                 (200, 3)])
+def test_lengths_past_the_default_limit_match_unfused(L, H):
+    """ISSUE 39: the plan's own call at a length whose block states its
+    VMEM limit: forward within a bf16 step of the kernel's dtype chain,
+    value and analytic gradient against the true unfused composition
+    at p = 0."""
+    N, d = 2, 64
+    rng = np.random.RandomState(6)
+    qkv = _rand_qkv(rng, L, N, H, d)
+    plan = selfatt_plan(L, H, N, 0.0, head_dim=d)
+    assert plan is not None and plan["L_pad"] == -(-L // 16) * 16
+    seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
+
+    def f(q):
+        return flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"])
+
+    o1 = f(qkv)
+    assert o1.shape == (L, N, H * d)
+    # one bf16 step: long rows' sums are not ordered as the chain's
+    np.testing.assert_allclose(np.asarray(o1),
+                               np.asarray(_ref_chain(qkv, H)),
+                               rtol=2 ** -7, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(_ref(qkv, H)),
+                               rtol=2e-2, atol=2e-2)
+    r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
+    assert _grad_gap(f, lambda q: _ref(q, H), qkv, r) < 3e-2
+
+
+@pytest.mark.parametrize("L,H,bbh", [(256, 2, 2), (200, 4, 2)])
+def test_dropout_at_the_configurations_rate(L, H, bbh):
+    """p = 0.1, BERT's: the backward recomputes each block's mask from
+    its seed, so value AND gradient agree with the composition under
+    the reconstructed masks; the keep rate is 0.9 within sampling
+    error; two head blocks never share a mask."""
+    N, d, p = 2, 64, 0.1
+    L_pad = -(-L // 16) * 16
+    n_hblk = H // bbh
+    rng = np.random.RandomState(7)
+    qkv = _rand_qkv(rng, L, N, H, d)
+    seeds = jnp.asarray(rng.randint(0, 2 ** 31 - 1, (N * n_hblk,))
+                        .astype(np.int32))
+    thresh = min(int(p * 2 ** 32), 2 ** 32 - 1)
+    blocks = [_keep_mask(None, seeds[b], (bbh, L_pad, L_pad), thresh, True)
+              for b in range(N * n_hblk)]
+    assert abs(float(jnp.mean(blocks[0] != blocks[1]))
+               - 2 * 0.9 * 0.1) < 0.01
+    masks = jnp.stack(blocks).reshape(N * H, L_pad, L_pad)
+    assert abs(float(jnp.mean(masks)) - 0.9) < 0.005     # 250k+ draws
+    masks = masks[:, :L, :L]
+
+    def ref_masked(q):
+        return _ref(q, H, att_hook=lambda att: jnp.where(
+            masks, att / (1.0 - p), 0.0).astype(att.dtype))
+
+    def f(q):
+        return flash_selfatt(q, seeds, heads=H, dropout=p,
+                             block_heads=bbh)
+
+    o1 = f(qkv)
+    assert bool(jnp.all(o1 == f(qkv)))
+    np.testing.assert_allclose(np.asarray(o1),
+                               np.asarray(ref_masked(qkv)),
+                               rtol=3e-2, atol=3e-2)
+    r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
+    assert _grad_gap(f, ref_masked, qkv, r) < 3e-2
+
+
 def test_central_difference_grads_through_registered_op():
     """Directional central-difference through _contrib_sdp_selfatt's
     flash path on a bf16-exact input grid (pointwise differences drown
@@ -239,15 +318,21 @@ def _walk_transposes(jaxpr, out):
     return out
 
 
-def test_no_transpose_between_projection_and_kernel():
+@pytest.mark.parametrize("L", [16, 256])
+def test_no_transpose_between_projection_and_kernel(L):
     """The static half of the transpose_jvp claim (ISSUE 14): trace
     QKV projection -> sdp_selfatt and assert NO transpose eqn touches
     the activation path — the only transpose in the whole trace is the
-    projection's weight transpose."""
+    projection's weight transpose. At a length whose call states its
+    VMEM limit too (ISSUE 39)."""
     from mxnet_tpu.ops import get_op
     op = get_op("_contrib_sdp_selfatt")
-    L, N, H, d = 16, 4, 4, 8
+    N, H, d = 4, 4, 8
     U = H * d
+    plan = selfatt_plan(L, H, N, 0.0, dtype=jnp.bfloat16, head_dim=d)
+    reckoned = pallas_attention._block_bytes(
+        plan["bbh"], plan["L_pad"], d, 2, 5) * 2
+    assert (reckoned > pallas_attention._VMEM_BUDGET) == (L == 256)
 
     def fn(x, w, b, key):
         qkv = jnp.matmul(x, w.T) + b           # the Dense projection
@@ -307,3 +392,89 @@ def test_plan_eligibility_ladder():
     # block_heads override out of range resolves to the safe default
     plan = selfatt_plan(16, 4, 4, 0.0, block_heads=0)
     assert plan is None
+
+
+# 12 heads x 64 (BERT-base): length -> heads a grid step
+PLANS = {128: 12, 256: 12, 336: 12, 384: 12, 400: 12, 512: 6, 768: 4,
+         1024: 2}
+
+
+@pytest.mark.parametrize("L", sorted(PLANS))
+def test_every_length_up_to_the_cap_has_a_plan(L):
+    """The lengths people train BERT-base at: a plan exists, its
+    reckoned working set is inside the budget it was planned under,
+    the limit its calls state leaves the v5e's 128 MiB room, and 128
+    resolves to the geometry it had (all twelve heads, nothing asked
+    of the compiler)."""
+    from jax.experimental.pallas import tpu as pltpu
+    H, N, d = 12, 32768 // L, 64
+    plan = selfatt_plan(L, H, N, 0.1, dtype=jnp.bfloat16, head_dim=d)
+    assert plan is not None
+    bbh, L_pad = plan["bbh"], plan["L_pad"]
+    assert bbh == PLANS[L] and L_pad == -(-L // 16) * 16
+    assert plan["n_blocks"] == N * plan["n_hblk"]
+    assert plan["heads_pad"] == plan["n_hblk"] * bbh == H
+    reckoned = pallas_attention._block_bytes(bbh, L_pad, d, 2, 5) * 2
+    assert reckoned <= pallas_attention._VMEM_MAX
+    params = pallas_attention._compiler_params(pltpu, reckoned)
+    if L == 128:
+        assert params == {}
+    else:
+        limit = params["compiler_params"].vmem_limit_bytes
+        assert reckoned < limit <= 112 << 20
+
+
+def test_the_budget_decides_the_plan(monkeypatch):
+    """Under the default scoped limit's budget alone the plans are the
+    ones before ISSUE 39: none at 512 positions (the composition ran),
+    twelve heads at 128; 2,048 is refused under either."""
+    assert selfatt_plan(2048, 12, 16, 0.1, dtype=jnp.bfloat16) is None
+    monkeypatch.setattr(pallas_attention, "_VMEM_MAX",
+                        pallas_attention._VMEM_BUDGET)
+    assert selfatt_plan(512, 12, 64, 0.1, dtype=jnp.bfloat16) is None
+    assert selfatt_plan(336, 12, 64, 0.1, dtype=jnp.bfloat16)["bbh"] == 2
+    assert selfatt_plan(128, 12, 256, 0.1, dtype=jnp.bfloat16)["bbh"] == 12
+    assert selfatt_plan(2048, 12, 16, 0.1, dtype=jnp.bfloat16) is None
+
+
+@pytest.mark.parametrize("dtype, devices, path, other",
+                         [(jnp.bfloat16, 1, "pallas", "xla"),
+                          (jnp.float32, 1, "xla", "pallas"),
+                          (jnp.bfloat16, 2, "xla", "pallas")],
+                         ids=["bf16", "f32", "bf16-two-devices"])
+def test_a_traced_call_counts_its_path(dtype, devices, path, other):
+    """ISSUE 39: ``mx_attn_selfatt_path_total{path}`` counts one a
+    traced call of ``_contrib_sdp_selfatt``: bf16 on one device the
+    kernel (one ``pallas_call`` left in the gradient, its backward
+    rule); float32, or a program GSPMD partitions over several devices
+    as the dp4 cell's, the composition (its six products)."""
+    from jax.sharding import Mesh
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import get_op, pallas_common
+    op = get_op("_contrib_sdp_selfatt")
+    qkv = jnp.ones((16, 2, 4 * 3 * 8), dtype)
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+
+    def loss(q):
+        out = op.impl(jax.random.key(0), q, heads=4, dropout=0.1,
+                      _train=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def counts():
+        return {p: telemetry.counter("mx_attn_selfatt_path_total",
+                                     path=p).get() for p in (path, other)}
+
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        start = counts()
+        with pallas_common.auto_partitioned(mesh):
+            jaxpr = jax.make_jaxpr(jax.grad(loss))(qkv)
+        got = {p: n - start[p] for p, n in counts().items()}
+    finally:
+        telemetry.enable(was)
+    assert got == {path: 1, other: 0}
+    heavy = [e.primitive.name for e in jaxpr.jaxpr.eqns
+             if e.primitive.name in ("pallas_call", "dot_general")]
+    assert heavy == (["pallas_call"] if path == "pallas"
+                     else ["dot_general"] * 6)
