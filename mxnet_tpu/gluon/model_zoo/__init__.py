@@ -10,10 +10,15 @@ top-k key selector in front of every attention layer, M-RoPE, a softmax
 router over SwiGLU experts), which returns a second loss beside its
 hidden states. ``mellum``: the third, Mellum 2 (sliding-window and full
 attention layers mixed by a per-layer list, a rotary table per layer
-type with YaRN on the full ones, the same router and experts)."""
+type with YaRN on the full ones, the same router and experts).
+``glm_moe_lite``: the fourth, GLM-4.7-Flash (latent attention, a
+leading dense layer before the expert layers, a shared expert beside a
+sigmoid router, and a multi-token-prediction module that uses the
+embedding and the head a second time)."""
 from . import vision
 from . import bert
 from . import nemotron_h
 from . import keye_vl
 from . import mellum
+from . import glm_moe_lite
 from .vision import get_model

@@ -5,11 +5,17 @@ several; plain, or slowed pair by pair by YaRN), blocked causal
 grouped-query attention over every earlier key or over a sliding window
 of them, the same attention over the keys a learned selector keeps (an
 index score for every causal pair, the exact ``top_k`` largest a query,
-and a second loss that trains the selector), and a dropless expert layer
-that is told which experts of the router's range it holds. (Dao & Gu,
-arXiv:2405.21060 sec. 6-7 for the scan; DeepSeek-V3.2-Exp's sparse
-attention for the selector; the layer equations are those of
-docs/KERNELS.md "Hybrid decoder ops".)
+and a second loss that trains the selector), latent attention (queries
+and keys / values through low-rank bottlenecks, one rotary key head
+shared by every query head), a dropless expert layer that is told which
+experts of the router's range it holds, a dense gated MLP, and the two
+pieces a multi-token-prediction module adds to a stack (the product that
+combines the next token's embedding with the stack's hidden state, and
+the sum of the two losses). (Dao & Gu, arXiv:2405.21060 sec. 6-7 for
+the scan; DeepSeek-V3.2-Exp's sparse attention for the selector;
+DeepSeek-V2, arXiv:2405.04434 sec. 2.1 for latent attention and
+DeepSeek-V3, arXiv:2412.19437 sec. 2.2 for multi-token prediction; the
+layer equations are those of docs/KERNELS.md "Hybrid decoder ops".)
 
 All but three are XLA compositions, which a GSPMD mesh partitions like
 any other op. Causal attention has two schedules of one algorithm: a
@@ -27,24 +33,27 @@ copies of them. Matrix products take their inputs in the dtype they
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
-Five *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
-``_contrib_gqa_mixer``, ``_contrib_rotary_gqa_mixer``,
+Seven *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
+``_contrib_glu_mlp_mixer``, ``_contrib_gqa_mixer``,
+``_contrib_rotary_gqa_mixer``, ``_contrib_mla_mixer``,
 ``_contrib_sparse_gqa_mixer``) hold a whole pre-norm mixer each,
 ``mixer(RMSNorm(x))``, and are where recomputation lives: the Mamba-2,
-expert, rotary and sparse-attention mixers are ``jax.checkpoint``-ed
-whole, so a training step keeps their input and recomputes their inside
-in the backward (the rotary one also keeps its
-context and, on the kernel path, the rows' log-sum-exp; the sparse one
-those and each row's selection threshold, so neither the search nor a
-second pass of the attention is repeated); the NoPE attention mixer
-keeps its q/k/v/context (and, on the kernel path, the rows'
-log-sum-exp) and recomputes each query block's scores. The device-side
-scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``, ``mx.moe.experts``,
-``mx.attn.causal``, ``mx.attn.window``, ``mx.attn.rotary`` (the rotary
-mixer, around either of the two before it) and ``mx.attn.dsa`` (inside
-it ``mx.attn.index``, ``mx.attn.select``, ``mx.attn.sparse``) name
-their instructions in the compiled program (forward, recomputation and
-backward alike).
+expert, dense gated, rotary, latent and sparse-attention mixers are
+``jax.checkpoint``-ed whole, so a training step keeps their input and
+recomputes their inside in the backward (the rotary and the latent one
+also keep their context and, on the kernel path, the rows'
+log-sum-exp; the sparse one those and each row's selection threshold,
+so neither the search nor a second pass of the attention is repeated);
+the NoPE attention mixer keeps its q/k/v/context (and, on the kernel
+path, the rows' log-sum-exp) and recomputes each query block's scores.
+The device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``,
+``mx.moe.experts``, ``mx.mlp``, ``mx.attn.causal``, ``mx.attn.window``,
+``mx.attn.rotary`` and ``mx.attn.mla`` (the rotary and the latent
+mixer, around the attention's own scope), ``mx.attn.dsa`` (inside it
+``mx.attn.index``, ``mx.attn.select``, ``mx.attn.sparse``) and
+``mx.mtp`` (what a multi-token-prediction module adds outside its
+block) name their instructions in the compiled program (forward,
+recomputation and backward alike).
 """
 from __future__ import annotations
 
@@ -498,6 +507,78 @@ def rotary_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
     with jax.named_scope("mx.attn.rotary"):
         return fn(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
                   q_norm_gamma, k_norm_gamma, positions)
+
+
+def _mla_mixer(data, norm_gamma, q_a_weight, q_a_norm_gamma, q_b_weight,
+               kv_a_weight, kv_a_norm_gamma, kv_b_weight, o_weight,
+               positions, *, h, nope, rope, vd, theta, eps):
+    b, length, _ = data.shape
+    if positions is None:
+        positions = _text_positions(b, length)
+    turn = _rotary_angles(positions, rope // 2, theta)
+    x = _rms(data, norm_gamma, eps)
+    # queries: down to the latent, its norm, up to every head's
+    # [no-position | rotary] lanes
+    q = _dense(_rms(_dense(x, q_a_weight), q_a_norm_gamma, eps), q_b_weight) \
+        .reshape(b, length, h, nope + rope)
+    # keys and values: the latent and ONE rotary key head a token; the
+    # latent's norm, up to every head's [no-position key | value] lanes
+    kv_a = _dense(x, kv_a_weight)
+    rank = kv_a.shape[-1] - rope
+    kv = _dense(_rms(kv_a[..., :rank], kv_a_norm_gamma, eps), kv_b_weight) \
+        .reshape(b, length, h, nope + vd)
+    k_rope = _rotate(kv_a[..., None, rank:], turn)
+    q = jnp.concatenate([q[..., :nope], _rotate(q[..., nope:], turn)], -1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_rope, (b, length, h, rope))], -1)
+    ctx = _attend(q, k, kv[..., nope:], keep=_CTX_KEPT)
+    return _dense(ctx.reshape(b, length, h * vd), o_weight)
+
+
+@register("_contrib_mla_mixer")
+def mla_mixer(data, norm_gamma, q_a_weight, q_a_norm_gamma, q_b_weight,
+              kv_a_weight, kv_a_norm_gamma, kv_b_weight, o_weight,
+              positions=None, *, num_heads, qk_nope_head_dim,
+              qk_rope_head_dim, v_head_dim, rope_theta=10000.0, eps=1e-5):
+    """A pre-norm latent-attention mixer (MLA), ``mixer(h)``, ``h =
+    RMSNorm(data)``, no bias anywhere. Per token, with ``n`` =
+    ``qk_nope_head_dim``, ``r`` = ``qk_rope_head_dim``, ``v`` =
+    ``v_head_dim`` and heads ``i = 1..num_heads``:
+
+        c_q = RMSNorm(W_qa h)                  q_a_weight (q_rank, hidden)
+        [q_nope ; q_rope]_i = W_qb c_q         q_b_weight (heads (n + r), q_rank)
+        [c ; k_r] = W_kva h                    kv_a_weight (kv_rank + r, hidden)
+        c_kv = RMSNorm(c);  k_rope = Rot(k_r)  one head, read by every query head
+        [k_nope ; v]_i = W_kvb c_kv            kv_b_weight (heads (n + v), kv_rank)
+        q_i = [q_nope_i ; Rot(q_rope_i)],  k_i = [k_nope_i ; k_rope]
+        o_t = W_o concat_i sum_{s <= t} softmax_s(q_it . k_is / sqrt(n + r)) v_is
+
+    ``Rot`` is :func:`rotary`'s rule over the ``r`` rotary lanes (lane
+    ``j`` paired with ``j + r/2``, ``rope_theta``; ``positions`` (batch,
+    length), the token's index where none are given). data (batch,
+    length, hidden). The expanded q, k and v, ``num_heads`` heads each
+    (no grouping), go to :func:`_attend`, the one causal attention of
+    this file, which needs q . k and v of one width: ``n + r == v``.
+    Recomputed whole in the backward (``jax.checkpoint``) but for the
+    context (and the kernel's log-sum-exp), which a step keeps beside
+    ``data``, by the rotary mixer's policy: the backward expands q, k
+    and v again from ``data`` (``heads (2 (n + r) + v)`` values a token
+    that are never kept), never runs the attention's forward."""
+    nope, rope, vd = (int(qk_nope_head_dim), int(qk_rope_head_dim),
+                      int(v_head_dim))
+    if nope + rope != vd:
+        raise ValueError(
+            "q . k over %d + %d lanes and values of %d: the causal "
+            "attention here takes one head width" % (nope, rope, vd))
+    fn = jax.checkpoint(
+        lambda *arrays: _mla_mixer(
+            *arrays, h=int(num_heads), nope=nope, rope=rope, vd=vd,
+            theta=float(rope_theta), eps=float(eps)),
+        policy=jax.checkpoint_policies.save_only_these_names(_CTX_KEPT))
+    with jax.named_scope("mx.attn.mla"):
+        return fn(data, norm_gamma, q_a_weight, q_a_norm_gamma, q_b_weight,
+                  kv_a_weight, kv_a_norm_gamma, kv_b_weight, o_weight,
+                  positions)
 
 
 # ---------------------------------------------------------------------------
@@ -1276,3 +1357,65 @@ def moe_mixer(data, norm_gamma, router_weight, expert_rows, w1, w2,
 
 
 moe_mixer.__doc__ += _MOE_DOC
+
+
+# ---------------------------------------------------------------------------
+# a dense gated MLP; what a multi-token-prediction module adds
+# ---------------------------------------------------------------------------
+@register("_contrib_glu_mlp_mixer")
+def glu_mlp_mixer(data, norm_gamma, gate_up_weight, down_weight, *,
+                  eps=1e-5):
+    """A pre-norm dense gated MLP (SwiGLU), ``mixer(h)``, ``h =
+    RMSNorm(data)``: ``W_down (silu(W_gate h) * W_up h)``, no bias.
+    gate_up_weight (2 x width, hidden) holds the gate's rows and then
+    the up projection's (an expert's layout: one product for both),
+    down_weight (hidden, width). data (batch, length, hidden).
+    Recomputed whole in the backward (``jax.checkpoint``): a step keeps
+    ``data`` only."""
+    def mixer(data, norm_gamma, gate_up_weight, down_weight):
+        x = _rms(data, norm_gamma, float(eps))
+        return _mlp(x, gate_up_weight, down_weight, _swiglu) \
+            .astype(data.dtype)
+
+    with jax.named_scope("mx.mlp"):
+        return jax.checkpoint(mixer)(data, norm_gamma, gate_up_weight,
+                                     down_weight)
+
+
+@register("_contrib_mtp_combine")
+def mtp_combine(embedding, hidden, embedding_norm_gamma, hidden_norm_gamma,
+                weight, *, eps=1e-5):
+    """The input of a multi-token-prediction module's block
+    (DeepSeek-V3, arXiv:2412.19437 eq. 21): ``u_t = W [RMSNorm_e(e_t) ;
+    RMSNorm_h(g_t)]``, ``e_t`` the embedding of the token after ``t``'s
+    (batch, length, hidden), ``g_t`` the stack's hidden state at ``t``
+    before its final norm, weight (hidden, 2 x hidden) reading the
+    embedding's lanes first, no bias. Recomputed in the backward: a
+    step keeps the two inputs."""
+    def combine(embedding, hidden, embedding_norm_gamma, hidden_norm_gamma,
+                weight):
+        return _dense(jnp.concatenate(
+            [_rms(embedding, embedding_norm_gamma, float(eps)),
+             _rms(hidden, hidden_norm_gamma, float(eps))], -1), weight)
+
+    with jax.named_scope("mx.mtp"):
+        return jax.checkpoint(combine)(
+            embedding, hidden, embedding_norm_gamma, hidden_norm_gamma,
+            weight)
+
+
+@register("_contrib_mtp_loss", num_outputs=1, mutate_aux={1: 2})
+def mtp_lm_loss(lm_loss, mtp_loss, loss_terms, *, mtp_weight):
+    """``mean(lm_loss) + mtp_weight * mean(mtp_loss[..., :-1])``, shape
+    (1,) float32: the next-token loss of every position plus the
+    weighted loss of a depth-1 multi-token-prediction module, both
+    per position (batch, length). The module's position ``t`` predicts
+    the token two after ``t``'s, which the last position of a row does
+    not have: it is left out of the second mean (whatever was fed
+    there gets no gradient). ``loss_terms`` (2,) float32 is an
+    auxiliary state (written, never differentiated): the two means of
+    this call."""
+    lm = jnp.mean(lm_loss.astype(F32))
+    mtp = jnp.mean(mtp_loss[..., :-1].astype(F32))
+    return (lm + float(mtp_weight) * mtp).reshape(1), \
+        lax.stop_gradient(jnp.stack([lm, mtp]))

@@ -175,7 +175,7 @@ def slice_op(data, *, begin, end, step=None):
 
 
 @register("slice_axis")
-def slice_axis(data, *, axis, begin, end):
+def slice_axis(data, *, axis, begin, end=None):
     ax = int(axis) % data.ndim
     idx = [slice(None)] * data.ndim
     idx[ax] = slice(begin, end)

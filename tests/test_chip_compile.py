@@ -631,3 +631,97 @@ def test_expert_mixer_under_a_mesh_keeps_the_composition(one_chip,
         text = jax.jit(_expert_mixer_gradient(sizes[3], **attrs)) \
             .lower(*args).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+# ---------------------------------------------------------------------------
+# GLM-4.7-Flash's mixers at the published widths (hidden 2048, 20 heads
+# of 192 + 64 / 256 lanes through bottlenecks of 768 and 512, a dense
+# MLP of 10,240, 8 of 64 experts of width 1,536 beside a shared one)
+# and the cell's 8,192 tokens
+# ---------------------------------------------------------------------------
+def _mla_mixer_gradient(one_chip, length):
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_mla_mixer").impl
+    hidden, h, qr, kvr, nope, rope, vd = 2048, 20, 768, 512, 192, 64, 256
+    shapes = [((1, length, hidden), BF), ((hidden,), BF), ((qr, hidden), BF),
+              ((qr,), BF), ((h * (nope + rope), qr), BF),
+              ((kvr + rope, hidden), BF), ((kvr,), BF),
+              ((h * (nope + vd), kvr), BF), ((hidden, h * vd), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(jax.value_and_grad(
+        lambda *a: _sum32(op(*a, num_heads=h, qk_nope_head_dim=nope,
+                             qk_rope_head_dim=rope, v_head_dim=vd,
+                             rope_theta=1e6, eps=1e-5)),
+        argnums=tuple(range(9)))).lower(*args).compile()
+
+
+def test_mla_mixer_at_8192_takes_the_causal_kernel_at_256_lanes(
+        one_chip, compiled_mode):
+    """The latent-attention mixer at the cell's shape: Mosaic accepts
+    the causal kernels at 256-wide heads and a group of one, as they
+    are; the forward kernel is in the program once (the mixer's
+    recomputation keeps the context and the log-sum-exp, and expands
+    q, k, v again), the backward once; both under ``mx.attn.causal``
+    inside ``mx.attn.mla``; no score block exists; and the whole
+    mixer's temporaries stay under a gigabyte."""
+    from mxbench import scopes
+    compiled = _mla_mixer_gradient(one_chip, 8192)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, ["mx.attn.causal", "mx.attn.mla"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_causal_gqa_")}
+    assert len(calls) == len(kernels) == 2
+    assert set(kernels.values()) == {"mx.attn.causal"}
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "pallas_causal_gqa_bwd", "pallas_causal_gqa_fwd"]
+    assert "mx.attn.mla" in placed.values()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    assert "f32[1,20,1,512," not in text and "f32[1,20,512," not in text
+
+
+def test_glm_expert_and_dense_mixers_compile_at_published_widths(
+        one_chip, compiled_mode):
+    """The expert op's fourth combination (sigmoid scores with a
+    selection bias, SwiGLU experts, a SwiGLU shared expert, x 1.8) at 8
+    of 64 experts of width 1,536: the grouped kernels' seven calls
+    under ``mx.moe.experts`` (24 blocks), the shared expert's products
+    outside it under ``mx.moe``; and the dense gated MLP of width
+    10,240 under ``mx.mlp``."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    moe = get_op("_contrib_moe_mixer").impl
+    length, hidden, width, held, routed = 8192, 2048, 1536, 8, 64
+
+    def loss(x, g, r, w1, w2, bias, s1, s2):
+        y, _ = moe(x, g, r, jnp.zeros((2, held), jnp.float32), w1, w2, bias,
+                   s1, s2, top_k=4, routed_scaling_factor=1.8,
+                   score_func="sigmoid", activation="swiglu", eps=1e-5)
+        return _sum32(y)
+
+    args = _expert_mixer_args(one_chip, length, hidden, width, held, routed,
+                              2) + [
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+            ((routed,), jnp.float32), ((2 * width, hidden), BF),
+            ((hidden, width), BF))]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 6, 7))) \
+        .lower(*args).compile().as_text()
+    placed = scopes.scope_map(text, ["mx.moe.experts", "mx.moe"])
+    kernels = {name: s for name, s in placed.items()
+               if "pallas_grouped_mlp_" in name}
+    assert len(kernels) == 7 and set(kernels.values()) == {"mx.moe.experts"}
+    assert "s32[24]" in text
+    assert "mx.moe" in placed.values()
+
+    mlp = get_op("_contrib_glu_mlp_mixer").impl
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((2 * 10240, hidden), BF), ((hidden, 10240), BF)]
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: _sum32(mlp(*a, eps=1e-5)), argnums=(0, 1, 2, 3))).lower(
+        *[jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+          for s, dt in shapes]).compile()
+    assert set(scopes.scope_map(compiled.as_text(), ["mx.mlp"]).values()) \
+        == {"mx.mlp"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

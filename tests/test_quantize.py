@@ -452,6 +452,7 @@ def test_kvstore_commwatch_dtype_bytes(monkeypatch):
     monkeypatch.setenv("MXNET_KVSTORE_QUANTIZE", "int8")
     monkeypatch.setenv("MXNET_KVSTORE_QUANTIZE_BLOCK", "32")
     monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    monkeypatch.delenv("MXNET_COMMWATCH", raising=False)
     from mxnet_tpu import commwatch, telemetry
     telemetry.refresh()
     try:

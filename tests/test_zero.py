@@ -31,6 +31,9 @@ def _clean(monkeypatch):
     monkeypatch.delenv("MXNET_ZERO_MIN_SIZE", raising=False)
     monkeypatch.delenv("MXNET_GUARD_NONFINITE", raising=False)
     monkeypatch.delenv("MXNET_GUARD_CLIP_NORM", raising=False)
+    # a benchmark test earlier in this worker may have left commwatch
+    # switched off for the process (mxbench.run.context)
+    monkeypatch.delenv("MXNET_COMMWATCH", raising=False)
     telemetry.refresh()
     yield
     telemetry.refresh()
