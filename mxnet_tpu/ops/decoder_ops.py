@@ -17,8 +17,12 @@ DeepSeek-V2, arXiv:2405.04434 sec. 2.1 for latent attention and
 DeepSeek-V3, arXiv:2412.19437 sec. 2.2 for multi-token prediction; the
 layer equations are those of docs/KERNELS.md "Hybrid decoder ops".)
 
-All but three are XLA compositions, which a GSPMD mesh partitions like
-any other op. Causal attention has two schedules of one algorithm: a
+All but four are XLA compositions, which a GSPMD mesh partitions like
+any other op. The Mamba-2 scan has two forms of one algorithm
+(:func:`_scan`): a pair of Pallas kernels that keep a chunk's decays
+and the carried state in VMEM (``ops/pallas_ssd.py``) where the call is
+one they can serve, the chunked composition here everywhere else
+(:func:`_ssd`). Causal attention has two schedules of one algorithm: a
 Pallas flash kernel (``ops/pallas_causal_gqa.py``) where the call is
 one it can serve, the blocked composition here everywhere else
 (:func:`_attend`). Attention over a selector's keys likewise
@@ -67,7 +71,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
 from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_sparse_gqa,
-               register)
+               pallas_ssd, register)
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -197,6 +201,24 @@ def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk):
     return y.astype(x.dtype)
 
 
+def _scan(x, dt, a_neg, bm, cm, d_skip, chunk):
+    """The scan by whichever form the call allows, chosen from what can
+    be observed here and nothing else: the kernels of
+    ``ops/pallas_ssd.py`` (a chunk's decays and mix and the carried
+    state in VMEM only) for bf16 x / B / C whose group of heads, state
+    and chunk are whole lane tiles, traced for one device
+    (``pallas_ssd.ssd_available``); the composition :func:`_ssd` for
+    everything else. Counted once a traced call in
+    ``mx_mamba2_ssd_path_total{path="pallas"|"xla"}``; the device-side
+    scope is ``mx.mamba2.ssd`` either way."""
+    kernel = pallas_ssd.ssd_available(x, bm, cm, chunk)
+    telemetry.count_event("mx_mamba2_ssd_path_total",
+                          path="pallas" if kernel else "xla")
+    with jax.named_scope(pallas_ssd.SCOPE):
+        form = pallas_ssd.ssd_scan if kernel else _ssd
+        return form(x, dt, a_neg, bm, cm, d_skip, chunk)
+
+
 @register("_contrib_ssd_scan")
 def ssd_scan(data, dt, a, b, c, d, *, chunk_size=128):
     """The selective state-space recurrence of Mamba-2 in its chunked
@@ -210,9 +232,8 @@ def ssd_scan(data, dt, a, b, c, d, *, chunk_size=128):
 
     computed chunk by chunk (``chunk_size`` steps: products inside a
     chunk, one carried state between chunks); any length (the tail is
-    padded with dt = 0)."""
-    with jax.named_scope("mx.mamba2.ssd"):
-        return _ssd(data, dt, a, b, c, d, chunk_size)
+    padded with dt = 0). Two forms of one algorithm (:func:`_scan`)."""
+    return _scan(data, dt, a, b, c, d, chunk_size)
 
 
 def _mamba2(u, norm_w, in_w, conv_w, conv_b, dt_bias, a_log, d_skip,
@@ -230,8 +251,7 @@ def _mamba2(u, norm_w, in_w, conv_w, conv_b, dt_bias, a_log, d_skip,
     bm = xbc[..., inner:inner + gn].reshape(b, length, groups, state)
     cm = xbc[..., inner + gn:].reshape(b, length, groups, state)
     dt = jax.nn.softplus(dt.astype(F32) + dt_bias.astype(F32))
-    with jax.named_scope("mx.mamba2.ssd"):
-        y = _ssd(x, dt, -jnp.exp(a_log.astype(F32)), bm, cm, d_skip, chunk)
+    y = _scan(x, dt, -jnp.exp(a_log.astype(F32)), bm, cm, d_skip, chunk)
     y = _gated_rms(y.reshape(b, length, inner), z, gate_norm_w,
                    inner // groups, eps)
     return _dense(y, out_w)

@@ -276,6 +276,87 @@ def test_causal_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
         assert 'mx.attn.causal' in line.split('op_name="')[1].split('"')[0]
 
 
+# a chunk's decays or mix over every chunk and head, as the compiled
+# composition holds them: (chunks, groups, heads a group, chunk, chunk)
+_DECAYS = re.compile(r"(f32|bf16)\[(1,)?\d+,(64|8,8),128,128\]")
+
+
+def _holds_the_ssd_kernels(text, names):
+    """A compiled program's Mosaic custom calls are the scan's kernels
+    ``names``, each placed under ``mx.mamba2.ssd`` by the benchmark's
+    own reader and carrying the scope in its ``op_name``; and nothing
+    of a chunk's decays is left in HBM."""
+    from mxbench import scopes
+    calls = [line.split('op_name="')[1].split('"')[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, ["mx.mamba2.ssd", "mx.mamba2"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_ssd_")}
+    assert len(calls) == len(kernels) == len(names)
+    assert sorted(n.split(".")[0] for n in kernels) == names
+    assert set(kernels.values()) == {"mx.mamba2.ssd"}
+    assert all("mx.mamba2.ssd" in op_name for op_name in calls)
+    assert not _DECAYS.search(text)
+
+
+@pytest.mark.parametrize("length", [8192, 1024])
+def test_ssd_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length):
+    """The scan op's gradient at the published widths (64 heads x 64,
+    8 groups x 128, chunk 128) takes the kernels: the forward rule's
+    (which writes the chunks' entering states) and the backward, named
+    ``pallas_ssd_*`` (what ``pallas_ms`` sums), each placed under
+    ``mx.mamba2.ssd`` by the benchmark's own reader, the one traced in
+    the backward rule too; and nothing of a chunk's decays is left in
+    HBM (64 chunks x 64 heads of 128 x 128 float32 are 268 MB)."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_ssd_scan").impl
+    f32 = jnp.float32
+    grad = jax.grad(lambda *a: _sum32(op(*a, chunk_size=128)),
+                    argnums=tuple(range(6)))
+    shapes = [((1, length, 64, 64), BF), ((1, length, 64), f32), ((64,), f32),
+              ((1, length, 8, 128), BF), ((1, length, 8, 128), BF),
+              ((64,), f32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(grad).lower(*args).compile()
+    _holds_the_ssd_kernels(compiled.as_text(),
+                           ["pallas_ssd_bwd", "pallas_ssd_fwd_states"])
+    # 269 MB at 8,192 (the entering states 67, the per-step columns and
+    # their gradient a lane tile wide in HBM 34 each, dy and the views);
+    # the composition's gradient holds 442
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 300e6 * length / 8192
+
+
+def test_mamba2_mixer_at_8192_recomputes_through_the_kernels(one_chip,
+                                                             compiled_mode):
+    """The whole mixer's gradient at the cell's shape (hidden 2,688, a
+    conv of 4): the forward kernel once (it writes no states), and in
+    the recomputation the forward rule's kernel, not the composition,
+    then the backward; all three under ``mx.mamba2.ssd`` inside
+    ``mx.mamba2``; no array of a chunk's decays."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_mamba2_mixer").impl
+    length, hidden, heads, p, groups, n = 8192, 2688, 64, 64, 8, 128
+    inner, conv = heads * p, heads * p + 2 * groups * n
+    f32 = jnp.float32
+    shapes = [((1, length, hidden), BF), ((hidden,), BF),
+              ((inner + conv + heads, hidden), BF), ((conv, 4), BF),
+              ((conv,), BF), ((heads,), f32), ((heads,), f32),
+              ((heads,), f32), ((inner,), BF), ((hidden, inner), BF)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: _sum32(op(*a, num_heads=heads, head_dim=p,
+                             n_groups=groups, state_size=n, chunk_size=128)),
+        argnums=tuple(range(10)))).lower(*args).compile()
+    _holds_the_ssd_kernels(
+        compiled.as_text(),
+        ["pallas_ssd_bwd", "pallas_ssd_fwd", "pallas_ssd_fwd_states"])
+
+
 # ---------------------------------------------------------------------------
 # the Keye-VL language model's sparse-attention mixer at the published
 # widths (hidden 2048, 32 / 4 heads of 128, selector 16 x 64), and a
