@@ -14,11 +14,16 @@ type with YaRN on the full ones, the same router and experts).
 ``glm_moe_lite``: the fourth, GLM-4.7-Flash (latent attention, a
 leading dense layer before the expert layers, a shared expert beside a
 sigmoid router, and a multi-token-prediction module that uses the
-embedding and the head a second time)."""
+embedding and the head a second time). ``laguna``: the fifth,
+Laguna-XS.2 (window and full attention layers whose query heads differ
+by a per-layer list, a sigmoid gate a head on the context, rotary over
+half a head's lanes on the full layers, a leading dense layer, a shared
+expert beside a scaled softmax router)."""
 from . import vision
 from . import bert
 from . import nemotron_h
 from . import keye_vl
 from . import mellum
 from . import glm_moe_lite
+from . import laguna
 from .vision import get_model

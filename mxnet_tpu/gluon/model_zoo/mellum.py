@@ -41,10 +41,15 @@ __all__ = ["MellumModel", "MellumLMLoss", "MellumDecoderLayer",
 KINDS = ("sliding_attention", "full_attention")
 
 
-def _rope_attrs(rope):
-    """A ``rope_parameters`` entry as the mixer op's attributes."""
+def _rope_attrs(rope, head_dim=None):
+    """A ``rope_parameters`` entry as the mixer op's attributes; with
+    ``partial_rotary_factor`` below 1, ``rotary_dim`` lanes of the
+    ``head_dim`` are turned."""
     kind = rope.get("rope_type", "default")
     attrs = dict(rope_theta=float(rope["rope_theta"]))
+    part = float(rope.get("partial_rotary_factor", 1))
+    if part != 1:
+        attrs["rotary_dim"] = int(head_dim * part)
     if kind == "yarn":
         attrs["rope_yarn"] = (
             float(rope["factor"]),

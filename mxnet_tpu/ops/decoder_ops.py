@@ -1,7 +1,8 @@
 """Ops of a decoder layer stack: RMSNorm (plain, and gated over
 groups), the causal depthwise conv and the chunked state-space (SSD)
 scan of a Mamba-2 mixer, rotary positions (one axis, or sectioned over
-several; plain, or slowed pair by pair by YaRN), blocked causal
+several; plain, or slowed pair by pair by YaRN; over a whole head or
+its first lanes), blocked causal
 grouped-query attention over every earlier key or over a sliding window
 of them, the same attention over the keys a learned selector keeps (an
 index score for every causal pair, the exact ``top_k`` largest a query,
@@ -53,7 +54,9 @@ path, the rows' log-sum-exp) and recomputes each query block's scores.
 The device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``,
 ``mx.moe.experts``, ``mx.mlp``, ``mx.attn.causal``, ``mx.attn.window``,
 ``mx.attn.rotary`` and ``mx.attn.mla`` (the rotary and the latent
-mixer, around the attention's own scope), ``mx.attn.dsa`` (inside it
+mixer, around the attention's own scope; inside the rotary one
+``mx.attn.gate``, a gate a head on the context, where a model has
+one), ``mx.attn.dsa`` (inside it
 ``mx.attn.index``, ``mx.attn.select``, ``mx.attn.sparse``) and
 ``mx.mtp`` (what a multi-token-prediction module adds outside its
 block) name their instructions in the compiled program (forward,
@@ -420,19 +423,23 @@ def _rotary_angles(positions, pairs, theta, sections=(), yarn=()):
 
 
 def _rotate(x, angles, attention_factor=1.0):
-    """x (batch, length, heads, d), lane ``i`` paired with ``i + d/2``:
-    each pair turned by its angle, in float32; cos and sin times
+    """x (batch, length, heads, d), angles (batch, length, pairs): the
+    first ``2 x pairs`` lanes are turned, lane ``i`` paired with ``i +
+    pairs``, each pair by its angle, in float32; cos and sin times
     ``attention_factor`` (YaRN's: q and k both carry it, so a score
-    carries its square)."""
-    half = x.shape[-1] // 2
+    carries its square). Lanes beyond them (a head turned in part) pass
+    unchanged and carry no factor."""
+    half = angles.shape[-1]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     if attention_factor != 1.0:
         cos, sin = cos * attention_factor, sin * attention_factor
     xf = x.astype(F32)
-    a, b = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1) \
-        .astype(x.dtype)
+    a, b = xf[..., :half], xf[..., half:2 * half]
+    out = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        out.append(xf[..., 2 * half:])
+    return jnp.concatenate(out, -1).astype(x.dtype)
 
 
 def _text_positions(batch, length, sections=()):
@@ -471,12 +478,16 @@ def _normed_rotary_qkv(x, q_weight, k_weight, v_weight, q_norm_gamma,
                        attention_factor=1.0):
     """q (batch, length, h, d), k and v (batch, length, kv, d) of the
     normed input x: bias-free projections, RMSNorm over each head of q
-    and k, both turned by the angles ``turn``."""
+    and k where a weight for it is given, both turned by the angles
+    ``turn`` (over the lanes the angles cover: :func:`_rotate`)."""
     b, length, _ = x.shape
-    q = _rotate(_rms(_dense(x, q_weight).reshape(b, length, h, d),
-                     q_norm_gamma, eps), turn, attention_factor)
-    k = _rotate(_rms(_dense(x, k_weight).reshape(b, length, kv, d),
-                     k_norm_gamma, eps), turn, attention_factor)
+
+    def heads(weight, n, gamma):
+        y = _dense(x, weight).reshape(b, length, n, d)
+        return y if gamma is None else _rms(y, gamma, eps)
+
+    q = _rotate(heads(q_weight, h, q_norm_gamma), turn, attention_factor)
+    k = _rotate(heads(k_weight, kv, k_norm_gamma), turn, attention_factor)
     return q, k, _dense(x, v_weight).reshape(b, length, kv, d)
 
 
@@ -484,49 +495,92 @@ _CTX_KEPT = "mx.attn.rotary.kept"   # what the mixer's checkpoint policy saves
 
 
 def _rotary_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
-                  q_norm_gamma, k_norm_gamma, positions, *, h, kv, d, theta,
-                  yarn, attention_factor, window, eps):
+                  q_norm_gamma, k_norm_gamma, positions, gate_weight, *, h,
+                  kv, d, rotary_dim, theta, yarn, attention_factor, window,
+                  eps):
     b, length, _ = data.shape
     if positions is None:
         positions = _text_positions(b, length)
     x = _rms(data, norm_gamma, eps)
     q, k, v = _normed_rotary_qkv(
         x, q_weight, k_weight, v_weight, q_norm_gamma, k_norm_gamma,
-        _rotary_angles(positions, d // 2, theta, yarn=yarn), h, kv, d, eps,
-        attention_factor)
+        _rotary_angles(positions, rotary_dim // 2, theta, yarn=yarn), h, kv,
+        d, eps, attention_factor)
     ctx = _attend(q, k, v, window, keep=_CTX_KEPT)
+    if gate_weight is not None:
+        # in the (.., heads, d) view the kernel's context is copied into
+        # that tiling and back (0.8 ms a copy at 8,192 tokens of 64
+        # heads on a v5e); spreading each head's gate over its lanes
+        # by a 0/1 product instead measured slower (PERF.md section 6,
+        # PR 42)
+        with jax.named_scope("mx.attn.gate"):
+            gate = jax.nn.sigmoid(_mm("...i,hi->...h", x, gate_weight))
+            ctx = (ctx.astype(F32) * gate[..., None]).astype(ctx.dtype)
     return _dense(ctx.reshape(b, length, h * d), o_weight)
 
 
 @register("_contrib_rotary_gqa_mixer")
 def rotary_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
-                     q_norm_gamma, k_norm_gamma, positions=None, *, num_heads,
-                     num_kv_heads, head_dim, rope_theta=10000.0, rope_yarn=(),
+                     q_norm_gamma=None, k_norm_gamma=None, positions=None,
+                     gate_weight=None, *, num_heads, num_kv_heads, head_dim,
+                     rotary_dim=0, rope_theta=10000.0, rope_yarn=(),
                      attention_factor=1.0, window=0, eps=1e-6):
-    """A pre-norm rotary attention mixer, ``mixer(RMSNorm(data))``:
-    bias-free q/k/v projections, RMSNorm over each head of q and k,
-    rotary positions (:func:`rotary`'s rule: ``rope_theta``, and YaRN's
-    ``rope_yarn`` and ``attention_factor`` where given; ``positions``
-    (batch, length), the token's index where none are given), causal
+    """A pre-norm rotary attention mixer, ``mixer(h)``, ``h =
+    RMSNorm(data)``: bias-free q/k/v projections, rotary positions
+    (:func:`rotary`'s rule: ``rope_theta``, and YaRN's ``rope_yarn``
+    and ``attention_factor`` where given; ``positions`` (batch,
+    length), the token's index where none are given), causal
     grouped-query attention (:func:`_attend`) over every earlier key,
     or with ``window`` > 0 over the keys ``t - window < s <= t``,
     bias-free output projection. data (batch, length, hidden). One
-    class of layer, two parameterisations: a model that mixes
-    sliding-window and full layers gives each its own attributes.
+    class of layer, several parameterisations: a model that mixes
+    sliding-window and full layers gives each its own attributes, and
+    its own ``num_heads`` where the kinds differ in their query heads
+    (q_weight (num_heads d, hidden), o_weight (hidden, num_heads d)).
+
+    Three terms are a model's to have or not (attributes and inputs
+    that describe it, as ``window`` and ``rope_yarn`` do). With ``d`` =
+    ``head_dim``, heads ``i`` and a token at position ``p``:
+
+    - ``q_norm_gamma`` / ``k_norm_gamma`` (d,): ``q_i = Rot(RMSNorm(W_q
+      h)_i)``, ``k_j = Rot(RMSNorm(W_k h)_j)``, the norm over each
+      head's ``d`` lanes with ``eps``; left out, ``q_i = Rot((W_q
+      h)_i)``, ``k_j = Rot((W_k h)_j)``.
+    - ``rotary_dim`` = ``r`` (even, at most ``d``; 0: the whole head):
+      ``Rot`` turns lanes ``0..r-1`` of a head, lane ``j`` paired with
+      ``j + r/2``, pair ``j`` by ``p f_j``, ``f_j = rope_theta^(-2j /
+      r)`` (under YaRN slowed by :func:`_yarn_ramp` over the ``r / 2``
+      pairs), cos and sin times ``attention_factor``; lanes ``r..d-1``
+      pass unchanged, without position and without the factor.
+    - ``gate_weight`` (num_heads, hidden): ``g = sigmoid(W_g h)``, one
+      value a query head a token, in float32; head ``i``'s context
+      ``c_i`` is multiplied by ``g_i`` before the output projection,
+      ``o = W_o concat_i (g_i c_i)``. The product, the sigmoid and the
+      multiply stand under the device scope ``mx.attn.gate``.
+
     Recomputed whole in the backward (``jax.checkpoint``) but for the
-    context (and the kernel's log-sum-exp), which a step keeps beside
-    ``data``: the backward runs the projections again, never the
-    attention's forward."""
+    attention's context (before the gate; and the kernel's
+    log-sum-exp), which a step keeps beside ``data``: the backward runs
+    the projections and the gate again, never the attention's
+    forward."""
+    d = int(head_dim)
+    turned = int(rotary_dim) or d
+    if turned % 2 or not 0 < turned <= d:
+        raise ValueError("rotary_dim %r is not an even number of a head's "
+                         "%d lanes" % (rotary_dim, d))
+    if (q_norm_gamma is None) != (k_norm_gamma is None):
+        raise ValueError("q and k are normed together or not at all")
     fn = jax.checkpoint(
         lambda *arrays: _rotary_mixer(
-            *arrays, h=int(num_heads), kv=int(num_kv_heads), d=int(head_dim),
-            theta=float(rope_theta), yarn=tuple(float(n) for n in rope_yarn),
+            *arrays, h=int(num_heads), kv=int(num_kv_heads), d=d,
+            rotary_dim=turned, theta=float(rope_theta),
+            yarn=tuple(float(n) for n in rope_yarn),
             attention_factor=float(attention_factor),
             window=int(window) or None, eps=float(eps)),
         policy=jax.checkpoint_policies.save_only_these_names(_CTX_KEPT))
     with jax.named_scope("mx.attn.rotary"):
         return fn(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
-                  q_norm_gamma, k_norm_gamma, positions)
+                  q_norm_gamma, k_norm_gamma, positions, gate_weight)
 
 
 def _mla_mixer(data, norm_gamma, q_a_weight, q_a_norm_gamma, q_b_weight,

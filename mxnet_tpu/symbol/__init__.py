@@ -58,6 +58,29 @@ class _Node:
         return self.op is None
 
 
+# A node holds no null inputs. Where an optional tensor is left out
+# before one that is given (a mixer without its q/k norms but with its
+# gate), the node's attrs name the impl's positional slot of each input
+# under this key; every site that runs a node puts the inputs back in
+# their slots (:func:`_in_slots`). Graph rewrites copy attrs, so the
+# key travels with the node, and through tojson / load_json.
+_SLOTS = "_input_slots"
+
+
+def _in_slots(node: "_Node", ins: List) -> Tuple[List, Dict[str, Any]]:
+    """(``ins`` in the impl's positional order, None where an optional
+    tensor was left out; the node's attrs without the slots' key)."""
+    attrs = dict(node.attrs)
+    slots = attrs.pop(_SLOTS, None)
+    if slots is None:
+        return ins, attrs
+    # (the slots ascend; ``max`` is an op in this namespace)
+    placed = [None] * (int(slots[-1]) + 1)
+    for slot, value in zip(slots, ins):
+        placed[int(slot)] = value
+    return placed, attrs
+
+
 class Symbol:
     """An output entry of a graph node (node, out_index) — possibly a
     group of several outputs (ref: nnvm SymbolEntry list)."""
@@ -423,7 +446,8 @@ def _walk_infer(sym: "Symbol", feed_shapes: Dict[str, tuple],
             raise MXNetError(
                 "shape inference failed at %s: unknown input shape(s) %s"
                 % (node.name, missing))
-        attrs = dict(canonical_attrs(node.attrs))
+        ins, attrs = _in_slots(node, ins)
+        attrs = dict(canonical_attrs(attrs))
         if node.op.needs_train_flag:
             attrs["_train"] = False
         fn = node.op.bind_attrs(attrs)
@@ -543,7 +567,7 @@ def _interpret_with(order: List[_Node], feed: Dict[str, Any], mode: str,
             continue
         ins = [results[id(s._entries[0][0])][s._entries[0][1]]
                for s in node.inputs]
-        attrs = dict(node.attrs)
+        ins, attrs = _in_slots(node, ins)
         if mode == "ndarray":
             out = nd_invoke(node.op, ins, attrs)
             outs = list(out) if isinstance(out, tuple) else [out]
@@ -642,21 +666,31 @@ def _make_sym_function(op: Operator):
                 raise TypeError("%s: positional args must be Symbols" % op.name)
         if not variadic:
             # bind keyword tensors BY NAME; a gap before a provided
-            # tensor cannot be represented in the symbol graph (nodes
-            # hold no null inputs), so reject it clearly
+            # tensor is kept as the inputs' slots in the node's attrs
+            # (nodes hold no null inputs: _in_slots). An op that writes
+            # an input back names it by position among the node's
+            # inputs, so a gap may only come after the ones it writes
             pending = []
-            for pname in fixed_names[len(inputs):]:
+            slots = list(range(len(inputs)))
+            for slot, pname in enumerate(fixed_names[len(inputs):],
+                                         len(inputs)):
                 if pname in kwargs and isinstance(kwargs[pname], Symbol):
-                    if pending:
+                    if pending and any(
+                            i >= fixed_names.index(pending[0])
+                            for i in op.mutate_aux.values()):
                         raise TypeError(
-                            "%s: optional tensor(s) %s omitted before "
-                            "%s — symbolic mode needs the earlier "
-                            "inputs too" % (op.name, pending, pname))
+                            "%s: optional tensor(s) %s omitted before an "
+                            "input the op writes back — symbolic mode "
+                            "needs the earlier inputs too"
+                            % (op.name, pending))
                     inputs.append(kwargs.pop(pname))
+                    slots.append(slot)
                 else:
                     if pname in kwargs and kwargs[pname] is None:
                         kwargs.pop(pname)
                     pending.append(pname)
+            if slots != list(range(len(slots))):
+                kwargs[_SLOTS] = tuple(slots)
         return _create(op.name, inputs, kwargs, name=name)
 
     fn.__name__ = op.name

@@ -806,3 +806,90 @@ def test_glm_expert_and_dense_mixers_compile_at_published_widths(
     assert set(scopes.scope_map(compiled.as_text(), ["mx.mlp"]).values()) \
         == {"mx.mlp"}
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# ---------------------------------------------------------------------------
+# Laguna-XS.2's mixers at the published widths (hidden 2048, 48 / 64
+# query heads over 8 key-value heads of 128, a gate a head, 32 of 256
+# experts of width 512 beside a shared one) and the cell's 8,192 tokens
+# ---------------------------------------------------------------------------
+def _gated_mixer_gradient(one_chip, heads, **attrs):
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_rotary_gqa_mixer").impl
+    length, hidden, kv, d = 8192, 2048, 8, 128
+    shapes = [(1, length, hidden), (hidden,), (heads * d, hidden),
+              (kv * d, hidden), (kv * d, hidden), (hidden, heads * d),
+              (heads, hidden)]
+    args = [jax.ShapeDtypeStruct(s, BF, sharding=one_chip) for s in shapes]
+    return jax.jit(jax.value_and_grad(
+        lambda *a: _sum32(op(*a[:6], gate_weight=a[6], num_heads=heads,
+                             num_kv_heads=kv, head_dim=d, eps=1e-6, **attrs)),
+        argnums=tuple(range(7)))).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kind, heads, attrs, scope, other", [
+    ("sliding", 64, dict(window=512, rope_theta=1e4),
+     "mx.attn.window", "mx.attn.causal"),
+    ("full", 48, dict(rotary_dim=64, rope_theta=5e5,
+                      rope_yarn=(64, 4096, 64, 1),
+                      attention_factor=1.4158883083359672),
+     "mx.attn.causal", "mx.attn.window")])
+def test_gated_rotary_mixer_at_8192_takes_the_kernel_at_groups_of_6_and_8(
+        one_chip, compiled_mode, kind, heads, attrs, scope, other):
+    """Both kinds of Laguna-XS.2's attention layer at the cell's
+    length: Mosaic accepts the causal kernels at a group of 6 query
+    heads a key-value head (no power of two) and the windowed ones at a
+    window of one tile (the diagonal tile and one ``cond``-ed edge
+    tile); forward once, backward once, under the kind's scope; the
+    gate's instructions under ``mx.attn.gate``; no q/k norm weight is
+    an input; the mixer's temporaries stay under 1.2 GB."""
+    from mxbench import scopes
+    compiled = _gated_mixer_gradient(one_chip, heads, **attrs)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    placed = scopes.scope_map(text, ["mx.attn.gate", scope, other,
+                                     "mx.attn.rotary"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_causal_gqa_")}
+    assert len(calls) == len(kernels) == 2
+    assert set(kernels.values()) == {scope}
+    assert other not in placed.values()
+    assert {"mx.attn.rotary", "mx.attn.gate"} <= set(placed.values())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert "f32[1,8,%d,512," % (heads // 8) not in text     # no score block
+
+
+def test_laguna_expert_mixer_fills_a_quarter_of_its_blocks(one_chip,
+                                                           compiled_mode):
+    """The expert op's fifth combination (softmax scores renormalised
+    and x 2.5, SwiGLU experts, a SwiGLU shared expert) at 32 of 256
+    experts of width 512: an expert's even share of 8,192 tokens at top
+    8 is 256 rows, half a block, so the buffer is 64 blocks of 512
+    (twice the share and a block an expert); the grouped kernels' seven
+    calls under ``mx.moe.experts``, the shared expert outside it."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    moe = get_op("_contrib_moe_mixer").impl
+    length, hidden, width, held, routed = 8192, 2048, 512, 32, 256
+
+    def loss(x, g, r, w1, w2, s1, s2):
+        y, _ = moe(x, g, r, jnp.zeros((2, held), jnp.float32), w1, w2, None,
+                   s1, s2, top_k=8, routed_scaling_factor=2.5,
+                   score_func="softmax", activation="swiglu", eps=1e-6)
+        return _sum32(y)
+
+    args = _expert_mixer_args(one_chip, length, hidden, width, held, routed,
+                              2) + [
+        jax.ShapeDtypeStruct(s, BF, sharding=one_chip)
+        for s in ((2 * width, hidden), (hidden, width))]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)))) \
+        .lower(*args).compile()
+    text = compiled.as_text()
+    placed = scopes.scope_map(text, ["mx.moe.experts", "mx.moe"])
+    kernels = {name: s for name, s in placed.items()
+               if "pallas_grouped_mlp_" in name}
+    assert len(kernels) == 7 and set(kernels.values()) == {"mx.moe.experts"}
+    assert "s32[64]" in text
+    assert "mx.moe" in placed.values()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
