@@ -34,7 +34,10 @@ composition. The expert buffer's products likewise
 (:func:`_blocks_product`): grouped kernels that read each block's
 weight tiles from the experts' own arrays
 (``ops/pallas_grouped_mlp.py``), or the batched product over gathered
-copies of them. Matrix products take their inputs in the dtype they
+copies of them; and the sum of a token's rows back out of the buffer
+(:func:`_sum_slots`): a kernel that reads the sorted buffer in short
+windows (``ops/pallas_moe_rows.py``), or XLA's gather of every slot's
+row. Matrix products take their inputs in the dtype they
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
@@ -73,8 +76,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
-from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_sparse_gqa,
-               pallas_ssd, register)
+from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_moe_rows,
+               pallas_sparse_gqa, pallas_ssd, register)
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -1115,29 +1118,39 @@ def _slots_to_rows(held, local, n_held, cap, block):
             expert_of_block, ends[-1] <= cap)
 
 
-@jax.custom_vjp
-def _gather_rows(x, token_of_row, row_of_slot):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(x, token_of_row, row_of_slot, sums):
     """Rows of ``x`` (T, D) into a buffer: ``out[r] = x[token_of_row[r]]``
-    (a zero row where ``token_of_row[r] == T``)."""
+    (a zero row where ``token_of_row[r] == T``). XLA's gather, which
+    moves these rows at the memory's pace (the indices rise within an
+    expert's run: PERF.md section 6, PR 43). ``sums`` is its
+    transpose's."""
     return jnp.concatenate([x, jnp.zeros_like(x[:1])])[token_of_row]
 
 
-@jax.custom_vjp
-def _sum_slots(rows, token_of_row, row_of_slot):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_slots(rows, token_of_row, row_of_slot, sums):
     """Each token's slots summed back: ``out[t] = sum_j rows[row_of_slot
-    [t, j]]`` (a zero row where the slot is one past the end)."""
+    [t, j]]`` (a zero row where the slot is one past the end), float32
+    accumulation, cast once: where ``sums`` (what
+    ``pallas_moe_rows.sum_available`` said of the call) by the window
+    kernel of ``ops/pallas_moe_rows.py`` (the buffer is sorted, so a
+    block of tokens finds its rows in a few short stretches), else by
+    XLA's gather of every slot's row."""
+    if sums:
+        return pallas_moe_rows.sum_slots(rows, token_of_row, row_of_slot)
     ext = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
     return jnp.sum(ext[row_of_slot].astype(F32), axis=1).astype(rows.dtype)
 
 
 # the two are each other's transposes (slots <-> rows is one-to-one on
-# what is held), so both backwards are gathers and no scatter runs
+# what is held), so both backwards are the other and no scatter runs
 _gather_rows.defvjp(
-    lambda x, tr, rs: (_gather_rows(x, tr, rs), (tr, rs)),
-    lambda res, g: (_sum_slots(g, *res), None, None))
+    lambda x, tr, rs, sums: (_gather_rows(x, tr, rs, sums), (tr, rs)),
+    lambda sums, res, g: (_sum_slots(g, *res, sums), None, None))
 _sum_slots.defvjp(
-    lambda rows, tr, rs: (_sum_slots(rows, tr, rs), (tr, rs)),
-    lambda res, g: (_gather_rows(g, *res), None, None))
+    lambda rows, tr, rs, sums: (_sum_slots(rows, tr, rs, sums), (tr, rs)),
+    lambda sums, res, g: (_gather_rows(g, *res, sums), None, None))
 
 
 _HIDDEN = "mx.moe.experts.hidden"   # what a chunk of blocks keeps
@@ -1191,12 +1204,16 @@ def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act, kernel):
 
 
 def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act,
-                    kernel):
+                    kernel, sums):
     """Rows gathered into one buffer sorted by expert, whole blocks an
-    expert; one batched product over the blocks, each against its
-    expert's weights (the same work whatever the routing: a block is
-    computed whole, rows that no slot fills are zeros); summed back by
-    token. Also the rows of each expert that were computed."""
+    expert (:func:`_gather_rows`: XLA's gather); one batched product
+    over the blocks, each against its expert's weights (the same work
+    whatever the routing: a block is computed whole, rows that no slot
+    fills are zeros); summed back by token (:func:`_sum_slots`: the
+    window kernel of ``ops/pallas_moe_rows.py`` where ``sums``, which
+    reads the sorted buffer in short contiguous stretches, else XLA's
+    gather of every slot's row). Also the rows of each expert that were
+    computed."""
     t, n_held = x.shape[0], up.shape[0]
     cap = expert_of_block.shape[0] * block
     slots = jnp.broadcast_to(jnp.arange(t)[:, None], row.shape)
@@ -1204,14 +1221,15 @@ def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act,
         .at[row.reshape(-1)].set(slots.reshape(-1))[:-1]
     weight_of_row = jnp.zeros((cap + 1,), F32) \
         .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1]
-    xr = _gather_rows(x, token_of_row, row).reshape(-1, block, x.shape[1])
+    xr = _gather_rows(x, token_of_row, row, sums) \
+        .reshape(-1, block, x.shape[1])
     yr = _blocks_product(xr, expert_of_block, weight_of_row, up, down, act,
                          kernel)
     filled = (token_of_row < t).reshape(-1, block)
     done = jnp.sum(jnp.where(
         expert_of_block[:, None] == jnp.arange(n_held),
         jnp.sum(filled, axis=1, dtype=jnp.int32)[:, None], 0), axis=0)
-    return _sum_slots(yr, token_of_row, row), done
+    return _sum_slots(yr, token_of_row, row, sums), done
 
 
 def _experts_dense(x, held, local, w_slot, counts, up, down, act):
@@ -1237,7 +1255,7 @@ def _experts_dense(x, held, local, w_slot, counts, up, down, act):
     return acc.astype(x.dtype)
 
 
-def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel):
+def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel, sums):
     """(the held experts' terms summed by token, the rows of each that
     were computed): the sorted buffer where the routing fits it. No row
     is dropped: routing that overfills the buffer takes the dense
@@ -1245,18 +1263,19 @@ def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel):
     pace: where routing piles the tokens on a few experts, cheaper than
     more passes of the gathered product, which PR 28 measured at 5x its
     cost). ``routing``: :func:`_slots_to_rows`' four results, ``held``
-    and ``local``."""
+    and ``local``; ``kernel`` / ``sums``: whether the buffer's products
+    / its slot sum are Pallas kernels."""
     row, counts, expert_of_block, fits, held, local = routing
     return lax.cond(
         fits,
         lambda: _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                block, act, kernel),
+                                block, act, kernel, sums),
         lambda: (_experts_dense(x, held, local, w_slot, counts, w1, w2, act),
                  counts))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act, sums):
     """:func:`_held_terms` on the kernel path, differentiated by hand so
     that nothing but its inputs crosses from the forward to the
     backward. Differentiated as it stands, the ``cond`` hands on each
@@ -1268,15 +1287,15 @@ def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act):
     the backward is a ``cond`` of its own over the same ``fits``, each
     branch the pullback of the forward's branch; the caller's
     ``jax.checkpoint`` recomputes nothing for it."""
-    return _held_terms(x, w_slot, w1, w2, routing, block, act, True)
+    return _held_terms(x, w_slot, w1, w2, routing, block, act, True, sums)
 
 
-def _kept_by_inputs_fwd(x, w_slot, w1, w2, routing, block, act):
-    return _held_terms(x, w_slot, w1, w2, routing, block, act, True), \
+def _kept_by_inputs_fwd(x, w_slot, w1, w2, routing, block, act, sums):
+    return _held_terms(x, w_slot, w1, w2, routing, block, act, True, sums), \
         (x, w_slot, w1, w2, routing)
 
 
-def _kept_by_inputs_bwd(block, act, res, cotangents):
+def _kept_by_inputs_bwd(block, act, sums, res, cotangents):
     x, w_slot, w1, w2, routing = res
     row, counts, expert_of_block, fits, held, local = routing
 
@@ -1289,7 +1308,7 @@ def _kept_by_inputs_bwd(block, act, res, cotangents):
             fits,
             pullback(lambda x, w_slot, w1, w2: _experts_sorted(
                 x, row, w_slot, expert_of_block, w1, w2, block, act,
-                True)[0]),
+                True, sums)[0]),
             pullback(lambda x, w_slot, w1, w2: _experts_dense(
                 x, held, local, w_slot, counts, w1, w2, act)))
     # the four gradients leave together: without the barrier the TPU
@@ -1299,6 +1318,20 @@ def _kept_by_inputs_bwd(block, act, res, cotangents):
 
 
 _held_terms_kept_by_inputs.defvjp(_kept_by_inputs_fwd, _kept_by_inputs_bwd)
+
+
+def _buffer(t, top_k, n_held, n_routed, capacity_factor=CAPACITY_FACTOR,
+            block_rows=BLOCK_ROWS):
+    """(rows a block, blocks, the most blocks any routing fills) of the
+    one buffer for the held experts' rows together: ``capacity_factor``
+    times their even share and a block of padding an expert. A token
+    chooses an expert at most once, so t x min(top_k, held) rows (and
+    their padding) always do."""
+    even = t * top_k / n_routed
+    block = min(int(block_rows), -(-math.ceil(capacity_factor * even) // 8) * 8)
+    most = -(-t * min(top_k, n_held) // block) + n_held
+    return block, min(most, math.ceil(capacity_factor * even * n_held / block)
+                      + n_held), most
 
 
 def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
@@ -1311,15 +1344,8 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
                          score_func)
     local = idx - offset
     held = (local >= 0) & (local < n_held)
-    # one buffer for the held experts' rows together: `capacity_factor`
-    # times their even share and a block of padding an expert. A token
-    # chooses an expert at most once, so t x min(top_k, held) rows (and
-    # their padding) always do
-    even = t * top_k / n_routed
-    block = min(int(block_rows), -(-math.ceil(capacity_factor * even) // 8) * 8)
-    most = -(-t * min(top_k, n_held) // block) + n_held
-    blocks = min(most, math.ceil(capacity_factor * even * n_held / block)
-                 + n_held)
+    block, blocks, most = _buffer(t, top_k, n_held, n_routed,
+                                  capacity_factor, block_rows)
     row, counts, expert_of_block, fits = _slots_to_rows(
         held, local, n_held, blocks * block, block)
     # the buffer's products by whichever schedule the call allows,
@@ -1331,17 +1357,23 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
         jax.ShapeDtypeStruct((blocks, block, x.shape[1]), x.dtype), w1, w2)
     telemetry.count_event("mx_moe_experts_path_total",
                           path="pallas" if kernel else "xla")
+    # and the sum of a token's rows back out of the buffer likewise
+    # (bf16 rows of whole lane tiles, a buffer of whole windows)
+    sums = pallas_moe_rows.sum_available(
+        jax.ShapeDtypeStruct((blocks * block, x.shape[1]), x.dtype), top_k, t)
+    telemetry.count_event("mx_moe_rows_path_total",
+                          path="pallas" if sums else "xla")
     routing = (row, counts, expert_of_block, fits, held, local)
     with jax.named_scope("mx.moe.experts"):
         if blocks >= most:
             y, done = _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                      block, act, kernel)
+                                      block, act, kernel, sums)
         elif kernel:
             y, done = _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing,
-                                                 block, act)
+                                                 block, act, sums)
         else:
             y, done = _held_terms(x, w_slot, w1, w2, routing, block, act,
-                                  False)
+                                  False, sums)
     return y, jnp.stack([counts, done]).astype(F32)
 
 
@@ -1360,13 +1392,16 @@ _MOE_DOC = """
     ``swiglu``, w1 (held, 2 x width, hidden) holding the gate's rows and
     then the up projection's, ``f_e(x) = W2_e (silu(W_gate,e x) *
     W_up,e x)``. Both attributes describe the model; neither changes how
-    the rows are moved. Rows are gathered, sorted by expert
-    and padded to whole blocks of ``BLOCK_ROWS`` an expert, into one
-    buffer of ``CAPACITY_FACTOR`` times the held experts' even share
-    (plus a block an expert), and every block multiplied by its
+    the rows are moved. Rows are gathered (XLA's gather), sorted by
+    expert and padded to whole blocks of ``BLOCK_ROWS`` an expert, into
+    one buffer of ``CAPACITY_FACTOR`` times the held experts' even
+    share (plus a block an expert), every block multiplied by its
     expert's weights (grouped Pallas kernels where the call allows
     them, :func:`_blocks_product`, else one batched product over the
-    blocks): the same work whatever the routing fills it with.
+    blocks): the same work whatever the routing fills it with; and
+    each token's rows summed back (the window kernel of
+    ``ops/pallas_moe_rows.py`` where the call allows it, else XLA's
+    gather of every slot's row).
     Routing that overfills the buffer takes a dense product over the
     held experts that are routed a row instead, so no row is ever
     dropped. ``expert_rows`` (2, held) float32 is an auxiliary state

@@ -3,6 +3,11 @@ expert's weights where they lie (``ops/decoder_ops.py::_blocks_product``
 holds the composition they stand in for, and stays the path of
 everything they cannot serve).
 
+The rows arrive by XLA's gather (``decoder_ops._gather_rows``: at the
+memory's pace, PERF.md section 6, PR 43) and leave, summed by token,
+through the window kernel of ``ops/pallas_moe_rows.py``
+(``decoder_ops._sum_slots``), which forks on its own predicate.
+
 The buffer is ``blocks`` blocks of ``block`` rows sorted by expert, and
 ``expert_of_block`` (int32, non-decreasing: an expert's blocks are
 contiguous, the empty blocks past the last expert's run are mapped to

@@ -656,7 +656,8 @@ def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
     # ``jvp_pallas_grouped_mlp_nt_``)
     kernels = {name: s for name, s in placed.items()
                if "pallas_grouped_mlp_" in name}
-    assert len(calls) == len(kernels) == 7
+    # (beside them the slot sum's two calls: the next test)
+    assert len(kernels) == 7 and len(calls) == 9
     assert set(kernels.values()) == {"mx.moe.experts"}
     assert sorted(re.search("pallas_grouped_mlp_(dw|nn|nt)", n).group(1)
                   for n in kernels) == ["dw"] * 2 + ["nn"] * 2 + ["nt"] * 3
@@ -679,7 +680,10 @@ def test_expert_mixer_off_the_lane_tiles_keeps_the_composition(one_chip,
     ``grouped_mlp_available`` says no (Mosaic takes the width as one
     whole tile, but the step's AUTO parameter layouts then do not
     survive the persistent compile cache: PERF.md section 6, PR 35),
-    and the mixer's gradient compiles with no Mosaic call."""
+    and the mixer's products compile as the composition; the only
+    Mosaic calls are the slot sum's two (``ops/pallas_moe_rows.py``
+    takes activations of any whole number of lane tiles: 2,688 is
+    21)."""
     from mxnet_tpu.ops import pallas_grouped_mlp
     sizes = (8192, 2688, 1856, 8, 128, 1)
     assert not pallas_grouped_mlp.grouped_mlp_available(
@@ -689,7 +693,70 @@ def test_expert_mixer_off_the_lane_tiles_keeps_the_composition(one_chip,
     text = jax.jit(_expert_mixer_gradient(
         8, top_k=6, routed_scaling_factor=2.5)).lower(
             *_expert_mixer_args(one_chip, *sizes)).compile().as_text()
-    assert "tpu_custom_call" not in text
+    assert "pallas_grouped_mlp" not in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2 and all("pallas_moe_rows_sum" in c for c in calls)
+
+
+# the expert mixer's gradient with the slot sum's window kernel in it:
+# buffer rows, (tokens, top_k), hidden; and the temporaries of the same
+# compile with the kernel stood down (PR 43's readings: 1,846,272,000 /
+# 593,056,768 / 724,051,456 bytes; with it 1,832,087,040 / 491,890,176 /
+# 695,194,112)
+ROWS_CELLS = {
+    "mellum2": (EXPERT_CELLS["mellum2"][0], 0, EXPERT_CELLS["mellum2"][2],
+                73728, 1.84e9),
+    "laguna": ((8192, 2048, 512, 32, 256, 2), 512,
+               dict(top_k=8, routed_scaling_factor=2.5, score_func="softmax",
+                    activation="swiglu"), 32768, 0.55e9),
+    "keye_vl": (EXPERT_CELLS["keye_vl"][0], 0, EXPERT_CELLS["keye_vl"][2],
+                24576, 0.71e9),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROWS_CELLS))
+def test_expert_mixer_sums_its_slots_by_the_window_kernel(one_chip,
+                                                          compiled_mode, cell):
+    """Mosaic accepts ``pallas_moe_rows_sum`` at the cells' shapes; its
+    two calls a layer (the forward's sum, which the recomputation does
+    not need again, and the pullback of the gather in the backward, whose
+    rule is traced after the caller's scopes have closed) are placed
+    under the scope the benchmark reads; no (tokens, top_k, hidden)
+    gather is left (the three gathers into the buffer are XLA's); and
+    the gradient's temporaries stay under what the same compile took
+    with the kernel stood down."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    sizes, shared, attrs, cap, bound = ROWS_CELLS[cell]
+    length, hidden, width, held, routed, mul = sizes
+    moe = get_op("_contrib_moe_mixer").impl
+
+    def loss(x, g, r, w1, w2, *s):
+        y, _ = moe(x, g, r, jnp.zeros((2, held), jnp.float32), w1, w2, None,
+                   *s, eps=1e-6, **attrs)
+        return _sum32(y)
+
+    args = _expert_mixer_args(one_chip, *sizes) + [
+        jax.ShapeDtypeStruct(s, BF, sharding=one_chip)
+        for s in ((2 * shared, hidden), (hidden, shared)) if shared]
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(len(args))))).lower(*args).compile()
+    text = compiled.as_text()
+    placed = scopes.scope_map(text, ["mx.moe.experts", "mx.moe"])
+    sums = {name: s for name, s in placed.items()
+            if "pallas_moe_rows_sum" in name}
+    assert len(sums) == 2 and set(sums.values()) == {"mx.moe.experts"}
+    assert sum("transpose(jvp" in line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "pallas_moe_rows_sum" in line) == 1
+    top_k = attrs["top_k"]
+    assert "bf16[%d,%d,%d]" % (length, top_k, hidden) not in text
+    assert "bf16[%d,%d]" % (length * top_k, hidden) not in text
+    gathers = [line for line in text.splitlines() if " gather(" in line
+               and "bf16[%d,%d]" % (cap, hidden) in line.split(" gather(")[0]]
+    assert len(gathers) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < bound
 
 
 def test_expert_mixer_under_a_mesh_keeps_the_composition(one_chip,
