@@ -4,7 +4,9 @@
                                       train/resnet50, train/bert_base,
                                       serve, timing sanity
     python chip_smoke.py --chips 4    four chips, and only that: BERT-base
-                                      dp=4 vs one device, then the Gluon
+                                      dp=4 vs one device, the per-shard
+                                      kernels in the compiled dp=4 step,
+                                      dropout's masks a shard, then the Gluon
                                       split_and_load loop on 4 contexts vs 1
     python chip_smoke.py --rehearse   the same control flow at toy sizes on
                                       whatever JAX finds (the CPU, with
@@ -231,6 +233,14 @@ BERT_KERNELS = ("pallas_layer_norm_fwd", "pallas_layer_norm_bwd",
                 "pallas_dropout_bwd")
 
 
+# of those, the ones that keep their XLA compositions in a program
+# partitioned over a mesh (ops/pallas_norm.py and pallas_epilogue.py
+# say why)
+MESH_COMPOSITIONS = ("pallas_layer_norm_fwd", "pallas_layer_norm_bwd",
+                     "pallas_bias_gelu_fwd", "pallas_bias_gelu_bwd",
+                     "pallas_residual_fwd")
+
+
 def seq_out(outputs):
     """The encoder's (batch, seq, units) output: a block with one
     output returns it bare, with several as a tuple."""
@@ -276,6 +286,16 @@ def phase_bert(meter, seed, rehearse):
     return step, data
 
 
+def kept_share(y, dx, p):
+    """The share of a block of ones that dropout kept, once it is about
+    1-p, scaled by 1/(1-p), and the backward's mask the forward's."""
+    kept = float((y != 0).mean())
+    assert abs(kept - (1 - p)) < 0.01, kept
+    np.testing.assert_allclose(y[y != 0], 1 / (1 - p), rtol=1e-2)
+    np.testing.assert_array_equal(y, dx)
+    return kept
+
+
 def phase_dropout_kernel(rehearse):
     """The in-kernel-PRNG dropout has no interpreter form, so no CPU
     test has ever executed it: check on the chip that it keeps about
@@ -294,10 +314,7 @@ def phase_dropout_kernel(rehearse):
     y, vjp = jax.vjp(lambda a: pallas_dropout(key, a, p), x)
     (dx,) = vjp(jnp.ones_like(y))
     y, dx = np.asarray(y, np.float32), np.asarray(dx, np.float32)
-    kept = float((y != 0).mean())
-    assert abs(kept - (1 - p)) < 0.01, kept
-    np.testing.assert_allclose(y[y != 0], 1 / (1 - p), rtol=1e-2)
-    np.testing.assert_array_equal(y, dx)
+    kept = kept_share(y, dx, p)
     say("dropout", "kept %.4f of %d elements (p=%.1f); backward mask == "
         "forward mask" % (kept, y.size, p))
 
@@ -400,13 +417,17 @@ def assert_four_busy(phase, devs):
 
 def phase_dp4_sharded(meter, seed, rehearse, devs):
     """BERT-base ShardedTrainStep on MeshConfig(dp=4), global batch
-    128, against the same seed and global batch on one device. Dropout
-    0 on both sides: the only difference is where the work ran."""
+    256, against the same seed and global batch on one device. Dropout
+    0 on both sides: the only difference is where the work ran. (A
+    batch unlike the length: a row-wise kernel finds the dimension to
+    run a shard at a time on by the batch's size.) Then the dp=4 step
+    at dropout 0.1: every kernel that runs once a shard is a custom
+    call of the compiled step, the norm and the epilogues are not."""
     import jax
     import mxnet_tpu as mx
     from bert_bench import build_step
     from mxnet_tpu.parallel import MeshConfig, make_mesh
-    batch, seq = (8, 32) if rehearse else (128, 128)
+    batch, seq = (8, 32) if rehearse else (256, 128)
     runs = {}
     for name, mesh_devs in (("dp=4", devs), ("one device", devs[:1])):
         mx.random.seed(seed)
@@ -436,6 +457,65 @@ def phase_dp4_sharded(meter, seed, rehearse, devs):
         gc.collect()
     assert all(np.isfinite(runs["dp=4"])), runs
     np.testing.assert_allclose(runs["dp=4"], runs["one device"], rtol=2e-2)
+    mx.random.seed(seed)
+    step, data = build_step(batch, seq, net=make_bert(rehearse, 0.1),
+                            mesh=make_mesh(MeshConfig(dp=len(devs)),
+                                           devices=devs))
+    loss = jax.device_get(step.step(*data)).item()
+    assert np.isfinite(loss), loss
+    if not rehearse:
+        found = kernel_counts(next(iter(step._compiled.values())).as_text())
+        say("dp4/sharded", "dropout 0.1: loss %.4f; tpu_custom_call by "
+            "kernel: %s" % (loss, json.dumps(found, sort_keys=True)))
+        missing = [k for k in BERT_KERNELS
+                   if k not in MESH_COMPOSITIONS and not found.get(k)]
+        assert not missing, \
+            "Pallas kernels that run once a shard absent from the " \
+            "compiled dp=4 BERT step: %s" % missing
+        assert not set(found) & set(MESH_COMPOSITIONS), found
+
+
+def phase_dp4_dropout_kernel(rehearse, devs):
+    """phase_dropout_kernel's checks a shard: the in-kernel-PRNG
+    dropout inside a program partitioned over the four chips keeps
+    about 1-p of every shard's elements, its backward regenerates that
+    shard's forward mask, and no two shards draw one mask."""
+    if rehearse:
+        return say("dp4/dropout", "skipped: pltpu PRNG has no interpreter")
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+    from mxnet_tpu.ops.pallas_common import auto_partitioned
+    from mxnet_tpu.ops.pallas_dropout import (pallas_dropout,
+                                              pallas_dropout_available)
+    from mxnet_tpu.parallel import MeshConfig, make_mesh
+    p, shape = 0.1, (128, 512, 768)
+    mesh = make_mesh(MeshConfig(dp=len(devs)), devices=devs)
+
+    def both(x):
+        with auto_partitioned(mesh, batch=("dp", shape[1])):
+            assert pallas_dropout_available(shape, jnp.bfloat16, p)
+            y, vjp = jax.vjp(
+                lambda a: pallas_dropout(jax.random.key(0), a, p), x)
+        return y, vjp(jnp.ones_like(y))[0]
+
+    rows = NamedSharding(mesh, PartitionSpec(None, "dp"))
+    fn = jax.jit(both, in_shardings=rows)
+    x = jax.device_put(jnp.ones(shape, jnp.bfloat16), rows)
+    found = kernel_counts(fn.lower(x).compile().as_text())
+    assert found == {"pallas_dropout_fwd": 1, "pallas_dropout_bwd": 1}, found
+    y, dx = fn(x)
+    assert y.sharding.is_equivalent_to(rows, 3), y.sharding
+    y, dx = np.asarray(y, np.float32), np.asarray(dx, np.float32)
+    shards = np.split(y, len(devs), axis=1)
+    kept = [kept_share(s, ds, p)
+            for s, ds in zip(shards, np.split(dx, len(devs), axis=1))]
+    same = [(i, j) for i in range(len(shards)) for j in range(i)
+            if np.array_equal(shards[i], shards[j])]
+    assert not same, "shards drew one mask: %s" % same
+    say("dp4/dropout", "kept %s of a shard's elements (p=%.1f); backward "
+        "mask == forward mask on every shard; no two shards' masks alike"
+        % (["%.4f" % k for k in kept], p))
 
 
 def phase_dp4_gluon(meter, seed, rehearse, devs):
@@ -525,6 +605,7 @@ def main():
     if args.chips == 4:
         devs = jax.devices()[:4]
         phase_dp4_sharded(meter, args.seed, args.rehearse, devs)
+        phase_dp4_dropout_kernel(args.rehearse, devs)
         phase_dp4_gluon(meter, args.seed, args.rehearse, devs)
     else:
         phase_native()

@@ -140,10 +140,15 @@ def selfatt_plan(L, heads, batch, dropout=0.0, dtype=None,
     when bbh does not divide heads), ``n_blocks = batch * n_hblk`` the
     per-block dropout-seed count. ``head_dim`` sizes the VMEM check and
     the lane alignment of ``bbh`` (BERT-family 64 when not given).
+
+    In a program partitioned over a mesh the kernel runs once a shard
+    (pallas_common.per_shard): the block is planned (and tuned) for a
+    shard's share of the batch, and ``n_blocks`` still counts the whole
+    batch's seeds, which split with it.
     """
     from ..config import get as _cfg
-    from .pallas_common import kernels_allowed
-    if not _cfg("MXNET_FLASH_ATTENTION") or not kernels_allowed():
+    from .pallas_common import split_of
+    if not _cfg("MXNET_FLASH_ATTENTION"):
         return None
     if L < 1 or L > _MAX_L or heads < 1 or batch < 1 or head_dim < 1:
         return None
@@ -153,10 +158,17 @@ def selfatt_plan(L, heads, batch, dropout=0.0, dtype=None,
         # through it would silently lose precision vs the unfused
         # composition (advisor r3) — f32 falls back
         return None
+    split = split_of("pallas_selfatt_packed", (L, batch), dim=1)
+    if not split:
+        return None
     esize = 2 if dtype is None else jnp.dtype(dtype).itemsize
     L_pad = _ceil_to(L, _SUBLANE)
-    return _resolve_plan(int(L), int(L_pad), int(heads), int(batch),
-                         esize, block_heads, int(head_dim))
+    plan = _resolve_plan(int(L), int(L_pad), int(heads),
+                         int(batch) // split.shards, esize, block_heads,
+                         int(head_dim))
+    if plan is not None:
+        plan["n_blocks"] *= split.shards
+    return plan
 
 
 def _resolve_plan(L, L_pad, heads, batch, esize, block_heads, hd):
@@ -488,7 +500,9 @@ def flash_selfatt(qkv, seeds, *, heads, dropout=0.0, block_heads=None):
     (ignored when dropout=0). Returns context (L, N, heads*hd).
     Scores/softmax in f32, matmul operands bf16 — matching the unfused
     XLA path. ``block_heads`` overrides the autotuned head-block size
-    (tests)."""
+    (tests). In a program partitioned over a mesh ``N`` is split and
+    the seeds, ``N``-major, with it."""
+    from .pallas_common import per_shard, split_of
     heads = int(heads)
     L, N, thd = qkv.shape
     if block_heads is None:
@@ -501,4 +515,5 @@ def flash_selfatt(qkv, seeds, *, heads, dropout=0.0, block_heads=None):
                 % (L, heads, N))
         block_heads = plan["bbh"]
     f = _make_op(heads, float(dropout), int(block_heads))
-    return f(qkv, seeds)
+    split = split_of("pallas_selfatt_packed", qkv.shape, dim=1)
+    return per_shard("pallas_selfatt_packed", split, f, qkv, seeds)

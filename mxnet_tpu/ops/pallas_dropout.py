@@ -21,6 +21,7 @@ shapes fall back to the jax.random.bernoulli composition in ops/nn.py
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +49,8 @@ def _pick_rows(M, C, esize):
 def pallas_dropout_available(shape, dtype, p):
     """True when the in-kernel-PRNG dropout can serve this call."""
     from ..config import get as _cfg
-    from .pallas_common import kernels_allowed
-    if not _cfg("MXNET_PALLAS_DROPOUT") or not kernels_allowed():
+    from .pallas_common import split_of
+    if not _cfg("MXNET_PALLAS_DROPOUT"):
         return False
     if _interpret():
         return False          # pltpu PRNG has no interpreter impl
@@ -61,6 +62,10 @@ def pallas_dropout_available(shape, dtype, p):
         return False
     if len(shape) < 2:
         return False
+    split = split_of("pallas_dropout", shape)
+    if not split:
+        return False
+    shape = split.local(shape)      # a shard's rows decide the tiling
     C = shape[-1]
     M = 1
     for s in shape[:-1]:
@@ -145,12 +150,26 @@ def pallas_dropout(rng, data, p):
 
     rng: JAX PRNG key (only used to derive per-block int32 seeds);
     data: (..., C) with the availability rules already checked;
-    p: drop probability. Returns data-shaped output in data.dtype."""
-    C = data.shape[-1]
-    M = data.size // C
+    p: drop probability. Returns data-shaped output in data.dtype.
+
+    In a program partitioned over a mesh the kernel runs once a shard
+    (pallas_common.per_shard) on the shard's own slice of the seeds:
+    one seed a row block of every shard is drawn here, outside, so no
+    two shards draw one mask, and the backward (the transpose of the
+    forward's ``shard_map``) regenerates a shard's mask from the slice
+    the forward saved there."""
+    from .pallas_common import per_shard, split_of
+    split = split_of("pallas_dropout", data.shape)
+    local = split.local(data.shape)
+    C = local[-1]
+    M = math.prod(local[:-1])
     esize = jnp.dtype(data.dtype).itemsize
     bm = _tuned_rows(M, C, esize, _pick_rows(M, C, esize))
-    seeds = jax.random.randint(rng, (M // bm,), 0, 2 ** 31 - 1,
-                               dtype=jnp.int32)
+    seeds = jax.random.randint(rng, (split.shards * (M // bm),), 0,
+                               2 ** 31 - 1, dtype=jnp.int32)
     f = _make_op(M, C, bm, float(p), jnp.dtype(data.dtype).name)
-    return f(data.reshape(M, C), seeds).reshape(data.shape)
+
+    def block(x, seeds):
+        return f(x.reshape(M, C), seeds).reshape(x.shape)
+
+    return per_shard("pallas_dropout", split, block, data, seeds)

@@ -113,6 +113,11 @@ def _tuned_rows(kernel, M, C, esize, n_streams, default, build_probe):
 def _available(shape, dtype, n_streams):
     from ..config import get as _cfg
     from .pallas_common import kernels_allowed
+    # no per-shard rule (pallas_common.per_shard), on purpose: in the
+    # partitioned BERT-base step these two kernels, a shard each, read
+    # 3,941 samples/s where XLA's own fusions of the compositions read
+    # 4,503 (PERF.md section 6, PR 45), so on a mesh they stand down
+    # (on one device too they lose: ROADMAP A2)
     if not _cfg("MXNET_PALLAS_EPILOGUE") or not kernels_allowed():
         return False
     if len(shape) < 2:

@@ -65,6 +65,11 @@ def pallas_ln_available(shape, dtype, axis):
     falls back to the XLA _ln_fused path otherwise)."""
     from ..config import get as _cfg
     from .pallas_common import kernels_allowed
+    # no per-shard rule (pallas_common.per_shard), on purpose: in the
+    # partitioned BERT-base step these kernels, a shard each, read
+    # 4,507 samples/s where XLA's fusions of the composition (with the
+    # residual add before it) read 4,620 (PERF.md section 6, PR 45), so
+    # on a mesh they stand down
     if not _cfg("MXNET_PALLAS_LAYERNORM") or not kernels_allowed():
         return False
     if len(shape) < 2 or axis != len(shape) - 1:

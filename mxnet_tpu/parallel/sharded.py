@@ -332,6 +332,17 @@ class ShardedTrainStep:
         compute_dtype = self._dtype
         mesh = self.mesh
 
+        batch_ax = batch_axes(mesh)
+        data_specs = [s.spec for s in self.data_shardings]
+
+        def split_batch(data):
+            """(mesh axes, size) of the batch the data inputs carry
+            split on their first dimension; None where none does."""
+            for x, spec in zip(data, data_specs):
+                if x.ndim and len(spec) and spec[0] == batch_ax:
+                    return batch_ax, x.shape[0]
+            return None
+
         def loss_of(params, aux, data, rng):
             feed = dict(params)
             feed.update(dict(zip(data_names, data)))
@@ -346,9 +357,11 @@ class ShardedTrainStep:
             # run the EMA carry in bf16 precision for nothing
             feed.update(aux)
             # GSPMD partitions this program over the mesh, and cannot
-            # partition a Mosaic kernel: on more than one device the
-            # ops take their XLA compositions (ops/pallas_common.py)
-            with auto_partitioned(mesh):
+            # partition a Mosaic kernel: on more than one device a
+            # kernel whose rows are a sample's own runs once a shard,
+            # on the dimension that holds this batch, and every other
+            # op takes its XLA composition (ops/pallas_common.py)
+            with auto_partitioned(mesh, batch=split_batch(data)):
                 out, new_aux = fn(feed, rng=rng) if needs_rng else fn(feed)
             # moving-stat updates (FMutateInputs semantics): carried as
             # auxiliary outputs, stored back in the caller's fp32 copies

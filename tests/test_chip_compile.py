@@ -155,36 +155,211 @@ def test_dropout(one_chip, compiled_mode):
                          *shapes) >= 1
 
 
-def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
-        one_chip, compiled_mode):
-    """Why ShardedTrainStep traces inside auto_partitioned(mesh): a
-    kernel in a program GSPMD partitions over four chips is refused;
-    inside the scope the ops answer "not available" instead."""
+@pytest.fixture
+def four_chips(one_chip):
+    """The described v5e:2x2 as a ``dp`` mesh, with the shardings of a
+    batch-split ``(L, N, ...)`` operand and of a replicated one."""
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    return (mesh, NamedSharding(mesh, P(None, "dp")),
+            NamedSharding(mesh, P()))
+
+
+def _collectives(text):
+    return {k: text.count(k + "(") + text.count(k + "-start(")
+            for k in ("all-gather", "all-to-all", "all-reduce")}
+
+
+def test_gspmd_refuses_a_mosaic_kernel_and_the_scope_stands_it_down(
+        four_chips, compiled_mode):
+    """Why ShardedTrainStep traces inside auto_partitioned(mesh): a
+    bare kernel in a program GSPMD partitions over four chips is
+    refused. Inside the scope a kernel with no rule answers "not
+    available"; the BERT kernels that have one, through their ops, run
+    once a shard on batch-split operands (next test)."""
     from mxnet_tpu.ops.pallas_attention import selfatt_plan
     from mxnet_tpu.ops.pallas_common import auto_partitioned, kernels_allowed
     from mxnet_tpu.ops.pallas_norm import (pallas_layer_norm,
                                            pallas_ln_available)
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    mesh = Mesh(np.array(topo.devices), ("dp",))
-    rows = NamedSharding(mesh, P(None, "dp"))
-    rep = NamedSharding(mesh, P())
+    mesh, rows, rep = four_chips
     args = [jax.ShapeDtypeStruct((L, N, C), BF, sharding=rows),
             jax.ShapeDtypeStruct((C,), BF, sharding=rep),
             jax.ShapeDtypeStruct((C,), BF, sharding=rep)]
     with pytest.raises(NotImplementedError, match="automatically part"):
         jax.jit(pallas_layer_norm).lower(*args).compile()
-    with auto_partitioned(mesh):
+    with auto_partitioned(mesh, batch=("dp", N)):
         assert not kernels_allowed()
         assert not pallas_ln_available((L, N, C), BF, 2)
+        assert selfatt_plan(L, H, N, 0.0, dtype=BF, head_dim=D) is not None
+    with auto_partitioned(mesh):        # no batch stated: nothing to split
         assert selfatt_plan(L, H, N, 0.0, dtype=BF, head_dim=D) is None
     assert kernels_allowed()
-    one = Mesh(np.array(topo.devices[:1]), ("dp",))
+    one = type(mesh)(mesh.devices.reshape(-1)[:1], ("dp",))
     with auto_partitioned(one):
-        assert pallas_ln_available((L, N, C), BF, 2)
+        assert kernels_allowed() and pallas_ln_available((L, N, C), BF, 2)
+
+
+def _op(name, **attrs):
+    """The registered op ``name`` as a function of its array operands
+    (a PRNG key first where it draws)."""
+    def call(*arrays):
+        from mxnet_tpu.ops import get_op
+        op = get_op(name)
+        if attrs:
+            arrays = (jax.random.key(0),) + arrays
+        return op.impl(*arrays, **attrs)
+    return call
+
+
+NB = 512    # the dp4 cell's batch: 128 a chip (and not the length:
+            # a row kernel finds the batch by its size)
+
+
+# op, operand shapes ("rows": split on N), custom calls forward + backward
+@pytest.mark.parametrize("op, shapes, calls", [
+    (_op("Dropout", p=0.1, _train=True), [((L, NB, C), "rows")], 2),
+    (_op("_contrib_sdp_selfatt", heads=H, dropout=0.1, _train=True),
+     [((L, NB, 3 * H * D), "rows")], 2),
+    (_op("LayerNorm"), [((L, NB, C), "rows"), ((C,), None), ((C,), None)],
+     0),
+    (_op("_contrib_bias_gelu"),
+     [((L, NB, 4 * C), "rows"), ((4 * C,), None)], 0),
+    (_op("_contrib_bias_add_residual"),
+     [((L, NB, C), "rows"), ((C,), None), ((L, NB, C), "rows")], 0),
+], ids=["dropout", "attention", "norm", "gelu", "residual"])
+def test_a_bert_kernel_compiles_once_a_shard_on_a_split_batch(
+        four_chips, compiled_mode, op, shapes, calls):
+    """ISSUE 45: value and gradient through the op on a described
+    v5e:2x2, 128 samples a chip: the Mosaic calls are there (inside a
+    ``shard_map`` the compiler takes), nothing is gathered, and the
+    only collective is the sum of the loss and the parameters'
+    gradients. The layer norm and the two epilogues keep their
+    compositions (no custom call): a shard each they lost to XLA's
+    fusions on the chip."""
+    from mxnet_tpu.ops.pallas_common import auto_partitioned
+    mesh, rows, rep = four_chips
+    args = [jax.ShapeDtypeStruct(s, BF, sharding=rows if d else rep)
+            for s, d in shapes]
+
+    def loss(*a):
+        with auto_partitioned(mesh, batch=("dp", NB)):
+            out = op(*a)
+        # a cotangent that depends on the operand keeps every kernel's
+        # backward alive
+        return _sum32(out * a[0][..., :out.shape[-1]])
+
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(len(args))))).lower(*args) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == calls
+    found = _collectives(text)
+    assert found["all-gather"] == found["all-to-all"] == 0
+    assert found["all-reduce"] <= 2
+
+
+# the whole depth compiles in 40 s alone, at the end of the file that
+# already takes a tier-1 worker longest: `slow`, its two-layer twin not
+@pytest.mark.parametrize("layers", [2, pytest.param(
+    12, marks=pytest.mark.slow)])
+def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
+        four_chips, layers, monkeypatch):
+    """The ``bert_base_pretrain_s128_dp4`` step (the zoo model through
+    ``trace_block``, bf16 on float32 masters, dropout 0.1, LAMB through
+    the shared ``_apply_update``) compiled for the described 2x2 with
+    512 samples split four ways: a layer's attention forward and
+    backward and dropout's kernels as ``tpu_custom_call``s (50 at 12
+    layers: the one-chip step's 138 less the 52 layer-norm and the 36
+    epilogue calls, which keep their compositions on a mesh), no
+    all-gather or all-to-all, four all-reduces (loss and gradients,
+    combined).
+    Traced, the step counts its 12 attention calls under
+    ``path="pallas"`` and every kernel ``how="sharded"``, none
+    ``composition``. Temporaries: PERF.md section 6, PR 45."""
+    from mxbench import manifest
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops import pallas_common
+    from mxnet_tpu.parallel.sharded import _apply_update, trace_block
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh, _, rep = four_chips
+    seq, batch = 128, 512
+    sizes, cfgmod, _ = manifest.config("bert_base")
+    net, loss, n_in = cfgmod.sharded_parts(
+        dict(sizes, num_hidden_layers=layers), 0.1, seq)
+    # only now: the shapes above were resolved by an eager forward, on
+    # the CPU and interpreted
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    fn, data_names, names, _ = trace_block(net, loss, n_in)
+    shapes = {n: p.shape for block in (net, loss.head)
+              for n, p in block.collect_params().items()}
+
+    def sds(shape, dt=jnp.float32, sharding=rep):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=sharding)
+
+    hp = dict(lr=1e-3, momentum=0.0, wd=0.01, beta1=0.9, beta2=0.999,
+              epsilon=1e-8, clip_gradient=-1.0, rescale_grad=1.0)
+
+    def loss_of(params, data, key):
+        feed = {k: v.astype(BF) for k, v in params.items()}
+        feed.update(zip(data_names, data))
+        with pallas_common.auto_partitioned(mesh, batch=("dp", batch)):
+            out, _ = fn(feed, rng=key)
+        return _sum32(out[0])
+
+    def step(params, states, t, key, *data):
+        value, grads = jax.value_and_grad(loss_of)(params, list(data), key)
+        return value, {k: _apply_update(
+            "lamb", hp, w, grads[k].astype(jnp.float32), states[k], t)
+            for k, w in params.items()}
+
+    params = {n: sds(shapes[n]) for n in names}
+    ids = sds((batch, seq), jnp.int32, NamedSharding(mesh, P("dp")))
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=rep)
+    drops = layers + 1
+    traced = {("mx_attn_selfatt_path_total", ("path", "pallas")): layers,
+              ("mx_attn_selfatt_path_total", ("path", "xla")): 0}
+    for kernel, n in (("pallas_selfatt_packed", layers),
+                      ("pallas_dropout", drops)):
+        traced["mx_pallas_partitioned_total", ("kernel", kernel),
+               ("how", "sharded")] = n
+        traced["mx_pallas_partitioned_total", ("kernel", kernel),
+               ("how", "composition")] = 0
+
+    def read():
+        return {k: telemetry.counter(k[0], **dict(k[1:])).get()
+                for k in traced}
+
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        start = read()
+        compiled = jax.jit(step).lower(
+            params, {n: (params[n], params[n]) for n in names}, sds(()),
+            key, ids, ids, ids).compile()
+        assert {k: n - start[k] for k, n in read().items()} == traced
+    finally:
+        telemetry.enable(was)
+    text = compiled.as_text()
+    calls = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.findall(r"pallas_(?!call)\w+", re.search(
+                r'op_name="([^"]*)"', line).group(1))[-1]
+            calls[name] = calls.get(name, 0) + 1
+    assert calls == {
+        "pallas_selfatt_packed_fwd": layers,
+        "pallas_selfatt_packed_bwd": layers,
+        "pallas_dropout_fwd": drops, "pallas_dropout_bwd": drops}
+    found = _collectives(text)
+    assert found["all-gather"] == found["all-to-all"] == 0
+    assert found["all-reduce"] <= 4
+    if layers == 12:
+        assert found["all-reduce"] == 4
+        # 8.37 GB in the parent's own step on the chip
+        assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 
 # ---------------------------------------------------------------------------

@@ -440,14 +440,16 @@ def test_the_budget_decides_the_plan(monkeypatch):
 @pytest.mark.parametrize("dtype, devices, path, other",
                          [(jnp.bfloat16, 1, "pallas", "xla"),
                           (jnp.float32, 1, "xla", "pallas"),
-                          (jnp.bfloat16, 2, "xla", "pallas")],
+                          (jnp.bfloat16, 2, "pallas", "xla")],
                          ids=["bf16", "f32", "bf16-two-devices"])
 def test_a_traced_call_counts_its_path(dtype, devices, path, other):
     """ISSUE 39: ``mx_attn_selfatt_path_total{path}`` counts one a
     traced call of ``_contrib_sdp_selfatt``: bf16 on one device the
     kernel (one ``pallas_call`` left in the gradient, its backward
-    rule); float32, or a program GSPMD partitions over several devices
-    as the dp4 cell's, the composition (its six products)."""
+    rule); float32 the composition (its six products); in a program
+    GSPMD partitions over several devices, as the dp4 cell's, the
+    kernel once a shard (ISSUE 45: the ``pallas_call`` inside the
+    transposed ``shard_map``)."""
     from jax.sharding import Mesh
     from mxnet_tpu import telemetry
     from mxnet_tpu.ops import get_op, pallas_common
@@ -468,13 +470,19 @@ def test_a_traced_call_counts_its_path(dtype, devices, path, other):
     telemetry.enable(True)
     try:
         start = counts()
-        with pallas_common.auto_partitioned(mesh):
+        with pallas_common.auto_partitioned(mesh, batch=("dp", 2)):
             jaxpr = jax.make_jaxpr(jax.grad(loss))(qkv)
         got = {p: n - start[p] for p, n in counts().items()}
     finally:
         telemetry.enable(was)
     assert got == {path: 1, other: 0}
-    heavy = [e.primitive.name for e in jaxpr.jaxpr.eqns
-             if e.primitive.name in ("pallas_call", "dot_general")]
-    assert heavy == (["pallas_call"] if path == "pallas"
-                     else ["dot_general"] * 6)
+
+    def heavy(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name in ("pallas_call", "dot_general"):
+                yield e.primitive.name
+            elif e.primitive.name == "shard_map":
+                yield from heavy(e.params["jaxpr"])
+
+    assert list(heavy(jaxpr.jaxpr)) == (
+        ["pallas_call"] if path == "pallas" else ["dot_general"] * 6)
