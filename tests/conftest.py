@@ -151,3 +151,55 @@ def pallas_interpret(monkeypatch):
     CPU-mesh numerics wherever the suite runs)."""
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
     yield
+
+
+# ---------------------------------------------------------------------------
+# programs compiled for a described chip: tests/test_chip_compile_*.py,
+# the only files that load the TPU library
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    """The sharding of one device of a described v5e:2x2 (no chip is
+    attached: what is compiled for it never runs)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_mode(monkeypatch):
+    """Kernels built for the chip, not the interpreter — steered from
+    the test: the code under test asks ``interpret_mode()`` and, with
+    only CPU devices attached, would answer True."""
+    from mxnet_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    """``compiled(key, build)``: what ``build()`` lowers and compiles,
+    once a ``key`` in a file. The key names everything that defines the
+    program (the function, its sizes and attributes, the fixtures that
+    steer its trace), so two tests that read one program pay for one
+    compile (Mosaic's is single-threaded: 20 to 30 s a mixer)."""
+    memo = {}
+
+    def get(key, build):
+        if key not in memo:
+            memo[key] = build()
+        return memo[key]
+
+    return get

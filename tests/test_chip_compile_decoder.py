@@ -1,0 +1,338 @@
+"""Chipless compiles, the decoders' attention, scan and selector at
+their published widths for a described v5e chip (see
+tests/test_chip_compile_bert.py for what such a compile can and cannot
+show): the causal, windowed and sparse flash kernels, the Mamba-2 scan's
+kernels, each under the scope the benchmark reads, and a whole toy
+Keye-VL step.
+"""
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from numerics import BF, described, mosaic_calls, sum32
+
+
+# ---------------------------------------------------------------------------
+# the hybrid decoder's ops at the Nemotron-H widths (hidden 2688; 64
+# Mamba heads x 64, 8 groups x 128; 32/2 attention heads x 128; experts
+# 2688 x 1856, 8 of 128 held, top 6), forward and backward: XLA
+# compositions, no kernel of this repo's or of the compiler's own; but
+# attention and the scan through their ops, which take the kernels
+# ---------------------------------------------------------------------------
+def _scan_operands(one_chip, length):
+    """x, dt, a, B, C, d of the scan: 64 heads x 64, 8 groups x 128."""
+    f32 = jnp.float32
+    return described(
+        one_chip, (1, length, 64, 64), ((1, length, 64), f32), ((64,), f32),
+        (1, length, 8, 128), (1, length, 8, 128), ((64,), f32))
+
+
+def _attention_operands(one_chip, length):
+    """q, k, v: 32 / 2 heads of 128."""
+    return described(one_chip, (1, length, 32, 128), (1, length, 2, 128),
+                     (1, length, 2, 128))
+
+
+@pytest.mark.parametrize("length", [1024])
+def test_scan_and_attention_compile_at_published_widths(one_chip, length):
+    from mxnet_tpu.ops import decoder_ops as D
+    scan = jax.grad(lambda *a: sum32(D._ssd(*a, 128)), argnums=(0, 1, 3, 4))
+    assert not mosaic_calls(jax.jit(scan).lower(
+        *_scan_operands(one_chip, length)).compile().as_text())
+    attn = jax.grad(lambda *a: sum32(D._causal_gqa(*a, 512)),
+                    argnums=(0, 1, 2))
+    assert not mosaic_calls(jax.jit(attn).lower(
+        *_attention_operands(one_chip, length)).compile().as_text())
+
+
+@pytest.mark.parametrize("length", [8192, 1024])
+def test_causal_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length):
+    """The op's gradient at the published widths takes the flash kernel:
+    two Mosaic custom calls, named ``pallas_causal_gqa_*`` (what
+    ``pallas_ms`` sums), each placed under ``mx.attn.causal`` by the
+    benchmark's own reader, the one traced in the backward rule too."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_causal_gqa_attention").impl
+    grad = jax.grad(lambda *a: sum32(op(*a)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(
+        *_attention_operands(one_chip, length)).compile().as_text()
+    calls = mosaic_calls(text)
+    placed = scopes.scope_map(text, ["mx.attn.causal"])
+    names = sorted(name for name in placed
+                   if name.startswith("pallas_causal_gqa_"))
+    assert len(calls) == len(names) == 2
+    assert names[0].startswith("pallas_causal_gqa_bwd")
+    assert names[1].startswith("pallas_causal_gqa_fwd")
+    for line in calls:
+        assert 'mx.attn.causal' in line.split('op_name="')[1].split('"')[0]
+
+
+# a chunk's decays or mix over every chunk and head, as the compiled
+# composition holds them: (chunks, groups, heads a group, chunk, chunk)
+_DECAYS = re.compile(r"(f32|bf16)\[(1,)?\d+,(64|8,8),128,128\]")
+
+
+def _holds_the_ssd_kernels(text, names):
+    """A compiled program's Mosaic custom calls are the scan's kernels
+    ``names``, each placed under ``mx.mamba2.ssd`` by the benchmark's
+    own reader and carrying the scope in its ``op_name``; and nothing
+    of a chunk's decays is left in HBM."""
+    from mxbench import scopes
+    calls = [line.split('op_name="')[1].split('"')[0]
+             for line in mosaic_calls(text)]
+    placed = scopes.scope_map(text, ["mx.mamba2.ssd", "mx.mamba2"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_ssd_")}
+    assert len(calls) == len(kernels) == len(names)
+    assert sorted(n.split(".")[0] for n in kernels) == names
+    assert set(kernels.values()) == {"mx.mamba2.ssd"}
+    assert all("mx.mamba2.ssd" in op_name for op_name in calls)
+    assert not _DECAYS.search(text)
+
+
+@pytest.mark.parametrize("length", [8192, 1024])
+def test_ssd_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length):
+    """The scan op's gradient at the published widths (64 heads x 64,
+    8 groups x 128, chunk 128) takes the kernels: the forward rule's
+    (which writes the chunks' entering states) and the backward, named
+    ``pallas_ssd_*`` (what ``pallas_ms`` sums), each placed under
+    ``mx.mamba2.ssd`` by the benchmark's own reader, the one traced in
+    the backward rule too; and nothing of a chunk's decays is left in
+    HBM (64 chunks x 64 heads of 128 x 128 float32 are 268 MB)."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_ssd_scan").impl
+    grad = jax.grad(lambda *a: sum32(op(*a, chunk_size=128)),
+                    argnums=tuple(range(6)))
+    compiled = jax.jit(grad).lower(
+        *_scan_operands(one_chip, length)).compile()
+    _holds_the_ssd_kernels(compiled.as_text(),
+                           ["pallas_ssd_bwd", "pallas_ssd_fwd_states"])
+    # 269 MB at 8,192 (the entering states 67, the per-step columns and
+    # their gradient a lane tile wide in HBM 34 each, dy and the views);
+    # the composition's gradient holds 442
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 300e6 * length / 8192
+
+
+def test_mamba2_mixer_at_8192_recomputes_through_the_kernels(one_chip,
+                                                             compiled_mode):
+    """The whole mixer's gradient at the cell's shape (hidden 2,688, a
+    conv of 4): the forward kernel once (it writes no states), and in
+    the recomputation the forward rule's kernel, not the composition,
+    then the backward; all three under ``mx.mamba2.ssd`` inside
+    ``mx.mamba2``; no array of a chunk's decays."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_mamba2_mixer").impl
+    length, hidden, heads, p, groups, n = 8192, 2688, 64, 64, 8, 128
+    inner, conv = heads * p, heads * p + 2 * groups * n
+    f32 = jnp.float32
+    args = described(
+        one_chip, (1, length, hidden), (hidden,),
+        (inner + conv + heads, hidden), (conv, 4), (conv,), ((heads,), f32),
+        ((heads,), f32), ((heads,), f32), (inner,), (hidden, inner))
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: sum32(op(*a, num_heads=heads, head_dim=p,
+                             n_groups=groups, state_size=n, chunk_size=128)),
+        argnums=tuple(range(10)))).lower(*args).compile()
+    _holds_the_ssd_kernels(
+        compiled.as_text(),
+        ["pallas_ssd_bwd", "pallas_ssd_fwd", "pallas_ssd_fwd_states"])
+
+
+# ---------------------------------------------------------------------------
+# the Keye-VL language model's sparse-attention mixer at the published
+# widths (hidden 2048, 32 / 4 heads of 128, selector 16 x 64), and a
+# whole toy training step: all XLA, nothing of Mosaic's, the selection
+# without a sort
+# ---------------------------------------------------------------------------
+def _sparse_mixer(one_chip, length, top_k):
+    """(the sparse mixer's loss over both outputs, its 13 parameters and
+    the state) at the published widths."""
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_sparse_gqa_mixer").impl
+    hidden, h, kv, d, ih, idim = 2048, 32, 4, 128, 16, 64
+    attrs = dict(num_heads=h, num_kv_heads=kv, head_dim=d, index_heads=ih,
+                 index_head_dim=idim, top_k=top_k, rope_theta=1e7,
+                 rope_sections=(16, 24, 24), eps=1e-6)
+
+    def loss(*a):
+        y, index_loss, _ = op(*a, **attrs)
+        return sum32(y) + index_loss[0]
+
+    return loss, described(
+        one_chip, (1, length, hidden), (hidden,), (h * d, hidden),
+        (kv * d, hidden), (kv * d, hidden), (hidden, h * d), (d,), (d,),
+        (ih * idim, hidden), (idim, hidden), (ih, hidden), (idim,), (idim,),
+        ((2,), jnp.float32))
+
+
+def test_sparse_attention_mixer_compiles_at_published_widths(one_chip):
+    """1,024 tokens, top-k 256 so that selection engages in both query
+    blocks: forward + backward for the described chip; the three inner
+    scopes name instructions under ``mx.attn.dsa``; the k-th largest
+    score comes from a loop of counts, no ``sort``."""
+    from mxbench import scopes
+    loss, args = _sparse_mixer(one_chip, 1024, 256)
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(13)))) \
+        .lower(*args).compile().as_text()
+    assert not mosaic_calls(text)
+    names = ("mx.attn.index", "mx.attn.select", "mx.attn.sparse",
+             "mx.attn.dsa")
+    found = scopes.scope_map(text, names)
+    assert set(found.values()) == set(names)
+    select = [line for line in text.splitlines() if "mx.attn.select" in line]
+    assert [line for line in select if " while(" in line]
+    assert not [line for line in select if " sort(" in line]
+    # two blocks of scores, never a length x length one
+    assert "f32[1,4,8,512,1024]" in text
+    assert "f32[1,4,8,1024,1024]" not in text
+
+
+def _sparse_mixer_gradient(one_chip, length, top_k):
+    """The compiled text of the sparse mixer's value and gradient (both
+    outputs, to all 13 parameters) at the published widths."""
+    loss, args = _sparse_mixer(one_chip, length, top_k)
+    return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(13)))) \
+        .lower(*args).compile().as_text()
+
+
+# two and four query blocks in tier-1; the published length's sixteen
+# (34 Mosaic calls, two minutes of one core) makes every same assertion:
+# `slow` here, and compiled on the chip by the Keye-VL cell
+@pytest.mark.parametrize("length, top_k", [
+    (1024, 256), (2048, 512),
+    pytest.param(8192, 2048, marks=pytest.mark.slow)])
+def test_sparse_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
+        one_chip, compiled_mode, length, top_k):
+    """Compiled, not interpreted, the mixer takes the flash kernels:
+    Mosaic accepts them within the VMEM limit, every custom call is
+    named ``pallas_sparse_gqa_*`` (what ``pallas_ms`` sums) and placed
+    under ``mx.attn.sparse`` by the benchmark's own reader (the forward
+    kernel once: the recomputation does not run it again; the
+    probabilities a query block in the forward and again in the
+    backward rule; one backward kernel), and no score block is left in
+    the program."""
+    from mxbench import scopes
+    text = _sparse_mixer_gradient(one_chip, length, top_k)
+    calls = mosaic_calls(text)
+    names = ("mx.attn.index", "mx.attn.select", "mx.attn.sparse",
+             "mx.attn.dsa")
+    placed = scopes.scope_map(text, names)
+    assert set(placed.values()) == set(names)
+    kernels = {name: scope for name, scope in placed.items()
+               if name.startswith("pallas_sparse_gqa_")}
+    blocks = length // 512
+    assert len(calls) == len(kernels) == 2 + 2 * blocks
+    assert set(kernels.values()) == {"mx.attn.sparse"}
+    kinds = [name.split(".")[0] for name in kernels]
+    assert kinds.count("pallas_sparse_gqa_fwd") == 1
+    assert kinds.count("pallas_sparse_gqa_bwd") == 1
+    assert kinds.count("pallas_sparse_gqa_probs") == 2 * blocks
+    for line in calls:
+        assert "mx.attn.sparse" in line.split('op_name="')[1].split('"')[0]
+    assert "f32[1,4,8,512," not in text
+    assert "f32[1,32,512," not in text
+
+
+def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
+    """The zoo model through ``trace_block`` as ``ShardedTrainStep``
+    traces it (both losses, bf16 compute, AdamW through the shared
+    ``_apply_update``), at the configuration's toy widths."""
+    from mxbench import manifest
+    from mxnet_tpu.parallel.sharded import _apply_update, trace_block
+    sizes, cfgmod, _ = manifest.config("keye_vl2_30b_a3b")
+    sizes = dict(sizes, **sizes["toy"])
+    net, loss, n_in = cfgmod.sharded_parts(sizes, 0.0, 64)
+    fn, data_names, names, _ = trace_block(net, loss, n_in)
+    shapes = {n: p.shape for block in (net, loss.head)
+              for n, p in block.collect_params().items()}
+    aux_names = [n for n in names if n in fn._aux_names]
+    names = [n for n in names if n not in fn._aux_names]
+    assert len(aux_names) == 2 * sizes["num_hidden_layers"]
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+
+    hp = dict(lr=1e-5, momentum=0.9, wd=1e-6, beta1=0.9, beta2=0.95,
+              epsilon=1e-8, clip_gradient=-1.0, rescale_grad=1.0)
+
+    def loss_of(params, aux, data):
+        feed = {k: v.astype(BF) for k, v in params.items()}
+        feed.update(zip(data_names, data))
+        feed.update(aux)
+        out, new_aux = fn(feed)
+        return sum32(out[0]), new_aux
+
+    def step(params, aux, states, t, *data):
+        (value, new_aux), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(params, aux, list(data))
+        new = {k: _apply_update("adamw", hp, w, grads[k], states[k], t)
+               for k, w in params.items()}
+        return value, new_aux, new
+
+    params = {n: sds(shapes[n]) for n in names}
+    aux = {n: sds(shapes[n]) for n in aux_names}
+    ids = sds((2, 64), jnp.int32)
+    text = jax.jit(step).lower(
+        params, aux, {n: (params[n], params[n]) for n in names}, sds(()),
+        ids, ids).compile().as_text()
+    assert not mosaic_calls(text)
+    for scope in cfgmod.SCOPES:
+        assert scope in text, scope
+
+
+# ---------------------------------------------------------------------------
+# Mellum 2's two mixers at the published widths (hidden 2304, 32 / 4
+# heads of 128, 16 of 64 experts of width 896) and the cell's 16,384
+# tokens: what a step of the long-context cell is made of
+# ---------------------------------------------------------------------------
+def _rotary_mixer_gradient(one_chip, length, **attrs):
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_rotary_gqa_mixer").impl
+    hidden, h, kv, d = 2304, 32, 4, 128
+    args = described(one_chip, (1, length, hidden), (hidden,),
+                     (h * d, hidden), (kv * d, hidden), (kv * d, hidden),
+                     (hidden, h * d), (d,), (d,))
+    return jax.jit(jax.value_and_grad(
+        lambda *a: sum32(op(*a, num_heads=h, num_kv_heads=kv, head_dim=d,
+                             rope_theta=5e5, eps=1e-6, **attrs)),
+        argnums=tuple(range(8)))).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kind, attrs, scope, other", [
+    ("sliding", dict(window=1024), "mx.attn.window", "mx.attn.causal"),
+    ("full", dict(rope_yarn=(16, 8192, 32, 1),
+                  attention_factor=1.2772588722239782),
+     "mx.attn.causal", "mx.attn.window")])
+def test_rotary_mixer_at_16384_takes_the_kernel_under_its_kind_s_scope(
+        one_chip, compiled_mode, kind, attrs, scope, other):
+    """Both kinds of Mellum 2's attention layer at the cell's length:
+    Mosaic accepts the windowed kernels (a loop from a traced first
+    tile, a ``cond`` around the band's tile) and the causal ones at
+    twice the Nemotron cell's length; the forward kernel is in the
+    program once (the mixer's recomputation keeps the context and the
+    log-sum-exp), the backward once; both under the scope the benchmark
+    reads for that kind, and the whole mixer's temporaries stay under a
+    gigabyte and a half."""
+    from mxbench import scopes
+    compiled = _rotary_mixer_gradient(one_chip, 16384, **attrs)
+    text = compiled.as_text()
+    calls = mosaic_calls(text)
+    placed = scopes.scope_map(text, [scope, other, "mx.attn.rotary"])
+    kernels = {name: s for name, s in placed.items()
+               if name.startswith("pallas_causal_gqa_")}
+    assert len(calls) == len(kernels) == 2
+    assert set(kernels.values()) == {scope}
+    assert sorted(n.split(".")[0] for n in kernels) == [
+        "pallas_causal_gqa_bwd", "pallas_causal_gqa_fwd"]
+    assert other not in placed.values()
+    assert "mx.attn.rotary" in placed.values()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    # no score block: 512 queries against a band, or against every key
+    assert "f32[1,4,8,512," not in text

@@ -11,47 +11,12 @@ import pytest
 
 from mxbench import manifest
 from mxnet_tpu.ops import decoder_ops as D, get_op
+from numerics import (F32, attention_ref, close, highest, jitted,  # noqa: F401
+                      near, rand, reference, remat_count, same_values_and_grads,
+                      swiglu_experts, value_and_grads)
 
-REF = manifest.load_module("reference", "nemotron_twotower_30b_a3b.py")
-F32 = jnp.float32
-
-
-@pytest.fixture(autouse=True)
-def _highest():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def _rand(seed, *shapes, scale=1.0):
-    keys = jax.random.split(jax.random.key(seed), len(shapes))
-    return [scale * jax.random.normal(k, s, F32) for k, s in zip(keys, shapes)]
-
-
-def _close(got, want, tol=2e-5):
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=tol,
-                                   atol=tol)
-
-
-def _same_values_and_grads(fn, ref, args, tol=2e-5):
-    _close(fn(*args), ref(*args), tol)
-    cot = _rand(99, jnp.shape(ref(*args)))[0]
-    argnums = tuple(range(len(args)))
-    got = jax.grad(lambda *a: jnp.sum(fn(*a) * cot), argnums)(*args)
-    want = jax.grad(lambda *a: jnp.sum(ref(*a) * cot), argnums)(*args)
-    _close(got, want, tol)
-
-
-# ---------------------------------------------------------------------------
-def _near(got, want, rel):
-    """Every leaf within ``rel`` of the wanted leaf's largest entry (a
-    bf16 path against float32 numbers)."""
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        assert g.shape == w.shape
-        assert np.abs(g - w).max() <= rel * max(np.abs(w).max(), 1e-6)
+REF = reference("nemotron_twotower_30b_a3b")
+pytestmark = pytest.mark.usefixtures("highest")
 
 
 @pytest.fixture(params=["xla", "pallas"])
@@ -72,11 +37,11 @@ def _experts_on(path, weights, seed, matrices):
     the rounded values in float32, so both route alike)."""
     if path == "xla":
         w, cfg = weights()
-        (x,) = _rand(seed, (2, 20, 12))
-        return w, cfg, x, lambda name, a: a, _close, \
-            lambda got, want: _close(got, want, 5e-5)
+        (x,) = rand(seed, (2, 20, 12))
+        return w, cfg, x, lambda name, a: a, close, \
+            lambda got, want: close(got, want, 5e-5)
     w, cfg = weights(hidden=128, width=128)
-    (x,) = _rand(seed, (2, 20, 128))
+    (x,) = rand(seed, (2, 20, 128))
     x = x.astype(jnp.bfloat16).astype(F32)
     w = {n: a.astype(jnp.bfloat16).astype(F32) if n in matrices else a
          for n, a in w.items()}
@@ -84,8 +49,8 @@ def _experts_on(path, weights, seed, matrices):
     def given(name, a):
         return a.astype(jnp.bfloat16) if name in matrices + ("x",) else a
 
-    return w, cfg, x, given, lambda got, want: _near(got, want, 2e-2), \
-        lambda got, want: _near(got, want, 3e-2)
+    return w, cfg, x, given, lambda got, want: near(got, want, 2e-2), \
+        lambda got, want: near(got, want, 3e-2)
 
 
 def _takes_the_kernels(fn, *args):
@@ -94,14 +59,14 @@ def _takes_the_kernels(fn, *args):
 
 @pytest.mark.parametrize("shape", [(3, 16), (2, 5, 24)])
 def test_rms_norm(shape):
-    x, w = _rand(0, shape, shape[-1:])
+    x, w = rand(0, shape, shape[-1:])
     op = get_op("_contrib_rms_norm").impl
-    _same_values_and_grads(lambda x, w: op(x, w, eps=1e-5),
-                           lambda x, w: REF._rms(x, w, 1e-5), (x, w))
+    same_values_and_grads(lambda x, w: op(x, w, eps=1e-5),
+                          lambda x, w: REF._rms(x, w, 1e-5), (x, w))
 
 
 def test_rms_norm_keeps_the_dtype_and_norms_in_float32():
-    x = (100 * _rand(1, (4, 64))[0]).astype(jnp.bfloat16)
+    x = (100 * rand(1, (4, 64))[0]).astype(jnp.bfloat16)
     y = get_op("_contrib_rms_norm").impl(x, jnp.ones((64,), jnp.bfloat16))
     assert y.dtype == jnp.bfloat16
     np.testing.assert_allclose(
@@ -110,32 +75,32 @@ def test_rms_norm_keeps_the_dtype_and_norms_in_float32():
 
 @pytest.mark.parametrize("group", [8, 16, 32])
 def test_gated_rms_norm(group):
-    y, z, w = _rand(2, (2, 7, 32), (2, 7, 32), (32,))
+    y, z, w = rand(2, (2, 7, 32), (2, 7, 32), (32,))
 
     def ref(y, z, w):
         g = (y * jax.nn.silu(z)).reshape(2, 7, 32 // group, group)
         return REF._rms(g, 1.0, 1e-5).reshape(2, 7, 32) * w
 
     op = get_op("_contrib_gated_rms_norm").impl
-    _same_values_and_grads(
+    same_values_and_grads(
         lambda y, z, w: op(y, z, w, group_size=group, eps=1e-5), ref,
         (y, z, w))
 
 
 @pytest.mark.parametrize("length, k", [(9, 4), (3, 4), (12, 2)])
 def test_causal_conv1d(length, k):
-    x, w, b = _rand(3, (2, length, 6), (6, k), (6,))
-    _same_values_and_grads(get_op("_contrib_causal_conv1d").impl, REF._conv,
-                           (x, w, b))
+    x, w, b = rand(3, (2, length, 6), (6, k), (6,))
+    same_values_and_grads(get_op("_contrib_causal_conv1d").impl, REF._conv,
+                          (x, w, b))
     # causal: an input after t never reaches y[t]
-    y0 = D._causal_conv1d(x, w, b)
-    y1 = D._causal_conv1d(x.at[:, -1].add(5.0), w, b)
-    _close(y0[:, :-1], y1[:, :-1], 0)
+    conv = jitted(D._causal_conv1d)
+    y0, y1 = conv(x, w, b), conv(x.at[:, -1].add(5.0), w, b)
+    close(y0[:, :-1], y1[:, :-1], 0)
 
 
 # ---------------------------------------------------------------------------
 def _ssd_args(seed, length, batch=2, heads=4, p=8, groups=2, n=16):
-    x, dt, a, bm, cm, d = _rand(
+    x, dt, a, bm, cm, d = rand(
         seed, (batch, length, heads, p), (batch, length, heads), (heads,),
         (batch, length, groups, n), (batch, length, groups, n), (heads,))
     return x, jax.nn.softplus(dt - 2.0), -jnp.exp(a), bm, cm, d
@@ -146,21 +111,22 @@ def test_chunked_scan_is_the_step_by_step_recurrence(length):
     """Chunk 8: lengths that are multiples of it, that are not (the
     tail is padded with dt = 0), and shorter than one chunk."""
     op = get_op("_contrib_ssd_scan").impl
-    _same_values_and_grads(lambda *a: op(*a, chunk_size=8), REF.recurrence,
-                           _ssd_args(4, length), tol=5e-5)
+    same_values_and_grads(lambda *a: op(*a, chunk_size=8), REF.recurrence,
+                          _ssd_args(4, length), tol=5e-5)
 
 
 def test_scan_does_not_depend_on_the_chunk():
     args = _ssd_args(5, 24)
-    y4, y8, y24 = (D._ssd(*args, c) for c in (4, 8, 24))
-    _close(y4, y8)
-    _close(y8, y24)
+    y4, y8, y24 = (jax.jit(lambda *a, c=c: D._ssd(*a, c))(*args)
+                   for c in (4, 8, 24))
+    close(y4, y8)
+    close(y8, y24)
 
 
 def test_scan_without_its_skip_term_is_another_function():
     args = _ssd_args(6, 16)
-    y = D._ssd(*args, 8)
-    no_d = D._ssd(*args[:5], jnp.zeros_like(args[5]), 8)
+    scan = jax.jit(lambda *a: D._ssd(*a, 8))
+    y, no_d = scan(*args), scan(*args[:5], jnp.zeros_like(args[5]))
     assert float(jnp.max(jnp.abs(y - no_d))) > 0.1
 
 
@@ -168,34 +134,26 @@ def test_the_reference_recurrence_keeps_states_by_segment():
     """Lengths over SEGMENT that it divides take the nested scan:
     the same numbers."""
     args = _ssd_args(7, 2 * REF.SEGMENT, batch=1, heads=2, p=4, n=4)
-    _close(REF.recurrence(*args), D._ssd(*args, 16), 5e-5)
+    close(jitted(REF.recurrence)(*args),
+          jax.jit(lambda *a: D._ssd(*a, 16))(*args), 5e-5)
 
 
 # ---------------------------------------------------------------------------
-def _attention_ref(q, k, v):
-    heads, kv = q.shape[2], k.shape[2]
-    k, v = (jnp.repeat(t, heads // kv, axis=2) for t in (k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(q.shape[-1]))
-    seen = jnp.tril(jnp.ones(s.shape[-2:], bool))
-    att = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1)
-    return jnp.einsum("bhqk,bkhd->bqhd", att, v)
-
-
 @pytest.mark.parametrize("length, block", [(16, 4), (21, 8), (7, 16), (8, 8)])
 def test_blocked_causal_gqa_attention(length, block):
-    q, k, v = _rand(8, (2, length, 4, 8), (2, length, 2, 8),
-                    (2, length, 2, 8))
-    _same_values_and_grads(lambda *a: D._causal_gqa(*a, block),
-                           _attention_ref, (q, k, v))
+    q, k, v = rand(8, (2, length, 4, 8), (2, length, 2, 8),
+                   (2, length, 2, 8))
+    same_values_and_grads(lambda *a: D._causal_gqa(*a, block),
+                          attention_ref, (q, k, v))
     # the op itself, at its own block size
-    _close(get_op("_contrib_causal_gqa_attention").impl(q, k, v),
-           _attention_ref(q, k, v))
+    close(jitted(get_op("_contrib_causal_gqa_attention").impl)(q, k, v),
+          jitted(attention_ref)(q, k, v))
 
 
 def test_attention_never_builds_a_length_by_length_array():
     """At 64 positions in blocks of 16 the largest score array is
     16 x 64, not 64 x 64."""
-    q, k, v = _rand(9, (1, 64, 2, 4), (1, 64, 1, 4), (1, 64, 1, 4))
+    q, k, v = rand(9, (1, 64, 2, 4), (1, 64, 1, 4), (1, 64, 1, 4))
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(D._causal_gqa(*a, 16)), (0, 1, 2)))(q, k, v)
     def walk(jp):
@@ -216,8 +174,8 @@ CFG = {"num_experts_per_tok": 3, "routed_scaling_factor": 2.5,
 
 
 def _moe_weights(seed, hidden=12, routed=16, held=4, width=10, offset=4):
-    r, b, up, down = _rand(seed, (routed, hidden), (routed,),
-                           (held, width, hidden), (held, hidden, width))
+    r, b, up, down = rand(seed, (routed, hidden), (routed,),
+                          (held, width, hidden), (held, hidden, width))
     return {"router_weight": r, "e_score_correction_bias": 0.1 * b,
             "experts_up_weight": up, "experts_down_weight": down}, \
         dict(CFG, expert_offset=offset)
@@ -225,7 +183,12 @@ def _moe_weights(seed, hidden=12, routed=16, held=4, width=10, offset=4):
 
 def _moe(x, w, cfg, capacity_factor=None):
     """The op; with a ``capacity_factor``, what the op runs with another
-    buffer than its own (``D.CAPACITY_FACTOR``)."""
+    buffer than its own (``D.CAPACITY_FACTOR``). Compiled, as every
+    caller of the op runs it."""
+    return jax.jit(lambda x, w: _moe_traced(x, w, cfg, capacity_factor))(x, w)
+
+
+def _moe_traced(x, w, cfg, capacity_factor):
     if capacity_factor is None:
         return get_op("_contrib_moe_experts").impl(
             x, w["router_weight"], w["e_score_correction_bias"],
@@ -244,6 +207,11 @@ def _moe(x, w, cfg, capacity_factor=None):
     return y.reshape(x.shape), rows
 
 
+def _ref_experts(ref, w, x, cfg, **kw):
+    """A reference's loop over the held experts, compiled."""
+    return jax.jit(lambda w, x: ref.experts(w, "", x, cfg, **kw))(w, x)
+
+
 @pytest.mark.parametrize("capacity_factor", [0.25, None, 100.0])
 def test_routed_experts(capacity_factor, path):
     """Buffers too small for the routing (the dense path), the
@@ -253,25 +221,28 @@ def test_routed_experts(capacity_factor, path):
     w, cfg, x, given, value_close, grad_close = _experts_on(
         path, lambda **kw: _moe_weights(10, **kw), 11,
         ("experts_up_weight", "experts_down_weight"))
+    # the score bias decides the choice and takes no gradient
+    bias = {"e_score_correction_bias": w.pop("e_score_correction_bias")}
     names = sorted(w)
 
     def fn(x, *ws):
         ws = {n: given(n, a) for n, a in zip(names, ws)}
-        return _moe(given("x", x), ws, cfg, capacity_factor)[0].astype(F32)
+        return _moe_traced(given("x", x), dict(ws, **bias), cfg,
+                           capacity_factor)[0].astype(F32)
 
     def ref(x, *ws):
-        return REF.experts(dict(zip(names, ws)), "", x, cfg, shared=False)
+        return REF.experts(dict(zip(names, ws), **bias), "", x, cfg,
+                           shared=False)
 
     args = (x,) + tuple(w[n] for n in names)
     # (a quarter of the buffer is blocks of 8 rows: not a bf16 tile)
     assert _takes_the_kernels(fn, *args) == (
         path == "pallas" and capacity_factor != 0.25)
-    value_close(fn(*args), ref(*args))
-    cot = _rand(12, x.shape)[0]
-    nums = (0,) + tuple(1 + i for i, n in enumerate(names)
-                        if n != "e_score_correction_bias")
-    grad_close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
-               jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args))
+    (cot,) = rand(12, x.shape)
+    got = value_and_grads(fn, *args, cot=cot)
+    want = value_and_grads(ref, *args, cot=cot)
+    value_close(got[0], want[0])
+    grad_close(got[1:], want[1:])
 
 
 @pytest.mark.parametrize("capacity_factor", [0.25, None])
@@ -287,9 +258,9 @@ def test_routing_at_its_extremes_drops_nothing(favoured, rows,
     w, cfg = _moe_weights(13)
     w["e_score_correction_bias"] = jnp.zeros((16,)).at[jnp.array(favoured)] \
         .set(10.0)
-    (x,) = _rand(14, (40, 12))
+    (x,) = rand(14, (40, 12))
     y, counts = _moe(x, w, cfg, capacity_factor)
-    _close(y, REF.experts(w, "", x, cfg, shared=False))
+    close(y, _ref_experts(REF, w, x, cfg, shared=False))
     np.testing.assert_array_equal(np.asarray(counts[0]), rows)
     np.testing.assert_array_equal(np.asarray(counts[1]), rows)
     if not any(rows):
@@ -300,9 +271,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
     """16 experts in shares of 4: the four shares' routed parts plus
     the shared expert once are the layer with all 16 held."""
     w, cfg = _moe_weights(15, held=16, offset=0)
-    shared_up, shared_down, x = _rand(16, (14, 12), (12, 14), (30, 12))
+    shared_up, shared_down, x = rand(16, (14, 12), (12, 14), (30, 12))
     whole = dict(w, shared_up_weight=shared_up, shared_down_weight=shared_down)
-    want = REF.experts(whole, "", x, cfg)
+    want = _ref_experts(REF, whole, x, cfg)
     got = REF._relu2_mlp(x, shared_up, shared_down)
     counts = []
     for offset in (0, 4, 8, 12):
@@ -310,33 +281,29 @@ def test_the_shares_add_up_to_the_uncut_layer():
                      [offset:offset + 4], experts_down_weight=w[
                          "experts_down_weight"][offset:offset + 4])
         part, rows = _moe(x, share, dict(cfg, expert_offset=offset))
-        _close(part, REF.experts(share, "", x, dict(cfg, expert_offset=offset),
+        close(part, _ref_experts(REF, share, x,
+                                 dict(cfg, expert_offset=offset),
                                  shared=False))
         got = got + part
         counts.append(np.asarray(rows[0]))
-    _close(got, want)
+    close(got, want)
     assert int(np.sum(counts)) == 30 * 3        # every choice held once
 
 
 def test_a_wrong_scaling_factor_is_seen():
     w, cfg = _moe_weights(17)
-    (x,) = _rand(18, (20, 12))
+    (x,) = rand(18, (20, 12))
     y = _moe(x, w, cfg)[0]
     off = _moe(x, w, dict(cfg, routed_scaling_factor=1.0))[0]
-    _close(y, 2.5 * off)
+    close(y, 2.5 * off)
     assert float(jnp.max(jnp.abs(y - off))) > 1e-2
 
 
 # ---------------------------------------------------------------------------
-def _remat_count(fn, *args):
-    text = str(jax.make_jaxpr(fn)(*args))
-    return text.count("checkpoint") + text.count("remat")
-
-
 def test_the_mamba2_mixer_recomputes_its_inside():
     hidden, heads, p, groups, n, k = 16, 4, 4, 2, 8, 4
     inner, conv = heads * p, heads * p + 2 * groups * n
-    u, nw, inw, cw, cb, dtb, al, d, gw, ow = _rand(
+    u, nw, inw, cw, cb, dtb, al, d, gw, ow = rand(
         19, (2, 12, hidden), (hidden,), (inner + conv + heads, hidden),
         (conv, k), (conv,), (heads,), (heads,), (heads,), (inner,),
         (hidden, inner), scale=0.3)
@@ -346,27 +313,29 @@ def test_the_mamba2_mixer_recomputes_its_inside():
     op = get_op("_contrib_mamba2_mixer").impl
     plain = lambda *a: D._mamba2(*a, heads=heads, head_dim=p, groups=groups,
                                  state=n, chunk=4, eps=1e-5)
-    _same_values_and_grads(lambda *a: op(*a, **attrs), plain, args, tol=5e-5)
+    same_values_and_grads(lambda *a: op(*a, **attrs), plain, args, tol=5e-5)
     w = {"in_proj_weight": inw, "conv_weight": cw, "conv_bias": cb,
          "dt_bias": dtb, "a_log": al, "d": d, "gate_norm_weight": gw,
          "out_proj_weight": ow}
     cfg = {"mamba_num_heads": heads, "mamba_head_dim": p, "n_groups": groups,
            "ssm_state_size": n, "layer_norm_epsilon": 1e-5}
-    _close(op(*args, **attrs),
-           REF.mamba2(w, "", REF._rms(u, nw, 1e-5), cfg), 5e-5)
+    close(jax.jit(lambda *a: op(*a, **attrs))(*args),
+          jax.jit(lambda u, nw, w: REF.mamba2(w, "", REF._rms(u, nw, 1e-5),
+                                              cfg))(u, nw, w), 5e-5)
     grad = jax.grad(lambda *a: jnp.sum(op(*a, **attrs)))
-    assert _remat_count(grad, *args) > 0
-    assert _remat_count(jax.grad(lambda *a: jnp.sum(plain(*a))), *args) == 0
+    assert remat_count(grad, *args) > 0
+    assert remat_count(jax.grad(lambda *a: jnp.sum(plain(*a))), *args) == 0
 
 
 def test_mixers_take_bfloat16_and_stay_near_float32():
     """What ShardedTrainStep feeds them: bf16 in, bf16 out, float32
     inside where it matters."""
     args = _ssd_args(20, 32)
-    want = D._ssd(*args, 8)
+    scan = jax.jit(lambda *a: D._ssd(*a, 8))
+    want = scan(*args)
     low = [a.astype(jnp.bfloat16) if a.ndim > 1 and i != 1 else a
            for i, a in enumerate(args)]
-    got = D._ssd(*low, 8)
+    got = scan(*low)
     assert got.dtype == jnp.bfloat16
     err = jnp.linalg.norm(got.astype(F32) - want) / jnp.linalg.norm(want)
     assert float(err) < 2e-2
@@ -377,25 +346,25 @@ def test_mixers_take_bfloat16_and_stay_near_float32():
 # keys it keeps, softmax / SwiGLU experts: against the plain reference
 # of the benchmark's Keye-VL configuration
 # ---------------------------------------------------------------------------
-KREF = manifest.load_module("reference", "keye_vl2_30b_a3b.py")
+KREF = reference("keye_vl2_30b_a3b")
 KTOY = manifest.load_json("configs", "keye_vl2_30b_a3b.json")["toy"]
 
 
 def test_rotary_with_three_distinct_position_axes():
-    (x,) = _rand(30, (2, 9, 3, 16))
+    (x,) = rand(30, (2, 9, 3, 16))
     pos = jnp.asarray(np.random.default_rng(0).integers(0, 5000, (3, 2, 9)),
                       jnp.int32)
     assert not np.array_equal(pos[0], pos[1])
     op = get_op("_contrib_rotary").impl
-    _same_values_and_grads(
+    same_values_and_grads(
         lambda x: op(x, pos, theta=1e7, sections=(2, 3, 3)),
         lambda x: KREF.rope(x, pos, 1e7, [2, 3, 3]), (x,))
     # one axis, and text: no positions given is every axis the index
-    _close(op(x, pos[1], theta=1e4), KREF.rope(x, pos[1], 1e4))
+    close(op(x, pos[1], theta=1e4), KREF.rope(x, pos[1], 1e4))
     index = jnp.broadcast_to(jnp.arange(9), (3, 2, 9))
-    _close(op(x, theta=1e7, sections=(2, 3, 3)),
-           KREF.rope(x, index, 1e7, [2, 3, 3]))
-    _close(op(x, theta=1e7), KREF.rope(x, index[0], 1e7))
+    close(op(x, theta=1e7, sections=(2, 3, 3)),
+          KREF.rope(x, index, 1e7, [2, 3, 3]))
+    close(op(x, theta=1e7), KREF.rope(x, index[0], 1e7))
     # a section that reads another axis changes the result
     swapped = op(x, pos[jnp.array([0, 2, 1])], theta=1e7, sections=(2, 3, 3))
     assert float(jnp.max(jnp.abs(
@@ -409,16 +378,20 @@ def _program_set(scores, first, k):
     n = scores.shape[-1]
     seen = jnp.arange(n)[None, :] <= (first + jnp.arange(
         scores.shape[-2]))[:, None]
-    return jax.vmap(lambda s: D._selected(
-        s, seen, *D._thresholds(s, seen, k)))(scores)
+    return jax.jit(jax.vmap(lambda s: D._selected(
+        s, seen, *D._thresholds(s, seen, k))))(scores)
+
+
+def _reference_set(scores, first, k):
+    return jax.jit(lambda s: KREF.selected(s, first, k))(scores)
 
 
 @pytest.mark.parametrize("first, k", [(0, 8), (24, 8), (24, 40), (5, 1)])
 def test_the_selected_set_is_top_ks_row_for_row(first, k):
-    iq, ik, iw = _rand(31, (2, 16, 4, 8), (2, first + 16, 8), (2, 16, 4))
-    scores = D._index_scores(iq, ik, iw)
-    _close(scores, KREF.index_scores(iq, ik, iw), 1e-5)
-    want = KREF.selected(scores, first, k)
+    iq, ik, iw = rand(31, (2, 16, 4, 8), (2, first + 16, 8), (2, 16, 4))
+    scores = jitted(D._index_scores)(iq, ik, iw)
+    close(scores, jitted(KREF.index_scores)(iq, ik, iw), 1e-5)
+    want = _reference_set(scores, first, k)
     got = _program_set(scores, first, k)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     rows = np.asarray(got).sum(-1)
@@ -443,7 +416,7 @@ def test_ties_go_to_the_lower_index():
     for first in (24, 0):
         # zeros of either sign are one value, as the reference's own
         # index_scores hands them to lax.top_k
-        want = KREF.selected(jnp.where(scores == 0, 0.0, scores), first, 6)
+        want = _reference_set(jnp.where(scores == 0, 0.0, scores), first, 6)
         got = _program_set(scores, first, 6)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     tied = np.flatnonzero(np.asarray(scores[0, 2]) == kth)
@@ -483,7 +456,7 @@ def _attn_weights(seed, cfg):
              "index_w_weight", "index_k_norm_weight", "index_k_norm_bias"]
     shapes = [(h * d, u), (kv * d, u), (kv * d, u), (u, h * d), (d,), (d,),
               (ih * idim, u), (idim, u), (ih, u), (idim,), (idim,)]
-    w = dict(zip(names, _rand(seed, *shapes, scale=0.3)))
+    w = dict(zip(names, rand(seed, *shapes, scale=0.3)))
     for n in ("q_norm_weight", "k_norm_weight", "index_k_norm_weight"):
         w[n] = 1.0 + w[n]
     return names, w
@@ -505,15 +478,16 @@ def _mixer(x, norm_w, w, names, cfg, positions=None):
 @pytest.mark.parametrize("length, block", [(37, 16), (32, 512)])
 def test_sparse_attention_mixer_against_the_reference(monkeypatch, length,
                                                       block):
-    """Forward, both outputs and the state; ``jax.grad`` of each output
+    """Forward, both outputs and the state; the gradient of both outputs
     to every input; several query blocks and one, a length that is not
-    whole blocks; three distinct position axes."""
+    whole blocks; three distinct position axes. (Traced here, under the
+    patched block size.)"""
     monkeypatch.setattr(D, "QUERY_BLOCK", block)
     monkeypatch.setattr(KREF, "QUERY_BLOCK", block)
     cfg = _attn_cfg()
     names, w = _attn_weights(32, cfg)
-    x, norm_w = _rand(33, (2, length, cfg["hidden_size"]),
-                      (cfg["hidden_size"],))
+    x, norm_w = rand(33, (2, length, cfg["hidden_size"]),
+                     (cfg["hidden_size"],))
     norm_w = 1.0 + 0.1 * norm_w
     pos = jnp.asarray(np.random.default_rng(1).integers(
         0, 900, (3, 2, length)), jnp.int32)
@@ -527,25 +501,19 @@ def test_sparse_attention_mixer_against_the_reference(monkeypatch, length,
                               cfg)
 
     args = (x, norm_w) + tuple(w[n] for n in names)
-    y, loss, state = fn(*args)
-    want_y, want_loss = ref(*args)
-    _close(y, want_y, 1e-4)
+    # one pullback of both outputs together (a cotangent on the mixer's
+    # output, the index loss weighted 3) to every input
+    (cot,) = rand(34, x.shape)
+    y, loss, state, *got = value_and_grads(fn, *args, cot=(cot, 3.0, 0.0))
+    want_y, want_loss, *want = value_and_grads(ref, *args, cot=(cot, 3.0))
+    close(y, want_y, 1e-4)
     assert loss.shape == (1,) and float(loss[0]) > 0
     assert float(loss[0]) == pytest.approx(float(want_loss), rel=1e-4)
     k = cfg["sa_config"]["topk"]
     assert float(state[0]) == pytest.approx(
         sum(min(t + 1, k) for t in range(length)) / length)
     assert float(state[1]) == pytest.approx(float(loss[0]))
-    # one gradient of both outputs together (a cotangent on the mixer's
-    # output, the index loss weighted 3) to every input
-    argnums = tuple(range(len(args)))
-    (cot,) = _rand(34, y.shape)
-
-    def both(out):
-        return jnp.sum(out[0] * cot) + 3.0 * jnp.sum(out[1])
-
-    _close(jax.grad(lambda *a: both(fn(*a)), argnums)(*args),
-           jax.grad(lambda *a: both(ref(*a)), argnums)(*args), 2e-4)
+    close(got, want, 2e-4)
 
 
 def test_each_loss_trains_its_own_parameters():
@@ -553,16 +521,16 @@ def test_each_loss_trains_its_own_parameters():
     else; the language model's side reaches everything but them."""
     cfg = _attn_cfg()
     names, w = _attn_weights(35, cfg)
-    x, norm_w = _rand(36, (1, 12, cfg["hidden_size"]), (cfg["hidden_size"],))
+    x, norm_w = rand(36, (1, 12, cfg["hidden_size"]), (cfg["hidden_size"],))
     args = (x, 1.0 + 0.1 * norm_w) + tuple(w[n] for n in names)
     argnums = tuple(range(len(args)))
 
     def fn(*a):
         return _mixer(a[0], a[1], dict(zip(names, a[2:])), names, cfg)
 
-    by_index = jax.grad(lambda *a: fn(*a)[1].sum(), argnums)(*args)
-    by_lm = jax.grad(lambda *a: jnp.sum(jnp.square(fn(*a)[0])),
-                     argnums)(*args)
+    by_index = jax.jit(jax.grad(lambda *a: fn(*a)[1].sum(), argnums))(*args)
+    by_lm = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.square(fn(*a)[0])),
+                             argnums))(*args)
     for name, gi, gl in zip(("data", "norm") + tuple(names), by_index, by_lm):
         moved_i = float(jnp.max(jnp.abs(gi))) > 0
         moved_l = float(jnp.max(jnp.abs(gl))) > 0
@@ -576,14 +544,14 @@ def test_the_sparse_mixer_keeps_thresholds_and_context_only(capsys):
     projection."""
     cfg = _attn_cfg()
     names, w = _attn_weights(37, cfg)
-    x, norm_w = _rand(38, (1, 24, cfg["hidden_size"]), (cfg["hidden_size"],))
+    x, norm_w = rand(38, (1, 24, cfg["hidden_size"]), (cfg["hidden_size"],))
     args = (x, norm_w) + tuple(w[n] for n in names)
 
     def fn(*a):
         return jnp.sum(_mixer(a[0], a[1], dict(zip(names, a[2:])), names,
                               cfg)[0])
 
-    assert _remat_count(jax.grad(fn), *args) > 0
+    assert remat_count(jax.grad(fn), *args) > 0
     jax.ad_checkpoint.print_saved_residuals(fn, *args)
     kept = [line.split(" ")[0] for line in capsys.readouterr().out
             .splitlines() if "from the argument" not in line
@@ -594,17 +562,12 @@ def test_the_sparse_mixer_keeps_thresholds_and_context_only(capsys):
 
 
 # ---------------------------------------------------------------------------
-KCFG = {"num_experts_per_tok": 3, "norm_topk_prob": True}
-
-
-def _swiglu_weights(seed, hidden=12, routed=16, held=4, width=10, offset=4):
-    r, gate_up, down = _rand(seed, (routed, hidden),
-                             (held, 2 * width, hidden), (held, hidden, width))
-    return {"router_weight": r, "experts_gate_up_weight": gate_up,
-            "experts_down_weight": down}, dict(KCFG, expert_offset=offset)
-
-
 def _swiglu_moe(x, w, cfg, capacity_factor=None):
+    return jax.jit(lambda x, w: _swiglu_moe_traced(
+        x, w, cfg, capacity_factor))(x, w)
+
+
+def _swiglu_moe_traced(x, w, cfg, capacity_factor):
     kwargs = {} if capacity_factor is None \
         else {"capacity_factor": capacity_factor}
     y, rows = D._moe_experts(
@@ -623,14 +586,14 @@ def test_softmax_swiglu_experts(capacity_factor, path):
     and gradients of the reference's loop over the held experts, by the
     composition and by the grouped kernels."""
     w, cfg, x, given, value_close, grad_close = _experts_on(
-        path, lambda **kw: _swiglu_weights(40, **kw), 41,
+        path, lambda **kw: swiglu_experts(40, **kw), 41,
         ("experts_gate_up_weight", "experts_down_weight"))
     names = sorted(w)
 
     def fn(x, *ws):
         ws = {n: given(n, a) for n, a in zip(names, ws)}
-        return _swiglu_moe(given("x", x), ws, cfg,
-                           capacity_factor)[0].astype(F32)
+        return _swiglu_moe_traced(given("x", x), ws, cfg,
+                                  capacity_factor)[0].astype(F32)
 
     def ref(x, *ws):
         return KREF.experts(dict(zip(names, ws)), "", x, cfg)
@@ -638,41 +601,43 @@ def test_softmax_swiglu_experts(capacity_factor, path):
     args = (x,) + tuple(w[n] for n in names)
     assert _takes_the_kernels(fn, *args) == (
         path == "pallas" and capacity_factor != 0.25)
-    value_close(fn(*args), ref(*args))
-    (cot,) = _rand(42, x.shape)
-    nums = tuple(range(len(args)))
-    grad_close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
-               jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args))
+    (cot,) = rand(42, x.shape)
+    got = value_and_grads(fn, *args, cot=cot)
+    want = value_and_grads(ref, *args, cot=cot)
+    value_close(got[0], want[0])
+    grad_close(got[1:], want[1:])
     # through the registered op too, the bias left out
-    y, rows = get_op("_contrib_moe_experts").impl(
-        given("x", x), w["router_weight"], None, jnp.zeros((2, 4), F32),
+    op = get_op("_contrib_moe_experts").impl
+    y, rows = jax.jit(lambda x, r, gate_up, down: op(
+        x, r, None, jnp.zeros((2, 4), F32), gate_up, down, top_k=3,
+        expert_offset=4, score_func="softmax", activation="swiglu"))(
+        given("x", x), w["router_weight"],
         given("experts_gate_up_weight", w["experts_gate_up_weight"]),
-        given("experts_down_weight", w["experts_down_weight"]), top_k=3,
-        expert_offset=4, score_func="softmax", activation="swiglu")
-    value_close(y, ref(*args))
+        given("experts_down_weight", w["experts_down_weight"]))
+    value_close(y, want[0])
     np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
 
 
 def test_the_moe_mixer_takes_its_optional_inputs_last():
     """Without a score bias and a shared expert, and with both: the
     same op, the routed part unchanged."""
-    w, cfg = _swiglu_weights(43)
-    x, norm_w, bias, shared_up, shared_down = _rand(
+    w, cfg = swiglu_experts(43)
+    x, norm_w, bias, shared_up, shared_down = rand(
         44, (2, 10, 12), (12,), (16,), (2 * 6, 12), (12, 6))
     op = get_op("_contrib_moe_mixer").impl
     attrs = dict(top_k=3, expert_offset=4, score_func="softmax",
                  activation="swiglu", eps=1e-6)
+    mixer = jax.jit(lambda *a: op(*a, **attrs))
     rows = jnp.zeros((2, 4), F32)
-    bare, _ = op(x, norm_w, w["router_weight"], rows,
-                 w["experts_gate_up_weight"], w["experts_down_weight"],
-                 **attrs)
+    bare, _ = mixer(x, norm_w, w["router_weight"], rows,
+                    w["experts_gate_up_weight"], w["experts_down_weight"])
     h = KREF._rms(x, norm_w, 1e-6)
-    _close(bare, KREF.experts(w, "", h, cfg))
-    full, _ = op(x, norm_w, w["router_weight"], rows,
-                 w["experts_gate_up_weight"], w["experts_down_weight"],
-                 0.0 * bias, shared_up, shared_down, **attrs)
-    _close(full, bare + KREF.swiglu(h, shared_up[:6], shared_up[6:],
-                                    shared_down))
+    close(bare, _ref_experts(KREF, w, h, cfg))
+    full, _ = mixer(x, norm_w, w["router_weight"], rows,
+                    w["experts_gate_up_weight"], w["experts_down_weight"],
+                    0.0 * bias, shared_up, shared_down)
+    close(full, bare + KREF.swiglu(h, shared_up[:6], shared_up[6:],
+                                   shared_down))
     with pytest.raises(KeyError):
         op(x, norm_w, w["router_weight"], rows, w["experts_gate_up_weight"],
            w["experts_down_weight"], **dict(attrs, activation="gelu"))
@@ -682,18 +647,18 @@ def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
     """16 experts in 8 shares of 2 (the cell: 128 in 8 shares of 16):
     the shares' parts, with no shared expert to count once, are the
     layer with all 16 held."""
-    w, cfg = _swiglu_weights(45, held=16, offset=0)
-    (x,) = _rand(46, (30, 12))
-    want = KREF.experts(w, "", x, cfg)
+    w, cfg = swiglu_experts(45, held=16, offset=0)
+    (x,) = rand(46, (30, 12))
+    want = _ref_experts(KREF, w, x, cfg)
     got, counts = 0.0, []
     for offset in range(0, 16, 2):
         share = dict(w, experts_gate_up_weight=w["experts_gate_up_weight"]
                      [offset:offset + 2], experts_down_weight=w[
                          "experts_down_weight"][offset:offset + 2])
         part, rows = _swiglu_moe(x, share, dict(cfg, expert_offset=offset))
-        _close(part, KREF.experts(share, "", x,
-                                  dict(cfg, expert_offset=offset)))
+        close(part, _ref_experts(KREF, share, x,
+                                 dict(cfg, expert_offset=offset)))
         got = got + part
         counts.append(np.asarray(rows[0]))
-    _close(got, want)
+    close(got, want)
     assert int(np.sum(counts)) == 30 * 3        # every choice held once

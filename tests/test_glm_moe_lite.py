@@ -15,18 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
-from mxbench import manifest
+from decoder_harness import OPT, Toy, ids as _ids
 from mxnet_tpu import autograd, nd, telemetry
 from mxnet_tpu.gluon.model_zoo import glm_moe_lite as zoo
 from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_causal_gqa
-from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
-from test_pallas_causal_gqa import _close, _qkv, _value_and_grads
+from numerics import BF, F32, jitted, near, normal, qkv, value_and_grads
 
-REF = manifest.load_module("reference", "glm_4_7_flash_30b_a3b.py")
-CFGMOD = manifest.load_module("configs", "glm_4_7_flash_30b_a3b.py")
-
-F32, BF = jnp.float32, jnp.bfloat16
 CFG = dict(
     hidden_size=48, num_attention_heads=3, num_key_value_heads=3,
     q_lora_rank=20, kv_lora_rank=12, qk_nope_head_dim=10, qk_rope_head_dim=6,
@@ -41,41 +35,29 @@ ATTN = dict(num_heads=3, qk_nope_head_dim=10, qk_rope_head_dim=6,
             v_head_dim=16, rope_theta=1e6, eps=1e-5)
 
 
-def _build(cfg=CFG, seed=3):
-    """The toy model from the seeded init, its queries and rotary keys
-    made 12 times larger: at 48 hidden lanes N(0, 0.02) weights give
-    scores of 1e-3 and attention that looks at nothing, so that no
-    position could matter (at the published widths the same init gives
-    scores of 0.3)."""
-    mx.random.seed(seed)
-    net = zoo.Glm4MoeLiteModel(cfg, prefix="")
-    head = zoo.Glm4MoeLiteLMLoss(cfg, prefix="")
-    net.initialize()
-    head.initialize()
+def _sharper_scores(net):
+    """The seeded queries and rotary keys made 12 times larger: at 48
+    hidden lanes N(0, 0.02) weights give scores of 1e-3 and attention
+    that looks at nothing, so that no position could matter (at the
+    published widths the same init gives scores of 0.3)."""
     for name, p in net.collect_params().items():
         if name.endswith(("q_b_weight", "kv_a_weight")):
             p.set_data(p.data() * 12)
-    return net, head
 
 
-def _weights(net, head):
-    return {k: jnp.asarray(v) for k, v in CFGMOD.named_weights(
-        net, CFGMOD._HeadLoss(head)).items()}
+TOY = Toy("glm_4_7_flash_30b_a3b", zoo.Glm4MoeLiteModel,
+          zoo.Glm4MoeLiteLMLoss, CFG, prepare=_sharper_scores)
+REF, CFGMOD = TOY.ref, TOY.cfgmod
+_build, _batch, _step, _sizes = TOY.build, TOY.batch, TOY.step, TOY.sizes
+
+
+def _device_weights(net, head):
+    return {k: jnp.asarray(v) for k, v in TOY.weights(net, head).items()}
 
 
 def _trained(w):
     return {k: v for k, v in w.items()
             if not k.endswith(REF.STATES + REF.FROZEN)}
-
-
-def _batch(seed=0, shape=(2, 21)):
-    rng = np.random.default_rng(seed)
-    return (rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32),
-            rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32))
-
-
-def _ids(a):
-    return nd.array(a, dtype="int32")
 
 
 # ---------------------------------------------------------------------------
@@ -122,18 +104,14 @@ def test_mla_mixer_matches_the_reference_in_value_and_gradient():
     """float32 on both sides, the same sums in another order: 1e-4 of
     the largest entry."""
     w = _mla_weights()
-    x = jax.random.normal(jax.random.key(0), (2, 37, 48), F32)
-    cot = jax.random.normal(jax.random.key(1), (2, 37, 48), F32)
+    x = normal(jax.random.key(0), (2, 37, 48))
+    cot = normal(jax.random.key(1), (2, 37, 48))
     with jax.default_matmul_precision("highest"):
-        got, pull = jax.vjp(_mla_op, x, w)
-        want, pull_ref = jax.vjp(_mla_ref, x, w)
-        (gx, gw), (wx, ww) = pull(cot), pull_ref(cot)
-    np.testing.assert_allclose(got, want, rtol=0,
-                               atol=1e-4 * float(jnp.abs(want).max()))
-    for name, g, r in [("x", gx, wx)] + [(k, gw[k], ww[k]) for k in w]:
-        assert float(jnp.abs(r).max()) > 0, name
-        np.testing.assert_allclose(g, r, rtol=0, err_msg=name,
-                                   atol=1e-4 * float(jnp.abs(r).max()))
+        got = value_and_grads(_mla_op, x, w, cot=cot)
+        want = value_and_grads(_mla_ref, x, w, cot=cot)
+    assert len(want) == 2 + len(w)      # the value, d x, d every weight
+    assert all(float(jnp.abs(r).max()) > 0 for r in want)
+    near(got, want, 1e-4)
 
 
 def test_mla_mixer_refuses_heads_of_two_widths():
@@ -150,7 +128,7 @@ def test_hidden_states_and_both_loss_terms_match_the_reference():
     with autograd.pause():
         hidden, mtp_hidden = net(_ids(ids))
         loss = head(hidden, mtp_hidden, _ids(labels)).asnumpy().item()
-    w = _weights(net, head)
+    w = _device_weights(net, head)
     with jax.default_matmul_precision("highest"):
         (want, want_mtp), (lm, mtp) = jax.jit(lambda w: (
             REF.forward(w, ids, CFG), REF.loss_terms(w, ids, labels, CFG)))(w)
@@ -180,7 +158,7 @@ def test_gradients_of_every_parameter_match_the_reference():
     net, head = _build()
     ids, labels = _batch(1)
     got = _program_grads(net, head, ids, labels)
-    w = _weights(net, head)
+    w = _device_weights(net, head)
     fixed = {k: v for k, v in w.items() if k.endswith(REF.FROZEN)}
     with jax.default_matmul_precision("highest"):
         want = jax.jit(jax.grad(lambda t: REF.lm_loss(
@@ -233,14 +211,6 @@ def test_a_configuration_that_cannot_be_built_is_refused(change):
         net(_ids(_batch()[0]))
 
 
-def _step(net, head, dtype=None, **hp):
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    hp = dict(dict(lr=1e-3, wd=1e-4, beta2=0.95), **hp)
-    return ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
-                            optimizer="adamw", dtype=dtype, n_data_inputs=2,
-                            data_specs=[P(), P()], **hp)
-
-
 def test_states_ride_as_auxiliary_states_and_paths_are_counted():
     telemetry.reset()
     was = telemetry.enabled()
@@ -280,33 +250,30 @@ def test_states_ride_as_auxiliary_states_and_paths_are_counted():
 
 def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():
     net, head = _build()
-    w = _weights(net, head)
+    w = _device_weights(net, head)
     step = _step(net, head, dtype="bfloat16")
     ids, labels = _batch(2)
     got = float(step.step(_ids(ids), _ids(labels)))
     with jax.default_matmul_precision("highest"):
-        want = float(REF.lm_loss(w, ids, labels, CFG))
+        want = float(jax.jit(lambda w: REF.lm_loss(w, ids, labels, CFG))(w))
     assert got == pytest.approx(want, rel=5e-3)
 
 
-OPT = dict(name="adamw", lr=3e-3, wd=3e-5, beta1=0.9, beta2=0.95,
-           epsilon=1e-8)
+@pytest.fixture(scope="module")
+def right():
+    """The seeded weights, a batch, and the reference's losses on it
+    before any update and after one and two."""
+    w = TOY.weights(*_build())
+    batch = _batch(4)
+    return w, batch, REF.train_losses(w, batch, _sizes(), OPT, 3)
 
 
-def _sizes(**change):
-    cfg = dict(CFG, **change)
-    return dict(cfg, deployment={"expert_offset": cfg["expert_offset"]})
-
-
-def test_adamw_steps_match_the_reference():
+def test_adamw_steps_match_the_reference(right):
     """Three losses, the last after two updates: float32 on both sides,
     2e-5 as the other decoders' steps agree."""
-    net, head = _build()
-    w = CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
-    step = _step(net, head, **{k: v for k, v in OPT.items() if k != "name"})
-    ids, labels = _batch(4)
+    _, (ids, labels), want = right
+    step = TOY.reference_step(*_build())
     got = [float(step.step(_ids(ids), _ids(labels))) for _ in range(3)]
-    want = REF.train_losses(w, (ids, labels), _sizes(), OPT, 3)
     np.testing.assert_allclose(got, want, rtol=2e-5)
     assert got[2] < got[1] < got[0]
 
@@ -319,19 +286,20 @@ def test_the_shared_rotary_key_matters():
     ``Rot`` taken out (no lane turns), and the reference with a rotary
     key a head (heads past the first read keys of their own), are
     hundreds of times further from it."""
-    x = jax.random.normal(jax.random.key(2), (2, 37, 48), F32)
+    x = normal(jax.random.key(2), (2, 37, 48))
     w = _mla_weights()
     with jax.default_matmul_precision("highest"):
-        got = _mla_op(x, w)
-        right = _mla_ref(x, w)
-        no_rot = _mla_ref(x, w, dict(CFG, partial_rotary_factor=0))
-        per_head = _mla_ref(x, _mla_weights(rope_keys=3))
+        got = jitted(_mla_op)(x, w)
+        right = jitted(_mla_ref)(x, w)
+        no_rot = jax.jit(lambda x, w: _mla_ref(
+            x, w, dict(CFG, partial_rotary_factor=0)))(x, w)
+        per_head = jitted(_mla_ref)(x, _mla_weights(rope_keys=3))
         # the per-head reference is the shared one when every head's
         # key rows are the first head's: the fault is the keys, not the
         # code path
         tiled = dict(w, kv_a_weight=jnp.concatenate(
             [w["kv_a_weight"]] + [w["kv_a_weight"][-6:]] * 2))
-        same = _mla_ref(x, tiled)
+        same = jitted(_mla_ref)(x, tiled)
     scale = float(jnp.abs(right).max())
     assert float(jnp.abs(got - right).max()) < 1e-4 * scale
     assert float(jnp.abs(same - right).max()) < 1e-5 * scale
@@ -348,14 +316,6 @@ WRONG_MODELS = {
     "no_mtp_term": _sizes(mtp_loss_weight=0.0),
     "mtp_weight_0_3": _sizes(mtp_loss_weight=0.3),
 }
-
-
-@pytest.fixture(scope="module")
-def right():
-    net, head = _build()
-    w = CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
-    batch = _batch(4)
-    return w, batch, REF.train_losses(w, batch, _sizes(), OPT, 2)
 
 
 @pytest.mark.parametrize("fault", sorted(WRONG_MODELS))
@@ -401,9 +361,9 @@ def test_the_modules_targets_are_the_tokens_two_ahead():
     net, head = _build()
     ids, labels = _batch(6)
     lm, mtp = _terms(net, head, ids, labels)
-    w = _weights(net, head)
+    w = _device_weights(net, head)
     with jax.default_matmul_precision("highest"):
-        hidden, mtp_hidden = REF.forward(w, ids, CFG)
+        hidden, mtp_hidden = jax.jit(lambda w: REF.forward(w, ids, CFG))(w)
         logp = jax.nn.log_softmax(mtp_hidden @ w["head_weight"].T, -1)
         by_hand = -jnp.take_along_axis(
             logp, jnp.asarray(labels)[:, 1:, None], -1).mean()
@@ -452,7 +412,7 @@ def test_embedding_and_head_gradients_are_the_sums_of_both_uses():
     net, head = _build()
     ids, labels = _batch(8)
     got = _program_grads(net, head, ids, labels)
-    w = _weights(net, head)
+    w = _device_weights(net, head)
     fixed = {k: v for k, v in w.items() if k.endswith(REF.FROZEN)}
     with jax.default_matmul_precision("highest"):
         lm, mtp = (jax.jit(jax.grad(lambda t, i=i: REF.loss_terms(
@@ -496,20 +456,21 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     w = {k: jnp.asarray(v, F32) for k, v in w.items()}
     cfg = dict(CFG, expert_offset=0, num_experts_per_tok=4)
     with jax.default_matmul_precision("highest"):
-        whole = REF.experts(w, "", REF._rms(x, gamma, 1e-5), cfg)
+        whole = jax.jit(lambda w, x: REF.experts(
+            w, "", REF._rms(x, gamma, 1e-5), cfg))(w, x)
     op = get_op("_contrib_moe_mixer").impl
 
     def share(offset, shared):
-        y, rows = op(
-            x, gamma, w["router_weight"], jnp.zeros((2, held), F32),
-            w["experts_gate_up_weight"][offset:offset + held],
-            w["experts_down_weight"][offset:offset + held],
-            w["e_score_correction_bias"],
-            *((w["shared_gate_up_weight"], w["shared_down_weight"])
-              if shared else (None, None)),
+        y, rows = jax.jit(lambda x, up, down, *shared: op(
+            x, gamma, w["router_weight"], jnp.zeros((2, held), F32), up,
+            down, w["e_score_correction_bias"], *(shared or (None, None)),
             top_k=4, expert_offset=offset, routed_scaling_factor=1.8,
             norm_topk_prob=True, score_func="sigmoid", activation="swiglu",
-            eps=1e-5)
+            eps=1e-5))(
+                x, w["experts_gate_up_weight"][offset:offset + held],
+                w["experts_down_weight"][offset:offset + held],
+                *((w["shared_gate_up_weight"], w["shared_down_weight"])
+                  if shared else ()))
         return np.asarray(y, np.float64), float(np.asarray(rows)[0].sum())
 
     total, routed_rows = 0.0, 0.0
@@ -536,11 +497,12 @@ def test_the_kernel_at_256_lanes_matches_the_composition(length, tile):
     interprets it at 128 lanes: every query head its own key head, 256
     lanes a head; bf16 results of sums taken in two orders, 2e-2 of the
     largest entry."""
-    q, k, v, cot = _qkv(length, length, 3, 3, d=256)
-    got = _value_and_grads(
-        lambda *a: pallas_causal_gqa.flash_causal_gqa(*a, tile), q, k, v, cot)
-    _close(got, _value_and_grads(lambda *a: D._causal_gqa(*a, tile),
-                                 q, k, v, cot), 2e-2)
+    q, k, v, cot = qkv(length, length, 3, 3, d=256)
+    got = value_and_grads(
+        lambda *a: pallas_causal_gqa.flash_causal_gqa(*a, tile), q, k, v,
+        cot=cot)
+    near(got, value_and_grads(lambda *a: D._causal_gqa(*a, tile),
+                              q, k, v, cot=cot), 2e-2)
 
 
 def test_attend_takes_256_lanes_and_a_group_of_one():
@@ -555,9 +517,9 @@ def test_attend_takes_256_lanes_and_a_group_of_one():
     assert not pallas_causal_gqa.causal_gqa_available(*[shape(16384)] * 3,
                                                       512)
     assert pallas_causal_gqa._bwd_vmem_bytes(8192, 256, 512) == 60_817_408
-    q, k, v, cot = _qkv(9, 512, 2, 2, d=256)
+    q, k, v, cot = qkv(9, 512, 2, 2, d=256)
     assert pallas_causal_gqa.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
-    got = _value_and_grads(D._attend, q, k, v, cot)
+    got = value_and_grads(D._attend, q, k, v, cot=cot)
     f32 = [t.astype(F32) for t in (q, k, v)]
     assert not pallas_causal_gqa.causal_gqa_available(*f32, D.QUERY_BLOCK)
-    _close(got, _value_and_grads(D._attend, *f32, cot), 2e-2)
+    near(got, value_and_grads(D._attend, *f32, cot=cot), 2e-2)

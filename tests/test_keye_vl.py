@@ -9,14 +9,10 @@ import jax
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
+from decoder_harness import OPT, Toy, ids as _ids
 from mxbench import manifest
-from mxnet_tpu import autograd, nd, telemetry
+from mxnet_tpu import autograd, telemetry
 from mxnet_tpu.gluon.model_zoo import keye_vl as zoo
-from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
-
-REF = manifest.load_module("reference", "keye_vl2_30b_a3b.py")
-CFGMOD = manifest.load_module("configs", "keye_vl2_30b_a3b.py")
 
 CFG = dict(
     hidden_size=48, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
@@ -30,27 +26,10 @@ CFG = dict(
     mlp_only_layers=[])
 
 
-def _build(cfg=CFG, seed=3):
-    mx.random.seed(seed)
-    net = zoo.KeyeVLTextModel(cfg, prefix="")
-    head = zoo.KeyeVLLMLoss(cfg, prefix="")
-    net.initialize()
-    head.initialize()
-    return net, head
-
-
-def _weights(net, head):
-    return CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
-
-
-def _batch(seed=0, shape=(2, 21)):
-    rng = np.random.default_rng(seed)
-    return (rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32),
-            rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32))
-
-
-def _ids(a):
-    return nd.array(a, dtype="int32")
+TOY = Toy("keye_vl2_30b_a3b", zoo.KeyeVLTextModel, zoo.KeyeVLLMLoss, CFG)
+REF, CFGMOD = TOY.ref, TOY.cfgmod
+_build, _weights, _batch, _step, _sizes = (TOY.build, TOY.weights, TOY.batch,
+                                           TOY.step, TOY.sizes)
 
 
 def test_blocks_and_both_losses_match_the_reference():
@@ -61,8 +40,9 @@ def test_blocks_and_both_losses_match_the_reference():
         loss = head(hidden, index_loss, _ids(labels)).asnumpy().item()
     w = _weights(net, head)
     with jax.default_matmul_precision("highest"):
-        want, want_index = REF.forward(w, ids, CFG)
-        want_loss = float(REF.lm_loss(w, ids, labels, CFG))
+        (want, want_index), want_loss = jax.jit(lambda w: (
+            REF.forward(w, ids, CFG), REF.lm_loss(w, ids, labels, CFG)))(w)
+    want_loss = float(want_loss)
     np.testing.assert_allclose(hidden.asnumpy(), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
     assert index_loss.shape == (1,)
@@ -82,7 +62,8 @@ def test_position_ids_with_three_distinct_axes_reach_every_layer():
         text, _ = net(_ids(ids))
     w = _weights(net, head)
     with jax.default_matmul_precision("highest"):
-        want, want_index = REF.forward(w, ids, CFG, positions=pos)
+        want, want_index = jax.jit(lambda w: REF.forward(
+            w, ids, CFG, positions=pos))(w)
     np.testing.assert_allclose(hidden.asnumpy(), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
     assert index_loss.asnumpy().item() == pytest.approx(float(want_index),
@@ -127,14 +108,6 @@ def test_a_configuration_that_cannot_be_built_is_refused(change):
         zoo.KeyeVLTextModel(dict(CFG, **change), prefix="")
 
 
-def _step(net, head, dtype=None, **hp):
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    hp = dict(dict(lr=1e-3, wd=1e-4, beta2=0.95), **hp)
-    return ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
-                            optimizer="adamw", dtype=dtype, n_data_inputs=2,
-                            data_specs=[P(), P()], **hp)
-
-
 def test_counts_and_selector_states_ride_as_auxiliary_states():
     """Not trainable: no gradient, no optimizer state; rewritten by the
     step; the second loss goes through ``trace_block`` with the net's
@@ -158,8 +131,8 @@ def test_counts_and_selector_states_ride_as_auxiliary_states():
     # the index loss is in the step's loss: the reference's total
     w = _weights(*_build())
     with jax.default_matmul_precision("highest"):
-        assert first == pytest.approx(
-            float(REF.lm_loss(w, ids, labels, CFG)), rel=1e-5)
+        assert first == pytest.approx(float(jax.jit(
+            lambda w: REF.lm_loss(w, ids, labels, CFG))(w)), rel=1e-5)
 
 
 def test_selector_states_and_expert_rows_are_published():
@@ -203,16 +176,8 @@ def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():
     ids, labels = _batch(2)
     got = float(step.step(_ids(ids), _ids(labels)))
     with jax.default_matmul_precision("highest"):
-        want = float(REF.lm_loss(w, ids, labels, CFG))
+        want = float(jax.jit(lambda w: REF.lm_loss(w, ids, labels, CFG))(w))
     assert got == pytest.approx(want, rel=5e-3)
-
-
-OPT = dict(name="adamw", lr=3e-3, wd=3e-5, beta1=0.9, beta2=0.95,
-           epsilon=1e-8)
-
-
-def _sizes(cfg=CFG):
-    return dict(cfg, deployment={"expert_offset": cfg["expert_offset"]})
 
 
 def test_two_adamw_steps_match_the_reference_and_a_wrong_model_does_not():
@@ -221,7 +186,7 @@ def test_two_adamw_steps_match_the_reference_and_a_wrong_model_does_not():
     norms and one whose selector is trained by nothing wrong."""
     net, head = _build()
     w = _weights(net, head)
-    step = _step(net, head, **{k: v for k, v in OPT.items() if k != "name"})
+    step = TOY.reference_step(net, head)
     ids, labels = _batch(4)
     got = [float(step.step(_ids(ids), _ids(labels))) for _ in range(3)]
     want = REF.train_losses(w, (ids, labels), _sizes(), OPT, 3)

@@ -16,19 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
+from decoder_harness import OPT, Toy, ids as _ids
 from mxbench import manifest
 from mxnet_tpu import autograd, nd, telemetry
 from mxnet_tpu.gluon.model_zoo import laguna as zoo
 from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_causal_gqa as P
-from mxnet_tpu.parallel import MeshConfig, P as Spec, ShardedTrainStep, \
-    make_mesh
-from test_rotary_window_ops import _window_ref
+from numerics import F32, near, qkv, value_and_grads, window_ref
 
-REF = manifest.load_module("reference", "laguna_xs2_33b_a3b.py")
-CFGMOD = manifest.load_module("configs", "laguna_xs2_33b_a3b.py")
-
-F32, BF = jnp.float32, jnp.bfloat16
 SLIDING, FULL = zoo.KINDS
 ROPE = {
     FULL: {"rope_type": "yarn", "rope_theta": 500000, "factor": 64,
@@ -52,27 +46,10 @@ CFG = dict(
     vocab_size=64)
 
 
-def _build(cfg=CFG, seed=3):
-    mx.random.seed(seed)
-    net = zoo.LagunaModel(cfg, prefix="")
-    head = zoo.LagunaLMLoss(cfg, prefix="")
-    net.initialize()
-    head.initialize()
-    return net, head
-
-
-def _weights(net, head):
-    return CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
-
-
-def _batch(seed=0, shape=(2, 21)):
-    rng = np.random.default_rng(seed)
-    return (rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32),
-            rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32))
-
-
-def _ids(a):
-    return nd.array(a, dtype="int32")
+TOY = Toy("laguna_xs2_33b_a3b", zoo.LagunaModel, zoo.LagunaLMLoss, CFG)
+REF, CFGMOD = TOY.ref, TOY.cfgmod
+_build, _weights, _batch, _step, _sizes = (TOY.build, TOY.weights, TOY.batch,
+                                           TOY.step, TOY.sizes)
 
 
 # ---------------------------------------------------------------------------
@@ -117,14 +94,16 @@ def test_the_mixer_under_laguna_s_parameterisation(kind, heads):
     a = _mixer_args(11, heads)
     assert zoo._rope_attrs(ROPE[FULL], 16)["rotary_dim"] == 8
     assert "rotary_dim" not in zoo._rope_attrs(ROPE[SLIDING], 16)
+    def sum_of_sines(mixer):
+        def of(a):
+            y = mixer(a, kind, heads)
+            return jnp.sum(jnp.sin(y)), y
+        return jax.jit(jax.value_and_grad(of, has_aux=True))
+
     with jax.default_matmul_precision("highest"):
-        got, got_g = jax.value_and_grad(
-            lambda a: jnp.sum(jnp.sin(_mixer_op(a, kind, heads))))(a)
-        want, want_g = jax.value_and_grad(
-            lambda a: jnp.sum(jnp.sin(_mixer_ref(a, kind, heads))))(a)
-        np.testing.assert_allclose(
-            _mixer_op(a, kind, heads), _mixer_ref(a, kind, heads),
-            rtol=1e-4, atol=1e-4)
+        (got, y), got_g = sum_of_sines(_mixer_op)(a)
+        (want, want_y), want_g = sum_of_sines(_mixer_ref)(a)
+        np.testing.assert_allclose(y, want_y, rtol=1e-4, atol=1e-4)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for name in a:
         scale = float(jnp.abs(want_g[name]).max())
@@ -207,14 +186,17 @@ def test_a_wrong_mixer_fails_the_mixer_s_comparison(fault, monkeypatch):
     heads = 8 if kind == SLIDING else 6
     a = _mixer_args(15, heads, shape=(2, 40))
     with jax.default_matmul_precision("highest"):
-        got = _mixer_op(a, kind, heads)
+        got = jax.jit(lambda a: _mixer_op(a, kind, heads))(a)
         if how == "ramp":
+            # (eager: the wrong table is worked out in numpy)
             monkeypatch.setattr(REF, "rope_table", _ramp_over_the_head_s_pairs)
             wrong = _mixer_ref(a, kind, heads)
         elif how == "six_heads":
-            wrong = _mixer_ref(_first_six_heads(a), kind, 6)
+            wrong = jax.jit(lambda a: _mixer_ref(a, kind, 6))(
+                _first_six_heads(a))
         else:
-            wrong = _mixer_ref(a, kind, heads, dict(CFG, **how))
+            wrong = jax.jit(lambda a: _mixer_ref(
+                a, kind, heads, dict(CFG, **how)))(a)
     assert float(jnp.abs(wrong - got).max()) \
         > 1e-2 * float(jnp.abs(got).max()), fault
 
@@ -366,14 +348,6 @@ def test_a_configuration_that_cannot_be_built_is_refused(change):
         zoo.LagunaModel(dict(CFG, **change), prefix="")
 
 
-def _step(net, head, dtype=None, **hp):
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    hp = dict(dict(lr=1e-3, wd=1e-4, beta2=0.95), **hp)
-    return ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
-                            optimizer="adamw", dtype=dtype, n_data_inputs=2,
-                            data_specs=[Spec(), Spec()], **hp)
-
-
 def test_expert_rows_ride_as_auxiliary_states_and_paths_are_counted():
     telemetry.reset()
     was = telemetry.enabled()
@@ -406,16 +380,8 @@ def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():
     ids, labels = _batch(2)
     got = float(step.step(_ids(ids), _ids(labels)))
     with jax.default_matmul_precision("highest"):
-        want = float(REF.lm_loss(w, ids, labels, CFG))
+        want = float(jax.jit(lambda w: REF.lm_loss(w, ids, labels, CFG))(w))
     assert got == pytest.approx(want, rel=5e-3)
-
-
-OPT = dict(name="adamw", lr=3e-3, wd=3e-5, beta1=0.9, beta2=0.95,
-           epsilon=1e-8)
-
-
-def _sizes(cfg=CFG):
-    return dict(cfg, deployment={"expert_offset": cfg["expert_offset"]})
 
 
 def test_two_adamw_steps_match_the_reference():
@@ -423,7 +389,7 @@ def test_two_adamw_steps_match_the_reference():
     on both sides."""
     net, head = _build()
     w = _weights(net, head)
-    step = _step(net, head, **{k: v for k, v in OPT.items() if k != "name"})
+    step = TOY.reference_step(net, head)
     ids, labels = _batch(4)
     got = [float(step.step(_ids(ids), _ids(labels))) for _ in range(3)]
     want = REF.train_losses(w, (ids, labels), _sizes(), OPT, 3)
@@ -541,24 +507,31 @@ def test_the_eight_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
     cfg = dict(CFG, expert_offset=0)
     normed = REF._rms(jnp.asarray(x), gamma, 1e-6)
     with jax.default_matmul_precision("highest"):
-        whole = REF.experts(w, "", normed, cfg)
-        shared = REF.shared_expert(w, "", normed)
-    op = get_op("_contrib_moe_mixer").impl
+        whole, shared = jax.jit(lambda w, x: (
+            REF.experts(w, "", x, cfg), REF.shared_expert(w, "", x)))(
+            w, normed)
+    moe = get_op("_contrib_moe_mixer").impl
     attrs = dict(top_k=3, score_func="softmax", activation="swiglu",
                  routed_scaling_factor=2.5, eps=1e-6)
+
+    def op(*share, expert_offset):      # one compiled program a share
+        return jax.jit(lambda *a: moe(
+            *a[:6], *([None] + list(a[6:]) if a[6:] else []),
+            expert_offset=expert_offset, **attrs))(*share)
+
     total, routed_rows = np.asarray(shared, np.float64), 0.0
     for offset in range(0, routed, held):
         share = (jnp.asarray(x), jnp.asarray(gamma), w["router_weight"],
                  jnp.zeros((2, held), F32),
                  w["experts_gate_up_weight"][offset:offset + held],
                  w["experts_down_weight"][offset:offset + held])
-        y, rows = op(*share, expert_offset=offset, **attrs)
+        y, rows = op(*share, expert_offset=offset)
         total = total + np.asarray(y, np.float64)
         routed_rows += float(np.asarray(rows)[0].sum())
         # the mixer with its shared expert is that share plus the
         # shared expert's term
-        both, _ = op(*share, None, w["shared_gate_up_weight"],
-                     w["shared_down_weight"], expert_offset=offset, **attrs)
+        both, _ = op(*share, w["shared_gate_up_weight"],
+                     w["shared_down_weight"], expert_offset=offset)
         np.testing.assert_allclose(both, y + shared, rtol=1e-4, atol=1e-4)
     assert routed_rows == 2 * 21 * 3        # every choice held somewhere
     np.testing.assert_allclose(total, np.asarray(whole), rtol=1e-4, atol=1e-4)
@@ -567,25 +540,6 @@ def test_the_eight_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
 # ---------------------------------------------------------------------------
 # the attention at groups of 6 and 8 and a window of one tile
 # ---------------------------------------------------------------------------
-def _qkv(seed, length, heads, kv, d=128, dtype=BF):
-    keys = jax.random.split(jax.random.key(seed), 4)
-    shapes = [(1, length, heads, d), (1, length, kv, d), (1, length, kv, d),
-              (1, length, heads, d)]
-    return [jax.random.normal(k, s, F32).astype(dtype)
-            for k, s in zip(keys, shapes)]
-
-
-def _value_and_grads(fn, q, k, v, cot):
-    out, vjp = jax.vjp(fn, q, k, v)
-    return [t.astype(F32) for t in (out,) + vjp(cot.astype(out.dtype))]
-
-
-def _close(got, want, rel):
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
-                                   atol=rel * float(jnp.max(jnp.abs(w))))
-
-
 @pytest.mark.parametrize("heads, kv", [(6, 1), (12, 2), (8, 1)],
                          ids=["group_of_6", "two_groups_of_6", "group_of_8"])
 @pytest.mark.parametrize("window", [128, None], ids=["one_tile", "causal"])
@@ -597,21 +551,21 @@ def test_the_kernel_at_laguna_s_groups_and_a_window_of_one_tile(heads, kv,
     query tile, nothing between) against the composition and a whole
     mask. 2e-2 of the largest entry: bf16 results of sums taken in
     different orders, as tests/test_pallas_causal_gqa.py."""
-    q, k, v, cot = _qkv(heads + (window or 0), 384, heads, kv)
-    got = _value_and_grads(
-        lambda *a: P.flash_causal_gqa(*a, 128, window), q, k, v, cot)
-    _close(got, _value_and_grads(
-        lambda *a: D._causal_gqa(*a, 128, window), q, k, v, cot), 2e-2)
-    _close(got, _value_and_grads(
-        lambda *a: _window_ref(*a, window or 384),
-        *(t.astype(F32) for t in (q, k, v, cot))), 2e-2)
+    q, k, v, cot = qkv(heads + (window or 0), 384, heads, kv)
+    got = value_and_grads(
+        lambda *a: P.flash_causal_gqa(*a, 128, window), q, k, v, cot=cot)
+    near(got, value_and_grads(
+        lambda *a: D._causal_gqa(*a, 128, window), q, k, v, cot=cot), 2e-2)
+    near(got, value_and_grads(
+        lambda *a: window_ref(*a, window or 384),
+        *(t.astype(F32) for t in (q, k, v)), cot=cot), 2e-2)
 
 
 def test_a_window_of_one_tile_visits_two_key_tiles():
     """The diagonal tile and the one the band's edge crosses: one
     ``cond`` forward and one backward beside the causal program's, no
     loop over whole tiles between them."""
-    q, k, v, _ = _qkv(3, 384, 6, 1)
+    q, k, v, _ = qkv(3, 384, 6, 1)
 
     def grad_text(window):
         fn = lambda *a: jnp.sum(P.flash_causal_gqa(*a, 128, window)
@@ -629,16 +583,16 @@ def test_attend_with_a_window_of_one_query_block(heads, kv):
     of one: float32 takes the composition, bf16 the (interpreted)
     kernel, both the whole mask's values."""
     window = D.QUERY_BLOCK
-    q, k, v, cot = _qkv(heads, 2 * window, heads, kv)
+    q, k, v, cot = qkv(heads, 2 * window, heads, kv)
     assert P.causal_gqa_available(q, k, v, window)
-    got = _value_and_grads(lambda *a: D._attend(*a, window=window),
-                           q, k, v, cot)
-    f32 = [t.astype(F32) for t in (q, k, v, cot)]
-    assert not P.causal_gqa_available(*f32[:3], window)
-    want = _value_and_grads(lambda *a: _window_ref(*a, window), *f32)
-    _close(got, want, 2e-2)
-    _close(_value_and_grads(lambda *a: D._attend(*a, window=window), *f32),
-           want, 1e-4)
+    got = value_and_grads(lambda *a: D._attend(*a, window=window),
+                          q, k, v, cot=cot)
+    f32 = [t.astype(F32) for t in (q, k, v)]
+    assert not P.causal_gqa_available(*f32, window)
+    want = value_and_grads(lambda *a: window_ref(*a, window), *f32, cot=cot)
+    near(got, want, 2e-2)
+    near(value_and_grads(lambda *a: D._attend(*a, window=window), *f32,
+                         cot=cot), want, 1e-4)
 
 
 # ---------------------------------------------------------------------------

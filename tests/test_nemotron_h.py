@@ -6,14 +6,10 @@ import jax
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
-from mxbench import manifest
+from decoder_harness import OPT, Toy, ids as _ids
 from mxnet_tpu import autograd, gluon, nd, telemetry
 from mxnet_tpu.gluon.model_zoo import nemotron_h as zoo
 from mxnet_tpu.parallel import MeshConfig, P, ShardedTrainStep, make_mesh
-
-REF = manifest.load_module("reference", "nemotron_twotower_30b_a3b.py")
-CFGMOD = manifest.load_module("configs", "nemotron_twotower_30b_a3b.py")
 
 CFG = dict(
     hidden_size=48, hybrid_override_pattern="MEMEM*EMEMEM", num_hidden_layers=9,
@@ -26,36 +22,24 @@ CFG = dict(
     num_attention_heads=4, num_key_value_heads=2, head_dim=8, vocab_size=64)
 
 
-def _build(cfg=CFG, seed=3):
-    mx.random.seed(seed)
-    net = zoo.NemotronHModel(cfg, prefix="")
-    head = zoo.NemotronHLMLoss(cfg, prefix="")
-    net.initialize()
-    head.initialize()
-    return net, head
-
-
-def _weights(net, head):
-    return CFGMOD.named_weights(net, CFGMOD._HeadLoss(head))
-
-
-def _batch(seed=0, shape=(2, 21)):
-    rng = np.random.default_rng(seed)
-    return (rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32),
-            rng.integers(0, CFG["vocab_size"], shape, dtype=np.int32))
+TOY = Toy("nemotron_twotower_30b_a3b", zoo.NemotronHModel,
+          zoo.NemotronHLMLoss, CFG)
+REF = TOY.ref
+_build, _weights, _batch = TOY.build, TOY.weights, TOY.batch
 
 
 def test_blocks_and_loss_match_the_reference():
     net, head = _build()
     ids, labels = _batch()
     with autograd.pause():
-        hidden = net(nd.array(ids, dtype="int32"))
-        loss = head(hidden, nd.array(labels, dtype="int32")).mean() \
+        hidden = net(_ids(ids))
+        loss = head(hidden, _ids(labels)).mean() \
             .asnumpy().item()
     w = _weights(net, head)
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(REF.forward(w, ids, CFG))
-        want_loss = float(REF.lm_loss(w, ids, labels, CFG))
+        want, want_loss = jax.jit(lambda w: (
+            REF.forward(w, ids, CFG), REF.lm_loss(w, ids, labels, CFG)))(w)
+    want, want_loss = np.asarray(want), float(want_loss)
     np.testing.assert_allclose(hidden.asnumpy(), want, rtol=1e-4, atol=1e-4)
     assert loss == pytest.approx(want_loss, rel=1e-5)
 
@@ -104,29 +88,25 @@ def test_seeded_initial_values():
         again.collect_params()["layers2_in_proj_weight"].data().asnumpy())
 
 
-def _step(net, head, dtype=None):
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    return ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
-                            optimizer="adamw", lr=3e-4, wd=3e-5, beta2=0.95,
-                            dtype=dtype, n_data_inputs=2,
-                            data_specs=[P(), P()])
+def _a_step(net, head, dtype=None):
+    return TOY.step(net, head, dtype, lr=3e-4, wd=3e-5)
 
 
 def test_bias_and_row_counts_ride_as_auxiliary_states():
     """Not trainable: no gradient, no optimizer state; the bias keeps
     its seeded value, the counts are rewritten by the step."""
     net, head = _build()
-    step = _step(net, head)
+    step = _a_step(net, head)
     aux = sorted(step.aux)
     assert aux == sorted("layers%d_%s" % (i, n) for i in (1, 3, 6, 8)
                          for n in ("e_score_correction_bias", "expert_rows"))
     assert not set(aux) & set(step.params) and not set(aux) & set(step.states)
     bias = np.asarray(step.aux["layers1_e_score_correction_bias"])
     ids, labels = _batch()
-    first = float(step.step(nd.array(ids, dtype="int32"),
-                            nd.array(labels, dtype="int32")))
-    second = float(step.step(nd.array(ids, dtype="int32"),
-                             nd.array(labels, dtype="int32")))
+    first = float(step.step(_ids(ids),
+                            _ids(labels)))
+    second = float(step.step(_ids(ids),
+                             _ids(labels)))
     assert second < first
     np.testing.assert_array_equal(
         np.asarray(step.aux["layers1_e_score_correction_bias"]), bias)
@@ -138,9 +118,9 @@ def test_bias_and_row_counts_ride_as_auxiliary_states():
 def test_expert_rows_are_published_and_nothing_is_dropped():
     telemetry.reset()
     net, head = _build()
-    step = _step(net, head)
+    step = _a_step(net, head)
     ids, labels = _batch(1)
-    step.step(nd.array(ids, dtype="int32"), nd.array(labels, dtype="int32"))
+    step.step(_ids(ids), _ids(labels))
     rows = zoo.publish_expert_rows(step.aux)
     assert sorted(rows) == ["layers1", "layers3", "layers6", "layers8"]
     want = np.asarray(step.aux["layers3_expert_rows"])[0]
@@ -151,7 +131,7 @@ def test_expert_rows_are_published_and_nothing_is_dropped():
     assert telemetry.counter("mx_moe_dropped_rows_total").value == 0
     # from the Gluon parameters too (an eager forward writes them)
     with autograd.pause():
-        net(nd.array(ids, dtype="int32"))
+        net(_ids(ids))
     eager = zoo.publish_expert_rows(
         {k: v.data() for k, v in net.collect_params().items()})
     assert eager["layers1"].sum() > 0
@@ -161,12 +141,12 @@ def test_expert_rows_are_published_and_nothing_is_dropped():
 def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():
     net, head = _build()
     w = _weights(net, head)
-    step = _step(net, head, dtype="bfloat16")
+    step = _a_step(net, head, dtype="bfloat16")
     ids, labels = _batch(2)
-    got = float(step.step(nd.array(ids, dtype="int32"),
-                          nd.array(labels, dtype="int32")))
+    got = float(step.step(_ids(ids),
+                          _ids(labels)))
     with jax.default_matmul_precision("highest"):
-        want = float(REF.lm_loss(w, ids, labels, CFG))
+        want = float(jax.jit(lambda w: REF.lm_loss(w, ids, labels, CFG))(w))
     assert got == pytest.approx(want, rel=5e-3)
 
 
@@ -203,7 +183,7 @@ def test_integer_inputs_reach_the_embedding_unrounded():
                             data_specs=[P(), P()])
     ids = np.array([[16383, 16381, 257, 8191, 1, 12345]], np.int32)
     assert (ids % 2 == 1).all()
-    loss = float(step.step(nd.array(ids, dtype="int32"),
+    loss = float(step.step(_ids(ids),
                            nd.array(np.zeros(ids.shape, np.float32))))
     assert loss == 1.0
 
@@ -214,18 +194,11 @@ def test_three_adamw_steps_at_a_constant_rate_match_the_reference():
     the router's bias never updated."""
     net, head = _build()
     w = _weights(net, head)
-    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
-    opt = dict(name="adamw", lr=3e-3, wd=3e-5, beta1=0.9, beta2=0.95,
-               epsilon=1e-8)
-    step = ShardedTrainStep(net, CFGMOD._HeadLoss(head), mesh,
-                            n_data_inputs=2, data_specs=[P(), P()],
-                            optimizer="adamw",
-                            **{k: v for k, v in opt.items() if k != "name"})
+    step = TOY.reference_step(net, head)
     ids, labels = _batch(4)
-    got = [float(step.step(nd.array(ids, dtype="int32"),
-                           nd.array(labels, dtype="int32")))
+    got = [float(step.step(_ids(ids),
+                           _ids(labels)))
            for _ in range(3)]
-    sizes = dict(CFG, deployment={"expert_offset": CFG["expert_offset"]})
-    want = REF.train_losses(w, (ids, labels), sizes, opt, 3)
+    want = REF.train_losses(w, (ids, labels), TOY.sizes(), OPT, 3)
     np.testing.assert_allclose(got, want, rtol=2e-5)
     assert got[2] < got[1] < got[0]

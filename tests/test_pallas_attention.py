@@ -12,6 +12,7 @@ from mxnet_tpu.ops.pallas_attention import (flash_selfatt,
                                             selfatt_plan)
 from mxnet_tpu.ops.contrib_ops import (interleaved_matmul_selfatt_qk,
                                        interleaved_matmul_selfatt_valatt)
+from numerics import value_and_grads
 
 
 def _ref(qkv, heads):
@@ -27,16 +28,13 @@ def test_flash_selfatt_matches_unfused(L, N, H, d):
     assert flash_selfatt_available(L, H, N)
     plan = selfatt_plan(L, H, N, 0.0)
     seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
-    o1 = flash_selfatt(qkv, seeds, heads=H,
-                       block_heads=plan["bbh"])
-    o2 = _ref(qkv, H)
+    r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
+    o1, g1 = value_and_grads(
+        lambda q: flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"]),
+        qkv, cot=r)
+    o2, g2 = value_and_grads(lambda q: _ref(q, H), qkv, cot=r)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                rtol=2e-2, atol=2e-2)
-    r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-    g1 = jax.grad(lambda q: jnp.sum(
-        flash_selfatt(q, seeds, heads=H,
-                      block_heads=plan["bbh"]) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
     denom = float(jnp.max(jnp.abs(g2))) + 1e-9
     assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
 

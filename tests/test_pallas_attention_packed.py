@@ -62,6 +62,15 @@ def _rand_qkv(rng, L, N, H, d):
     return jnp.asarray(rng.randn(L, N, H * 3 * d).astype(np.float32))
 
 
+def _grad_gap(f, g, qkv, r):
+    """The largest difference of the two gradients of sum(. * r), over
+    the largest entry of ``g``'s: each traced and compiled once."""
+    g1 = jax.jit(jax.grad(lambda q: jnp.sum(f(q) * r)))(qkv)
+    g2 = jax.jit(jax.grad(lambda q: jnp.sum(g(q) * r)))(qkv)
+    return float(jnp.max(jnp.abs(g1 - g2))) \
+        / (float(jnp.max(jnp.abs(g2))) + 1e-9)
+
+
 @pytest.mark.parametrize("L,N,H,d", [(16, 4, 4, 8), (32, 2, 8, 16)])
 def test_packed_bitwise_fwd(L, N, H, d):
     """Forward is bitwise-equal to the unfused composition run through
@@ -71,8 +80,9 @@ def test_packed_bitwise_fwd(L, N, H, d):
     plan = selfatt_plan(L, H, N, 0.0)
     assert plan is not None
     seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
-    o1 = flash_selfatt(qkv, seeds, heads=H, block_heads=plan["bbh"])
-    o2 = _ref_chain(qkv, H)
+    o1 = jax.jit(lambda q: flash_selfatt(
+        q, seeds, heads=H, block_heads=plan["bbh"]))(qkv)
+    o2 = jax.jit(lambda q: _ref_chain(q, H))(qkv)
     assert bool(jnp.all(o1 == o2))
 
 
@@ -84,16 +94,15 @@ def test_packed_matches_unfused(L, N, H, d):
     qkv = _rand_qkv(rng, L, N, H, d)
     plan = selfatt_plan(L, H, N, 0.0)
     seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
-    o1 = flash_selfatt(qkv, seeds, heads=H, block_heads=plan["bbh"])
-    o2 = _ref(qkv, H)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
+
+    def f(q):
+        return flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"])
+
+    np.testing.assert_allclose(np.asarray(jax.jit(f)(qkv)),
+                               np.asarray(jax.jit(lambda q: _ref(q, H))(qkv)),
                                rtol=2e-2, atol=2e-2)
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-    g1 = jax.grad(lambda q: jnp.sum(
-        flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"]) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
-    denom = float(jnp.max(jnp.abs(g2))) + 1e-9
-    assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
+    assert _grad_gap(f, lambda q: _ref(q, H), qkv, r) < 3e-2
 
 
 def test_ragged_seq_l127_stays_on_kernel():
@@ -107,15 +116,15 @@ def test_ragged_seq_l127_stays_on_kernel():
     plan = selfatt_plan(L, H, N, 0.0)
     assert plan["L_pad"] == 128 and plan["n_blocks"] == N
     seeds = jnp.zeros((plan["n_blocks"],), jnp.int32)
-    o1 = flash_selfatt(qkv, seeds, heads=H, block_heads=plan["bbh"])
+
+    def f(q):
+        return flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"])
+
+    o1 = jax.jit(f)(qkv)
     assert o1.shape == (L, N, H * d)
-    assert bool(jnp.all(o1 == _ref_chain(qkv, H)))
+    assert bool(jnp.all(o1 == jax.jit(lambda q: _ref_chain(q, H))(qkv)))
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-    g1 = jax.grad(lambda q: jnp.sum(
-        flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"]) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
-    denom = float(jnp.max(jnp.abs(g2))) + 1e-9
-    assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
+    assert _grad_gap(f, lambda q: _ref(q, H), qkv, r) < 3e-2
 
 
 @pytest.mark.parametrize("H,bbh", [(5, 5), (5, 4), (12, 8)])
@@ -128,15 +137,15 @@ def test_non_dividing_heads_and_padded_blocks(H, bbh):
     qkv = _rand_qkv(rng, L, N, H, d)
     n_hblk = -(-H // bbh)
     seeds = jnp.zeros((N * n_hblk,), jnp.int32)
-    o1 = flash_selfatt(qkv, seeds, heads=H, block_heads=bbh)
+
+    def f(q):
+        return flash_selfatt(q, seeds, heads=H, block_heads=bbh)
+
+    o1 = jax.jit(f)(qkv)
     assert o1.shape == (L, N, H * d)
-    assert bool(jnp.all(o1 == _ref_chain(qkv, H)))
+    assert bool(jnp.all(o1 == jax.jit(lambda q: _ref_chain(q, H))(qkv)))
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-    g1 = jax.grad(lambda q: jnp.sum(
-        flash_selfatt(q, seeds, heads=H, block_heads=bbh) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(_ref(q, H) * r))(qkv)
-    denom = float(jnp.max(jnp.abs(g2))) + 1e-9
-    assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
+    assert _grad_gap(f, lambda q: _ref(q, H), qkv, r) < 3e-2
 
 
 def test_dropout_seed_recompute_parity():
@@ -162,31 +171,20 @@ def test_dropout_seed_recompute_parity():
         return _ref(q, H, att_hook=lambda att: jnp.where(
             masks, att / (1.0 - p), 0.0).astype(att.dtype))
 
-    def f(q):
+    def f(q, seeds=seeds):
         return flash_selfatt(q, seeds, heads=H, dropout=p,
                              block_heads=bbh)
 
-    o1, o2 = f(qkv), f(qkv)
+    kernel = jax.jit(f)
+    o1, o2 = kernel(qkv), kernel(qkv)
     assert bool(jnp.all(o1 == o2))            # same seeds, same mask
     np.testing.assert_allclose(np.asarray(o1),
-                               np.asarray(ref_masked(qkv)),
+                               np.asarray(jax.jit(ref_masked)(qkv)),
                                rtol=3e-2, atol=3e-2)
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
-    g1 = jax.grad(lambda q: jnp.sum(f(q) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(ref_masked(q) * r))(qkv)
-    denom = float(jnp.max(jnp.abs(g2))) + 1e-9
-    assert float(jnp.max(jnp.abs(g1 - g2))) / denom < 3e-2
+    assert _grad_gap(f, ref_masked, qkv, r) < 3e-2
     # different seeds -> different mask -> different output
-    o3 = flash_selfatt(qkv, seeds + 1, heads=H, dropout=p,
-                       block_heads=bbh)
-    assert not bool(jnp.all(o1 == o3))
-
-
-def _grad_gap(f, g, qkv, r):
-    g1 = jax.grad(lambda q: jnp.sum(f(q) * r))(qkv)
-    g2 = jax.grad(lambda q: jnp.sum(g(q) * r))(qkv)
-    return float(jnp.max(jnp.abs(g1 - g2))) \
-        / (float(jnp.max(jnp.abs(g2))) + 1e-9)
+    assert not bool(jnp.all(o1 == kernel(qkv, seeds + 1)))
 
 
 # (L, heads) at BERT's head width 64, past the 336 positions where the
@@ -209,14 +207,15 @@ def test_lengths_past_the_default_limit_match_unfused(L, H):
     def f(q):
         return flash_selfatt(q, seeds, heads=H, block_heads=plan["bbh"])
 
-    o1 = f(qkv)
+    o1 = jax.jit(f)(qkv)
     assert o1.shape == (L, N, H * d)
     # one bf16 step: long rows' sums are not ordered as the chain's
-    np.testing.assert_allclose(np.asarray(o1),
-                               np.asarray(_ref_chain(qkv, H)),
-                               rtol=2 ** -7, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(_ref(qkv, H)),
-                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(
+        np.asarray(o1), np.asarray(jax.jit(lambda q: _ref_chain(q, H))(qkv)),
+        rtol=2 ** -7, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(o1), np.asarray(jax.jit(lambda q: _ref(q, H))(qkv)),
+        rtol=2e-2, atol=2e-2)
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
     assert _grad_gap(f, lambda q: _ref(q, H), qkv, r) < 3e-2
 
@@ -251,10 +250,11 @@ def test_dropout_at_the_configurations_rate(L, H, bbh):
         return flash_selfatt(q, seeds, heads=H, dropout=p,
                              block_heads=bbh)
 
-    o1 = f(qkv)
-    assert bool(jnp.all(o1 == f(qkv)))
+    kernel = jax.jit(f)
+    o1 = kernel(qkv)
+    assert bool(jnp.all(o1 == kernel(qkv)))
     np.testing.assert_allclose(np.asarray(o1),
-                               np.asarray(ref_masked(qkv)),
+                               np.asarray(jax.jit(ref_masked)(qkv)),
                                rtol=3e-2, atol=3e-2)
     r = jnp.asarray(rng.randn(L, N, H * d).astype(np.float32))
     assert _grad_gap(f, ref_masked, qkv, r) < 3e-2
@@ -279,7 +279,8 @@ def test_central_difference_grads_through_registered_op():
         out = op.impl(key, q, heads=H, dropout=0.0, _train=True)
         return jnp.sum(out.astype(jnp.float32) * r)
 
-    g = jax.grad(f)(qkv).astype(jnp.float32)
+    f = jax.jit(f)
+    g = jax.jit(jax.grad(f))(qkv).astype(jnp.float32)
     gnorm = float(jnp.linalg.norm(g))
     checked = 0
     for trial in range(4):

@@ -1,10 +1,10 @@
 """The causal grouped-query flash kernel (ops/pallas_causal_gqa.py) in
 interpret mode on the CPU: values and gradients against the blocked
 composition it stands in for (``decoder_ops._causal_gqa``) and against
-the float32 reference of tests/test_decoder_ops.py; causality; and the
+the plain float32 reference (tests/numerics.py); causality; and the
 ladder by which ``decoder_ops._attend`` picks a schedule, each rung
 counted in ``mx_attn_causal_path_total``. What Mosaic makes of the
-kernels at the published widths is tests/test_chip_compile.py's."""
+kernels at the published widths is tests/test_chip_compile_*.py's."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,32 +14,10 @@ from jax.sharding import Mesh
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_causal_gqa as P
 from mxnet_tpu.ops.pallas_common import auto_partitioned
-from test_decoder_ops import _attention_ref
-from test_rotary_window_ops import _window_ref
+from numerics import (BF, F32, attention_ref, near, qkv, value_and_grads,
+                      window_ref)
 
-F32, BF = jnp.float32, jnp.bfloat16
 COUNTER = "mx_attn_causal_path_total"
-
-
-def _qkv(seed, length, heads, kv, d=128, batch=1, dtype=BF):
-    keys = jax.random.split(jax.random.key(seed), 4)
-    shapes = [(batch, length, heads, d), (batch, length, kv, d),
-              (batch, length, kv, d), (batch, length, heads, d)]
-    return [jax.random.normal(k, s, F32).astype(dtype)
-            for k, s in zip(keys, shapes)]
-
-
-def _value_and_grads(fn, q, k, v, cot):
-    out, vjp = jax.vjp(fn, q, k, v)
-    return [t.astype(F32) for t in (out,) + vjp(cot.astype(out.dtype))]
-
-
-def _close(got, want, rel):
-    """Each array to within ``rel`` of the wanted one's largest entry
-    (bf16 results of sums taken in different orders)."""
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
-                                   atol=rel * float(jnp.max(jnp.abs(w))))
 
 
 @pytest.mark.parametrize("heads, kv", [(2, 2), (4, 1), (16, 1)],
@@ -48,24 +26,24 @@ def _close(got, want, rel):
                          ids=["one_tile", "three_tiles", "two_tiles_of_256"])
 def test_kernel_matches_the_composition_and_the_reference(length, tile,
                                                           heads, kv):
-    q, k, v, cot = _qkv(length + heads, length, heads, kv, batch=2)
-    got = _value_and_grads(lambda *a: P.flash_causal_gqa(*a, tile),
-                           q, k, v, cot)
+    q, k, v, cot = qkv(length + heads, length, heads, kv, batch=2)
+    got = value_and_grads(lambda *a: P.flash_causal_gqa(*a, tile),
+                          q, k, v, cot=cot)
     # the composition on the same bf16 inputs: two roundings of one sum
-    _close(got, _value_and_grads(lambda *a: D._causal_gqa(*a, tile),
-                                 q, k, v, cot), 2e-2)
+    near(got, value_and_grads(lambda *a: D._causal_gqa(*a, tile),
+                              q, k, v, cot=cot), 2e-2)
     # the plain float32 reference on the same values
-    _close(got, _value_and_grads(
-        _attention_ref, *(t.astype(F32) for t in (q, k, v, cot))), 2e-2)
+    near(got, value_and_grads(
+        attention_ref, *(t.astype(F32) for t in (q, k, v)), cot=cot), 2e-2)
 
 
 @pytest.mark.parametrize("t", [0, 127, 128, 200, 382])
 def test_a_key_after_position_t_never_reaches_output_t(t):
-    q, k, v, _ = _qkv(5, 384, 4, 2)
+    q, k, v, _ = qkv(5, 384, 4, 2)
     later = (jnp.arange(384) > t)[None, :, None, None]
-    out = P.flash_causal_gqa(q, k, v, 128)
-    moved = P.flash_causal_gqa(q, jnp.where(later, k + 3, k),
-                               jnp.where(later, v - 2, v), 128)
+    kernel = jax.jit(lambda *a: P.flash_causal_gqa(*a, 128))
+    out = kernel(q, k, v)
+    moved = kernel(q, jnp.where(later, k + 3, k), jnp.where(later, v - 2, v))
     np.testing.assert_array_equal(np.asarray(out[:, :t + 1], F32),
                                   np.asarray(moved[:, :t + 1], F32))
     assert not np.array_equal(np.asarray(out[:, t + 1:], F32),
@@ -135,7 +113,7 @@ RUNGS = {
 def test_each_rung_takes_the_composition_and_is_counted_xla(rung, op,
                                                             counted):
     length, heads, kv, d, dtype, scope = RUNGS[rung]
-    q, k, v, _ = _qkv(1, length, heads, kv, d, dtype=dtype)
+    q, k, v, _ = qkv(1, length, heads, kv, d, dtype=dtype)
     if scope is None:
         assert not P.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
         calls = _pallas_calls(op, q, k, v)
@@ -149,17 +127,17 @@ def test_each_rung_takes_the_composition_and_is_counted_xla(rung, op,
 
 @pytest.mark.parametrize("op", [_attention, _mixer], ids=["op", "mixer"])
 def test_an_eligible_call_takes_the_kernel_and_is_counted_pallas(op, counted):
-    q, k, v, _ = _qkv(2, D.QUERY_BLOCK, 2, 1)
+    q, k, v, _ = qkv(2, D.QUERY_BLOCK, 2, 1)
     assert P.causal_gqa_available(q, k, v, D.QUERY_BLOCK)
     assert _pallas_calls(op, q, k, v) == 2      # forward, backward
     assert counted() == {"pallas": 1, "xla": 0}
 
 
 def test_the_op_on_the_kernel_path_gives_the_composition_s_values():
-    q, k, v, cot = _qkv(3, 2 * D.QUERY_BLOCK, 2, 1)
-    _close(_value_and_grads(_attention, q, k, v, cot),
-           _value_and_grads(lambda *a: D._causal_gqa(*a, D.QUERY_BLOCK),
-                            q, k, v, cot), 2e-2)
+    q, k, v, cot = qkv(3, 2 * D.QUERY_BLOCK, 2, 1)
+    near(value_and_grads(_attention, q, k, v, cot=cot),
+         value_and_grads(lambda *a: D._causal_gqa(*a, D.QUERY_BLOCK),
+                         q, k, v, cot=cot), 2e-2)
 
 
 def test_a_length_whose_keys_do_not_fit_vmem_takes_the_composition():
@@ -186,14 +164,14 @@ WINDOW_COUNTER = "mx_attn_window_path_total"
     ids=["inside", "boundary", "narrow", "length", "beyond"])
 def test_windowed_kernel_matches_a_whole_mask_and_the_composition(
         length, tile, window):
-    q, k, v, cot = _qkv(length + window, length, 2, 1)
-    got = _value_and_grads(
-        lambda *a: P.flash_causal_gqa(*a, tile, window), q, k, v, cot)
-    _close(got, _value_and_grads(
-        lambda *a: D._causal_gqa(*a, tile, window), q, k, v, cot), 2e-2)
-    _close(got, _value_and_grads(
-        lambda *a: _window_ref(*a, window),
-        *(t.astype(F32) for t in (q, k, v, cot))), 2e-2)
+    q, k, v, cot = qkv(length + window, length, 2, 1)
+    got = value_and_grads(
+        lambda *a: P.flash_causal_gqa(*a, tile, window), q, k, v, cot=cot)
+    near(got, value_and_grads(
+        lambda *a: D._causal_gqa(*a, tile, window), q, k, v, cot=cot), 2e-2)
+    near(got, value_and_grads(
+        lambda *a: window_ref(*a, window),
+        *(t.astype(F32) for t in (q, k, v)), cot=cot), 2e-2)
 
 
 @pytest.mark.parametrize("t", [130, 255, 256, 383])
@@ -201,15 +179,16 @@ def test_a_key_before_the_band_never_reaches_output_t(t):
     """Keys at or before ``t - window`` do not move row ``t``; the
     band's first key does."""
     window = 130
-    q, k, v, _ = _qkv(6, 384, 4, 2)
-    out = P.flash_causal_gqa(q, k, v, 128, window)
+    q, k, v, _ = qkv(6, 384, 4, 2)
+    kernel = jax.jit(lambda *a: P.flash_causal_gqa(*a, 128, window))
+    out = kernel(q, k, v)
     before = (jnp.arange(384) <= t - window)[None, :, None, None]
-    moved = P.flash_causal_gqa(q, jnp.where(before, k + 3, k),
-                               jnp.where(before, v - 2, v), 128, window)
+    moved = kernel(q, jnp.where(before, k + 3, k),
+                   jnp.where(before, v - 2, v))
     np.testing.assert_array_equal(np.asarray(out[:, t:], F32),
                                   np.asarray(moved[:, t:], F32))
     first = (jnp.arange(384) == t - window + 1)[None, :, None, None]
-    moved = P.flash_causal_gqa(q, k, jnp.where(first, v - 2, v), 128, window)
+    moved = kernel(q, k, jnp.where(first, v - 2, v))
     assert not np.array_equal(np.asarray(out[:, t], F32),
                               np.asarray(moved[:, t], F32))
 
@@ -225,7 +204,7 @@ def test_without_a_window_the_kernel_s_program_is_the_causal_one():
     """``window=None``, and a window no shorter than the length, trace
     the kernels they traced before the argument existed: one loop from
     tile 0 and the diagonal tile, no ``cond`` for a band's tile."""
-    q, k, v, cot = _qkv(7, 384, 2, 1)
+    q, k, v, cot = qkv(7, 384, 2, 1)
 
     def grad_text(*window):
         fn = lambda *a: jnp.sum(P.flash_causal_gqa(*a, 128, *window)
@@ -245,7 +224,7 @@ def test_without_a_window_the_kernel_s_program_is_the_causal_one():
 def test_a_windowed_call_is_counted_in_a_series_of_its_own(counted):
     was = {p: telemetry.counter(WINDOW_COUNTER, path=p).get()
            for p in ("pallas", "xla")}
-    q, k, v, _ = _qkv(8, D.QUERY_BLOCK, 2, 1)
+    q, k, v, _ = qkv(8, D.QUERY_BLOCK, 2, 1)
     assert _pallas_calls(lambda *a: D._attend(*a, window=100), q, k, v) == 2
     assert _pallas_calls(lambda *a: D._attend(
         *(t.astype(F32) for t in a), window=100), q, k, v) == 0
@@ -256,7 +235,7 @@ def test_a_windowed_call_is_counted_in_a_series_of_its_own(counted):
 
 
 def test_the_scope_follows_the_kind_forward_and_backward():
-    q, k, v, _ = _qkv(9, D.QUERY_BLOCK, 2, 1)
+    q, k, v, _ = qkv(9, D.QUERY_BLOCK, 2, 1)
 
     def text(**kw):
         fn = lambda *a: jnp.sum(D._attend(*a, **kw).astype(F32))

@@ -11,6 +11,7 @@ from mxnet_tpu.ops.pallas_epilogue import (bias_gelu_available,
                                            bias_residual_available,
                                            pallas_bias_gelu,
                                            pallas_bias_residual)
+from numerics import jitted
 
 
 @pytest.fixture(autouse=True)
@@ -55,8 +56,9 @@ def test_bias_gelu_exact_grads():
     def s2(x, b):
         return jnp.sum(_gelu_ref(x, b) * r)
 
-    g1 = jax.grad(s1, argnums=(0, 1))(x, b)
-    g2 = jax.grad(s2, argnums=(0, 1))(x, b)
+    g1 = jax.jit(jax.grad(s1, argnums=(0, 1)))(x, b)
+    g2 = jax.jit(jax.grad(s2, argnums=(0, 1)))(x, b)
+    s1 = jax.jit(s1)
     np.testing.assert_allclose(np.asarray(g1[0]), np.asarray(g2[0]),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(g1[1]), np.asarray(g2[1]),
@@ -80,7 +82,7 @@ def test_bias_gelu_multiblock_db_accumulation():
         def s(x, b):
             return jnp.sum(pallas_bias_gelu(x, b,
                                             block_rows=block_rows))
-        return jax.grad(s, argnums=1)(x, b)
+        return jax.jit(jax.grad(s, argnums=1))(x, b)
 
     np.testing.assert_allclose(np.asarray(db_of(8)),
                                np.asarray(db_of(64)),
@@ -97,10 +99,10 @@ def test_bias_residual_exact_and_grads():
     o = pallas_bias_residual(x, b, r)
     assert bool(jnp.all(o == x + b + r))
     w = jnp.asarray(rng.randn(M, C).astype(np.float32))
-    g1 = jax.grad(lambda x, b, r: jnp.sum(
-        pallas_bias_residual(x, b, r) * w), argnums=(0, 1, 2))(x, b, r)
-    g2 = jax.grad(lambda x, b, r: jnp.sum(
-        (x + b + r) * w), argnums=(0, 1, 2))(x, b, r)
+    g1 = jax.jit(jax.grad(lambda x, b, r: jnp.sum(
+        pallas_bias_residual(x, b, r) * w), argnums=(0, 1, 2)))(x, b, r)
+    g2 = jax.jit(jax.grad(lambda x, b, r: jnp.sum(
+        (x + b + r) * w), argnums=(0, 1, 2)))(x, b, r)
     for a, c in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=1e-6, atol=1e-6)
@@ -227,7 +229,7 @@ def test_erf_matches_xla():
         np.linspace(-8.0, 8.0, 400001),
         np.random.RandomState(0).randn(100000) * 3.0,
         [0.0, -0.0, 1e-20, 4.0, -4.0, 1e30, -1e30]]).astype(np.float32))
-    got = np.asarray(jax.jit(_erf)(x))
+    got = np.asarray(jitted(_erf)(x))
     np.testing.assert_allclose(got, np.asarray(lax.erf(x)), rtol=0,
                                atol=1e-6)
     assert np.all(np.abs(got) <= 1.0 + 1e-6)

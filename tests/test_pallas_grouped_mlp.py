@@ -8,7 +8,7 @@ float32 loop over the held experts (the benchmark's own references are
 of the weight-gradient kernel (an expert with no block, the empty blocks
 past the last run, one expert drawing nearly every token); the overflow
 path; the ladder of what the kernels do not serve; the counter. What
-Mosaic makes of the real widths is ``tests/test_chip_compile.py``'s to
+Mosaic makes of the real widths is ``tests/test_chip_compile_*.py``'s to
 say."""
 import jax
 import jax.numpy as jnp
@@ -18,8 +18,8 @@ import pytest
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, pallas_common, pallas_moe_rows
 from mxnet_tpu.ops import pallas_grouped_mlp as G
+from numerics import BF, F32, near, normal, rand
 
-F32, BF = jnp.float32, jnp.bfloat16
 COUNTER = "mx_moe_experts_path_total"
 HIDDEN = WIDTH = 128
 
@@ -27,21 +27,6 @@ HIDDEN = WIDTH = 128
 @pytest.fixture
 def interpreted(monkeypatch):
     monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
-
-
-def _rand(seed, *shapes, scale=1.0):
-    keys = jax.random.split(jax.random.key(seed), len(shapes))
-    return [(scale * jax.random.normal(k, s, F32)).astype(BF)
-            for k, s in zip(keys, shapes)]
-
-
-def _near(got, want, rel=2e-2):
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        assert g.shape == w.shape
-        assert np.all(np.isfinite(g))
-        assert np.abs(g - w).max() <= rel * max(np.abs(w).max(), 1e-6)
 
 
 def _pallas_calls(fn, *args):
@@ -61,8 +46,8 @@ BLOCK = 16
 def _buffer(seed, act):
     mul = ACTS[act][1]
     rows = len(EXPERT_OF_BLOCK) * BLOCK
-    x, up, down = _rand(seed, (rows, HIDDEN), (4, mul * WIDTH, HIDDEN),
-                        (4, HIDDEN, WIDTH), scale=0.3)
+    x, up, down = rand(seed, (rows, HIDDEN), (4, mul * WIDTH, HIDDEN),
+                       (4, HIDDEN, WIDTH), scale=0.3, dtype=BF)
     filled = jnp.arange(rows) < 6 * BLOCK       # the last two blocks: empty
     x = jnp.where(filled[:, None], x, 0).astype(BF)
     w = jnp.where(filled, jax.random.uniform(jax.random.key(seed + 1),
@@ -84,7 +69,7 @@ def _by_hand(x, eob, w, up, down, act):
 def test_kernels_against_the_composition_and_float32(interpreted, act):
     x, eob, w, up, down = _buffer(1, act)
     fn = ACTS[act][0]
-    (cot,) = _rand(3, x.shape)
+    (cot,) = rand(3, x.shape, dtype=BF)
     cot = cot.astype(F32)
 
     def kernels(x, w, up, down):
@@ -103,11 +88,13 @@ def test_kernels_against_the_composition_and_float32(interpreted, act):
     assert G.grouped_mlp_available(
         x.reshape(len(EXPERT_OF_BLOCK), BLOCK, HIDDEN), up, down)
     assert _pallas_calls(jax.grad(kernels, (0, 1, 2, 3)), x, w, up, down) == 6
-    _near(G.grouped_mlp(x, eob, w, up, down, fn),
-          _by_hand(x, eob, w, up, down, act))
-    got = jax.grad(kernels, (0, 1, 2, 3))(x, w, up, down)
-    _near(got, jax.grad(composed, (0, 1, 2, 3))(x, w, up, down))
-    _near(got, jax.grad(plain, (0, 1, 2, 3))(x, w, up, down))
+    near(jax.jit(lambda *a: G.grouped_mlp(*a, fn))(x, eob, w, up, down),
+         jax.jit(lambda *a: _by_hand(*a, act))(x, eob, w, up, down), 2e-2)
+    got, by_composition, by_hand = (
+        jax.jit(jax.grad(loss, (0, 1, 2, 3)))(x, w, up, down)
+        for loss in (kernels, composed, plain))
+    near(got, by_composition, 2e-2)
+    near(got, by_hand, 2e-2)
     # the expert no block is mapped to: exact zeros, not what the
     # kernel's unvisited tiles held; the empty blocks' rows likewise
     for dw in got[2:]:
@@ -121,7 +108,7 @@ def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
     """Whatever an unvisited tile of the output holds (here: what the
     interpreter left there), ``_dw`` returns zeros for that expert, and
     the last expert's sum includes the empty blocks' zeros."""
-    g, x = _rand(5, (8 * BLOCK, HIDDEN), (8 * BLOCK, WIDTH))
+    g, x = rand(5, (8 * BLOCK, HIDDEN), (8 * BLOCK, WIDTH), dtype=BF)
     eob = jnp.array(EXPERT_OF_BLOCK, jnp.int32)
     dw = G._dw(g, x, eob, 4)
     want = jnp.stack([
@@ -129,7 +116,7 @@ def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
              @ x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
              for b, e in enumerate(EXPERT_OF_BLOCK) if e == held),
             jnp.zeros((HIDDEN, WIDTH), F32)) for held in range(4)])
-    _near(dw, want, 1e-2)
+    near(dw, want, 1e-2)
     assert float(jnp.max(jnp.abs(dw[2].astype(F32)))) == 0.0
     np.testing.assert_array_equal(np.asarray(G.visited(eob, 4)),
                                   [True, True, False, True])
@@ -140,10 +127,9 @@ def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
 # ---------------------------------------------------------------------------
 def _layer(seed, act, tokens=256, routed=16, held=4, offset=4):
     mul = ACTS[act][1]
-    x, up, down = _rand(seed, (tokens, HIDDEN), (held, mul * WIDTH, HIDDEN),
-                        (held, HIDDEN, WIDTH), scale=0.3)
-    r = 0.3 * jax.random.normal(jax.random.key(seed + 7), (routed, HIDDEN),
-                                F32)
+    x, up, down = rand(seed, (tokens, HIDDEN), (held, mul * WIDTH, HIDDEN),
+                       (held, HIDDEN, WIDTH), scale=0.3, dtype=BF)
+    r = normal(jax.random.key(seed + 7), (routed, HIDDEN), scale=0.3)
     return x, r, up, down, offset
 
 
@@ -195,13 +181,18 @@ def test_expert_layer_by_the_kernels(interpreted, monkeypatch, case, act):
     x, r, up, down, offset = _layer(11, act)
     bias = CASES[case]()
 
-    def loss(fn):
-        (cot,) = _rand(13, x.shape)
+    (cot,) = rand(13, x.shape, dtype=BF)
 
+    def loss(fn):
         def of(x, r, up, down):
-            return jnp.sum(fn(x, r, bias, up, down, act, offset)[0]
-                           * cot.astype(F32))
-        return of
+            y, rows = fn(x, r, bias, up, down, act, offset)
+            return jnp.sum(y * cot.astype(F32)), (y, rows)
+        return jax.value_and_grad(of, (0, 1, 2, 3), has_aux=True)
+
+    def run(fn):
+        """(y, rows), the gradients: traced now, one compiled program."""
+        (_, out), grads = jax.jit(loss(fn))(*args)
+        return out, grads
 
     def op(*a):
         return _experts(*a)
@@ -210,22 +201,22 @@ def test_expert_layer_by_the_kernels(interpreted, monkeypatch, case, act):
         return _reference(*a), None
 
     args = (x, r, up, down)
-    assert _pallas_calls(jax.grad(loss(op), (0, 1, 2, 3)), *args) > 0
-    y, rows = _experts(x, r, bias, up, down, act, offset)
-    got = jax.grad(loss(op), (0, 1, 2, 3))(*args)
-    _near(y, ref(x, r, bias, up, down, act, offset)[0])
-    _near(got, jax.grad(loss(ref), (0, 1, 2, 3))(*args), 3e-2)
+    assert _pallas_calls(loss(op), *args) > 0
+    (y, rows), got = run(op)
+    (y_ref, _), want = run(ref)
+    near(y, y_ref, 2e-2)
+    near(got, want, 3e-2)
     # the composition on the same call: the same rows counted, numbers
     # and gradients within bf16 of each other
     # (the slot sum's kernel, ops/pallas_moe_rows.py, forks on its own
     # predicate: it stands down with them here)
     monkeypatch.setattr(G, "grouped_mlp_available", lambda *a: False)
     monkeypatch.setattr(pallas_moe_rows, "sum_available", lambda *a: False)
-    assert _pallas_calls(jax.grad(loss(op), (0, 1, 2, 3)), *args) == 0
-    y_xla, rows_xla = _experts(x, r, bias, up, down, act, offset)
+    assert _pallas_calls(loss(op), *args) == 0
+    (y_xla, rows_xla), got_xla = run(op)
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_xla))
-    _near(y, y_xla)
-    _near(got, jax.grad(loss(op), (0, 1, 2, 3))(*args), 3e-2)
+    near(y, y_xla, 2e-2)
+    near(got, got_xla, 3e-2)
     counts = np.asarray(rows[0])
     if case == "one_draws_most":
         assert counts[1] > 0.8 * x.shape[0]
@@ -251,8 +242,10 @@ def test_overflowing_routing_still_takes_the_dense_product(interpreted, act):
 
     text = str(jax.make_jaxpr(fn)(x))
     assert "pallas_call" in text and "cond" in text
-    y, rows = _experts(x, r, bias, up, down, act, offset, **kw)
-    _near(y, _reference(x, r, bias, up, down, act, offset))
+    y, rows = jax.jit(lambda x: _experts(x, r, bias, up, down, act, offset,
+                                         **kw))(x)
+    near(y, jax.jit(lambda x: _reference(x, r, bias, up, down, act,
+                                         offset))(x), 2e-2)
     np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
     assert float(rows[0][1]) > 384      # it did overflow the 384 rows
 

@@ -5,7 +5,7 @@ are exact in either order at these magnitudes); ``_gather_rows`` /
 ``_sum_slots`` as each other's transposes with the kernel on one side;
 the expert layer end to end on both branches of ``fits``; what stands
 the kernel down; the counter. What Mosaic makes of the real shapes is
-``tests/test_chip_compile.py``'s to say."""
+``tests/test_chip_compile_*.py``'s to say."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,8 +14,8 @@ import pytest
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, pallas_common
 from mxnet_tpu.ops import pallas_moe_rows as R
+from numerics import BF, F32, jitted, near, normal, value_and_grads
 
-F32, BF = jnp.float32, jnp.bfloat16
 COUNTER = "mx_moe_rows_path_total"
 
 
@@ -39,15 +39,21 @@ def _routing(seed, tokens, hidden, routed, held, top_k, bias=None,
     ``_moe_experts`` lays the buffer out for the first ``held`` of
     ``routed`` experts."""
     kx, kr = jax.random.split(jax.random.key(seed))
-    x = jax.random.normal(kx, (tokens, hidden), F32).astype(BF)
-    r = 0.3 * jax.random.normal(kr, (routed, hidden), F32)
-    idx, _ = D._route(x, r, bias, top_k, 1.0, True, "softmax")
+    x = normal(kx, (tokens, hidden), BF)
+    r = normal(kr, (routed, hidden), scale=0.3)
     block, blocks, _ = D._buffer(tokens, top_k, held, routed, capacity_factor)
     cap = blocks * block
-    row, _, _, fits = D._slots_to_rows(idx < held, idx, held, cap, block)
-    slots = jnp.broadcast_to(jnp.arange(tokens)[:, None], row.shape)
-    token_of_row = jnp.full((cap + 1,), tokens, jnp.int32) \
-        .at[row.reshape(-1)].set(slots.reshape(-1))[:-1]
+
+    @jax.jit
+    def lay_out(x, r, bias):
+        idx, _ = D._route(x, r, bias, top_k, 1.0, True, "softmax")
+        row, _, _, fits = D._slots_to_rows(idx < held, idx, held, cap, block)
+        slots = jnp.broadcast_to(jnp.arange(tokens)[:, None], row.shape)
+        token_of_row = jnp.full((cap + 1,), tokens, jnp.int32) \
+            .at[row.reshape(-1)].set(slots.reshape(-1))[:-1]
+        return token_of_row, row, fits
+
+    token_of_row, row, fits = lay_out(x, r, bias)
     return x, token_of_row, row, cap, bool(fits)
 
 
@@ -78,18 +84,17 @@ def test_the_kernel_sums_what_xla_sums(interpreted, case):
     _, token_of_row, row, cap, fits = _routing(3, tokens, hidden, routed,
                                                held, top_k, bias)
     assert fits
-    rows, = [jax.random.normal(jax.random.key(5), (cap, hidden), F32)
-             .astype(BF)]
+    rows = normal(jax.random.key(5), (cap, hidden), BF)
     assert R.sum_available(rows, top_k, tokens)
     # slots one past the end: wherever not every expert is held
     assert (int(jnp.sum(row == cap)) > 0) == (held < routed)
     if case == "all rows empty":
         assert int(jnp.sum(row < cap)) == 0
-    got = R.sum_slots(rows, token_of_row, row)
+    got = jitted(R.sum_slots)(rows, token_of_row, row)
     assert got.dtype == BF and got.shape == (tokens, hidden)
     np.testing.assert_array_equal(
         np.asarray(got, np.float32),
-        np.asarray(_xla_sum(rows, token_of_row, row), np.float32))
+        np.asarray(jitted(_xla_sum)(rows, token_of_row, row), np.float32))
 
 
 @pytest.mark.parametrize("window, group", [(16, 4), (32, 2), (64, 3)])
@@ -102,21 +107,23 @@ def test_windows_and_groups_of_other_sizes(interpreted, monkeypatch, window,
     monkeypatch.setattr(R, "_TOKENS", 128)
     R._sum_call.cache_clear()
     _, token_of_row, row, cap, _ = _routing(7, 512, 128, 16, 4, 8)
-    rows = jax.random.normal(jax.random.key(9), (cap, 128), F32).astype(BF)
-    order, count = R._windows(token_of_row, 512, 128)
+    rows = normal(jax.random.key(9), (cap, 128), BF)
+    order, count = jax.jit(lambda t: R._windows(t, 512, 128))(token_of_row)
     assert order.shape == (4 * cap // window,)
     assert int(count.max()) > group and any(int(c) % group for c in count)
-    got = R.sum_slots(rows, token_of_row, row)
+    got = jitted(R.sum_slots)(rows, token_of_row, row)
+    # the kernel was built at this window and group, not found traced
+    assert R._sum_call.cache_info().currsize == 1
     R._sum_call.cache_clear()
     np.testing.assert_array_equal(
         np.asarray(got, np.float32),
-        np.asarray(_xla_sum(rows, token_of_row, row), np.float32))
+        np.asarray(jitted(_xla_sum)(rows, token_of_row, row), np.float32))
 
 
 def test_the_windows_listed_are_the_windows_needed(interpreted):
     _, token_of_row, row, cap, _ = _routing(11, 512, 128, 16, 4, 8)
     tokens = 128
-    order, count = R._windows(token_of_row, 512, tokens)
+    order, count = jax.jit(lambda t: R._windows(t, 512, tokens))(token_of_row)
     order = np.asarray(order).reshape(512 // tokens, cap // R._WINDOW)
     row = np.asarray(row)
     for b in range(512 // tokens):
@@ -135,10 +142,10 @@ def test_rows_in_any_order_are_still_summed(interpreted):
     where = jnp.argsort(shuffle)            # old row -> new row
     token_of_row = token_of_row[shuffle]
     row = jnp.where(row < cap, where[jnp.minimum(row, cap - 1)], cap)
-    rows = jax.random.normal(jax.random.key(9), (cap, 128), F32).astype(BF)
+    rows = normal(jax.random.key(9), (cap, 128), BF)
     np.testing.assert_array_equal(
-        np.asarray(R.sum_slots(rows, token_of_row, row), np.float32),
-        np.asarray(_xla_sum(rows, token_of_row, row), np.float32))
+        np.asarray(jitted(R.sum_slots)(rows, token_of_row, row), np.float32),
+        np.asarray(jitted(_xla_sum)(rows, token_of_row, row), np.float32))
 
 
 @pytest.mark.parametrize("case", ["mellum2 (16 of 64, top 8)",
@@ -151,31 +158,34 @@ def test_each_is_the_other_s_transpose(interpreted, monkeypatch, case):
     tokens, hidden, routed, held, top_k, bias = CASES[case]
     x, token_of_row, row, cap, _ = _routing(13, tokens, hidden, routed, held,
                                             top_k, bias)
-    rows = jax.random.normal(jax.random.key(15), (cap, hidden), F32) \
-        .astype(BF)
+    rows = normal(jax.random.key(15), (cap, hidden), BF)
+
+    def same(got, want):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want.astype(BF), np.float32))
+
+    def pulled(fn, primal, cot):
+        return jax.vjp(fn, primal)[1](cot)[0]
+
+    def sum_slots(r):
+        return D._sum_slots(r, token_of_row, row, True)
+
+    assert "pallas_call" in str(jax.make_jaxpr(sum_slots)(rows))
+    y, pull = value_and_grads(sum_slots, rows, cot=x)
+    same(y, jitted(_xla_sum)(rows, token_of_row, row))
+    same(pull, value_and_grads(
+        lambda r: _xla_sum(r, token_of_row, row).astype(F32),
+        rows.astype(F32), cot=x)[1])
+
+    def gather_rows(a):
+        return D._gather_rows(a, token_of_row, row, True)
+
     assert "pallas_call" in str(jax.make_jaxpr(
-        lambda r: D._sum_slots(r, token_of_row, row, True))(rows))
-    y, pull = jax.vjp(lambda r: D._sum_slots(r, token_of_row, row, True),
-                      rows)
-    np.testing.assert_array_equal(
-        np.asarray(y, np.float32),
-        np.asarray(_xla_sum(rows, token_of_row, row), np.float32))
-    _, want = jax.vjp(lambda r: _xla_sum(r, token_of_row, row)
-                      .astype(F32), rows.astype(F32))
-    np.testing.assert_array_equal(
-        np.asarray(pull(x)[0], np.float32),
-        np.asarray(want(x.astype(F32))[0].astype(BF), np.float32))
-    xr, pull = jax.vjp(lambda a: D._gather_rows(a, token_of_row, row, True),
-                       x)
-    assert "pallas_call" in str(jax.make_jaxpr(pull)(rows))
-    np.testing.assert_array_equal(
-        np.asarray(xr, np.float32),
-        np.asarray(_xla_gather(x, token_of_row, row), np.float32))
-    _, want = jax.vjp(lambda a: _xla_gather(a, token_of_row, row),
-                      x.astype(F32))
-    np.testing.assert_array_equal(
-        np.asarray(pull(rows)[0], np.float32),
-        np.asarray(want(rows.astype(F32))[0].astype(BF), np.float32))
+        lambda a, c: pulled(gather_rows, a, c))(x, rows))
+    xr, pull = value_and_grads(gather_rows, x, cot=rows)
+    same(xr, jitted(_xla_gather)(x, token_of_row, row))
+    same(pull, value_and_grads(lambda a: _xla_gather(a, token_of_row, row),
+                               x.astype(F32), cot=rows)[1])
 
 
 # ---------------------------------------------------------------------------
@@ -183,21 +193,11 @@ def test_each_is_the_other_s_transpose(interpreted, monkeypatch, case):
 # ---------------------------------------------------------------------------
 def _layer(seed, tokens=256, hidden=128, width=128, routed=16, held=4):
     keys = jax.random.split(jax.random.key(seed), 4)
-    x = (0.3 * jax.random.normal(keys[0], (tokens, hidden), F32)).astype(BF)
-    up = (0.3 * jax.random.normal(keys[1], (held, 2 * width, hidden), F32)) \
-        .astype(BF)
-    down = (0.3 * jax.random.normal(keys[2], (held, hidden, width), F32)) \
-        .astype(BF)
-    r = 0.3 * jax.random.normal(keys[3], (routed, hidden), F32)
+    x = normal(keys[0], (tokens, hidden), BF, 0.3)
+    up = normal(keys[1], (held, 2 * width, hidden), BF, 0.3)
+    down = normal(keys[2], (held, hidden, width), BF, 0.3)
+    r = normal(keys[3], (routed, hidden), scale=0.3)
     return x, r, up, down
-
-
-def _near(got, want, rel=2e-2):
-    for g, w in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
-        assert g.shape == w.shape and np.all(np.isfinite(g))
-        assert np.abs(g - w).max() <= rel * max(np.abs(w).max(), 1e-6)
 
 
 @pytest.mark.parametrize("branch", ["sorted", "dense"])
@@ -212,7 +212,7 @@ def test_expert_layer_with_the_kernel_in_it(interpreted, monkeypatch, branch,
     x, r, up, down = _layer(17 + top_k)
     bias = _favouring(5, 10.0, 16) if branch == "dense" else None
     kw = dict(capacity_factor=0.5) if branch == "dense" else {}
-    cot = jax.random.normal(jax.random.key(19), x.shape, F32)
+    cot = normal(jax.random.key(19), x.shape)
 
     def loss(x, r, up, down):
         y, rows = D._moe_experts(
@@ -227,16 +227,16 @@ def test_expert_layer_with_the_kernel_in_it(interpreted, monkeypatch, branch,
     args = (x, r, up, down)
     text = str(jax.make_jaxpr(grad())(*args))
     assert "pallas_moe_rows_sum" in text and "cond" in text
-    (value, rows), got = grad()(*args)
+    (value, rows), got = jax.jit(grad())(*args)
     # nearly every token chooses the favoured expert, or about top_k / 16
     assert (float(rows[0].max()) > 0.9 * 256) == (branch == "dense")
     np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[1]))
     monkeypatch.setattr(R, "sum_available", lambda *a: False)
     assert "pallas_moe_rows_sum" not in str(jax.make_jaxpr(grad())(*args))
-    (value_xla, rows_xla), got_xla = grad()(*args)
+    (value_xla, rows_xla), got_xla = jax.jit(grad())(*args)
     np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_xla))
-    _near(value, value_xla, 1e-2)
-    _near(got, got_xla, 3e-2)
+    near(value, value_xla, 1e-2)
+    near(got, got_xla, 3e-2)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ fp32, the output_mean_var path, and the MXNET_PALLAS_LAYERNORM off-path.
 
 Runs in Pallas interpret mode (pallas_interpret fixture): numerics are
 checked against the interpreter wherever the suite runs. Whether the
-kernels compile for the chip is tests/test_chip_compile.py's question.
+kernels compile for the chip is tests/test_chip_compile_*.py's question.
 """
 import numpy as np
 import pytest
@@ -16,6 +16,7 @@ from mxnet_tpu.ops.nn import _ln_fused
 from mxnet_tpu.ops.pallas_norm import (pallas_layer_norm,
                                        pallas_ln_available)
 from mxnet_tpu.test_utils import check_numeric_gradient
+from numerics import jitted
 
 
 def _data(rng, shape, dtype):
@@ -53,17 +54,18 @@ def test_ln_kernel_matches_xla_reference(pallas_interpret, shape, dtype):
     # bf16 outputs can differ in the last mantissa bit between the two
     # schedules; f32 only by reduction order
     bf16 = jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16)
-    np.testing.assert_allclose(float(f_pallas(x, g, b)),
-                               float(f_xla(x, g, b)),
+    (sum_p, g1), (sum_x, g2) = (
+        jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(x, g, b)
+        for f in (f_pallas, f_xla))
+    np.testing.assert_allclose(float(sum_p), float(sum_x),
                                rtol=5e-3 if bf16 else 2e-4)
-    out_p = np.asarray(pallas_layer_norm(x, g, b, eps=1e-5), np.float32)
-    out_x = np.asarray(_ln_fused(ax, len(shape), 1e-5)(x, g, b),
+    out_p = np.asarray(jax.jit(lambda *a: pallas_layer_norm(*a, eps=1e-5))(
+        x, g, b), np.float32)
+    out_x = np.asarray(jitted(_ln_fused(ax, len(shape), 1e-5))(x, g, b),
                        np.float32)
     np.testing.assert_allclose(out_p, out_x,
                                rtol=1e-2 if bf16 else 2e-5,
                                atol=1e-2 if bf16 else 2e-5)
-    g1 = jax.grad(f_pallas, argnums=(0, 1, 2))(x, g, b)
-    g2 = jax.grad(f_xla, argnums=(0, 1, 2))(x, g, b)
     for a, ref, nm in zip(g1, g2, "xgb"):
         a = np.asarray(a, np.float32)
         ref = np.asarray(ref, np.float32)
@@ -86,8 +88,8 @@ def test_ln_kernel_multiblock_accumulation(pallas_interpret):
     def f_xla(x, g, b):
         return jnp.sum(_ln_fused(1, 2, 1e-5)(x, g, b))
 
-    g1 = jax.grad(f_pallas, argnums=(0, 1, 2))(x, g, b)
-    g2 = jax.grad(f_xla, argnums=(0, 1, 2))(x, g, b)
+    g1 = jax.jit(jax.grad(f_pallas, argnums=(0, 1, 2)))(x, g, b)
+    g2 = jax.jit(jax.grad(f_xla, argnums=(0, 1, 2)))(x, g, b)
     for a, ref in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
@@ -121,7 +123,7 @@ def test_ln_flag_off_reproduces_xla_path(pallas_interpret, monkeypatch):
     off = nd.LayerNorm(x, g, b, axis=-1, eps=1e-5).asnumpy()
     # the eager op path runs _ln_fused under jit — compare against the
     # identically-jitted reference for bitwise equality
-    ref = np.asarray(jax.jit(_ln_fused(1, 2, 1e-5))(
+    ref = np.asarray(jitted(_ln_fused(1, 2, 1e-5))(
         jnp.asarray(x.asnumpy()), jnp.asarray(g.asnumpy()),
         jnp.asarray(b.asnumpy())))
     np.testing.assert_array_equal(off, ref)

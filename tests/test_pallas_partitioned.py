@@ -7,7 +7,7 @@ kernel gives on the whole array. The layer-norm and the two epilogue
 kernels, which a shard each lost to XLA's fusions on the chip (PERF.md
 section 6, PR 45), and every decoder kernel keep their compositions.
 Four host devices, kernels interpreted; what Mosaic and the v5e:2x2
-compiler say of the same calls is ``tests/test_chip_compile.py``'s, the
+compiler say of the same calls is ``tests/test_chip_compile_*.py``'s, the
 in-kernel PRNG's masks ``chip_smoke.py --chips 4``'s.
 """
 import numpy as np
@@ -40,7 +40,7 @@ def _spec(dim):
     return P(*([None] * dim + ["dp"]))
 
 
-def _rand(shape, dtype, seed=0):
+def _randn(shape, dtype, seed=0):
     x = np.random.RandomState(seed).standard_normal(shape)
     return jnp.asarray(x, dtype)
 
@@ -55,15 +55,6 @@ def _sharded(fn, mesh, operands, dims, batch=BATCH, axes="dp"):
         mesh, P() if d is None else _spec(d)))
         for x, d in zip(operands, dims)]
     return jax.jit(scoped), placed
-
-
-def _value_and_grads(op, n_args):
-    """(out, d args) of sum(op(*args) * cotangent), cotangent last."""
-    def f(*a):
-        out = op(*a[:n_args])
-        return jnp.sum((out * a[n_args]).astype(jnp.float32)), out
-    return lambda *a: jax.value_and_grad(
-        f, argnums=tuple(range(n_args)), has_aux=True)(*a)
 
 
 class _Counts:
@@ -109,9 +100,9 @@ def test_attention_a_shard_is_the_kernel_on_the_whole_batch(length, p):
     forward's mask, from its own slice of the seeds."""
     plan = pallas_attention.selfatt_plan(length, HEADS, BATCH, p,
                                          dtype=jnp.bfloat16, head_dim=D)
-    qkv = _rand((length, BATCH, 3 * HEADS * D), jnp.bfloat16)
+    qkv = _randn((length, BATCH, 3 * HEADS * D), jnp.bfloat16)
     seeds = jnp.arange(1, plan["n_blocks"] + 1, dtype=jnp.int32) * 7919
-    cot = _rand((length, BATCH, HEADS * D), jnp.bfloat16, 3)
+    cot = _randn((length, BATCH, HEADS * D), jnp.bfloat16, 3)
     op = _attention(p, plan["bbh"])
 
     def step(qkv, seeds, cot):
@@ -135,7 +126,7 @@ def test_no_two_shards_of_the_op_draw_one_attention_mask():
     is their masks. The op draws one seed a (sample, head block) of the
     WHOLE batch and plans the block for a shard's share of it."""
     op = get_op("_contrib_sdp_selfatt").impl
-    one = _rand((OTHER, 1, 3 * HEADS * D), jnp.bfloat16)
+    one = _randn((OTHER, 1, 3 * HEADS * D), jnp.bfloat16)
     qkv = jnp.tile(one, (1, BATCH, 1))
 
     def fwd(qkv):
@@ -286,11 +277,11 @@ def _program_cases():
     plan = pallas_attention.selfatt_plan(OTHER, HEADS, BATCH, 0.0,
                                          dtype=jnp.bfloat16, head_dim=D)
     return {
-        "dropout": (_dropout, (_rand(_shape("LNC"), jnp.bfloat16),), (1,)),
-        "dropout-BTC": (_dropout, (_rand(_shape("BTC"), jnp.bfloat16),),
+        "dropout": (_dropout, (_randn(_shape("LNC"), jnp.bfloat16),), (1,)),
+        "dropout-BTC": (_dropout, (_randn(_shape("BTC"), jnp.bfloat16),),
                         (0,)),
         "attention": (_attention(0.0, plan["bbh"]), (
-            _rand((OTHER, BATCH, 3 * HEADS * D), jnp.bfloat16),
+            _randn((OTHER, BATCH, 3 * HEADS * D), jnp.bfloat16),
             jnp.zeros((plan["n_blocks"],), jnp.int32)), (1, 0)),
     }
 
@@ -370,7 +361,7 @@ def test_a_composition_op_under_the_scope_is_the_sum_gspmd_partitions():
     """The registered op on a batch that does not divide: the XLA
     composition, no ``shard_map`` in the program."""
     op = get_op("_contrib_sdp_selfatt").impl
-    qkv = _rand((OTHER, 6, 3 * HEADS * D), jnp.bfloat16)
+    qkv = _randn((OTHER, 6, 3 * HEADS * D), jnp.bfloat16)
     with auto_partitioned(_mesh(), batch=("dp", 6)):
         ops = _ops_of(jax.make_jaxpr(lambda q: op(
             jax.random.key(0), q, heads=HEADS, dropout=0.1,
@@ -380,7 +371,7 @@ def test_a_composition_op_under_the_scope_is_the_sum_gspmd_partitions():
 
 
 def test_a_kernel_called_past_its_availability_says_so():
-    qkv = _rand((OTHER, 6, 3 * HEADS * D), jnp.bfloat16)
+    qkv = _randn((OTHER, 6, 3 * HEADS * D), jnp.bfloat16)
     with auto_partitioned(_mesh(), batch=("dp", 6)):
         with pytest.raises(ValueError, match="a shard at a time"):
             pallas_attention.flash_selfatt(
@@ -407,8 +398,8 @@ def test_the_kernels_that_lost_keep_their_compositions_on_a_mesh(op, n_args):
     shard each they lost to XLA's own fusions in the dp=4 BERT step
     (PERF.md section 6, PR 45). One device: the kernel, as ever."""
     shape = _shape("LNC")
-    args = [_rand(shape, jnp.bfloat16), _rand((C,), jnp.bfloat16, 1),
-            _rand(shape if op != "LayerNorm" else (C,), jnp.bfloat16, 2)]
+    args = [_randn(shape, jnp.bfloat16), _randn((C,), jnp.bfloat16, 1),
+            _randn(shape if op != "LayerNorm" else (C,), jnp.bfloat16, 2)]
     impl = get_op(op).impl
 
     def ops():

@@ -6,7 +6,7 @@ rows whose selected keys miss whole tiles; the causal limit; causality;
 what the mixer keeps; and the ladder by which
 ``decoder_ops._sparse_attend`` picks a form, each rung counted in
 ``mx_attn_sparse_path_total``. What Mosaic makes of the kernels at the
-published widths is tests/test_chip_compile.py's."""
+published widths is tests/test_chip_compile_*.py's."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,9 +17,9 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.ops import (decoder_ops as D, get_op, pallas_causal_gqa as P,
                            pallas_common, pallas_sparse_gqa as S)
 from mxnet_tpu.ops.pallas_common import auto_partitioned
-from test_decoder_ops import KREF
+from numerics import BF, F32, near, normal, reference, value_and_grads
 
-F32, BF = jnp.float32, jnp.bfloat16
+KREF = reference("keye_vl2_30b_a3b")
 COUNTER = "mx_attn_sparse_path_total"
 TILE = 128
 
@@ -40,10 +40,8 @@ def _inputs(seed, length, heads, kv, d=128, batch=1, dtype=BF, ih=2, idim=8):
               (batch, length, kv, d), (batch, length, ih, idim),
               (batch, length, idim), (batch, length, ih),
               (batch, length, heads, d)]
-    q, k, v, iq, ik, iw, cot = (jax.random.normal(key, s, F32)
-                                for key, s in zip(keys, shapes))
-    return [t.astype(dtype) for t in (q, k, v, iq, ik)] + [iw] \
-        + [cot.astype(dtype)]
+    return [normal(key, s, F32 if i == 5 else dtype)
+            for i, (key, s) in enumerate(zip(keys, shapes))]
 
 
 def _reference(q, k, v, iq, ik, iw, top_k):
@@ -62,26 +60,11 @@ def _reference(q, k, v, iq, ik, iw, top_k):
             jnp.sum(keep, dtype=F32) / (b * length))
 
 
-def _value_and_grads(form, args, cot, top_k):
+def _outputs_and_grads(form, args, cot, top_k):
     """[context, index loss, keys a query, six gradients] of the context
     under ``cot`` plus three times the index loss, float32."""
-    def both(*a):
-        ctx, loss, kept = form(*a, top_k)
-        return jnp.sum(ctx.astype(F32) * cot.astype(F32)) + 3.0 * loss, \
-            (ctx, loss, kept)
-
-    (_, out), grads = jax.jit(jax.value_and_grad(
-        both, argnums=tuple(range(6)), has_aux=True))(*args)
-    return [t.astype(F32) for t in out + grads]
-
-
-def _close(got, want, rel):
-    """Each array to within ``rel`` of the wanted one's largest entry
-    (bf16 results of sums taken in different orders)."""
-    for g, w in zip(got, want):
-        assert bool(jnp.all(jnp.isfinite(g)))
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
-                                   atol=rel * float(jnp.max(jnp.abs(w))))
+    return value_and_grads(lambda *a: form(*a, top_k), *args,
+                           cot=(cot, 3.0, 0.0))
 
 
 @pytest.mark.parametrize("heads, kv", [(2, 2), (8, 1), (16, 1)],
@@ -92,18 +75,18 @@ def _close(got, want, rel):
 def test_kernels_match_the_composition_and_the_reference(length, top_k,
                                                          heads, kv):
     *args, cot = _inputs(length + heads + top_k, length, heads, kv)
-    got = _value_and_grads(D._sparse_gqa_flash, args, cot, top_k)
+    got = _outputs_and_grads(D._sparse_gqa_flash, args, cot, top_k)
     # the composition on the same bf16 inputs: the same selected set bit
     # for bit, two roundings of one sum elsewhere
-    want = _value_and_grads(D._sparse_gqa, args, cot, top_k)
+    want = _outputs_and_grads(D._sparse_gqa, args, cot, top_k)
     assert float(got[2]) == float(want[2]) == pytest.approx(
         sum(min(t + 1, top_k) for t in range(length)) / length)
-    _close(got, want, 2e-2)
+    near(got, want, 2e-2)
     assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-3)
     # the plain float32 reference on the same values
-    ref = _value_and_grads(_reference, [t.astype(F32) for t in args], cot,
+    ref = _outputs_and_grads(_reference, [t.astype(F32) for t in args], cot,
                            top_k)
-    _close(got, ref, 2e-2)
+    near(got, ref, 2e-2)
     assert float(got[1]) == pytest.approx(float(ref[1]), rel=2e-3)
 
 
@@ -143,22 +126,25 @@ def test_rows_that_select_nothing_in_a_tile_stay_finite_and_right():
     keep = jnp.where((rows >= 340), cols < TILE, keep)
     keep = jnp.broadcast_to((keep | (rows == cols)) & seen,
                             (2, length, length))
-    blocks = _kernel_mask(keep)
-    mask = S.mask_blocks(blocks, length)
 
-    ctx, lse = S.attend(q, k, v, mask, TILE)
-    dq, dk, dv = S.attend_bwd(q, k, v, mask, ctx, lse, cot, TILE)
-    probs = jnp.concatenate([
-        jnp.pad(jnp.swapaxes(S.head_mean_probs(q, k, blk, lse, i, TILE), 1, 2),
-                ((0, 0), (0, 0), (0, length - blk.shape[1])))
-        for i, blk in enumerate(blocks)], axis=1)
+    @jax.jit
+    def kernels(q, k, v, cot):
+        blocks = _kernel_mask(keep)
+        mask = S.mask_blocks(blocks, length)
+        ctx, lse = S.attend(q, k, v, mask, TILE)
+        probs = jnp.concatenate([
+            jnp.pad(jnp.swapaxes(S.head_mean_probs(q, k, blk, lse, i, TILE),
+                                 1, 2),
+                    ((0, 0), (0, 0), (0, length - blk.shape[1])))
+            for i, blk in enumerate(blocks)], axis=1)
+        return (ctx, probs, *S.attend_bwd(q, k, v, mask, ctx, lse, cot, TILE),
+                lse)
 
-    f32 = [t.astype(F32) for t in (q, k, v)]
-    (want_ctx, want_probs), vjp = jax.vjp(
-        lambda *a: _masked_reference(*a, keep), *f32)
-    want_grads = vjp((cot.astype(F32), jnp.zeros_like(want_probs)))
-    _close([t.astype(F32) for t in (ctx, probs, dq, dk, dv)],
-           [want_ctx, want_probs, *want_grads], 2e-2)
+    ctx, probs, dq, dk, dv, lse = kernels(q, k, v, cot)
+    near([t.astype(F32) for t in (ctx, probs, dq, dk, dv)],
+         value_and_grads(lambda *a: _masked_reference(*a, keep),
+                         *(t.astype(F32) for t in (q, k, v)),
+                         cot=(cot, 0.0)), 2e-2)
     assert bool(jnp.all(jnp.isfinite(lse)))
     # a pair that is not selected has probability 0, exactly
     assert float(jnp.max(jnp.where(keep, 0.0, probs))) == 0.0
@@ -169,13 +155,13 @@ def test_rows_that_see_no_more_than_top_k_give_the_causal_kernel_s_values():
     the kernels give ``flash_causal_gqa``'s values."""
     length = 3 * TILE
     *args, cot = _inputs(13, length, 4, 2)
-    got = _value_and_grads(D._sparse_gqa_flash, args, cot, length)
+    got = _outputs_and_grads(D._sparse_gqa_flash, args, cot, length)
     assert float(got[2]) == (length + 1) / 2
-    out, vjp = jax.vjp(lambda *a: P.flash_causal_gqa(*a, TILE), *args[:3])
     # (one entry in 196,608 a bf16 rounding apart: XLA's CPU fusions of
     # the two kernels' tile code differ)
-    _close([got[0]] + got[3:6],
-           [t.astype(F32) for t in (out,) + vjp(cot)], 1e-3)
+    near([got[0]] + got[3:6],
+         value_and_grads(lambda *a: P.flash_causal_gqa(*a, TILE), *args[:3],
+                         cot=cot), 1e-3)
 
 
 @pytest.mark.parametrize("t", [0, 127, 128, 200, 300])
@@ -348,7 +334,7 @@ def test_the_op_on_the_kernel_path_gives_the_composition_s_values(
     monkeypatch.setattr(S, "sparse_gqa_available", lambda *a: False)
     want = run()
     np.testing.assert_array_equal(got[2][0], want[2][0])   # keys a query
-    _close(got, want, 3e-2)
+    near(got, want, 3e-2)
 
 
 def test_the_mixer_on_the_kernel_path_keeps_thresholds_context_and_lse(

@@ -7,7 +7,7 @@ composition to) and in bf16 (what the predicate admits, to the tolerance
 a bf16 path is held to there); a tail that is no whole chunk; the ladder
 by which ``decoder_ops._scan`` picks a form, each rung counted in
 ``mx_mamba2_ssd_path_total``. What Mosaic makes of the kernels at the
-published widths is tests/test_chip_compile.py's."""
+published widths is tests/test_chip_compile_*.py's."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,9 +17,10 @@ from jax.sharding import Mesh
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, get_op, pallas_ssd as P
 from mxnet_tpu.ops.pallas_common import auto_partitioned
-from test_decoder_ops import REF, _close, _near
+from numerics import (BF, F32, close, jitted, near, normal, reference,
+                      value_and_grads)
 
-F32, BF = jnp.float32, jnp.bfloat16
+REF = reference("nemotron_twotower_30b_a3b")
 COUNTER = "mx_mamba2_ssd_path_total"
 CHUNK = 128
 # two chunks, and three with a tail of 37 that is padded with dt = 0
@@ -30,23 +31,12 @@ def _args(seed, length, batch=2, heads=8, p=64, groups=2, n=128, dtype=BF):
     """(x, dt, a, B, C, d, a cotangent of y): x / B / C in ``dtype``,
     the rest float32, as ``_mamba2`` hands them over."""
     keys = jax.random.split(jax.random.key(seed), 7)
-    x, cot = (jax.random.normal(k, (batch, length, heads, p), F32)
-              for k in keys[:2])
-    dt = jax.nn.softplus(
-        jax.random.normal(keys[2], (batch, length, heads), F32) - 2.0)
-    a = -jnp.exp(jax.random.normal(keys[3], (heads,), F32))
-    bm, cm = (0.3 * jax.random.normal(k, (batch, length, groups, n), F32)
+    x, cot = (normal(k, (batch, length, heads, p), dtype) for k in keys[:2])
+    dt = jax.nn.softplus(normal(keys[2], (batch, length, heads)) - 2.0)
+    a = -jnp.exp(normal(keys[3], (heads,)))
+    bm, cm = (normal(k, (batch, length, groups, n), dtype, 0.3)
               for k in keys[4:6])
-    d = jax.random.normal(keys[6], (heads,), F32)
-    return [x.astype(dtype), dt, a, bm.astype(dtype), cm.astype(dtype), d,
-            cot.astype(dtype)]
-
-
-def _value_and_grads(fn, *args):
-    """[y, dx, d dt, da, dB, dC, dd] in float32."""
-    *inputs, cot = args
-    out, vjp = jax.vjp(fn, *inputs)
-    return [t.astype(F32) for t in (out,) + vjp(cot.astype(out.dtype))]
+    return [x, dt, a, bm, cm, normal(keys[6], (heads,)), cot]
 
 
 def _kernels(*a):
@@ -62,12 +52,13 @@ def test_float32_kernels_are_the_step_by_step_recurrence(length):
     """The mathematics alone (interpreted float32 products are exact):
     the kernels' forward and their hand-written backward against the
     recurrence and the composition, all six gradients."""
-    args = _args(length, length, dtype=F32)
-    got = _value_and_grads(_kernels, *args)
-    for want in (_value_and_grads(REF.recurrence, *args),
-                 _value_and_grads(_composition, *args)):
-        _near(got, want, 5e-5)
-        _close(got[0], want[0], 5e-5)
+    *args, cot = _args(length, length, dtype=F32)
+    # [y, dx, d dt, da, dB, dC, dd]
+    got = value_and_grads(_kernels, *args, cot=cot)
+    for want in (value_and_grads(REF.recurrence, *args, cot=cot),
+                 value_and_grads(_composition, *args, cot=cot)):
+        near(got, want, 5e-5)
+        close(got[0], want[0], 5e-5)
 
 
 @pytest.mark.parametrize("heads, p, groups, chunk", [
@@ -77,11 +68,11 @@ def test_other_widths_the_predicate_admits(heads, p, groups, chunk):
     """A head of a whole lane tile (a window is one head), four heads
     of 32 lanes a window, and a chunk of two lane tiles: the same
     numbers as the composition, in float32."""
-    args = _args(heads + p, 2 * chunk, 1, heads, p, groups, dtype=F32)
+    *args, cot = _args(heads + p, 2 * chunk, 1, heads, p, groups, dtype=F32)
     bf = [t.astype(BF) for t in args]
     assert P.ssd_available(bf[0], bf[3], bf[4], chunk)
-    _near(_value_and_grads(lambda *a: P.ssd_scan(*a, chunk), *args),
-          _value_and_grads(lambda *a: D._ssd(*a, chunk), *args), 5e-5)
+    near(value_and_grads(lambda *a: P.ssd_scan(*a, chunk), *args, cot=cot),
+         value_and_grads(lambda *a: D._ssd(*a, chunk), *args, cot=cot), 5e-5)
 
 
 @pytest.mark.parametrize("length", LENGTHS)
@@ -89,15 +80,16 @@ def test_bfloat16_kernels_stay_near_float32_and_the_composition(length):
     """What the predicate admits: bf16 x / B / C. Against the float32
     recurrence on the same rounded values, and against the composition
     on the same bf16 inputs (two roundings of one sum)."""
-    args = _args(length + 1, length)
+    *args, cot = _args(length + 1, length)
     assert P.ssd_available(args[0], args[3], args[4], CHUNK)
-    got = _value_and_grads(_kernels, *args)
+    got = value_and_grads(_kernels, *args, cot=cot)
     assert got[0].shape == args[0].shape
-    exact = _value_and_grads(REF.recurrence, *(t.astype(F32) for t in args))
-    composed = _value_and_grads(_composition, *args)
+    exact = value_and_grads(REF.recurrence, *(t.astype(F32) for t in args),
+                            cot=cot)
+    composed = value_and_grads(_composition, *args, cot=cot)
     for want in (exact, composed):
-        _near(got[:1], want[:1], 2e-2)
-        _near(got[1:], want[1:], 3e-2)
+        near(got[:1], want[:1], 2e-2)
+        near(got[1:], want[1:], 3e-2)
     # no further from float32 than the composition is, by the norm
     for g, c, w in zip(got, composed, exact):
         assert jnp.linalg.norm(g - w) <= 2 * jnp.linalg.norm(c - w) \
@@ -108,9 +100,10 @@ def test_a_step_after_position_t_never_reaches_output_t():
     x, dt, a, bm, cm, d, _ = _args(3, 384)
     t = 200
     later = (jnp.arange(384) > t)[None, :, None, None]
-    out = _kernels(x, dt, a, bm, cm, d)
-    moved = _kernels(jnp.where(later, x + 3, x), dt, a,
-                     jnp.where(later, bm - 2, bm), cm, d)
+    kernels = jitted(_kernels)
+    out = kernels(x, dt, a, bm, cm, d)
+    moved = kernels(jnp.where(later, x + 3, x), dt, a,
+                    jnp.where(later, bm - 2, bm), cm, d)
     np.testing.assert_array_equal(np.asarray(out[:, :t + 1], F32),
                                   np.asarray(moved[:, :t + 1], F32))
     assert not np.array_equal(np.asarray(out[:, t + 1:], F32),
@@ -220,8 +213,8 @@ def test_an_eligible_call_takes_the_kernels_and_is_counted_pallas(
 
 
 def test_the_op_on_the_kernel_path_gives_the_composition_s_values():
-    args = _args(5, 384 + 37)
-    got, want = (_value_and_grads(fn, *args)
+    *args, cot = _args(5, 384 + 37)
+    got, want = (value_and_grads(fn, *args, cot=cot)
                  for fn in (_scan_op, _composition))
-    _near(got[:1], want[:1], 2e-2)
-    _near(got[1:], want[1:], 3e-2)
+    near(got[:1], want[:1], 2e-2)
+    near(got[1:], want[1:], 3e-2)

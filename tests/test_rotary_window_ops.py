@@ -6,39 +6,23 @@ length), YaRN's angles against the written-out rule of the benchmark's
 reference (mxbench/reference/mellum2_12b_a2_5b.py), the rotary
 attention mixer of both kinds against that reference, what the traced
 programs of the older callers keep, and the expert product over a
-buffer of many blocks. (A file of its own beside tests/test_decoder_ops.py,
-whose helpers it borrows: that file alone is most of a test run's
-length.)"""
+buffer of many blocks."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mxbench import manifest
 from mxnet_tpu.ops import decoder_ops as D, get_op
-from test_decoder_ops import (KREF, _attention_ref, _close, _highest, _rand,
-                              _remat_count, _same_values_and_grads,
-                              _swiglu_weights)
+from numerics import (attention_ref, close, highest, jitted,  # noqa: F401
+                      rand, reference, remat_count, same_values_and_grads,
+                      swiglu_experts, value_and_grads, window_ref)
 
-F32 = jnp.float32
-
-
-MREF = manifest.load_module("reference", "mellum2_12b_a2_5b.py")
+KREF = reference("keye_vl2_30b_a3b")
+MREF = reference("mellum2_12b_a2_5b")
+pytestmark = pytest.mark.usefixtures("highest")
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
         "original_max_position_embeddings": 8192, "beta_fast": 32,
         "beta_slow": 1, "attention_factor": 1.2772588722239782}
-
-
-def _window_ref(q, k, v, window):
-    """Whole masks by index arithmetic, no block, no slice."""
-    heads, kv = q.shape[2], k.shape[2]
-    k, v = (jnp.repeat(t, heads // kv, axis=2) for t in (k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(q.shape[-1]))
-    t = jnp.arange(q.shape[1])[:, None]
-    u = jnp.arange(k.shape[1])[None, :]
-    att = jax.nn.softmax(
-        jnp.where((u <= t) & (t - u < window), s, -jnp.inf), -1)
-    return jnp.einsum("bhqk,bkhd->bqhd", att, v)
 
 
 @pytest.mark.parametrize("length, block, window", [
@@ -50,19 +34,19 @@ def _window_ref(q, k, v, window):
     (7, 16, 4)],
     ids=["inside", "boundary", "narrow", "length", "beyond", "one_block"])
 def test_windowed_composition_against_a_whole_mask(length, block, window):
-    q, k, v = _rand(40, (2, length, 4, 8), (2, length, 2, 8),
-                    (2, length, 2, 8))
-    _same_values_and_grads(
-        jax.jit(lambda *a: D._causal_gqa(*a, block, window)),
-        jax.jit(lambda *a: _window_ref(*a, window)), (q, k, v))
+    q, k, v = rand(40, (2, length, 4, 8), (2, length, 2, 8),
+                   (2, length, 2, 8))
+    same_values_and_grads(lambda *a: D._causal_gqa(*a, block, window),
+                          lambda *a: window_ref(*a, window), (q, k, v))
     if window >= length:
-        _close(D._causal_gqa(q, k, v, block, window), _attention_ref(q, k, v))
+        close(jax.jit(lambda *a: D._causal_gqa(*a, block, window))(q, k, v),
+              jitted(attention_ref)(q, k, v))
 
 
 def test_a_window_leaves_the_keys_before_the_band_alone():
     """At 64 positions in blocks of 16 under a window of 8 no score
     block is wider than a block and its band, 16 + 7 keys."""
-    q, k, v = _rand(41, (1, 64, 2, 4), (1, 64, 1, 4), (1, 64, 1, 4))
+    q, k, v = rand(41, (1, 64, 2, 4), (1, 64, 1, 4), (1, 64, 1, 4))
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(D._causal_gqa(*a, 16, 8)), (0, 1, 2)))(q, k, v)
 
@@ -82,7 +66,7 @@ def test_without_a_window_the_traced_program_is_the_one_before():
     """``window=None`` adds nothing to what is traced: the composition,
     the op and the NoPE mixer give the jaxpr they gave without the
     argument, and it holds no trace of a band."""
-    q, k, v = _rand(42, (1, 32, 4, 8), (1, 32, 2, 8), (1, 32, 2, 8))
+    q, k, v = rand(42, (1, 32, 4, 8), (1, 32, 2, 8), (1, 32, 2, 8))
     plain = str(jax.make_jaxpr(lambda *a: D._causal_gqa(*a, 8))(q, k, v))
     assert plain == str(jax.make_jaxpr(
         lambda *a: D._causal_gqa(*a, 8, None))(q, k, v))
@@ -91,7 +75,7 @@ def test_without_a_window_the_traced_program_is_the_one_before():
     attend = str(jax.make_jaxpr(D._attend)(q, k, v))
     assert attend == str(jax.make_jaxpr(
         lambda *a: D._attend(*a, window=None, keep=None))(q, k, v))
-    assert "mx.attn.window" not in str(jax.jit(D._attend).lower(q, k, v)
+    assert "mx.attn.window" not in str(jitted(D._attend).lower(q, k, v)
                                        .as_text(debug_info=True))
 
 
@@ -121,32 +105,34 @@ def test_yarn_rotary_against_the_written_out_rule(original):
     configuration file's equations: the angles, and the attention
     factor on cos and sin."""
     rope = dict(YARN, original_max_position_embeddings=original)
-    (x,) = _rand(43, (2, 40, 3, 128))
+    (x,) = rand(43, (2, 40, 3, 128))
     op = get_op("_contrib_rotary").impl
     attrs = dict(theta=5e5, yarn=(16, original, 32, 1))
     want = MREF.rotate(x, MREF.rope_table(rope, 128, 40))
-    _same_values_and_grads(
+    same_values_and_grads(
         lambda x: op(x, attention_factor=rope["attention_factor"], **attrs),
         lambda x: MREF.rotate(x, MREF.rope_table(rope, 128, 40)), (x,),
         tol=1e-4)
     # the factor is on cos and sin: a rotation times it
-    _close(op(x, **attrs) * rope["attention_factor"], want, tol=1e-4)
+    unscaled = jax.jit(lambda x: op(x, **attrs))(x)
+    close(unscaled * rope["attention_factor"], want, tol=1e-4)
     # and YaRN is not plain rotary at these positions
-    assert float(jnp.max(jnp.abs(op(x, **attrs) - op(x, theta=5e5)))) > 0.1
+    assert float(jnp.max(jnp.abs(
+        unscaled - jax.jit(lambda x: op(x, theta=5e5))(x)))) > 0.1
 
 
 def test_plain_rotary_is_unchanged_by_the_new_arguments():
-    (x,) = _rand(44, (2, 9, 3, 16))
+    (x,) = rand(44, (2, 9, 3, 16))
     op = get_op("_contrib_rotary").impl
     plain = jax.make_jaxpr(lambda x: op(x, theta=5e5))(x)
     assert str(plain) == str(jax.make_jaxpr(
         lambda x: op(x, theta=5e5, yarn=(), attention_factor=1.0))(x))
-    _close(op(x, theta=5e5),
-           MREF.rotate(x, MREF.rope_table({"rope_theta": 5e5}, 16, 9)))
+    close(op(x, theta=5e5),
+          MREF.rotate(x, MREF.rope_table({"rope_theta": 5e5}, 16, 9)))
 
 
 def _rotary_mixer_args(seed, hidden=24, heads=4, kv=2, d=8, length=21):
-    x, norm_w, qw, kw, vw, ow, qn, kn = _rand(
+    x, norm_w, qw, kw, vw, ow, qn, kn = rand(
         seed, (2, length, hidden), (hidden,), (heads * d, hidden),
         (kv * d, hidden), (kv * d, hidden), (hidden, heads * d), (d,), (d,),
         scale=0.3)
@@ -175,9 +161,9 @@ def test_rotary_gqa_mixer_against_the_reference(kind, window, rope):
         attrs.update(rope_yarn=(16, 8, 32, 1),
                      attention_factor=rope["attention_factor"])
     op = get_op("_contrib_rotary_gqa_mixer").impl
-    _same_values_and_grads(lambda *a: op(*a, **attrs),
-                           lambda *a: _rotary_mixer_ref(a, kind, window, rope),
-                           args, tol=1e-4)
+    same_values_and_grads(lambda *a: op(*a, **attrs),
+                          lambda *a: _rotary_mixer_ref(a, kind, window, rope),
+                          args, tol=1e-4)
 
 
 def test_the_rotary_mixer_keeps_its_context_only(capsys):
@@ -190,7 +176,7 @@ def test_the_rotary_mixer_keeps_its_context_only(capsys):
         return jnp.sum(op(*a, num_heads=4, num_kv_heads=2, head_dim=8,
                           rope_theta=5e5, window=6))
 
-    assert _remat_count(jax.grad(fn), *args) > 0
+    assert remat_count(jax.grad(fn), *args) > 0
     jax.ad_checkpoint.print_saved_residuals(fn, *args)
     kept = [line.split(" ")[0] for line in capsys.readouterr().out
             .splitlines() if "from the argument" not in line
@@ -208,8 +194,8 @@ def test_a_buffer_of_many_blocks_is_multiplied_in_chunks(monkeypatch, at_once,
     and up to it the program traced before the loop existed."""
     monkeypatch.setattr(D, "BLOCKS_AT_ONCE", at_once)
     monkeypatch.setattr(D, "BLOCKS_A_CHUNK", a_chunk)
-    w, cfg = _swiglu_weights(50)
-    (x,) = _rand(51, (2, 40, 12))
+    w, cfg = swiglu_experts(50)
+    (x,) = rand(51, (2, 40, 12))
     names = sorted(w)
 
     def fn(x, *ws):     # 34 blocks of 8 rows: a buffer no routing overfills
@@ -228,8 +214,8 @@ def test_a_buffer_of_many_blocks_is_multiplied_in_chunks(monkeypatch, at_once,
     assert text.count("scan[") == (1 if chunk else 0)
     if chunk:
         assert "f32[%d,%d,8,12]" % (34 // chunk, chunk) in text
-    _close(fn(*args), ref(*args))
-    (cot,) = _rand(52, x.shape)
-    nums = tuple(range(len(args)))
-    _close(jax.grad(lambda *a: jnp.sum(fn(*a) * cot), nums)(*args),
-           jax.grad(lambda *a: jnp.sum(ref(*a) * cot), nums)(*args), 5e-5)
+    (cot,) = rand(52, x.shape)
+    got = value_and_grads(fn, *args, cot=cot)
+    want = value_and_grads(ref, *args, cot=cot)
+    close(got[0], want[0])
+    close(got[1:], want[1:], 5e-5)

@@ -41,7 +41,8 @@ def _clean(monkeypatch):
     commwatch.reset()
 
 
-def _build(zero, ndev=4, opt="sgd", opt_kw=None, seed=5, dcn=0):
+def _build(zero, ndev=4, opt="sgd", opt_kw=None, seed=5, dcn=0,
+           layers=("dense0_", "dense1_")):
     os.environ["MXNET_ZERO"] = "1" if zero else "0"
     if dcn:
         os.environ["MXNET_ZERO_DCN"] = str(dcn)
@@ -52,7 +53,14 @@ def _build(zero, ndev=4, opt="sgd", opt_kw=None, seed=5, dcn=0):
     # sizes 35, 5, 15, 3: none divisible by 4 or 8 replicas, and the
     # 3-element bias is SMALLER than the replica count (frag=1, most
     # replicas own pure padding for it) — the uneven-shard edge cases
-    net.add(nn.Dense(5, in_units=7), nn.Dense(3))
+    # (named here, dense0 and dense1 in every net: a checkpoint is
+    # restored by the parameters' sorted names, and under the
+    # process-wide counter two nets of one test can stand either side
+    # of dense9 / dense10, which sort the other way: ROADMAP C3 and
+    # test_zero_checkpoint_across_a_digit_boundary_of_the_layer_names)
+    with net.name_scope():
+        net.add(nn.Dense(5, in_units=7, prefix=layers[0]),
+                nn.Dense(3, prefix=layers[1]))
     net.initialize(ctx=ctxs, init=mx.initializer.Xavier())
     net(nd.ones((2, 7), ctx=ctxs[0]))
     tr = gluon.Trainer(net.collect_params(), opt,
@@ -214,20 +222,22 @@ def test_zero_save_states_is_canonical(tmp_path):
                                rtol=1e-5, atol=1e-7)
 
 
-@pytest.mark.zero
-def test_zero_checkpoint_round_trips_across_topologies(tmp_path):
-    """sharded(4) -> save -> load on replicated(2) AND on sharded(8):
-    both restored trainers continue bit-compatibly (feeds ROADMAP
-    item 5: resume on a different chip count)."""
+def _round_trip(tmp_path, saved, restored):
+    """sharded(4), its layers named ``saved`` -> save -> load on
+    replicated(2) AND on sharded(8), their layers named ``restored``:
+    both restored trainers continue bit-compatibly."""
     kw = {"learning_rate": 0.01}
-    net_a, tr_a, ctx_a = _build(True, ndev=4, opt="adam", opt_kw=dict(kw))
+    net_a, tr_a, ctx_a = _build(True, ndev=4, opt="adam", opt_kw=dict(kw),
+                                layers=saved)
     _run(net_a, tr_a, ctx_a, 3)
     ckpt = str(tmp_path / "zero.states")
     tr_a.save_states(ckpt)
     w0 = _weights(net_a, ctx_a[0])
 
-    net_b, tr_b, ctx_b = _build(False, ndev=2, opt="adam", opt_kw=dict(kw))
-    net_c, tr_c, ctx_c = _build(True, ndev=8, opt="adam", opt_kw=dict(kw))
+    net_b, tr_b, ctx_b = _build(False, ndev=2, opt="adam", opt_kw=dict(kw),
+                                layers=restored)
+    net_c, tr_c, ctx_c = _build(True, ndev=8, opt="adam", opt_kw=dict(kw),
+                                layers=restored)
     for w, (_, pb), (_, pc) in zip(w0, net_b.collect_params().items(),
                                    net_c.collect_params().items()):
         pb.set_data(nd.array(w))
@@ -238,6 +248,27 @@ def test_zero_checkpoint_round_trips_across_topologies(tmp_path):
     _run(net_b, tr_b, ctx_b, 2, seed=17)
     _run(net_c, tr_c, ctx_c, 2, seed=17)
     _assert_parity(net_b, ctx_b[0], net_c, ctx_c[0])
+
+
+@pytest.mark.zero
+def test_zero_checkpoint_round_trips_across_topologies(tmp_path):
+    """Feeds ROADMAP item 5: resume on a different chip count."""
+    _round_trip(tmp_path, ("dense0_", "dense1_"), ("dense0_", "dense1_"))
+
+
+@pytest.mark.zero
+@pytest.mark.xfail(strict=True, reason="ROADMAP C3: a checkpoint is "
+                   "restored by the parameters' sorted names")
+def test_zero_checkpoint_across_a_digit_boundary_of_the_layer_names(tmp_path):
+    """The names the process-wide counter gives a net built after eight
+    other ``Dense`` blocks: ``dense10_`` sorts before ``dense9_``, so
+    the saved net's states stand second layer first, the restoring
+    nets' first layer first, and a 3-element bias's state meets a
+    5-element bias (``restore size mismatch``). Counts as neither a
+    pass nor a failure while the program is as it is, and fails
+    (strict) on the day the program restores by position: take the
+    marker off then."""
+    _round_trip(tmp_path, ("dense9_", "dense10_"), ("dense11_", "dense12_"))
 
 
 @pytest.mark.zero
