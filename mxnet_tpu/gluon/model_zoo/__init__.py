@@ -18,7 +18,12 @@ embedding and the head a second time). ``laguna``: the fifth,
 Laguna-XS.2 (window and full attention layers whose query heads differ
 by a per-layer list, a sigmoid gate a head on the context, rotary over
 half a head's lanes on the full layers, a leading dense layer, a shared
-expert beside a scaled softmax router)."""
+expert beside a scaled softmax router). ``lfm2``: the sixth, LFM2's
+expert model (gated short-convolution layers and grouped-query
+attention layers with q/k norms mixed by a per-layer list, leading
+dense layers by a count, a sigmoid router with a selection bias and no
+shared expert, the head tied to the embedding: the loss block reads the
+net's own parameter)."""
 from . import vision
 from . import bert
 from . import nemotron_h
@@ -26,4 +31,5 @@ from . import keye_vl
 from . import mellum
 from . import glm_moe_lite
 from . import laguna
+from . import lfm2
 from .vision import get_model

@@ -1,6 +1,8 @@
 """Ops of a decoder layer stack: RMSNorm (plain, and gated over
 groups), the causal depthwise conv and the chunked state-space (SSD)
-scan of a Mamba-2 mixer, rotary positions (one axis, or sectioned over
+scan of a Mamba-2 mixer, a gated short convolution (two element-wise
+gates around that conv, with no bias and any kernel length, as the
+whole mixer), rotary positions (one axis, or sectioned over
 several; plain, or slowed pair by pair by YaRN; over a whole head or
 its first lanes), blocked causal
 grouped-query attention over every earlier key or over a sliding window
@@ -41,12 +43,14 @@ row. Matrix products take their inputs in the dtype they
 are given (bf16 inside ``ShardedTrainStep``) and accumulate in float32;
 decays, softmax, norms and the router are computed in float32.
 
-Seven *mixer* ops (``_contrib_mamba2_mixer``, ``_contrib_moe_mixer``,
+Eight *mixer* ops (``_contrib_mamba2_mixer``,
+``_contrib_short_conv_mixer``, ``_contrib_moe_mixer``,
 ``_contrib_glu_mlp_mixer``, ``_contrib_gqa_mixer``,
 ``_contrib_rotary_gqa_mixer``, ``_contrib_mla_mixer``,
 ``_contrib_sparse_gqa_mixer``) hold a whole pre-norm mixer each,
 ``mixer(RMSNorm(x))``, and are where recomputation lives: the Mamba-2,
-expert, dense gated, rotary, latent and sparse-attention mixers are
+short-convolution, expert, dense gated, rotary, latent and
+sparse-attention mixers are
 ``jax.checkpoint``-ed whole, so a training step keeps their input and
 recomputes their inside in the backward (the rotary and the latent one
 also keep their context and, on the kernel path, the rows'
@@ -54,7 +58,9 @@ log-sum-exp; the sparse one those and each row's selection threshold,
 so neither the search nor a second pass of the attention is repeated);
 the NoPE attention mixer keeps its q/k/v/context (and, on the kernel
 path, the rows' log-sum-exp) and recomputes each query block's scores.
-The device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.moe``,
+The device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.conv``
+(the short-convolution mixer; inside it ``mx.conv.gate``, both gates
+and the taps between its two projections), ``mx.moe``,
 ``mx.moe.experts``, ``mx.mlp``, ``mx.attn.causal``, ``mx.attn.window``,
 ``mx.attn.rotary`` and ``mx.attn.mla`` (the rotary and the latent
 mixer, around the attention's own scope; inside the rotary one
@@ -136,22 +142,25 @@ def gated_rms_norm(data, gate, gamma, *, group_size, eps=1e-5):
 # ---------------------------------------------------------------------------
 # Mamba-2
 # ---------------------------------------------------------------------------
-def _causal_conv1d(x, w, b):
+def _causal_conv1d(x, w, b=None, dtype=F32):
+    """The taps' products and their sum in ``dtype``, the result in
+    ``x``'s."""
     k, length = w.shape[1], x.shape[1]
-    xp = jnp.pad(x.astype(F32), ((0, 0), (k - 1, 0), (0, 0)))
-    wf = w.astype(F32)
-    y = b.astype(F32)
+    xp = jnp.pad(x.astype(dtype), ((0, 0), (k - 1, 0), (0, 0)))
+    wf = w.astype(dtype)
+    y = None if b is None else b.astype(dtype)
     for j in range(k):
-        y = y + xp[:, j:j + length, :] * wf[:, j]
+        tap = xp[:, j:j + length, :] * wf[:, j]
+        y = tap if y is None else y + tap
     return y.astype(x.dtype)
 
 
 @register("_contrib_causal_conv1d")
-def causal_conv1d(data, weight, bias):
+def causal_conv1d(data, weight, bias=None):
     """Causal depthwise conv over time: data (batch, length, channels),
-    weight (channels, k), bias (channels,);
-    ``y[t] = bias + sum_j weight[:, j] * data[t - (k-1) + j]`` with
-    zeros before the start."""
+    weight (channels, k) for any kernel length ``k``, bias (channels,)
+    or none; ``y[t] = bias + sum_j weight[:, j] * data[t - (k-1) + j]``
+    with zeros before the start, summed in float32."""
     return _causal_conv1d(data, weight, bias)
 
 
@@ -283,6 +292,46 @@ def mamba2_mixer(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
 
 
 # ---------------------------------------------------------------------------
+# a gated short convolution
+# ---------------------------------------------------------------------------
+def _short_conv(data, norm_w, in_w, conv_w, out_w, *, eps):
+    bcu = _dense(_rms(data, norm_w, eps), in_w)
+    # between the two products nothing but element-wise work, in the
+    # products' own dtype: in float32 with one rounding at the end the
+    # mixer measured 33.7 ms forward + backward where this takes 30.4
+    # (4 x 8,192 tokens on a v5e), and the cell's step 545 ms for 530
+    # (PERF.md section 6, PR 47)
+    with jax.named_scope("mx.conv.gate"):
+        b, c, u = jnp.split(bcu, 3, axis=-1)
+        y = c * _causal_conv1d(b * u, conv_w, dtype=bcu.dtype)
+    return _dense(y, out_w)
+
+
+@register("_contrib_short_conv_mixer")
+def short_conv_mixer(data, norm_gamma, in_weight, conv_weight, out_weight, *,
+                     eps=1e-5):
+    """A pre-norm gated short-convolution mixer (LFM2's ``conv`` layer),
+    ``mixer(h)``, ``h = RMSNorm(data)``: ``[B ; C ; u] = W_in h`` (three
+    runs of ``channels`` values, in that order; in_weight (3 x channels,
+    hidden)), ``z = B * u``, ``c_t = sum_j w_j z_{t-(k-1)+j}`` (depthwise
+    and causal: conv_weight (channels, k), one filter a channel, zeros
+    before the sequence's start, no bias; :func:`causal_conv1d`), ``y_t
+    = W_out (C_t * c_t)`` (out_weight (hidden, channels)). No bias and no
+    activation function: every product but the two projections is
+    element-wise. data (batch, length, hidden). The two gates and the
+    taps are computed in ``data``'s dtype (bf16 inside
+    ``ShardedTrainStep``: each product and the taps' sum rounded, as
+    the projections' outputs are). Recomputed whole
+    in the backward (``jax.checkpoint``): a step keeps ``data`` only.
+    The device scope is ``mx.conv``; the part between the two
+    projections (both gates and the taps) stands under ``mx.conv.gate``
+    inside it, forward, recomputation and backward alike."""
+    fn = jax.checkpoint(functools.partial(_short_conv, eps=float(eps)))
+    with jax.named_scope("mx.conv"):
+        return fn(data, norm_gamma, in_weight, conv_weight, out_weight)
+
+
+# ---------------------------------------------------------------------------
 # causal grouped-query attention
 # ---------------------------------------------------------------------------
 def _causal_gqa(q, k, v, block, window=None):
@@ -318,7 +367,8 @@ def _causal_gqa(q, k, v, block, window=None):
 def _attend(q, k, v, window=None, keep=None):
     """Causal GQA by whichever schedule the call allows, chosen from
     what can be observed here and nothing else: the flash kernel for
-    bf16 q / k / v with a head width of whole lane tiles, whole groups
+    bf16 q / k / v with a head width of whole lane tiles (or of half a
+    tile, 64 lanes, two heads of an even group a step), whole groups
     of query heads and a length of whole ``QUERY_BLOCK`` tiles, traced
     for one device (``pallas_causal_gqa.causal_gqa_available``); the
     blocked composition for everything else. With ``window`` query

@@ -14,6 +14,23 @@ tiles up to its own: tiles wholly above the diagonal are never visited,
 the diagonal tile is masked, so the work is the composition's at
 ``QUERY_BLOCK`` granularity.
 
+Heads of 64 lanes (half a lane tile; a ``BlockSpec`` cannot pick 64
+lanes out of that view) are served two a grid step, where the groups
+are even: a 128-lane block of q / o / do / dq holds two query heads of
+one group, a 128-lane block of k / v / dk / dv the pair of key-value
+heads of which the step's group reads one half. Each head's q (and
+do) is moved to that half and zero in the other (:class:`_Pair`), so
+every product is over whole 128-lane tiles: a score contracts 64 real
+lanes and 64 zeros, dk / dv take a zero contribution for the other
+key-value head, and the valid half of the context and of dq is read
+back out of the accumulators. The MXU does a 128-lane head's work for
+each 64-lane head (a contraction of 64 fills half the array either
+way); q, k, v and the context move as they lie, with no padded copy.
+On a v5e at (4, 8192, 32, 64) over 8 key-value heads, forward +
+backward: 54.9 ms, against 62.7 for heads zero-padded to a lane tile
+on the way in (the same kernel steps plus the copies and twice the
+traffic) and 1,265 for the composition (PERF.md section 6, PR 47).
+
 With a ``window`` (a sliding-window layer: query ``t`` sees the keys
 ``t - window < s <= t``) the same two kernels visit only the key tiles
 that hold a key of the step's band: the diagonal tile first (every
@@ -95,20 +112,36 @@ def _bwd_vmem_bytes(length, d, tile):
     return resident + tiles
 
 
+def _heads_a_step(heads, kv, d):
+    """Query heads one grid step takes (0: a shape the kernel does not
+    serve): one head of whole 128-lane tiles; or two heads of 64 lanes
+    that share a key-value head, side by side in one lane tile, where
+    the groups are even (a pair never straddles two groups) and the
+    key-value heads pair up likewise."""
+    if kv <= 0 or heads % kv:
+        return 0
+    if d % _LANE == 0:
+        return 1
+    if 2 * d == _LANE and (heads // kv) % 2 == 0 and kv % 2 == 0:
+        return 2
+    return 0
+
+
 def causal_gqa_available(q, k, v, tile):
     """Whether the kernel may serve this call, from what the code can
     observe: one device in the mesh being traced for, bf16 q / k / v,
-    a head width of whole 128-lane tiles, whole groups of query heads,
-    a length of whole tiles, and a k / v that fits VMEM."""
+    a head width of whole 128-lane tiles or of half a tile (64 lanes,
+    with even groups over an even number of key-value heads), whole
+    groups of query heads, a length of whole tiles, and a k / v that
+    fits VMEM."""
     b, length, heads, d = q.shape
-    kv = k.shape[2]
+    pack = _heads_a_step(heads, k.shape[2], d)
     return bool(
         pallas_common.kernels_allowed()
         and all(t.dtype == BF16 for t in (q, k, v))
-        and d % _LANE == 0 and tile % _LANE == 0
-        and kv > 0 and heads % kv == 0
+        and pack and tile % _LANE == 0
         and length > 0 and length % tile == 0
-        and _bwd_vmem_bytes(length, d, tile) <= _VMEM_BUDGET)
+        and _bwd_vmem_bytes(length, d * pack, tile) <= _VMEM_BUDGET)
 
 
 def _dot(a, b, dims):
@@ -160,13 +193,51 @@ def _compiler_params(pltpu, semantics, length, d, tile):
                                 vmem_limit_bytes=min(nbytes, 110 << 20))
 
 
-def _block_specs(pl, length, d, tile, rep):
+def _block_specs(pl, length, d, tile, rep, pack=1):
     """(a head's tile of q / o / do / dq, its group's whole k / v / dk /
     dv, a head's tile of a row statistic) over the grid (batch, head,
-    query tile)."""
+    query tile). With ``pack`` 2 a step is two 64-lane heads of one
+    group: ``d`` is their lane tile (128), a k / v block the pair of
+    key-value heads of which the step reads one half, and ``rep`` steps
+    share it as ``rep`` heads share a 128-lane key-value head."""
+    rows = (None, None, 1, tile) if pack == 1 else (None, pack, 1, tile)
     return (pl.BlockSpec((None, tile, d), lambda n, h, i: (n, i, h)),
             pl.BlockSpec((None, length, d), lambda n, h, i: (n, 0, h // rep)),
-            pl.BlockSpec((None, None, 1, tile), lambda n, h, i: (n, h, 0, i)))
+            pl.BlockSpec(rows, lambda n, h, i: (n, h, 0, i)))
+
+
+class _Pair:
+    """What a step of two 64-lane query heads needs beside the
+    one-head step's code. The step's q / do block holds head ``a`` in
+    lanes ``64 a ..``; its k / v block holds two key-value heads, the
+    group's own in half ``s`` (by the step's place among the ``rep``
+    that share the block). ``to_kv`` gives each head ``a``'s lanes moved to
+    half ``s`` and zeros in the other half, so a product over all 128
+    lanes with a k / v / dk / dv block is head ``a``'s against its own
+    key-value head and adds nothing to the other's. ``from_kv`` reads a
+    (2 x 128, queries) float32 accumulator's valid rows (half ``s`` of
+    each head's 128) as the (queries, 128) block of both heads."""
+
+    def __init__(self, pl, pltpu, rep, d):
+        self.pl, self.pltpu, self.d = pl, pltpu, d
+        self.s = (pl.program_id(1) // (rep // 2)) % 2
+        self.half = lax.broadcasted_iota(jnp.int32, (1, _LANE), 1) // d
+
+    def to_kv(self, x):
+        """[head 0's, head 1's] of a q / do block."""
+        swapped = self.pltpu.roll(x.astype(F32), self.d, 1).astype(x.dtype)
+        return [jnp.where(self.half == self.s,
+                          jnp.where(self.s == a, x, swapped),
+                          jnp.zeros_like(x)) for a in range(2)]
+
+    def from_kv(self, acc_ref, scale_of=None):
+        d, pl = self.d, self.pl
+        rows = []
+        for a in range(2):
+            top = pl.multiple_of(a * _LANE + self.s * d, d)
+            part = acc_ref[pl.ds(top, d), :]
+            rows.append(part if scale_of is None else part / scale_of(a))
+        return jnp.concatenate(rows, axis=0).T
 
 
 @functools.lru_cache(maxsize=None)
@@ -176,48 +247,66 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
 
     rep, nq = heads // kv, length // tile
     scale = 1.0 / math.sqrt(d)
+    pack = _heads_a_step(heads, kv, d)
+    lanes = d * pack
 
     def pallas_causal_gqa_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref,
                               m_ref, l_ref, acc_ref):
         i = pl.program_id(2)
         q = q_ref[...]
+        if pack == 1:
+            qs, stat, part = [q], [...], [...]
+        else:
+            pair = _Pair(pl, pltpu, rep, d)
+            qs = pair.to_kv(q)
+            stat = [pl.ds(a, 1) for a in range(pack)]
+            part = [pl.ds(a * lanes, lanes) for a in range(pack)]
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, F32)
         l_ref[...] = jnp.zeros(l_ref.shape, F32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
 
         def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
-            st = _dot(k_ref[rows, :], q, _NT) * scale       # keys x queries
-            if mask is not None:
-                st = jnp.where(mask, st, -jnp.inf)
-            m_prev = m_ref[...]
-            m_next = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_next)
-            pt = jnp.exp(st - m_next)
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(pt, axis=0,
-                                                      keepdims=True)
-            acc_ref[...] = alpha * acc_ref[...] + _dot(
-                v_ref[rows, :], pt.astype(BF16), _TN)       # d x queries
-            m_ref[...] = m_next
+            for a in range(pack):
+                st = _dot(k_ref[rows, :], qs[a], _NT) * scale   # keys x queries
+                if mask is not None:
+                    st = jnp.where(mask, st, -jnp.inf)
+                m_prev = m_ref[stat[a]]
+                m_next = jnp.maximum(m_prev,
+                                     jnp.max(st, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                pt = jnp.exp(st - m_next)
+                l_ref[stat[a]] = alpha * l_ref[stat[a]] + jnp.sum(
+                    pt, axis=0, keepdims=True)
+                acc_ref[part[a]] = alpha * acc_ref[part[a]] + _dot(
+                    v_ref[rows, :], pt.astype(BF16), _TN)   # d x queries
+                m_ref[stat[a]] = m_next
 
         _key_tiles(pl, i, key_tile, tile, length, window)
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l)
+        if pack == 1:
+            o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
+            lse_ref[...] = m_ref[...] + jnp.log(l)
+        else:
+            o_ref[...] = pair.from_kv(
+                acc_ref, lambda a: l_ref[stat[a]]).astype(o_ref.dtype)
+            lse_ref[...] = (m_ref[...] + jnp.log(l))[:, None, :]
 
-    q_spec, kv_spec, row_spec = _block_specs(pl, length, d, tile, rep)
+    q_spec, kv_spec, row_spec = _block_specs(pl, length, lanes, tile, rep,
+                                             pack)
     return pl.pallas_call(
         pallas_causal_gqa_fwd,
-        grid=(b, heads, nq),
+        grid=(b, heads // pack, nq),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
                    jax.ShapeDtypeStruct((b, heads, 1, length), F32)],
-        scratch_shapes=[pltpu.VMEM((1, tile), F32),
-                        pltpu.VMEM((1, tile), F32),
-                        pltpu.VMEM((d, tile), F32)],
+        scratch_shapes=[pltpu.VMEM((pack, tile), F32),
+                        pltpu.VMEM((pack, tile), F32),
+                        pltpu.VMEM((pack * lanes, tile), F32)],
         compiler_params=_compiler_params(
-            pltpu, ("parallel", "parallel", "arbitrary"), length, d, tile),
+            pltpu, ("parallel", "parallel", "arbitrary"), length, lanes,
+            tile),
         interpret=interpret,
         name="pallas_causal_gqa_fwd",
     )
@@ -230,6 +319,8 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
 
     rep, nq = heads // kv, length // tile
     scale = 1.0 / math.sqrt(d)
+    pack = _heads_a_step(heads, kv, d)
+    lanes = d * pack
 
     def pallas_causal_gqa_bwd(q_ref, k_ref, v_ref, do_ref, lse_ref,
                               delta_ref, dq_ref, dk_ref, dv_ref,
@@ -243,43 +334,57 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
 
         q, do = q_ref[...], do_ref[...]
         lse, delta = lse_ref[...], delta_ref[...]           # (1, tile)
+        if pack == 1:
+            qs, dos, lses, deltas, part = [q], [do], [lse], [delta], [...]
+        else:
+            pair = _Pair(pl, pltpu, rep, d)
+            qs, dos = pair.to_kv(q), pair.to_kv(do)
+            lses = [lse[a] for a in range(pack)]
+            deltas = [delta[a] for a in range(pack)]
+            part = [pl.ds(a * lanes, lanes) for a in range(pack)]
         dq_acc[...] = jnp.zeros(dq_acc.shape, F32)
 
         def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
             kj, vj = k_ref[rows, :], v_ref[rows, :]
-            st = _dot(kj, q, _NT) * scale               # keys x queries
-            if mask is not None:
-                st = jnp.where(mask, st, -jnp.inf)
-            pt = jnp.exp(st - lse)
-            dv_acc[rows, :] += _dot(pt.astype(BF16), do, _NN)
-            dpt = _dot(vj, do, _NT)
-            dst = (pt * (dpt - delta) * scale).astype(BF16)
-            dk_acc[rows, :] += _dot(dst, q, _NN)
-            dq_acc[...] += _dot(kj, dst, _TN)               # d x queries
+            for a in range(pack):
+                st = _dot(kj, qs[a], _NT) * scale           # keys x queries
+                if mask is not None:
+                    st = jnp.where(mask, st, -jnp.inf)
+                pt = jnp.exp(st - lses[a])
+                dv_acc[rows, :] += _dot(pt.astype(BF16), dos[a], _NN)
+                dpt = _dot(vj, dos[a], _NT)
+                dst = (pt * (dpt - deltas[a]) * scale).astype(BF16)
+                dk_acc[rows, :] += _dot(dst, qs[a], _NN)
+                dq_acc[part[a]] += _dot(kj, dst, _TN)       # d x queries
 
         _key_tiles(pl, i, key_tile, tile, length, window)
-        dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
+        if pack == 1:
+            dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
+        else:
+            dq_ref[...] = pair.from_kv(dq_acc).astype(dq_ref.dtype)
 
         @pl.when((h % rep == rep - 1) & (i == nq - 1))
         def _():
             dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    q_spec, kv_spec, row_spec = _block_specs(pl, length, d, tile, rep)
+    q_spec, kv_spec, row_spec = _block_specs(pl, length, lanes, tile, rep,
+                                             pack)
     kv_shape = jax.ShapeDtypeStruct((b, length, kv * d), BF16)
     return pl.pallas_call(
         pallas_causal_gqa_bwd,
-        grid=(b, heads, nq),
+        grid=(b, heads // pack, nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[q_spec, kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
                    kv_shape, kv_shape],
-        scratch_shapes=[pltpu.VMEM((d, tile), F32),
-                        pltpu.VMEM((length, d), F32),
-                        pltpu.VMEM((length, d), F32)],
+        scratch_shapes=[pltpu.VMEM((pack * lanes, tile), F32),
+                        pltpu.VMEM((length, lanes), F32),
+                        pltpu.VMEM((length, lanes), F32)],
         compiler_params=_compiler_params(
-            pltpu, ("parallel", "arbitrary", "arbitrary"), length, d, tile),
+            pltpu, ("parallel", "arbitrary", "arbitrary"), length, lanes,
+            tile),
         interpret=interpret,
         name="pallas_causal_gqa_bwd",
     )

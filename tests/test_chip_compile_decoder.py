@@ -336,3 +336,53 @@ def test_rotary_mixer_at_16384_takes_the_kernel_under_its_kind_s_scope(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
     # no score block: 512 queries against a band, or against every key
     assert "f32[1,4,8,512," not in text
+
+
+# ---------------------------------------------------------------------------
+# LFM2's widths (hidden 2048; 32 / 8 attention heads of 64 lanes; a gated
+# short convolution of three taps)
+# ---------------------------------------------------------------------------
+def test_heads_of_64_lanes_take_the_kernel_two_a_step(one_chip,
+                                                      compiled_mode):
+    """The op's gradient at LFM2's heads (32 over 8, 64 lanes; four
+    sequences of 8,192, the cell's batch) takes the flash kernel: Mosaic
+    accepts the step of two heads (the lane roll, the selects, the
+    accumulators' 64-row reads), two custom calls under
+    ``mx.attn.causal``; an odd group of such heads keeps the
+    composition."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_causal_gqa_attention").impl
+    grad = jax.grad(lambda *a: sum32(op(*a)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(*described(
+        one_chip, (4, 8192, 32, 64), (4, 8192, 8, 64),
+        (4, 8192, 8, 64))).compile().as_text()
+    calls = mosaic_calls(text)
+    placed = scopes.scope_map(text, ["mx.attn.causal"])
+    names = sorted(name for name in placed
+                   if name.startswith("pallas_causal_gqa_"))
+    assert len(calls) == len(names) == 2
+    assert names[0].startswith("pallas_causal_gqa_bwd")
+    assert names[1].startswith("pallas_causal_gqa_fwd")
+    odd = jax.jit(grad).lower(*described(
+        one_chip, (1, 1024, 24, 64), (1, 1024, 8, 64),
+        (1, 1024, 8, 64))).compile().as_text()
+    assert not mosaic_calls(odd)
+
+
+def test_the_short_conv_mixer_compiles_under_its_two_scopes(one_chip):
+    """An XLA composition (no Mosaic call) whose gates and taps stand
+    under ``mx.conv.gate`` and whose products under ``mx.conv``, in the
+    compiled program the benchmark's reader maps."""
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    op = get_op("_contrib_short_conv_mixer").impl
+    grad = jax.grad(lambda *a: sum32(op(*a, eps=1e-5)),
+                    argnums=(0, 2, 3, 4))
+    text = jax.jit(grad).lower(*described(
+        one_chip, (1, 2048, 2048), (2048,), (6144, 2048), (2048, 3),
+        (2048, 2048))).compile().as_text()
+    assert not mosaic_calls(text)
+    placed = set(scopes.scope_map(text, ["mx.conv.gate", "mx.conv"])
+                 .values())
+    assert placed == {"mx.conv.gate", "mx.conv"}
