@@ -228,17 +228,12 @@ def kernel_counts(compiled_text):
 # BERT training path
 BERT_KERNELS = ("pallas_layer_norm_fwd", "pallas_layer_norm_bwd",
                 "pallas_selfatt_packed_fwd", "pallas_selfatt_packed_bwd",
-                "pallas_bias_gelu_fwd", "pallas_bias_gelu_bwd",
-                "pallas_residual_fwd", "pallas_dropout_fwd",
-                "pallas_dropout_bwd")
+                "pallas_dropout_fwd", "pallas_dropout_bwd")
 
 
 # of those, the ones that keep their XLA compositions in a program
-# partitioned over a mesh (ops/pallas_norm.py and pallas_epilogue.py
-# say why)
-MESH_COMPOSITIONS = ("pallas_layer_norm_fwd", "pallas_layer_norm_bwd",
-                     "pallas_bias_gelu_fwd", "pallas_bias_gelu_bwd",
-                     "pallas_residual_fwd")
+# partitioned over a mesh (ops/pallas_norm.py says why)
+MESH_COMPOSITIONS = ("pallas_layer_norm_fwd", "pallas_layer_norm_bwd")
 
 
 def seq_out(outputs):
@@ -280,6 +275,7 @@ def phase_bert(meter, seed, rehearse):
         assert not missing, \
             "default-on Pallas kernels absent from the compiled BERT " \
             "step: %s" % missing
+        assert set(found) <= set(BERT_KERNELS), found
     say("train/bert_base", "batch %d seq %d lamb: loss %.4f -> %.4f; peak "
         "memory %s bytes" % (batch, seq, losses[0], losses[-1],
                              peak_bytes(jax.devices()[0])))
@@ -422,7 +418,8 @@ def phase_dp4_sharded(meter, seed, rehearse, devs):
     batch unlike the length: a row-wise kernel finds the dimension to
     run a shard at a time on by the batch's size.) Then the dp=4 step
     at dropout 0.1: every kernel that runs once a shard is a custom
-    call of the compiled step, the norm and the epilogues are not."""
+    call of the compiled step, the norm is not (and the Dense epilogues
+    are XLA's fusions on any device)."""
     import jax
     import mxnet_tpu as mx
     from bert_bench import build_step

@@ -1,9 +1,9 @@
 """Measurement-driven kernel auto-tuner (ROADMAP item 3, round 7).
 
-The Pallas kernel layer (pallas_norm / pallas_dropout / pallas_attention
-/ pallas_epilogue) and the streaming chunked CE each carry hand-picked
-tiling constants — LN/dropout/epilogue row-block sizes, the attention
-head-block `_BB`, `MXNET_CHUNKED_CE_CHUNK`. Those defaults were chosen
+The Pallas kernel layer (pallas_norm / pallas_dropout / pallas_attention)
+and the streaming chunked CE each carry hand-picked tiling constants —
+LN/dropout row-block sizes, the attention head-block `_BB`,
+`MXNET_CHUNKED_CE_CHUNK`. Those defaults were chosen
 for the BERT-base flagship shape on one device kind; other shapes and
 chips deserve other constants, and guessing them per call site does not
 scale. This module replaces the guess with the cost-model idea of
@@ -416,7 +416,7 @@ def _tune(m: str, cands: List[Candidate],
 
 # ---------------------------------------------------------------------------
 # shared consult for row-blocked elementwise kernels (pallas_norm,
-# pallas_dropout, pallas_epilogue): ONE candidate grid, ONE validation
+# pallas_dropout): ONE candidate grid, ONE validation
 # — a cached entry must clear the same sublane-floor and VMEM rules as
 # a freshly picked block, so a stale/hand-edited table can degrade perf
 # but never crash a kernel build (the module contract).

@@ -148,15 +148,6 @@ define("MXNET_PALLAS_DROPOUT", bool, True,
        "backward regenerates the mask from the saved seeds). Only "
        "active on a real TPU; CPU and ineligible shapes fall back to "
        "the jax.random path.")
-define("MXNET_PALLAS_EPILOGUE", bool, True,
-       "Serve the Dense epilogues of the model-zoo BERT path — fused "
-       "bias+GeLU (exact erf form; single-sweep backward re-deriving "
-       "the GeLU derivative from the streamed pre-activation) and "
-       "bias+residual-add — with the Pallas kernels in "
-       "ops/pallas_epilogue.py. Off (or ineligible shapes/dtypes) "
-       "falls back to the reference-idiomatic XLA composition, "
-       "bitwise-identical to the pre-epilogue graph "
-       "(docs/KERNELS.md 'Fused epilogues').")
 define("MXNET_AUTOTUNE", str, "off",
        "Kernel auto-tuner mode (mxnet_tpu/autotune.py): 'off' "
        "(default) keeps every hand-picked kernel constant — "

@@ -14,10 +14,9 @@ FP16_FUNCS = [
     "_contrib_interleaved_matmul_selfatt_valatt",
     "_contrib_interleaved_matmul_encdec_qk",
     "_contrib_interleaved_matmul_encdec_valatt",
-    # fused Dense epilogues (ops/pallas_epilogue.py): classified with
+    # the Dense epilogues (ops/contrib_ops.py): classified with
     # FullyConnected so the bias rides in the SAME low-precision dtype
-    # it did when it was a FullyConnected input (r6 graph) — the
-    # Pallas kernels require matching dtypes and compute f32 inside
+    # it did when it was a FullyConnected input (r6 graph)
     "_contrib_bias_gelu",
     "_contrib_bias_add_residual",
 ]

@@ -18,7 +18,7 @@ __all__ = ["BERTEncoder", "BERTModel", "BERTMLMLoss", "bert_12_768_12",
 
 
 class PositionwiseFFN(HybridBlock):
-    """Dense→GeLU→Dense FFN with fused epilogues (ISSUE 14): ffn_1
+    """Dense→GeLU→Dense FFN with its epilogues named (ISSUE 14): ffn_1
     carries the bias+GeLU epilogue; when there is no dropout between
     ffn_2 and the residual add, ffn_2 carries the bias+residual
     epilogue too (dropout must see the biased activations, so with
@@ -38,7 +38,7 @@ class PositionwiseFFN(HybridBlock):
             self.layer_norm = nn.LayerNorm(in_channels=units)
 
     def hybrid_forward(self, F, x):
-        out = self.ffn_1(x)              # fused bias+GeLU epilogue
+        out = self.ffn_1(x)              # bias+GeLU epilogue
         if self._dropout:
             out = self.ffn_2(out)
             out = self.dropout_layer(out)
@@ -79,7 +79,7 @@ class BERTEncoderCell(HybridBlock):
             att = self.attn_dropout(att)
             context = F._contrib_interleaved_matmul_selfatt_valatt(
                 qkv, att, heads=self._num_heads)
-        # fused bias+residual epilogue (ops/pallas_epilogue.py)
+        # bias + residual as one op (_contrib_bias_add_residual)
         out = self.proj(context, x)
         out = self.layer_norm(out)
         return self.ffn(out)

@@ -78,17 +78,17 @@ class HybridSequential(HybridBlock):
 class Dense(HybridBlock):
     """Fully-connected layer (ref: nn.Dense → FullyConnected op; MXU-bound).
 
-    ``epilogue`` selects a fused Dense epilogue (ISSUE 14, served by
-    ops/pallas_epilogue.py behind MXNET_PALLAS_EPILOGUE with a bitwise
-    reference fallback):
+    ``epilogue`` names what follows the product as one op (ISSUE 14;
+    ops/contrib_ops.py: the plain compositions, which XLA fuses into
+    the products beside them):
 
     * ``"gelu"`` — the matmul feeds ``_contrib_bias_gelu`` (bias-add +
-      exact GeLU in one kernel sweep per direction) instead of the
-      in-op bias add followed by a separate activation.
+      exact GeLU) instead of the in-op bias add followed by a separate
+      activation.
     * ``"residual"`` — the layer accepts an optional second input
       (``dense(x, residual)``) and feeds ``_contrib_bias_add_residual``
-      (bias-add + residual-add in one sweep). Called without a
-      residual it behaves like a plain Dense.
+      (bias-add + residual-add). Called without a residual it behaves
+      like a plain Dense.
 
     ``epilogue`` requires ``use_bias`` and excludes ``activation``.
     """

@@ -143,32 +143,28 @@ def sdp_selfatt(rng, queries_keys_values, *, heads, dropout=0.0,
 
 
 # ---------------------------------------------------------------------------
-# fused Dense epilogues (round-7 kernel work, ISSUE 14): bias+GeLU and
-# bias+residual, served by ops/pallas_epilogue.py behind
-# MXNET_PALLAS_EPILOGUE with the reference-idiomatic XLA composition
-# as the fallback — the flag-off path runs exactly the ops the model
-# ran before these ops existed (bitwise; tests/test_pallas_epilogue.py)
+# Dense epilogues: bias+GeLU and bias+residual as ops of their own, so
+# a model names the epilogue once (gluon.nn.Dense(epilogue=...), the zoo
+# BERT, the AMP lists, saved symbols). Their bodies are the plain
+# compositions: XLA fuses them into the fusions of the products beside
+# them, which beat the Pallas kernels that served them until PR 48 on a
+# mesh and on one chip alike (docs/KERNELS.md "Fused epilogues"). GeLU's
+# backward is autodiff's: a rule of its own that keeps the product's
+# output alone and recomputes erf and exp saves the 512-position BERT
+# step 2.2 GB and costs both one-chip cells 2 to 2.6% (PERF.md
+# section 6, PR 48)
 # ---------------------------------------------------------------------------
 @register("_contrib_bias_gelu")
 def bias_gelu(data, bias):
-    """GeLU(data + bias), exact erf form — the Dense→GeLU FFN epilogue
-    as ONE kernel sweep per direction instead of separate bias-add and
-    activation fusions (docs/KERNELS.md "Fused epilogues")."""
-    from .pallas_epilogue import bias_gelu_available, pallas_bias_gelu
-    if bias_gelu_available(data.shape, data.dtype, bias.dtype):
-        return pallas_bias_gelu(data, bias)
+    """GeLU(data + bias), exact erf form: the Dense->GeLU FFN
+    epilogue."""
     return jax.nn.gelu(data + bias, approximate=False)
 
 
 @register("_contrib_bias_add_residual")
 def bias_add_residual(data, bias, residual):
-    """data + bias + residual in one sweep — the projection/FFN output
-    epilogue feeding the post-attention LayerNorm."""
-    from .pallas_epilogue import (bias_residual_available,
-                                  pallas_bias_residual)
-    if data.shape == residual.shape and bias_residual_available(
-            data.shape, data.dtype, bias.dtype, residual.dtype):
-        return pallas_bias_residual(data, bias, residual)
+    """data + bias + residual: the projection/FFN output epilogue
+    feeding the post-attention LayerNorm."""
     return data + bias + residual
 
 

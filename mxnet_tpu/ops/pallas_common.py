@@ -8,8 +8,7 @@ Mosaic kernel (the TPU compiler refuses it). Two answers live here:
 * a kernel with no rule stands down: :func:`kernels_allowed` is False
   inside :func:`auto_partitioned` on a mesh of several devices, and the
   op takes its XLA composition (every decoder kernel, and the
-  layer-norm and epilogue kernels, which a shard each lost to XLA's
-  fusions);
+  layer-norm kernel, which a shard each lost to XLA's fusions);
 * a kernel whose mathematics is local to a sample and that pays (packed
   self-attention, dropout) runs once a shard on the shard's own rows:
   :func:`split_of` says on which dimension, :func:`per_shard` wraps the

@@ -69,7 +69,9 @@ def pallas_ln_available(shape, dtype, axis):
     # partitioned BERT-base step these kernels, a shard each, read
     # 4,507 samples/s where XLA's fusions of the composition (with the
     # residual add before it) read 4,620 (PERF.md section 6, PR 45), so
-    # on a mesh they stand down
+    # on a mesh they stand down. On one chip they earn their place:
+    # off reads 1,244 -> 1,201 samples/s at 128 positions and 268.6 ->
+    # 268.2 at 512 (PERF.md section 6, PR 48)
     if not _cfg("MXNET_PALLAS_LAYERNORM") or not kernels_allowed():
         return False
     if len(shape) < 2 or axis != len(shape) - 1:

@@ -85,25 +85,6 @@ def test_the_op_at_512_positions_compiles_to_its_two_kernels(one_chip,
     assert "bernoulli" not in text and "dot(" not in text
 
 
-def test_bias_gelu(one_chip, compiled_mode):
-    from mxnet_tpu.ops.pallas_epilogue import (bias_gelu_available,
-                                               pallas_bias_gelu)
-    assert bias_gelu_available((L, N, 4 * C), BF, BF)
-    shapes = [(L, N, 4 * C), (4 * C,)]
-    assert _custom_calls(one_chip, pallas_bias_gelu, *shapes) == 1
-    grad = jax.grad(lambda x, b: sum32(pallas_bias_gelu(x, b)),
-                    argnums=(0, 1))
-    assert _custom_calls(one_chip, grad, *shapes) >= 1
-
-
-def test_bias_residual(one_chip, compiled_mode):
-    from mxnet_tpu.ops.pallas_epilogue import (bias_residual_available,
-                                               pallas_bias_residual)
-    assert bias_residual_available((L, N, C), BF, BF, BF)
-    shapes = [(L, N, C), (C,), (L, N, C)]
-    assert _custom_calls(one_chip, pallas_bias_residual, *shapes) == 1
-
-
 def test_dropout(one_chip, compiled_mode):
     from mxnet_tpu.ops.pallas_dropout import (pallas_dropout,
                                               pallas_dropout_available)
@@ -189,11 +170,7 @@ NB = 512    # the dp4 cell's batch: 128 a chip (and not the length:
      [((L, NB, 3 * H * D), "rows")], 2),
     (_op("LayerNorm"), [((L, NB, C), "rows"), ((C,), None), ((C,), None)],
      0),
-    (_op("_contrib_bias_gelu"),
-     [((L, NB, 4 * C), "rows"), ((4 * C,), None)], 0),
-    (_op("_contrib_bias_add_residual"),
-     [((L, NB, C), "rows"), ((C,), None), ((L, NB, C), "rows")], 0),
-], ids=["dropout", "attention", "norm", "gelu", "residual"])
+], ids=["dropout", "attention", "norm"])
 def test_a_bert_kernel_compiles_once_a_shard_on_a_split_batch(
         four_chips, compiled_mode, op, shapes, calls):
     """ISSUE 45: value and gradient through the op on a described
@@ -224,30 +201,20 @@ def test_a_bert_kernel_compiles_once_a_shard_on_a_split_batch(
     assert found["all-reduce"] <= 2
 
 
-# the whole depth compiles in 40 s alone: `slow`, its two-layer twin not
-@pytest.mark.parametrize("layers", [2, pytest.param(
-    12, marks=pytest.mark.slow)])
-def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
-        four_chips, layers, monkeypatch):
-    """The ``bert_base_pretrain_s128_dp4`` step (the zoo model through
+def _bert_step(mesh, layers, seq, batch, monkeypatch):
+    """The BERT-base pretraining step (the zoo model through
     ``trace_block``, bf16 on float32 masters, dropout 0.1, LAMB through
-    the shared ``_apply_update``) compiled for the described 2x2 with
-    512 samples split four ways: a layer's attention forward and
-    backward and dropout's kernels as ``tpu_custom_call``s (50 at 12
-    layers: the one-chip step's 138 less the 52 layer-norm and the 36
-    epilogue calls, which keep their compositions on a mesh), no
-    all-gather or all-to-all, four all-reduces (loss and gradients,
-    combined).
-    Traced, the step counts its 12 attention calls under
-    ``path="pallas"`` and every kernel ``how="sharded"``, none
-    ``composition``. Temporaries: PERF.md section 6, PR 45."""
+    the shared ``_apply_update``) compiled for the described devices of
+    ``mesh`` with ``batch`` samples split over them, traced in the scope
+    ``ShardedTrainStep`` opens. Returns the compiled step, its Mosaic
+    calls by kernel, how the trace moved the kernels' counters, and its
+    collectives by kind."""
     from mxbench import manifest
     from mxnet_tpu import telemetry
     from mxnet_tpu.ops import pallas_common
     from mxnet_tpu.parallel.sharded import _apply_update, trace_block
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh, _, rep = four_chips
-    seq, batch = 128, 512
+    rep = NamedSharding(mesh, P())
     sizes, cfgmod, _ = manifest.config("bert_base")
     net, loss, n_in = cfgmod.sharded_parts(
         dict(sizes, num_hidden_layers=layers), 0.1, seq)
@@ -280,19 +247,16 @@ def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
     params = {n: sds(shapes[n]) for n in names}
     ids = sds((batch, seq), jnp.int32, NamedSharding(mesh, P("dp")))
     key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=rep)
-    drops = layers + 1
-    traced = {("mx_attn_selfatt_path_total", ("path", "pallas")): layers,
-              ("mx_attn_selfatt_path_total", ("path", "xla")): 0}
-    for kernel, n in (("pallas_selfatt_packed", layers),
-                      ("pallas_dropout", drops)):
-        traced["mx_pallas_partitioned_total", ("kernel", kernel),
-               ("how", "sharded")] = n
-        traced["mx_pallas_partitioned_total", ("kernel", kernel),
-               ("how", "composition")] = 0
+    counters = [("mx_attn_selfatt_path_total", ("path", path))
+                for path in ("pallas", "xla")]
+    counters += [("mx_pallas_partitioned_total", ("kernel", kernel),
+                  ("how", how))
+                 for kernel in ("pallas_selfatt_packed", "pallas_dropout")
+                 for how in ("sharded", "composition")]
 
     def read():
         return {k: telemetry.counter(k[0], **dict(k[1:])).get()
-                for k in traced}
+                for k in counters}
 
     was = telemetry.enabled()
     telemetry.enable(True)
@@ -301,7 +265,7 @@ def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
         compiled = jax.jit(step).lower(
             params, {n: (params[n], params[n]) for n in names}, sds(()),
             key, ids, ids, ids).compile()
-        assert {k: n - start[k] for k, n in read().items()} == traced
+        traced = {k: n - start[k] for k, n in read().items()}
     finally:
         telemetry.enable(was)
     text = compiled.as_text()
@@ -310,14 +274,68 @@ def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
         name = re.findall(r"pallas_(?!call)\w+", re.search(
             r'op_name="([^"]*)"', line).group(1))[-1]
         calls[name] = calls.get(name, 0) + 1
-    assert calls == {
-        "pallas_selfatt_packed_fwd": layers,
-        "pallas_selfatt_packed_bwd": layers,
-        "pallas_dropout_fwd": drops, "pallas_dropout_bwd": drops}
-    found = _collectives(text)
+    return compiled, calls, traced, _collectives(text)
+
+
+def _attention_and_dropout(layers, how=None):
+    """A layer's attention forward and backward and dropout's kernels
+    (one more dropout after the embedding), and what tracing them
+    counts: ``how`` they ran on a mesh."""
+    calls = {"pallas_selfatt_packed_fwd": layers,
+             "pallas_selfatt_packed_bwd": layers,
+             "pallas_dropout_fwd": layers + 1,
+             "pallas_dropout_bwd": layers + 1}
+    traced = {("mx_attn_selfatt_path_total", ("path", "pallas")): layers,
+              ("mx_attn_selfatt_path_total", ("path", "xla")): 0}
+    for kernel, n in (("pallas_selfatt_packed", layers),
+                      ("pallas_dropout", layers + 1)):
+        for h in ("sharded", "composition"):
+            traced["mx_pallas_partitioned_total", ("kernel", kernel),
+                   ("how", h)] = n if h == how else 0
+    return calls, traced
+
+
+# the whole depth compiles in 40 s alone: `slow`, its two-layer twin not
+@pytest.mark.parametrize("layers", [2, pytest.param(
+    12, marks=pytest.mark.slow)])
+def test_the_bert_dp4_step_at_128_a_chip_holds_every_kernel(
+        four_chips, layers, monkeypatch):
+    """The ``bert_base_pretrain_s128_dp4`` step compiled for the
+    described 2x2 with 512 samples split four ways: a layer's attention
+    forward and backward and dropout's kernels as ``tpu_custom_call``s
+    (50 at 12 layers: the one-chip step's 102 less the 52 layer-norm
+    calls, which keep their composition on a mesh), no all-gather or
+    all-to-all, four all-reduces (loss and gradients, combined).
+    Traced, the step counts its 12 attention calls under
+    ``path="pallas"`` and every kernel ``how="sharded"``, none
+    ``composition``. Temporaries: PERF.md section 6, PR 45."""
+    compiled, calls, traced, found = _bert_step(four_chips[0], layers, 128,
+                                                512, monkeypatch)
+    assert (calls, traced) == _attention_and_dropout(layers, "sharded")
     assert found["all-gather"] == found["all-to-all"] == 0
     assert found["all-reduce"] <= 4
     if layers == 12:
         assert found["all-reduce"] == 4
         # 8.37 GB in the parent's own step on the chip
         assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+@pytest.mark.parametrize("layers", [2, pytest.param(
+    12, marks=pytest.mark.slow)])
+def test_the_one_chip_bert_step_holds_no_epilogue_kernel(
+        four_chips, layers, monkeypatch):
+    """The ``bert_base_pretrain_s128`` step (256 x 128 on one described
+    chip): the attention and dropout calls of the ``dp4`` step and the
+    layer norm's (two a layer, the embedding's and the head's, forward
+    and backward), 102 at 12 layers, and no other. The Dense epilogues are
+    XLA's fusions here as on a mesh (PR 48: 138 calls before it, 36 of
+    them ``pallas_bias_gelu_*`` / ``pallas_residual_fwd``), and on one
+    device nothing is wrapped a shard."""
+    mesh = four_chips[0]
+    one = type(mesh)(mesh.devices.reshape(-1)[:1], ("dp",))
+    _, calls, traced, found = _bert_step(one, layers, 128, 256, monkeypatch)
+    want, counted = _attention_and_dropout(layers)
+    want.update(pallas_layer_norm_fwd=2 * layers + 2,
+                pallas_layer_norm_bwd=2 * layers + 2)
+    assert (calls, traced) == (want, counted)
+    assert sum(found.values()) == 0

@@ -3,9 +3,10 @@
 of several devices the packed self-attention and the dropout kernels
 run once a shard, on the shard's own rows (``pallas_common.per_shard``:
 a ``jax.shard_map`` over the batch axes), and give what the one-device
-kernel gives on the whole array. The layer-norm and the two epilogue
-kernels, which a shard each lost to XLA's fusions on the chip (PERF.md
-section 6, PR 45), and every decoder kernel keep their compositions.
+kernel gives on the whole array. The layer-norm kernel, which a shard
+each lost to XLA's fusions on the chip (PERF.md section 6, PR 45), and
+every decoder kernel keep their compositions; the two Dense epilogues
+are compositions everywhere (PR 48).
 Four host devices, kernels interpreted; what Mosaic and the v5e:2x2
 compiler say of the same calls is ``tests/test_chip_compile_*.py``'s, the
 in-kernel PRNG's masks ``chip_smoke.py --chips 4``'s.
@@ -19,7 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import (get_op, pallas_attention, pallas_common,
-                           pallas_dropout, pallas_epilogue, pallas_norm)
+                           pallas_dropout, pallas_norm)
 from mxnet_tpu.ops.pallas_common import auto_partitioned
 
 SHARDS = 4
@@ -394,9 +395,10 @@ def test_the_decoder_kernels_still_stand_down():
                                         ("_contrib_bias_gelu", 2),
                                         ("_contrib_bias_add_residual", 3)])
 def test_the_kernels_that_lost_keep_their_compositions_on_a_mesh(op, n_args):
-    """The layer norm and the two epilogues have no per-shard rule: a
-    shard each they lost to XLA's own fusions in the dp=4 BERT step
-    (PERF.md section 6, PR 45). One device: the kernel, as ever."""
+    """The layer norm has no per-shard rule and the two epilogues no
+    kernel: a shard each all three lost to XLA's own fusions in the
+    dp=4 BERT step (PERF.md section 6, PR 45), the epilogues on one
+    chip too (PR 48). One device: the layer norm's kernel, as ever."""
     shape = _shape("LNC")
     args = [_randn(shape, jnp.bfloat16), _randn((C,), jnp.bfloat16, 1),
             _randn(shape if op != "LayerNorm" else (C,), jnp.bfloat16, 2)]
@@ -412,15 +414,12 @@ def test_the_kernels_that_lost_keep_their_compositions_on_a_mesh(op, n_args):
         with auto_partitioned(_mesh(), batch=("dp", BATCH)):
             assert not pallas_norm.pallas_ln_available(shape, jnp.bfloat16,
                                                        2)
-            assert not pallas_epilogue.bias_gelu_available(shape,
-                                                           jnp.bfloat16)
-            assert not pallas_epilogue.bias_residual_available(
-                shape, jnp.bfloat16)
             on_mesh = ops()
         with auto_partitioned(_mesh(1), batch=("dp", BATCH)):
             on_one = ops()
     assert "pallas_call" not in on_mesh and "shard_map" not in on_mesh
-    assert on_one.count("pallas_call") == 1
+    assert on_one.count("pallas_call") == (op == "LayerNorm")
+    assert "shard_map" not in on_one
     assert counts.got == {}
 
 

@@ -14,10 +14,7 @@ assertion for the new programs.
    reference-packed QKV layout directly vs the unfused
    interleaved-matmul composition, fwd+bwd at the BERT-base attention
    shape (L=128, N=32, 12 heads, hd=64).
-4. **Fused epilogues** (round 7): _contrib_bias_gelu /
-   _contrib_bias_add_residual Pallas kernels vs their XLA
-   compositions at the BERT FFN shapes.
-5. **Zero steady-state recompiles**: every program above is a
+4. **Zero steady-state recompiles**: every program above is a
    compilewatch.WatchedJit; after warmup, further calls may not compile
    anything (the recompile-storm regression gate for the new kernels).
 
@@ -178,55 +175,6 @@ def build_pairs(small):
                               fn_label="micro.attn_unfused",
                               site="kernel_micro"),
                   (qkv, seeds)))
-
-    # -- fused epilogues (round 7) --------------------------------------
-    from mxnet_tpu.ops.pallas_epilogue import (
-        pallas_bias_gelu, bias_gelu_available,
-        pallas_bias_residual, bias_residual_available)
-
-    Me, Ce = (64, 32) if small else (4096, 3072)
-    xe = jnp.asarray(rng.randn(Me, Ce).astype(np.float32)).astype(dtype)
-    be = jnp.asarray(rng.randn(Ce).astype(np.float32)).astype(dtype)
-    re_ = jnp.asarray(rng.randn(Me, Ce).astype(np.float32)).astype(dtype)
-    assert bias_gelu_available((Me, Ce), dtype, dtype)
-    assert bias_residual_available((Me, Ce), dtype, dtype, dtype)
-
-    def gelu_pallas(x, b):
-        def s(x, b):
-            return jnp.sum(pallas_bias_gelu(x, b).astype(jnp.float32))
-        return jax.grad(s, argnums=(0, 1))(x, b)
-
-    def gelu_xla(x, b):
-        def s(x, b):
-            return jnp.sum(jax.nn.gelu(x + b, approximate=False)
-                           .astype(jnp.float32))
-        return jax.grad(s, argnums=(0, 1))(x, b)
-
-    pairs.append(("bias_gelu",
-                  watched_jit(gelu_pallas, fn_label="micro.gelu_pallas",
-                              site="kernel_micro"),
-                  watched_jit(gelu_xla, fn_label="micro.gelu_xla",
-                              site="kernel_micro"),
-                  (xe, be)))
-
-    def resid_pallas(x, b, r):
-        def s(x, b, r):
-            return jnp.sum(pallas_bias_residual(x, b, r)
-                           .astype(jnp.float32))
-        return jax.grad(s, argnums=(0, 1, 2))(x, b, r)
-
-    def resid_xla(x, b, r):
-        def s(x, b, r):
-            return jnp.sum((x + b + r).astype(jnp.float32))
-        return jax.grad(s, argnums=(0, 1, 2))(x, b, r)
-
-    pairs.append(("bias_residual",
-                  watched_jit(resid_pallas,
-                              fn_label="micro.resid_pallas",
-                              site="kernel_micro"),
-                  watched_jit(resid_xla, fn_label="micro.resid_xla",
-                              site="kernel_micro"),
-                  (xe, be, re_)))
     return pairs
 
 
