@@ -256,9 +256,17 @@ def publish_expert_rows(aux):
     """Publish the expert layers' row counts from their auxiliary
     states (``ShardedTrainStep.aux``, or any ``{name: array}`` holding
     ``*expert_rows``): gauges ``mx_moe_expert_rows{block, expert}`` (rows
-    routed to each held expert in the last step) and the counter
+    routed to each held expert in the last step), the counter
     ``mx_moe_dropped_rows_total`` (rows routed to a held expert that
-    its product did not compute: the layer is dropless, so it stays 0).
+    its product did not compute: the layer is dropless, so it stays 0)
+    and, where the op recorded its buffer's shape as it was traced
+    (``mx_moe_buffer_shape``: telemetry on, this process),
+    ``mx_moe_buffer_blocks{block, state}``: the blocks of the sorted
+    buffer that hold a routed row (``computed``: each expert's rows in
+    whole blocks, which is what the grouped kernels multiply; more than
+    ``held`` where the routing overfilled the buffer and the dense
+    product ran instead) and the blocks the buffer has (``held``: what
+    the composition multiplies).
     Returns ``{block: routed counts}``. One device-to-host read: call
     it after a window, not inside one."""
     import jax
@@ -275,4 +283,12 @@ def publish_expert_rows(aux):
                             expert=str(e)).set(float(r))
         telemetry.counter("mx_moe_dropped_rows_total").inc(
             float(np.sum(routed) - np.sum(done)))
+        block_rows, blocks = (
+            telemetry.gauge("mx_moe_buffer_shape", held=str(len(routed)),
+                            dim=dim).get() for dim in ("block_rows", "blocks"))
+        if block_rows > 0:      # (0: none recorded; -1: more than one)
+            computed = float(np.sum(np.ceil(out[block] / block_rows)))
+            for state, n in (("computed", computed), ("held", blocks)):
+                telemetry.gauge("mx_moe_buffer_blocks", block=block,
+                                state=state).set(n)
     return out

@@ -1151,8 +1151,11 @@ def _slots_to_rows(held, local, n_held, cap, block):
     ``block`` rows: the slot's row number, the (padded) rows before its
     expert plus its rank among the expert's (``cap``, one past the end,
     for a slot that is not held here or falls beyond the buffer); the
-    routed count of each held expert; the expert of each block; and
-    whether the padded runs fit the buffer."""
+    routed count of each held expert and how many of its slots have a
+    row inside the buffer; the expert of each block; how many blocks
+    hold a routed row (the runs are packed, so they are the buffer's
+    first and the rest is an empty tail); and whether the padded runs
+    fit the buffer."""
     t, k = held.shape
     onehot = (local.reshape(-1, 1) == jnp.arange(n_held)) \
         & held.reshape(-1, 1)
@@ -1161,11 +1164,13 @@ def _slots_to_rows(held, local, n_held, cap, block):
     ends = jnp.cumsum(-(-counts // block) * block)
     first = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
     row = jnp.sum(jnp.where(onehot, rank + first, 0), axis=1).reshape(t, k)
+    starts = jnp.arange(0, cap, block)
     expert_of_block = jnp.minimum(
-        jnp.sum(jnp.arange(0, cap, block)[:, None] >= ends, axis=1),
-        n_held - 1)
-    return (jnp.where(held & (row < cap), row, cap), counts,
-            expert_of_block, ends[-1] <= cap)
+        jnp.sum(starts[:, None] >= ends, axis=1), n_held - 1)
+    placed = held & (row < cap)
+    done = jnp.sum(onehot & placed.reshape(-1, 1), axis=0, dtype=jnp.int32)
+    return (jnp.where(placed, row, cap), (counts, done), expert_of_block,
+            jnp.sum(starts < ends[-1], dtype=jnp.int32), ends[-1] <= cap)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -1206,23 +1211,26 @@ _sum_slots.defvjp(
 _HIDDEN = "mx.moe.experts.hidden"   # what a chunk of blocks keeps
 
 
-def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act, kernel):
+def _blocks_product(xr, expert_of_block, used, weight_of_row, up, down, act,
+                    kernel):
     """Each block of ``xr`` (blocks, rows, hidden) through its expert's
     two products, each row times its slot's weight: (blocks x rows,
-    hidden) in ``xr``'s dtype, every block computed, by the schedule
-    :func:`_moe_experts` picked (``kernel``). The grouped
-    kernels take the whole buffer in one call and read each block's
-    weight tiles where the experts' arrays hold them; their backward
-    sums the weight gradients by expert in VMEM. In the composition
-    every block reads a copy of its expert's weights, and the backward
-    writes a float32 gradient a block before summing by expert; so up
-    to ``BLOCKS_AT_ONCE`` blocks that is one batched product, and
-    beyond it a loop over chunks of blocks, each chunk keeping its
-    first product's output and gathering its weights again in the
-    backward: the copies that exist at once are a chunk's, not the
-    buffer's, and the float32 rows a chunk's (144 blocks of 8.3 M
-    weights at 16,384 tokens over 16 experts of width 896: 9.5 GB of
-    temporaries as one product)."""
+    hidden) in ``xr``'s dtype, by the schedule :func:`_moe_experts`
+    picked (``kernel``). The grouped kernels take the whole buffer in
+    one call, read each block's weight tiles where the experts' arrays
+    hold them and compute the first ``used`` blocks, those that hold a
+    routed row (the empty tail past them is written as the zeros it
+    would come to, no product run); their backward sums the weight
+    gradients by expert in VMEM. The composition computes every block,
+    whatever ``used``: every block reads a copy of its expert's
+    weights, and the backward writes a float32 gradient a block before
+    summing by expert; so up to ``BLOCKS_AT_ONCE`` blocks that is one
+    batched product, and beyond it a loop over chunks of blocks, each
+    chunk keeping its first product's output and gathering its weights
+    again in the backward: the copies that exist at once are a chunk's,
+    not the buffer's, and the float32 rows a chunk's (144 blocks of
+    8.3 M weights at 16,384 tokens over 16 experts of width 896: 9.5 GB
+    of temporaries as one product)."""
     def product(xb, eb, kept=lambda pre: pre):
         pre = kept(_mm("bmd,bfd->bmf", xb, up[eb]))
         return _mm("bmf,bdf->bmd", act(pre).astype(xb.dtype), down[eb])
@@ -1230,8 +1238,8 @@ def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act, kernel):
     n, block = xr.shape[:2]
     if kernel:
         return pallas_grouped_mlp.grouped_mlp(
-            xr.reshape(n * block, -1), expert_of_block, weight_of_row, up,
-            down, act)
+            xr.reshape(n * block, -1), expert_of_block, used, weight_of_row,
+            up, down, act)
     if n <= BLOCKS_AT_ONCE:
         yr = product(xr, expert_of_block).reshape(n * block, -1)
         return (yr * weight_of_row[:, None]).astype(xr.dtype)
@@ -1253,18 +1261,20 @@ def _blocks_product(xr, expert_of_block, weight_of_row, up, down, act, kernel):
         .reshape(n * block, -1)
 
 
-def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act,
-                    kernel, sums):
+def _experts_sorted(x, row, w_slot, expert_of_block, used, up, down, block,
+                    act, kernel, sums):
     """Rows gathered into one buffer sorted by expert, whole blocks an
-    expert (:func:`_gather_rows`: XLA's gather); one batched product
-    over the blocks, each against its expert's weights (the same work
-    whatever the routing: a block is computed whole, rows that no slot
-    fills are zeros); summed back by token (:func:`_sum_slots`: the
-    window kernel of ``ops/pallas_moe_rows.py`` where ``sums``, which
-    reads the sorted buffer in short contiguous stretches, else XLA's
-    gather of every slot's row). Also the rows of each expert that were
-    computed."""
-    t, n_held = x.shape[0], up.shape[0]
+    expert (:func:`_gather_rows`: XLA's gather); the blocks' products,
+    each against its expert's weights (:func:`_blocks_product`: a block
+    that holds a routed row is computed whole, the rows of it that no
+    slot fills being zeros; the buffer is packed, so the blocks from
+    ``used`` on are an empty tail, which the grouped kernels skip and
+    the composition computes as zeros); summed back by token
+    (:func:`_sum_slots`: the window kernel of
+    ``ops/pallas_moe_rows.py`` where ``sums``, which reads the sorted
+    buffer in short contiguous stretches, else XLA's gather of every
+    slot's row)."""
+    t = x.shape[0]
     cap = expert_of_block.shape[0] * block
     slots = jnp.broadcast_to(jnp.arange(t)[:, None], row.shape)
     token_of_row = jnp.full((cap + 1,), t, jnp.int32) \
@@ -1273,13 +1283,9 @@ def _experts_sorted(x, row, w_slot, expert_of_block, up, down, block, act,
         .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1]
     xr = _gather_rows(x, token_of_row, row, sums) \
         .reshape(-1, block, x.shape[1])
-    yr = _blocks_product(xr, expert_of_block, weight_of_row, up, down, act,
-                         kernel)
-    filled = (token_of_row < t).reshape(-1, block)
-    done = jnp.sum(jnp.where(
-        expert_of_block[:, None] == jnp.arange(n_held),
-        jnp.sum(filled, axis=1, dtype=jnp.int32)[:, None], 0), axis=0)
-    return _sum_slots(yr, token_of_row, row, sums), done
+    yr = _blocks_product(xr, expert_of_block, used, weight_of_row, up, down,
+                         act, kernel)
+    return _sum_slots(yr, token_of_row, row, sums)
 
 
 def _experts_dense(x, held, local, w_slot, counts, up, down, act):
@@ -1306,22 +1312,22 @@ def _experts_dense(x, held, local, w_slot, counts, up, down, act):
 
 
 def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel, sums):
-    """(the held experts' terms summed by token, the rows of each that
-    were computed): the sorted buffer where the routing fits it. No row
-    is dropped: routing that overfills the buffer takes the dense
+    """The held experts' terms summed by token: the sorted buffer where
+    the routing fits it. No row is dropped: routing that overfills the
+    buffer takes the dense
     product over the held experts instead (whole matrices at the MXU's
     pace: where routing piles the tokens on a few experts, cheaper than
     more passes of the gathered product, which PR 28 measured at 5x its
-    cost). ``routing``: :func:`_slots_to_rows`' four results, ``held``
-    and ``local``; ``kernel`` / ``sums``: whether the buffer's products
-    / its slot sum are Pallas kernels."""
-    row, counts, expert_of_block, fits, held, local = routing
+    cost). ``routing``: :func:`_slots_to_rows`' row of each slot, routed
+    counts, expert of each block, blocks that hold a row and whether
+    the runs fit, then ``held`` and ``local``; ``kernel`` / ``sums``:
+    whether the buffer's products / its slot sum are Pallas kernels."""
+    row, counts, expert_of_block, used, fits, held, local = routing
     return lax.cond(
         fits,
-        lambda: _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                block, act, kernel, sums),
-        lambda: (_experts_dense(x, held, local, w_slot, counts, w1, w2, act),
-                 counts))
+        lambda: _experts_sorted(x, row, w_slot, expert_of_block, used, w1,
+                                w2, block, act, kernel, sums),
+        lambda: _experts_dense(x, held, local, w_slot, counts, w1, w2, act))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -1345,20 +1351,20 @@ def _kept_by_inputs_fwd(x, w_slot, w1, w2, routing, block, act, sums):
         (x, w_slot, w1, w2, routing)
 
 
-def _kept_by_inputs_bwd(block, act, sums, res, cotangents):
+def _kept_by_inputs_bwd(block, act, sums, res, cotangent):
     x, w_slot, w1, w2, routing = res
-    row, counts, expert_of_block, fits, held, local = routing
+    row, counts, expert_of_block, used, fits, held, local = routing
 
     def pullback(branch):
-        return lambda: jax.vjp(branch, x, w_slot, w1, w2)[1](cotangents[0])
+        return lambda: jax.vjp(branch, x, w_slot, w1, w2)[1](cotangent)
 
     # (the rule is traced after the caller's scopes have closed)
     with jax.named_scope("mx.moe"), jax.named_scope("mx.moe.experts"):
         grads = lax.cond(
             fits,
             pullback(lambda x, w_slot, w1, w2: _experts_sorted(
-                x, row, w_slot, expert_of_block, w1, w2, block, act,
-                True, sums)[0]),
+                x, row, w_slot, expert_of_block, used, w1, w2, block, act,
+                True, sums)),
             pullback(lambda x, w_slot, w1, w2: _experts_dense(
                 x, held, local, w_slot, counts, w1, w2, act)))
     # the four gradients leave together: without the barrier the TPU
@@ -1396,7 +1402,7 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
     held = (local >= 0) & (local < n_held)
     block, blocks, most = _buffer(t, top_k, n_held, n_routed,
                                   capacity_factor, block_rows)
-    row, counts, expert_of_block, fits = _slots_to_rows(
+    row, (counts, placed), expert_of_block, used, fits = _slots_to_rows(
         held, local, n_held, blocks * block, block)
     # the buffer's products by whichever schedule the call allows,
     # chosen from what can be observed here and nothing else (bf16 rows
@@ -1407,23 +1413,37 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
         jax.ShapeDtypeStruct((blocks, block, x.shape[1]), x.dtype), w1, w2)
     telemetry.count_event("mx_moe_experts_path_total",
                           path="pallas" if kernel else "xla")
+    # the buffer's shape beside it, under the one fact of the layer that
+    # its ``expert_rows`` state shows too (the experts held): what the
+    # state's publisher turns routed rows into blocks with. Another
+    # shape under the same count cannot be told from this one there:
+    # -1, and the publisher leaves such layers out
+    if telemetry.enabled():
+        shape = [telemetry.gauge("mx_moe_buffer_shape", held=str(n_held),
+                                 dim=dim) for dim in ("block_rows", "blocks")]
+        one = [g.get() for g in shape] in ([0, 0], [block, blocks])
+        for g, size in zip(shape, (block, blocks)):
+            g.set(size if one else -1)
     # and the sum of a token's rows back out of the buffer likewise
     # (bf16 rows of whole lane tiles, a buffer of whole windows)
     sums = pallas_moe_rows.sum_available(
         jax.ShapeDtypeStruct((blocks * block, x.shape[1]), x.dtype), top_k, t)
     telemetry.count_event("mx_moe_rows_path_total",
                           path="pallas" if sums else "xla")
-    routing = (row, counts, expert_of_block, fits, held, local)
+    routing = (row, counts, expert_of_block, used, fits, held, local)
     with jax.named_scope("mx.moe.experts"):
         if blocks >= most:
-            y, done = _experts_sorted(x, row, w_slot, expert_of_block, w1, w2,
-                                      block, act, kernel, sums)
+            y = _experts_sorted(x, row, w_slot, expert_of_block, used, w1, w2,
+                                block, act, kernel, sums)
         elif kernel:
-            y, done = _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing,
-                                                 block, act, sums)
+            y = _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block,
+                                           act, sums)
         else:
-            y, done = _held_terms(x, w_slot, w1, w2, routing, block, act,
-                                  False, sums)
+            y = _held_terms(x, w_slot, w1, w2, routing, block, act, False,
+                            sums)
+    # the rows of each expert that were computed: in the buffer those of
+    # its slots that have a row there, in the dense product all of them
+    done = jnp.where(fits, placed, counts)
     return y, jnp.stack([counts, done]).astype(F32)
 
 
@@ -1445,10 +1465,12 @@ _MOE_DOC = """
     the rows are moved. Rows are gathered (XLA's gather), sorted by
     expert and padded to whole blocks of ``BLOCK_ROWS`` an expert, into
     one buffer of ``CAPACITY_FACTOR`` times the held experts' even
-    share (plus a block an expert), every block multiplied by its
-    expert's weights (grouped Pallas kernels where the call allows
-    them, :func:`_blocks_product`, else one batched product over the
-    blocks): the same work whatever the routing fills it with; and
+    share (plus a block an expert), and the blocks are multiplied by
+    their experts' weights (:func:`_blocks_product`): by grouped Pallas
+    kernels where the call allows them, which compute the blocks that
+    hold a routed row and skip the buffer's empty tail, so their work
+    follows the routing; else by one batched product over every block,
+    the same work whatever the routing fills the buffer with; and
     each token's rows summed back (the window kernel of
     ``ops/pallas_moe_rows.py`` where the call allows it, else XLA's
     gather of every slot's row).

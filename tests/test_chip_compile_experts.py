@@ -133,7 +133,8 @@ EXPERT_CELLS = {
 @pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
 def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
         one_chip, compiled_mode, compiled, cell):
-    """Mosaic accepts the three kernels at both cells' widths; a step's
+    """Mosaic accepts the three kernels, the skipped tail's branches
+    and clamped index maps with them, at both cells' widths; a step's
     seven calls (two
     forward, the first again in the mixer's recomputation, four
     backward) are placed under the scope the benchmark reads; no
@@ -156,7 +157,11 @@ def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
     assert set(kernels.values()) == {"mx.moe.experts"}
     assert sorted(re.search("pallas_grouped_mlp_(dw|nn|nt)", n).group(1)
                   for n in kernels) == ["dw"] * 2 + ["nn"] * 2 + ["nt"] * 3
-    assert "s32[%d]" % blocks in text
+    # the two scalar prefetches lead each call's operands: the expert of
+    # each block, and how many blocks hold a routed row (the rest are
+    # skipped)
+    prefetched = "operand_layout_constraints={s32[%d]{0}, s32[1]{0}, " % blocks
+    assert sum(prefetched in c for c in calls) == 7
     for out, inner in ((mul * width, hidden), (hidden, width)):
         assert "bf16[%d,%d,%d]" % (blocks, out, inner) not in text
         assert "f32[%d,%d,%d]" % (blocks, out, inner) not in text

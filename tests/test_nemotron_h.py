@@ -3,6 +3,7 @@ widths on the CPU: the blocks against the benchmark's plain float32
 reference, the auxiliary states through ``ShardedTrainStep``, the
 expert rows' gauges, and integer inputs through the sharded step."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -136,6 +137,83 @@ def test_expert_rows_are_published_and_nothing_is_dropped():
         {k: v.data() for k, v in net.collect_params().items()})
     assert eager["layers1"].sum() > 0
     assert telemetry.counter("mx_moe_dropped_rows_total").value == 0
+
+
+@pytest.mark.parametrize("shape_recorded", [True, False])
+def test_buffer_blocks_are_published_from_the_routed_rows(shape_recorded):
+    """The op records its buffer's shape as it is traced, where
+    telemetry is on; ``publish_expert_rows`` (one argument, as the
+    benchmark calls it) turns each layer's routed rows into the blocks
+    that hold a row, beside the blocks the buffer has. Traced with
+    telemetry off there is no shape and no such gauge; the rows' gauges
+    and the dropped rows' counter are as before either way."""
+    from mxnet_tpu.ops import decoder_ops as D
+    was = telemetry.enabled()
+    telemetry.reset()
+    telemetry.enable(shape_recorded)
+    try:
+        net, head = _build()
+        step = _a_step(net, head)
+        ids, labels = _batch(1)
+        step.step(_ids(ids), _ids(labels))
+    finally:
+        telemetry.enable(was)
+    rows = zoo.publish_expert_rows(step.aux)
+    assert sorted(rows) == ["layers1", "layers3", "layers6", "layers8"]
+    assert telemetry.counter("mx_moe_dropped_rows_total").value == 0
+    gauges = telemetry.snapshot()["gauges"]
+    assert gauges['mx_moe_expert_rows{block="layers3",expert="2"}'] \
+        == rows["layers3"][2]
+    published = [k for k in gauges if k.startswith("mx_moe_buffer_blocks")]
+    if not shape_recorded:
+        assert not published
+        return
+    block, blocks, _ = D._buffer(ids.size, CFG["num_experts_per_tok"],
+                                 CFG["experts_held"], CFG["n_routed_experts"])
+    assert len(published) == 2 * len(rows) and blocks > 0
+    for name, routed in rows.items():
+        computed, held = (telemetry.gauge("mx_moe_buffer_blocks", block=name,
+                                          state=state).value
+                          for state in ("computed", "held"))
+        assert held == blocks
+        assert computed == sum(-(-int(r) // block) for r in routed) > 0
+
+
+def test_two_buffer_shapes_under_one_count_publish_no_blocks():
+    """Two layers that hold the same number of experts over buffers of
+    different shapes share the one series the op can record: the second
+    marks it, and ``publish_expert_rows`` then publishes the rows as
+    ever and no ``mx_moe_buffer_blocks`` for such layers (not one
+    layer's blocks under another's name)."""
+    from mxnet_tpu.ops import decoder_ops as D
+    was = telemetry.enabled()
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        net, head = _build()
+        step = _a_step(net, head)
+        ids, labels = _batch(1)
+        step.step(_ids(ids), _ids(labels))
+        held = CFG["experts_held"]
+        key = jax.random.key(0)
+        x = jax.random.normal(key, (ids.size // 2, CFG["hidden_size"]))
+        jax.eval_shape(
+            lambda *a: D._moe_experts(
+                *a, top_k=CFG["num_experts_per_tok"], offset=0, scale=1.0,
+                norm_topk=True),
+            x, jnp.zeros((CFG["n_routed_experts"], x.shape[1])), None,
+            jnp.zeros((held, CFG["moe_intermediate_size"], x.shape[1])),
+            jnp.zeros((held, x.shape[1], CFG["moe_intermediate_size"])))
+    finally:
+        telemetry.enable(was)
+    assert telemetry.gauge("mx_moe_buffer_shape", held=str(held),
+                           dim="blocks").value == -1
+    rows = zoo.publish_expert_rows(step.aux)
+    assert sorted(rows) == ["layers1", "layers3", "layers6", "layers8"]
+    gauges = telemetry.snapshot()["gauges"]
+    assert not [k for k in gauges if k.startswith("mx_moe_buffer_blocks")]
+    assert gauges['mx_moe_expert_rows{block="layers3",expert="2"}'] \
+        == rows["layers3"][2]
 
 
 def test_sharded_step_matches_the_reference_in_bfloat16_within_reason():

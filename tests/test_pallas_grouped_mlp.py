@@ -6,10 +6,11 @@ float32 loop over the held experts (the benchmark's own references are
 ``tests/test_decoder_ops.py::test_routed_experts`` /
 ``test_softmax_swiglu_experts``, which run under both paths); the traps
 of the weight-gradient kernel (an expert with no block, the empty blocks
-past the last run, one expert drawing nearly every token); the overflow
-path; the ladder of what the kernels do not serve; the counter. What
-Mosaic makes of the real widths is ``tests/test_chip_compile_*.py``'s to
-say."""
+past the last run, one expert drawing nearly every token); the empty
+tail the kernels skip (``used``: any number of computed blocks gives the
+all-blocks kernels' numbers bit for bit); the overflow path; the ladder
+of what the kernels do not serve; the counter. What Mosaic makes of the
+real widths is ``tests/test_chip_compile_*.py``'s to say."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +19,7 @@ import pytest
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, pallas_common, pallas_moe_rows
 from mxnet_tpu.ops import pallas_grouped_mlp as G
-from numerics import BF, F32, near, normal, rand
+from numerics import BF, F32, near, normal, rand, value_and_grads
 
 COUNTER = "mx_moe_experts_path_total"
 HIDDEN = WIDTH = 128
@@ -40,25 +41,26 @@ ACTS = {"relu2": (D._relu2, 1), "swiglu": (D._swiglu, 2)}
 # 8 blocks over 4 experts: a long run, an expert with no block, a run of
 # one, and two empty blocks mapped to the last expert
 EXPERT_OF_BLOCK = [0, 0, 0, 0, 1, 3, 3, 3]
+USED = 6
 BLOCK = 16
 
 
-def _buffer(seed, act):
+def _buffer(seed, act, eob=EXPERT_OF_BLOCK, used=USED):
     mul = ACTS[act][1]
-    rows = len(EXPERT_OF_BLOCK) * BLOCK
+    rows = len(eob) * BLOCK
     x, up, down = rand(seed, (rows, HIDDEN), (4, mul * WIDTH, HIDDEN),
                        (4, HIDDEN, WIDTH), scale=0.3, dtype=BF)
-    filled = jnp.arange(rows) < 6 * BLOCK       # the last two blocks: empty
+    filled = jnp.arange(rows) < used * BLOCK    # the blocks past: empty
     x = jnp.where(filled[:, None], x, 0).astype(BF)
     w = jnp.where(filled, jax.random.uniform(jax.random.key(seed + 1),
                                              (rows,), F32, 0.1, 1.0), 0.0)
-    return x, jnp.array(EXPERT_OF_BLOCK, jnp.int32), w, up, down
+    return x, jnp.array(eob, jnp.int32), w, up, down
 
 
-def _by_hand(x, eob, w, up, down, act):
+def _by_hand(x, eob, w, up, down, act, experts=EXPERT_OF_BLOCK):
     """Block by block in float32, the casts where the paths make them."""
     out = []
-    for b, e in enumerate(EXPERT_OF_BLOCK):
+    for b, e in enumerate(experts):
         xb = x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
         h = ACTS[act][0](xb @ up[e].astype(F32).T).astype(BF).astype(F32)
         out.append(h @ down[e].astype(F32).T)
@@ -68,13 +70,14 @@ def _by_hand(x, eob, w, up, down, act):
 @pytest.mark.parametrize("act", sorted(ACTS))
 def test_kernels_against_the_composition_and_float32(interpreted, act):
     x, eob, w, up, down = _buffer(1, act)
+    used = jnp.int32(USED)
     fn = ACTS[act][0]
     (cot,) = rand(3, x.shape, dtype=BF)
     cot = cot.astype(F32)
 
     def kernels(x, w, up, down):
-        return jnp.sum(G.grouped_mlp(x, eob, w, up, down, fn).astype(F32)
-                       * cot)
+        return jnp.sum(G.grouped_mlp(x, eob, used, w, up, down, fn)
+                       .astype(F32) * cot)
 
     def composed(x, w, up, down):
         xr = x.reshape(len(EXPERT_OF_BLOCK), BLOCK, HIDDEN)
@@ -88,8 +91,9 @@ def test_kernels_against_the_composition_and_float32(interpreted, act):
     assert G.grouped_mlp_available(
         x.reshape(len(EXPERT_OF_BLOCK), BLOCK, HIDDEN), up, down)
     assert _pallas_calls(jax.grad(kernels, (0, 1, 2, 3)), x, w, up, down) == 6
-    near(jax.jit(lambda *a: G.grouped_mlp(*a, fn))(x, eob, w, up, down),
-         jax.jit(lambda *a: _by_hand(*a, act))(x, eob, w, up, down), 2e-2)
+    near(jax.jit(lambda x, eob, *a: G.grouped_mlp(x, eob, used, *a, fn))(
+        x, eob, w, up, down),
+        jax.jit(lambda *a: _by_hand(*a, act))(x, eob, w, up, down), 2e-2)
     got, by_composition, by_hand = (
         jax.jit(jax.grad(loss, (0, 1, 2, 3)))(x, w, up, down)
         for loss in (kernels, composed, plain))
@@ -105,12 +109,13 @@ def test_kernels_against_the_composition_and_float32(interpreted, act):
 
 
 def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
-    """Whatever an unvisited tile of the output holds (here: what the
-    interpreter left there), ``_dw`` returns zeros for that expert, and
-    the last expert's sum includes the empty blocks' zeros."""
+    """A tile of the output that no block is mapped to keeps the zeros
+    of the array the call writes over: ``_dw`` returns zeros for that
+    expert, and the last expert's sum includes the empty blocks'
+    zeros."""
     g, x = rand(5, (8 * BLOCK, HIDDEN), (8 * BLOCK, WIDTH), dtype=BF)
     eob = jnp.array(EXPERT_OF_BLOCK, jnp.int32)
-    dw = G._dw(g, x, eob, 4)
+    dw = G._dw(g, x, eob, jnp.int32(len(EXPERT_OF_BLOCK)), 4)
     want = jnp.stack([
         sum((g[b * BLOCK:(b + 1) * BLOCK].astype(F32).T
              @ x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
@@ -118,8 +123,75 @@ def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
             jnp.zeros((HIDDEN, WIDTH), F32)) for held in range(4)])
     near(dw, want, 1e-2)
     assert float(jnp.max(jnp.abs(dw[2].astype(F32)))) == 0.0
-    np.testing.assert_array_equal(np.asarray(G.visited(eob, 4)),
-                                  [True, True, False, True])
+    for held in (0, 1, 3):
+        assert float(jnp.max(jnp.abs(dw[held].astype(F32)))) > 0.0
+
+
+# (the expert of each block, the blocks that hold a row): what routing
+# can leave in a packed buffer, the blocks past the last row mapped to
+# the last expert
+TAILS = {
+    # every block skipped: dW all zeros, whatever the accumulator held
+    "no_expert_routed_a_row": ([3] * 8, 0),
+    # the tail's expert is not the last computed block's
+    "one_block_and_a_last_expert_routed_nothing": ([1] + [3] * 7, 1),
+    "a_tail_behind_the_last_experts_run": (EXPERT_OF_BLOCK, USED),
+    # the accumulator holds expert 2's sum where expert 3's run begins
+    "a_tail_and_a_last_expert_routed_nothing": ([0, 0, 0, 1, 2, 2, 3, 3], 6),
+    "no_tail": (EXPERT_OF_BLOCK, len(EXPERT_OF_BLOCK)),
+}
+
+
+@pytest.mark.parametrize("tail", sorted(TAILS))
+def test_skipping_the_empty_tail_changes_no_number(interpreted, tail):
+    """Each kernel alone (``nt`` with and without the row scale, ``nn``,
+    ``dw`` likewise) and ``grouped_mlp``'s value and four gradients,
+    computing ``used`` blocks of a buffer whose other blocks are zero
+    rows of weight 0: the all-blocks kernels' numbers (``used`` = every
+    block) exactly, and the float32 loop's within bf16."""
+    experts, used = TAILS[tail]
+    blocks = len(experts)
+    x, eob, w, up, down = _buffer(21, "swiglu", experts, used)
+    (g,) = rand(23, x.shape, dtype=BF)
+    g = jnp.where((w > 0)[:, None], g, 0).astype(BF)    # no row, no cotangent
+    fn = ACTS["swiglu"][0]
+
+    @jax.jit
+    def kernels(used):
+        out, pull = jax.vjp(lambda x, w, up, down: G.grouped_mlp(
+            x, eob, used, w, up, down, fn), x, w, up, down)
+        return dict(
+            nt=G._rows(x, up, eob, used, True, F32),
+            nt_scaled=G._rows(x, down, eob, used, True, BF, w),
+            nn=G._rows(g, down, eob, used, False, F32),
+            dw=G._dw(g, x, eob, used, 4),
+            dw_scaled=G._dw(g, x, eob, used, 4, w),
+            value=out, grads=pull(g))
+
+    skipping, whole = kernels(jnp.int32(used)), kernels(jnp.int32(blocks))
+    for got, want in zip(jax.tree_util.tree_leaves(skipping),
+                         jax.tree_util.tree_leaves(whole), strict=True):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got.astype(F32)),
+                                      np.asarray(want.astype(F32)))
+    if used:
+        near([skipping["value"], *skipping["grads"]], value_and_grads(
+            lambda x, w, up, down: _by_hand(x, eob, w, up, down, "swiglu",
+                                            experts),
+            x, w, up, down, cot=g), 2e-2)
+    # an expert with no computed block: exact zeros from both weight
+    # gradients and both bare kernels, not a neighbour's sum
+    for e in sorted(set(range(4)) - set(experts[:used])):
+        for dw in (skipping["dw"], skipping["dw_scaled"],
+                   *skipping["grads"][2:]):
+            assert float(jnp.max(jnp.abs(dw[e].astype(F32)))) == 0.0
+    for e in set(experts[:used]):
+        assert float(jnp.max(jnp.abs(skipping["dw"][e].astype(F32)))) > 0.0
+    # and a skipped block's rows
+    for rows in (skipping["nt"], skipping["nt_scaled"], skipping["nn"],
+                 skipping["value"], skipping["grads"][0]):
+        assert float(jnp.max(jnp.abs(rows[used * BLOCK:].astype(F32)),
+                             initial=0.0)) == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +244,11 @@ CASES = {
     # no token to any held expert: every block empty, mapped to the last
     "none_held": lambda: jnp.zeros((16,), F32).at[jnp.array([0, 1])]
     .set(10.0),
+    # the last two held experts routed nothing, the first two a block
+    # each: 2 of the buffer's 8 blocks hold a row, and the tail's expert
+    # is not the last computed block's
+    "quarter_full": lambda: jnp.zeros((16,), F32).at[jnp.array([6, 7])]
+    .set(-10.0),
 }
 
 
@@ -222,6 +299,10 @@ def test_expert_layer_by_the_kernels(interpreted, monkeypatch, case, act):
         assert counts[1] > 0.8 * x.shape[0]
     if case == "none_held":
         assert not counts.any() and float(jnp.max(jnp.abs(y))) == 0.0
+    if case == "quarter_full":
+        block, blocks, _ = D._buffer(x.shape[0], 2, 4, 16)
+        assert (blocks, np.sum(-(-counts // block))) == (8, 2)
+        assert counts[:2].all() and not counts[2:].any()
     for dw in got[2:]:      # an expert routed no row: exact zeros
         for e in np.flatnonzero(counts == 0):
             assert float(jnp.max(jnp.abs(dw[e].astype(F32)))) == 0.0

@@ -47,7 +47,8 @@ def _routing(seed, tokens, hidden, routed, held, top_k, bias=None,
     @jax.jit
     def lay_out(x, r, bias):
         idx, _ = D._route(x, r, bias, top_k, 1.0, True, "softmax")
-        row, _, _, fits = D._slots_to_rows(idx < held, idx, held, cap, block)
+        row, _, _, _, fits = D._slots_to_rows(idx < held, idx, held, cap,
+                                              block)
         slots = jnp.broadcast_to(jnp.arange(tokens)[:, None], row.shape)
         token_of_row = jnp.full((cap + 1,), tokens, jnp.int32) \
             .at[row.reshape(-1)].set(slots.reshape(-1))[:-1]
