@@ -51,11 +51,36 @@ Eight *mixer* ops (``_contrib_mamba2_mixer``,
 ``mixer(RMSNorm(x))``, and are where recomputation lives: the Mamba-2,
 short-convolution, expert, dense gated, rotary, latent and
 sparse-attention mixers are
-``jax.checkpoint``-ed whole, so a training step keeps their input and
-recomputes their inside in the backward (the rotary and the latent one
+``jax.checkpoint``-ed, so a training step keeps their input and
+recomputes their inside in the backward. One rule says what else is
+kept: *a recomputed mixer keeps, beside its input, the output of a
+product that reads its normed input, where the mixer's next stage reads
+that output as it lies* (the backward needs it anyway, so running the
+product again is one whole product a mixer a step for an array small
+beside what the steps leave free); element-wise work, norms, rotary,
+gates, the convolution's taps and the scan are run again. It holds for
+three mixers, counted once a traced call in
+``mx_mixer_kept_total{mixer=}``: the short-convolution one keeps
+``W_in``'s output (its gates read it in its own dtype), the Mamba-2 one
+``in_proj``'s, the rotary one its v projection (the attention kernel's
+operand as it is). The rotary mixer's q and k are not kept: they are
+normed and turned from the product's float32 accumulator, which XLA
+carries through that element-wise work, so a kept rounded copy is a
+second array for the forward to write and one the compiler lays out
+against the rest; before the norms and the turn, or after the turn, it
+measured slower than running the two products again (PERF.md section
+6, PR 52).
+The others keep what they kept, each for a reason in numbers (PERF.md
+section 7): the latent mixer's products on its normed input are the two
+small down-projections and its expansions read the latents (1.3 GB in a
+cell 2.3 GB under the chip); the sparse mixer's cell stands 16 MB under
+the chip; the dense gated mixer's ``gate_up`` output is 1.54 GB in the
+LFM2 cell, whose step the rule's own 1.8 GB already brings to 14.6 GB;
+the expert mixer's large products read the routed buffer, whose ``pre``
+a chunk already keeps (``_HIDDEN``). The rotary and the latent mixer
 also keep their context and, on the kernel path, the rows'
 log-sum-exp; the sparse one those and each row's selection threshold,
-so neither the search nor a second pass of the attention is repeated);
+so neither the search nor a second pass of the attention is repeated;
 the NoPE attention mixer keeps its q/k/v/context (and, on the kernel
 path, the rows' log-sum-exp) and recomputes each query block's scores.
 The device-side scopes ``mx.mamba2``, ``mx.mamba2.ssd``, ``mx.conv``
@@ -106,6 +131,30 @@ def _mm(spec, a, b):
 def _dense(x, w):
     """x (..., in) @ w (out, in)^T in x's dtype (MXNet Dense layout)."""
     return _mm("...i,oi->...o", x, w).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# what a recomputed mixer keeps
+# ---------------------------------------------------------------------------
+_IN_KEPT = "mx.mixer.in.kept"   # the product that reads a mixer's normed input
+
+
+def _in_product(x, w):
+    """:func:`_dense` of a mixer's normed input, its output named for
+    :func:`_recomputed_but_in_product`."""
+    return checkpoint_name(_dense(x, w), _IN_KEPT)
+
+
+def _recomputed_but_in_product(body, mixer, *also):
+    """``jax.checkpoint`` of a mixer's ``body`` by the module's rule: a
+    step keeps the mixer's arguments, what the body names ``_IN_KEPT``
+    (:func:`_in_product`) and the names ``also``; the backward runs
+    everything else again. Counted once a traced call in
+    ``mx_mixer_kept_total{mixer=}``."""
+    telemetry.count_event("mx_mixer_kept_total", mixer=mixer)
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            _IN_KEPT, *also))
 
 
 # ---------------------------------------------------------------------------
@@ -256,7 +305,7 @@ def _mamba2(u, norm_w, in_w, conv_w, conv_b, dt_bias, a_log, d_skip,
             eps):
     b, length, _ = u.shape
     inner, gn = heads * head_dim, groups * state
-    zxbcdt = _dense(_rms(u, norm_w, eps), in_w)
+    zxbcdt = _in_product(_rms(u, norm_w, eps), in_w)
     z = zxbcdt[..., :inner]
     xbc = zxbcdt[..., inner:2 * inner + 2 * gn]
     dt = zxbcdt[..., 2 * inner + 2 * gn:]
@@ -280,12 +329,16 @@ def mamba2_mixer(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
     """A pre-norm Mamba-2 mixer, ``mixer(RMSNorm(data))``: in_proj to
     ``[z | xBC | dt]``, causal depthwise conv + SiLU over xBC, softplus
     dt, the SSD scan (:func:`ssd_scan`), the gated grouped RMSNorm and
-    out_proj. data (batch, length, hidden). Recomputed whole in the
-    backward (``jax.checkpoint``): a step keeps ``data`` only."""
-    fn = jax.checkpoint(lambda *arrays: _mamba2(
+    out_proj. data (batch, length, hidden). Recomputed in the backward
+    (``jax.checkpoint``) but for in_proj's output, which a step keeps
+    beside ``data`` (``2 x inner + 2 x groups x state + heads`` values a
+    token, by the module's rule): the backward runs the norm, the conv,
+    SiLU, softplus, the scan's forward and the gated norm again, never
+    in_proj (out_proj's product is dead in the recomputation)."""
+    fn = _recomputed_but_in_product(lambda *arrays: _mamba2(
         *arrays, heads=int(num_heads), head_dim=int(head_dim),
         groups=int(n_groups), state=int(state_size), chunk=int(chunk_size),
-        eps=float(eps)))
+        eps=float(eps)), "mamba2")
     with jax.named_scope("mx.mamba2"):
         return fn(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
                   dt_bias, a_log, d, gate_norm_gamma, out_proj_weight)
@@ -295,7 +348,7 @@ def mamba2_mixer(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
 # a gated short convolution
 # ---------------------------------------------------------------------------
 def _short_conv(data, norm_w, in_w, conv_w, out_w, *, eps):
-    bcu = _dense(_rms(data, norm_w, eps), in_w)
+    bcu = _in_product(_rms(data, norm_w, eps), in_w)
     # between the two products nothing but element-wise work, in the
     # products' own dtype: in float32 with one rounding at the end the
     # mixer measured 33.7 ms forward + backward where this takes 30.4
@@ -321,12 +374,17 @@ def short_conv_mixer(data, norm_gamma, in_weight, conv_weight, out_weight, *,
     element-wise. data (batch, length, hidden). The two gates and the
     taps are computed in ``data``'s dtype (bf16 inside
     ``ShardedTrainStep``: each product and the taps' sum rounded, as
-    the projections' outputs are). Recomputed whole
-    in the backward (``jax.checkpoint``): a step keeps ``data`` only.
+    the projections' outputs are). Recomputed in the backward
+    (``jax.checkpoint``) but for ``W_in``'s output ``[B ; C ; u]``,
+    which a step keeps beside ``data`` (by the module's rule): the
+    backward runs the norm, both gates and the taps again (element-wise
+    work that is not worth three more arrays of ``channels`` a token),
+    never ``W_in`` (``W_out``'s product is dead in the recomputation).
     The device scope is ``mx.conv``; the part between the two
     projections (both gates and the taps) stands under ``mx.conv.gate``
     inside it, forward, recomputation and backward alike."""
-    fn = jax.checkpoint(functools.partial(_short_conv, eps=float(eps)))
+    fn = _recomputed_but_in_product(
+        functools.partial(_short_conv, eps=float(eps)), "conv")
     with jax.named_scope("mx.conv"):
         return fn(data, norm_gamma, in_weight, conv_weight, out_weight)
 
@@ -528,11 +586,13 @@ def rotary(data, positions=None, *, theta=10000.0, sections=(), yarn=(),
 
 def _normed_rotary_qkv(x, q_weight, k_weight, v_weight, q_norm_gamma,
                        k_norm_gamma, turn, h, kv, d, eps,
-                       attention_factor=1.0):
+                       attention_factor=1.0, v_product=_dense):
     """q (batch, length, h, d), k and v (batch, length, kv, d) of the
-    normed input x: bias-free projections, RMSNorm over each head of q
-    and k where a weight for it is given, both turned by the angles
-    ``turn`` (over the lanes the angles cover: :func:`_rotate`)."""
+    normed input x: bias-free projections (v's by ``v_product``:
+    :func:`_dense`, or :func:`_in_product` for a mixer that keeps it),
+    RMSNorm over each head of q and k where a weight for it is given,
+    both turned by the angles ``turn`` (over the lanes the angles
+    cover: :func:`_rotate`)."""
     b, length, _ = x.shape
 
     def heads(weight, n, gamma):
@@ -541,7 +601,7 @@ def _normed_rotary_qkv(x, q_weight, k_weight, v_weight, q_norm_gamma,
 
     q = _rotate(heads(q_weight, h, q_norm_gamma), turn, attention_factor)
     k = _rotate(heads(k_weight, kv, k_norm_gamma), turn, attention_factor)
-    return q, k, _dense(x, v_weight).reshape(b, length, kv, d)
+    return q, k, v_product(x, v_weight).reshape(b, length, kv, d)
 
 
 _CTX_KEPT = "mx.attn.rotary.kept"   # what the mixer's checkpoint policy saves
@@ -558,7 +618,7 @@ def _rotary_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
     q, k, v = _normed_rotary_qkv(
         x, q_weight, k_weight, v_weight, q_norm_gamma, k_norm_gamma,
         _rotary_angles(positions, rotary_dim // 2, theta, yarn=yarn), h, kv,
-        d, eps, attention_factor)
+        d, eps, attention_factor, v_product=_in_product)
     ctx = _attend(q, k, v, window, keep=_CTX_KEPT)
     if gate_weight is not None:
         # in the (.., heads, d) view the kernel's context is copied into
@@ -611,11 +671,14 @@ def rotary_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
       ``o = W_o concat_i (g_i c_i)``. The product, the sigmoid and the
       multiply stand under the device scope ``mx.attn.gate``.
 
-    Recomputed whole in the backward (``jax.checkpoint``) but for the
-    attention's context (before the gate; and the kernel's
+    Recomputed in the backward (``jax.checkpoint``) but for the v
+    projection's output (the attention kernel's operand as it lies; by
+    the module's rule, which also says why q and k are not kept) and
+    the attention's context (before the gate; and the kernel's
     log-sum-exp), which a step keeps beside ``data``: the backward runs
-    the projections and the gate again, never the attention's
-    forward."""
+    the q and k projections, the norms, the turn and the gate again,
+    never v's projection (``o_weight``'s product is dead in the
+    recomputation) and never the attention's forward."""
     d = int(head_dim)
     turned = int(rotary_dim) or d
     if turned % 2 or not 0 < turned <= d:
@@ -623,14 +686,14 @@ def rotary_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
                          "%d lanes" % (rotary_dim, d))
     if (q_norm_gamma is None) != (k_norm_gamma is None):
         raise ValueError("q and k are normed together or not at all")
-    fn = jax.checkpoint(
+    fn = _recomputed_but_in_product(
         lambda *arrays: _rotary_mixer(
             *arrays, h=int(num_heads), kv=int(num_kv_heads), d=d,
             rotary_dim=turned, theta=float(rope_theta),
             yarn=tuple(float(n) for n in rope_yarn),
             attention_factor=float(attention_factor),
             window=int(window) or None, eps=float(eps)),
-        policy=jax.checkpoint_policies.save_only_these_names(_CTX_KEPT))
+        "rotary", _CTX_KEPT)
     with jax.named_scope("mx.attn.rotary"):
         return fn(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
                   q_norm_gamma, k_norm_gamma, positions, gate_weight)
@@ -688,9 +751,13 @@ def mla_mixer(data, norm_gamma, q_a_weight, q_a_norm_gamma, q_b_weight,
     this file, which needs q . k and v of one width: ``n + r == v``.
     Recomputed whole in the backward (``jax.checkpoint``) but for the
     context (and the kernel's log-sum-exp), which a step keeps beside
-    ``data``, by the rotary mixer's policy: the backward expands q, k
-    and v again from ``data`` (``heads (2 (n + r) + v)`` values a token
-    that are never kept), never runs the attention's forward."""
+    ``data``: the backward expands q, k and v again from ``data``
+    (``heads (2 (n + r) + v)`` values a token that are never kept),
+    never runs the attention's forward. The module's rule keeps nothing
+    more here: the products that read the normed input are the two
+    small down-projections (45 GFLOP a block in the GLM-4.7-Flash
+    cell), the expansions read the latents, and keeping them is 1.3 GB
+    in a cell that stands at 14.63 GB (PERF.md section 7)."""
     nope, rope, vd = (int(qk_nope_head_dim), int(qk_rope_head_dim),
                       int(v_head_dim))
     if nope + rope != vd:
@@ -1081,7 +1148,9 @@ def sparse_gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
     Recomputed whole in the backward (``jax.checkpoint``), but for each
     row's selection threshold and the context (and the rows'
     log-sum-exp on the kernel path), which a step keeps beside
-    ``data``."""
+    ``data``. Its v projection is run again in the backward with q's
+    and k's, unlike the rotary mixer's: the one cell that calls this
+    mixer (Keye-VL) stands 16 MB under the chip (PERF.md section 7)."""
     fn = jax.checkpoint(
         lambda *arrays: _sparse_mixer(
             *arrays, h=int(num_heads), kv=int(num_kv_heads), d=int(head_dim),
@@ -1514,7 +1583,10 @@ def moe_mixer(data, norm_gamma, router_weight, expert_rows, w1, w2,
     shared_w2 (hidden, width_s)). The optional inputs come last: a
     model without a score bias or a shared expert leaves them out. data
     (batch, length, hidden). Recomputed whole in the backward
-    (``jax.checkpoint``): a step keeps ``data`` only."""
+    (``jax.checkpoint``): a step keeps ``data`` only. What reads the
+    normed input here is the router and the shared expert; the large
+    products read the routed buffer, whose ``pre`` a chunk of blocks
+    keeps already (``_HIDDEN``), so the module's rule adds nothing."""
     act = _ACTIVATIONS[str(activation)]
 
     # (the argument order PR 28 gave it: a compiled Nemotron step is
@@ -1552,7 +1624,10 @@ def glu_mlp_mixer(data, norm_gamma, gate_up_weight, down_weight, *,
     the up projection's (an expert's layout: one product for both),
     down_weight (hidden, width). data (batch, length, hidden).
     Recomputed whole in the backward (``jax.checkpoint``): a step keeps
-    ``data`` only."""
+    ``data`` only. The module's rule would keep ``gate_up``'s output,
+    ``2 x width`` values a token: 1.54 GB in the LFM2 cell, whose step
+    stands at 14.6 GB with the 1.8 GB the rule keeps there already, so
+    it is left to a later change (PERF.md section 7)."""
     def mixer(data, norm_gamma, gate_up_weight, down_weight):
         x = _rms(data, norm_gamma, float(eps))
         return _mlp(x, gate_up_weight, down_weight, _swiglu) \

@@ -2,8 +2,8 @@
 their published widths for a described v5e chip (see
 tests/test_chip_compile_bert.py for what such a compile can and cannot
 show): the causal, windowed and sparse flash kernels, the Mamba-2 scan's
-kernels, each under the scope the benchmark reads, and a whole toy
-Keye-VL step.
+kernels, each under the scope the benchmark reads, and whole toy
+steps of four decoders.
 """
 import re
 
@@ -240,13 +240,19 @@ def test_sparse_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
     assert "f32[1,32,512," not in text
 
 
-def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
-    """The zoo model through ``trace_block`` as ``ShardedTrainStep``
-    traces it (both losses, bf16 compute, AdamW through the shared
-    ``_apply_update``), at the configuration's toy widths."""
+# temporaries, arguments, outputs of the toy Keye-VL step on PR 52's parent
+_KEYE_TOY_BYTES = (15484416, 3672064, 3673600)
+
+
+def _toy_step(one_chip, name):
+    """A zoo decoder through ``trace_block`` as ``ShardedTrainStep``
+    traces it (its losses, bf16 compute, AdamW through the shared
+    ``_apply_update``), at the configuration's toy widths, two sequences
+    of 64 tokens: (the compiled step, the configuration's module, its
+    auxiliary states' names)."""
     from mxbench import manifest
     from mxnet_tpu.parallel.sharded import _apply_update, trace_block
-    sizes, cfgmod, _ = manifest.config("keye_vl2_30b_a3b")
+    sizes, cfgmod, _ = manifest.config(name)
     sizes = dict(sizes, **sizes["toy"])
     net, loss, n_in = cfgmod.sharded_parts(sizes, 0.0, 64)
     fn, data_names, names, _ = trace_block(net, loss, n_in)
@@ -254,7 +260,6 @@ def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
               for n, p in block.collect_params().items()}
     aux_names = [n for n in names if n in fn._aux_names]
     names = [n for n in names if n not in fn._aux_names]
-    assert len(aux_names) == 2 * sizes["num_hidden_layers"]
 
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
@@ -279,12 +284,43 @@ def test_a_whole_toy_keye_step_compiles_for_the_chip(one_chip):
     params = {n: sds(shapes[n]) for n in names}
     aux = {n: sds(shapes[n]) for n in aux_names}
     ids = sds((2, 64), jnp.int32)
-    text = jax.jit(step).lower(
+    return jax.jit(step).lower(
         params, aux, {n: (params[n], params[n]) for n in names}, sds(()),
-        ids, ids).compile().as_text()
+        ids, ids).compile(), cfgmod, aux_names
+
+
+@pytest.mark.parametrize("name", [
+    "keye_vl2_30b_a3b", "laguna_xs2_33b_a3b", "lfm2_24b_a2b",
+    "nemotron_twotower_30b_a3b"])
+def test_a_whole_toy_decoder_step_compiles_for_the_chip(one_chip, compiled,
+                                                        name):
+    """The Keye-VL step, whose mixers keep what they kept, and the three
+    whose mixers keep a product that reads their normed input (the
+    rotary mixer's v, the short-convolution mixer's ``W_in``, the
+    Mamba-2 mixer's ``in_proj``: Laguna-XS.2, LFM2, Nemotron): every
+    scope the configuration's readers name is in the
+    compiled program, and at toy widths nothing of Mosaic's."""
+    step, cfgmod, _ = compiled(("toy step", name),
+                               lambda: _toy_step(one_chip, name))
+    text = step.as_text()
     assert not mosaic_calls(text)
     for scope in cfgmod.SCOPES:
         assert scope in text, scope
+
+
+def test_the_toy_keye_step_takes_the_bytes_it_took(one_chip, compiled):
+    """The sparse mixer shares ``_normed_rotary_qkv`` with the rotary
+    one and keeps no projection of its own (its cell stands 16 MB under
+    the chip): the toy step's buffers are, byte for byte, those of the
+    tree before the rotary, short-convolution and Mamba-2 mixers kept a
+    product (PR 52's parent, read by this test's own code there)."""
+    step, _, aux_names = compiled(
+        ("toy step", "keye_vl2_30b_a3b"),
+        lambda: _toy_step(one_chip, "keye_vl2_30b_a3b"))
+    assert len(aux_names) == 2 * 2          # two states a layer, two layers
+    m = step.memory_analysis()
+    assert (m.temp_size_in_bytes, m.argument_size_in_bytes,
+            m.output_size_in_bytes) == _KEYE_TOY_BYTES
 
 
 # ---------------------------------------------------------------------------

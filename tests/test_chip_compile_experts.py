@@ -391,7 +391,8 @@ def test_gated_rotary_mixer_at_8192_takes_the_kernel_at_groups_of_6_and_8(
     window of one tile (the diagonal tile and one ``cond``-ed edge
     tile); forward once, backward once, under the kind's scope; the
     gate's instructions under ``mx.attn.gate``; no q/k norm weight is
-    an input; the mixer's temporaries stay under 1.2 GB."""
+    an input; the mixer's temporaries stay under 1.25 GB (17 MB of them
+    the v projection that a step keeps)."""
     from mxbench import scopes
     compiled = _gated_mixer_gradient(one_chip, heads, **attrs)
     text = compiled.as_text()
@@ -404,7 +405,7 @@ def test_gated_rotary_mixer_at_8192_takes_the_kernel_at_groups_of_6_and_8(
     assert set(kernels.values()) == {scope}
     assert other not in placed.values()
     assert {"mx.attn.rotary", "mx.attn.gate"} <= set(placed.values())
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25e9
     assert "f32[1,8,%d,512," % (heads // 8) not in text     # no score block
 
 

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from mxbench import manifest
+from mxnet_tpu import telemetry
 from mxnet_tpu.ops import decoder_ops as D, get_op
 from numerics import (F32, attention_ref, close, highest, jitted,  # noqa: F401
                       near, rand, reference, remat_count, same_values_and_grads,
@@ -542,23 +543,9 @@ def test_the_sparse_mixer_keeps_thresholds_and_context_only(capsys):
     """Beside its arguments the mixer's checkpoint keeps each row's
     threshold and tie count and the context: no score block, no
     projection."""
-    cfg = _attn_cfg()
-    names, w = _attn_weights(37, cfg)
-    x, norm_w = rand(38, (1, 24, cfg["hidden_size"]), (cfg["hidden_size"],))
-    args = (x, norm_w) + tuple(w[n] for n in names)
-
-    def fn(*a):
-        return jnp.sum(_mixer(a[0], a[1], dict(zip(names, a[2:])), names,
-                              cfg)[0])
-
+    fn, args, kept = _dsa_case()
     assert remat_count(jax.grad(fn), *args) > 0
-    jax.ad_checkpoint.print_saved_residuals(fn, *args)
-    kept = [line.split(" ")[0] for line in capsys.readouterr().out
-            .splitlines() if "from the argument" not in line
-            and "from a constant" not in line]
-    h, d = cfg["num_attention_heads"], cfg["head_dim"]
-    assert sorted(kept) == sorted(["u32[1,24]", "i32[1,24]",
-                                   "f32[1,24,%d,%d]" % (h, d)])
+    assert sorted(_saved(capsys, fn, *args)) == sorted(kept)
 
 
 # ---------------------------------------------------------------------------
@@ -662,3 +649,169 @@ def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer():
         counts.append(np.asarray(rows[0]))
     close(got, want)
     assert int(np.sum(counts)) == 30 * 3        # every choice held once
+
+
+# ---------------------------------------------------------------------------
+# what a recomputed mixer keeps (the module's rule): its input and the
+# output of a product that reads its normed input, where the next stage
+# reads that output as it lies (W_in's, in_proj's, the rotary mixer's v)
+# ---------------------------------------------------------------------------
+_B, _L, _U = 2, 21, 24                      # batch, length, hidden
+_H, _KV, _HD = 4, 2, 8                      # the rotary mixer's heads
+
+
+def _rotary_case(norms=True, gate=False, window=0):
+    x, g, qw, kw, vw, ow, qn, kn, gw = rand(
+        61, (_B, _L, _U), (_U,), (_H * _HD, _U), (_KV * _HD, _U),
+        (_KV * _HD, _U), (_U, _H * _HD), (_HD,), (_HD,), (_H, _U), scale=0.3)
+    args = [x, 1 + g, qw, kw, vw, ow] + ([1 + qn, 1 + kn] if norms else []) \
+        + ([gw] if gate else [])
+
+    def spread(a):
+        # the op's order: ..., q_norm, k_norm, positions, gate_weight
+        a = list(a)
+        gate_w = a.pop() if gate else None
+        return a + ([] if norms else [None, None]) + [None, gate_w]
+
+    op = get_op("_contrib_rotary_gqa_mixer").impl
+    attrs = dict(num_heads=_H, num_kv_heads=_KV, head_dim=_HD,
+                 rope_theta=5e5, window=window, eps=1e-6)
+    body = lambda *a: D._rotary_mixer(
+        *spread(a), h=_H, kv=_KV, d=_HD, rotary_dim=_HD, theta=5e5, yarn=(),
+        attention_factor=1.0, window=window or None, eps=1e-6)
+    before = jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            D._CTX_KEPT))
+    kept = ["f32[%d,%d,%d]" % (_B, _L, _KV * _HD),           # v
+            "f32[%d,%d,%d,%d]" % (_B, _L, _H, _HD)]          # the context
+    return (lambda *a: op(*spread(a), **attrs)), body, before, args, kept, 1
+
+
+def _conv_case():
+    x, g, w_in, w_c, w_out = rand(62, (_B, _L, _U), (_U,), (3 * _U, _U),
+                                  (_U, 3), (_U, _U), scale=0.5)
+    op = get_op("_contrib_short_conv_mixer").impl
+    body = lambda *a: D._short_conv(*a, eps=1e-5)
+    return (lambda *a: op(*a, eps=1e-5)), body, jax.checkpoint(body), \
+        (x, 1 + g, w_in, w_c, w_out), ["f32[%d,%d,%d]" % (_B, _L, 3 * _U)], 1
+
+
+def _mamba2_case():
+    heads, p, groups, n, k = 4, 4, 2, 8, 4
+    inner, conv = heads * p, heads * p + 2 * groups * n
+    args = rand(63, (_B, 12, 16), (16,), (inner + conv + heads, 16),
+                (conv, k), (conv,), (heads,), (heads,), (heads,), (inner,),
+                (16, inner), scale=0.3)
+    op = get_op("_contrib_mamba2_mixer").impl
+    attrs = dict(num_heads=heads, head_dim=p, n_groups=groups, state_size=n,
+                 chunk_size=4, eps=1e-5)
+    body = lambda *a: D._mamba2(*a, heads=heads, head_dim=p, groups=groups,
+                                state=n, chunk=4, eps=1e-5)
+    return (lambda *a: op(*a, **attrs)), body, jax.checkpoint(body), args, \
+        ["f32[%d,12,%d]" % (_B, inner + conv + heads)], 1
+
+
+_KEEPERS = {
+    "rotary": _rotary_case,
+    "rotary_without_qk_norms": lambda: _rotary_case(norms=False),
+    "rotary_gated": lambda: _rotary_case(norms=False, gate=True),
+    "rotary_windowed": lambda: _rotary_case(window=6),
+    "rotary_gated_windowed_normed": lambda: _rotary_case(gate=True, window=6),
+    "conv": _conv_case,
+    "mamba2": _mamba2_case,
+}
+
+
+def _saved(capsys, fn, *args):
+    """What ``fn``'s checkpoints keep beside arguments and constants."""
+    jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    return [line.split(" ")[0] for line in capsys.readouterr().out
+            .splitlines() if "from the argument" not in line
+            and "from a constant" not in line]
+
+
+@pytest.mark.parametrize("case", sorted(_KEEPERS))
+def test_a_keeping_mixer_keeps_its_in_product_and_nothing_else(case, capsys):
+    """Beside its arguments: the named product's output (and, for the
+    rotary mixer, the context); no q or k, no normed, turned or gated
+    copy, no tap, no scan state."""
+    op, _, _, args, kept, _ = _KEEPERS[case]()
+    assert sorted(_saved(capsys, lambda *a: jnp.sum(op(*a)), *args)) \
+        == sorted(kept)
+
+
+@pytest.mark.parametrize("case", sorted(_KEEPERS))
+def test_the_backward_runs_no_kept_product_again(case):
+    """One ``dot_general`` fewer a kept product than the form that kept
+    the input alone (the context too, for the rotary mixer), while the
+    element-wise inside is still recomputed."""
+    op, _, before, args, _, products = _KEEPERS[case]()
+
+    def dots(mixer):
+        return str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(mixer(*a))))(
+            *args)).count("dot_general")
+
+    assert dots(before) - dots(op) == products
+    assert remat_count(jax.grad(lambda *a: jnp.sum(op(*a))), *args) > 0
+
+
+@pytest.mark.parametrize("case", sorted(_KEEPERS))
+def test_a_keeping_mixer_is_its_body_in_value_and_gradient(case):
+    op, body, _, args, _, _ = _KEEPERS[case]()
+    same_values_and_grads(op, body, tuple(args), tol=5e-5)
+
+
+@pytest.mark.parametrize("case, mixer", [
+    ("rotary_gated", "rotary"), ("conv", "conv"), ("mamba2", "mamba2")])
+def test_a_keeping_mixer_is_counted_once_a_traced_call(case, mixer):
+    op, _, _, args, _, _ = _KEEPERS[case]()
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        count = telemetry.counter("mx_mixer_kept_total", mixer=mixer)
+        before = count.value
+        jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(op(*a))))(*args)
+        assert count.value == before + 1
+    finally:
+        telemetry.enable(was)
+
+
+def _mla_case():
+    h, nope, rope, q_rank, kv_rank = 2, 8, 8, 12, 10
+    vd = nope + rope
+    args = rand(64, (_B, _L, _U), (_U,), (q_rank, _U), (q_rank,),
+                (h * (nope + rope), q_rank), (kv_rank + rope, _U), (kv_rank,),
+                (h * (nope + vd), kv_rank), (_U, h * vd), scale=0.3)
+    op = get_op("_contrib_mla_mixer").impl
+    return (lambda *a: jnp.sum(op(
+        *a, num_heads=h, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=vd))), args, ["f32[%d,%d,%d,%d]" % (_B, _L, h, vd)]
+
+
+def _dsa_case():
+    cfg = _attn_cfg()
+    names, w = _attn_weights(37, cfg)
+    x, norm_w = rand(38, (1, 24, cfg["hidden_size"]), (cfg["hidden_size"],))
+    h, d = cfg["num_attention_heads"], cfg["head_dim"]
+    return (lambda *a: jnp.sum(_mixer(a[0], a[1], dict(zip(names, a[2:])),
+                                      names, cfg)[0])), \
+        (x, norm_w) + tuple(w[n] for n in names), \
+        ["u32[1,24]", "i32[1,24]", "f32[1,24,%d,%d]" % (h, d)]
+
+
+def _glu_case():
+    args = rand(65, (_B, _L, _U), (_U,), (2 * 16, _U), (_U, 16), scale=0.3)
+    op = get_op("_contrib_glu_mlp_mixer").impl
+    return (lambda *a: jnp.sum(op(*a))), args, []
+
+
+@pytest.mark.parametrize("case", [_mla_case, _dsa_case, _glu_case],
+                         ids=["mla", "dsa", "glu_mlp"])
+def test_the_other_mixers_keep_what_they_kept(case, capsys):
+    """The rule stops at three mixers: the latent one keeps its context,
+    the sparse one thresholds, tie counts and context, the dense gated
+    one nothing, and none of them a projection (why: the module's
+    docstring)."""
+    fn, args, kept = case()
+    assert sorted(_saved(capsys, fn, *args)) == sorted(kept)
+    assert remat_count(jax.grad(fn), *args) > 0

@@ -218,17 +218,17 @@ def test_the_unturned_lanes_carry_no_position():
                                rtol=1e-6)
 
 
-def test_the_mixer_keeps_its_context_only(capsys):
-    """Beside its arguments the gated mixer's checkpoint keeps the
-    attention's context (before the gate): no projection, no gate, no
-    score block."""
+def test_the_mixer_keeps_v_and_its_context_only(capsys):
+    """Beside its arguments the gated mixer's checkpoint keeps the v
+    projection's output and the attention's context (before the gate):
+    no q, no k, no gate, no score block."""
     a = _mixer_args(13, 6)
     fn = lambda a: jnp.sum(_mixer_op(a, FULL, 6))
     jax.ad_checkpoint.print_saved_residuals(fn, a)
     kept = [line.split(" ")[0] for line in capsys.readouterr().out
             .splitlines() if "from the argument" not in line
             and "from a constant" not in line]
-    assert kept == ["f32[2,21,6,16]"]
+    assert kept == ["f32[2,21,32]", "f32[2,21,6,16]"]
 
 
 def test_the_gate_stands_under_its_own_scope_forward_and_backward():

@@ -166,9 +166,10 @@ def test_rotary_gqa_mixer_against_the_reference(kind, window, rope):
                           args, tol=1e-4)
 
 
-def test_the_rotary_mixer_keeps_its_context_only(capsys):
-    """Beside its arguments the mixer's checkpoint keeps the context:
-    no projection, no score block."""
+def test_the_rotary_mixer_keeps_v_and_its_context_only(capsys):
+    """Beside its arguments the mixer's checkpoint keeps the v
+    projection's output and the context: no q, no k, normed or turned
+    or neither, no score block."""
     args = _rotary_mixer_args(46)
     op = get_op("_contrib_rotary_gqa_mixer").impl
 
@@ -181,7 +182,7 @@ def test_the_rotary_mixer_keeps_its_context_only(capsys):
     kept = [line.split(" ")[0] for line in capsys.readouterr().out
             .splitlines() if "from the argument" not in line
             and "from a constant" not in line]
-    assert kept == ["f32[2,21,4,8]"]
+    assert kept == ["f32[2,21,16]", "f32[2,21,4,8]"]
 
 
 @pytest.mark.parametrize("at_once, a_chunk, chunk", [(3, 3, 2), (20, 20, 17),
