@@ -18,6 +18,12 @@ Usage mirrors the reference::
 
 from __future__ import annotations
 
+# the start of ``setup::import`` (telemetry.setup_phase), which can be
+# recorded only once the package's own modules are importable: the
+# last lines of this file
+import time as _time
+_IMPORT_T0 = _time.perf_counter()
+
 # TPU-hardware PRNG by default: the threefry generator costs ~8.7 ms/step
 # of pure RNG on BERT-base (batch 32, seq 128, dropout 0.1 — measured r3);
 # "rbg" lowers jax.random to the on-chip generator. Set
@@ -90,3 +96,7 @@ from .util import is_np_array
 
 # AMP lives under contrib to mirror the reference layout
 from . import contrib
+
+with telemetry.setup_phase("import") as _span:
+    _span.backdate(_IMPORT_T0)
+del _span, _IMPORT_T0, _time

@@ -13,9 +13,12 @@ is tuned against.
 
 Wrapped sites are the four DYNAMIC jit caches (ops._jit_cache,
 _jitted_with_none_slots, CachedOp's three programs, the fused
-backward) — the ones keyed on user-data shapes that can storm. Static
-single-compile sites (parallel/sharded, optimizer fused update, rtc,
-kvstore allsum) still call jax.jit directly and are not watched yet.
+backward) — the ones keyed on user-data shapes that can storm. The
+sharded step (parallel/sharded.py::ShardedTrainStep._executable), which
+keeps its own AOT executables, compiles each through
+:func:`compile_stages` and leaves the same record a program. The other
+static single-compile sites (optimizer fused update, rtc, kvstore
+allsum) still call jax.jit directly and are not watched yet.
 
 One primitive: :func:`watched_jit` wraps a pure function in a
 :class:`WatchedJit` — a drop-in ``jax.jit`` replacement that, when the
@@ -64,8 +67,8 @@ from . import telemetry
 
 __all__ = ["WatchedJit", "watched_jit", "enabled", "programs", "report",
            "recompile_log", "cache_counts", "cache_entries", "reset",
-           "render_report", "compile_seconds_total",
-           "note_external_compile"]
+           "render_report", "compile_seconds_total", "compile_stages",
+           "watch_compile", "publish", "cache_misses"]
 
 _LOG = logging.getLogger("mxnet_tpu.compilewatch")
 
@@ -233,6 +236,66 @@ def _extract_memory(compiled) -> Dict[str, int]:
         except Exception:
             pass
     return out
+
+
+# ---------------------------------------------------------------------------
+# the AOT stages, timed — what every compile record is made from
+# ---------------------------------------------------------------------------
+# JAX says through jax.monitoring whether a compile asked the persistent
+# cache and whether it was served from it, on the thread that compiles
+_USES_CACHE = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class _CacheEvents(threading.local):
+    def __init__(self):
+        self.requests = 0
+        self.hits = 0
+
+
+_CACHE_EVENTS = _CacheEvents()
+_CACHE_LISTENER = [False]
+
+
+def _on_jax_event(event, **_):
+    if event == _CACHE_HIT:
+        _CACHE_EVENTS.hits += 1
+    elif event == _USES_CACHE:
+        _CACHE_EVENTS.requests += 1
+
+
+def _listen_for_cache():
+    if not _CACHE_LISTENER[0]:
+        with _PROG_LOCK:
+            if not _CACHE_LISTENER[0]:
+                jax.monitoring.register_event_listener(_on_jax_event)
+                _CACHE_LISTENER[0] = True
+
+
+def compile_stages(jitted, args):
+    """``jitted`` through ``.trace()`` / ``.lower()`` / ``.compile()``
+    for ``args``, each stage timed: ``(traced, lowered, compiled,
+    stages, persistent_cache)``. ``stages`` is {"trace", "lower",
+    "compile"} seconds in that order; ``persistent_cache`` is "hit"
+    where JAX served the executable from its persistent cache (the
+    ``compile`` stage is then the load), "miss" where it asked the
+    cache and compiled, None where it reported neither (no cache
+    directory set, a program it does not cache). Raises what the
+    stages raise."""
+    _listen_for_cache()
+    t0 = time.perf_counter()
+    traced = jitted.trace(*args)
+    t1 = time.perf_counter()
+    lowered = traced.lower()
+    t2 = time.perf_counter()
+    seen = _CACHE_EVENTS
+    requests, hits = seen.requests, seen.hits
+    compiled = lowered.compile()
+    t3 = time.perf_counter()
+    word = "hit" if seen.hits > hits \
+        else "miss" if seen.requests > requests else None
+    return (traced, lowered, compiled,
+            {"trace": t1 - t0, "lower": t2 - t1, "compile": t3 - t2}, word)
 
 
 # ---------------------------------------------------------------------------
@@ -427,33 +490,35 @@ class WatchedJit:
             stages: Dict[str, float] = {}
             compiled = None
             traced = None
+            cache_word = None
             out = _MISSING = object()
             try:
-                traced = self._jit.trace(*args)
-                t1 = time.perf_counter()
-                lowered = traced.lower()
-                t2 = time.perf_counter()
-                compiled = lowered.compile()
-                t3 = time.perf_counter()
-                stages = {"trace": t1 - t0, "lower": t2 - t1,
-                          "compile": t3 - t2}
+                traced, _, compiled, stages, cache_word = compile_stages(
+                    self._jit, args)
             except Exception:
                 compiled = None
             if compiled is not None:
                 flops = _extract_cost(compiled)
                 mem = _extract_memory(compiled)
-                if self._exec_via_jit:
-                    # analysis-only AOT: drop the executable (jit keeps
-                    # its own) and serve every call from the fast path
-                    out = self._jit(*args)
-                    self._cache[sig] = _VIA_JIT
-                else:
-                    try:
-                        out = compiled(*args)
-                        self._cache[sig] = compiled
-                    except Exception:
-                        compiled = None
-                        out = _MISSING
+                # the host's side of the program's first call: the
+                # executable loaded onto the device, the launch handed
+                # over (not waited for). Through the plain jit (the
+                # per-op sites) its own compile of the small program
+                # is inside
+                with telemetry.setup_phase("first_launch"):
+                    if self._exec_via_jit:
+                        # analysis-only AOT: drop the executable (jit
+                        # keeps its own) and serve every call from the
+                        # fast path
+                        out = self._jit(*args)
+                        self._cache[sig] = _VIA_JIT
+                    else:
+                        try:
+                            out = compiled(*args)
+                            self._cache[sig] = compiled
+                        except Exception:
+                            compiled = None
+                            out = _MISSING
             if compiled is None:
                 # whole-call fallback: the plain jitted call compiles
                 # internally; one "total" stage is the best we can time
@@ -461,6 +526,7 @@ class WatchedJit:
                 tw0 = time.perf_counter()
                 out = self._jit(*args)
                 stages = {"total": time.perf_counter() - tw0}
+                t0 = tw0        # a record's stages run from its time
                 self._cache[sig] = _DEGRADED
             self._last_sig = sig
             if flops:
@@ -474,6 +540,7 @@ class WatchedJit:
                 "stages": stages, "flops": flops, "bytes": mem,
                 "signature": [_fmt_arg(s) for s in sig],
                 "changed": changed, "time": t0,
+                "persistent_cache": cache_word,
             }
             if self.static_repr:
                 record["static"] = self.static_repr
@@ -490,50 +557,10 @@ class WatchedJit:
                 self._diff_history.append(
                     {"changed": changed,
                      "signature": record["signature"]})
-            self._publish(record, t0)
+            publish(record)
             if is_recompile:
                 self._storm_guard(record)
         return out
-
-    # -- accounting (never poisons the compiled call) -------------------
-    def _publish(self, record: dict, t0: float):
-        try:
-            with _PROG_LOCK:
-                if len(_PROGRAMS) == _PROGRAMS_CAP:
-                    _DROPPED[0] += 1      # deque maxlen evicts oldest
-                _PROGRAMS.append(record)
-            fn = self.fn_label
-            telemetry.counter("mx_compile_total", fn=fn).inc()
-            if record["kind"] == "recompile":
-                telemetry.counter("mx_recompiles_total", fn=fn).inc()
-            total = 0.0
-            for stage, dt in record["stages"].items():
-                telemetry.histogram("mx_compile_seconds", fn=fn,
-                                    stage=stage).observe(dt)
-                total += dt
-            with _PROG_LOCK:
-                _COMPILE_SECONDS[0] += total
-            if record["flops"] is not None:
-                telemetry.counter("mx_compile_flops", fn=fn).inc(
-                    record["flops"])
-            for kind, nbytes in record["bytes"].items():
-                telemetry.gauge("mx_hbm_bytes", kind=kind).inc(nbytes)
-            telemetry.gauge("mx_jit_cache_entries").set(cache_entries())
-            args = {"site": self.site, "instance": self.instance,
-                    "kind": record["kind"],
-                    "signature": record["signature"]}
-            for stage, dt in record["stages"].items():
-                args["%s_ms" % stage] = round(dt * 1e3, 3)
-            if record["flops"] is not None:
-                args["flops"] = record["flops"]
-            if record["bytes"]:
-                args["bytes"] = record["bytes"]
-            if record["changed"]:
-                args["changed"] = record["changed"]
-            profiler.record_event("compile::%s" % fn, "compile",
-                                  t0 * 1e6, total * 1e6, args)
-        except Exception:
-            pass
 
     def _storm_guard(self, record: dict):
         """MXNET_COMPILE_WARN_N / MXNET_COMPILE_STRICT: a function that
@@ -562,6 +589,75 @@ class WatchedJit:
             _LOG.warning(msg)
         if _cfg("MXNET_COMPILE_STRICT"):
             raise MXNetError(msg)
+
+
+# -- accounting (never poisons the compiled call) ---------------------------
+def publish(record: dict):
+    """One compile record into ``programs()``, the ``mx_compile_*``
+    instruments and the chrome trace (``compile::<fn>`` from the
+    record's ``time`` over its stages). A record is {"site", "fn",
+    "instance", "kind" (compile | recompile), "stages" {stage:
+    seconds, in the order they ran}, "flops", "bytes", "signature",
+    "changed", "time" (``time.perf_counter`` at the first stage's
+    start), "persistent_cache" (:func:`compile_stages`)}."""
+    try:
+        with _PROG_LOCK:
+            if len(_PROGRAMS) == _PROGRAMS_CAP:
+                _DROPPED[0] += 1      # deque maxlen evicts oldest
+            _PROGRAMS.append(record)
+        fn = record["fn"]
+        telemetry.counter("mx_compile_total", fn=fn).inc()
+        if record["kind"] == "recompile":
+            telemetry.counter("mx_recompiles_total", fn=fn).inc()
+        total = 0.0
+        for stage, dt in record["stages"].items():
+            telemetry.histogram("mx_compile_seconds", fn=fn,
+                                stage=stage).observe(dt)
+            total += dt
+        with _PROG_LOCK:
+            _COMPILE_SECONDS[0] += total
+        if record["flops"] is not None:
+            telemetry.counter("mx_compile_flops", fn=fn).inc(
+                record["flops"])
+        for kind, nbytes in record["bytes"].items():
+            telemetry.gauge("mx_hbm_bytes", kind=kind).inc(nbytes)
+        telemetry.gauge("mx_jit_cache_entries").set(cache_entries())
+        args = {"site": record["site"], "instance": record["instance"],
+                "kind": record["kind"],
+                "signature": record["signature"]}
+        for stage, dt in record["stages"].items():
+            args["%s_ms" % stage] = round(dt * 1e3, 3)
+        for key in ("flops", "persistent_cache"):
+            if record[key] is not None:
+                args[key] = record[key]
+        if record["bytes"]:
+            args["bytes"] = record["bytes"]
+        if record["changed"]:
+            args["changed"] = record["changed"]
+        profiler.record_event("compile::%s" % fn, "compile",
+                              record["time"] * 1e6, total * 1e6, args)
+    except Exception:
+        pass
+
+
+def watch_compile(jitted, args, *, fn: str, site: str, instance: str,
+                  recompile: bool, signature: Sequence[tuple]):
+    """A site that keeps its own AOT executables (the sharded step):
+    ``jitted`` through :func:`compile_stages` for ``args``, with the
+    record a watched site leaves. ``signature`` is the ``(shape,
+    dtype)`` pairs the site keys its programs on. Returns (lowered,
+    compiled); raises what the stages raise. For the telemetry-on path:
+    the caller reads the gate."""
+    t0 = time.perf_counter()
+    _, lowered, compiled, stages, cache_word = compile_stages(jitted, args)
+    publish({"site": site, "fn": fn, "instance": instance,
+             "kind": "recompile" if recompile else "compile",
+             "stages": stages, "flops": _extract_cost(compiled),
+             "bytes": _extract_memory(compiled),
+             "signature": [_fmt_leaf((tuple(shape), dtype, False, None))
+                           for shape, dtype in signature],
+             "changed": [], "time": t0, "persistent_cache": cache_word})
+    return lowered, compiled
 
 
 def watched_jit(fn: Callable, fn_label: str, site: str,
@@ -610,14 +706,6 @@ def compile_seconds_total() -> float:
     return _COMPILE_SECONDS[0]
 
 
-def note_external_compile(seconds: float):
-    """Add compile time observed OUTSIDE the watched sites (e.g. the
-    sharded-step AOT compile in parallel/sharded.py) to the goodput
-    debit total."""
-    with _PROG_LOCK:
-        _COMPILE_SECONDS[0] += max(0.0, float(seconds))
-
-
 def recompile_log(fn_label: Optional[str] = None) -> List[dict]:
     """Recompile records (with their attribution diffs), oldest first."""
     return [r for r in programs()
@@ -657,8 +745,48 @@ def _fmt_count(v: float) -> str:
     return "%.0f" % v
 
 
+def cache_misses() -> List[dict]:
+    """The compile records JAX's persistent cache did not serve
+    (``persistent_cache == "miss"``), longest ``compile`` stage first:
+    the programs a warm start still compiles."""
+    return sorted((r for r in programs()
+                   if r.get("persistent_cache") == "miss"),
+                  key=lambda r: -r["stages"].get("compile", 0.0))
+
+
+_MISSES_SHOWN = 20      # a cold start misses with every program
+
+
+def _render_startup() -> List[str]:
+    """The start's seconds by phase and the programs that missed the
+    persistent cache (docs/OBSERVABILITY.md "Start-up"); nothing where
+    the process recorded no start."""
+    phases = telemetry.startup_phases()
+    covered = phases.pop("covered")
+    if not covered:
+        return []
+    out = ["", "start-up to the first step, seconds by phase (exclusive):"]
+    out += ["  %-14s %9.3f" % kv
+            for kv in sorted(phases.items(), key=lambda kv: -kv[1])]
+    out.append("  %-14s %9.3f" % ("covered", covered))
+    missed = cache_misses()
+    if missed:
+        out.append("programs that missed the persistent cache "
+                   "(compile seconds):")
+        out += ["  %-40s %-24s %9.3f"
+                % (r["fn"], r["instance"], r["stages"].get("compile", 0.0))
+                for r in missed[:_MISSES_SHOWN]]
+        rest = missed[_MISSES_SHOWN:]
+        if rest:
+            out.append("  ... and %d more, %.3f s together" % (
+                len(rest), sum(r["stages"].get("compile", 0.0)
+                               for r in rest)))
+    return out
+
+
 def render_report(rows: Optional[List[dict]] = None) -> str:
-    """The per-program table tools/compile_report.py prints."""
+    """The per-program table tools/compile_report.py prints, then the
+    start-up's phases and cache misses."""
     rows = report() if rows is None else rows
     out = ["%-24s %-22s %8s %9s %10s %10s %12s"
            % ("callsite", "fn", "compiles", "recompile",
@@ -670,7 +798,7 @@ def render_report(rows: Optional[List[dict]] = None) -> str:
                       r["compile_seconds"],
                       _fmt_count(r["flops"]) if r["flops"] else "-",
                       _fmt_count(hbm) if hbm else "-"))
-    return "\n".join(out)
+    return "\n".join(out + _render_startup())
 
 
 def reset():

@@ -354,6 +354,10 @@ class HybridBlock(Block):
 
     # ------------------------------------------------------------------
     def _build_cache(self, *args):
+        with telemetry.setup_phase("graph"):
+            self._trace_cached_op(*args)
+
+    def _trace_cached_op(self, *args):
         # trace hybrid_forward with symbolic placeholders
         data_syms = [sym_mod.var("data%d" % i) for i in range(len(args))]
         params = {name: p for name, p in self._collect_params_with_prefix().items()}
@@ -455,7 +459,7 @@ class HybridBlock(Block):
         was_active = self._active
         self._active = False
         try:
-            with autograd.pause():
+            with telemetry.setup_phase("init"), autograd.pause():
                 self.__call__(*args)
         finally:
             self._active = was_active

@@ -20,6 +20,7 @@ from ..ndarray import NDArray
 from .. import autograd
 from .. import initializer as init_mod
 from .. import symbol as sym_mod
+from .. import telemetry
 
 __all__ = ["DeferredInitializationError", "Parameter", "Constant",
            "ParameterDict", "tensor_types"]
@@ -145,7 +146,8 @@ class Parameter:
             raise DeferredInitializationError(
                 "Parameter %s has unknown shape %s" % (self.name, self._shape))
         init, ctx, default_init = self._deferred_init
-        self._init_impl(init if init is not None else default_init, ctx)
+        with telemetry.setup_phase("init"):
+            self._init_impl(init if init is not None else default_init, ctx)
 
     # ------------------------------------------------------------------
     def _check_initialized(self, ctx=None):
@@ -358,8 +360,9 @@ class ParameterDict:
 
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
         init = init if init is not None else init_mod.Uniform()
-        for _, v in self.items():
-            v.initialize(None, ctx, init, force_reinit=force_reinit)
+        with telemetry.setup_phase("init"):
+            for _, v in self.items():
+                v.initialize(None, ctx, init, force_reinit=force_reinit)
 
     def zero_grad(self):
         for p in self.values():

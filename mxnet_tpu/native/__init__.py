@@ -21,6 +21,12 @@ _LIB = None
 
 def _load(target):
     """ctypes handle of ``target``, built with make if absent."""
+    from .. import telemetry
+    with telemetry.setup_phase("native"):
+        return _build_and_load(target)
+
+
+def _build_and_load(target):
     path = os.path.join(_DIR, target)
     if not os.path.exists(path):
         # one target at a time: the engine must not become unavailable

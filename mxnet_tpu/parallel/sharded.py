@@ -61,6 +61,12 @@ def trace_block(net, loss_fn, n_data_inputs: int = 2):
 
     net/loss are gluon HybridBlocks; data inputs are named data0..dataN
     (the last is the label fed to the loss)."""
+    from .. import telemetry
+    with telemetry.setup_phase("graph"):
+        return _trace_block(net, loss_fn, n_data_inputs)
+
+
+def _trace_block(net, loss_fn, n_data_inputs):
     from .. import symbol as sym_mod
     from ..symbol import compile_graph
     from ..symbol.layout_opt import (convert_layout, elide_conv_bias_into_bn,
@@ -199,6 +205,8 @@ class ShardedTrainStep:
                  grad_accum: int = 1, seed: int = 0,
                  split_update: bool = False):
         self.mesh = mesh
+        # what a compile record calls this step: the net's own name
+        self._name = getattr(net, "name", None) or type(net).__name__
         fn, data_names, param_names, needs_rng = trace_block(
             net, loss_fn, n_data_inputs)
         self._fn = fn
@@ -245,7 +253,6 @@ class ShardedTrainStep:
         # fp32 master copies; compute dtype is applied inside the step.
         # A PARAMETRIC loss (e.g. a block owning an MLM head) trains
         # too: its params join the step like the net's.
-        params = {}
         all_params = dict(net.collect_params())
         if hasattr(loss_fn, "collect_params"):
             for k, v in loss_fn.collect_params().items():
@@ -259,6 +266,25 @@ class ShardedTrainStep:
                         "name; use a different prefix" % k)
                 all_params[k] = v
         self._loss_fn = loss_fn
+        # the float32 master copies, their placement on the mesh and the
+        # optimizer states: a start's "place" phase
+        from .. import telemetry
+        with telemetry.setup_phase("place"):
+            self._place_params(all_params, param_names, param_rules,
+                               _n_states(optimizer, momentum))
+        if data_specs is None:
+            batch_ax = batch_axes(mesh)
+            data_specs = [P(batch_ax) for _ in data_names]
+        self.data_shardings = [NamedSharding(mesh, s) for s in data_specs]
+        self._grads = None       # accumulated grads (grad_accum > 1)
+        self._build()
+
+    def _place_params(self, all_params, param_names, param_rules, n_states):
+        """Float32 master copies of the net's parameters (hoisted
+        layouts applied), on the mesh by ``param_rules``, with the
+        replicated auxiliary states and zeroed optimizer states."""
+        mesh = self.mesh
+        params = {}
         for name in param_names + self._aux_names:
             p = all_params[name]
             try:
@@ -308,19 +334,12 @@ class ShardedTrainStep:
         self.param_shardings = shardings
         self.params = {k: jax.device_put(v, shardings[k])
                        for k, v in params.items()}
-        n_states = _n_states(optimizer, momentum)
         self.states = {k: tuple(jax.device_put(jnp.zeros_like(v), shardings[k])
                                 for _ in range(n_states))
                        for k, v in self.params.items()}
         self.state_shardings = {k: tuple(shardings[k]
                                          for _ in range(n_states))
                                 for k in self.params}
-        if data_specs is None:
-            batch_ax = batch_axes(mesh)
-            data_specs = [P(batch_ax) for _ in data_names]
-        self.data_shardings = [NamedSharding(mesh, s) for s in data_specs]
-        self._grads = None       # accumulated grads (grad_accum > 1)
-        self._build()
 
     # ------------------------------------------------------------------
     def _build(self):
@@ -523,14 +542,17 @@ class ShardedTrainStep:
         # lower from abstract avals: concrete arrays carry a
         # committed layout, which conflicts with AUTO
         sds = lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype)
-        ent = fn, _ = self._executable("fused_step", jitted, key, (
+        ent = self._executable("fused_step", jitted, key, (
             jax.tree_util.tree_map(sds, self.params),
             jax.tree_util.tree_map(sds, self.aux),
             jax.tree_util.tree_map(sds, self.states),
             sds(self._t_dev), sds(self._rng_dev),
             *[sds(a) for a in arrays]))
+        # the executable itself: ``ent`` calls it under the first
+        # launch's span
+        fn = self._programs["fused_step", key][0]
         if first:
-            from .. import commwatch, compilewatch
+            from .. import commwatch, compilewatch, telemetry
             commwatch.register_program(
                 ("sharded_step", id(self), key), "sharded_step",
                 compiled=fn, mesh=self.mesh,
@@ -538,50 +560,36 @@ class ShardedTrainStep:
             in_fmts = fn.input_formats[0]
             self._param_formats = in_fmts[0]
             self._state_formats = in_fmts[2]
-            self.params = jax.tree_util.tree_map(
-                jax.device_put, self.params, in_fmts[0])
-            self.states = jax.tree_util.tree_map(
-                jax.device_put, self.states, in_fmts[2])
+            with telemetry.setup_phase("place"):
+                self.params = jax.tree_util.tree_map(
+                    jax.device_put, self.params, in_fmts[0])
+                self.states = jax.tree_util.tree_map(
+                    jax.device_put, self.states, in_fmts[2])
         self._compiled[key] = fn
         return ent
 
     def _watched_executable(self, arrays, key):
         """Observability execution path (MXNET_TELEMETRY +
-        MXNET_COMMWATCH): compile the fused step ONCE per data shape
-        through the AOT stages and execute the AOT executable — same
-        policy as CachedOp's watched sites (multi-second programs must
-        never compile twice), and the compiled object is what the
-        meters feed on: its ``cost_analysis`` FLOPs become the
-        measured mx_mfu numerator and its HLO text yields the
-        GSPMD-collective inventory (op/axis/bytes) commwatch charges
-        per execution (ISSUE 6). Gate off: the same executable runs
-        with no meter on it."""
-        import time
-        from .. import commwatch, compilewatch, telemetry
-        if ("fused_step", key) not in self._programs:
-            t0 = time.perf_counter()
-            self._executable("fused_step", self._fused, key, (
-                self.params, self.aux, self.states, self._t_dev,
-                self._rng_dev, *arrays))
-            dt = time.perf_counter() - t0
-            compilewatch.note_external_compile(dt)
-            try:
-                telemetry.counter("mx_compile_total",
-                                  fn="sharded_step").inc()
-                telemetry.histogram("mx_compile_seconds", fn="sharded_step",
-                                    stage="total").observe(dt)
-            except Exception:
-                pass
-        compiled, program = self._programs["fused_step", key]
+        MXNET_COMMWATCH): the step's AOT executable (``_executable``:
+        compiled once per data shape, as on every path) with its meters
+        fed: its ``cost_analysis`` FLOPs become the measured mx_mfu
+        numerator and its HLO text yields the GSPMD-collective
+        inventory (op/axis/bytes) commwatch charges per execution
+        (ISSUE 6)."""
+        from .. import commwatch, compilewatch
+        fn, program = self._executable("fused_step", self._fused, key, (
+            self.params, self.aux, self.states, self._t_dev,
+            self._rng_dev, *arrays))
         prog_key = ("sharded_step", id(self), key)
         if not commwatch.has_program(prog_key):
             # new, or telemetry.reset() cleared the inventories (the
             # warmup -> reset -> meter pattern) and the executable
             # outlived them: MFU and GSPMD comm keep flowing
+            compiled = self._programs["fused_step", key][0]
             commwatch.register_program(
                 prog_key, "sharded_step", compiled=compiled,
                 mesh=self.mesh, flops=compilewatch._extract_cost(compiled))
-        return compiled, program, prog_key
+        return fn, program, prog_key
 
     # ------------------------------------------------------------------
     # device-side scopes (docs/OBSERVABILITY.md "Device-side scopes")
@@ -592,14 +600,32 @@ class ShardedTrainStep:
         avals): lowered and compiled once through the AOT stages and
         kept. Every program of the step launches through here, so the
         executable whose text the table is read from is the one that
-        ran, and no table costs a second compile."""
+        ran, and no table costs a second compile. With telemetry on the
+        stages are timed into one ``compilewatch`` record
+        (``fn="sharded_step:<label>"``: trace / lower / compile seconds
+        and what the persistent cache said), and the pair handed back
+        by the call that compiled, and by no later one, makes the
+        program's first call under ``setup::first_launch``."""
         ent = self._programs.get((label, key))
         if ent is None:
-            from .. import telemetry
-            lowered = jitted.lower(*args)
-            compiled = lowered.compile()
-            ent = self._programs[(label, key)] = (
-                compiled, telemetry.DeviceProgram(label, lowered, compiled))
+            from .. import compilewatch, telemetry
+            if not telemetry.enabled():
+                lowered = jitted.lower(*args)
+                compiled = lowered.compile()
+                call = compiled
+            else:
+                lowered, compiled = compilewatch.watch_compile(
+                    jitted, args, fn="sharded_step:" + label,
+                    site="parallel.sharded", instance=self._name,
+                    recompile=any(l == label for l, _ in self._programs),
+                    signature=key)
+
+                def call(*step_args):
+                    with telemetry.setup_phase("first_launch"):
+                        return compiled(*step_args)
+            program = telemetry.DeviceProgram(label, lowered, compiled)
+            self._programs[(label, key)] = (compiled, program)
+            ent = (call, program)
         return ent
 
     def device_scopes(self) -> List[dict]:
@@ -717,10 +743,11 @@ class ShardedTrainStep:
                         # inventory lost to telemetry.reset(), or the
                         # gate was off when _layout_compiled ran
                         from .. import compilewatch
+                        compiled = self._compiled[key]
                         commwatch.register_program(
-                            prog_key, "sharded_step", compiled=fn,
+                            prog_key, "sharded_step", compiled=compiled,
                             mesh=self.mesh,
-                            flops=compilewatch._extract_cost(fn))
+                            flops=compilewatch._extract_cost(compiled))
                     watch = commwatch.program_watch(prog_key,
                                                     "sharded_step")
             elif commwatch.enabled():

@@ -53,12 +53,19 @@ span only under the Estimator), ``backward`` in ``autograd.backward``,
 ``allreduce`` / ``guard`` / ``optimizer`` on the classic one,
 ``sharded`` with ``sharded.place`` / ``sharded.launch`` in
 ``ShardedTrainStep.step``, ``data`` in the Estimator / DataLoader,
-and Module.update's allreduce / guard / optimizer.
+and Module.update's allreduce / guard / optimizer. The regions of a
+job's start carry ``setup::<phase>`` spans (:func:`setup_phase`: the
+package's import, the native libraries' load, ``ParameterDict.initialize``,
+``HybridBlock._build_cache`` and ``parallel.sharded.trace_block``,
+``ShardedTrainStep``'s placement, a program's first launch), kept apart
+from the steps' and merged with compilewatch's records by
+:func:`startup_phases` (docs/OBSERVABILITY.md "Start-up").
 """
 from __future__ import annotations
 
 import bisect
 import collections
+import heapq
 import logging
 import re
 import threading
@@ -77,6 +84,7 @@ from . import profiler
 __all__ = ["Counter", "Gauge", "Histogram", "span", "phase", "counter",
            "gauge", "histogram", "enabled", "enable", "refresh",
            "snapshot", "render_prometheus", "mark_step", "step_log",
+           "setup_phase", "setup_log", "startup_phases", "STARTUP_PHASES",
            "count_launch", "innermost_scope", "hlo_scopes",
            "DeviceProgram", "device_scope_tables",
            "heartbeat_line", "count_event", "guard_event",
@@ -327,6 +335,7 @@ def reset():
         _STEP["flops0"] = 0.0
         _STEP["compile_at_last"] = 0.0
         _STEPLOG.clear()
+        _SETUPLOG.clear()
     with _FLEET_LOCK:
         _FLEET["last"] = None
     _LAST_LAUNCHED[0] = _TRACED[0] = None
@@ -383,8 +392,27 @@ class _OpenSpans(threading.local):
         self.names: List[str] = []     # innermost last
 
 
+class _SetupLog:
+    """The spans of category ``setup`` (:func:`setup_phase`), which
+    belong to no step: ``(name, start, end, parent)`` tuples in order of
+    exit, ``time.perf_counter`` seconds. A start is a few hundred of
+    them; past ``CAP`` a span is counted in ``dropped`` and not kept."""
+    CAP = 4096
+
+    __slots__ = ("spans", "dropped")
+
+    def __init__(self):
+        self.clear()
+
+    def clear(self):
+        self.spans: List[tuple] = []
+        self.dropped = 0
+
+
 _STEPLOG = _StepLog()
+_SETUPLOG = _SetupLog()
 _OPEN_SPANS = _OpenSpans()
+SETUP_CATEGORY = "setup"
 LAUNCH_COUNTER = "mx_program_launches_total"
 LAUNCH_PATHS = ("gluon", "sharded")
 FUSED_STEP_COUNTER = "mx_fused_step_total"
@@ -409,7 +437,10 @@ class span:
     not two. Instrumentation failures are swallowed — a span must
     never poison the region it observes. ``cancel()`` inside the block
     drops the record (e.g. a probe that turned out not to be real
-    work)."""
+    work). A span of category ``setup`` goes to the set-up log
+    (:func:`setup_log`) in place of the step log: it belongs to no
+    step. ``backdate(t)`` inside the block moves the span's start to
+    ``t`` (the package's import began before a span could be made)."""
 
     __slots__ = ("name", "cat", "hist", "labels", "args", "_t0", "_live",
                  "_ann", "_parent")
@@ -425,6 +456,10 @@ class span:
 
     def cancel(self):
         self._live = False
+
+    def backdate(self, t0: float):
+        if self._ann is not None:
+            self._t0 = min(self._t0, t0)
 
     def __enter__(self):
         self._ann = None
@@ -455,12 +490,19 @@ class span:
             _OPEN_SPANS.names.pop()
             if not self._live:              # cancelled inside the block
                 return False
-            log = _STEPLOG
-            if len(log.open) < log.OPEN_SPAN_CAP:
-                log.open.append((self.name, self._t0, t1, self._parent,
-                                 _STEP["count"]))
+            if self.cat == SETUP_CATEGORY:
+                log = _SETUPLOG
+                if len(log.spans) < log.CAP:
+                    log.spans.append((self.name, self._t0, t1, self._parent))
+                else:
+                    log.dropped += 1
             else:
-                log.dropped += 1
+                log = _STEPLOG
+                if len(log.open) < log.OPEN_SPAN_CAP:
+                    log.open.append((self.name, self._t0, t1, self._parent,
+                                     _STEP["count"]))
+                else:
+                    log.dropped += 1
             dt = t1 - self._t0
             profiler.record_event(self.name, self.cat, self._t0 * 1e6,
                                   dt * 1e6, self.args)
@@ -483,6 +525,97 @@ def phase(name: str) -> span:
     docs/OBSERVABILITY.md "Step spans"."""
     return span("step::%s" % name, "step", hist="mx_step_phase_seconds",
                 phase=name)
+
+
+# ---------------------------------------------------------------------------
+# start-up — the regions of a job's start, from the import to a
+# program's first launch, on one timeline with compilewatch's compile
+# records (docs/OBSERVABILITY.md "Start-up")
+# ---------------------------------------------------------------------------
+SETUP_PREFIX = "setup::"
+STARTUP_PHASES = ("import", "native", "init", "graph", "place",
+                  "trace_lower", "compile_miss", "cache_load",
+                  "first_launch")
+# a compile record's stage -> the phase its seconds count under; the
+# ``compile`` stage (and ``total``, the degraded whole-call timing)
+# goes by the record's ``persistent_cache``
+_STAGE_PHASE = {"trace": "trace_lower", "lower": "trace_lower"}
+
+
+def setup_phase(name: str) -> span:
+    """A set-up span: chrome-trace event ``setup::<name>`` (category
+    ``setup``) + the ``mx_setup_phase_seconds{phase=<name>}`` histogram,
+    kept in :func:`setup_log` and in no step's record. Phases, each
+    opened where the work happens: import (the package's), native
+    (``make`` + ``ctypes.CDLL`` of a native library), init (parameter
+    initialisers, deferred ones too), graph (symbol tracing, layout and
+    AMP passes, ``compile_graph``), place (``ShardedTrainStep``'s master
+    copies, ``device_put``s, optimizer states, AUTO re-layout),
+    first_launch (the host's side of a program's first call, on the
+    compile-miss path only). The names are stable:
+    docs/OBSERVABILITY.md "Start-up"."""
+    return span(SETUP_PREFIX + name, SETUP_CATEGORY,
+                hist="mx_setup_phase_seconds", phase=name)
+
+
+def setup_log() -> List[tuple]:
+    """Every set-up span kept so far, in order of exit:
+    ``(name, start, end, parent)``, ``time.perf_counter`` seconds;
+    ``parent`` the enclosing open span's name on the same thread."""
+    return list(_SETUPLOG.spans)
+
+
+def _startup_intervals() -> List[tuple]:
+    """``(phase, start, end)`` of every set-up span and of every stage
+    of every compile record (stages run back to back from the record's
+    ``time``, in the order the record lists them)."""
+    out = [(name[len(SETUP_PREFIX):], start, end)
+           for name, start, end, _parent in _SETUPLOG.spans]
+    from . import compilewatch
+    for rec in compilewatch.programs():
+        at = rec["time"]
+        # no word from JAX (no persistent cache): a real compile
+        how = "cache_load" if rec.get("persistent_cache") == "hit" \
+            else "compile_miss"
+        for stage, dt in rec["stages"].items():
+            out.append((_STAGE_PHASE.get(stage, how), at, at + dt))
+            at += dt
+    return out
+
+
+def startup_phases(until: Optional[float] = None) -> Dict[str, float]:
+    """Seconds of the start by phase (:data:`STARTUP_PHASES`), from
+    :func:`setup_log` and ``compilewatch.programs()`` on one clock, up
+    to ``until`` (a ``time.perf_counter`` instant; default: the first
+    :func:`mark_step`; an interval that starts after it is left out,
+    one that straddles it is cut there). Each phase is **exclusive**:
+    an instant belongs to the innermost interval that covers it (the
+    one that started last), so a compile inside ``setup::init`` counts
+    as compile and not as init, and the phases sum to ``covered``, the
+    union of all the intervals: what of the start the program's own
+    spans and records see."""
+    if until is None:
+        until = _STEP["t0"]
+    intervals = sorted(
+        (start, end if until is None else min(end, until), name)
+        for name, start, end in _startup_intervals()
+        if until is None or start < until)
+    out = dict.fromkeys(STARTUP_PHASES, 0.0)
+    edges = sorted({t for start, end, _ in intervals for t in (start, end)})
+    active: List[tuple] = []    # heap: latest start first, then shortest
+    nxt = 0
+    for lo, hi in zip(edges, edges[1:]):
+        while nxt < len(intervals) and intervals[nxt][0] <= lo:
+            start, end, name = intervals[nxt]
+            heapq.heappush(active, (-start, end, name))
+            nxt += 1
+        while active and active[0][1] <= lo:
+            heapq.heappop(active)
+        if active:
+            name = active[0][2]
+            out[name] = out.get(name, 0.0) + (hi - lo)
+    out["covered"] = sum(out.values())
+    return out
 
 
 # ---------------------------------------------------------------------------
