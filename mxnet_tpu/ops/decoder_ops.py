@@ -29,10 +29,12 @@ one they can serve, the chunked composition here everywhere else
 Pallas flash kernel (``ops/pallas_causal_gqa.py``) where the call is
 one it can serve, the blocked composition here everywhere else
 (:func:`_attend`). Attention over a selector's keys likewise
-(:func:`_sparse_attend`): the selection is always the XLA code here,
-the attention over the selected set runs in flash kernels that take the
-set as a mask (``ops/pallas_sparse_gqa.py``) or as the masked
-composition. The expert buffer's products likewise
+(:func:`_sparse_attend`): thresholds, ties and mask are always the XLA
+code here; the attention over the selected set runs in flash kernels
+that take the set as a mask (``ops/pallas_sparse_gqa.py``), fed where
+they can serve by kernels that sum the index scores over their heads in
+VMEM (``ops/pallas_index_scores.py``), or as the masked composition.
+The expert buffer's products likewise
 (:func:`_blocks_product`): grouped kernels that read each block's
 weight tiles from the experts' own arrays
 (``ops/pallas_grouped_mlp.py``), or the batched product over gathered
@@ -107,8 +109,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import telemetry
-from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_moe_rows,
-               pallas_sparse_gqa, pallas_ssd, register)
+from . import (pallas_causal_gqa, pallas_grouped_mlp, pallas_index_scores,
+               pallas_moe_rows, pallas_sparse_gqa, pallas_ssd, register)
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
@@ -926,14 +928,20 @@ def _sparse_gqa_flash(q, k, v, iq, ik, iw, top_k):
     """:func:`_sparse_gqa`'s three results, the attention over the
     selected keys in the flash kernels of ``ops/pallas_sparse_gqa.py``:
     no score block exists outside VMEM. The selection is the
-    composition's own code and so its set, handed to the kernels as a
-    mask; the index loss reads the kernels' head-averaged probabilities.
-    Differentiated by hand (:func:`_flash_bwd`), a block at a time like
-    the composition's recomputed ``rows``: kept from the forward are
-    each row's threshold and tie count, the context and the rows'
-    log-sum-exp (all named for the mixer's ``jax.checkpoint``), so the
-    backward runs the index scores, the mask and the probabilities
-    again, never the forward kernel."""
+    composition's own code (thresholds, ties, mask), handed to the
+    kernels as a mask: ``lax.top_k``'s set of the index scores this
+    path computed, which are :func:`_index_scores` a block or, where
+    ``ops/pallas_index_scores.py`` can serve, its kernels' (the heads
+    summed in VMEM, another order of one sum: a score may differ from
+    the composition's in its last bit, and a tie at the ``top_k``-th
+    place with it). The index loss reads the kernels' head-averaged
+    probabilities. Differentiated by hand (:func:`_flash_bwd`), a block
+    at a time like the composition's recomputed ``rows``: kept from the
+    forward are each row's threshold and tie count, the context and the
+    rows' log-sum-exp (all named for the mixer's ``jax.checkpoint``), so
+    the backward runs the index scores (by the forward's own code: its
+    bits, so the forward's mask), the mask and the probabilities again,
+    never the forward kernel."""
     return _flash_fwd(q, k, v, iq, ik, iw, top_k)[0]
 
 
@@ -966,9 +974,13 @@ def _flash_fwd(q, k, v, iq, ik, iw, top_k):
     tile = QUERY_BLOCK
     b, length = q.shape[:2]
     scores, taus, ties, keeps, masks = [], [], [], [], []
-    for _, lo, index_in in _index_blocks(iq, ik, iw):
+    fused = pallas_index_scores.index_scores_available(iq, ik, iw, tile)
+    for i, lo, index_in in _index_blocks(iq, ik, iw):
         with jax.named_scope("mx.attn.index"):
-            scores.append(_index_scores(*index_in))
+            if fused and i == 0:    # one kernel, every block's scores
+                summed = pallas_index_scores.index_score_blocks(iq, ik, iw,
+                                                                tile)
+            scores.append(summed[i] if fused else _index_scores(*index_in))
         with jax.named_scope("mx.attn.select"):
             tau, tie = _block_thresholds(scores[-1], lo, top_k)
             keeps.append(_block_selected(scores[-1], tau, tie, lo))
@@ -996,39 +1008,55 @@ def _flash_bwd(top_k, res, cotangents):
     """A block at a time: its index scores again (with the pullback to
     the index inputs), its mask from the kept thresholds, its
     probabilities from the kept log-sum-exp, the index loss's gradient;
-    then one backward kernel under the blocks' masks together. (The
-    rule is traced after the caller's scopes have closed: it opens them
-    again.)"""
+    then one backward kernel under the blocks' masks together. Where
+    the index scores' kernels serve, every block's scores come from
+    one call of the forward's kernel and the blocks' cotangents go back
+    through one backward kernel. (The rule is traced after the caller's
+    scopes have closed: it opens them again.)"""
     q, k, v, iq, ik, iw, tau, ties, ctx, lse = res
     dctx, dloss, _ = cotangents
     tile = QUERY_BLOCK
     b, length = q.shape[:2]
     share = dloss / (b * length)
-    diq, diw, masks = [], [], []
+    diq, diw, masks, dscores = [], [], [], []
     dik = jnp.zeros(ik.shape, F32)
+    fused = pallas_index_scores.index_scores_available(iq, ik, iw, tile)
     with jax.named_scope("mx.attn.dsa"):
         for i, lo, index_in in _index_blocks(iq, ik, iw):
             hi = lo + tile
             with jax.named_scope("mx.attn.index"):
-                scores, pull = jax.vjp(_index_scores, *index_in)
+                if fused and i == 0:    # the forward's kernel: its bits
+                    summed, pull = pallas_index_scores.index_score_blocks_vjp(
+                        iq, ik, iw, tile)
+                if fused:
+                    scores = summed[i]
+                else:
+                    scores, pull = jax.vjp(_index_scores, *index_in)
             with jax.named_scope("mx.attn.select"):
                 keep = _block_selected(scores, tau[:, lo:hi], ties[:, lo:hi],
                                        lo)
                 masks.append(_keys_by_queries(keep))
             target = _head_mean_probs(q, k, masks[-1], lse, i)
             with jax.named_scope("mx.attn.index"):
-                dq_i, dk_i, dw_i = pull(
-                    jax.grad(_index_kl)(scores, keep, target) * share)
-                dik = dik.at[:, :hi].add(dk_i.astype(F32))
-            diq.append(dq_i)
-            diw.append(dw_i)
+                dscore = jax.grad(_index_kl)(scores, keep, target) * share
+                if fused:
+                    dscores.append(dscore)
+                else:
+                    dq_i, dk_i, dw_i = pull(dscore)
+                    dik = dik.at[:, :hi].add(dk_i.astype(F32))
+                    diq.append(dq_i)
+                    diw.append(dw_i)
+        with jax.named_scope("mx.attn.index"):
+            if fused:       # one kernel under the blocks' cotangents
+                diq, dik, diw = pull(tuple(dscores))
+            else:
+                diq, diw = (jnp.concatenate(t, axis=1) for t in (diq, diw))
         with jax.named_scope("mx.attn.select"):
             mask = pallas_sparse_gqa.mask_blocks(masks, length)
         with jax.named_scope(pallas_sparse_gqa.SCOPE):
             dq, dk, dv = pallas_sparse_gqa.attend_bwd(q, k, v, mask, ctx, lse,
                                                       dctx, tile)
-    return (dq, dk, dv, jnp.concatenate(diq, axis=1), dik.astype(ik.dtype),
-            jnp.concatenate(diw, axis=1))
+    return dq, dk, dv, diq, dik.astype(ik.dtype), diw
 
 
 _sparse_gqa_flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1040,13 +1068,20 @@ def _sparse_attend(q, k, v, iq, ik, iw, top_k):
     else (``pallas_sparse_gqa.sparse_gqa_available``: what
     :func:`_attend` asks, and kernels that will be compiled or whose
     interpretation was asked for); counted once a traced call in
-    ``mx_attn_sparse_path_total{path="pallas"|"masked"}``. The caller
-    opens ``mx.attn.dsa``."""
+    ``mx_attn_sparse_path_total{path="pallas"|"masked"}``, and beside it
+    how the index scores are computed, in
+    ``mx_attn_index_path_total{path="pallas"|"xla"}`` (``pallas`` only
+    inside the flash form: ``index_scores_available``). The caller opens
+    ``mx.attn.dsa``."""
     kernel = pallas_sparse_gqa.sparse_gqa_available(q, k, v, QUERY_BLOCK)
     telemetry.count_event("mx_attn_sparse_path_total",
                           path="pallas" if kernel else "masked")
-    form = _sparse_gqa_flash if kernel else _sparse_gqa
-    ctx, loss, kept = form(q, k, v, iq, ik, iw.astype(F32), int(top_k))
+    form, iw = _sparse_gqa_flash if kernel else _sparse_gqa, iw.astype(F32)
+    telemetry.count_event(
+        "mx_attn_index_path_total", path="pallas"
+        if kernel and pallas_index_scores.index_scores_available(
+            iq, ik, iw, QUERY_BLOCK) else "xla")
+    ctx, loss, kept = form(q, k, v, iq, ik, iw, int(top_k))
     return ctx, loss.reshape(1), lax.stop_gradient(jnp.stack([kept, loss]))
 
 
@@ -1076,8 +1111,13 @@ _DSA_DOC = """
     pattern: 32 passes of compare-and-count) and recomputes its scores
     in the backward from the kept thresholds. Where the call allows it
     (:func:`_sparse_attend`) the attention scores live in the VMEM of
-    flash kernels that read the selected set as a mask; the set is the
-    same bit for bit."""
+    flash kernels that read the selected set as a mask, and the index
+    scores' product a head in the VMEM of kernels that write only its
+    sum over the heads. Exact in every form: the set is ``lax.top_k``'s
+    of the index scores the form computed, and the backward's mask is
+    the forward's. Not exact between forms: the heads are summed in
+    another order, so a score may differ in its last bit and two keys an
+    ulp apart at the ``top_k``-th place may change places."""
 
 
 @register("_contrib_sparse_gqa_attention", num_outputs=2, mutate_aux={2: 6})

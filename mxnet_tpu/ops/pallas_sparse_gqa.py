@@ -1,10 +1,18 @@
 """Causal grouped-query attention over the keys a learned selector
 keeps, as flash kernels that take the selection as a mask
 (``ops/decoder_ops.py::_sparse_gqa`` is the composition they stand in
-for, and stays the path of everything they cannot serve). The selection
-itself (index scores, thresholds, ties) stays the XLA code of
-``decoder_ops``: a product accumulated in another order moves ties and
-with them the set, so the kernels are handed the set, never the scores.
+for, and stays the path of everything they cannot serve). The kernels
+are handed the selected set, never the scores: thresholds, ties and
+mask stay the XLA code of ``decoder_ops``, one code for every form, so
+the set is exactly ``lax.top_k``'s of the index scores the form
+computed, and the backward's mask, rebuilt from the kept thresholds and
+the scores computed again by the forward's own code, is the forward's
+bit for bit. The index scores themselves are ``decoder_ops.
+_index_scores`` a block or, where they can serve, the kernels of
+``ops/pallas_index_scores.py``, which sum the heads in VMEM in an order
+of their own: against the composition a score may differ in its last
+bit, and which of two keys an ulp apart is kept is not part of the
+result (the float32 reference and the bf16 system differ there too).
 
 The mask. ``int8``, keys x queries, a query tile's whole column of key
 tiles one block: ``(batch, query tiles, length, tile)``, zero past the

@@ -202,8 +202,15 @@ def _sparse_mixer_gradient(one_chip, length, top_k):
         .lower(*args).compile().as_text()
 
 
+# the selector's kernels (ops/pallas_index_scores.py): the scores'
+# forward in the forward and again in the backward rule, one backward
+_INDEX_KERNELS = {"pallas_index_scores_fwd": 2, "pallas_index_scores_bwd": 1}
+# a query block's index product a head, as the composition holds it
+_PER_HEAD_SCORES = re.compile(r"f32\[(1,)?16,512,\d+\]|f32\[\d+,512,16\]")
+
+
 # two and four query blocks in tier-1; the published length's sixteen
-# (34 Mosaic calls, two minutes of one core) makes every same assertion:
+# (37 Mosaic calls, two minutes of one core) makes every same assertion:
 # `slow` here, and compiled on the chip by the Keye-VL cell
 @pytest.mark.parametrize("length, top_k", [
     (1024, 256), (2048, 512),
@@ -217,7 +224,10 @@ def test_sparse_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
     kernel once: the recomputation does not run it again; the
     probabilities a query block in the forward and again in the
     backward rule; one backward kernel), and no score block is left in
-    the program."""
+    the program. The index scores likewise: three calls named
+    ``pallas_index_scores_*`` whatever the number of blocks, under
+    ``mx.attn.index`` (what ``index_scores_ms`` reads), and no index
+    product a head is left either."""
     from mxbench import scopes
     text = _sparse_mixer_gradient(one_chip, length, top_k)
     calls = mosaic_calls(text)
@@ -227,34 +237,41 @@ def test_sparse_gqa_kernels_compile_under_the_scope_the_benchmark_reads(
     assert set(placed.values()) == set(names)
     kernels = {name: scope for name, scope in placed.items()
                if name.startswith("pallas_sparse_gqa_")}
+    index = {name: scope for name, scope in placed.items()
+             if name.startswith("pallas_index_scores_")}
     blocks = length // 512
-    assert len(calls) == len(kernels) == 2 + 2 * blocks
+    assert len(kernels) == 2 + 2 * blocks
+    assert len(calls) == len(kernels) + len(index)
     assert set(kernels.values()) == {"mx.attn.sparse"}
-    kinds = [name.split(".")[0] for name in kernels]
+    assert set(index.values()) == {"mx.attn.index"}
+    kinds = [name.split(".")[0] for name in list(kernels) + list(index)]
     assert kinds.count("pallas_sparse_gqa_fwd") == 1
     assert kinds.count("pallas_sparse_gqa_bwd") == 1
     assert kinds.count("pallas_sparse_gqa_probs") == 2 * blocks
+    assert {k: kinds.count(k) for k in _INDEX_KERNELS} == _INDEX_KERNELS
     for line in calls:
-        assert "mx.attn.sparse" in line.split('op_name="')[1].split('"')[0]
+        scope = "index" if "pallas_index_scores" in line else "sparse"
+        assert "mx.attn." + scope in line.split('op_name="')[1].split('"')[0]
     assert "f32[1,4,8,512," not in text
     assert "f32[1,32,512," not in text
+    assert not _PER_HEAD_SCORES.search(text)
 
 
 # temporaries, arguments, outputs of the toy Keye-VL step on PR 52's parent
 _KEYE_TOY_BYTES = (15484416, 3672064, 3673600)
 
 
-def _toy_step(one_chip, name):
+def _toy_step(one_chip, name, length=64, **widths):
     """A zoo decoder through ``trace_block`` as ``ShardedTrainStep``
     traces it (its losses, bf16 compute, AdamW through the shared
-    ``_apply_update``), at the configuration's toy widths, two sequences
-    of 64 tokens: (the compiled step, the configuration's module, its
-    auxiliary states' names)."""
+    ``_apply_update``), at the configuration's toy widths (but for
+    ``widths``), two sequences of ``length`` tokens: (the compiled step,
+    the configuration's module, its auxiliary states' names)."""
     from mxbench import manifest
     from mxnet_tpu.parallel.sharded import _apply_update, trace_block
     sizes, cfgmod, _ = manifest.config(name)
-    sizes = dict(sizes, **sizes["toy"])
-    net, loss, n_in = cfgmod.sharded_parts(sizes, 0.0, 64)
+    sizes = dict(sizes, **dict(sizes["toy"], **widths))
+    net, loss, n_in = cfgmod.sharded_parts(sizes, 0.0, length)
     fn, data_names, names, _ = trace_block(net, loss, n_in)
     shapes = {n: p.shape for block in (net, loss.head)
               for n, p in block.collect_params().items()}
@@ -283,7 +300,7 @@ def _toy_step(one_chip, name):
 
     params = {n: sds(shapes[n]) for n in names}
     aux = {n: sds(shapes[n]) for n in aux_names}
-    ids = sds((2, 64), jnp.int32)
+    ids = sds((2, length), jnp.int32)
     return jax.jit(step).lower(
         params, aux, {n: (params[n], params[n]) for n in names}, sds(()),
         ids, ids).compile(), cfgmod, aux_names
@@ -321,6 +338,63 @@ def test_the_toy_keye_step_takes_the_bytes_it_took(one_chip, compiled):
     m = step.memory_analysis()
     assert (m.temp_size_in_bytes, m.argument_size_in_bytes,
             m.output_size_in_bytes) == _KEYE_TOY_BYTES
+
+
+def test_the_toy_keye_step_on_heads_the_kernels_serve_holds_them_all(
+        one_chip, compiled_mode):
+    """The toy step with the published heads (128 lanes; index heads of
+    64, in pairs) over two query blocks, compiled and not interpreted:
+    a layer's attention kernels and the selector's three, the latter
+    under ``mx.attn.index``, in the whole step as ``ShardedTrainStep``
+    traces it (two sequences: the kernels' batch axis)."""
+    from mxbench import manifest
+    toy = manifest.config("keye_vl2_30b_a3b")[0]["toy"]
+    step, _, _ = _toy_step(
+        one_chip, "keye_vl2_30b_a3b", length=1024, head_dim=128,
+        rope_scaling=dict(toy["rope_scaling"], mrope_section=[16, 24, 24]),
+        sa_config=dict(toy["sa_config"], indexer_head_dim=64))
+    text = step.as_text()
+    names = [line.split("=")[0].strip().lstrip("%").split(".")[0]
+             for line in mosaic_calls(text)]
+    names = [n for n in names if "_sparse_gqa_" in n or "_index_scores_" in n]
+    layers = 2
+    assert {n: names.count(n) for n in set(names)} == {
+        "pallas_sparse_gqa_fwd": layers, "pallas_sparse_gqa_bwd": layers,
+        "pallas_sparse_gqa_probs": layers * 2 * 2,
+        **{k: layers * n for k, n in _INDEX_KERNELS.items()}}
+    for line in mosaic_calls(text):
+        if "pallas_index_scores" in line:
+            assert "mx.attn.index" in line.split('op_name="')[1].split('"')[0]
+    assert not re.search(r"f32\[(2,)?4,512,\d+\]", text)
+
+
+# what the compiler may give the Keye-VL cell's whole step in temporaries
+_KEYE_STEP_TEMPORARIES = 4.5e9
+
+
+@pytest.mark.slow
+def test_the_keye_cell_s_whole_step_stays_under_its_bytes(one_chip):
+    """``tools/step_bytes.py keye_vl2_30b_a3b_midtrain_s8192`` in this
+    process (two to three minutes, 8 GB): the cell's step as
+    ``ShardedTrainStep`` builds it, compiled for the described chip,
+    fits, and its temporaries stay under a bound. Read here: 9,873,819,136
+    bytes on PR 54's parent (arguments 7,910,355,968, code 210,776,576:
+    16 MB under the chip by ``memory_peak_bytes``, nineteen of the twenty
+    largest buffers at the heap's peak the selector's per-head index
+    scores); **3,733,122,560** since PR 54 sums those scores over their
+    heads in VMEM (arguments the same, code 649,655,296). The bound
+    leaves a fifth of room: a change that brings a gigabyte back has
+    to say so here (ROADMAP A11)."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "step_bytes", os.path.join(os.path.dirname(__file__), os.pardir,
+                                   "tools", "step_bytes.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    found = tool.step_bytes("keye_vl2_30b_a3b_midtrain_s8192")
+    assert found["layers"] == 6
+    assert found["temporaries"] <= _KEYE_STEP_TEMPORARIES, found
 
 
 # ---------------------------------------------------------------------------

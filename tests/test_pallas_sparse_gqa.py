@@ -5,8 +5,15 @@ loss, state and every gradient against the composition they stand in for
 rows whose selected keys miss whole tiles; the causal limit; causality;
 what the mixer keeps; and the ladder by which
 ``decoder_ops._sparse_attend`` picks a form, each rung counted in
-``mx_attn_sparse_path_total``. What Mosaic makes of the kernels at the
+``mx_attn_sparse_path_total``. And the selector's index scores summed
+over their heads in VMEM (ops/pallas_index_scores.py), which the flash
+form takes where it can: scores and gradients against the composition
+(``decoder_ops._index_scores``) and the float32 reference, the mask the
+backward rebuilds against the forward's, its own rungs, each counted in
+``mx_attn_index_path_total``. What Mosaic makes of the kernels at the
 published widths is tests/test_chip_compile_*.py's."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,13 +22,20 @@ from jax.sharding import Mesh
 
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import (decoder_ops as D, get_op, pallas_causal_gqa as P,
-                           pallas_common, pallas_sparse_gqa as S)
+                           pallas_common, pallas_index_scores as I,
+                           pallas_sparse_gqa as S)
 from mxnet_tpu.ops.pallas_common import auto_partitioned
-from numerics import BF, F32, near, normal, reference, value_and_grads
+from numerics import (BF, F32, close, jitted, near, normal, reference,
+                      value_and_grads)
 
 KREF = reference("keye_vl2_30b_a3b")
 COUNTER = "mx_attn_sparse_path_total"
+INDEX_COUNTER = "mx_attn_index_path_total"
+PATHS = {COUNTER: ("pallas", "masked"), INDEX_COUNTER: ("pallas", "xla")}
 TILE = 128
+# index heads the kernels of ops/pallas_index_scores.py serve (the
+# published 64 lanes, in pairs); ``_inputs``' own (2 x 8) they do not
+SUMMED = dict(ih=2, idim=64)
 
 pytestmark = pytest.mark.usefixtures("pallas_interpret")
 
@@ -74,7 +88,26 @@ def _outputs_and_grads(form, args, cot, top_k):
     ids=["one_tile", "three_tiles_k_below", "k_a_tile", "k_above"])
 def test_kernels_match_the_composition_and_the_reference(length, top_k,
                                                          heads, kv):
-    *args, cot = _inputs(length + heads + top_k, length, heads, kv)
+    _flash_against_the_composition_and_the_reference(length, top_k, heads, kv)
+
+
+@pytest.mark.parametrize("length, top_k, ih, idim", [
+    (3 * TILE, 48, 4, 64), (2 * TILE, 200, 2, 128)],
+    ids=["two_pairs_of_64", "two_heads_of_128"])
+def test_the_flash_form_on_summed_index_scores_matches_them_too(
+        length, top_k, ih, idim):
+    """The same comparison where the index scores come from the kernels
+    that sum them over the heads in VMEM (and their gradient from the
+    backward kernel)."""
+    assert I.index_scores_available(
+        *_inputs(0, length, 4, 2, ih=ih, idim=idim)[3:6], TILE)
+    _flash_against_the_composition_and_the_reference(length, top_k, 4, 2,
+                                                     ih=ih, idim=idim)
+
+
+def _flash_against_the_composition_and_the_reference(length, top_k, heads, kv,
+                                                     **index):
+    *args, cot = _inputs(length + heads + top_k, length, heads, kv, **index)
     got = _outputs_and_grads(D._sparse_gqa_flash, args, cot, top_k)
     # the composition on the same bf16 inputs: the same selected set bit
     # for bit, two roundings of one sum elsewhere
@@ -88,6 +121,85 @@ def test_kernels_match_the_composition_and_the_reference(length, top_k,
                            top_k)
     near(got, ref, 2e-2)
     assert float(got[1]) == pytest.approx(float(ref[1]), rel=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# the index scores summed over their heads in VMEM
+# ---------------------------------------------------------------------------
+INDEX_SHAPES = {
+    # name: (length, index heads, their width)
+    "one_tile_a_pair": (TILE, 2, 64),
+    "three_tiles_two_pairs": (3 * TILE, 4, 64),
+    "two_tiles_heads_of_a_lane_tile": (2 * TILE, 2, 128),
+}
+
+
+def _composition_blocks(iq, ik, iw):
+    return tuple(D._index_scores(*index_in)
+                 for _, _, index_in in D._index_blocks(iq, ik, iw))
+
+
+@pytest.mark.parametrize("shape", sorted(INDEX_SHAPES))
+def test_summed_index_scores_match_the_composition_and_the_reference(shape):
+    """Every query block's scores (the second and third blocks read keys
+    of earlier tiles) and, under a cotangent a block, ``d qI``, ``d kI``
+    and ``dw``."""
+    length, ih, idim = INDEX_SHAPES[shape]
+    iq, ik, iw = _inputs(17, length, 2, 1, batch=2, ih=ih, idim=idim)[3:6]
+    assert I.index_scores_available(iq, ik, iw, TILE)
+    n = length // TILE
+    cot = tuple(normal(key, (2, TILE, (i + 1) * TILE)) for i, key in
+                enumerate(jax.random.split(jax.random.key(18), n)))
+    got = value_and_grads(lambda *a: I.index_score_blocks(*a, TILE),
+                          iq, ik, iw, cot=cot)
+    want = value_and_grads(_composition_blocks, iq, ik, iw, cot=cot)
+    close(got[:n], want[:n], 1e-5)
+    whole = jitted(KREF.index_scores)(iq.astype(F32), ik.astype(F32), iw)
+    for i, block in enumerate(got[:n]):
+        close(block, whole[:, i * TILE:(i + 1) * TILE, :(i + 1) * TILE], 1e-5)
+    near(got[n:n + 2], want[n:n + 2], 1e-2)     # bf16 gradients
+    near(got[n + 2:], want[n + 2:], 1e-5)
+
+
+def _loss_gradient(form, inputs):
+    """Run the gradient of both outputs of ``_sparse_gqa_flash`` or of
+    the mixer op at ``inputs``."""
+    *args, cot = inputs
+    if form == "flash":
+        return _outputs_and_grads(D._sparse_gqa_flash, args, cot, 48)
+    fn, args = _mixer_form(args[0], args[1], args[3])
+
+    def loss(*a):
+        y, index_loss, _ = fn(*a)
+        return jnp.sum(y.astype(F32)) + index_loss[0]
+
+    return jax.block_until_ready(jax.jit(jax.grad(loss))(*args))
+
+
+@pytest.mark.parametrize("form", ["flash", "mixer"])
+def test_the_backward_rebuilds_the_forward_s_mask_bit_for_bit(form,
+                                                              monkeypatch):
+    """On the path of the summed index scores: the mask the backward
+    kernel is handed (the scores computed again, the kept thresholds
+    and tie counts) is the one the forward kernel was handed."""
+    masks = {"attend": [], "attend_bwd": []}
+    for name, kept in masks.items():
+        def spy(q, k, v, mask, *rest, kernel=getattr(S, name), kept=kept):
+            jax.debug.callback(lambda m: kept.append(np.asarray(m)), mask)
+            return kernel(q, k, v, mask, *rest)
+        monkeypatch.setattr(S, name, spy)
+    inputs = _inputs(21, 3 * TILE, 4, 2, **SUMMED)
+    assert I.index_scores_available(*inputs[3:6], TILE)
+    _loss_gradient(form, inputs)
+    jax.effects_barrier()
+    # (the mixer's recomputation keeps the spy's callback, and so its
+    # mask, though not the forward kernel: one more forward mask there)
+    assert len(masks["attend"]) == (2 if form == "mixer" else 1)
+    (backward,) = masks["attend_bwd"]
+    for forward in masks["attend"]:
+        # selection engaged: some seen pair of the last block is not kept
+        assert 0 < forward[0, 2].sum() < 2.5 * TILE * TILE
+        np.testing.assert_array_equal(forward, backward)
 
 
 # ---------------------------------------------------------------------------
@@ -184,13 +296,15 @@ def test_a_key_after_position_t_never_reaches_output_t(t):
 # ---------------------------------------------------------------------------
 @pytest.fixture
 def counted():
-    """{path: count} of the calls counted since the fixture began."""
+    """{path: count} of the calls counted since the fixture began, in
+    ``mx_attn_sparse_path_total`` or the counter named."""
     was = telemetry.enabled()
     telemetry.enable(True)
-    start = {p: telemetry.counter(COUNTER, path=p).get()
-             for p in ("pallas", "masked")}
-    yield lambda: {p: telemetry.counter(COUNTER, path=p).get() - n
-                   for p, n in start.items()}
+    start = {(c, p): telemetry.counter(c, path=p).get()
+             for c, paths in PATHS.items() for p in paths}
+    yield lambda counter=COUNTER: {
+        p: telemetry.counter(c, path=p).get() - n
+        for (c, p), n in start.items() if c == counter}
     telemetry.enable(was)
 
 
@@ -271,17 +385,18 @@ RUNGS = {
 def test_each_rung_takes_the_composition_and_is_counted_masked(rung, form,
                                                                counted):
     length, d, dtype, scope = RUNGS[rung]
-    inputs = _inputs(1, length, 2, 1, d, dtype=dtype)
+    inputs = _inputs(1, length, 2, 1, d, dtype=dtype, **SUMMED)
     fn, args = _forms(inputs)[form]
-    if scope is None:
+    # the index scores' kernels stand down on the same rungs (and go
+    # with the flash form where only the attention's width refuses)
+    index_too = rung != "head_width_off_the_lanes"
+    with (scope or contextlib.nullcontext)():
         assert not S.sparse_gqa_available(*inputs[:3], TILE)
+        assert I.index_scores_available(*inputs[3:6], TILE) != index_too
         calls = _kernel_calls(fn, args)
-    else:
-        with scope():
-            assert not S.sparse_gqa_available(*inputs[:3], TILE)
-            calls = _kernel_calls(fn, args)
     assert calls == {}
     assert counted() == {"pallas": 0, "masked": 1}
+    assert counted(INDEX_COUNTER) == {"pallas": 0, "xla": 1}
 
 
 @pytest.mark.parametrize("form", ["op", "mixer"])
@@ -290,35 +405,56 @@ def test_no_chip_and_no_interpretation_asked_takes_the_composition(
     """Kernels that would be interpreted only because no chip is
     attached serve nothing: the call is the composition's, as on any
     CPU; where they will be compiled they serve."""
-    inputs = _inputs(1, TILE, 2, 1)
+    inputs = _inputs(1, TILE, 2, 1, **SUMMED)
     fn, args = _forms(inputs)[form]
     monkeypatch.delenv("MXNET_PALLAS_INTERPRET")
     assert pallas_common.interpret_mode()
     assert not S.sparse_gqa_available(*inputs[:3], TILE)
+    assert not I.index_scores_available(*inputs[3:6], TILE)
     assert _kernel_calls(fn, args) == {}
     assert counted() == {"pallas": 0, "masked": 1}
+    assert counted(INDEX_COUNTER) == {"pallas": 0, "xla": 1}
     monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
     assert S.sparse_gqa_available(*inputs[:3], TILE)
+    assert I.index_scores_available(*inputs[3:6], TILE)
+
+
+# index heads the attention's kernels take with the scores' composition
+INDEX_RUNGS = {"index_width_off_the_lanes": dict(ih=2, idim=8),
+               "an_odd_head_of_64": dict(ih=3, idim=64)}
 
 
 @pytest.mark.parametrize("form", ["op", "mixer"])
-def test_an_eligible_call_takes_the_kernels_and_is_counted_pallas(form,
-                                                                  counted):
+@pytest.mark.parametrize("index", ["summed", *sorted(INDEX_RUNGS)])
+def test_an_eligible_call_takes_the_kernels_and_is_counted_pallas(
+        index, form, counted):
     """Two query blocks: one forward kernel (the mixer's recomputation
     does not run it again), the probabilities a block in the forward
-    and a block in the backward, one backward kernel."""
-    inputs = _inputs(2, 2 * TILE, 2, 1)
+    and a block in the backward, one backward kernel. Index heads the
+    scores' kernels serve: their forward once in the forward and once
+    in the backward rule (the same kernel, so the same bits), one
+    backward kernel, whatever the number of blocks; heads they cannot
+    serve: the composition's scores under the same attention kernels,
+    counted ``xla``."""
+    summed = index == "summed"
+    inputs = _inputs(2, 2 * TILE, 2, 1, **INDEX_RUNGS.get(index, SUMMED))
     fn, args = _forms(inputs)[form]
     assert S.sparse_gqa_available(*inputs[:3], TILE)
-    assert _kernel_calls(fn, args) == {"pallas_sparse_gqa_fwd": 1,
-                                       "pallas_sparse_gqa_probs": 4,
-                                       "pallas_sparse_gqa_bwd": 1}
+    assert I.index_scores_available(*inputs[3:6], TILE) == summed
+    assert _kernel_calls(fn, args) == dict(
+        {"pallas_sparse_gqa_fwd": 1, "pallas_sparse_gqa_probs": 4,
+         "pallas_sparse_gqa_bwd": 1},
+        **({"pallas_index_scores_fwd": 2, "pallas_index_scores_bwd": 1}
+           if summed else {}))
     assert counted() == {"pallas": 1, "masked": 0}
+    assert counted(INDEX_COUNTER) == {"pallas": int(summed),
+                                      "xla": int(not summed)}
 
 
+@pytest.mark.parametrize("index", [{}, SUMMED], ids=["index_xla", "summed"])
 def test_the_op_on_the_kernel_path_gives_the_composition_s_values(
-        monkeypatch):
-    inputs = _inputs(3, 2 * TILE, 4, 2)
+        index, monkeypatch):
+    inputs = _inputs(3, 2 * TILE, 4, 2, **index)
     fn, args = _forms(inputs)["mixer"]
 
     def run():
@@ -337,12 +473,14 @@ def test_the_op_on_the_kernel_path_gives_the_composition_s_values(
     near(got, want, 3e-2)
 
 
+@pytest.mark.parametrize("index", [{}, SUMMED], ids=["index_xla", "summed"])
 def test_the_mixer_on_the_kernel_path_keeps_thresholds_context_and_lse(
-        capsys):
+        index, capsys):
     """Beside its arguments the mixer's checkpoint keeps each row's
     threshold and tie count, the context and the rows' log-sum-exp: no
-    mask, no probabilities, no projection."""
-    inputs = _inputs(4, 2 * TILE, 2, 1)
+    mask, no probabilities, no projection, and no index score where the
+    kernels sum them."""
+    inputs = _inputs(4, 2 * TILE, 2, 1, **index)
     fn, args = _forms(inputs)["mixer"]
 
     def loss(*a):

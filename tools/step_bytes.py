@@ -34,21 +34,17 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def main():
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("cell", help="a training cell of mxbench/workloads/")
-    ap.add_argument("--layers", type=int, default=0,
-                    help="num_hidden_layers in place of the configuration's")
-    ap.add_argument("--dump", default="",
-                    help="directory for the compiler's dump of the step")
-    args = ap.parse_args()
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    if args.dump:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") + " --xla_dump_to=%s "
-            "--xla_dump_hlo_as_text --xla_dump_hlo_module_re=jit_fused_step"
-            % args.dump)
+class DoesNotFit(Exception):
+    """The compiler's sentence (or the head of its error)."""
 
+
+def step_bytes(name, layers=0):
+    """``{"temporaries", "arguments", "code", "layers"}`` of the training
+    cell ``name``'s step compiled for a described v5e (``layers``: a
+    ``num_hidden_layers`` in place of the configuration's), or
+    :class:`DoesNotFit`. The process's ``jax.device_put`` and the
+    kernels' ``interpret_mode`` are stood in for while it runs and put
+    back."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -60,18 +56,16 @@ def main():
     from mxnet_tpu.parallel import sharded
 
     jax.config.update("jax_enable_compilation_cache", False)
-    # kernels as the chip gets them, though only CPU devices are attached
-    pallas_common.interpret_mode = lambda: False
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1), ("dp",))
     rep = NamedSharding(mesh, P())
 
-    cell = manifest.workload(args.cell)
+    cell = manifest.workload(name)
     traffic, _ = manifest.traffic(cell["traffic"])
     sizes, cfgmod, _ = manifest.config(cell["config"])
-    if args.layers:
-        sizes = dict(sizes, num_hidden_layers=args.layers)
+    if layers:
+        sizes = dict(sizes, num_hidden_layers=layers)
     seq, batch = int(traffic["seq"]), int(traffic["batch_per_chip"])
     net, loss, n_in = cfgmod.sharded_parts(
         sizes, float(traffic.get("dropout", 0.0)), seq)
@@ -104,37 +98,58 @@ def main():
     n_states = sharded._n_states(step._optimizer, step._hp["momentum"])
     step.state_shardings = {n: (rep,) * n_states for n in names}
     step.data_shardings = [rep] * n_in
-    put = jax.device_put
-    jax.device_put = lambda x, s=None: sds(np.shape(x), np.asarray(x).dtype)
-    try:
-        step._build()
-    finally:
-        jax.device_put = put
-    if not step._use_auto_layout:
-        sys.exit("step_bytes: the step was not built with AUTO layouts")
-
     params = {n: sds(shapes[n]) for n in names}
     states = {n: (params[n],) * n_states for n in names}
     ids = sds((batch, seq), jnp.int32)
+    put, interpret = jax.device_put, pallas_common.interpret_mode
+    jax.device_put = lambda x, s=None: sds(np.shape(x), np.asarray(x).dtype)
+    # kernels as the chip gets them, though only CPU devices are attached
+    pallas_common.interpret_mode = lambda: False
+    try:
+        step._build()
+        if not step._use_auto_layout:
+            raise RuntimeError("the step was not built with AUTO layouts")
+        lowered = step._fused.lower(
+            params, step.aux, states, sds(()),
+            sds(step._rng.shape, jnp.uint32), *([ids] * n_in))
+        try:
+            compiled = lowered.compile()
+        except Exception as e:   # the compiler's own error types vary
+            said = re.search(r"Used [0-9.]+G of [0-9.]+G hbm\. Exceeded hbm "
+                             r"capacity by [0-9.]+[KMG]", str(e))
+            raise DoesNotFit(said.group(0) if said else str(e)[:400]) from e
+    finally:
+        jax.device_put, pallas_common.interpret_mode = put, interpret
+    m = compiled.memory_analysis()
+    return {"temporaries": m.temp_size_in_bytes,
+            "arguments": m.argument_size_in_bytes,
+            "code": m.generated_code_size_in_bytes,
+            "layers": layers or sizes.get("num_hidden_layers")}
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("cell", help="a training cell of mxbench/workloads/")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="num_hidden_layers in place of the configuration's")
+    ap.add_argument("--dump", default="",
+                    help="directory for the compiler's dump of the step")
+    args = ap.parse_args()
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    if args.dump:
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") + " --xla_dump_to=%s "
+            "--xla_dump_hlo_as_text --xla_dump_hlo_module_re=jit_fused_step"
+            % args.dump)
     t0 = time.time()
     try:
-        compiled = step._fused.lower(
-            params, step.aux, states, sds(()),
-            sds(step._rng.shape, jnp.uint32), *([ids] * n_in)).compile()
-    except Exception as e:   # the compiler's own error types vary
-        said = re.search(r"Used [0-9.]+G of [0-9.]+G hbm\. Exceeded hbm "
-                         r"capacity by [0-9.]+[KMG]", str(e))
+        found = step_bytes(args.cell, args.layers)
+    except DoesNotFit as e:
         print("step_bytes %s: does not fit: %s (%d s)" % (
-            args.cell, said.group(0) if said else str(e)[:400],
-            time.time() - t0))
+            args.cell, e, time.time() - t0))
         sys.exit(1)
-    m = compiled.memory_analysis()
-    print("step_bytes %s: %s (%d s)" % (args.cell, json.dumps({
-        "temporaries": m.temp_size_in_bytes,
-        "arguments": m.argument_size_in_bytes,
-        "code": m.generated_code_size_in_bytes,
-        "layers": args.layers or sizes.get("num_hidden_layers")}),
-        time.time() - t0))
+    print("step_bytes %s: %s (%d s)" % (args.cell, json.dumps(found),
+                                        time.time() - t0))
 
 
 if __name__ == "__main__":
