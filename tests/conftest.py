@@ -4,8 +4,13 @@ exercised without TPU hardware. MXNET_TEST_ON_TPU=1 leaves the platform
 alone (the reference's gpu-suite pattern); no PR has shown the suite
 passing on the chip that way — chip_smoke.py is the on-chip check.
 """
+import faulthandler
 import os
+import signal
 import sys
+import threading
+import time
+import traceback
 
 _ON_TPU = bool(os.environ.get("MXNET_TEST_ON_TPU"))
 if not _ON_TPU:
@@ -46,7 +51,126 @@ def _seed_all(request):
     request.node.user_properties.append(("seed", seed))
 
 
+# ---------------------------------------------------------------------------
+# one deadline for every test: a wait that never returns costs the test
+# it is in, not the run
+#
+# Stage 1, where a signal reaches (the main thread in Python, or in a
+# lock, queue, socket, sleep or ``Popen.wait``): an interval timer over
+# setup, call and teardown whose handler fails the test with every
+# thread's stack and kills the children the test left behind; fixtures
+# unwind and the worker goes on with its file. Stage 2, where none does
+# (the main thread in a native call: a collective short of a peer, a
+# compile): ``faulthandler`` writes the stacks to the worker's stderr and
+# ends the process; xdist reports the test as failed ("node down"),
+# starts another worker and gives it the rest of the file.
+# ---------------------------------------------------------------------------
+DEADLINE_S = 300.0        # a quiet run's slowest test is ~60 s; a test
+                          # marked ``slow`` (minutes by design) gets four
+_UNWIND_S = 10.0          # what teardown is left with after a deadline
+_LAST_RESORT_S = 20.0     # after the deadline, before the worker is ended
+_test = {"ends": 0.0, "began": 0.0, "stderr": None}
+
+
+def _kill_children_since(began):
+    """Kill and reap this process's descendants born after ``began``
+    (epoch seconds); returns what was killed, as text."""
+    import psutil
+    young = [c for c in psutil.Process().children(recursive=True)
+             if c.create_time() >= began - 1.0]
+    said = []
+    for c in young:
+        try:
+            said.append("%d %s" % (c.pid, " ".join(c.cmdline())[:120]))
+            c.kill()
+        except psutil.Error:
+            pass
+    psutil.wait_procs(young, timeout=5)
+    return said
+
+
+def _on_deadline(signum, frame):
+    main = threading.main_thread().ident
+    names = {t.ident: t.name for t in threading.enumerate()}
+    others = "".join(
+        "\n--- thread %s ---\n%s" % (
+            names.get(ident, ident),
+            "".join(traceback.format_stack(f)[-12:]))
+        for ident, f in sys._current_frames().items() if ident != main)
+    killed = _kill_children_since(_test["began"])
+    pytest.fail("the test's deadline passed (DEADLINE_S = %g s in "
+                "tests/conftest.py); children killed: %s; the other "
+                "threads:%s" % (DEADLINE_S, killed or "none", others))
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """``--dist loadfile`` as xdist 3.8 has it, with a dead worker's
+    leavings mended. xdist puts back all the worker was ever given: the
+    files it had finished and the test it died in too. A replacement
+    handed a finished file, or a file's one last test, reports nothing
+    (a worker starts a test only once it knows the next, or that there is
+    none) and is never asked again, so the run never ends; one handed the
+    fatal test dies of it."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class Mended(LoadFileScheduling):
+        def remove_node(self, node):
+            crashitem = super().remove_node(node)
+            for scope, unit in list(self.workqueue.items()):
+                if crashitem in unit:
+                    unit[crashitem] = True
+                if all(unit.values()):
+                    del self.workqueue[scope]
+            return crashitem
+
+        def _reschedule(self, node):
+            super()._reschedule(node)
+            while (not node.shutting_down
+                   and self._pending_of(self.assigned_work[node]) < 2):
+                if self.workqueue:
+                    self._assign_work_unit(node)
+                else:
+                    node.shutdown()
+
+    return Mended(config, log)
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item, nextitem):
+    allowed = DEADLINE_S * (4 if item.get_closest_marker("slow") else 1)
+    _test["began"] = time.time()
+    _test["ends"] = time.monotonic() + allowed
+    faulthandler.dump_traceback_later(allowed + _LAST_RESORT_S,
+                                      exit=True, file=_test["stderr"])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(wrapper=True, tryfirst=True)
+def pytest_runtest_setup(item):
+    """Each phase runs with what is left of the test's deadline on the
+    interval timer (teardown always gets ``_UNWIND_S``)."""
+    left = max(_test["ends"] - time.monotonic(), _UNWIND_S)
+    signal.setitimer(signal.ITIMER_REAL, left)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+
+
+pytest_runtest_call = pytest_runtest_teardown = pytest_runtest_setup
+
+
 def pytest_configure(config):
+    # capture is suspended here, so fd 2 is the run's own stderr
+    _test["stderr"] = os.fdopen(os.dup(2), "w")
+    if threading.current_thread() is threading.main_thread():
+        signal.signal(signal.SIGALRM, _on_deadline)
     config.addinivalue_line("markers", "seed(n): pin the RNG seed")
     config.addinivalue_line("markers", "slow: long-running test")
     config.addinivalue_line(

@@ -1,14 +1,19 @@
 """Chipless compiles, the BERT path: every Pallas kernel of it, at
 BERT-base widths, through the TPU compiler for a DESCRIBED v5e chip (no
 chip is attached here; nothing runs), alone, a shard on a split batch,
-and in the whole ``dp4`` step. Interpret mode — what every other kernel
-test uses — cannot see what Mosaic refuses: block shapes off the (8, 128)
-tiling, unsupported shape casts, primitives with no TPU lowering.
+and in the whole ``dp4`` step (the attention kernel alone, a length a
+case: ``test_chip_compile_flash.py``). Interpret mode — what every other
+kernel test uses — cannot see what Mosaic refuses: block shapes off the
+(8, 128) tiling, unsupported shape casts, primitives with no TPU lowering.
 
-The three ``test_chip_compile_*.py`` files are the only ones that load
-the TPU library: the topology is described inside a module-scoped fixture
+The ``test_chip_compile_*.py`` files are the only ones that load the TPU
+library: the topology is described inside a module-scoped fixture
 (``one_chip`` in conftest.py, never at import), and every compile happens
 in the test's own process, once a program (``compiled`` in conftest.py).
+They are cut by what they compile into files of a dozen tests or fewer:
+xdist gives a file to one worker and hands files out by their count of
+tests, the largest first, so a long file of few tests starts late and the
+run waits for it alone.
 A compile that passes is not a chip run; ``chip_smoke.py`` is.
 """
 import re
@@ -38,32 +43,6 @@ def test_layer_norm(one_chip, compiled_mode):
     grad = jax.grad(lambda x, g, b: sum32(pallas_layer_norm(x, g, b)),
                     argnums=(0, 1, 2))
     assert _custom_calls(one_chip, grad, *shapes) >= 1
-
-
-# (length, batch) of 32,768 tokens a step: the s128 cell's call, the
-# s512 cell's, and lengths no cell runs, up to the cap (ISSUE 39: a plan
-# past 336 positions, under a VMEM limit the call states itself)
-@pytest.mark.parametrize("p", [0.0, 0.1])
-@pytest.mark.parametrize("length, batch", [(L, N), (384, 85), (512, 64),
-                                           (768, 42), (1024, 32)],
-                         ids=["L128", "L384", "L512", "L768", "L1024"])
-def test_flash_attention(one_chip, compiled_mode, length, batch, p):
-    from mxnet_tpu.ops.pallas_attention import flash_selfatt, selfatt_plan
-    plan = selfatt_plan(length, H, batch, p, dtype=BF, head_dim=D)
-    assert plan is not None
-
-    def fwd(qkv, seeds):
-        return flash_selfatt(qkv, seeds, heads=H, dropout=p,
-                             block_heads=plan["bbh"])
-
-    # value and gradient in one program: the forward kernel once (the
-    # backward rule does not run it again), then the backward's
-    calls = mosaic_calls(jax.jit(jax.value_and_grad(
-        lambda qkv, seeds: sum32(fwd(qkv, seeds)))).lower(*described(
-            one_chip, (length, batch, 3 * H * D),
-            ((plan["n_blocks"],), jnp.int32))).compile().as_text())
-    assert sum("pallas_selfatt_packed_fwd" in c for c in calls) == 1
-    assert sum("pallas_selfatt_packed_bwd" in c for c in calls) >= 1
 
 
 def test_the_op_at_512_positions_compiles_to_its_two_kernels(one_chip,

@@ -3,7 +3,8 @@ published widths for a described v5e chip (see
 tests/test_chip_compile_bert.py for what such a compile can and cannot
 show): the composition, the grouped kernels of ops/pallas_grouped_mlp.py
 and the slot sum's window kernel of ops/pallas_moe_rows.py, with
-GLM-4.7-Flash's and Laguna-XS.2's other mixers.
+GLM-4.7-Flash's dense MLP (the cells' attention mixers:
+``test_chip_compile_mixers.py``).
 """
 import re
 
@@ -271,51 +272,10 @@ def test_expert_mixer_under_a_mesh_keeps_the_composition(one_chip,
 
 
 # ---------------------------------------------------------------------------
-# GLM-4.7-Flash's mixers at the published widths (hidden 2048, 20 heads
-# of 192 + 64 / 256 lanes through bottlenecks of 768 and 512, a dense
-# MLP of 10,240, 8 of 64 experts of width 1,536 beside a shared one)
-# and the cell's 8,192 tokens
+# GLM-4.7-Flash's experts and dense MLP at the published widths (hidden
+# 2048, a dense MLP of 10,240, 8 of 64 experts of width 1,536 beside a
+# shared one) and the cell's 8,192 tokens
 # ---------------------------------------------------------------------------
-def _mla_mixer_gradient(one_chip, length):
-    from mxnet_tpu.ops import get_op
-    op = get_op("_contrib_mla_mixer").impl
-    hidden, h, qr, kvr, nope, rope, vd = 2048, 20, 768, 512, 192, 64, 256
-    args = described(
-        one_chip, (1, length, hidden), (hidden,), (qr, hidden), (qr,),
-        (h * (nope + rope), qr), (kvr + rope, hidden), (kvr,),
-        (h * (nope + vd), kvr), (hidden, h * vd))
-    return jax.jit(jax.value_and_grad(
-        lambda *a: sum32(op(*a, num_heads=h, qk_nope_head_dim=nope,
-                             qk_rope_head_dim=rope, v_head_dim=vd,
-                             rope_theta=1e6, eps=1e-5)),
-        argnums=tuple(range(9)))).lower(*args).compile()
-
-
-def test_mla_mixer_at_8192_takes_the_causal_kernel_at_256_lanes(
-        one_chip, compiled_mode):
-    """The latent-attention mixer at the cell's shape: Mosaic accepts
-    the causal kernels at 256-wide heads and a group of one, as they
-    are; the forward kernel is in the program once (the mixer's
-    recomputation keeps the context and the log-sum-exp, and expands
-    q, k, v again), the backward once; both under ``mx.attn.causal``
-    inside ``mx.attn.mla``; no score block exists; and the whole
-    mixer's temporaries stay under a gigabyte."""
-    from mxbench import scopes
-    compiled = _mla_mixer_gradient(one_chip, 8192)
-    text = compiled.as_text()
-    calls = mosaic_calls(text)
-    placed = scopes.scope_map(text, ["mx.attn.causal", "mx.attn.mla"])
-    kernels = {name: s for name, s in placed.items()
-               if name.startswith("pallas_causal_gqa_")}
-    assert len(calls) == len(kernels) == 2
-    assert set(kernels.values()) == {"mx.attn.causal"}
-    assert sorted(n.split(".")[0] for n in kernels) == [
-        "pallas_causal_gqa_bwd", "pallas_causal_gqa_fwd"]
-    assert "mx.attn.mla" in placed.values()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
-    assert "f32[1,20,1,512," not in text and "f32[1,20,512," not in text
-
-
 def test_glm_expert_and_dense_mixers_compile_at_published_widths(
         one_chip, compiled_mode):
     """The expert op's fourth combination (sigmoid scores with a
@@ -359,56 +319,9 @@ def test_glm_expert_and_dense_mixers_compile_at_published_widths(
 
 
 # ---------------------------------------------------------------------------
-# Laguna-XS.2's mixers at the published widths (hidden 2048, 48 / 64
-# query heads over 8 key-value heads of 128, a gate a head, 32 of 256
+# Laguna-XS.2's experts at the published widths (hidden 2048, 32 of 256
 # experts of width 512 beside a shared one) and the cell's 8,192 tokens
 # ---------------------------------------------------------------------------
-def _gated_mixer_gradient(one_chip, heads, **attrs):
-    from mxnet_tpu.ops import get_op
-    op = get_op("_contrib_rotary_gqa_mixer").impl
-    length, hidden, kv, d = 8192, 2048, 8, 128
-    args = described(one_chip, (1, length, hidden), (hidden,),
-                     (heads * d, hidden), (kv * d, hidden), (kv * d, hidden),
-                     (hidden, heads * d), (heads, hidden))
-    return jax.jit(jax.value_and_grad(
-        lambda *a: sum32(op(*a[:6], gate_weight=a[6], num_heads=heads,
-                             num_kv_heads=kv, head_dim=d, eps=1e-6, **attrs)),
-        argnums=tuple(range(7)))).lower(*args).compile()
-
-
-@pytest.mark.parametrize("kind, heads, attrs, scope, other", [
-    ("sliding", 64, dict(window=512, rope_theta=1e4),
-     "mx.attn.window", "mx.attn.causal"),
-    ("full", 48, dict(rotary_dim=64, rope_theta=5e5,
-                      rope_yarn=(64, 4096, 64, 1),
-                      attention_factor=1.4158883083359672),
-     "mx.attn.causal", "mx.attn.window")])
-def test_gated_rotary_mixer_at_8192_takes_the_kernel_at_groups_of_6_and_8(
-        one_chip, compiled_mode, kind, heads, attrs, scope, other):
-    """Both kinds of Laguna-XS.2's attention layer at the cell's
-    length: Mosaic accepts the causal kernels at a group of 6 query
-    heads a key-value head (no power of two) and the windowed ones at a
-    window of one tile (the diagonal tile and one ``cond``-ed edge
-    tile); forward once, backward once, under the kind's scope; the
-    gate's instructions under ``mx.attn.gate``; no q/k norm weight is
-    an input; the mixer's temporaries stay under 1.25 GB (17 MB of them
-    the v projection that a step keeps)."""
-    from mxbench import scopes
-    compiled = _gated_mixer_gradient(one_chip, heads, **attrs)
-    text = compiled.as_text()
-    calls = mosaic_calls(text)
-    placed = scopes.scope_map(text, ["mx.attn.gate", scope, other,
-                                     "mx.attn.rotary"])
-    kernels = {name: s for name, s in placed.items()
-               if name.startswith("pallas_causal_gqa_")}
-    assert len(calls) == len(kernels) == 2
-    assert set(kernels.values()) == {scope}
-    assert other not in placed.values()
-    assert {"mx.attn.rotary", "mx.attn.gate"} <= set(placed.values())
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.25e9
-    assert "f32[1,8,%d,512," % (heads // 8) not in text     # no score block
-
-
 def test_laguna_expert_mixer_fills_a_quarter_of_its_blocks(
         one_chip, compiled_mode, compiled):
     """The expert op's fifth combination (softmax scores renormalised

@@ -68,7 +68,7 @@ def test_dist_trainer_matches_single_process():
 def test_num_servers_rejected():
     out = subprocess.run(
         [sys.executable, LAUNCH, "-n", "1", "-s", "2", "echo", "hi"],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=60)
     assert out.returncode != 0
     assert "parameter-server" in out.stderr
 

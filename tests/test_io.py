@@ -252,7 +252,7 @@ def test_im2rec_tool_end_to_end(tmp_path):
     tool = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tools", "im2rec.py")
     out = subprocess.run([sys.executable, tool, prefix, str(root)],
-                         capture_output=True, text=True)
+                         capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert os.path.exists(prefix + ".rec")
     it = ImageRecordIter(path_imgrec=prefix + ".rec",
@@ -266,7 +266,7 @@ def test_im2rec_tool_end_to_end(tmp_path):
     prefix2 = str(tmp_path / "raw")
     out2 = subprocess.run([sys.executable, tool, prefix2, str(root),
                            "--pass-through-raw"],
-                          capture_output=True, text=True)
+                          capture_output=True, text=True, timeout=120)
     assert out2.returncode == 0, out2.stderr
     it2 = ImageRecordIter(path_imgrec=prefix2 + ".rec",
                           path_imgidx=prefix2 + ".idx",

@@ -683,7 +683,21 @@ class TestEstimatorElastic:
         assert elastic.announce(6)
         assert elastic.poll_survivors(ctxs) == ctxs[:6]
 
-    def test_sigterm_handler_lock_free(self, monkeypatch):
+    @pytest.fixture
+    def sigterm_as_found(self):
+        """The handler is installed for the process's life: left in, the
+        worker and every child it forks swallow SIGTERM from here on
+        (``DataLoader`` retires its workers with ``terminate()``)."""
+        import signal
+        was = signal.getsignal(signal.SIGTERM)
+        installed = elastic._SIGTERM_INSTALLED[0]
+        yield
+        signal.signal(signal.SIGTERM, was)
+        elastic._SIGTERM_INSTALLED[0] = installed
+        elastic._SIGTERM_FLAG[0] = False
+        assert signal.getsignal(signal.SIGTERM) is was
+
+    def test_sigterm_handler_lock_free(self, monkeypatch, sigterm_as_found):
         """SIGTERM may arrive while the main thread HOLDS the elastic
         lock (poll_survivors runs every elastic poll); the handler
         must not acquire it — the old locked handler deadlocked the

@@ -583,7 +583,7 @@ class TestServeInterleaveEndToEnd:
         # engine op; batch 2 is then pushed while batch 1 is still in
         # flight — the overlap is deterministic, not a thread race
         assert sess._exec_lock is not None
-        sess._exec_lock.acquire()
+        assert sess._exec_lock.acquire(timeout=30)
         try:
             futs.append(sched.submit(xs, tenant="a"))
             deadline = time.time() + 10

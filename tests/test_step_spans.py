@@ -66,7 +66,8 @@ def test_parent_is_per_thread():
     with telemetry.span("main-thread"):
         t = threading.Thread(target=worker)
         t.start()
-        t.join()
+        t.join(timeout=30)
+        assert not t.is_alive()
     telemetry.mark_step()
     (rec,) = telemetry.step_log()
     assert seen and {e[0]: e[3] for e in rec["events"]} == {

@@ -104,7 +104,8 @@ def test_counter_thread_safety():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
     assert c.get() == 8000
     assert h.summary()["count"] == 8000
 
@@ -577,7 +578,8 @@ def test_profiler_counter_threaded_increment():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
     profiler.set_state("stop")
     profiler.dumps(reset=True)
     assert c.value == 8 * (2000 - 500), \

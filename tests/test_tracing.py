@@ -193,7 +193,8 @@ def test_sustained_load_keeps_ring_bounded(monkeypatch):
         time.sleep(0.01)
     stop.set()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     st = tracing.stats()
     assert st["buffered"] <= 32 and st["dropped"] > 0
 
