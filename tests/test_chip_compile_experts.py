@@ -134,14 +134,18 @@ EXPERT_CELLS = {
 @pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
 def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
         one_chip, compiled_mode, compiled, cell):
-    """Mosaic accepts the three kernels, the skipped tail's branches
-    and clamped index maps with them, at both cells' widths; a step's
-    seven calls (two
-    forward, the first again in the mixer's recomputation, four
-    backward) are placed under the scope the benchmark reads; no
-    gathered copy of a weight and no float32 gradient a block exists;
-    and the Mellum 2 mixer's gradient needs 1.9 GB of temporaries where
-    the chunked composition needs 3.63."""
+    """Mosaic accepts the five kernels, the skipped tail's branches
+    and clamped index maps and the activation and its ``jax.vjp`` traced
+    in the bodies with them, at both cells' widths; a step's seven calls
+    (``up`` and ``nt`` forward, ``up`` again, keeping ``pre``, in the
+    mixer's recomputation, ``dh``, ``nn`` and two ``dw`` backward) are
+    placed under the scope the benchmark reads; no gathered copy of a
+    weight and no float32 gradient a block exists; between the products
+    no float32 array of the buffer's length but ``pre``, which only the
+    kernels write and read (``dh`` stays in VMEM; of the slot weights'
+    gradient a float32 column a tile of the width leaves); and the
+    Mellum 2 mixer's gradient needs 1.7 GB of temporaries where the
+    chunked composition needs 3.63."""
     from mxbench import scopes
     sizes, blocks, _ = EXPERT_CELLS[cell]
     length, hidden, width, held, routed, mul = sizes
@@ -150,14 +154,15 @@ def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
     calls = mosaic_calls(text)
     placed = scopes.scope_map(text, ["mx.moe.experts", "mx.moe"])
     # (a call traced inside the backward's ``jax.vjp`` is named
-    # ``jvp_pallas_grouped_mlp_nt_``)
+    # ``jvp_pallas_grouped_mlp_up_``)
     kernels = {name: s for name, s in placed.items()
                if "pallas_grouped_mlp_" in name}
     # (beside them the slot sum's two calls: the next test)
     assert len(kernels) == 7 and len(calls) == 9
     assert set(kernels.values()) == {"mx.moe.experts"}
-    assert sorted(re.search("pallas_grouped_mlp_(dw|nn|nt)", n).group(1)
-                  for n in kernels) == ["dw"] * 2 + ["nn"] * 2 + ["nt"] * 3
+    assert sorted(re.search("pallas_grouped_mlp_(dh|dw|nn|nt|up)", n).group(1)
+                  for n in kernels) \
+        == ["dh"] + ["dw"] * 2 + ["nn", "nt"] + ["up"] * 2
     # the two scalar prefetches lead each call's operands: the expert of
     # each block, and how many blocks hold a routed row (the rest are
     # skipped)
@@ -166,13 +171,26 @@ def test_expert_mixer_takes_the_grouped_kernels_under_its_scope(
     for out, inner in ((mul * width, hidden), (hidden, width)):
         assert "bf16[%d,%d,%d]" % (blocks, out, inner) not in text
         assert "f32[%d,%d,%d]" % (blocks, out, inner) not in text
+    # ``dh`` (rows, width) in float32 is nowhere, ``pre`` in neither
+    # layout outside the kernels: the line that defines or reads it is a
+    # grouped call's, or takes an element of the tuple one returned
+    rows = blocks * 512
+    for shape in ((rows, width), (rows, mul * width), (blocks, 512, width),
+                  (blocks, 512, mul * width)):
+        assert "f32[%s]" % ",".join(map(str, shape)) not in text
+    kept = [line for line in text.splitlines()
+            if "f32[%d,%d,%d]" % (mul, rows, width) in line]
+    assert kept and all(
+        "pallas_grouped_mlp_" in line.split(" = ")[0]
+        or " get-tuple-element(%jvp_pallas_grouped_mlp_up_" in line
+        for line in kept), kept
     memory = program.memory_analysis()
     # nothing but its inputs crosses the overflow ``cond`` (a copy of
     # the weights and a zero array of their size did: 168 MB of program
     # at the Keye-VL widths for 17)
     assert memory.generated_code_size_in_bytes < 40e6
     if cell == "mellum2":
-        assert memory.temp_size_in_bytes < 2.5e9
+        assert memory.temp_size_in_bytes < 1.75e9
 
 
 def test_expert_mixer_off_the_lane_tiles_keeps_the_composition(one_chip,
@@ -206,7 +224,8 @@ def test_expert_mixer_off_the_lane_tiles_keeps_the_composition(one_chip,
 # buffer rows, (tokens, top_k), hidden; and the temporaries of the same
 # compile with the kernel stood down (PR 43's readings: 1,846,272,000 /
 # 593,056,768 / 724,051,456 bytes; with it 1,832,087,040 / 491,890,176 /
-# 695,194,112)
+# 695,194,112; since PR 57, the activation inside the grouped kernels,
+# 1.699 / 0.492 / 0.670 GB)
 ROWS_CELLS = {
     "mellum2": (EXPERT_CELLS["mellum2"][0], 0, EXPERT_CELLS["mellum2"][2],
                 73728, 1.84e9),
@@ -280,7 +299,9 @@ def test_glm_expert_and_dense_mixers_compile_at_published_widths(
         one_chip, compiled_mode):
     """The expert op's fourth combination (sigmoid scores with a
     selection bias, SwiGLU experts, a SwiGLU shared expert, x 1.8) at 8
-    of 64 experts of width 1,536: the grouped kernels' seven calls
+    of 64 experts of width 1,536 (the LFM2 cell's too: two tiles of 768
+    a piece, the widest working set the kernels have beside Mellum
+    2's): the grouped kernels' seven calls
     under ``mx.moe.experts`` (24 blocks), the shared expert's products
     outside it under ``mx.moe``; and the dense gated MLP of width
     10,240 under ``mx.mlp``."""

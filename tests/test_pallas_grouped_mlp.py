@@ -8,7 +8,8 @@ float32 loop over the held experts (the benchmark's own references are
 of the weight-gradient kernel (an expert with no block, the empty blocks
 past the last run, one expert drawing nearly every token); the empty
 tail the kernels skip (``used``: any number of computed blocks gives the
-all-blocks kernels' numbers bit for bit); the overflow path; the ladder
+all-blocks kernels' numbers bit for bit); the two kernels that hold the
+activation and its derivative, each alone; the overflow path; the ladder
 of what the kernels do not serve; the counter. What Mosaic makes of the
 real widths is ``tests/test_chip_compile_*.py``'s to say."""
 import jax
@@ -32,6 +33,22 @@ def interpreted(monkeypatch):
 
 def _pallas_calls(fn, *args):
     return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+def _pallas_outs(jaxpr):
+    """What each ``pallas_call`` of a jaxpr writes, in program order,
+    those of the jaxprs its equations hold (a ``custom_vjp``'s, a
+    ``jit``'s) among them."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append([(v.aval.dtype, v.aval.shape) for v in eqn.outvars])
+            continue
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                found += _pallas_outs(inner)
+    return found
 
 
 # ---------------------------------------------------------------------------
@@ -115,7 +132,7 @@ def test_weight_gradient_kernel_masks_what_it_never_visits(interpreted):
     zeros."""
     g, x = rand(5, (8 * BLOCK, HIDDEN), (8 * BLOCK, WIDTH), dtype=BF)
     eob = jnp.array(EXPERT_OF_BLOCK, jnp.int32)
-    dw = G._dw(g, x, eob, jnp.int32(len(EXPERT_OF_BLOCK)), 4)
+    dw = G._dw(g[None], x, eob, jnp.int32(len(EXPERT_OF_BLOCK)), 4)
     want = jnp.stack([
         sum((g[b * BLOCK:(b + 1) * BLOCK].astype(F32).T
              @ x[b * BLOCK:(b + 1) * BLOCK].astype(F32)
@@ -139,33 +156,149 @@ TAILS = {
     # the accumulator holds expert 2's sum where expert 3's run begins
     "a_tail_and_a_last_expert_routed_nothing": ([0, 0, 0, 1, 2, 2, 3, 3], 6),
     "no_tail": (EXPERT_OF_BLOCK, len(EXPERT_OF_BLOCK)),
+    # the tail begins inside the first expert's run: the blocks behind
+    # it are that expert's and two more experts', all skipped
+    "a_tail_from_inside_the_first_run": (EXPERT_OF_BLOCK, 3),
+    # one block short of the whole buffer
+    "all_but_the_last_block": ([0, 1, 1, 2, 2, 2, 3, 3], 7),
 }
 
 
+def _stacks(up, act):
+    return G._stacks(up, ACTS[act][1])
+
+
+def _plain_pre(x, eob_list, up):
+    """The first product in float32, block by block: (rows, f1)."""
+    return jnp.concatenate([
+        x[b * BLOCK:(b + 1) * BLOCK].astype(F32) @ up[e].astype(F32).T
+        for b, e in enumerate(eob_list)])
+
+
+def _piece_major(a, act):
+    """(rows, pieces x f) as the kernels keep it: (pieces, rows, f)."""
+    return jnp.stack(jnp.split(a, ACTS[act][1], axis=-1))
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+def test_up_kernel_alone_activates_in_vmem(interpreted, act):
+    """``pallas_grouped_mlp_up`` against the float32 loop: ``h`` in
+    bf16, and where it is asked to keep it the float32 ``pre``,
+    piece-major; the call that keeps nothing has one output, the same
+    ``h`` bit for bit, and no float32 array of the buffer's length."""
+    x, eob, w, up, down = _buffer(31, act)
+    fn, pieces = ACTS[act]
+    used = jnp.int32(USED)
+    h, pre = jax.jit(lambda x, up: G._up(x, up, eob, used, fn, pieces, True))(
+        x, up)
+    (alone,) = jax.jit(lambda x, up: G._up(x, up, eob, used, fn, pieces,
+                                           False))(x, up)
+    want = _plain_pre(x, EXPERT_OF_BLOCK, up)
+    assert (h.dtype, pre.dtype) == (BF, F32)
+    assert pre.shape == (pieces, x.shape[0], WIDTH)
+    near(pre, _piece_major(want, act), 1e-5)
+    near(h, fn(want), 1e-2)
+    np.testing.assert_array_equal(np.asarray(h.astype(F32)),
+                                  np.asarray(alone.astype(F32)))
+    # the blocks past ``used``: zeros, written and not computed
+    assert float(jnp.max(jnp.abs(pre[:, USED * BLOCK:]))) == 0.0
+    assert float(jnp.max(jnp.abs(h[USED * BLOCK:].astype(F32)))) == 0.0
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+def test_dh_kernel_alone_differentiates_in_vmem(interpreted, act):
+    """``pallas_grouped_mlp_dh`` against XLA's ``jax.vjp`` of the
+    activation on the float32 ``dh``: ``dpre`` in bf16, piece-major, the
+    slot weights' gradient from the bf16-rounded ``h``, and that ``h``,
+    the forward's bit for bit."""
+    x, eob, w, up, down = _buffer(33, act)
+    fn = ACTS[act][0]
+    (g,) = rand(35, x.shape, dtype=BF)
+    forward, kept = jax.jit(lambda x, up: G._up(
+        x, up, eob, jnp.int32(USED), fn, ACTS[act][1], True))(x, up)
+    pre = jnp.concatenate(list(kept), axis=-1)      # as ``act`` reads it
+    dpre, d_weight, h = jax.jit(lambda g, down, pre, w: G._dh(
+        g, down, pre, eob, jnp.int32(USED), w, fn))(g, down, kept, w)
+
+    @jax.jit
+    def plain(g, down, pre, w):
+        dh = jnp.concatenate([
+            g[b * BLOCK:(b + 1) * BLOCK].astype(F32) @ down[e].astype(F32)
+            for b, e in enumerate(EXPERT_OF_BLOCK)])
+        h, pull = jax.vjp(fn, pre)
+        return (pull(dh * w[:, None])[0],
+                jnp.sum(h.astype(BF).astype(F32) * dh, axis=-1))
+
+    want_dpre, want_weight = plain(g, down, pre, w)
+    filled = (jnp.arange(x.shape[0]) < USED * BLOCK)
+    assert (dpre.dtype, d_weight.dtype, h.dtype) == (BF, F32, BF)
+    np.testing.assert_array_equal(np.asarray(h.astype(F32)),
+                                  np.asarray(forward.astype(F32)))
+    near(dpre, _piece_major(jnp.where(filled[:, None], want_dpre, 0), act),
+         1e-2)
+    near(d_weight, jnp.where(filled, want_weight, 0), 1e-3)
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
+def test_the_primal_call_keeps_no_pre(interpreted, act):
+    """The plain primal (what the mixer's forward pass runs: nothing
+    there reads ``pre``) is two calls, neither with a float32 output;
+    the differentiated forward is the same two, the first writing
+    ``pre`` beside ``h``, and keeps ``pre`` alone of the two."""
+    x, eob, w, up, down = _buffer(37, act)
+    fn, pieces = ACTS[act]
+    used = jnp.int32(USED)
+
+    def primal(x, w, up, down):
+        return G.grouped_mlp(x, eob, used, w, up, down, fn)
+
+    def kept(x, w, up, down):
+        return G._vjp_fwd(x, eob, used, w, up, down, fn)
+
+    def outs(fn):
+        return _pallas_outs(jax.make_jaxpr(fn)(x, w, up, down).jaxpr)
+
+    rows = x.shape[0]
+    h, y = (BF, (rows, WIDTH)), (BF, (rows, HIDDEN))
+    assert _pallas_calls(primal, x, w, up, down) == 2
+    assert outs(primal) == [[h], [y]]
+    assert outs(kept) == [[h, (F32, (pieces, rows, WIDTH))], [y]]
+    # (the rows of the buffer have ``h``'s shape at these sizes: one)
+    kept_shapes = [(r.dtype, r.shape) for r in jax.eval_shape(
+        kept, x, w, up, down)[1]]
+    assert kept_shapes.count((F32, (pieces, rows, WIDTH))) == 1
+    assert kept_shapes.count(h) == 1
+
+
+@pytest.mark.parametrize("act", sorted(ACTS))
 @pytest.mark.parametrize("tail", sorted(TAILS))
-def test_skipping_the_empty_tail_changes_no_number(interpreted, tail):
-    """Each kernel alone (``nt`` with and without the row scale, ``nn``,
-    ``dw`` likewise) and ``grouped_mlp``'s value and four gradients,
-    computing ``used`` blocks of a buffer whose other blocks are zero
-    rows of weight 0: the all-blocks kernels' numbers (``used`` = every
-    block) exactly, and the float32 loop's within bf16."""
+def test_skipping_the_empty_tail_changes_no_number(interpreted, tail, act):
+    """Each kernel alone (``up`` keeping ``pre`` and not, ``nt`` with the
+    row scale, ``dh``, ``nn``, ``dw`` with and without the scale) and
+    ``grouped_mlp``'s value and four gradients, computing ``used``
+    blocks of a buffer whose other blocks are zero rows of weight 0: the
+    all-blocks kernels' numbers (``used`` = every block) exactly, and
+    the float32 loop's within bf16."""
     experts, used = TAILS[tail]
     blocks = len(experts)
-    x, eob, w, up, down = _buffer(21, "swiglu", experts, used)
+    x, eob, w, up, down = _buffer(21, act, experts, used)
     (g,) = rand(23, x.shape, dtype=BF)
     g = jnp.where((w > 0)[:, None], g, 0).astype(BF)    # no row, no cotangent
-    fn = ACTS["swiglu"][0]
+    fn, pieces = ACTS[act]
 
     @jax.jit
     def kernels(used):
         out, pull = jax.vjp(lambda x, w, up, down: G.grouped_mlp(
             x, eob, used, w, up, down, fn), x, w, up, down)
+        h, pre = G._up(x, up, eob, used, fn, pieces, True)
+        dpre, d_weight, again = G._dh(g, down, pre, eob, used, w, fn)
         return dict(
-            nt=G._rows(x, up, eob, used, True, F32),
-            nt_scaled=G._rows(x, down, eob, used, True, BF, w),
-            nn=G._rows(g, down, eob, used, False, F32),
-            dw=G._dw(g, x, eob, used, 4),
-            dw_scaled=G._dw(g, x, eob, used, 4, w),
+            up=(h, pre), up_alone=G._up(x, up, eob, used, fn, pieces, False),
+            nt_scaled=G._rows(h[None], down[:, None], eob, used, True, w),
+            dh=(dpre, d_weight, again),
+            nn=G._rows(dpre, _stacks(up, act), eob, used, False),
+            dw=G._dw(dpre, x, eob, used, 4),
+            dw_scaled=G._dw(g[None], h, eob, used, 4, w),
             value=out, grads=pull(g))
 
     skipping, whole = kernels(jnp.int32(used)), kernels(jnp.int32(blocks))
@@ -176,7 +309,7 @@ def test_skipping_the_empty_tail_changes_no_number(interpreted, tail):
                                       np.asarray(want.astype(F32)))
     if used:
         near([skipping["value"], *skipping["grads"]], value_and_grads(
-            lambda x, w, up, down: _by_hand(x, eob, w, up, down, "swiglu",
+            lambda x, w, up, down: _by_hand(x, eob, w, up, down, act,
                                             experts),
             x, w, up, down, cot=g), 2e-2)
     # an expert with no computed block: exact zeros from both weight
@@ -187,9 +320,12 @@ def test_skipping_the_empty_tail_changes_no_number(interpreted, tail):
             assert float(jnp.max(jnp.abs(dw[e].astype(F32)))) == 0.0
     for e in set(experts[:used]):
         assert float(jnp.max(jnp.abs(skipping["dw"][e].astype(F32)))) > 0.0
-    # and a skipped block's rows
-    for rows in (skipping["nt"], skipping["nt_scaled"], skipping["nn"],
-                 skipping["value"], skipping["grads"][0]):
+    # and a skipped block's rows, the kept ``pre`` and ``dpre`` among them
+    for rows in (skipping["up"][0], skipping["nt_scaled"], skipping["nn"],
+                 skipping["dh"][1][:, None], skipping["dh"][2],
+                 skipping["value"],
+                 skipping["grads"][0], *skipping["up"][1],
+                 *skipping["dh"][0]):
         assert float(jnp.max(jnp.abs(rows[used * BLOCK:].astype(F32)),
                              initial=0.0)) == 0.0
 
