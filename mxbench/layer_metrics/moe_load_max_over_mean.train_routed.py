@@ -1,0 +1,8 @@
+"""``moe_load_max_over_mean.train`` for the cells judged on ``train_routed_samples_per_s``: a
+per-layer metric moves one end-to-end metric, so the quantity has one
+name for each."""
+from mxbench import manifest
+
+_base = manifest.layer_metric("moe_load_max_over_mean.train")
+UNIT = _base.UNIT
+read = _base.read

@@ -40,7 +40,9 @@ before anything touches the chip.
 
 Reads from its traffic file: ``seq``, ``batch_per_chip``, ``optimizer``,
 ``feed`` (``pool_sequences``), ``inflight_steps``, ``warmup_steps``,
-``trace_seconds``, ``toy``; the ``loop`` is ``sharded_step``.
+``trace_seconds``, ``toy``; the ``loop`` is ``sharded_step``. Optional:
+``weights_seed`` (``seed_weights``): the initialisation is then the
+mix's, and ``--seed`` draws the data alone.
 """
 from __future__ import annotations
 
@@ -87,6 +89,16 @@ class TokenRowsFeed:
         pass
 
 
+def seed_weights(ctx):
+    """Seed the model's initialisation: from the mix's ``weights_seed``
+    where it names one (``--seed`` then draws the token pool and its
+    order and nothing of the model: a step whose time follows the
+    router's weights takes the same time under every ``--seed``), else
+    from ``--seed``. The one place either is read for the weights."""
+    import mxnet_tpu as mx
+    mx.random.seed(int(ctx.traffic.get("weights_seed", ctx.seed)) % (2 ** 31))
+
+
 def agree(got, want, chk):
     """The comparison that decides ``correct``: every loss finite, the
     first loss (the forward) within ``loss_rtol`` of the reference's,
@@ -105,9 +117,8 @@ def reference_first(ctx, batch, seq, lower=False):
     """Before the system's instance exists: the seeded weights, the
     check's batch, and the plain reference's losses over the check's
     steps (``lower``: the control's as well)."""
-    import mxnet_tpu as mx
     steps = int(ctx.sizes["check"]["steps"])
-    mx.random.seed(ctx.seed % (2 ** 31))
+    seed_weights(ctx)
     net, loss, _ = ctx.cfgmod.sharded_parts(ctx.sizes, 0.0, seq)
     weights = ctx.cfgmod.named_weights(net, loss)
     del net, loss
@@ -130,12 +141,11 @@ def checked_loop(ctx, batch, seq):
     step 2 the backward and the optimizer. What is returned is what was
     checked: one object, one compiled step, the state after the
     check's steps."""
-    import mxnet_tpu as mx
     from mxnet_tpu import nd
     chk = ctx.sizes["check"]
     weights, host, (want,) = reference_first(ctx, batch, seq)
     ctx.say("check: reference steps done, its state freed")
-    mx.random.seed(ctx.seed % (2 ** 31))
+    seed_weights(ctx)
     loop = _stream.ShardedLoop(ctx, batch, float(ctx.traffic.get(
         "dropout", 0.0)), seq)
     same = set(weights) == set(loop.weights) and all(

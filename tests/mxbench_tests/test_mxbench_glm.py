@@ -185,11 +185,13 @@ def test_the_traffic_file_is_the_issues():
     assert cell["metrics"] == ["train_samples_per_s", "setup_s"]
     assert len(cell["why"]) <= 200 and len(cell["layer_metrics"]) == 22
     assert set(NEW_READERS) <= set(cell["layer_metrics"])
-    # the Mellum 2 cell's list without the window's two, the three new
-    # ones, and the five of the program's own table
+    # the Mellum 2 cell's list (there under the names that move
+    # ``train_routed_samples_per_s``) without the window's two, the three
+    # new ones, and the five of the program's own table
     mellum = manifest.workload("mellum2_12b_a2_5b_longctx_s16384")
     assert cell["layer_metrics"] == [
-        m for m in mellum["layer_metrics"] if not m.startswith("window_")] \
+        m.replace(".train_routed", ".train")
+        for m in mellum["layer_metrics"] if not m.startswith("window_")] \
         + list(NEW_READERS) + ["optimizer_ms.train", "param_cast_ms.train",
                                "lm_head_ms.train", "embed_ms.train",
                                "unscoped_ms.train"]
@@ -410,7 +412,8 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_READERS:
         m = by_name[name]
-        assert m["workloads"] == [CELL] and m["layer"] == "kernels"
+        # (a later cell that reads the scope may join the list)
+        assert CELL in m["workloads"] and m["layer"] == "kernels"
         assert (m["moves"], m["source"]) == ("train_samples_per_s",
                                              "device_trace")
         assert m["unit"] == manifest.layer_metric(name).UNIT
@@ -423,4 +426,3 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
             assert CELL not in m.get("workloads", []), name
     assert CELL in [m for m in bench["end_to_end"]
                     if m["name"] == "train_samples_per_s"][0]["workloads"]
-    assert len(bench["configs"]) == 6 and len(bench["workloads"]) == 9

@@ -19,6 +19,10 @@ from mxbench import manifest, run as mxrun, scopes
 
 CELL = "keye_vl2_30b_a3b_midtrain_s8192"
 CONFIG = "keye_vl2_30b_a3b"
+# the rate's name: the cell's step takes what its sequences route, so its
+# runs spread more widely than a 1% bound takes, and it is judged under a
+# name and a bound of its own (mxbench/README.md, PERF.md section 2)
+RATE = "train_routed_samples_per_s"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 SCOPE_READERS = {
     "index_scores_ms.train": "mx.attn.index",
@@ -403,9 +407,10 @@ def test_benchmark_json_lists_the_cell_and_its_metrics():
     for name in NEW_READERS:
         m = by_name[name]
         assert m["workloads"] == [CELL] and m["layer"] == "kernels"
-        assert m["moves"] == "train_samples_per_s"
+        assert m["moves"] == RATE
         assert m["unit"] == manifest.layer_metric(name).UNIT
     for name in manifest.workload(CELL)["layer_metrics"]:
         assert CELL in by_name[name].get("workloads", [CELL]), name
     assert CELL in [m for m in bench["end_to_end"]
-                    if m["name"] == "train_samples_per_s"][0]["workloads"]
+                    if m["name"] == RATE][0]["workloads"]
+    assert manifest.workload(CELL)["metrics"] == [RATE, "setup_s"]

@@ -120,20 +120,23 @@ def test_unknown_device_kind_is_an_error():
 
 def test_drop_in_files_are_found_with_no_edit(tmp_path):
     """A new cell, traffic mix and layer metric: three new files in a
-    copy of mxbench/, no file that was there edited."""
+    copy of mxbench/, no file that was there edited. The names are ones
+    no cell will take, so that the files dropped in are new ones."""
+    drop_cell, drop_mix = "zz_drop_in_cell_s384", "zz_drop_in_mix_s384"
     pkg = tmp_path / "mxbench"
     shutil.copytree(os.path.join(ROOT, "mxbench"), pkg,
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: p.read_bytes() for p in pkg.rglob("*") if p.is_file()}
+    assert not (pkg / "workloads" / (drop_cell + ".json")).exists()
+    assert not (pkg / "traffic" / (drop_mix + ".json")).exists()
     cell = json.loads((pkg / "workloads" /
                        "bert_base_pretrain_s128.json").read_text())
-    cell["traffic"] = "pretrain_mlm_s512"
+    cell["traffic"] = drop_mix
     cell["layer_metrics"].append("steps_traced.train")
-    (pkg / "workloads" / "bert_base_pretrain_s512.json").write_text(
-        json.dumps(cell))
+    (pkg / "workloads" / (drop_cell + ".json")).write_text(json.dumps(cell))
     mix = json.loads((pkg / "traffic" / "pretrain_mlm_s128.json").read_text())
-    mix.update(seq=512, batch_per_chip=64)
-    (pkg / "traffic" / "pretrain_mlm_s512.json").write_text(json.dumps(mix))
+    mix.update(seq=384, batch_per_chip=80)
+    (pkg / "traffic" / (drop_mix + ".json")).write_text(json.dumps(mix))
     (pkg / "layer_metrics" / "steps_traced.train.py").write_text(
         'UNIT = "steps"\n\n\ndef read(run):\n    return run.traced_steps\n')
     env = dict(os.environ, PYTHONPATH=str(tmp_path), JAX_PLATFORMS="cpu")
@@ -142,16 +145,16 @@ def test_drop_in_files_are_found_with_no_edit(tmp_path):
                          text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     found = json.loads(out.stdout)
-    assert "bert_base_pretrain_s512" in found["workloads"]
-    assert "pretrain_mlm_s512" in found["traffic"]
+    assert drop_cell in found["workloads"]
+    assert drop_mix in found["traffic"]
     assert "steps_traced.train" in found["layer_metrics"]
     assert all(p.read_bytes() == data for p, data in before.items())
     # and the new cell resolves through the copy's own manifest
     probe = ("from mxbench import manifest as m; c = m.workload("
-             "'bert_base_pretrain_s512'); t, g = m.traffic(c['traffic']); "
+             "'%s'); t, g = m.traffic(c['traffic']); "
              "print(t['seq'], m.layer_metric(c['layer_metrics'][-1]).UNIT, "
-             "m.ROOT)")
+             "m.ROOT)" % drop_cell)
     out = subprocess.run([sys.executable, "-c", probe], cwd=tmp_path, env=env,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
-    assert out.stdout.split() == ["512", "steps", str(pkg)]
+    assert out.stdout.split() == ["384", "steps", str(pkg)]

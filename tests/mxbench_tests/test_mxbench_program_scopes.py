@@ -175,7 +175,7 @@ def test_the_cell_lists_the_five_beside_the_s128_cells_nine():
     base, _ = manifest.traffic(old["traffic"])
     assert mix["kind"] == "train_stream" and gen.UNITS == {
         "train_samples_per_s": "samples/s", "train_images_per_s": "img/s",
-        "setup_s": "s"}
+        "train_routed_samples_per_s": "samples/s", "setup_s": "s"}
     assert mix["seq"] == 512 and base["seq"] == 128
     same = ("loop", "dropout", "optimizer", "feed", "inflight_steps",
             "warmup_steps", "trace_seconds", "toy")

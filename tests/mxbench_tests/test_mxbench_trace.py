@@ -139,6 +139,8 @@ def test_spans_are_counted_inside_a_window():
     ("host_gap_ms.train", (1000 - 327) * 1e-6),
     ("device_idle_pct.train", 67.3),
     ("device_idle_pct.train_img", 67.3),
+    ("device_idle_pct.train_routed", 67.3),
+    ("host_gap_ms.train_routed", (1000 - 327) * 1e-6),
 ])
 def test_host_side_readers_use_the_untraced_window(metric, want):
     import types
