@@ -23,7 +23,12 @@ expert model (gated short-convolution layers and grouped-query
 attention layers with q/k norms mixed by a per-layer list, leading
 dense layers by a count, a sigmoid router with a selection bias and no
 shared expert, the head tied to the embedding: the loss block reads the
-net's own parameter)."""
+net's own parameter). ``granite_hybrid``: the seventh, Granite 4.0-H's
+dense model (nine Mamba-2 mixers to one NoPE attention mixer by a
+per-layer list, a dense gated MLP in every layer, four multipliers on
+the embedding, the branches, the scores and the logits, a tied head),
+the first that takes a packed row's document ids beside its tokens:
+conv taps, scan state and attention stop at a document's start."""
 from . import vision
 from . import bert
 from . import nemotron_h
@@ -32,4 +37,5 @@ from . import mellum
 from . import glm_moe_lite
 from . import laguna
 from . import lfm2
+from . import granite_hybrid
 from .vision import get_model

@@ -193,29 +193,44 @@ def gated_rms_norm(data, gate, gamma, *, group_size, eps=1e-5):
 # ---------------------------------------------------------------------------
 # Mamba-2
 # ---------------------------------------------------------------------------
-def _causal_conv1d(x, w, b=None, dtype=F32):
+def _causal_conv1d(x, w, b=None, dtype=F32, segments=None):
     """The taps' products and their sum in ``dtype``, the result in
-    ``x``'s."""
+    ``x``'s. With ``segments`` (batch, length) a tap whose source token
+    carries another id than the token it is summed into adds 0."""
     k, length = w.shape[1], x.shape[1]
     xp = jnp.pad(x.astype(dtype), ((0, 0), (k - 1, 0), (0, 0)))
     wf = w.astype(dtype)
     y = None if b is None else b.astype(dtype)
+    if segments is not None:
+        sp = jnp.pad(segments, ((0, 0), (k - 1, 0)))
     for j in range(k):
         tap = xp[:, j:j + length, :] * wf[:, j]
+        if segments is not None and j < k - 1:
+            tap = jnp.where((sp[:, j:j + length] == segments)[..., None],
+                            tap, 0)
         y = tap if y is None else y + tap
     return y.astype(x.dtype)
 
 
 @register("_contrib_causal_conv1d")
-def causal_conv1d(data, weight, bias=None):
+def causal_conv1d(data, weight, bias=None, segment_ids=None):
     """Causal depthwise conv over time: data (batch, length, channels),
     weight (channels, k) for any kernel length ``k``, bias (channels,)
     or none; ``y[t] = bias + sum_j weight[:, j] * data[t - (k-1) + j]``
-    with zeros before the start, summed in float32."""
-    return _causal_conv1d(data, weight, bias)
+    with zeros before the start, summed in float32. With
+    ``segment_ids`` (batch, length; integers, one id a document of a
+    packed row) the taps also read zeros before the start of ``t``'s
+    document: a term whose source token has another id is left out."""
+    return _causal_conv1d(data, weight, bias, segments=segment_ids)
 
 
-def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk):
+def _document_starts(segments):
+    """(batch, length) bool: the tokens whose id is not the one before
+    theirs (a row's first token is no start: nothing precedes it)."""
+    return jnp.pad(segments[:, 1:] != segments[:, :-1], ((0, 0), (1, 0)))
+
+
+def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk, reset=None):
     b, length, heads, p = x.shape
     groups, n = bm.shape[2], bm.shape[3]
     rep = heads // groups
@@ -224,11 +239,13 @@ def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk):
     if pad:     # dt 0 there: the state is carried unchanged, y is cut
         x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) *
                                  (v.ndim - 2)) for v in (x, dt, bm, cm))
+        if reset is not None:
+            reset = jnp.pad(reset, ((0, 0), (0, pad)))
     nc = (length + pad) // q
     dtf = dt.astype(F32)
     # log-decays, cumulative inside each chunk: (b, nc, heads, q)
-    acs = jnp.cumsum((dtf * a_neg.astype(F32)).reshape(b, nc, q, heads)
-                     .transpose(0, 1, 3, 2), axis=-1)
+    acs = jnp.cumsum(pallas_ssd.log_decays(dtf, a_neg, reset)
+                     .reshape(b, nc, q, heads).transpose(0, 1, 3, 2), axis=-1)
     xdt = (x.astype(F32) * dtf[..., None]).astype(x.dtype) \
         .reshape(b, nc, q, groups, rep, p)
     bc = bm.reshape(b, nc, q, groups, n)
@@ -251,6 +268,10 @@ def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk):
 
     # the state entering chunk c: sum_{c'<c} exp(sum_{c'<k<c} a_k) states_c'
     total = acs[..., -1].reshape(b, nc, groups, rep)
+    if reset is not None:
+        # a chunk that holds a start lets nothing through, however many
+        # it holds: one start's worth keeps the sums over chunks small
+        total = jnp.maximum(total, -pallas_ssd.RESET)
     cs = jnp.cumsum(total, axis=1)
     before = jnp.concatenate([jnp.zeros_like(cs[:, :1]), cs[:, :-1]], 1)
     gap = before[:, :, None] - cs[:, None, :]         # (b, c, c', g, r)
@@ -267,7 +288,7 @@ def _ssd(x, dt, a_neg, bm, cm, d_skip, chunk):
     return y.astype(x.dtype)
 
 
-def _scan(x, dt, a_neg, bm, cm, d_skip, chunk):
+def _scan(x, dt, a_neg, bm, cm, d_skip, chunk, segments=None):
     """The scan by whichever form the call allows, chosen from what can
     be observed here and nothing else: the kernels of
     ``ops/pallas_ssd.py`` (a chunk's decays and mix and the carried
@@ -276,17 +297,23 @@ def _scan(x, dt, a_neg, bm, cm, d_skip, chunk):
     (``pallas_ssd.ssd_available``); the composition :func:`_ssd` for
     everything else. Counted once a traced call in
     ``mx_mamba2_ssd_path_total{path="pallas"|"xla"}``; the device-side
-    scope is ``mx.mamba2.ssd`` either way."""
+    scope is ``mx.mamba2.ssd`` either way. With ``segments`` (batch,
+    length) the state is reset where the id changes: both forms take
+    their log-decays from one function (``pallas_ssd.log_decays``),
+    which puts ``-RESET`` in a document's first step's place, so
+    neither the kernels nor the composition's products know of
+    documents."""
     kernel = pallas_ssd.ssd_available(x, bm, cm, chunk)
     telemetry.count_event("mx_mamba2_ssd_path_total",
                           path="pallas" if kernel else "xla")
     with jax.named_scope(pallas_ssd.SCOPE):
         form = pallas_ssd.ssd_scan if kernel else _ssd
-        return form(x, dt, a_neg, bm, cm, d_skip, chunk)
+        return form(x, dt, a_neg, bm, cm, d_skip, chunk,
+                    None if segments is None else _document_starts(segments))
 
 
 @register("_contrib_ssd_scan")
-def ssd_scan(data, dt, a, b, c, d, *, chunk_size=128):
+def ssd_scan(data, dt, a, b, c, d, segment_ids=None, *, chunk_size=128):
     """The selective state-space recurrence of Mamba-2 in its chunked
     matrix form. data (batch, length, heads, head_dim); dt (batch,
     length, heads), already positive; a (heads,), negative; b, c
@@ -298,26 +325,33 @@ def ssd_scan(data, dt, a, b, c, d, *, chunk_size=128):
 
     computed chunk by chunk (``chunk_size`` steps: products inside a
     chunk, one carried state between chunks); any length (the tail is
-    padded with dt = 0). Two forms of one algorithm (:func:`_scan`)."""
-    return _scan(data, dt, a, b, c, d, chunk_size)
+    padded with dt = 0). Two forms of one algorithm (:func:`_scan`).
+    With ``segment_ids`` (batch, length; integers, non-decreasing along
+    a row) ``S_{t-1}`` is taken as 0 at every ``t`` whose id differs
+    from ``t - 1``'s: each document of a packed row starts from the
+    zero state. The reset is a decay of ``exp(-RESET)`` = 4e-18 in that
+    step's place (``pallas_ssd.log_decays``), not a mask: what crosses
+    a boundary is under float32's resolution of anything it meets."""
+    return _scan(data, dt, a, b, c, d, chunk_size, segment_ids)
 
 
 def _mamba2(u, norm_w, in_w, conv_w, conv_b, dt_bias, a_log, d_skip,
-            gate_norm_w, out_w, *, heads, head_dim, groups, state, chunk,
-            eps):
+            gate_norm_w, out_w, segments=None, *, heads, head_dim, groups,
+            state, chunk, eps):
     b, length, _ = u.shape
     inner, gn = heads * head_dim, groups * state
     zxbcdt = _in_product(_rms(u, norm_w, eps), in_w)
     z = zxbcdt[..., :inner]
     xbc = zxbcdt[..., inner:2 * inner + 2 * gn]
     dt = zxbcdt[..., 2 * inner + 2 * gn:]
-    xbc = jax.nn.silu(_causal_conv1d(xbc, conv_w, conv_b).astype(F32)) \
-        .astype(u.dtype)
+    xbc = jax.nn.silu(_causal_conv1d(xbc, conv_w, conv_b, segments=segments)
+                      .astype(F32)).astype(u.dtype)
     x = xbc[..., :inner].reshape(b, length, heads, head_dim)
     bm = xbc[..., inner:inner + gn].reshape(b, length, groups, state)
     cm = xbc[..., inner + gn:].reshape(b, length, groups, state)
     dt = jax.nn.softplus(dt.astype(F32) + dt_bias.astype(F32))
-    y = _scan(x, dt, -jnp.exp(a_log.astype(F32)), bm, cm, d_skip, chunk)
+    y = _scan(x, dt, -jnp.exp(a_log.astype(F32)), bm, cm, d_skip, chunk,
+              segments)
     y = _gated_rms(y.reshape(b, length, inner), z, gate_norm_w,
                    inner // groups, eps)
     return _dense(y, out_w)
@@ -325,9 +359,9 @@ def _mamba2(u, norm_w, in_w, conv_w, conv_b, dt_bias, a_log, d_skip,
 
 @register("_contrib_mamba2_mixer")
 def mamba2_mixer(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
-                 dt_bias, a_log, d, gate_norm_gamma, out_proj_weight, *,
-                 num_heads, head_dim, n_groups, state_size, chunk_size=128,
-                 eps=1e-5):
+                 dt_bias, a_log, d, gate_norm_gamma, out_proj_weight,
+                 segment_ids=None, *, num_heads, head_dim, n_groups,
+                 state_size, chunk_size=128, eps=1e-5):
     """A pre-norm Mamba-2 mixer, ``mixer(RMSNorm(data))``: in_proj to
     ``[z | xBC | dt]``, causal depthwise conv + SiLU over xBC, softplus
     dt, the SSD scan (:func:`ssd_scan`), the gated grouped RMSNorm and
@@ -336,14 +370,31 @@ def mamba2_mixer(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
     beside ``data`` (``2 x inner + 2 x groups x state + heads`` values a
     token, by the module's rule): the backward runs the norm, the conv,
     SiLU, softplus, the scan's forward and the gated norm again, never
-    in_proj (out_proj's product is dead in the recomputation)."""
+    in_proj (out_proj's product is dead in the recomputation). With
+    ``segment_ids`` (batch, length; integers, non-decreasing along a
+    row: the documents of a packed row) the conv's taps and the scan's
+    state stop at a document's first token (:func:`causal_conv1d`,
+    :func:`ssd_scan`); the projections, the norms and the gate are a
+    token's own and need no ids."""
     fn = _recomputed_but_in_product(lambda *arrays: _mamba2(
         *arrays, heads=int(num_heads), head_dim=int(head_dim),
         groups=int(n_groups), state=int(state_size), chunk=int(chunk_size),
         eps=float(eps)), "mamba2")
+    ids = () if segment_ids is None else (segment_ids,)
     with jax.named_scope("mx.mamba2"):
         return fn(data, norm_gamma, in_proj_weight, conv_weight, conv_bias,
-                  dt_bias, a_log, d, gate_norm_gamma, out_proj_weight)
+                  dt_bias, a_log, d, gate_norm_gamma, out_proj_weight, *ids)
+
+
+@register("_contrib_count_documents", num_outputs=1, mutate_aux={1: 1})
+def count_documents(segment_ids, seq_documents):
+    """``segment_ids`` (batch, length; integers, one id a document of a
+    packed row) as they are, for the mixers that take them; into the
+    auxiliary state ``seq_documents`` (1,) float32 (written, never
+    differentiated) goes the number of documents a row holds, one more
+    than the places where the id changes, mean over the batch."""
+    starts = jnp.sum(_document_starts(segment_ids).astype(F32), axis=1)
+    return segment_ids, (1.0 + jnp.mean(starts)).reshape(1)
 
 
 # ---------------------------------------------------------------------------
@@ -394,22 +445,27 @@ def short_conv_mixer(data, norm_gamma, in_weight, conv_weight, out_weight, *,
 # ---------------------------------------------------------------------------
 # causal grouped-query attention
 # ---------------------------------------------------------------------------
-def _causal_gqa(q, k, v, block, window=None):
+def _causal_gqa(q, k, v, block, window=None, segments=None):
     b, length, heads, d = q.shape
     kv = k.shape[2]
     qg = q.reshape(b, length, kv, heads // kv, d)
     scale = 1.0 / math.sqrt(d)
 
     @jax.checkpoint
-    def rows(qb, kb, vb, first):
+    def rows(qb, kb, vb, first, *ids):
         # one block of queries against its prefix of keys (``first``:
-        # the first query's position among the keys handed in)
+        # the first query's position among the keys handed in; ``ids``:
+        # the queries' and the keys' document ids, or nothing)
         s = _mm("bqgrd,bkgd->bgrqk", qb, kb) * scale
         qi = first + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
         ki = lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
         seen = ki <= qi
         if window is not None:
             seen = seen & (qi - ki < window)
+        if ids:
+            sq, sk = ids
+            seen = seen & (sq[:, None, None, :, None]
+                           == sk[:, None, None, None, :])
         p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
         return _mm("bgrqk,bkgd->bqgrd", p.astype(vb.dtype), vb) \
             .astype(qb.dtype)
@@ -419,12 +475,14 @@ def _causal_gqa(q, k, v, block, window=None):
         hi = min(lo + block, length)
         # with a window, only the keys of the block's band
         start = 0 if window is None else max(lo - window + 1, 0)
+        ids = () if segments is None else (segments[:, lo:hi],
+                                           segments[:, start:hi])
         out.append(rows(qg[:, lo:hi], k[:, start:hi], v[:, start:hi],
-                        lo - start))
+                        lo - start, *ids))
     return jnp.concatenate(out, axis=1).reshape(b, length, heads, d)
 
 
-def _attend(q, k, v, window=None, keep=None):
+def _attend(q, k, v, window=None, keep=None, segments=None, scale=None):
     """Causal GQA by whichever schedule the call allows, chosen from
     what can be observed here and nothing else: the flash kernel for
     bf16 q / k / v with a head width of whole lane tiles (or of half a
@@ -438,23 +496,36 @@ def _attend(q, k, v, window=None, keep=None):
     a windowed call in ``mx_attn_window_path_total`` instead; the
     device-side scope is ``mx.attn.causal`` or ``mx.attn.window``
     likewise. ``keep`` names the context (and the kernel's log-sum-exp)
-    for a caller's ``jax.checkpoint`` policy."""
-    kernel = pallas_causal_gqa.causal_gqa_available(q, k, v, QUERY_BLOCK)
+    for a caller's ``jax.checkpoint`` policy. With ``segments`` (batch,
+    length; integers) a query sees only the keys whose id is its own,
+    by a third mask in either schedule; such a call is counted in
+    ``mx_attn_segments_path_total{path=}`` as well. ``scale`` is the
+    scores' factor where it is not ``1 / sqrt(d)``: q is multiplied by
+    ``scale * sqrt(d)`` here, in its own dtype, and both schedules keep
+    their ``1 / sqrt(d)`` (a power of two is exact; any other factor
+    rounds q once)."""
+    kernel = pallas_causal_gqa.causal_gqa_available(q, k, v, QUERY_BLOCK,
+                                                    segments)
     windowed = window is not None
+    path = "pallas" if kernel else "xla"
     telemetry.count_event("mx_attn_window_path_total" if windowed
-                          else "mx_attn_causal_path_total",
-                          path="pallas" if kernel else "xla")
+                          else "mx_attn_causal_path_total", path=path)
+    if segments is not None:
+        telemetry.count_event("mx_attn_segments_path_total", path=path)
     with jax.named_scope(pallas_causal_gqa.WINDOW_SCOPE if windowed
                          else pallas_causal_gqa.SCOPE):
+        if scale is not None:
+            q = q * jnp.asarray(float(scale) * math.sqrt(q.shape[-1]),
+                                q.dtype)
         if kernel:
-            return pallas_causal_gqa.flash_causal_gqa(q, k, v, QUERY_BLOCK,
-                                                      window, keep)
-        ctx = _causal_gqa(q, k, v, QUERY_BLOCK, window)
+            return pallas_causal_gqa.flash_causal_gqa(
+                q, k, v, QUERY_BLOCK, window, keep, segments)
+        ctx = _causal_gqa(q, k, v, QUERY_BLOCK, window, segments)
         return ctx if keep is None else checkpoint_name(ctx, keep)
 
 
 @register("_contrib_causal_gqa_attention")
-def causal_gqa_attention(query, key, value):
+def causal_gqa_attention(query, key, value, segment_ids=None, *, scale=None):
     """Causal ``softmax(Q K^T / sqrt(d)) V`` with grouped keys and
     values and no positional term: query (batch, length, heads, d),
     key / value (batch, length, kv_heads, d), query head h reading
@@ -463,15 +534,21 @@ def causal_gqa_attention(query, key, value):
     end only (the masked upper triangle is not computed beyond the
     diagonal block), so no length x length array exists; each block's
     scores are recomputed in the backward (:func:`_attend`: in VMEM by
-    the flash kernel, through HBM by the composition)."""
-    return _attend(query, key, value)
+    the flash kernel, through HBM by the composition). With
+    ``segment_ids`` (batch, length; integers, one id a document of a
+    packed row) query ``t`` sees the keys ``s <= t`` of its own
+    document only. ``scale`` replaces ``1 / sqrt(d)`` (a model whose
+    ``attention_multiplier`` is its own; :func:`_attend` says how)."""
+    return _attend(query, key, value, segments=segment_ids, scale=scale)
 
 
 @register("_contrib_gqa_mixer")
-def gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight, *,
-              num_heads, num_kv_heads, head_dim, eps=1e-5):
+def gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight,
+              segment_ids=None, *, num_heads, num_kv_heads, head_dim,
+              scale=None, eps=1e-5):
     """A pre-norm attention mixer, ``mixer(RMSNorm(data))``: bias-free
-    q/k/v projections, :func:`causal_gqa_attention`, bias-free output
+    q/k/v projections, :func:`causal_gqa_attention` (its
+    ``segment_ids`` and ``scale`` likewise), bias-free output
     projection. data (batch, length, hidden). The score blocks are
     recomputed in the backward; q, k, v and the context are kept."""
     b, length, _ = data.shape
@@ -480,7 +557,7 @@ def gqa_mixer(data, norm_gamma, q_weight, k_weight, v_weight, o_weight, *,
     q = _dense(x, q_weight).reshape(b, length, h, d)
     k = _dense(x, k_weight).reshape(b, length, kv, d)
     v = _dense(x, v_weight).reshape(b, length, kv, d)
-    ctx = _attend(q, k, v)
+    ctx = _attend(q, k, v, segments=segment_ids, scale=scale)
     return _dense(ctx.reshape(b, length, h * d), o_weight)
 
 

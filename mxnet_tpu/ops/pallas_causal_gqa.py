@@ -44,6 +44,20 @@ the length) the traced program is the causal one, unchanged. k and v
 stay whole in VMEM either way: the window saves products, not
 residency, and the length is bounded as before.
 
+With ``segments`` (packed documents: one integer id a token, query
+``t`` sees the keys ``s <= t`` *of its own document*) both kernels take
+the ids twice, as a row ``(batch, 1, length)`` of which a step reads
+its query tile's (queries on lanes, as the row statistics lie) and as a
+column ``(batch, length, 1)`` that sits whole in VMEM beside k and v
+(keys on sublanes; a lane tile wide there: 4 MB at 8,192 tokens, which
+the budget counts), and every visited tile is masked by ``id_key ==
+id_query`` on top of its causal or band mask. The diagonal tile is
+visited first, as under a window and for the same reason: a query's
+earlier tiles may hold no key of its document. Tiles that lie wholly in
+other documents are visited and masked, not skipped: a step's time does
+not follow its row's boundaries (PERF.md section 7). Without
+``segments`` the traced program is the one it was.
+
 Both kernels compute every tile transposed, keys x queries
 (``S^T = K_j Q^T``): a query's running max and sum, the saved
 log-sum-exp and ``delta = sum(dO * O)`` are then dense ``(1, tile)``
@@ -77,6 +91,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
@@ -106,8 +121,10 @@ _NN = (((1,), (0,)), ((), ()))      # a @ b
 _TN = (((0,), (0,)), ((), ()))      # a^T @ b
 
 
-def _bwd_vmem_bytes(length, d, tile):
+def _bwd_vmem_bytes(length, d, tile, segmented=False):
     resident = length * d * (4 * 2 * 2 + 2 * 4)     # k v dk dv x2, 2 acc
+    if segmented:       # the keys' ids, a lane tile wide, x2
+        resident += length * _LANE * 4 * 2
     tiles = 6 * tile * tile * 4 + 8 * tile * d * 4
     return resident + tiles
 
@@ -127,13 +144,13 @@ def _heads_a_step(heads, kv, d):
     return 0
 
 
-def causal_gqa_available(q, k, v, tile):
+def causal_gqa_available(q, k, v, tile, segments=None):
     """Whether the kernel may serve this call, from what the code can
     observe: one device in the mesh being traced for, bf16 q / k / v,
     a head width of whole 128-lane tiles or of half a tile (64 lanes,
     with even groups over an even number of key-value heads), whole
-    groups of query heads, a length of whole tiles, and a k / v that
-    fits VMEM."""
+    groups of query heads, a length of whole tiles, and a k / v (and,
+    with ``segments``, the keys' ids) that fit VMEM."""
     b, length, heads, d = q.shape
     pack = _heads_a_step(heads, k.shape[2], d)
     return bool(
@@ -141,7 +158,8 @@ def causal_gqa_available(q, k, v, tile):
         and all(t.dtype == BF16 for t in (q, k, v))
         and pack and tile % _LANE == 0
         and length > 0 and length % tile == 0
-        and _bwd_vmem_bytes(length, d * pack, tile) <= _VMEM_BUDGET)
+        and _bwd_vmem_bytes(length, d * pack, tile, segments is not None)
+        <= _VMEM_BUDGET)
 
 
 def _dot(a, b, dims):
@@ -165,13 +183,17 @@ def _band(tile, dist, window):
     return (ahead >= 0) & (ahead < window)
 
 
-def _key_tiles(pl, i, key_tile, tile, length, window):
+def _key_tiles(pl, i, key_tile, tile, length, window, diagonal_first=False):
     """Run ``key_tile(j, mask)`` over the key tiles that query tile
     ``i`` sees; ``mask`` (keys x queries, or None for a tile seen
-    whole) is static."""
+    whole) is static. ``diagonal_first``: the diagonal tile before the
+    earlier ones (under a window it always is)."""
     if window is None or window >= length:
+        if diagonal_first:
+            key_tile(i, _seen(tile))
         lax.fori_loop(0, i, lambda j, c: key_tile(j, None), None)
-        key_tile(i, _seen(tile))
+        if not diagonal_first:
+            key_tile(i, _seen(tile))
         return
     key_tile(i, _seen(tile) if window >= tile else _band(tile, 0, window))
     # before the diagonal: tile i - dist holds the pairs dist * tile -
@@ -186,9 +208,9 @@ def _key_tiles(pl, i, key_tile, tile, length, window):
             key_tile, i - dist, _band(tile, dist, window)))
 
 
-def _compiler_params(pltpu, semantics, length, d, tile):
+def _compiler_params(pltpu, semantics, length, d, tile, segmented):
     """The backward's working set bounds the forward's too."""
-    nbytes = _bwd_vmem_bytes(length, d, tile) + (16 << 20)
+    nbytes = _bwd_vmem_bytes(length, d, tile, segmented) + (16 << 20)
     return pltpu.CompilerParams(dimension_semantics=semantics,
                                 vmem_limit_bytes=min(nbytes, 110 << 20))
 
@@ -204,6 +226,19 @@ def _block_specs(pl, length, d, tile, rep, pack=1):
     return (pl.BlockSpec((None, tile, d), lambda n, h, i: (n, i, h)),
             pl.BlockSpec((None, length, d), lambda n, h, i: (n, 0, h // rep)),
             pl.BlockSpec(rows, lambda n, h, i: (n, h, 0, i)))
+
+
+def _segment_specs(pl, length, tile):
+    """[the query tile's ids as a row, every key's id as a column] of
+    the operands (batch, 1, length) and (batch, length, 1)."""
+    return [pl.BlockSpec((None, 1, tile), lambda n, h, i: (n, 0, i)),
+            pl.BlockSpec((None, length, 1), lambda n, h, i: (n, 0, 0))]
+
+
+def _in_document(mask, sq_ref, sk_ref, rows):
+    """``mask`` (keys x queries, or None) and ``id_key == id_query``."""
+    same = sk_ref[rows, :] == sq_ref[...]
+    return same if mask is None else mask & same
 
 
 class _Pair:
@@ -241,7 +276,7 @@ class _Pair:
 
 
 @functools.lru_cache(maxsize=None)
-def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
+def _fwd_call(b, length, heads, kv, d, tile, window, segmented, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -250,8 +285,8 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
     pack = _heads_a_step(heads, kv, d)
     lanes = d * pack
 
-    def pallas_causal_gqa_fwd(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                              m_ref, l_ref, acc_ref):
+    def pallas_causal_gqa_fwd(q_ref, k_ref, v_ref, *refs):
+        segs, (o_ref, lse_ref, m_ref, l_ref, acc_ref) = refs[:-5], refs[-5:]
         i = pl.program_id(2)
         q = q_ref[...]
         if pack == 1:
@@ -267,6 +302,8 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
 
         def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+            if segmented:
+                mask = _in_document(mask, *segs, rows)
             for a in range(pack):
                 st = _dot(k_ref[rows, :], qs[a], _NT) * scale   # keys x queries
                 if mask is not None:
@@ -282,7 +319,7 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
                     v_ref[rows, :], pt.astype(BF16), _TN)   # d x queries
                 m_ref[stat[a]] = m_next
 
-        _key_tiles(pl, i, key_tile, tile, length, window)
+        _key_tiles(pl, i, key_tile, tile, length, window, segmented)
         l = l_ref[...]
         if pack == 1:
             o_ref[...] = (acc_ref[...] / l).T.astype(o_ref.dtype)
@@ -297,7 +334,8 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
     return pl.pallas_call(
         pallas_causal_gqa_fwd,
         grid=(b, heads // pack, nq),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, kv_spec] + (
+            _segment_specs(pl, length, tile) if segmented else []),
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
                    jax.ShapeDtypeStruct((b, heads, 1, length), F32)],
@@ -306,14 +344,14 @@ def _fwd_call(b, length, heads, kv, d, tile, window, interpret):
                         pltpu.VMEM((pack * lanes, tile), F32)],
         compiler_params=_compiler_params(
             pltpu, ("parallel", "parallel", "arbitrary"), length, lanes,
-            tile),
+            tile, segmented),
         interpret=interpret,
         name="pallas_causal_gqa_fwd",
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
+def _bwd_call(b, length, heads, kv, d, tile, window, segmented, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -323,8 +361,9 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
     lanes = d * pack
 
     def pallas_causal_gqa_bwd(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                              delta_ref, dq_ref, dk_ref, dv_ref,
-                              dq_acc, dk_acc, dv_acc):
+                              delta_ref, *refs):
+        segs, (dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = \
+            refs[:-6], refs[-6:]
         h, i = pl.program_id(1), pl.program_id(2)
 
         @pl.when((h % rep == 0) & (i == 0))
@@ -347,6 +386,8 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
         def key_tile(j, mask):
             rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
             kj, vj = k_ref[rows, :], v_ref[rows, :]
+            if segmented:
+                mask = _in_document(mask, *segs, rows)
             for a in range(pack):
                 st = _dot(kj, qs[a], _NT) * scale           # keys x queries
                 if mask is not None:
@@ -358,7 +399,7 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
                 dk_acc[rows, :] += _dot(dst, qs[a], _NN)
                 dq_acc[part[a]] += _dot(kj, dst, _TN)       # d x queries
 
-        _key_tiles(pl, i, key_tile, tile, length, window)
+        _key_tiles(pl, i, key_tile, tile, length, window, segmented)
         if pack == 1:
             dq_ref[...] = dq_acc[...].T.astype(dq_ref.dtype)
         else:
@@ -375,7 +416,8 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
     return pl.pallas_call(
         pallas_causal_gqa_bwd,
         grid=(b, heads // pack, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec] + (
+            _segment_specs(pl, length, tile) if segmented else []),
         out_specs=[q_spec, kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct((b, length, heads * d), BF16),
                    kv_shape, kv_shape],
@@ -384,24 +426,27 @@ def _bwd_call(b, length, heads, kv, d, tile, window, interpret):
                         pltpu.VMEM((length, lanes), F32)],
         compiler_params=_compiler_params(
             pltpu, ("parallel", "arbitrary", "arbitrary"), length, lanes,
-            tile),
+            tile, segmented),
         interpret=interpret,
         name="pallas_causal_gqa_bwd",
     )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_causal_gqa(q, k, v, tile, window=None, keep=None):
+def flash_causal_gqa(q, k, v, tile, window=None, keep=None, segments=None):
     """Causal ``softmax(Q K^T / sqrt(d)) V``: q (batch, length, heads,
     d), k / v (batch, length, kv_heads, d), all bf16, query head h
     reading key-value head ``h // (heads // kv_heads)``; ``tile``
     queries and keys a tile (check :func:`causal_gqa_available`
     first). With ``window`` query ``t`` sees the keys ``t - window < s
-    <= t`` only, and only their tiles are visited. ``keep`` names the
-    context and the rows' log-sum-exp (``checkpoint_name``) for a
-    caller whose ``jax.checkpoint`` policy saves them, so that its
-    backward does not run the forward kernel again."""
-    return _forward(q, k, v, tile, window)[0]
+    <= t`` only, and only their tiles are visited. With ``segments``
+    (batch, length), integers, it sees only the keys whose id is its
+    own (packed documents; every tile the causal or band rule visits is
+    visited and masked). ``keep`` names the context and the rows'
+    log-sum-exp (``checkpoint_name``) for a caller whose
+    ``jax.checkpoint`` policy saves them, so that its backward does not
+    run the forward kernel again."""
+    return _forward(q, k, v, tile, window, segments)[0]
 
 
 def _lanes(x):
@@ -413,33 +458,47 @@ def _static(window):
     return None if window is None else int(window)
 
 
-def _forward(q, k, v, tile, window):
+def _ids(segments):
+    """The ids as the kernels take them: [] without, else [(batch, 1,
+    length), (batch, length, 1)] int32."""
+    if segments is None:
+        return []
+    ids = segments.astype(jnp.int32)
+    return [ids[:, None, :], ids[:, :, None]]
+
+
+def _forward(q, k, v, tile, window, segments):
     b, length, heads, d = q.shape
     call = _fwd_call(b, length, heads, k.shape[2], d, int(tile),
-                     _static(window), pallas_common.interpret_mode())
-    o, lse = call(_lanes(q), _lanes(k), _lanes(v))
+                     _static(window), segments is not None,
+                     pallas_common.interpret_mode())
+    o, lse = call(_lanes(q), _lanes(k), _lanes(v), *_ids(segments))
     return o.reshape(q.shape), lse
 
 
-def _vjp_fwd(q, k, v, tile, window, keep):
-    o, lse = _forward(q, k, v, tile, window)
+def _vjp_fwd(q, k, v, tile, window, keep, segments):
+    o, lse = _forward(q, k, v, tile, window, segments)
     if keep is not None:
         o, lse = checkpoint_name(o, keep), checkpoint_name(lse, keep)
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, o, lse, segments)
 
 
 def _vjp_bwd(tile, window, keep, res, do):
-    q, k, v, o, lse = res
+    q, k, v, o, lse, segments = res
     b, length, heads, d = q.shape
     call = _bwd_call(b, length, heads, k.shape[2], d, int(tile),
-                     _static(window), pallas_common.interpret_mode())
+                     _static(window), segments is not None,
+                     pallas_common.interpret_mode())
     with jax.named_scope(SCOPE if window is None else WINDOW_SCOPE):
         do = do.astype(BF16)
         delta = jnp.sum(o.astype(F32) * do.astype(F32), axis=-1) \
             .transpose(0, 2, 1)[:, :, None, :]
         dq, dk, dv = call(_lanes(q), _lanes(k), _lanes(v), _lanes(do), lse,
-                          delta)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+                          delta, *_ids(segments))
+    # (an integer operand's cotangent is a float0 zero)
+    none = None if segments is None else np.zeros(segments.shape,
+                                                  jax.dtypes.float0)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), none
 
 
 flash_causal_gqa.defvjp(_vjp_fwd, _vjp_bwd)

@@ -79,7 +79,7 @@ from jax import lax
 from . import pallas_common
 from .pallas_causal_gqa import BF16, F32, _NN, _NT, _TN, _dot
 
-__all__ = ["SCOPE", "ssd_available", "ssd_scan"]
+__all__ = ["SCOPE", "RESET", "log_decays", "ssd_available", "ssd_scan"]
 
 # the device-side scope of the scan, kernel or composition
 # (``decoder_ops._scan`` opens it; the backward rule here opens it
@@ -89,6 +89,29 @@ SCOPE = "mx.mamba2.ssd"
 _LANE = 128
 _ROWS = 8                       # a float32 sublane tile
 _VMEM_BUDGET = 96 * 1024 * 1024
+
+# A document's first step in a packed row decays what came before it by
+# exp(-RESET) = 4.2e-18: under float32's resolution (6e-8) of anything
+# the new document adds, by ten orders. Not larger: the log-decays are
+# summed in float32 inside a chunk, a sum that has passed k starts is
+# about -k RESET, and a decay is the exp of a difference of two such
+# sums, so its relative error is their spacing there: 3e-5 at ten
+# starts a chunk of 128 (documents of 16 tokens, the shortest a feed
+# here draws, and a row's cut ends), where the sums of ``dt a`` alone
+# already reach -200 and 1.5e-5
+RESET = 40.0
+
+
+def log_decays(dt, a_neg, reset=None):
+    """(batch, length, heads) float32: a step's log-decay ``dt a``, and
+    ``-RESET`` where ``reset`` (batch, length) bool says that a
+    document starts (the state before it counts for nothing; no
+    gradient goes to that step's ``dt`` or to ``a`` through its decay,
+    as none is due). What both forms of the scan sum."""
+    la = dt.astype(F32) * a_neg.astype(F32)
+    if reset is None:
+        return la
+    return jnp.where(reset[..., None], -RESET, la)
 
 
 def _bwd_vmem_bytes(q, w, n):
@@ -454,7 +477,7 @@ def _scan_bwd(dims, q, res, dy):
 _scan.defvjp(_scan_fwd, _scan_bwd, optimize_remat=True)
 
 
-def ssd_scan(x, dt, a_neg, bm, cm, d_skip, chunk):
+def ssd_scan(x, dt, a_neg, bm, cm, d_skip, chunk, reset=None):
     """``decoder_ops._ssd``'s result by the kernels: x (batch, length,
     heads, head_dim), dt (batch, length, heads), a_neg (heads,), bm /
     cm (batch, length, groups, state), d_skip (heads,); check
@@ -463,7 +486,9 @@ def ssd_scan(x, dt, a_neg, bm, cm, d_skip, chunk):
     and y is cut. The log-decays ``dt a`` and their cumulative sum
     inside each chunk are XLA's, in float32 (2 MB a layer at the
     Nemotron cell's call), and so is the reverse sum of the backward,
-    by autodiff."""
+    by autodiff. ``reset`` (batch, length) bool marks the steps before
+    which the state counts for nothing (:func:`log_decays`): the
+    kernels are handed those sums and know nothing of documents."""
     b, length, heads, p = x.shape
     groups, n = bm.shape[2], bm.shape[3]
     rep, q = heads // groups, int(chunk)
@@ -471,10 +496,12 @@ def ssd_scan(x, dt, a_neg, bm, cm, d_skip, chunk):
     if pad:
         x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) *
                                  (v.ndim - 2)) for v in (x, dt, bm, cm))
+        if reset is not None:
+            reset = jnp.pad(reset, ((0, 0), (0, pad)))
     full = length + pad
     dt = dt.astype(F32)
-    s = jnp.cumsum((dt * a_neg.astype(F32)).reshape(b, full // q, q, heads),
-                   axis=2)
+    s = jnp.cumsum(log_decays(dt, a_neg, reset)
+                   .reshape(b, full // q, q, heads), axis=2)
     steps = jnp.concatenate([s.reshape(b, full, groups, rep),
                              dt.reshape(b, full, groups, rep)], axis=-1)
     y = _scan(

@@ -207,3 +207,52 @@ def test_gated_rotary_mixer_at_8192_takes_the_kernel_at_groups_of_6_and_8(
     assert {"mx.attn.rotary", "mx.attn.gate"} <= set(placed.values())
     assert compiled.memory_analysis().temp_size_in_bytes < 1.25e9
     assert "f32[1,8,%d,512," % (heads // 8) not in text     # no score block
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-micro's two mixers at the published widths (hidden 2048;
+# 64 scan heads of 64 lanes in one group, state 128; 32 / 8 attention
+# heads of 64 lanes with the model's own score factor) on a packed row
+# of 8,192 tokens: the document ids as a traced operand
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_granite_s_mixers_take_their_kernels_on_a_packed_row(
+        one_chip, compiled_mode, kind):
+    """Mosaic accepts the scan kernels at a lane tile of 4,096 (64 heads
+    in one group, chunks of 128: the forward that writes its states and
+    the backward; a whole step runs the plain forward too) and the
+    causal kernels with the
+    ids as a row and a column operand (the integer compare broadcast
+    both ways, the 4 MB column beside k and v); each under the scope
+    the benchmark reads. (That the scan stands down to the composition
+    at the published chunk of 256 is ``tests/test_granite_hybrid.py``'s,
+    by the kernels' own rule.)"""
+    import jax.numpy as jnp
+    from mxbench import scopes
+    from mxnet_tpu.ops import get_op
+    hidden, length = 2048, 8192
+    ids = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+    if kind == "mamba":
+        op = get_op("_contrib_mamba2_mixer").impl
+        shapes = [(1, length, hidden), (hidden,), (8512, hidden), (4352, 4),
+                  (4352,), (64,), (64,), (64,), (4096,), (hidden, 4096)]
+        attrs = dict(num_heads=64, head_dim=64, n_groups=1, state_size=128)
+        scope, kernel, calls = "mx.mamba2.ssd", "pallas_ssd_", 2
+    else:
+        op = get_op("_contrib_gqa_mixer").impl
+        shapes = [(1, length, hidden), (hidden,), (hidden, hidden),
+                  (512, hidden), (512, hidden), (hidden, hidden)]
+        attrs = dict(num_heads=32, num_kv_heads=8, head_dim=64,
+                     scale=0.015625)
+        scope, kernel, calls = "mx.attn.causal", "pallas_causal_gqa_", 2
+
+    def text(**more):
+        return jax.jit(jax.grad(
+            lambda seg, *a: sum32(op(*a, segment_ids=seg, **attrs, **more)),
+            argnums=tuple(range(1, len(shapes) + 1)))).lower(
+                ids, *described(one_chip, *shapes)).compile().as_text()
+
+    got = text(chunk_size=128) if kind == "mamba" else text()
+    placed = scopes.scope_map(got, [scope])
+    assert len(mosaic_calls(got)) == calls == len(
+        [name for name in placed if name.startswith(kernel)])
