@@ -79,7 +79,8 @@ cell 2.3 GB under the chip); the sparse mixer's cell stands 16 MB under
 the chip; the dense gated mixer's ``gate_up`` output is 1.54 GB in the
 LFM2 cell, whose step the rule's own 1.8 GB already brings to 14.6 GB;
 the expert mixer's large products read the routed buffer, whose ``pre``
-a chunk already keeps (``_HIDDEN``). The rotary and the latent mixer
+a chunk already keeps (``_HIDDEN``); of its routing it keeps the
+integers and a weight a row (``_ROUTED``). The rotary and the latent mixer
 also keep their context and, on the kernel path, the rows'
 log-sum-exp; the sparse one those and each row's selection threshold,
 so neither the search nor a second pass of the attention is repeated;
@@ -1288,6 +1289,17 @@ sparse_gqa_mixer.__doc__ += _DSA_DOC
 # ---------------------------------------------------------------------------
 # routed experts
 # ---------------------------------------------------------------------------
+_ROUTED = "mx.moe.routed"   # what a recomputed expert mixer keeps
+
+
+def _routed(tree):
+    """``tree``'s arrays named for :func:`moe_mixer`'s checkpoint: the
+    chosen experts and their scores, each slot's row, the buffer's maps
+    and counts cross it (under 2 MB a layer in the LFM2 cell), so the
+    backward chooses, counts, sorts and scatters nothing again."""
+    return jax.tree.map(lambda a: checkpoint_name(a, _ROUTED), tree)
+
+
 def _route(x, router_w, bias, top_k, scale, norm_topk, score_func="sigmoid"):
     """(chosen expert ids (T, k), their weights (T, k) float32). Every
     expert's score is ``score_func`` of its float32 logit: ``sigmoid``
@@ -1300,7 +1312,12 @@ def _route(x, router_w, bias, top_k, scale, norm_topk, score_func="sigmoid"):
                         precision=_HI)
     s = _SCORES[score_func](logits)
     _, idx = lax.top_k(s if bias is None else s + bias.astype(F32), top_k)
-    w = jnp.take_along_axis(s, idx, axis=1)
+    idx = _routed(idx)
+    # the chosen scores by a one-hot sum (exact: one term is not 0) with
+    # an element-wise pullback: 0.2 ms each way in the LFM2 cell where a
+    # gather of T x k scalars took 1.4 and its scatter-add back 1.0
+    chosen = idx[:, :, None] == jnp.arange(s.shape[1])
+    w = _routed(jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1))
     if norm_topk:
         w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
     return idx, w * scale
@@ -1359,13 +1376,59 @@ def _slots_to_rows(held, local, n_held, cap, block):
             jnp.sum(starts < ends[-1], dtype=jnp.int32), ends[-1] <= cap)
 
 
+def _placed(values, at, size, fill):
+    """``out[at[i]] = values[i]`` over ``size`` places, ``fill`` where
+    none lands, in one scatter in which no two updates share a place
+    (XLA is told so and neither sorts nor compares them): an update for
+    a place past the end (``at[i] >= size``) is sent to one of its own
+    beyond it and dropped. On the chip a scatter is a loop over its
+    updates, ~5 ns each, so what counts is how many there are and how
+    often it runs (PERF.md section 6, PR 60)."""
+    own = size + jnp.arange(at.shape[0], dtype=jnp.int32)
+    return jnp.full((size,), fill, values.dtype) \
+        .at[jnp.where(at < size, at, own)] \
+        .set(values, mode="drop", unique_indices=True)
+
+
+def _rows_to_slots(row, cap):
+    """:func:`_slots_to_rows`' ``row`` (T, k) the other way round: the
+    slot that fills each of the buffer's ``cap`` rows, as ``token * k +
+    choice`` (``T * k`` where none does), and the row's token (``T``
+    where none)."""
+    t, k = row.shape
+    slot_of_row = _placed(jnp.arange(t * k, dtype=jnp.int32),
+                          row.reshape(-1), cap, t * k)
+    return slot_of_row, jnp.where(slot_of_row < t * k, slot_of_row // k, t)
+
+
+@jax.custom_vjp
+def _weight_of_row(w_slot, slot_of_row):
+    """Each buffer row's slot weight, float32 (0 where no slot fills
+    it): a gather of ``w_slot`` (T, k) at :func:`_rows_to_slots`' slots.
+    Its pullback sends each row's cotangent to its slot (:func:`_placed`,
+    ``cap`` updates: a fifth of the time of a gather at every slot's
+    row, PERF.md section 6, PR 60)."""
+    return w_slot.reshape(-1).at[slot_of_row].get(mode="fill", fill_value=0)
+
+
+_weight_of_row.defvjp(
+    lambda w_slot, slot_of_row: (_weight_of_row(w_slot, slot_of_row),
+                                 (slot_of_row, w_slot.shape)),
+    lambda res, g: (_placed(g, res[0], math.prod(res[1]), 0.0)
+                    .reshape(res[1]), None))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _gather_rows(x, token_of_row, row_of_slot, sums):
     """Rows of ``x`` (T, D) into a buffer: ``out[r] = x[token_of_row[r]]``
-    (a zero row where ``token_of_row[r] == T``). XLA's gather, which
-    moves these rows at the memory's pace (the indices rise within an
-    expert's run: PERF.md section 6, PR 43). ``sums`` is its
-    transpose's."""
+    (a zero row where ``token_of_row[r] == T``). XLA's gather, at the
+    memory's pace where the tokens fit on chip (the indices rise within
+    an expert's run: PERF.md section 6, PRs 43, 60). The zero row costs
+    a copy of ``x`` and stays: a row no slot fills must be a true zero
+    for the grouped kernels' ``dW`` and the window kernel's 0/1 product
+    wherever a token no held expert is routed holds a NaN (a clamped
+    index reads the last token's row), and XLA's own fill, a select over
+    the buffer, costs more than the copy. ``sums`` is its transpose's."""
     return jnp.concatenate([x, jnp.zeros_like(x[:1])])[token_of_row]
 
 
@@ -1447,8 +1510,8 @@ def _blocks_product(xr, expert_of_block, used, weight_of_row, up, down, act,
         .reshape(n * block, -1)
 
 
-def _experts_sorted(x, row, w_slot, expert_of_block, used, up, down, block,
-                    act, kernel, sums):
+def _experts_sorted(x, row, token_of_row, weight_of_row, expert_of_block,
+                    used, up, down, block, act, kernel, sums):
     """Rows gathered into one buffer sorted by expert, whole blocks an
     expert (:func:`_gather_rows`: XLA's gather); the blocks' products,
     each against its expert's weights (:func:`_blocks_product`: a block
@@ -1459,14 +1522,9 @@ def _experts_sorted(x, row, w_slot, expert_of_block, used, up, down, block,
     (:func:`_sum_slots`: the window kernel of
     ``ops/pallas_moe_rows.py`` where ``sums``, which reads the sorted
     buffer in short contiguous stretches, else XLA's gather of every
-    slot's row)."""
-    t = x.shape[0]
-    cap = expert_of_block.shape[0] * block
-    slots = jnp.broadcast_to(jnp.arange(t)[:, None], row.shape)
-    token_of_row = jnp.full((cap + 1,), t, jnp.int32) \
-        .at[row.reshape(-1)].set(slots.reshape(-1))[:-1]
-    weight_of_row = jnp.zeros((cap + 1,), F32) \
-        .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1]
+    slot's row). The buffer's maps (:func:`_rows_to_slots`,
+    :func:`_weight_of_row`) come with the routing, made once a layer
+    call by :func:`_moe_experts`, never here."""
     xr = _gather_rows(x, token_of_row, row, sums) \
         .reshape(-1, block, x.shape[1])
     yr = _blocks_product(xr, expert_of_block, used, weight_of_row, up, down,
@@ -1497,27 +1555,33 @@ def _experts_dense(x, held, local, w_slot, counts, up, down, act):
     return acc.astype(x.dtype)
 
 
-def _held_terms(x, w_slot, w1, w2, routing, block, act, kernel, sums):
+def _held_terms(x, w_slot, weight_of_row, w1, w2, routing, block, act, kernel,
+                sums):
     """The held experts' terms summed by token: the sorted buffer where
     the routing fits it. No row is dropped: routing that overfills the
     buffer takes the dense
     product over the held experts instead (whole matrices at the MXU's
     pace: where routing piles the tokens on a few experts, cheaper than
     more passes of the gathered product, which PR 28 measured at 5x its
-    cost). ``routing``: :func:`_slots_to_rows`' row of each slot, routed
-    counts, expert of each block, blocks that hold a row and whether
-    the runs fit, then ``held`` and ``local``; ``kernel`` / ``sums``:
-    whether the buffer's products / its slot sum are Pallas kernels."""
-    row, counts, expert_of_block, used, fits, held, local = routing
+    cost). ``routing``: :func:`_slots_to_rows`' row of each slot, the
+    token of each row, routed counts, expert of each block, blocks that
+    hold a row and whether the runs fit, then ``held`` and ``local``;
+    the slots' weights by slot (the dense product's) and by row (the
+    buffer's); ``kernel`` / ``sums``: whether the buffer's products /
+    its slot sum are Pallas kernels."""
+    row, token_of_row, counts, expert_of_block, used, fits, held, local = \
+        routing
     return lax.cond(
         fits,
-        lambda: _experts_sorted(x, row, w_slot, expert_of_block, used, w1,
-                                w2, block, act, kernel, sums),
+        lambda: _experts_sorted(x, row, token_of_row, weight_of_row,
+                                expert_of_block, used, w1, w2, block, act,
+                                kernel, sums),
         lambda: _experts_dense(x, held, local, w_slot, counts, w1, w2, act))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act, sums):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _held_terms_kept_by_inputs(x, w_slot, weight_of_row, w1, w2, routing,
+                               block, act, sums):
     """:func:`_held_terms` on the kernel path, differentiated by hand so
     that nothing but its inputs crosses from the forward to the
     backward. Differentiated as it stands, the ``cond`` hands on each
@@ -1527,33 +1591,37 @@ def _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block, act, sums):
     carry a zero array of their size (144 MiB in the Keye-VL cell, whose
     step then no longer fits the chip: PERF.md section 6, PR 35). Here
     the backward is a ``cond`` of its own over the same ``fits``, each
-    branch the pullback of the forward's branch; the caller's
-    ``jax.checkpoint`` recomputes nothing for it."""
-    return _held_terms(x, w_slot, w1, w2, routing, block, act, True, sums)
+    branch the pullback of the forward's branch (the buffer's maps are
+    among the inputs: it builds none); the caller's ``jax.checkpoint``
+    recomputes nothing for it."""
+    return _held_terms(x, w_slot, weight_of_row, w1, w2, routing, block, act,
+                       True, sums)
 
 
-def _kept_by_inputs_fwd(x, w_slot, w1, w2, routing, block, act, sums):
-    return _held_terms(x, w_slot, w1, w2, routing, block, act, True, sums), \
-        (x, w_slot, w1, w2, routing)
+def _kept_by_inputs_fwd(x, w_slot, weight_of_row, w1, w2, routing, block, act,
+                        sums):
+    return _held_terms(x, w_slot, weight_of_row, w1, w2, routing, block, act,
+                       True, sums), (x, w_slot, weight_of_row, w1, w2, routing)
 
 
 def _kept_by_inputs_bwd(block, act, sums, res, cotangent):
-    x, w_slot, w1, w2, routing = res
-    row, counts, expert_of_block, used, fits, held, local = routing
+    *inputs, routing = res
+    row, token_of_row, counts, expert_of_block, used, fits, held, local = \
+        routing
 
     def pullback(branch):
-        return lambda: jax.vjp(branch, x, w_slot, w1, w2)[1](cotangent)
+        return lambda: jax.vjp(branch, *inputs)[1](cotangent)
 
     # (the rule is traced after the caller's scopes have closed)
     with jax.named_scope("mx.moe"), jax.named_scope("mx.moe.experts"):
         grads = lax.cond(
             fits,
-            pullback(lambda x, w_slot, w1, w2: _experts_sorted(
-                x, row, w_slot, expert_of_block, used, w1, w2, block, act,
-                True, sums)),
-            pullback(lambda x, w_slot, w1, w2: _experts_dense(
+            pullback(lambda x, w_slot, weight_of_row, w1, w2: _experts_sorted(
+                x, row, token_of_row, weight_of_row, expert_of_block, used,
+                w1, w2, block, act, True, sums)),
+            pullback(lambda x, w_slot, weight_of_row, w1, w2: _experts_dense(
                 x, held, local, w_slot, counts, w1, w2, act)))
-    # the four gradients leave together: without the barrier the TPU
+    # the gradients leave together: without the barrier the TPU
     # compiler's buffer assignment for the Keye-VL cell's step needs
     # 31 MB more than the chip has (PERF.md section 6, PR 35)
     return tuple(lax.optimization_barrier(grads)) + (None,)
@@ -1588,8 +1656,9 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
     held = (local >= 0) & (local < n_held)
     block, blocks, most = _buffer(t, top_k, n_held, n_routed,
                                   capacity_factor, block_rows)
-    row, (counts, placed), expert_of_block, used, fits = _slots_to_rows(
-        held, local, n_held, blocks * block, block)
+    # (named, as the buffer's maps below: a recomputed mixer keeps them)
+    row, (counts, placed), expert_of_block, used, fits = _routed(
+        _slots_to_rows(held, local, n_held, blocks * block, block))
     # the buffer's products by whichever schedule the call allows,
     # chosen from what can be observed here and nothing else (bf16 rows
     # and weights of sizes the kernels' tiles take, traced for one
@@ -1616,17 +1685,22 @@ def _moe_experts(x, router_w, bias, w1, w2, *, top_k, offset, scale,
         jax.ShapeDtypeStruct((blocks * block, x.shape[1]), x.dtype), top_k, t)
     telemetry.count_event("mx_moe_rows_path_total",
                           path="pallas" if sums else "xla")
-    routing = (row, counts, expert_of_block, used, fits, held, local)
     with jax.named_scope("mx.moe.experts"):
+        slot_of_row, token_of_row = _routed(
+            _rows_to_slots(row, blocks * block))
+        weight_of_row = _routed(_weight_of_row(w_slot, slot_of_row))
+        routing = (row, token_of_row, counts, expert_of_block, used, fits,
+                   held, local)
         if blocks >= most:
-            y = _experts_sorted(x, row, w_slot, expert_of_block, used, w1, w2,
-                                block, act, kernel, sums)
+            y = _experts_sorted(x, row, token_of_row, weight_of_row,
+                                expert_of_block, used, w1, w2, block, act,
+                                kernel, sums)
         elif kernel:
-            y = _held_terms_kept_by_inputs(x, w_slot, w1, w2, routing, block,
-                                           act, sums)
+            y = _held_terms_kept_by_inputs(x, w_slot, weight_of_row, w1, w2,
+                                           routing, block, act, sums)
         else:
-            y = _held_terms(x, w_slot, w1, w2, routing, block, act, False,
-                            sums)
+            y = _held_terms(x, w_slot, weight_of_row, w1, w2, routing, block,
+                            act, False, sums)
     # the rows of each expert that were computed: in the buffer those of
     # its slots that have a row there, in the dense product all of them
     done = jnp.where(fits, placed, counts)
@@ -1651,7 +1725,10 @@ _MOE_DOC = """
     the rows are moved. Rows are gathered (XLA's gather), sorted by
     expert and padded to whole blocks of ``BLOCK_ROWS`` an expert, into
     one buffer of ``CAPACITY_FACTOR`` times the held experts' even
-    share (plus a block an expert), and the blocks are multiplied by
+    share (plus a block an expert); which slot, token and weight each
+    row holds is worked out once a call (one scatter in which no two
+    updates share a place, one gather) and handed with the routing to
+    the backward; and the blocks are multiplied by
     their experts' weights (:func:`_blocks_product`): by grouped Pallas
     kernels where the call allows them, which compute the blocks that
     hold a routed row and skip the buffer's empty tail, so their work
@@ -1699,8 +1776,9 @@ def moe_mixer(data, norm_gamma, router_weight, expert_rows, w1, w2,
     (width_s, hidden), or (2 x width_s, hidden) for ``swiglu``;
     shared_w2 (hidden, width_s)). The optional inputs come last: a
     model without a score bias or a shared expert leaves them out. data
-    (batch, length, hidden). Recomputed whole in the backward
-    (``jax.checkpoint``): a step keeps ``data`` only. What reads the
+    (batch, length, hidden). Recomputed in the backward
+    (``jax.checkpoint``) but for the routing: a step keeps ``data`` and
+    :func:`_routed`'s integers and row weights. What reads the
     normed input here is the router and the shared expert; the large
     products read the routed buffer, whose ``pre`` a chunk of blocks
     keeps already (``_HIDDEN``), so the module's rule adds nothing."""
@@ -1722,8 +1800,10 @@ def moe_mixer(data, norm_gamma, router_weight, expert_rows, w1, w2,
             lax.stop_gradient(rows)
 
     with jax.named_scope("mx.moe"):
-        return jax.checkpoint(mixer)(data, norm_gamma, router_weight,
-                                     score_bias, shared_w1, shared_w2, w1, w2)
+        return jax.checkpoint(
+            mixer, policy=jax.checkpoint_policies.save_only_these_names(
+                _ROUTED))(data, norm_gamma, router_weight, score_bias,
+                          shared_w1, shared_w2, w1, w2)
 
 
 moe_mixer.__doc__ += _MOE_DOC

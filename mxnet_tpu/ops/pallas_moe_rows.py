@@ -53,8 +53,12 @@ finite wherever the step is.)
 ``decoder_ops._sum_slots`` calls :func:`sum_slots` where
 :func:`sum_available` allows; it is ``_gather_rows``' transpose under
 ``custom_vjp`` (slots <-> rows is one-to-one on what is held): its
-backward is XLA's gather, the gather's backward is this kernel, and no
-scatter runs.
+backward is XLA's gather, the gather's backward is this kernel, and
+neither runs a scatter. ``token_of_row`` comes with the routing, made
+once a layer call (``decoder_ops._rows_to_slots``: the layer's one
+scatter, in which no two updates share a place); the windows' lists are
+made from it at each of a layer's two calls (0.04 ms each in the LFM2
+cell: PERF.md section 6, PR 60).
 """
 from __future__ import annotations
 

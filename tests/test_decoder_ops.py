@@ -4,6 +4,8 @@ float32 reference of the benchmark's Nemotron-H configuration
 CPU: forward and gradients, the chunked scan against the step-by-step
 recurrence at lengths that are and are not multiples of the chunk,
 routing at its extremes, the expert-parallel share, recomputation."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -266,6 +268,236 @@ def test_routing_at_its_extremes_drops_nothing(favoured, rows,
     np.testing.assert_array_equal(np.asarray(counts[1]), rows)
     if not any(rows):
         assert float(jnp.max(jnp.abs(y))) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the sorted buffer's row maps (made once a layer call since PR 60)
+def _scatter_maps(row, w_slot, cap):
+    """The plain reference, the maps as every trace of the buffer made
+    them until PR 60: two scatters, every slot without a row writing the
+    one place past the end."""
+    tokens = jnp.broadcast_to(jnp.arange(row.shape[0])[:, None], row.shape)
+    return (jnp.full((cap + 1,), row.shape[0], jnp.int32)
+            .at[row.reshape(-1)].set(tokens.reshape(-1))[:-1],
+            jnp.zeros((cap + 1,), F32)
+            .at[row.reshape(-1)].set(w_slot.reshape(-1))[:-1])
+
+
+def _plain_layout(held, local, n_held, cap, block):
+    """(expert of each block, blocks that hold a row, whether the runs
+    fit), counted one expert and one block at a time."""
+    held, local = np.asarray(held), np.asarray(local)
+    ends, end = [], 0
+    for e in range(n_held):
+        end += -(-int(np.sum(held & (local == e))) // block) * block
+        ends.append(end)
+    starts = range(0, cap, block)
+    return ([min(sum(s >= e for e in ends), n_held - 1) for s in starts],
+            sum(s < end for s in starts), end <= cap)
+
+
+def _mixer_before_pr60(data, norm_gamma, router_w, bias, w1, w2, *, top_k,
+                       offset, eps):
+    """``_contrib_moe_mixer`` as it stood at PR 59 from the pieces that
+    are left: the maps made inside the buffer's branch, by
+    :func:`_scatter_maps`, the overflow ``cond`` differentiated as it
+    stands, the checkpoint keeping its arguments alone."""
+    def mixer(data, norm_gamma, router_w, w1, w2):
+        x = D._rms(data, norm_gamma, eps).reshape(-1, data.shape[-1])
+        t, n_held = x.shape[0], w1.shape[0]
+        idx, w_slot = D._route(x, router_w, bias, top_k, 1.0, True)
+        local = idx - offset
+        held = (local >= 0) & (local < n_held)
+        block, blocks, _ = D._buffer(t, top_k, n_held, router_w.shape[0])
+        cap = blocks * block
+        row, (counts, _), expert_of_block, used, fits = D._slots_to_rows(
+            held, local, n_held, cap, block)
+        kernel = D.pallas_grouped_mlp.grouped_mlp_available(
+            jax.ShapeDtypeStruct((blocks, block, x.shape[1]), x.dtype), w1,
+            w2)
+        sums = D.pallas_moe_rows.sum_available(
+            jax.ShapeDtypeStruct((cap, x.shape[1]), x.dtype), top_k, t)
+
+        def buffer():
+            token_of_row, weight_of_row = _scatter_maps(row, w_slot, cap)
+            xr = D._gather_rows(x, token_of_row, row, sums) \
+                .reshape(-1, block, x.shape[1])
+            yr = D._blocks_product(xr, expert_of_block, used, weight_of_row,
+                                   w1, w2, D._relu2, kernel)
+            return D._sum_slots(yr, token_of_row, row, sums)
+
+        y = jax.lax.cond(fits, buffer, lambda: D._experts_dense(
+            x, held, local, w_slot, counts, w1, w2, D._relu2))
+        return y.astype(data.dtype).reshape(data.shape)
+
+    return jax.checkpoint(mixer)(data, norm_gamma, router_w, w1, w2)
+
+
+# tokens, the experts a bias of 10 favours, what the routing is
+_MAP_CASES = {
+    "random": (80, ()),
+    "one_held_expert": (80, (4, 0, 1)),
+    "no_slot_held": (80, (0, 1, 2)),
+    "a_run_ends_on_a_block": (32, (4, 0, 1)),
+    "overfills_the_buffer": (80, (4, 5, 6)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MAP_CASES))
+def test_the_row_maps_are_the_scatter_s_and_the_layer_the_parent_s(case, path):
+    """The buffer's maps by one collision-free scatter and a gather
+    (``_rows_to_slots``, ``_weight_of_row``) are the maps the two
+    colliding scatters made, the blocks' layout is the plain count's,
+    and the mixer with the maps made once and kept across its
+    checkpoint gives the output and the five gradients of the mixer
+    that made them in every trace: by the composition to a millionth of
+    each one's largest entry, within the kernels' tolerance where they
+    are interpreted."""
+    tokens, favoured = _MAP_CASES[case]
+    w, cfg, _, given, _, grad_close = _experts_on(
+        path, lambda **kw: _moe_weights(50, **kw), 51,
+        ("experts_up_weight", "experts_down_weight"))
+    hidden = w["router_weight"].shape[1]
+    x, gamma = rand(52, (1, tokens, hidden), (hidden,))
+    x = given("x", x.astype(jnp.bfloat16).astype(F32)) if path == "pallas" \
+        else x
+    bias = jnp.zeros((16,)).at[jnp.array(favoured, jnp.int32)].set(10.0)
+    up, down = (given(n, w[n]) for n in ("experts_up_weight",
+                                         "experts_down_weight"))
+    top_k, offset, n_held = 3, cfg["expert_offset"], up.shape[0]
+
+    @jax.jit
+    def maps(x):
+        idx, w_slot = D._route(x[0], w["router_weight"], bias, top_k, 1.0,
+                               True)
+        local = idx - offset
+        held = (local >= 0) & (local < n_held)
+        block, blocks, _ = D._buffer(tokens, top_k, n_held, 16)
+        row, _, expert_of_block, used, fits = D._slots_to_rows(
+            held, local, n_held, blocks * block, block)
+        slot_of_row, token_of_row = D._rows_to_slots(row, blocks * block)
+        return (token_of_row, D._weight_of_row(w_slot, slot_of_row)), \
+            _scatter_maps(row, w_slot, blocks * block), \
+            (expert_of_block, used, fits), (held, local, block, blocks)
+
+    got, want, layout, (held, local, block, blocks) = maps(x)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    plain = _plain_layout(held, local, n_held, int(blocks * block),
+                          int(block))
+    for a, b in zip(layout, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert bool(layout[2]) == (case != "overfills_the_buffer")
+    if case == "a_run_ends_on_a_block":
+        assert tokens % int(block) == 0 and int(layout[1]) == tokens // block
+
+    op = get_op("_contrib_moe_mixer").impl
+
+    def now(x, gamma, r, up, down):
+        return op(x, gamma, r, jnp.zeros((2, n_held), F32), up, down, bias,
+                  top_k=top_k, expert_offset=offset, eps=1e-5)[0].astype(F32)
+
+    def before(x, gamma, r, up, down):
+        return _mixer_before_pr60(x, gamma, r, bias, up, down, top_k=top_k,
+                                  offset=offset, eps=1e-5).astype(F32)
+
+    args = (x, gamma, w["router_weight"], up, down)
+    assert _takes_the_kernels(now, *args) == (path == "pallas")
+    (cot,) = rand(53, x.shape)
+    got = value_and_grads(now, *args, cot=cot)
+    want = value_and_grads(before, *args, cot=cot)
+    assert len(got) == 6
+    if path == "xla":
+        # to a float32's last bits: the same sums in the same order, but
+        # two programs, and XLA's CPU code rounds a sigmoid or contracts
+        # a product and a sum by the loop a fusion puts them in (with the
+        # chosen scores gathered, as until PR 60, they agreed to the bit)
+        near(got, want, 1e-6)
+    else:
+        grad_close(got, want)
+
+
+def test_a_row_no_slot_fills_reaches_no_output_and_no_gradient(path):
+    """A NaN in the last token, which no held expert is routed, in the
+    rows and in the cotangent: the buffer's empty rows are true zeros
+    (not the last token's row, which an index clamped to the tokens
+    would read), so the outputs and the four gradients are what they
+    are with that token zeroed, and finite."""
+    w, _, _, given, _, _ = _experts_on(
+        path, lambda **kw: _moe_weights(54, **kw), 55,
+        ("experts_up_weight", "experts_down_weight"))
+    (x,) = rand(55, (80, w["router_weight"].shape[1]))
+    x = given("x", x)
+    up, down = (given(n, w[n]) for n in ("experts_up_weight",
+                                         "experts_down_weight"))
+    tokens, n_held, top_k = x.shape[0], up.shape[0], 3
+    local = np.random.default_rng(56).permuted(
+        np.tile(np.arange(16) - 4, (tokens, 1)), axis=1)[:, :top_k]
+    local[-1] = [-1, -2, 5]         # the last token: none of the four held
+    local = jnp.asarray(local, jnp.int32)
+    held = (local >= 0) & (local < n_held)
+    block, blocks, _ = D._buffer(tokens, top_k, n_held, 16)
+    (w_slot,) = rand(57, (tokens, top_k))
+    # (both kernels where they are interpreted: the grouped products'
+    # and the slot sum's windows)
+    kernel = sums = path == "pallas"
+    assert sums == D.pallas_moe_rows.sum_available(
+        jax.ShapeDtypeStruct((blocks * block, x.shape[1]), x.dtype), top_k,
+        tokens)
+
+    def fn(x, w_slot, up, down):
+        row, _, expert_of_block, used, fits = D._slots_to_rows(
+            held, local, n_held, blocks * block, block)
+        slot_of_row, token_of_row = D._rows_to_slots(row, blocks * block)
+        return D._experts_sorted(
+            x, row, token_of_row, D._weight_of_row(w_slot, slot_of_row),
+            expert_of_block, used, up, down, block, D._relu2, kernel,
+            sums).astype(F32)
+
+    assert _takes_the_kernels(fn, x, w_slot, up, down) == kernel
+    (cot,) = rand(58, x.shape)
+    want = value_and_grads(fn, x.at[-1].set(0), w_slot, up, down,
+                           cot=cot.at[-1].set(0))
+    got = value_and_grads(fn, x.at[-1].set(jnp.nan), w_slot, up, down,
+                          cot=cot.at[-1].set(jnp.nan))
+    for a, b in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_mixer_s_gradient_makes_the_maps_once_and_sorts_nothing_again():
+    """The lowered value and gradient of ``_contrib_moe_mixer``
+    (StableHLO, before any compiler has its say): one scatter of the buffer's length, the
+    collision-free one (six a layer until PR 60: two in each of the
+    forward, the recomputation and the backward rule), and none of one
+    place more; the backward holds no ``top_k``, sort or running count
+    beyond the forward's, because the chosen experts, the slots' rows
+    and the maps cross the checkpoint by name."""
+    tokens, hidden, held, cap = 80, 12, 4, 256
+    x, gamma, r, up, down = rand(59, (1, tokens, hidden), (hidden,),
+                                 (16, hidden), (held, 10, hidden),
+                                 (held, hidden, 10))
+    op = get_op("_contrib_moe_mixer").impl
+
+    def loss(*args):
+        return jnp.sum(op(*args[:3], jnp.zeros((2, held), F32), *args[3:],
+                          top_k=3, expert_offset=4)[0].astype(F32))
+
+    def lowered(fn):
+        return jax.jit(fn).lower(x, gamma, r, up, down).as_text()
+
+    forward = lowered(loss)
+    gradient = lowered(jax.value_and_grad(loss, range(5)))
+    assert D._buffer(tokens, 3, held, 16)[:2] == (32, cap // 32)
+    scattered = re.findall(r'"stablehlo\.scatter"\(.*?-> tensor<([0-9x]*)x[a-z]',
+                           gradient, re.S)
+    assert scattered.count(str(cap)) == 1, scattered
+    assert str(cap + 1) not in scattered
+    assert "unique_indices = true" in gradient
+    for again in (r"chlo\.top_k", r"stablehlo\.sort", r"call @cumsum"):
+        assert len(re.findall(again, gradient)) \
+            == len(re.findall(again, forward)), again
+    assert len(re.findall(r"chlo\.top_k", forward)) == 1
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
